@@ -178,10 +178,12 @@ class ConcurrencyPlane:
                           else cfg.encode_process_mode),
             process_min_rows=cfg.encode_process_min_rows)
         self._tls = threading.local()
-        # the serving fabric (shm/): attach once per process, register
-        # the scrape-time collectors (fabric gauges + worker-metrics
-        # fold), and default the persistent XLA compilation cache to the
-        # shared namespace — all no-ops when GTPU_SHM_FABRIC is off
+        # the serving fabric (shm/): attach once per process and
+        # register the scrape-time collectors (fabric gauges +
+        # worker-metrics fold) — no-ops when GTPU_SHM_FABRIC is off.
+        # Compiled executables are shared through JAX's persistent
+        # compilation cache, which every process of a checkout places by
+        # the one rule in greptimedb_tpu/__init__.py
         from greptimedb_tpu import shm
 
         if cfg.enabled and shm.get_fabric() is not None:
@@ -189,23 +191,6 @@ class ConcurrencyPlane:
 
             metrics_bridge.install_collector()
             shm.install_stats_collector()
-            shm.apply_shared_xla_cache()
-            # the engine builds its PhysicalExecutor BEFORE this plane,
-            # so the executor's enable_compilation_cache() ran without
-            # the shared dir; re-wire now (idempotent, process-global
-            # jax config) so THIS process caches into the fabric
-            from greptimedb_tpu.query.physical import (
-                enable_compilation_cache,
-            )
-
-            if enable_compilation_cache():
-                # in the shared namespace cache even sub-second
-                # compiles: on an N-process box every executable cached
-                # here is another frontend's first-query win
-                import jax
-
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.0)
 
     # ---- batching gate -----------------------------------------------------
 

@@ -66,9 +66,27 @@ def _worker_watchdog(parent: int) -> None:
 def _worker_init() -> None:
     import os
 
+    import jax
+
+    # a chip belongs to one process, and that is the server that spawned
+    # this worker with its own environment (JAX_PLATFORMS may name the
+    # accelerator). A worker only serializes host arrays; pin it to the
+    # CPU so nothing it imports can ever initialise — or hang on — the
+    # parent's chip. jax was already imported by the package import that
+    # unpickled this initializer, so the config, not the env var, pins.
+    jax.config.update("jax_platforms", "cpu")
     t = threading.Thread(target=_worker_watchdog, args=(os.getppid(),),
                          daemon=True, name="gtpu-encode-watchdog")
     t.start()
+
+
+def worker_jax_platforms() -> str:
+    """The platform list jax is pinned to in the calling process; run on
+    the pool (`pool.run(worker_jax_platforms)`) it shows what a spawn
+    worker could initialise."""
+    import jax
+
+    return jax.config.jax_platforms
 
 
 class EncodePool:

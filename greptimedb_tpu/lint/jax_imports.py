@@ -2,8 +2,8 @@
 
 PR 7 introduced the `sys.modules` seam so `storage/region.py` can
 notify the device hot set without ever importing the query layer: a
-pure-storage datanode child must never pay jax's import cost (or touch
-an accelerator tunnel) for work that is all parquet and WAL bytes.
+pure-storage datanode child must never pay jax's import cost (or reach
+for the frontend's chip) for work that is all parquet and WAL bytes.
 
 Two rules, both verified over the *top-level* import graph (imports
 inside a function are lazy and fine — only module-body imports execute

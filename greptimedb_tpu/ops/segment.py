@@ -86,7 +86,7 @@ def dense_segment_sum(plane: jax.Array, ids: jax.Array,
     if mode != "off" and finite and plane.ndim == 2:
         from greptimedb_tpu.ops import pallas_segment as ps
 
-        backend = jax.default_backend()
+        backend = ps.target_platform()
         dtype_ok = plane.dtype in (jnp.float32, jnp.bfloat16) \
             or backend != "tpu"
         # cheap pure checks first: the canary costs one Mosaic compile,
@@ -94,9 +94,7 @@ def dense_segment_sum(plane: jax.Array, ids: jax.Array,
         if dtype_ok and ps.eligible(plane.shape, num_segments) and (
                 mode == "on" or (mode == "auto" and backend == "tpu"
                                  and ps.tpu_compile_ok())):
-            return ps.pallas_dense_segment_sum(
-                plane, ids, num_segments,
-                interpret=backend != "tpu")
+            return ps.pallas_dense_segment_sum(plane, ids, num_segments)
     return jax.ops.segment_sum(plane, ids, num_segments=num_segments)
 
 

@@ -159,7 +159,6 @@ def fused_sparse_segment_agg(
     want_sumsq: bool = False,
     tile: int = FUSED_TILE,
     block_rows: int = 512,
-    interpret: bool = False,
 ) -> dict:
     """Tiled fused-kernel reduction over sort-compacted ranks.
 
@@ -210,8 +209,7 @@ def fused_sparse_segment_agg(
                           ids_c - base, jnp.int32(r))
         out = ps.pallas_fused_segment_agg(
             vals_c, local, r + 1, want_min=want_min, want_max=want_max,
-            want_sumsq=want_sumsq, block_rows=block_rows,
-            interpret=interpret)
+            want_sumsq=want_sumsq, block_rows=block_rows)
 
         def fold(name, combine):
             plane = out[name][:r].astype(dt)

@@ -268,9 +268,6 @@ class QueryOptions:
     stream_block_rows: int = 2 << 20
     # HBM budget for the device columnar hot set; 0 = auto
     device_cache_bytes: int = 0
-    # persistent XLA compilation cache dir; "" = auto (on for TPU),
-    # "off" disables
-    compilation_cache_dir: str = ""
     # background Pallas kernel pre-warm at open; "" = auto (TPU only)
     prewarm: str = ""
     # measured per-tier routing history; off = static heuristic only
@@ -603,8 +600,6 @@ def apply_query_env(opts: "StandaloneOptions") -> None:
         "GREPTIMEDB_TPU_DEVICE_CACHE_BYTES":
             (str(q.device_cache_bytes) if q.device_cache_bytes else "",
              ""),
-        "GREPTIMEDB_TPU_COMPILATION_CACHE_DIR":
-            (q.compilation_cache_dir, dq.compilation_cache_dir),
         "GREPTIMEDB_TPU_PREWARM": (q.prewarm, dq.prewarm),
         "GREPTIMEDB_TPU_TIER_ADAPTIVE":
             ("" if q.tier_adaptive else "off", ""),
@@ -969,8 +964,6 @@ _DOC = {
     "query.stream_block_rows": "Rows per streamed device block.",
     "query.device_cache_bytes":
         "HBM budget for the device columnar hot set; 0 = auto.",
-    "query.compilation_cache_dir":
-        "Persistent XLA compile cache; empty = auto (TPU), 'off' disables.",
     "query.prewarm":
         "Background kernel pre-warm at open: on | off; empty = auto (TPU).",
     "query.tier_adaptive":

@@ -18,12 +18,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6: top-level shard_map, replication check kwarg is check_vma
-    from jax import shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except ImportError:  # older jax: experimental module, kwarg is check_rep
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
+from jax import shard_map
 
 from greptimedb_tpu.ops.segment import segment_agg
 
@@ -74,8 +69,7 @@ def init_distributed(coordinator: Optional[str] = None,
     if process_id is None:
         env_p = os.environ.get("GREPTIMEDB_TPU_PROCESS_ID")
         process_id = int(env_p) if env_p else None
-    already = getattr(jax.distributed, "is_initialized", None)
-    if already is not None and already():
+    if jax.distributed.is_initialized():
         return True  # idempotent: embedding + multiple server entries
     import sys
 
@@ -153,7 +147,7 @@ def sharded_segment_agg(
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=out_specs,
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     def step(v, g, m, *rest):
         from greptimedb_tpu.ops.segment import combine_partial_aggs

@@ -1759,6 +1759,10 @@ class QueryEngine:
             path = getattr(self.executor, "last_path", None)
             if path:
                 lines.append(f"  execution path: {path}")
+                # which tier ran it (device | host | mesh): the first
+                # touch of a shape may be hedged to the host tier
+                lines.append(
+                    f"  execution tier: {self.executor.last_tier}")
         # the merged per-process span TREE: children nest under their
         # parents (remote datanode spans re-parent under the frontend
         # span that issued the RPC via the piggybacked linkage), each

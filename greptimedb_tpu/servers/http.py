@@ -40,11 +40,6 @@ class HttpServer:
         self._thread: Optional[threading.Thread] = None
 
     def start(self) -> int:
-        # initialize the jax backend from the MAIN thread: some PJRT
-        # plugins refuse lazy initialization from worker threads
-        import jax
-        jax.devices()
-
         qe = self.qe
 
         provider = self.user_provider
@@ -302,6 +297,13 @@ class _Handler(BaseHTTPRequestHandler):
                     "fired": [{"labels": labels, "count": count}
                               for labels, count in
                               FAULT_INJECTIONS.series()]})
+            if path == "/v1/device":
+                # which device this process serves from and whether
+                # anything on the device path degraded (canaries,
+                # latches, warm-up failures) — what chip_smoke.py and an
+                # operator check before trusting a latency
+                return self._send(
+                    200, self.query_engine.executor.device_status())
             if path == "/v1/maintenance":
                 # background maintenance plane debug surface: queue
                 # depth + job list (newest first) + stall counters

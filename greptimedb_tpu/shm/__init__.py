@@ -185,17 +185,3 @@ def collect_fabric_stats() -> None:
                              dim="size")
         SHM_FABRIC_BYTES.set(float(st["heap_used"]), segment="fabric",
                              dim="used")
-
-
-def apply_shared_xla_cache() -> None:
-    """Point this process's persistent XLA compilation cache at the
-    fabric's shared namespace (unless the operator pinned an explicit
-    one) — the shared-executable leg of the tentpole: process 2's first
-    query loads the executable process 1 compiled."""
-    cfg = config_from_env()
-    if not cfg.fabric:
-        return
-    if os.environ.get("GREPTIMEDB_TPU_COMPILATION_CACHE_DIR"):
-        return  # operator override wins
-    os.environ["GREPTIMEDB_TPU_COMPILATION_CACHE_DIR"] = \
-        os.path.join(cfg.fabric_dir, "xla-cache")

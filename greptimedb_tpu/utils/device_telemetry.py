@@ -31,6 +31,7 @@ from greptimedb_tpu.utils.metrics import (
     DEVICE_MEMORY,
     DEVICE_TRANSFER_BYTES,
     REGISTRY,
+    XLA_CACHE_RETRIEVALS,
     XLA_COMPILE_SECONDS,
     XLA_COMPILES,
 )
@@ -78,18 +79,18 @@ def _on_event_duration(event: str, duration_secs: float, **kwargs) -> None:
         return
     if event != _COMPILE_EVENT:
         return
+    import jax
+
+    # a compile event means a backend is up: default_backend() cannot
+    # initialise (or fail to initialise) anything here
+    backend = jax.default_backend()
     pending = getattr(_compile_tls, "cache_hits", 0)
     if pending:
         # persistent-cache retrieval wrapped in a compile event: the
         # backend compiled nothing, so the compile counter stays put
         _compile_tls.cache_hits = pending - 1
+        XLA_CACHE_RETRIEVALS.inc(backend=backend)
         return
-    import jax
-
-    try:
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 — never let telemetry break a compile
-        backend = "unknown"
     XLA_COMPILES.inc(backend=backend)
     XLA_COMPILE_SECONDS.observe(float(duration_secs), backend=backend)
 
@@ -100,17 +101,17 @@ def _collect_device_memory() -> None:
     for cache in list(_caches):
         cache_bytes += getattr(cache, "_bytes", 0)
     DEVICE_MEMORY.set(float(cache_bytes), kind="cache")
-    try:
-        import jax
+    import jax
 
-        stats = jax.devices()[0].memory_stats()
-    except Exception:  # noqa: BLE001 — backend may not be initialized yet
-        stats = None
-    if stats:
-        if "bytes_in_use" in stats:
-            DEVICE_MEMORY.set(float(stats["bytes_in_use"]), kind="in_use")
-        if "bytes_limit" in stats:
-            DEVICE_MEMORY.set(float(stats["bytes_limit"]), kind="limit")
+    stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    if any("bytes_in_use" in st for st in stats):
+        # every local device counts: a mesh spreads the hot set
+        DEVICE_MEMORY.set(
+            float(sum(st.get("bytes_in_use", 0) for st in stats)),
+            kind="in_use")
+        DEVICE_MEMORY.set(
+            float(sum(st.get("bytes_limit", 0) for st in stats)),
+            kind="limit")
     else:
         # CPU backend (no PJRT allocator stats): the block cache's pinned
         # bytes ARE the device working set — report them so the series
@@ -126,10 +127,7 @@ def install() -> None:
         if _installed:
             return
         _installed = True
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        monitoring.register_event_duration_secs_listener(_on_event_duration)
-    except Exception:  # noqa: BLE001 — older jax without monitoring
-        pass
+    monitoring.register_event_duration_secs_listener(_on_event_duration)
     REGISTRY.register_collector(_collect_device_memory)

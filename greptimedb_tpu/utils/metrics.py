@@ -532,13 +532,19 @@ REQUEST_BUDGET_REMAINING = REGISTRY.histogram(
 XLA_COMPILES = REGISTRY.counter(
     "greptimedb_tpu_xla_compile_total",
     "XLA compilations observed via jax.monitoring, by backend")
+XLA_CACHE_RETRIEVALS = REGISTRY.counter(
+    "greptimedb_tpu_xla_cache_retrieval_total",
+    "Executables served by JAX's persistent compilation cache instead "
+    "of being compiled, by backend (a warm start shows retrievals and "
+    "no xla_compile_total growth)")
 XLA_COMPILE_SECONDS = REGISTRY.histogram(
     "greptimedb_tpu_xla_compile_duration_seconds",
     "XLA backend-compile wall time per compilation, by backend")
 DEVICE_MEMORY = REGISTRY.gauge(
     "greptimedb_tpu_device_memory_bytes",
-    "Accelerator memory by kind (in_use/limit from the PJRT allocator "
-    "when available, cache = bytes pinned by the device block cache)")
+    "Accelerator memory by kind (in_use/limit summed over the local "
+    "devices' PJRT allocators when they report, cache = bytes pinned "
+    "by the device block cache; per-device figures at /v1/device)")
 DEVICE_TRANSFER_BYTES = REGISTRY.counter(
     "greptimedb_tpu_device_transfer_bytes_total",
     "Host<->device bytes moved by the query engine, by direction "
@@ -557,10 +563,19 @@ DEVICE_HOT_SET_BYTES = REGISTRY.gauge(
     "Bytes currently pinned in HBM by the device columnar hot set")
 PALLAS_DISPATCHES = REGISTRY.counter(
     "greptimedb_tpu_pallas_dispatch_total",
-    "Pallas TPU kernel dispatches by kernel (fused_agg = the fused "
-    "scan/filter/bucket/aggregate kernel, segment_sum = the one-hot "
-    "matmul segment-sum; fused_agg_failed = mid-query degradations to "
-    "the XLA scatter path)")
+    "Pallas kernel dispatches by kernel (fused_agg = the fused "
+    "scan/filter/bucket/aggregate kernel, sparse_fused_agg = its tiled "
+    "sparse twin; fused_agg_failed = mid-query degradations to the XLA "
+    "scatter path) and mode (compiled = Mosaic on a TPU, interpret = "
+    "the Pallas interpreter off-TPU)")
+DEVICE_DEGRADATIONS = REGISTRY.counter(
+    "greptimedb_tpu_device_degradation_total",
+    "Times the device path stopped being the device path while serving "
+    "continued, by kind (canary_dense/canary_fused = Mosaic refused a "
+    "kernel family, fused_latch/partial_latch = a runtime failure "
+    "latched a path off, warmup_failed = a hedged device warm-up "
+    "failed and the shape stays on the host tier, prewarm_failed = the "
+    "background kernel pre-warm failed); any non-zero value is a bug")
 SPARSE_DISPATCHES = REGISTRY.counter(
     "greptimedb_tpu_sparse_dispatch_total",
     "Sparse sort-compact aggregation dispatches by path (classic = "

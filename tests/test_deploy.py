@@ -32,8 +32,7 @@ def _spawn(tmp_path, name, *args):
     proc = subprocess.Popen(
         [sys.executable, "-m", "greptimedb_tpu", *args],
         stdout=log, stderr=subprocess.STDOUT,
-        env={**os.environ, "JAX_PLATFORMS": "cpu",
-             "GREPTIMEDB_TPU_PLATFORM": "cpu"},
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     return proc, log
 

@@ -68,7 +68,7 @@ def test_fused_kernel_matches_oracle(n, f, g, seed):
     ids = rng.integers(0, g - 1, n).astype(np.int32)
     got = pallas_fused_segment_agg(
         jnp.asarray(vals), jnp.asarray(ids), g,
-        want_min=True, want_max=True, interpret=True)
+        want_min=True, want_max=True)
     want = _oracle(vals, ids, g)
     live = g - 1
     np.testing.assert_allclose(np.asarray(got["sum"])[:live],
@@ -91,8 +91,7 @@ def test_fused_integer_planes_bit_exact():
     n, f, g = 2048, 6, 33
     vals = rng.integers(-1000, 1000, (n, f)).astype(np.float64)
     ids = rng.integers(0, g, n).astype(np.int32)
-    got = pallas_fused_segment_agg(jnp.asarray(vals), jnp.asarray(ids), g,
-                                   interpret=True)
+    got = pallas_fused_segment_agg(jnp.asarray(vals), jnp.asarray(ids), g)
     want_sum = np.asarray(jax.ops.segment_sum(
         jnp.asarray(vals), jnp.asarray(ids), num_segments=g))
     np.testing.assert_array_equal(np.asarray(got["sum"]), want_sum)
@@ -109,7 +108,7 @@ def test_fused_f32_tolerance():
     ids = rng.integers(0, g, n).astype(np.int32)
     got = pallas_fused_segment_agg(
         jnp.asarray(vals), jnp.asarray(ids), g,
-        want_min=True, want_max=True, interpret=True)
+        want_min=True, want_max=True)
     want = _oracle(vals.astype(np.float64), ids, g)
     np.testing.assert_allclose(np.asarray(got["sum"]), want["sum"],
                                rtol=2e-5)
@@ -126,8 +125,7 @@ def test_fused_dead_segment_rows_excluded():
     vals = np.asarray([[1.0], [2.0], [1e9]])
     ids = np.asarray([0, 0, 2], dtype=np.int32)  # row 2 -> dead seg
     got = pallas_fused_segment_agg(jnp.asarray(vals), jnp.asarray(ids), 3,
-                                   want_min=True, want_max=True,
-                                   interpret=True)
+                                   want_min=True, want_max=True)
     assert float(got["sum"][0, 0]) == 3.0
     assert float(got["rows"][0]) == 2.0
     assert float(got["max"][0, 0]) == 2.0
@@ -477,13 +475,24 @@ class TestFusedDegradation:
             raise RuntimeError("injected Mosaic failure")
 
         monkeypatch.setattr(ph, "_agg_scan_fused", boom)
+        from greptimedb_tpu.utils.metrics import DEVICE_DEGRADATIONS
+
         before = PALLAS_DISPATCHES.get(kernel="fused_agg_failed")
+        degraded = DEVICE_DEGRADATIONS.get(kind="fused_latch")
         got = qe.execute_one(AGG_SQL).rows()
         assert got == want  # the query still answered
         assert qe.executor.last_path == "dense_prepared"
         assert ph._FUSED_DISABLED["flag"] is True
         assert PALLAS_DISPATCHES.get(
             kernel="fused_agg_failed") == before + 1
+        # never silent: counted, and the failure's own message is kept
+        # for GET /v1/device
+        assert DEVICE_DEGRADATIONS.get(kind="fused_latch") == degraded + 1
+        status = qe.executor.device_status()
+        assert status["pallas"]["fused_disabled"] is True
+        assert any(d["kind"] == "fused_latch"
+                   and "injected Mosaic failure" in d["error"]
+                   for d in status["degradations"])
         # latched: later queries skip the fused attempt outright
         qe.execute_one(AGG_SQL)
         assert qe.executor.last_path == "dense_prepared"

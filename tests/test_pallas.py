@@ -46,7 +46,7 @@ def test_kernel_matches_scatter(n, w, gsz):
     ids[dead] = gsz - 1
     plane[dead] = 0.0
     got = np.asarray(pallas_dense_segment_sum(
-        jnp.asarray(plane), jnp.asarray(ids), gsz, interpret=True))
+        jnp.asarray(plane), jnp.asarray(ids), gsz))
     want = _oracle(plane, ids, gsz)
     # summation ORDER differs (matmul vs scatter): allclose, not equal
     np.testing.assert_allclose(got, want, rtol=1e-10, atol=1e-9)
@@ -57,7 +57,7 @@ def test_kernel_f32():
     plane = rng.uniform(0, 100, (2048, 21)).astype(np.float32)
     ids = rng.integers(0, 48, 2048).astype(np.int32)
     got = np.asarray(pallas_dense_segment_sum(
-        jnp.asarray(plane), jnp.asarray(ids), 48, interpret=True))
+        jnp.asarray(plane), jnp.asarray(ids), 48))
     want = _oracle(plane, ids, 48)
     np.testing.assert_allclose(got, want, rtol=2e-5)
 
@@ -65,8 +65,7 @@ def test_kernel_f32():
 def test_empty_segments_are_zero():
     plane = jnp.ones((64, 3))
     ids = jnp.full((64,), 7, dtype=jnp.int32)
-    out = np.asarray(pallas_dense_segment_sum(plane, ids, 16,
-                                              interpret=True))
+    out = np.asarray(pallas_dense_segment_sum(plane, ids, 16))
     assert out[7, 0] == 64.0
     assert (np.delete(out, 7, axis=0) == 0).all()
 

@@ -84,12 +84,13 @@ class TestRooflineAccountant:
     def test_time_preference_device_then_agg_then_duration(self):
         base = {"h2d_bytes": 1_000_000_000}
         assert roofline.account({**base, "device_ms": 100.0,
-                                 "agg_ms": 999.0},
-                                duration_ms=5555.0)["window_ms"] == 100.0
+                                 "agg_ms": 999.0}, duration_ms=5555.0,
+                                peak=100.0)["window_ms"] == 100.0
         assert roofline.account({**base, "agg_ms": 200.0},
-                                duration_ms=5555.0)["window_ms"] == 200.0
-        assert roofline.account(base,
-                                duration_ms=400.0)["window_ms"] == 400.0
+                                duration_ms=5555.0,
+                                peak=100.0)["window_ms"] == 200.0
+        assert roofline.account(base, duration_ms=400.0,
+                                peak=100.0)["window_ms"] == 400.0
 
     def test_host_only_statement_stamps_nothing(self):
         # no bytes, or no time window -> None, never a misleading zero
@@ -109,32 +110,19 @@ class TestRooflineAccountant:
         assert attrs["roofline_fraction"] == pytest.approx(0.001)
         assert rf["bytes_total"] == 819_000_000
 
-    def test_peak_env_override_and_backend_table(self, monkeypatch):
+    def test_peak_by_device_kind_unknown_device_has_none(self, monkeypatch):
         monkeypatch.setenv("GTPU_ROOFLINE_PEAK_GBPS", "123.5")
         assert roofline.peak_gbps() == 123.5
         monkeypatch.delenv("GTPU_ROOFLINE_PEAK_GBPS")
-        assert roofline.peak_gbps("tpu") == 819.0
-        assert roofline.peak_gbps("cpu") == 100.0
+        assert roofline.peak_gbps("TPU v5 lite") == 819.0
+        # a device the table does not know has no peak — and no fold,
+        # never a default (the tests' CPU device is one)
+        assert roofline.peak_gbps("some future chip") is None
+        assert roofline.peak_gbps() is None
+        assert roofline.account(
+            {"h2d_bytes": 1_000_000, "device_ms": 1.0}) is None
         monkeypatch.setenv("GTPU_ROOFLINE_PEAK_GBPS", "not-a-number")
-        assert roofline.peak_gbps("tpu") == 819.0
-
-    def test_tunnel_link_clamps_peak(self, monkeypatch):
-        # over a network tunnel the measured D2H rate is the real
-        # ceiling — the fraction must read vs what's attainable, not
-        # vs HBM the link can never deliver
-        monkeypatch.delenv("GTPU_ROOFLINE_PEAK_GBPS", raising=False)
-        from greptimedb_tpu.query import physical
-
-        monkeypatch.setattr(
-            physical, "_LINK",
-            {"backend": "tpu", "rtt_ms": 66.0, "d2h_mbps": 11.0,
-             "colocated": False})
-        assert roofline.peak_gbps() == pytest.approx(0.011)
-        monkeypatch.setattr(
-            physical, "_LINK",
-            {"backend": "tpu", "rtt_ms": 0.3, "d2h_mbps": 9000.0,
-             "colocated": True})
-        assert roofline.peak_gbps() == 819.0
+        assert roofline.peak_gbps("TPU v5 lite") == 819.0
 
     def test_format_line_stable(self):
         rf = roofline.account({"h2d_bytes": 2_000_000, "device_ms": 4.0},

@@ -75,16 +75,12 @@ def fabric_dir(tmp_path):
 @pytest.fixture
 def fabric_env(fabric_dir, monkeypatch):
     """Fabric switched on for this process, singleton reset on both
-    sides so other tests never see a stale attach. The shared XLA
-    cache is pinned OFF: tests tear the fabric dir down, and a latched
-    process-global compilation cache pointing into a deleted tmp dir
-    would outlive the test."""
+    sides so other tests never see a stale attach."""
     from greptimedb_tpu import shm
 
     shm.shutdown_fabric()
     monkeypatch.setenv("GTPU_SHM_FABRIC", "1")
     monkeypatch.setenv("GTPU_SHM_FABRIC_DIR", fabric_dir)
-    monkeypatch.setenv("GREPTIMEDB_TPU_COMPILATION_CACHE_DIR", "off")
     yield fabric_dir
     shm.shutdown_fabric()
 

@@ -33,7 +33,7 @@ def test_link_probe_on_cpu_is_colocated():
 
 
 class TestRemoteLinkPolicy:
-    """Stub a tunnel-shaped link and a non-cpu backend."""
+    """Stub a slow, not-attached link and a non-cpu backend."""
 
     @pytest.fixture(autouse=True)
     def remote_link(self, monkeypatch, executor):

@@ -583,22 +583,30 @@ class TestEncodePath:
         assert cfg.encode_process_mode == "on"
         assert cfg.encode_process_min_rows == 7
 
-    def test_process_pool_round_trip(self):
+    def test_process_pool_round_trip(self, monkeypatch):
         """Spawn-mode process encoding returns the same bytes as
         inline (full GIL escape behind [concurrency]
-        encode_process_pool)."""
+        encode_process_pool). The worker inherits the server's
+        environment — which on a chip names the accelerator — and must
+        pin itself to the CPU: the chip belongs to the server."""
+        from greptimedb_tpu.concurrency.encode_pool import (
+            worker_jax_platforms,
+        )
         from greptimedb_tpu.servers.encode import encode_sql_payload
 
         r = QueryResult(["a", "b"], [None, None],
                         [np.asarray([1.0, float("nan")]),
                          np.asarray(["x", "y"], dtype=object)])
         want = encode_sql_payload([r], 1.25)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
         pool = EncodePool(workers=1, process=True)
         try:
             got = pool.run(encode_sql_payload, [r], 1.25)
+            platforms = pool.run(worker_jax_platforms)
         finally:
             pool.shutdown()
         assert got == want
+        assert platforms == "cpu"
 
     def test_http_50_clients_byte_identical_to_idle_serial(self, tmp_path):
         """The satellite acceptance: threaded keep-alive clients under
