@@ -1127,12 +1127,9 @@ def bench_device_tier(engine, qe, results):
     """Device-tier micro-phase (ISSUE 7): the headline double-groupby
     shape pinned to the device tier — cold (empty hot set) vs hot-set-
     warm p50, warmup compile seconds, per-query H2D bytes from the
-    transfer-counter deltas, accountant-folded achieved GB/s and
-    roofline fraction (ledger bytes over probed link peak — replaces
-    the old allocator-only hbm_utilization readout), and the
-    post-flush query that must re-upload ONLY the new file's blocks."""
+    transfer-counter deltas, and the post-flush query that must
+    re-upload ONLY the new file's blocks."""
     from greptimedb_tpu.datatypes import DictVector, RecordBatch
-    from greptimedb_tpu.utils import ledger, roofline
     from greptimedb_tpu.utils.metrics import (
         DEVICE_HOT_SET_BYTES,
         DEVICE_TRANSFER_BYTES,
@@ -1164,15 +1161,9 @@ def bench_device_tier(engine, qe, results):
     try:
         ex.cache.clear()  # cold: nothing resident in HBM
         c0, b0, f0 = compile_s(), h2d(), fused_dispatches()
-        # fold the cold run (the bandwidth-bound one: real H2D traffic)
-        # through the per-query ledger so the roofline numbers come from
-        # the same accountant that stamps spans and slow-query records
-        with ledger.attach_fresh() as led:
-            t0 = time.perf_counter()
-            qe.execute_one(sql)
-            cold_ms = (time.perf_counter() - t0) * 1000
-        cold_counts = ledger.derive(led.snapshot()) if led is not None \
-            else {}
+        t0 = time.perf_counter()
+        qe.execute_one(sql)
+        cold_ms = (time.perf_counter() - t0) * 1000
         warmup_compile_s = compile_s() - c0
         cold_h2d = h2d() - b0
         path = ex.last_path
@@ -1216,18 +1207,11 @@ def bench_device_tier(engine, qe, results):
             os.environ.pop("GREPTIMEDB_TPU_HOST_TIER", None)
         else:
             os.environ["GREPTIMEDB_TPU_HOST_TIER"] = prev
-    # accountant-folded roofline for the cold (bandwidth-bound) run:
-    # ledger bytes over device time vs the probed link peak — the same
-    # numbers stamped on spans, so bench and traces can't disagree
-    rf = roofline.account(cold_counts, duration_ms=cold_ms)
-    achieved = round(rf["achieved_gbps"], 3) if rf else None
-    fraction = round(rf["roofline_fraction"], 4) if rf else None
     log(f"device-tier: cold {cold_ms:.0f} ms ({cold_h2d / 1e6:.0f} MB "
         f"H2D, compile {warmup_compile_s:.1f}s) -> warm {warm_ms:.1f} ms "
         f"({warm_h2d_per_q / 1e6:.2f} MB/query), post-flush "
         f"{incr_ms:.0f} ms ({incr_h2d / 1e6:.1f} MB), path={path}, "
-        f"hot set {hot_bytes / 1e6:.0f} MB, achieved_gbps={achieved} "
-        f"roofline_fraction={fraction}")
+        f"hot set {hot_bytes / 1e6:.0f} MB")
     results["device_tier"] = {
         "path": path,
         "cold_ms": round(cold_ms, 1),
@@ -1239,8 +1223,6 @@ def bench_device_tier(engine, qe, results):
         "post_flush_h2d_bytes": int(incr_h2d),
         "hot_set_bytes": int(hot_bytes),
         "fused_kernel_dispatches": int(fused_served),
-        "achieved_gbps": achieved,
-        "roofline_fraction": fraction,
         "baseline_ms": None, "vs_baseline": None}
 
 
@@ -1644,7 +1626,6 @@ def bench_qps(qe, results, clients=None, requests_total=None):
     )
     from greptimedb_tpu.utils.metrics import (
         PLAN_CACHE_EVENTS,
-        QUERY_ACHIEVED_GBPS,
         QUERY_BATCH_EVENTS,
     )
 
@@ -1678,8 +1659,6 @@ def bench_qps(qe, results, clients=None, requests_total=None):
                   QUERY_BATCH_EVENTS.get(event="stacked"),
                   QUERY_BATCH_EVENTS.get(event="vmapped"))
         serving0 = _serving_snapshot()
-        gbps0 = (QUERY_ACHIEVED_GBPS.total_count(),
-                 QUERY_ACHIEVED_GBPS.total_sum())
 
         per_client = max(1, requests_total // clients)
         latencies = [[] for _ in range(clients)]
@@ -1840,13 +1819,6 @@ def bench_qps(qe, results, clients=None, requests_total=None):
             "qps": 0.0, "clients": clients, "requests": 0, "errors": n_err}
         return
     qps = done / wall
-    d_cnt = QUERY_ACHIEVED_GBPS.total_count() - gbps0[0]
-    d_sum = QUERY_ACHIEVED_GBPS.total_sum() - gbps0[1]
-    mean_gbps = (d_sum / d_cnt) if d_cnt else None
-    from greptimedb_tpu.utils import roofline as _rl
-
-    peak = _rl.peak_gbps()
-    rl_fraction = (mean_gbps / peak) if (mean_gbps and peak) else None
     d_hit = PLAN_CACHE_EVENTS.get(event="hit") - cache0[0]
     d_miss = PLAN_CACHE_EVENTS.get(event="miss") - cache0[1]
     hit_rate = d_hit / (d_hit + d_miss) if (d_hit + d_miss) else None
@@ -1873,15 +1845,10 @@ def bench_qps(qe, results, clients=None, requests_total=None):
         f"off {profiling_ab['qps_profiling_off']} qps -> "
         f"{profiling_ab['overhead_pct']:+.2f}% overhead (budget 2%), "
         f"{profiling_ab['flame_samples']} samples "
-        f"({profiling_ab['flame_attributed']} attributed); mean achieved "
-        f"{-1.0 if mean_gbps is None else mean_gbps:.3f} GB/s")
+        f"({profiling_ab['flame_attributed']} attributed)")
     results["qps_single_groupby"] = {
         "tracing_overhead": tracing_ab,
         "profiling_overhead": profiling_ab,
-        "achieved_gbps_mean": (None if mean_gbps is None
-                               else round(mean_gbps, 4)),
-        "roofline_fraction_mean": (None if rl_fraction is None
-                                   else round(rl_fraction, 6)),
         "qps": round(qps, 1), "clients": clients, "requests": done,
         "errors": n_err,
         "mean_ms": round(float(lats.mean() * 1000), 2),
@@ -2881,6 +2848,11 @@ def bench_tail_latency(results):
     log(f"tail_latency: {json.dumps(out)}")
 
 
+#: peak memory bandwidth by jax device_kind, GB/s (Google Cloud
+#: documentation, "TPU v5e": 16 GB HBM2e, 819 GB/s per chip)
+_PEAK_HBM_GBPS = {"TPU v5 lite": 819.0}
+
+
 def roofline_detail(device_kind, results, rows):
     """Analytic achieved-bandwidth/FLOP numbers for the headline query,
     plus the chip roofline when on TPU — the MFU computation the round-3
@@ -2907,10 +2879,9 @@ def roofline_detail(device_kind, results, rows):
         "achieved_gbps": round(total_bytes / p50_s / 1e9, 1),
         "achieved_gflops": round(flops / p50_s / 1e9, 1),
     }
-    # one peak table, keyed by device_kind (utils/roofline.py); a device
-    # it does not know gets no utilization figure, never a default peak
-    from greptimedb_tpu.utils import roofline
-    peak_gbps = roofline.peak_gbps(device_kind)
+    # a device the table does not know gets no utilization figure,
+    # never a default peak
+    peak_gbps = _PEAK_HBM_GBPS.get(device_kind)
     if peak_gbps is not None:
         out["peak_hbm_gbps"] = peak_gbps
         out["hbm_utilization"] = round(

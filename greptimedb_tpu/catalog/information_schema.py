@@ -198,7 +198,7 @@ def _slow_queries(qe, ctx):
     cols = {k: [] for k in (
         "trace_id", "kind", "query", "db", "duration_ms", "threshold_ms",
         "rows", "execution_path", "plan_cache_skip", "started_at",
-        "stages", "ledger", "achieved_gbps", "roofline_fraction")}
+        "stages", "ledger")}
     for rec in slow_query.records():
         cols["trace_id"].append(rec.trace_id)
         cols["kind"].append(rec.kind)
@@ -216,8 +216,6 @@ def _slow_queries(qe, ctx):
         from greptimedb_tpu.utils import ledger as _ledger
 
         cols["ledger"].append(_ledger.format_dict(rec.ledger))
-        cols["achieved_gbps"].append(rec.achieved_gbps)
-        cols["roofline_fraction"].append(rec.roofline_fraction)
     return cols
 
 

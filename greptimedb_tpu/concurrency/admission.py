@@ -35,7 +35,7 @@ from collections import deque
 from contextlib import contextmanager
 
 from greptimedb_tpu.fault.retry import Unavailable
-from greptimedb_tpu.utils import ledger
+from greptimedb_tpu.utils import tracing
 from greptimedb_tpu.utils.metrics import (
     ADMISSION_EVENTS,
     ADMISSION_QUEUE_DEPTH,
@@ -174,13 +174,15 @@ class AdmissionController:
 
         try:
             # deadline/cancel-aware wait: a killed or expired query
-            # leaves the queue typed instead of burning queue_timeout_s
-            granted = dl.wait_event(w.event, self.queue_timeout_s,
-                                    where="admission queue")
+            # leaves the queue typed instead of burning queue_timeout_s.
+            # The stage span feeds query_stage_seconds and the ledger's
+            # admission_wait_ms
+            with tracing.stage("admission_wait"):
+                granted = dl.wait_event(w.event, self.queue_timeout_s,
+                                        where="admission queue")
         except Unavailable:
             waited = time.perf_counter() - t0
             ADMISSION_WAIT_SECONDS.observe(waited)
-            ledger.add("admission_wait_ms", waited * 1000.0)
             with self._lock:
                 granted_in_race = w.granted
                 if not granted_in_race:
@@ -200,7 +202,6 @@ class AdmissionController:
             raise
         waited = time.perf_counter() - t0
         ADMISSION_WAIT_SECONDS.observe(waited)
-        ledger.add("admission_wait_ms", waited * 1000.0)
         if granted:
             return
         with self._lock:

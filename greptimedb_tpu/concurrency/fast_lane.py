@@ -67,19 +67,16 @@ import hashlib
 import pickle
 import re
 import threading
-import time
 from collections import OrderedDict
 from typing import Optional
 
 from greptimedb_tpu.concurrency.plan_cache import _info_matches, normalize
 from greptimedb_tpu.fault.retry import Cancelled, DeadlineExceeded
 from greptimedb_tpu.sql import ast
-from greptimedb_tpu.utils import ledger, roofline
+from greptimedb_tpu.utils import ledger
 from greptimedb_tpu.utils.metrics import (
     FAST_LANE_EVENTS,
-    QUERY_ACHIEVED_GBPS,
     SHM_FABRIC_EVENTS,
-    STAGE_SECONDS,
     STMT_DURATION,
 )
 
@@ -755,11 +752,16 @@ class FastLane:
         if not leader:
             from greptimedb_tpu.utils import deadline as dl
 
+            from greptimedb_tpu.utils import tracing
+
             # a cancelled/expired follower unwinds typed; the leader
-            # (and everyone else in the flight) keeps executing
-            if dl.wait_event(flight.event, 30.0,
-                             where="fast-lane single-flight") \
-                    and flight.done:
+            # (and everyone else in the flight) keeps executing. The
+            # wait is queueing behind another request's execution:
+            # stage admission_wait, marked single_flight
+            with tracing.stage("admission_wait", kind="single_flight"):
+                joined = dl.wait_event(flight.event, 30.0,
+                                       where="fast-lane single-flight")
+            if joined and flight.done:
                 FAST_LANE_EVENTS.inc(event="coalesced")
                 if flight.error is not None:
                     raise flight.error
@@ -782,34 +784,17 @@ class FastLane:
     def _bind_execute(self, qe, entry, params):
         from greptimedb_tpu.utils import deadline as dl
 
+        from greptimedb_tpu.utils import tracing
+
         dl.check("fast-lane bind")
-        t0 = time.perf_counter()
-        try:
-            plan = qe.concurrency.plan_cache._bind(entry.plan_entry,
-                                                   params)
-        except Exception as e:
-            raise _BindFailed(str(e)) from e
-        STAGE_SECONDS.observe(time.perf_counter() - t0, stage="fast_bind")
-        t1 = time.perf_counter()
-        try:
-            # the parse-free lane bypasses execute_statement, so the
-            # roofline accountant folds here too — one observation per
-            # materialization (coalesced followers share the leader's)
-            with ledger.attach() as led:
-                led0 = led.snapshot() if led is not None else {}
-                try:
-                    result = qe.executor.execute(plan)
-                finally:
-                    if led is not None:
-                        d = ledger.diff(led0, led.snapshot())
-                        rf = roofline.account(
-                            d, duration_ms=(time.perf_counter() - t1) * 1e3)
-                        if rf is not None:
-                            QUERY_ACHIEVED_GBPS.observe(
-                                rf["achieved_gbps"], stmt="Select")
-        finally:
-            STAGE_SECONDS.observe(time.perf_counter() - t1,
-                                  stage="fast_execute")
+        with tracing.stage("fast_bind"):
+            try:
+                plan = qe.concurrency.plan_cache._bind(entry.plan_entry,
+                                                       params)
+            except Exception as e:
+                raise _BindFailed(str(e)) from e
+        with tracing.enclosing_stage("fast_execute"):
+            result = qe.executor.execute(plan)
         # batch-group style memo: coalesced followers and the encoder
         # share one row materialization / schema header
         result.encode_memo = {}

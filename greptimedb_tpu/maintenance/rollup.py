@@ -43,6 +43,7 @@ from typing import Optional
 import numpy as np
 
 from greptimedb_tpu.maintenance.retention import ms_to_units
+from greptimedb_tpu.utils import tracing
 
 #: bit added to a raw region id to name its rollup companion; the rule
 #: SLOT rides in bits 20.. so several resolutions coexist. Raw region
@@ -886,7 +887,8 @@ def try_substitute(qe, sel, info, ctx, shape_note=None):
             region_ids=rollup_rids)
         try:
             plan = plan_select(new_sel, rollup_info)
-            res = qe.executor.execute(plan)
+            with tracing.enclosing_stage("execute"):
+                res = qe.executor.execute(plan)
         except Exception:  # noqa: BLE001 — odd rewrite / schema drift:
             value_dependent()  # any doubt: raw now, but re-probe
             continue           # the raw path is always correct
@@ -981,7 +983,8 @@ def _try_substitute_distributed(qe, sel, info, ctx, shape_note=None):
             region_ids=[e["rollup_rid"] for e in common[res_ms]])
         try:
             plan = plan_select(new_sel, rollup_info)
-            res = qe.executor.execute(plan)
+            with tracing.enclosing_stage("execute"):
+                res = qe.executor.execute(plan)
         except Exception:  # noqa: BLE001 — drift/rewrite doubt: raw wins
             continue
         from greptimedb_tpu.utils.metrics import (

@@ -20,12 +20,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from greptimedb_tpu.utils.device_telemetry import kernel_name
 
 OP_PUT = 0
 OP_DELETE = 1
 
 
 @functools.partial(jax.jit, static_argnames=("assume_unique_ts", "keep_tombstones"))
+@kernel_name("sort_dedup")
 def sort_dedup(
     series_ids: jax.Array,  # [N] int32 dense series/primary-key ids
     ts: jax.Array,  # [N] int64

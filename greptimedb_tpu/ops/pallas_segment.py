@@ -38,6 +38,7 @@ import logging
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from greptimedb_tpu.utils.device_telemetry import kernel_name
 
 _log = logging.getLogger("greptimedb_tpu.pallas")
 
@@ -123,6 +124,7 @@ def _kernel(ids_ref, plane_ref, out_ref):
 
 @functools.partial(jax.jit,
                    static_argnames=("num_segments", "block_rows"))
+@kernel_name("pallas_dense_segment_sum")
 def pallas_dense_segment_sum(
     plane: jax.Array,  # [N, W] float values (zeros on invalid rows)
     ids: jax.Array,  # [N] int32 segment ids (dead rows -> num_segments-1)
@@ -151,6 +153,7 @@ def pallas_dense_segment_sum(
             out_specs=pl.BlockSpec((gp, wp), lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((gp, wp), plane.dtype),
             interpret=interpret_mode(),
+            name="pallas_dense_segment_sum",
         )(ids_p, plane_p)
     return out[:num_segments, :w]
 
@@ -267,6 +270,7 @@ def _fused_kernel(ids_ref, vals_ref, *out_refs, nf, fw, gt, want_min,
 @functools.partial(jax.jit,
                    static_argnames=("num_segments", "want_min", "want_max",
                                     "want_sumsq", "block_rows"))
+@kernel_name("pallas_fused_segment_agg")
 def pallas_fused_segment_agg(
     vals: jax.Array,  # [N, F] raw field values (NaN = NULL)
     ids: jax.Array,  # [N] int32 group ids (masked rows -> num_segments-1)
@@ -317,6 +321,7 @@ def pallas_fused_segment_agg(
             out_specs=out_specs,
             out_shape=out_shapes,
             interpret=interpret_mode(),
+            name="pallas_fused_segment_agg",
         )(ids_p, vals_p)
     total = outs[0]
     g = num_segments

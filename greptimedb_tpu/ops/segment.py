@@ -19,6 +19,7 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from greptimedb_tpu.utils.device_telemetry import kernel_name
 
 # Aggregate ops supported by the kernel. "first"/"last" are by time order
 # within the segment (used by lastpoint / PromQL instant selection);
@@ -102,6 +103,7 @@ def dense_segment_sum(plane: jax.Array, ids: jax.Array,
     jax.jit,
     static_argnames=("num_segments", "ops", "indices_are_sorted"),
 )
+@kernel_name("segment_agg")
 def segment_agg(
     values: jax.Array,  # [N] or [N, F] field values (float)
     seg_ids: jax.Array,  # [N] int32 dense group ids

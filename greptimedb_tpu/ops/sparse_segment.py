@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from greptimedb_tpu.ops.segment import segment_agg
+from greptimedb_tpu.utils.device_telemetry import kernel_name
 
 #: sorts after every real combined group id (key products are guarded
 #: upstream to stay below it)
@@ -102,6 +103,7 @@ class SparseGroupSpec:
         return (gids // strides[key_idx]) % self.sizes[key_idx]
 
 
+@kernel_name("sort_compact")
 def sort_compact(gid: jax.Array, mask: jax.Array, cap: int):
     """Sort-compact observed group ids to dense ranks.
 
@@ -130,6 +132,7 @@ def sort_compact(gid: jax.Array, mask: jax.Array, cap: int):
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "ops"))
+@kernel_name("sparse_segment_agg")
 def sparse_segment_agg(
     values: jax.Array,  # [N] or [N, F] value planes
     gid: jax.Array,  # [N] int64 combined group ids
@@ -150,6 +153,7 @@ def sparse_segment_agg(
     return part, uniq, n_groups
 
 
+@kernel_name("fused_sparse_segment_agg")
 def fused_sparse_segment_agg(
     vals: jax.Array,  # [N, F] SORTED raw field values (NaN = NULL)
     ids: jax.Array,  # [N] int32 compact ids from sort_compact (dead -> cap)

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from greptimedb_tpu.ops.segment import segment_agg
+from greptimedb_tpu.utils.device_telemetry import kernel_name
 
 BIG = jnp.iinfo(jnp.int32).max
 
@@ -37,6 +38,7 @@ BIG = jnp.iinfo(jnp.int32).max
     static_argnames=("num_series", "num_steps", "w", "stats",
                      "sorted_input"),
 )
+@kernel_name("window_stats")
 def window_stats(
     sidx: jax.Array,  # [N] int32 series index
     ts: jax.Array,  # [N] float64 sample time (seconds)
@@ -218,6 +220,7 @@ def _ts_to_float(t_int):
 
 
 @jax.jit
+@kernel_name("counter_adjust")
 def counter_adjust(sidx_sorted: jax.Array, values_sorted: jax.Array) -> jax.Array:
     """Reset-corrected counter values. Input MUST be sorted by (series, ts).
     adjusted[i] = v[i] + cumulative resets before i; within-series
@@ -233,6 +236,7 @@ def counter_adjust(sidx_sorted: jax.Array, values_sorted: jax.Array) -> jax.Arra
 
 
 @functools.partial(jax.jit, static_argnames=("is_counter", "is_rate"))
+@kernel_name("extrapolated_delta")
 def extrapolated_delta(
     first_val, first_ts, last_val, last_ts, count, window_start, window_end,
     is_counter: bool, is_rate: bool, range_s: float = 1.0,
@@ -267,6 +271,7 @@ def extrapolated_delta(
 
 @functools.partial(jax.jit,
                    static_argnames=("num_series", "num_steps", "w"))
+@kernel_name("window_edges")
 def window_edges(
     sidx: jax.Array,  # [N] int32 series index, sorted major
     ts: jax.Array,  # [N] float64 sample time (seconds), sorted within
@@ -330,6 +335,7 @@ def window_edges(
 
 
 @functools.partial(jax.jit, static_argnames=("num_steps", "w"))
+@kernel_name("window_edges_grid")
 def window_edges_grid(
     grid: jax.Array,  # [P] float64 shared sample grid (seconds, sorted)
     mat: jax.Array,  # [S, P, C] values pivoted onto the grid (NaN-free)
@@ -368,6 +374,7 @@ def window_edges_grid(
 
 
 @functools.partial(jax.jit, static_argnames=("num_steps", "w"))
+@kernel_name("window_sums_grid")
 def window_sums_grid(
     grid: jax.Array,  # [P] float64 shared sample grid (seconds, sorted)
     cs: jax.Array,  # [S, P+1, C] exclusive prefix sums over the pivot
@@ -393,6 +400,7 @@ def window_sums_grid(
     return {"sum": out_sum, "count": count_st}
 
 
+@kernel_name("exclusive_cumsum")
 def exclusive_cumsum(mat: jax.Array) -> jax.Array:
     """[S, P, C] -> [S, P+1, C] exclusive prefix sums along axis 1 (the
     shared idiom of window_stats' window sums and window_sums_grid)."""

@@ -42,6 +42,8 @@ from greptimedb_tpu.promql.parser import (
     parse_promql,
 )
 from greptimedb_tpu.query.result import QueryResult
+from greptimedb_tpu.utils import device_telemetry, tracing
+from greptimedb_tpu.utils.metrics import PROMQL_LOAD_CACHE_EVENTS
 
 
 _CALENDAR = frozenset({
@@ -99,6 +101,26 @@ def _fmt_prom_value(v: float) -> str:
     if v == int(v) and abs(v) < 1e15:
         return str(int(v))
     return np.format_float_positional(v, trim="-")
+
+
+def h2d(x, dtype=None) -> jax.Array:
+    """Host -> device copy of a numpy array or python value, counted in
+    device_transfer_bytes_total{h2d} (a jax array passes through)."""
+    if isinstance(x, jax.Array):
+        return x
+    arr = jnp.asarray(x, dtype=dtype)
+    device_telemetry.count_h2d(arr.nbytes)
+    return arr
+
+
+def d2h(x, dtype=None) -> np.ndarray:
+    """Device -> host readback, counted in
+    device_transfer_bytes_total{d2h}; host values pass through
+    uncounted. Blocks until the device has produced `x`."""
+    arr = np.asarray(x, dtype=dtype)
+    if isinstance(x, jax.Array):
+        device_telemetry.count_d2h(arr.nbytes)
+    return arr
 
 
 @dataclass
@@ -167,14 +189,22 @@ class PromqlEngine:
         # TQL statement arrives under execute_sql's watch, where this
         # one is a no-op (the re-entrancy guard)
         with slow_query.watch("promql", query,
-                              getattr(ctx, "db", None) or "public") as w:
-            node = parse_promql(query)
+                              getattr(ctx, "db", None) or "public"):
+            with tracing.stage("parse"):
+                node = parse_promql(query)
             n_steps = int(math.floor((end - start) / step)) + 1
             times = start + np.arange(n_steps) * step
             params = EvalParams(start, end, step, times)
-            result = self._eval(node, params, ctx)
+            # the evaluation dispatches eager device operations and
+            # jitted window kernels; the stages that _load and the
+            # aggregations open (scan, upload, readback, assemble) cut
+            # themselves out of this `device` stage
+            with tracing.stage("device"):
+                result = self._eval(node, params, ctx)
             if isinstance(result, SeriesMatrix):
-                w.rows = len(result.labels)
+                # on whichever watch is open: this one, or the HTTP
+                # handler's, which also covers readback and encoding
+                slow_query.annotate(rows=len(result.labels))
         return times, result
 
     def eval_instant(self, query: str, t: float, ctx=None):
@@ -246,7 +276,7 @@ class PromqlEngine:
         vals = st["last"][:, :, 0]
         lts = st["last_ts"]
         # exact lookback: bucket window may overcover; validate sample ts
-        ok = lts > (jnp.asarray(p.times)[None, :] - lookback)
+        ok = lts > (h2d(p.times)[None, :] - lookback)
         vals = jnp.where(ok, vals, jnp.nan)
         return SeriesMatrix(labels, vals, metric,
                             sample_ts=jnp.where(ok, lts, jnp.nan))
@@ -328,11 +358,11 @@ class PromqlEngine:
         S = n_series
         if S > 0 and n % S == 0:
             P = n // S
-            ts_np = np.asarray(ts)
+            ts_np = d2h(ts)
             grid = ts_np[:P]
             if (ts_np.reshape(S, P) == grid[None, :]).all() \
-                    and not bool(jnp.isnan(chans).any()):
-                result = (jnp.asarray(grid), chans.reshape(S, P,
+                    and not bool(d2h(jnp.isnan(chans).any())):
+                result = (h2d(grid), chans.reshape(S, P,
                                                            chans.shape[1]))
         if cache is not None:
             cache.append((sidx, chans, result))
@@ -391,7 +421,7 @@ class PromqlEngine:
             raise PromqlError("subquery needs an instant-vector expression")
         if v.num_series == 0:
             return None
-        vals = np.asarray(v.values)
+        vals = d2h(v.values)
         S, T2 = vals.shape
         sidx = np.repeat(np.arange(S, dtype=np.int32), T2)
         ts = np.tile(times + sq.offset_s, S)  # back on the outer timeline
@@ -399,9 +429,9 @@ class PromqlEngine:
         keep = ~np.isnan(flat)  # absent inner samples aren't samples
         if not keep.any():
             return None
-        d_sidx = jnp.asarray(sidx[keep])
-        d_ts = jnp.asarray(ts[keep])
-        d_vals = jnp.asarray(flat[keep])
+        d_sidx = h2d(sidx[keep])
+        d_ts = h2d(ts[keep])
+        d_vals = h2d(flat[keep])
         channels = self._make_channels(d_sidx, d_ts, d_vals,
                                        extra_channels, p)
         return d_sidx, d_ts, channels, v.labels, v.metric
@@ -497,92 +527,107 @@ class PromqlEngine:
                 idx_preds.setdefault(m.label, []).append(InSet.of([m.value]))
             elif m.op == "=~":
                 idx_preds.setdefault(m.label, []).append(Regex(m.value))
-        from greptimedb_tpu.utils import tracing
-
-        with tracing.span("promql_scan", metric=metric,
-                          field=field_name):
-            scan = qe.region_engine.scan(
-                info.region_ids[0], (lo, hi), [field_name],
-                tag_predicates={k: tuple(v)
-                                for k, v in idx_preds.items()} or None)
-        if scan is None or scan.num_rows == 0:
-            return None
-
-        # loaded-series cache: everything below (matcher masks, series
-        # factorization + label decode, the 9.6M-row device lexsort,
-        # channel building) is query-invariant for a given scan snapshot
-        # + selector — the PromQL analog of the prepared planes. Keyed on
-        # the scan identity, so data_version changes invalidate; "deriv"
-        # channels embed p.start and key on it.
-        ex = getattr(self.qe, "executor", None)
-        lcache = None
-        ckey = None
-        if ex is not None and scan.region_id >= 0:
-            lcache = getattr(ex, "_promql_load_cache", None)
-            if lcache is None:
-                from collections import OrderedDict
-
-                lcache = ex._promql_load_cache = OrderedDict()
-            ckey = (scan.region_id, scan.data_version,
-                    scan.scan_fingerprint, field_name, offset,
-                    tuple(sorted((m.label, m.op, m.value) for m in rest)),
-                    tuple(extra_channels), not info.append_mode,
-                    p.start if "deriv" in extra_channels else None)
-            hit = lcache.get(ckey)
-            if hit is not None:
-                lcache.move_to_end(ckey)
-                d_sidx, d_ts, channels, labels = hit
-                return d_sidx, d_ts, channels, labels, metric
-
-        tag_names = [c.name for c in schema.tag_columns]
-        mask = np.ones(scan.num_rows, dtype=bool)
-        for m in rest:
-            mask &= _matcher_mask(m, scan, tag_names)
-            if not mask.any():
+        # stage `scan`: the region scan (SST read, decode, merge) and,
+        # on a loaded-series cache miss, everything the host derives
+        # from it — matcher masks, series factorization, label decode
+        with tracing.stage("scan", metric=metric, field=field_name) as sa:
+            with tracing.span("promql_scan", metric=metric,
+                              field=field_name):
+                scan = qe.region_engine.scan(
+                    info.region_ids[0], (lo, hi), [field_name],
+                    tag_predicates={k: tuple(v)
+                                    for k, v in idx_preds.items()} or None)
+            if scan is None or scan.num_rows == 0:
                 return None
-        # dedup for non-append tables rides the same sort below
-        rows = np.flatnonzero(mask)
-        codes = [scan.columns[t][rows] for t in tag_names]
-        ts_raw = scan.columns[ts_col.name][rows]
-        vals = np.asarray(scan.columns[field_name][rows], dtype=np.float64)
+            sa["rows"] = scan.num_rows
 
-        if tag_names:
-            sizes = [len(scan.tag_dicts[t]) + 1 for t in tag_names]
-            combined = codes[0].astype(np.int64) + 1
-            for c, s in zip(codes[1:], sizes[1:]):
-                combined = combined * s + (c.astype(np.int64) + 1)
-            uniq, sidx = np.unique(combined, return_inverse=True)
-            # decode labels per unique series
-            labels = []
-            strides = [1] * len(sizes)
-            for i in range(len(sizes) - 2, -1, -1):
-                strides[i] = strides[i + 1] * sizes[i + 1]
-            for u in uniq:
-                lab = {}
-                for t_name, stride, size in zip(tag_names, strides, sizes):
-                    code = int(u // stride % size) - 1
-                    if code >= 0:
-                        lab[t_name] = str(scan.tag_dicts[t_name][code])
-                labels.append(lab)
-        else:
-            sidx = np.zeros(len(rows), dtype=np.int64)
-            labels = [{}]
+            # loaded-series cache: everything below (matcher masks,
+            # series factorization + label decode, the 9.6M-row device
+            # lexsort, channel building) is query-invariant for a given
+            # scan snapshot + selector — the PromQL analog of the
+            # prepared planes. Keyed on the scan identity, so
+            # data_version changes invalidate; "deriv" channels embed
+            # p.start and key on it.
+            ex = getattr(self.qe, "executor", None)
+            lcache = None
+            ckey = None
+            if ex is not None and scan.region_id >= 0:
+                lcache = getattr(ex, "_promql_load_cache", None)
+                if lcache is None:
+                    from collections import OrderedDict
 
-        ts_sec = ts_raw.astype(np.float64) * (unit / 1e9) + offset
-        # sort by (series, ts): required by counter_adjust / indicator
-        # channels, and makes segment ids sorted for the kernel. The
-        # storage scan already yields (tags..., ts)-sorted rows for a
-        # single flushed SST and series codes factorize in tag order —
-        # prove sortedness on host and skip the device lexsort chain
-        # (round-5: forcing that chain was 5.5 s of a 22 s first eval
-        # at 28.8M rows)
-        d_sidx = jnp.asarray(sidx.astype(np.int32))
-        d_ts = jnp.asarray(ts_sec)
-        d_vals = jnp.asarray(vals)
+                    lcache = ex._promql_load_cache = OrderedDict()
+                ckey = (scan.region_id, scan.data_version,
+                        scan.scan_fingerprint, field_name, offset,
+                        tuple(sorted((m.label, m.op, m.value)
+                                     for m in rest)),
+                        tuple(extra_channels), not info.append_mode,
+                        p.start if "deriv" in extra_channels else None)
+                hit = lcache.get(ckey)
+                PROMQL_LOAD_CACHE_EVENTS.inc(
+                    event="miss" if hit is None else "hit")
+                if hit is not None:
+                    lcache.move_to_end(ckey)
+                    d_sidx, d_ts, channels, labels = hit
+                    return d_sidx, d_ts, channels, labels, metric
+
+            tag_names = [c.name for c in schema.tag_columns]
+            mask = np.ones(scan.num_rows, dtype=bool)
+            for m in rest:
+                mask &= _matcher_mask(m, scan, tag_names)
+                if not mask.any():
+                    return None
+            # dedup for non-append tables rides the same sort below
+            rows = np.flatnonzero(mask)
+            codes = [scan.columns[t][rows] for t in tag_names]
+            ts_raw = scan.columns[ts_col.name][rows]
+            vals = np.asarray(scan.columns[field_name][rows],
+                              dtype=np.float64)
+
+            if tag_names:
+                sizes = [len(scan.tag_dicts[t]) + 1 for t in tag_names]
+                combined = codes[0].astype(np.int64) + 1
+                for c, sz in zip(codes[1:], sizes[1:]):
+                    combined = combined * sz + (c.astype(np.int64) + 1)
+                uniq, sidx = np.unique(combined, return_inverse=True)
+                # decode labels per unique series
+                labels = []
+                strides = [1] * len(sizes)
+                for i in range(len(sizes) - 2, -1, -1):
+                    strides[i] = strides[i + 1] * sizes[i + 1]
+                for u in uniq:
+                    lab = {}
+                    for t_name, stride, size in zip(tag_names, strides,
+                                                    sizes):
+                        code = int(u // stride % size) - 1
+                        if code >= 0:
+                            lab[t_name] = str(scan.tag_dicts[t_name][code])
+                    labels.append(lab)
+            else:
+                sidx = np.zeros(len(rows), dtype=np.int64)
+                labels = [{}]
+
+            ts_sec = ts_raw.astype(np.float64) * (unit / 1e9) + offset
+            # sort by (series, ts): required by counter_adjust /
+            # indicator channels, and makes segment ids sorted for the
+            # kernel. The storage scan already yields (tags..., ts)-
+            # sorted rows for a single flushed SST and series codes
+            # factorize in tag order — prove sortedness on host and skip
+            # the device lexsort chain (round-5: forcing that chain was
+            # 5.5 s of a 22 s first eval at 28.8M rows)
+            host_sorted = False
+            if info.append_mode:
+                ds = np.diff(sidx)
+                host_sorted = bool(np.all(
+                    (ds > 0) | ((ds == 0) & (np.diff(ts_sec) >= 0))))
+        with tracing.stage("upload"):
+            d_sidx = h2d(sidx.astype(np.int32))
+            d_ts = h2d(ts_sec)
+            d_vals = h2d(vals)
+            if not info.append_mode:
+                d_seq = h2d(scan.seq[rows].astype(np.int64))
+                d_op = h2d(scan.op_type[rows].astype(np.int8))
         if info.append_mode:
-            ds = np.diff(sidx)
-            host_sorted = bool(np.all(
-                (ds > 0) | ((ds == 0) & (np.diff(ts_sec) >= 0))))
             if not host_sorted:
                 order = jnp.lexsort((d_ts, d_sidx))
                 d_sidx, d_ts, d_vals = (d_sidx[order], d_ts[order],
@@ -597,8 +642,6 @@ class PromqlEngine:
             # sort_dedup enforces for SQL scans).
             from greptimedb_tpu.storage.region import OP_PUT
 
-            d_seq = jnp.asarray(scan.seq[rows].astype(np.int64))
-            d_op = jnp.asarray(scan.op_type[rows].astype(np.int8))
             order = jnp.lexsort((d_seq, d_ts, d_sidx))
             d_sidx, d_ts, d_vals, d_op = (d_sidx[order], d_ts[order],
                                           d_vals[order], d_op[order])
@@ -644,20 +687,20 @@ class PromqlEngine:
                 return v
             return self._eval_range_func(call, p, ctx)
         if fn == "time":
-            return jnp.asarray(p.times)
+            return h2d(p.times)
         if fn in _CALENDAR:
             # Prometheus calendar functions: input VALUES are unix
             # seconds (default vector(time())); output the UTC field
             if call.args:
                 v = self._eval(call.args[0], p, ctx)
             else:
-                v = SeriesMatrix([{}], jnp.asarray(p.times)[None, :])
+                v = SeriesMatrix([{}], h2d(p.times)[None, :])
             if not isinstance(v, SeriesMatrix):
                 v = SeriesMatrix([{}], _broadcast_scalar(v, p)[None, :])
-            vals = np.asarray(v.values, dtype=np.float64)
+            vals = d2h(v.values, dtype=np.float64)
             out = _calendar_field(fn, vals)
             # functions drop __name__ (same as the _map_values path)
-            return SeriesMatrix(v.labels, jnp.asarray(out))
+            return SeriesMatrix(v.labels, h2d(out))
         if fn == "scalar":
             v = self._eval(call.args[0], p, ctx)
             if isinstance(v, SeriesMatrix):
@@ -697,12 +740,12 @@ class PromqlEngine:
                 return v
             # order series by their value at the (last) evaluated instant,
             # NaN last — matches Prometheus sort() on instant vectors
-            key = np.asarray(v.values[:, -1]).astype(np.float64)
+            key = d2h(v.values[:, -1]).astype(np.float64)
             rank = np.where(np.isnan(key), np.inf,
                             key if fn == "sort" else -key)
             order = np.argsort(rank, kind="stable")
             return SeriesMatrix([v.labels[i] for i in order],
-                                v.values[np.asarray(order)], v.metric)
+                                v.values[h2d(order)], v.metric)
         if fn == "absent":
             v = self._eval(call.args[0], p, ctx)
             if not isinstance(v, SeriesMatrix):
@@ -736,7 +779,7 @@ class PromqlEngine:
                 return SeriesMatrix([], jnp.zeros((0, p.T)))
             st, labels, metric, w, range_s = r
             ch = 1 if counter else 0
-            times = jnp.asarray(p.times)
+            times = h2d(p.times)
             vals = extrapolated_delta(
                 st["first"][:, :, ch], st["first_ts"],
                 st["last"][:, :, ch], st["last_ts"],
@@ -758,7 +801,7 @@ class PromqlEngine:
             prev_v = st["last"][:, :, 1]
             prev_t = st["last"][:, :, 2]
             last_t = st["last_ts"]
-            wstart = jnp.asarray(p.times)[None, :] - range_s
+            wstart = h2d(p.times)[None, :] - range_s
             ok = (~jnp.isnan(prev_v)) & (prev_t > wstart) & (last_t > prev_t)
             if fn == "idelta":
                 out = last_v - prev_v
@@ -802,7 +845,7 @@ class PromqlEngine:
                 return SeriesMatrix(labels, slope)
             horizon = _scalar_of(self._eval(call.args[1], p, ctx))
             intercept = (sv - slope * t1) / jnp.maximum(n, 1)
-            now_r = jnp.asarray(p.times)[None, :] - p.start
+            now_r = h2d(p.times)[None, :] - p.start
             return SeriesMatrix(labels, intercept + slope * (now_r + horizon))
 
         # *_over_time family
@@ -892,7 +935,7 @@ class PromqlEngine:
             reached = counts >= rank[None, :]
             b = jnp.argmax(reached, axis=0)
             B = len(les)
-            d_les = jnp.asarray(les)
+            d_les = h2d(les)
             upper = d_les[b]
             lower = jnp.where(b > 0, d_les[jnp.maximum(b - 1, 0)], 0.0)
             cum_prev = jnp.where(b > 0,
@@ -936,9 +979,9 @@ class PromqlEngine:
         if loaded is None:
             return SeriesMatrix([], jnp.zeros((0, p.T)))
         sidx, ts, chans, labels, metric = loaded
-        sidx = np.asarray(sidx)
-        ts = np.asarray(ts)
-        vals = np.asarray(chans[:, 0])
+        sidx = d2h(sidx)
+        ts = d2h(ts)
+        vals = d2h(chans[:, 0])
         ok = ~np.isnan(vals)
         sidx, ts, vals = sidx[ok], ts[ok], vals[ok]
         S, T = len(labels), p.T
@@ -960,7 +1003,7 @@ class PromqlEngine:
                     b = tf * (s1 - s0) + (1 - tf) * b
                     s0 = s1
                 out[s, j] = s0
-        return SeriesMatrix(labels, jnp.asarray(out))
+        return SeriesMatrix(labels, h2d(out))
 
     def _range_stats_sq(self, sel, p, ctx):
         """Range stats with a squared-value channel (stddev/stdvar)."""
@@ -986,24 +1029,27 @@ class PromqlEngine:
         if v.num_series == 0:
             return SeriesMatrix([], jnp.zeros((0, p.T)))
 
-        # group signatures
-        sigs = []
-        out_labels = []
-        for lab in v.labels:
-            if agg.by:
-                kept = {k: lab.get(k, "") for k in agg.by if k in lab}
-            elif agg.without:
-                kept = {k: x for k, x in lab.items() if k not in agg.without}
-            elif agg.grouping:
-                kept = {}
-            else:
-                kept = {}
-            sigs.append(tuple(sorted(kept.items())))
-            out_labels.append(kept)
-        uniq = sorted(set(sigs))
-        gidx = np.asarray([uniq.index(s) for s in sigs], dtype=np.int32)
-        G = len(uniq)
-        glabels = [dict(u) for u in uniq]
+        # group signatures: label sets of the output, built on the host
+        with tracing.stage("assemble", step="group_labels"):
+            sigs = []
+            out_labels = []
+            for lab in v.labels:
+                if agg.by:
+                    kept = {k: lab.get(k, "") for k in agg.by if k in lab}
+                elif agg.without:
+                    kept = {k: x for k, x in lab.items()
+                            if k not in agg.without}
+                elif agg.grouping:
+                    kept = {}
+                else:
+                    kept = {}
+                sigs.append(tuple(sorted(kept.items())))
+                out_labels.append(kept)
+            uniq = sorted(set(sigs))
+            gidx = np.asarray([uniq.index(s) for s in sigs],
+                              dtype=np.int32)
+            G = len(uniq)
+            glabels = [dict(u) for u in uniq]
 
         vals = v.values  # [S, T]
         if agg.op in ("sum", "avg", "min", "max", "count", "group",
@@ -1016,7 +1062,7 @@ class PromqlEngine:
                 "stdvar": ("sum", "sumsq", "count"),
             }[agg.op]
             need = set(ops) | {"count"}
-            st = segment_agg(vals, jnp.asarray(gidx),
+            st = segment_agg(vals, h2d(gidx),
                              jnp.ones(v.num_series, bool), G,
                              ops=tuple(sorted(need)))
             cnt = st["count"]
@@ -1064,7 +1110,7 @@ class PromqlEngine:
                 raise PromqlError(
                     "count_values needs a string label parameter")
             label_name = agg.param.value
-            vn = np.asarray(vals, dtype=np.float64)  # [S, T]
+            vn = d2h(vals, dtype=np.float64)  # [S, T]
             S, T = vn.shape
             valid = ~np.isnan(vn)
             # sparse factorization: memory stays O(samples + series*T),
@@ -1087,7 +1133,7 @@ class PromqlEngine:
                 lab = dict(glabels[int(pair // D)])
                 lab[label_name] = _fmt_prom_value(float(distinct[pair % D]))
                 out_labels2.append(lab)
-            return SeriesMatrix(out_labels2, jnp.asarray(rows_m))
+            return SeriesMatrix(out_labels2, h2d(rows_m))
 
         raise PromqlError(f"unsupported aggregation {agg.op!r}")
 
@@ -1138,8 +1184,8 @@ class PromqlEngine:
                               else lhs.labels[i])
         if not li:
             return SeriesMatrix([], jnp.zeros((0, p.T)))
-        a = lhs.values[np.asarray(li)]
-        b = rhs.values[np.asarray(ri)]
+        a = lhs.values[h2d(np.asarray(li))]
+        b = rhs.values[h2d(np.asarray(ri))]
         out = _apply_op(node.op, a, b)
         if node.op in _CMP:
             out = out.astype(jnp.float64) if node.bool_mod else jnp.where(out, a, jnp.nan)
@@ -1240,7 +1286,7 @@ def _set_op(node: Binary, lhs: SeriesMatrix, rhs: SeriesMatrix, p: EvalParams):
     extra = [i for i, l in enumerate(rhs.labels)
              if _signature(l, node) not in lsigs]
     labels = list(lhs.labels) + [rhs.labels[i] for i in extra]
-    vals = jnp.concatenate([lhs.values, rhs.values[np.asarray(extra, dtype=np.int64)]]
+    vals = jnp.concatenate([lhs.values, rhs.values[h2d(np.asarray(extra, dtype=np.int64))]]
                            ) if extra else lhs.values
     return SeriesMatrix(labels, vals, lhs.metric)
 
@@ -1263,7 +1309,7 @@ def _map_values(v, f):
     if isinstance(v, SeriesMatrix):
         return SeriesMatrix(v.labels, f(v.values))
     if isinstance(v, (int, float)):
-        return f(jnp.asarray(v)).item() if False else float(f(jnp.asarray(float(v))))
+        return f(h2d(v)).item() if False else float(f(h2d(float(v))))
     return f(v)
 
 
@@ -1272,13 +1318,13 @@ def _broadcast_scalar(v, p: EvalParams):
         raise PromqlError("expected a scalar")
     if isinstance(v, (int, float)):
         return jnp.full(p.T, float(v))
-    return jnp.asarray(v)
+    return h2d(v)
 
 
 def _scalar_of(v) -> float:
     if isinstance(v, (int, float)):
         return float(v)
-    arr = np.asarray(v)
+    arr = d2h(v)
     return float(arr.reshape(-1)[0])
 
 
@@ -1356,12 +1402,19 @@ def _to_long_result(times: np.ndarray, result) -> QueryResult:
     """Matrix -> long-format table (tags..., ts, value), NaN cells dropped
     (matches the reference's TQL tabular output)."""
     if not isinstance(result, SeriesMatrix):
-        arr = np.asarray(_broadcast_with(times, result))
+        with tracing.stage("readback"):
+            arr = d2h(_broadcast_with(times, result))
         ts_ms = (times * 1000).astype(np.int64)
         return QueryResult(["ts", "value"],
                            [DataType.TIMESTAMP_MILLISECOND, DataType.FLOAT64],
                            [ts_ms, arr])
-    vals = np.asarray(result.values)
+    with tracing.stage("readback"):
+        vals = d2h(result.values)
+    with tracing.stage("assemble"):
+        return _long_table(times, result, vals)
+
+
+def _long_table(times: np.ndarray, result, vals: np.ndarray) -> QueryResult:
     S, T = vals.shape if vals.size else (0, len(times))
     label_keys = sorted({k for lab in result.labels for k in lab})
     ts_ms = (times * 1000).astype(np.int64)
@@ -1394,4 +1447,4 @@ def _to_long_result(times: np.ndarray, result) -> QueryResult:
 def _broadcast_with(times, v):
     if isinstance(v, (int, float)):
         return np.full(len(times), float(v))
-    return np.asarray(v)
+    return d2h(v)

@@ -159,8 +159,7 @@ class QueryEngine:
         # expression evaluation resolves plugin scalar functions against
         # THIS engine's container for the duration of the statement
         token = set_active(self.plugins)
-        from greptimedb_tpu.utils import slow_query
-        from greptimedb_tpu.utils.metrics import STAGE_SECONDS
+        from greptimedb_tpu.utils import slow_query, tracing
 
         try:
             # slow-query watch: crosses the threshold -> structured
@@ -172,10 +171,8 @@ class QueryEngine:
                 # assign it — clear it so a non-aggregate slow statement
                 # doesn't inherit the previous query's path
                 self.executor.last_path = None
-                t_parse = _time.perf_counter()
-                stmts = self._parse_cached(sql)
-                STAGE_SECONDS.observe(_time.perf_counter() - t_parse,
-                                      stage="parse")
+                with tracing.stage("parse"):
+                    stmts = self._parse_cached(sql)
                 # bounded admission + per-tenant fair scheduling: wait
                 # time counts into the slow-query watch (queueing IS
                 # part of the latency the operator debugs); nested
@@ -277,14 +274,6 @@ class QueryEngine:
                             d = ledger.diff(led0, led.snapshot())
                             if d:
                                 sp["ledger"] = ledger.format_dict(d)
-                                from greptimedb_tpu.utils import roofline
-                                from greptimedb_tpu.utils.metrics import \
-                                    QUERY_ACHIEVED_GBPS
-                                rf = roofline.stamp(sp, d)
-                                if rf is not None:
-                                    QUERY_ACHIEVED_GBPS.observe(
-                                        rf["achieved_gbps"],
-                                        stmt=type(stmt).__name__)
         finally:
             reset_session_tz(tz_token)
 
@@ -912,62 +901,58 @@ class QueryEngine:
         # a cached validated plan instead of re-planning; the entry also
         # memoizes a negative rollup-substitution probe (version-stamped
         # — any rollup state change re-probes)
-        import time as _time
+        from greptimedb_tpu.utils import tracing
 
-        from greptimedb_tpu.utils.metrics import STAGE_SECONDS
+        # a POSITIVE rollup substitution runs the whole substituted
+        # query inside try_substitute: its stages cut themselves out of
+        # this `plan` stage, and its `execute` encloses them there
+        with tracing.stage("plan"):
+            plan, entry, binding = self.concurrency.plan_cache.lookup(
+                sel, info)
+            # non-aggregate statements never probe, so their memo is
+            # trivially safe; a probed shape may memoize the negative
+            # outcome only when it was STRUCTURAL (shape_note) —
+            # coverage / alignment failures depend on this query's
+            # literal values and must not disable substitution for
+            # sibling parameter bindings
+            sub_note = {"memoizable": True}
+            sub_stamp = None
+            if sel.group_by or any(has_aggregate(it.expr)
+                                   for it in sel.items):
+                # rollup substitution: eligible coarse-bucket aggregates
+                # are served from downsampled plane SSTs
+                # (maintenance/rollup.py); None = ineligible/uncovered,
+                # fall through to the raw scan
+                if entry is None or not entry.skip_substitution():
+                    from greptimedb_tpu.concurrency.plan_cache import (
+                        substitution_stamp,
+                    )
+                    from greptimedb_tpu.maintenance.rollup import (
+                        try_substitute,
+                    )
 
-        t_plan = _time.perf_counter()
-        plan, entry, binding = self.concurrency.plan_cache.lookup(sel, info)
-        # non-aggregate statements never probe, so their memo is
-        # trivially safe; a probed shape may memoize the negative
-        # outcome only when it was STRUCTURAL (shape_note) — coverage /
-        # alignment failures depend on this query's literal values and
-        # must not disable substitution for sibling parameter bindings
-        sub_note = {"memoizable": True}
-        sub_stamp = None
-        if sel.group_by or any(has_aggregate(it.expr) for it in sel.items):
-            # rollup substitution: eligible coarse-bucket aggregates are
-            # served from downsampled plane SSTs (maintenance/rollup.py);
-            # None = ineligible/uncovered, fall through to the raw scan
-            if entry is None or not entry.skip_substitution():
-                from greptimedb_tpu.concurrency.plan_cache import (
-                    substitution_stamp,
-                )
-                from greptimedb_tpu.maintenance.rollup import try_substitute
-
-                # pre-probe stamp: a roll finishing mid-probe must not
-                # lend its fresher version to this negative outcome
-                sub_stamp = substitution_stamp()
-                # the probe itself is planning work, but a POSITIVE
-                # substitution runs the whole substituted query inside
-                # try_substitute — attribute that to execute, not plan
-                t_sub = _time.perf_counter()
-                res = try_substitute(self, sel, info, ctx,
-                                     shape_note=sub_note)
-                if res is not None:
-                    STAGE_SECONDS.observe(t_sub - t_plan, stage="plan")
-                    STAGE_SECONDS.observe(_time.perf_counter() - t_sub,
-                                          stage="execute")
-                    return res
+                    # pre-probe stamp: a roll finishing mid-probe must
+                    # not lend its fresher version to this negative
+                    # outcome
+                    sub_stamp = substitution_stamp()
+                    res = try_substitute(self, sel, info, ctx,
+                                         shape_note=sub_note)
+                    if res is not None:
+                        return res
+                    if entry is not None and sub_note.get("memoizable"):
+                        entry.mark_sub_ineligible(sub_stamp)
+            if plan is None:
+                plan = plan_select(sel, info)
+                entry = self.concurrency.plan_cache.store(binding, sel,
+                                                          info, plan)
                 if entry is not None and sub_note.get("memoizable"):
                     entry.mark_sub_ineligible(sub_stamp)
-        if plan is None:
-            plan = plan_select(sel, info)
-            entry = self.concurrency.plan_cache.store(binding, sel, info,
-                                                      plan)
-            if entry is not None and sub_note.get("memoizable"):
-                entry.mark_sub_ineligible(sub_stamp)
-        STAGE_SECONDS.observe(_time.perf_counter() - t_plan, stage="plan")
         # stamp a fast-lane build ticket (if this thread armed one):
         # the statement is about to execute exactly this plan-cache
         # plan, which is what a text-template entry memoizes
         self.concurrency.fast_lane.note_plan_execution(sel, info, entry)
-        t_exec = _time.perf_counter()
-        try:
+        with tracing.enclosing_stage("execute"):
             return self.executor.execute(plan)
-        finally:
-            STAGE_SECONDS.observe(_time.perf_counter() - t_exec,
-                                  stage="execute")
 
     def _try_window_pushdown(self, sel: ast.Select, info, ctx):
         """Ship [filter, prune, window] PlanFragments when every window
@@ -1773,10 +1758,6 @@ class QueryEngine:
             summary = led.summary()
             if summary:
                 lines.append(f"  resource ledger: {summary}")
-                from greptimedb_tpu.utils import roofline
-                rf = roofline.account(ledger.derive(led.snapshot()))
-                if rf is not None:
-                    lines.append(f"  roofline: {roofline.format_line(rf)}")
         return lines
 
     # ---- admin -------------------------------------------------------------

@@ -55,7 +55,7 @@ from greptimedb_tpu.query.result import QueryResult
 from greptimedb_tpu.sql import ast
 from greptimedb_tpu.storage.engine import RegionEngine
 from greptimedb_tpu.storage.region import ScanData
-from greptimedb_tpu.utils import device_telemetry
+from greptimedb_tpu.utils import device_telemetry, tracing
 from greptimedb_tpu.utils import flame as _flame
 
 # XLA compile + device memory telemetry rides jax.monitoring: one
@@ -68,6 +68,28 @@ def _readback(x) -> np.ndarray:
     arr = np.asarray(x)
     device_telemetry.count_d2h(arr.nbytes)
     return arr
+
+
+def _staged(name: str, kernel_step: bool = False):
+    """Run the decorated method as one serving stage (`_kstage` naming
+    for a step of a kernel path)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def staged(*args, **kwargs):
+            with (_kstage if kernel_step else tracing.stage)(name):
+                return fn(*args, **kwargs)
+        return staged
+    return deco
+
+
+def _kstage(name: str, **attrs):
+    """The serving stage of one step of a kernel path: `upload`,
+    `device` (dispatch of jitted steps) or `readback` on the device and
+    mesh tiers. On the host tier the same steps run on the CPU backend
+    of this process and are the host's aggregation work: `host_agg`."""
+    if _ACTIVE_TIER_VAR.get() == "host":
+        name = "host_agg"
+    return tracing.stage(name, **attrs)
 
 # primitive kernel ops backing each SQL aggregate
 # boundary first/last gather only pays when it shrinks the scan: above
@@ -279,6 +301,7 @@ def _agg_block_masked(
                      "num_segments", "tag_names", "schema", "float_ops",
                      "pack_dtype"),
 )
+@device_telemetry.kernel_name("agg_scan_prepared")
 def _agg_scan_prepared(
     blocks: tuple,  # per-block col dicts incl. "__prep__"
     n_valids: jax.Array,
@@ -381,6 +404,7 @@ def _pack_float_ops(sums, cnts, rows, tmin, tmax, tsq, float_ops,
                      "int_ops", "pack_dtype", "acc_dtype", "want_min",
                      "want_max", "want_sumsq"),
 )
+@device_telemetry.kernel_name("agg_scan_fused")
 def _agg_scan_fused(
     blocks: tuple,  # per-block dicts of RAW column arrays (hot set)
     n_valids: jax.Array,
@@ -461,6 +485,7 @@ def _agg_scan_fused(
                      "ts_name", "tag_names", "schema", "need_ts", "acc_dtype",
                      "float_ops", "int_ops", "pack_dtype"),
 )
+@device_telemetry.kernel_name("agg_scan")
 def _agg_scan(
     blocks: tuple,  # tuple of per-block col dicts (pytree)
     n_valids: jax.Array,  # [nblocks]
@@ -502,6 +527,7 @@ def _agg_scan(
                      "num_segments", "ts_name", "tag_names", "schema",
                      "acc_dtype", "float_ops", "pack_dtype"),
 )
+@device_telemetry.kernel_name("agg_scan_sharded")
 def _agg_scan_sharded(
     cols: dict,  # {name: [N_pad] array sharded along "shard"}
     base_mask: jax.Array,  # [N_pad] bool, sharded: padding & dedup survivors
@@ -550,6 +576,7 @@ def _agg_scan_sharded(
                      "ts_name", "tag_names", "schema", "need_ts",
                      "acc_dtype", "float_ops", "int_ops", "pack_dtype"),
 )
+@device_telemetry.kernel_name("agg_scan_sharded_sparse")
 def _agg_scan_sharded_sparse(
     cols: dict,  # {name: [N_pad] array sharded along "shard"}
     base_mask: jax.Array,  # [N_pad] bool, sharded
@@ -664,6 +691,7 @@ def _build_prep(scan, arg_names, start, end, out_rows, acc_dtype, has_nan,
                      "num_segments", "tag_names", "schema", "float_ops",
                      "pack_dtype"),
 )
+@device_telemetry.kernel_name("agg_scan_sharded_prepared")
 def _agg_scan_sharded_prepared(
     cols: dict,  # sharded cols incl. "__prep__" (+ optional min/max planes)
     base_mask: jax.Array,
@@ -771,6 +799,8 @@ def _prep_stream_step_impl(acc, cols, n_valid, *, where, keys, num_segments,
 
 _PREP_STREAM_STATICS = ("where", "keys", "num_segments", "tag_names",
                         "schema")
+_prep_stream_step_impl = device_telemetry.kernel_name("prep_stream_step")(
+    _prep_stream_step_impl)
 _prep_stream_step = functools.partial(
     jax.jit, static_argnames=_PREP_STREAM_STATICS)(_prep_stream_step_impl)
 # donated twin: the chunked bigger-than-HBM fold reuses the accumulator
@@ -882,7 +912,7 @@ _agg_block_jit = functools.partial(
     static_argnames=("where", "keys", "agg_args", "ops", "num_segments",
                      "ts_name", "tag_names", "schema", "need_ts",
                      "acc_dtype"),
-)(_agg_block)
+)(device_telemetry.kernel_name("agg_block")(_agg_block))
 
 
 @functools.partial(
@@ -890,6 +920,7 @@ _agg_block_jit = functools.partial(
     static_argnames=("where", "keys", "agg_args", "ops", "cap", "ts_name",
                      "tag_names", "schema", "need_ts", "acc_dtype"),
 )
+@device_telemetry.kernel_name("agg_block_sparse")
 def _agg_block_sparse(
     cols: dict,
     n_valid: jax.Array,
@@ -937,6 +968,7 @@ def _agg_step_impl(acc, cols, n_valid, *, where, keys, agg_args, ops,
 _AGG_STEP_STATICS = ("where", "keys", "agg_args", "ops", "num_segments",
                      "ts_name", "tag_names", "schema", "need_ts",
                      "acc_dtype")
+_agg_step_impl = device_telemetry.kernel_name("agg_step")(_agg_step_impl)
 _agg_step = functools.partial(
     jax.jit, static_argnames=_AGG_STEP_STATICS)(_agg_step_impl)
 # see _prep_stream_step_donated: accumulator + chunk buffers reused
@@ -989,6 +1021,7 @@ def _pack_part(part: dict, float_ops, int_ops, pack_dtype):
                      "tag_names", "schema", "need_ts", "acc_dtype",
                      "float_ops", "int_ops", "pack_dtype"),
 )
+@device_telemetry.kernel_name("agg_scan_sparse")
 def _agg_scan_sparse(
     cols: dict,  # {name: [N] padded whole-scan arrays}
     base_mask: jax.Array,  # [N] bool: padding & dedup survivors
@@ -1029,6 +1062,7 @@ def _agg_scan_sparse(
                      "tag_names", "schema", "acc_dtype", "float_ops",
                      "pack_dtype"),
 )
+@device_telemetry.kernel_name("agg_scan_sparse_fused")
 def _agg_scan_sparse_fused(
     cols: dict,  # {name: [N] padded whole-scan arrays}
     base_mask: jax.Array,
@@ -1063,6 +1097,7 @@ def _agg_scan_sparse_fused(
 
 
 @functools.partial(jax.jit, static_argnames=("where", "tag_names", "schema"))
+@device_telemetry.kernel_name("filter_block")
 def _filter_block(cols: dict, n_valid: jax.Array, dedup_mask, *, where,
                   tag_names, schema):
     some = next(iter(cols.values()))
@@ -1076,6 +1111,7 @@ def _filter_block(cols: dict, n_valid: jax.Array, dedup_mask, *, where,
 
 
 @jax.jit
+@device_telemetry.kernel_name("dedup_mask")
 def _dedup_mask(sid, ts, seq, op_type, valid):
     order, keep = sort_dedup(sid, ts, seq, op_type, valid)
     mask = jnp.zeros(valid.shape, dtype=bool)
@@ -1364,7 +1400,13 @@ class PhysicalExecutor:
         the single-groupby and double-groupby classes) so the first
         dashboard query pays HLO-level compile only, not Mosaic. Best
         effort — a failure never takes the node down — but never
-        silent: it is counted and logged (_note_degradation)."""
+        silent: it is counted and logged (_note_degradation). While it
+        runs it counts among the warm-ups still compiling
+        (device_status()["warmup"]["warming"]): a client that waits for
+        a warm server waits for these compiles too, instead of finding
+        them in its first minute of traffic."""
+        with self._warm_lock:
+            self._device_warming.add("prewarm")
         try:
             from greptimedb_tpu.ops import pallas_segment as ps
 
@@ -1394,6 +1436,9 @@ class PhysicalExecutor:
         except Exception:  # noqa: BLE001 — pre-warm must never take a node down
             _note_degradation("prewarm_failed",
                               "background Pallas kernel pre-warm")
+        finally:
+            with self._warm_lock:
+                self._device_warming.discard("prewarm")
 
     def _note_tier(self, tier: str, num_rows: int, seconds: float) -> None:
         """Feed one measured execution into the per-tier history ring
@@ -1554,6 +1599,21 @@ class PhysicalExecutor:
         return best
 
     def execute(self, plan: lp.LogicalPlan) -> QueryResult:
+        """Run one statement's plan and count the tier that answered it
+        (query_tier_total), once, where that is final: `cache` when the
+        partial-aggregate cache served every part and no kernel ran,
+        else the effective last_tier."""
+        from greptimedb_tpu.utils.metrics import QUERY_TIER
+
+        self._tls.__dict__.pop("last_tier", None)
+        self.last_partial_stats = None
+        res = self._execute(plan)
+        stats = self.last_partial_stats
+        QUERY_TIER.inc(tier="cache" if stats and not stats["delta_rows"]
+                       else self.last_tier)
+        return res
+
+    def _execute(self, plan: lp.LogicalPlan) -> QueryResult:
         # unwrap the linear chain
         limit = offset = None
         sort: Optional[lp.Sort] = None
@@ -1591,7 +1651,6 @@ class PhysicalExecutor:
         from greptimedb_tpu.storage.index import extract_tag_predicates
 
         tag_preds = extract_tag_predicates(where, table.schema) or None
-        from greptimedb_tpu.utils import tracing
 
         def run(ts_range):
             # lastpoint pruning: an all-`last` aggregate grouped by one
@@ -1603,8 +1662,8 @@ class PhysicalExecutor:
             lp_tag = self._lastpoint_tag(table, where, agg, ts_range)
             if (lp_tag is not None and len(table.region_ids) == 1
                     and hasattr(self.engine, "scan_last")):
-                with tracing.span("scan", table=table.name, regions=1,
-                                  lastpoint=True):
+                with tracing.stage("scan", table=table.name, regions=1,
+                                   lastpoint=True):
                     pruned = self.engine.scan_last(
                         table.region_ids[0], lp_tag, scan_node.columns)
                 if pruned is not None:
@@ -1656,8 +1715,8 @@ class PhysicalExecutor:
                     else:
                         stream.close()
 
-            with tracing.span("scan", table=table.name,
-                              regions=len(table.region_ids)) as scan_attrs:
+            with tracing.stage("scan", table=table.name,
+                               regions=len(table.region_ids)) as scan_attrs:
                 if len(table.region_ids) == 1:
                     scan = self.engine.scan(table.region_ids[0], ts_range,
                                             scan_node.columns, tag_preds)
@@ -1810,7 +1869,6 @@ class PhysicalExecutor:
         to the gather-rows MergeScan path."""
         from greptimedb_tpu.query.dist_agg import combine_partials, merge_topk
         from greptimedb_tpu.query.dist_plan import classify_prefix
-        from greptimedb_tpu.utils import tracing
 
         out = classify_prefix(table, where, agg, project, sort, limit,
                               offset, ts_range, scan_node,
@@ -1896,6 +1954,7 @@ class PhysicalExecutor:
         return self._post_process({}, None, None, project, sort, limit,
                                   offset, table, nrows, host_cols=host_cols)
 
+    @_staged("assemble")
     def _finalize_combined_agg(self, combined, table, agg, having, project,
                                sort, limit, offset,
                                spec_slot) -> QueryResult:
@@ -1931,10 +1990,14 @@ class PhysicalExecutor:
         keys: list[DeviceKey] = []
         decoders = []  # per key: fn(int indices) -> value array, dtype
         extra_cols: dict[str, np.ndarray] = {}
-        for i, (name, kexpr) in enumerate(agg.keys):
-            dk, decode = self._plan_key(i, kexpr, ctx, scan, scan_node, extra_cols)
-            keys.append(dk)
-            decoders.append(decode)
+        # host factorization of the group keys (date_bin over every
+        # scanned row included) is aggregation work done on the host
+        with tracing.stage("host_agg", step="group_keys"):
+            for i, (name, kexpr) in enumerate(agg.keys):
+                dk, decode = self._plan_key(i, kexpr, ctx, scan, scan_node,
+                                            extra_cols)
+                keys.append(dk)
+                decoders.append(decode)
         from greptimedb_tpu import config
 
         num_groups = 1
@@ -1975,8 +2038,9 @@ class PhysicalExecutor:
                 ops.update(_PRIMITIVES[spec.func])
         need_ts = bool({"first", "last"} & ops)
 
-        reduced = self._boundary_firstlast(scan, table, agg, bound_where,
-                                           keys, extra_cols)
+        with tracing.stage("host_agg", step="boundary_firstlast"):
+            reduced = self._boundary_firstlast(scan, table, agg,
+                                               bound_where, keys, extra_cols)
         # incremental aggregation (ISSUE 13): immutable parts' [G, F]
         # partials come from the partial-aggregate cache; only uncached
         # parts + the memtable delta run kernels. Runs after the
@@ -2034,7 +2098,6 @@ class PhysicalExecutor:
         PartialCacheIneligible internally and counts one `fallback`."""
         from greptimedb_tpu.query import partial_cache as pc
         from greptimedb_tpu.query.dist_agg import combine_partials
-        from greptimedb_tpu.utils import tracing
         from greptimedb_tpu.utils.metrics import PARTIAL_AGG_CACHE_EVENTS
 
         if not pc.enabled() or _PARTIAL_DISABLED["flag"]:
@@ -2065,10 +2128,12 @@ class PhysicalExecutor:
             _PARTIAL_DISABLED["flag"] = True
             PARTIAL_AGG_CACHE_EVENTS.inc(event="fallback")
             return None
-        with tracing.span("incremental_agg", parts=stats["parts"],
-                          part_hits=stats["part_hits"],
-                          delta_rows=stats["delta_rows"],
-                          total_rows=stats["total_rows"]):
+        # the fold of the per-part partials runs in numpy on the host
+        with tracing.stage("host_agg", step="combine_partials",
+                           parts=stats["parts"],
+                           part_hits=stats["part_hits"],
+                           delta_rows=stats["delta_rows"],
+                           total_rows=stats["total_rows"]):
             combined = combine_partials(partials, len(agg.keys),
                                         tuple(sorted(ops)))
         # measured-routing feed: the fold only ran kernels over the
@@ -2220,10 +2285,14 @@ class PhysicalExecutor:
                 dedup_mask, entry.start, entry.end, entry.block)
 
         def compute_partial_dense(entry):
-            out = _agg_block_jit(fetch_cols(entry),
-                                 jnp.asarray(entry.end - entry.start),
-                                 entry_dmask(entry), **kw)
-            planes = {op: _readback(v) for op, v in out.items()}
+            with _kstage("upload"):
+                cols = fetch_cols(entry)
+            with _kstage("device"):
+                out = _agg_block_jit(cols,
+                                     jnp.asarray(entry.end - entry.start),
+                                     entry_dmask(entry), **kw)
+            with _kstage("readback"):
+                planes = {op: _readback(v) for op, v in out.items()}
             rows = planes["rows"]
             rows1 = rows[:, 0] if rows.ndim == 2 else rows
             # keyed aggregates keep only observed groups (matching the
@@ -2247,24 +2316,28 @@ class PhysicalExecutor:
             # block (observed groups can't exceed part rows), so the
             # 64k dense cache ceiling never enters the per-part shapes
             cap = min(entry.block, config.sparse_groups_max())
-            out, uniq, n_groups = _agg_block_sparse(
-                fetch_cols(entry), jnp.asarray(entry.end - entry.start),
-                entry_dmask(entry), cap=cap, **sparse_kw)
-            u = int(n_groups)
+            with _kstage("upload"):
+                cols = fetch_cols(entry)
+            with _kstage("device"):
+                out, uniq, n_groups = _agg_block_sparse(
+                    cols, jnp.asarray(entry.end - entry.start),
+                    entry_dmask(entry), cap=cap, **sparse_kw)
+            with _kstage("readback"):
+                u = int(n_groups)
             if u > cap:
                 raise PlanError(
                     f"part observed {u} distinct groups, exceeding the "
                     f"sparse cap {cap}; raise "
                     "GREPTIMEDB_TPU_SPARSE_GROUPS_MAX or add predicates")
-            gids = np.asarray(uniq)[:u]
+            with _kstage("readback"):
+                gids = _readback(uniq)[:u]
+                planes = {op: _readback(v)[:u] for op, v in out.items()}
             key_cols = []
             for i, decode in enumerate(decoders):
                 idx = (gids // strides[i]) % keys[i].size
                 col, _ = decode(idx)
                 key_cols.append(np.asarray(col))
-            return {"keys": key_cols,
-                    "planes": {op: _readback(v)[:u]
-                               for op, v in out.items()}}
+            return {"keys": key_cols, "planes": planes}
 
         compute_partial = compute_partial_sparse if use_sparse \
             else compute_partial_dense
@@ -2357,8 +2430,10 @@ class PhysicalExecutor:
                 with self._warm_lock:
                     self._device_warming.discard(fp)
 
-        threading.Thread(target=warm, daemon=True,
-                         name="gtpu-incremental-warm").start()
+        # under the request's trace: the warm-up's compile hangs off the
+        # request that kicked it, marked thread="warmup"
+        threading.Thread(target=tracing.propagate(warm, background=True),
+                         daemon=True, name="gtpu-incremental-warm").start()
 
     def _parts_ts_disjoint(self, scan, ts_name: str) -> bool:
         """Whether every SST part's ts extent (and the memtable tail's)
@@ -2431,6 +2506,7 @@ class PhysicalExecutor:
                 return _OnShard
         return lambda fid: _TierCtx(tier)
 
+    @_staged("assemble")
     def _agg_tail(self, acc, sparse_gids, agg, keys, decoders, spec_slot,
                   host_info, having, project, sort, limit, offset,
                   table) -> QueryResult:
@@ -2533,7 +2609,11 @@ class PhysicalExecutor:
                     with self._warm_lock:
                         self._device_warming.discard(wkey)
 
-            threading.Thread(target=warm, daemon=True).start()
+            # under the request's trace: the warm-up's compile hangs off
+            # the request that kicked it, marked thread="warmup"
+            threading.Thread(
+                target=tracing.propagate(warm, background=True),
+                daemon=True, name="gtpu-device-warm").start()
         return "host"
 
     def _boundary_firstlast(self, scan, table, agg, bound_where, keys,
@@ -2667,10 +2747,13 @@ class PhysicalExecutor:
         need_ts = bool({"first", "last"} & ops)
 
         self.last_path = "stream"
-        acc = self._fold_stream(stream, table, bound_where, tuple(keys),
-                                tuple(arg_exprs), tuple(sorted(ops)),
-                                num_groups, ts_name, ctx, need_ts,
-                                len(arg_exprs))
+        # one stage for the whole fold: chunk decode and upload run on
+        # the prefetch thread and overlap the step dispatches here
+        with _kstage("device", kernel="stream_fold"):
+            acc = self._fold_stream(stream, table, bound_where, tuple(keys),
+                                    tuple(arg_exprs), tuple(sorted(ops)),
+                                    num_groups, ts_name, ctx, need_ts,
+                                    len(arg_exprs))
         return self._agg_tail(acc, None, agg, keys, decoders, spec_slot,
                               None, having, project, sort, limit, offset,
                               table)
@@ -2893,6 +2976,7 @@ class PhysicalExecutor:
                              base=base), decode_bucket
         raise _NotStreamable(f"group key {kexpr!r} needs materialized scan")
 
+    @_staged("host_agg")
     def _host_aggs(self, host_specs, keys, scan, extra_cols, bound_where,
                    table, ctx, num_groups, present, env, sparse_gids=None):
         """Order-statistic aggregates (argmax/percentile/…) over host
@@ -3019,7 +3103,6 @@ class PhysicalExecutor:
         """Run the device aggregation; returns (acc planes, sparse group
         ids or None). Dense: planes indexed by global group id. Sparse:
         planes indexed by compact slot, plus the observed global ids."""
-        from greptimedb_tpu.utils import tracing
 
         with tracing.span("device_agg", rows=scan.num_rows,
                           groups=num_groups):
@@ -3082,11 +3165,16 @@ class PhysicalExecutor:
                     # per-shard sort-compact + gid-space combine: the
                     # compact slots differ per shard but the global ids
                     # they decode to don't, so the host merge is exact
-                    return self._sparse_sharded_scan(
-                        scan, self.mesh, device_col_names, extra_cols,
-                        float_fields, acc_dtype, dedup_mask, bound_where,
-                        keys, arg_exprs, ops, ts_name, tag_names, schema,
-                        float_ops, int_ops, widths, pack_dtype)
+                    # one stage per mesh dispatch: per-shard uploads,
+                    # the shard_map program and the readback of its
+                    # per-shard planes
+                    with _kstage("device", kernel="agg_scan_sharded_sparse"):
+                        return self._sparse_sharded_scan(
+                            scan, self.mesh, device_col_names, extra_cols,
+                            float_fields, acc_dtype, dedup_mask,
+                            bound_where, keys, arg_exprs, ops, ts_name,
+                            tag_names, schema, float_ops, int_ops, widths,
+                            pack_dtype)
                 except MeshIneligible:
                     self.last_tier = "device"
             return self._sparse_scan(
@@ -3108,11 +3196,12 @@ class PhysicalExecutor:
 
             try:
                 self.last_path = "sharded"
-                packed_f = self._sharded_scan(
-                    scan, mesh, device_col_names, extra_cols, float_fields,
-                    acc_dtype, dedup_mask, bound_where, keys, arg_exprs,
-                    ops, num_groups, ts_name, tag_names, schema, float_ops,
-                    pack_dtype)
+                with _kstage("device", kernel="agg_scan_sharded"):
+                    packed_f = self._sharded_scan(
+                        scan, mesh, device_col_names, extra_cols,
+                        float_fields, acc_dtype, dedup_mask, bound_where,
+                        keys, arg_exprs, ops, num_groups, ts_name,
+                        tag_names, schema, float_ops, pack_dtype)
                 return (_unpack_acc(packed_f, None, float_ops, (),
                                     widths), None)
             except MeshIneligible:
@@ -3191,16 +3280,17 @@ class PhysicalExecutor:
 
             blocks, n_valids, dmasks = self._gather_blocks(
                 scan, plan, fetch_block, dedup_mask)
-            packed_f, packed_i = _agg_scan_prepared(
-                tuple(blocks), jnp.asarray(np.asarray(n_valids)),
-                tuple(dmasks) if dmasks is not None else None,
-                where=bound_where, keys=keys, nf=nf, has_nan=has_nan,
-                finite=not self._scan_has_inf(scan, arg_names,
-                                              dtype=prep_dtype),
-                num_segments=num_groups,
-                tag_names=tag_names, schema=schema, float_ops=float_ops,
-                pack_dtype=pack_dtype,
-            )
+            finite = not self._scan_has_inf(scan, arg_names,
+                                            dtype=prep_dtype)
+            with _kstage("device", kernel="agg_scan_prepared"):
+                packed_f, packed_i = _agg_scan_prepared(
+                    tuple(blocks), jnp.asarray(np.asarray(n_valids)),
+                    tuple(dmasks) if dmasks is not None else None,
+                    where=bound_where, keys=keys, nf=nf, has_nan=has_nan,
+                    finite=finite, num_segments=num_groups,
+                    tag_names=tag_names, schema=schema,
+                    float_ops=float_ops, pack_dtype=pack_dtype,
+                )
             return (_unpack_acc(packed_f, packed_i, float_ops, int_ops,
                                 widths), None)
         else:
@@ -3219,15 +3309,17 @@ class PhysicalExecutor:
 
             blocks, n_valids, dmasks = self._gather_blocks(
                 scan, plan, fetch_block, dedup_mask)
-            packed_f, packed_i = _agg_scan(
-                tuple(blocks), jnp.asarray(np.asarray(n_valids)),
-                tuple(dmasks) if dmasks is not None else None,
-                where=bound_where, keys=keys, agg_args=arg_exprs, ops=ops,
-                num_segments=num_groups, ts_name=ts_name, tag_names=tag_names,
-                schema=schema, need_ts=bool({"first", "last"} & set(ops)),
-                acc_dtype=acc_dtype, float_ops=float_ops, int_ops=int_ops,
-                pack_dtype=pack_dtype,
-            )
+            with _kstage("device", kernel="agg_scan"):
+                packed_f, packed_i = _agg_scan(
+                    tuple(blocks), jnp.asarray(np.asarray(n_valids)),
+                    tuple(dmasks) if dmasks is not None else None,
+                    where=bound_where, keys=keys, agg_args=arg_exprs,
+                    ops=ops, num_segments=num_groups, ts_name=ts_name,
+                    tag_names=tag_names, schema=schema,
+                    need_ts=bool({"first", "last"} & set(ops)),
+                    acc_dtype=acc_dtype, float_ops=float_ops,
+                    int_ops=int_ops, pack_dtype=pack_dtype,
+                )
         return _unpack_acc(packed_f, packed_i, float_ops, int_ops, widths), None
 
     def _sparse_scan(self, scan, device_col_names, extra_cols, float_fields,
@@ -3248,66 +3340,69 @@ class PhysicalExecutor:
         n = scan.num_rows
         n_pad = block_size_for(n)
         cap = min(n_pad, config.sparse_groups_max())
-        cols = {}
-        for name in device_col_names:
-            cast = acc_dtype if name in float_fields else None
+        with _kstage("upload"):
+            cols = {}
+            for name in device_col_names:
+                cast = acc_dtype if name in float_fields else None
 
-            def build(name=name, cast=cast):
-                src = extra_cols[name] if name in extra_cols \
-                    else scan.columns[name]
-                arr = pad_rows(src, n_pad)
-                if cast is not None and arr.dtype != cast:
-                    arr = arr.astype(cast)
-                return jnp.asarray(arr)
+                def build(name=name, cast=cast):
+                    src = extra_cols[name] if name in extra_cols \
+                        else scan.columns[name]
+                    arr = pad_rows(src, n_pad)
+                    if cast is not None and arr.dtype != cast:
+                        arr = arr.astype(cast)
+                    return jnp.asarray(arr)
 
-            if scan.region_id < 0 or name in extra_cols:
-                cols[name] = build()
-            else:
-                # whole-scan arrays cannot be file-anchored: snapshot key
-                key = ("snap", scan.region_id, _snap_version(scan),
-                       _ACTIVE_TIER_VAR.get(), scan.scan_fingerprint,
-                       name, "whole", n_pad, str(cast))
-                cols[name] = self.cache.get(key, build)
+                if scan.region_id < 0 or name in extra_cols:
+                    cols[name] = build()
+                else:
+                    # whole-scan arrays cannot be file-anchored: snapshot key
+                    key = ("snap", scan.region_id, _snap_version(scan),
+                           _ACTIVE_TIER_VAR.get(), scan.scan_fingerprint,
+                           name, "whole", n_pad, str(cast))
+                    cols[name] = self.cache.get(key, build)
         base = np.arange(n_pad) < n
         if dedup_mask is not None:
             base[:n] &= np.asarray(dedup_mask)[:n]
         packed = None
-        if self._sparse_fused_ok(ops, arg_exprs, scan, schema, extra_cols,
-                                 acc_dtype):
-            from greptimedb_tpu.ops import pallas_segment as ps
-            from greptimedb_tpu.utils.metrics import PALLAS_DISPATCHES
+        with _kstage("device", kernel="agg_scan_sparse"):
+            if self._sparse_fused_ok(ops, arg_exprs, scan, schema, extra_cols,
+                                     acc_dtype):
+                from greptimedb_tpu.ops import pallas_segment as ps
+                from greptimedb_tpu.utils.metrics import PALLAS_DISPATCHES
 
-            try:
-                packed_f, packed_i, uniq, n_groups = _agg_scan_sparse_fused(
+                try:
+                    packed_f, packed_i, uniq, n_groups = _agg_scan_sparse_fused(
+                        cols, jnp.asarray(base), where=bound_where, keys=keys,
+                        arg_names=tuple(a.name for a in arg_exprs), ops=ops,
+                        cap=cap, tag_names=tag_names, schema=schema,
+                        acc_dtype=acc_dtype, float_ops=float_ops,
+                        pack_dtype=pack_dtype)
+                    packed_f.block_until_ready()
+                    packed = (packed_f, packed_i, uniq, n_groups)
+                    self.last_path = "sparse_fused"
+                    PALLAS_DISPATCHES.inc(kernel="sparse_fused_agg",
+                                          mode=ps.dispatch_mode())
+                    SPARSE_DISPATCHES.inc(path="fused")
+                except Exception:  # noqa: BLE001 — degrade, never fail the query
+                    _note_degradation(
+                        "fused_latch",
+                        "sparse fused pallas kernel failed; serving this and "
+                        "later queries through the XLA scatter path")
+                    _FUSED_DISABLED["flag"] = True
+                    PALLAS_DISPATCHES.inc(kernel="fused_agg_failed")
+            if packed is None:
+                packed = _agg_scan_sparse(
                     cols, jnp.asarray(base), where=bound_where, keys=keys,
-                    arg_names=tuple(a.name for a in arg_exprs), ops=ops,
-                    cap=cap, tag_names=tag_names, schema=schema,
-                    acc_dtype=acc_dtype, float_ops=float_ops,
+                    agg_args=arg_exprs, ops=ops, cap=cap, ts_name=ts_name,
+                    tag_names=tag_names, schema=schema,
+                    need_ts=bool({"first", "last"} & set(ops)),
+                    acc_dtype=acc_dtype, float_ops=float_ops, int_ops=int_ops,
                     pack_dtype=pack_dtype)
-                packed_f.block_until_ready()
-                packed = (packed_f, packed_i, uniq, n_groups)
-                self.last_path = "sparse_fused"
-                PALLAS_DISPATCHES.inc(kernel="sparse_fused_agg",
-                                      mode=ps.dispatch_mode())
-                SPARSE_DISPATCHES.inc(path="fused")
-            except Exception:  # noqa: BLE001 — degrade, never fail the query
-                _note_degradation(
-                    "fused_latch",
-                    "sparse fused pallas kernel failed; serving this and "
-                    "later queries through the XLA scatter path")
-                _FUSED_DISABLED["flag"] = True
-                PALLAS_DISPATCHES.inc(kernel="fused_agg_failed")
-        if packed is None:
-            packed = _agg_scan_sparse(
-                cols, jnp.asarray(base), where=bound_where, keys=keys,
-                agg_args=arg_exprs, ops=ops, cap=cap, ts_name=ts_name,
-                tag_names=tag_names, schema=schema,
-                need_ts=bool({"first", "last"} & set(ops)),
-                acc_dtype=acc_dtype, float_ops=float_ops, int_ops=int_ops,
-                pack_dtype=pack_dtype)
-            SPARSE_DISPATCHES.inc(path="classic")
+                SPARSE_DISPATCHES.inc(path="classic")
         packed_f, packed_i, uniq, n_groups = packed
-        u = int(n_groups)
+        with _kstage("readback"):
+            u = int(n_groups)
         if u > cap:
             raise PlanError(
                 f"query observed {u} distinct groups, exceeding the sparse "
@@ -3316,7 +3411,9 @@ class PhysicalExecutor:
         SPARSE_COMPACTION_RATIO.set(sparse_ops.compaction_ratio(u, n))
         acc = _unpack_acc(packed_f, packed_i, float_ops, int_ops, widths)
         acc = {k: v[:u] for k, v in acc.items()}
-        return acc, np.asarray(uniq)[:u]
+        with _kstage("readback"):
+            gids = _readback(uniq)[:u]
+        return acc, gids
 
     def _sparse_fused_ok(self, ops, arg_exprs, scan, schema, extra_cols,
                          acc_dtype) -> bool:
@@ -3644,28 +3741,32 @@ class PhysicalExecutor:
     def _gather_blocks(self, scan, plan, fetch, dedup_mask):
         """Walk the block plan through `fetch`, double-buffering block
         i+1's host build + H2D behind block i's assembly (the upload
-        prefetch worker). Returns (blocks, n_valids, dedup block masks)."""
+        prefetch worker). Returns (blocks, n_valids, dedup block masks).
+        One `upload` stage: what the hot set already holds costs it
+        nothing."""
         from greptimedb_tpu.utils import deadline as dl
 
         blocks, n_valids = [], []
         dmasks = [] if dedup_mask is not None else None
         do_prefetch = self._upload_prefetch_ok(scan)
-        for i, entry in enumerate(plan):
-            # host-level deadline checkpoint per device block: the
-            # jitted kernels below can't be interrupted, but a streamed
-            # scan crosses here once per block — an expired or killed
-            # query stops dispatching instead of walking the whole plan
-            dl.check("device dispatch")
-            if do_prefetch and i + 1 < len(plan):
-                # double buffering: the background worker builds and
-                # uploads block i+1 while this thread assembles
-                # block i (and the device chews on what's queued)
-                fetch(plan[i + 1], prefetch_only=True)
-            blocks.append(fetch(entry))
-            n_valids.append(entry.end - entry.start)
-            if dmasks is not None:
-                dmasks.append(_pad_device_mask(dedup_mask, entry.start,
-                                               entry.end, entry.block))
+        with _kstage("upload", blocks=len(plan)):
+            for i, entry in enumerate(plan):
+                # host-level deadline checkpoint per device block: the
+                # jitted kernels below can't be interrupted, but a
+                # streamed scan crosses here once per block — an expired
+                # or killed query stops dispatching instead of walking
+                # the whole plan
+                dl.check("device dispatch")
+                if do_prefetch and i + 1 < len(plan):
+                    # double buffering: the background worker builds and
+                    # uploads block i+1 while this thread assembles
+                    # block i (and the device chews on what's queued)
+                    fetch(plan[i + 1], prefetch_only=True)
+                blocks.append(fetch(entry))
+                n_valids.append(entry.end - entry.start)
+                if dmasks is not None:
+                    dmasks.append(_pad_device_mask(dedup_mask, entry.start,
+                                                   entry.end, entry.block))
         return blocks, n_valids, dmasks
 
     def _fused_ok(self, ops, arg_names, num_groups, scan) -> bool:
@@ -3733,18 +3834,20 @@ class PhysicalExecutor:
         blocks, n_valids, dmasks = self._gather_blocks(
             scan, plan, fetch_block, dedup_mask)
         try:
-            packed_f, packed_i = _agg_scan_fused(
-                tuple(blocks), jnp.asarray(np.asarray(n_valids)),
-                tuple(dmasks) if dmasks is not None else None,
-                where=bound_where, keys=keys, arg_names=arg_names,
-                num_segments=num_groups, ts_name=ts_name,
-                tag_names=tag_names, schema=schema, float_ops=float_ops,
-                int_ops=int_ops, pack_dtype=pack_dtype,
-                acc_dtype=acc_dtype, want_min="min" in ops,
-                want_max="max" in ops, want_sumsq="sumsq" in ops)
-            # surface async execution errors HERE, inside the latch —
-            # the result is consumed immediately downstream anyway
-            packed_f.block_until_ready()
+            with _kstage("device", kernel="agg_scan_fused"):
+                packed_f, packed_i = _agg_scan_fused(
+                    tuple(blocks), jnp.asarray(np.asarray(n_valids)),
+                    tuple(dmasks) if dmasks is not None else None,
+                    where=bound_where, keys=keys, arg_names=arg_names,
+                    num_segments=num_groups, ts_name=ts_name,
+                    tag_names=tag_names, schema=schema,
+                    float_ops=float_ops, int_ops=int_ops,
+                    pack_dtype=pack_dtype, acc_dtype=acc_dtype,
+                    want_min="min" in ops, want_max="max" in ops,
+                    want_sumsq="sumsq" in ops)
+                # surface async execution errors HERE, inside the latch
+                # — the result is consumed immediately downstream anyway
+                packed_f.block_until_ready()
         except Exception:  # noqa: BLE001 — any kernel failure must degrade
             _note_degradation(
                 "fused_latch",
@@ -3942,6 +4045,7 @@ class PhysicalExecutor:
         scan._dedup_mask_cache = mask
         return mask
 
+    @_staged("device", kernel_step=True)
     def _compute_dedup(self, scan: ScanData, table) -> jax.Array:
         tag_names = [c.name for c in table.schema.tag_columns]
         if tag_names:
@@ -3988,6 +4092,7 @@ class PhysicalExecutor:
             scan, schema, bound_where, where_unbound, dedup_mask,
             referenced, n)
 
+    @_staged("device", kernel_step=True)
     def _device_filtered_indices(self, scan, schema, ctx, bound_where,
                                  dedup_mask, obj_cols, n) -> np.ndarray:
         tag_names = frozenset(ctx.tag_names)
@@ -4008,6 +4113,7 @@ class PhysicalExecutor:
             picked.append(np.flatnonzero(np.asarray(mask)) + start)
         return np.concatenate(picked) if picked else np.empty(0, dtype=np.int64)
 
+    @_staged("host_agg")
     def _host_filtered_indices(self, scan, schema, bound_where,
                                where_unbound, dedup_mask, referenced,
                                n) -> np.ndarray:
@@ -4049,17 +4155,19 @@ class PhysicalExecutor:
                                          where_unbound=where)
 
         # gather + decode on host
-        host_cols: dict[str, np.ndarray] = {}
-        for name, arr in scan.columns.items():
-            taken = arr[idx]
-            if name in scan.tag_dicts:
-                from greptimedb_tpu.datatypes.vector import DictVector
-                taken = DictVector(taken, scan.tag_dicts[name]).decode()
-            host_cols[name] = taken
+        with tracing.stage("assemble"):
+            host_cols: dict[str, np.ndarray] = {}
+            for name, arr in scan.columns.items():
+                taken = arr[idx]
+                if name in scan.tag_dicts:
+                    from greptimedb_tpu.datatypes.vector import DictVector
+                    taken = DictVector(taken, scan.tag_dicts[name]).decode()
+                host_cols[name] = taken
 
-        env: dict = {}
-        return self._post_process(env, None, None, project, sort, limit, offset,
-                                  table, len(idx), host_cols=host_cols)
+            env: dict = {}
+            return self._post_process(env, None, None, project, sort, limit,
+                                      offset, table, len(idx),
+                                      host_cols=host_cols)
 
     # ---- shared tail: project/having/sort/limit over host arrays -----------
 
@@ -4118,6 +4226,7 @@ class PhysicalExecutor:
 
 
 @functools.partial(jax.jit, static_argnames=("start", "end", "block"))
+@device_telemetry.kernel_name("pad_device_mask")
 def _pad_device_mask(mask: jax.Array, start: int, end: int, block: int) -> jax.Array:
     sl = jax.lax.dynamic_slice_in_dim(mask, start, end - start)
     return jnp.pad(sl, (0, block - (end - start)), constant_values=False)
@@ -4125,8 +4234,11 @@ def _pad_device_mask(mask: jax.Array, start: int, end: int, block: int) -> jax.A
 
 def _unpack_acc(packed_f, packed_i, float_ops, int_ops, widths):
     """Split the kernel's packed output matrix back into per-op planes.
-    This is the dense/prepared paths' D2H readback boundary."""
-    host_f = _readback(packed_f)
+    This is the dense/prepared paths' D2H readback boundary: the
+    `readback` stage waits here for whatever the device still runs."""
+    with _kstage("readback"):
+        host_f = _readback(packed_f)
+        host_i = _readback(packed_i) if int_ops else None
     acc: dict[str, np.ndarray] = {}
     off = 0
     for k in float_ops:
@@ -4136,10 +4248,8 @@ def _unpack_acc(packed_f, packed_i, float_ops, int_ops, widths):
         if k in ("count", "rows"):
             sl = sl.astype(np.int64)
         acc[k] = sl
-    if int_ops:
-        host_i = _readback(packed_i)
-        for j, k in enumerate(int_ops):
-            acc[k] = host_i[:, j]
+    for j, k in enumerate(int_ops):
+        acc[k] = host_i[:, j]
     return acc
 
 

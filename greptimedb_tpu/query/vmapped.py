@@ -59,6 +59,7 @@ from greptimedb_tpu.query.expr import (
 )
 from greptimedb_tpu.ops.segment import segment_agg
 from greptimedb_tpu.sql import ast
+from greptimedb_tpu.utils.device_telemetry import kernel_name
 
 
 class VmapIneligible(Exception):
@@ -118,6 +119,7 @@ def _member_mask(cols, base_mask, shared_where, param_specs, pvals,
                      "tag_names", "schema", "acc_dtype", "float_ops",
                      "pack_dtype"),
 )
+@kernel_name("vmapped_agg_scan")
 def _vmapped_agg_scan(
     blocks: tuple,  # per-block col dicts (member-invariant)
     n_valids: jax.Array,
@@ -171,6 +173,7 @@ def _vmapped_agg_scan(
                      "ops", "cap", "ts_name", "need_ts", "tag_names",
                      "schema", "acc_dtype", "float_ops", "pack_dtype"),
 )
+@kernel_name("vmapped_sparse_agg_scan")
 def _vmapped_sparse_agg_scan(
     cols: dict,  # whole-scan padded col arrays (member-invariant)
     base_mask: jax.Array,  # [N] padding & dedup survivors

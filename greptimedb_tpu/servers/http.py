@@ -8,8 +8,10 @@ queries execute as device kernels; no framework dependencies.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import math
+import os
 import threading
 import time
 import traceback
@@ -115,6 +117,12 @@ class _Handler(BaseHTTPRequestHandler):
         # a ShmPayload (serving fabric's zero-copy handoff) is written
         # straight from its shared-memory view — duck-typed so this
         # module never imports shm
+        from greptimedb_tpu.utils import tracing
+
+        # stages belong to a request with a root (/health, /metrics and
+        # a refused login have none and stay out of the stage histogram)
+        stage = tracing.stage if getattr(self, "_traceparent", None) \
+            else (lambda name, **kw: contextlib.nullcontext())
         shm_payload = None
         if getattr(payload, "is_shm_payload", False):
             shm_payload = payload
@@ -122,18 +130,21 @@ class _Handler(BaseHTTPRequestHandler):
         elif isinstance(payload, bytes):
             data = payload
         else:
-            data = json.dumps(payload).encode()
+            with stage("encode"):
+                data = json.dumps(payload).encode()
         try:
-            self.send_response(code)
-            self.send_header("Content-Type", content_type)
-            self.send_header("Content-Length", str(len(data)))
-            # W3C egress: echo the request's trace context so the caller
-            # can join its spans to ours (set per traced request in _route)
-            tp = getattr(self, "_traceparent", None)
-            if tp:
-                self.send_header("traceparent", tp)
-            self.end_headers()
-            self.wfile.write(data)
+            with stage("send", bytes=len(data)):
+                self.send_response(code)
+                self.send_header("Content-Type", content_type)
+                self.send_header("Content-Length", str(len(data)))
+                # W3C egress: echo the request's trace context so the
+                # caller can join its spans to ours (set per traced
+                # request in _route)
+                tp = getattr(self, "_traceparent", None)
+                if tp:
+                    self.send_header("traceparent", tp)
+                self.end_headers()
+                self.wfile.write(data)
         finally:
             if shm_payload is not None:
                 shm_payload.release()
@@ -282,6 +293,24 @@ class _Handler(BaseHTTPRequestHandler):
                         out = profiling.mem_profile(
                             top=int(qs.get("top", ["50"])[0]))
                     return self._send(200, out.encode(), "text/plain")
+                if path == "/debug/pprof/device":
+                    # a jax.profiler session around the next n seconds,
+                    # host tracer on: device operations and this
+                    # program's spans on one clock, written under the
+                    # data home (tools/trace_gaps.py reads it)
+                    secs = min(float(qs.get("seconds", ["5"])[0]), 60.0)
+                    data_dir = getattr(getattr(
+                        self.query_engine.region_engine, "config", None),
+                        "data_dir", None)
+                    if data_dir is None:
+                        return self._send(404, {
+                            "error": "no local data home to write under"})
+                    try:
+                        out = profiling.device_trace(secs, os.path.join(
+                            os.path.dirname(data_dir), "profiles"))
+                    except profiling.ProfilerBusy as e:
+                        return self._send(409, {"error": str(e)})
+                    return self._send(200, out)
                 return self._send(404, {"error": f"no route {path}"})
             if path == "/v1/faults":
                 # chaos-state debug surface: armed points, partitions,
@@ -489,18 +518,27 @@ class _Handler(BaseHTTPRequestHandler):
         # than this request thread (byte-identical either way)
         elapsed = round((time.perf_counter() - t0) * 1000, 3)
         pool = getattr(self.query_engine.concurrency, "encode", None)
-        if pool is not None:
-            rows = sum(r.num_rows for r in results if r.is_query)
-            data = pool.run(encode_sql_payload, results, elapsed,
-                            cost_rows=rows, shm_result=True)
-        else:
-            data = encode_sql_payload(results, elapsed)
+        from greptimedb_tpu.utils import tracing
+
+        # as this request thread sees it: on the pool it also waits for
+        # a worker
+        with tracing.stage("encode"):
+            if pool is not None:
+                rows = sum(r.num_rows for r in results if r.is_query)
+                data = pool.run(encode_sql_payload, results, elapsed,
+                                cost_rows=rows, shm_result=True)
+            else:
+                data = encode_sql_payload(results, elapsed)
         self._send(200, data)
 
     # ---- Prometheus API (reference http.rs:724-744) ------------------------
 
     def _handle_promql_range(self, v1=False):
-        from greptimedb_tpu.promql.engine import PromqlEngine, SeriesMatrix
+        from greptimedb_tpu.promql.engine import (
+            PromqlEngine,
+            SeriesMatrix,
+            d2h,
+        )
 
         params = self._form_or_query()
         query = params.get("query") or params.get("promql")
@@ -514,20 +552,35 @@ class _Handler(BaseHTTPRequestHandler):
             return self._send(400, _prom_err(f"bad range params: {e}"))
         ctx = self._ctx(params)
         engine = PromqlEngine(self.query_engine)
-        with QUERY_DURATION.time(kind="promql_range"):
-            times, result = engine.eval_matrix(query, start, end, step, ctx)
-        if isinstance(result, SeriesMatrix):
-            payload = _matrix_json(times, result)
-        else:
-            vals = np.broadcast_to(np.asarray(result, dtype=np.float64),
-                                   times.shape)
-            payload = {"resultType": "matrix",
-                       "result": [{"metric": {},
-                                   "values": _values_json(times, vals)}]}
-        self._send(200, {"status": "success", "data": payload})
+        from greptimedb_tpu.utils import slow_query, tracing
+
+        # the watch covers readback, encoding and the socket write too
+        # (eval_matrix's own is a no-op inside it): a slow PromQL
+        # request's record holds its whole stage tree
+        with slow_query.watch("promql", query, ctx.db):
+            with QUERY_DURATION.time(kind="promql_range"):
+                times, result = engine.eval_matrix(query, start, end, step,
+                                                   ctx)
+            if isinstance(result, SeriesMatrix):
+                payload = _matrix_json(times, result)
+            else:
+                with tracing.stage("readback"):
+                    vals = np.broadcast_to(d2h(result, dtype=np.float64),
+                                           times.shape)
+                with tracing.stage("encode"):
+                    payload = {"resultType": "matrix",
+                               "result": [{"metric": {},
+                                           "values": _values_json(times,
+                                                                  vals)}]}
+            self._send(200, {"status": "success", "data": payload})
 
     def _handle_promql_instant(self):
-        from greptimedb_tpu.promql.engine import PromqlEngine, SeriesMatrix
+        from greptimedb_tpu.promql.engine import (
+            PromqlEngine,
+            SeriesMatrix,
+            d2h,
+        )
+        from greptimedb_tpu.utils import slow_query, tracing
 
         params = self._form_or_query()
         query = params.get("query")
@@ -536,24 +589,30 @@ class _Handler(BaseHTTPRequestHandler):
         t = _prom_time(params.get("time", str(time.time())))
         ctx = self._ctx(params)
         engine = PromqlEngine(self.query_engine)
-        with QUERY_DURATION.time(kind="promql_instant"):
-            times, result = engine.eval_matrix(query, t, t, 1.0, ctx)
-        if isinstance(result, SeriesMatrix):
-            vals = np.asarray(result.values)
-            out = []
-            for i, lab in enumerate(result.labels):
-                v = vals[i, -1]
-                if math.isnan(v):
-                    continue
-                metric = dict(lab)
-                if result.metric:
-                    metric["__name__"] = result.metric
-                out.append({"metric": metric, "value": [t, _fmt_float(v)]})
-            payload = {"resultType": "vector", "result": out}
-        else:
-            v = float(np.asarray(result).reshape(-1)[-1])
-            payload = {"resultType": "scalar", "value": [t, _fmt_float(v)]}
-        self._send(200, {"status": "success", "data": payload})
+        with slow_query.watch("promql", query, ctx.db):
+            with QUERY_DURATION.time(kind="promql_instant"):
+                times, result = engine.eval_matrix(query, t, t, 1.0, ctx)
+            with tracing.stage("readback"):
+                vals = d2h(result.values
+                            if isinstance(result, SeriesMatrix) else result)
+            with tracing.stage("encode"):
+                if isinstance(result, SeriesMatrix):
+                    out = []
+                    for i, lab in enumerate(result.labels):
+                        v = vals[i, -1]
+                        if math.isnan(v):
+                            continue
+                        metric = dict(lab)
+                        if result.metric:
+                            metric["__name__"] = result.metric
+                        out.append({"metric": metric,
+                                    "value": [t, _fmt_float(v)]})
+                    payload = {"resultType": "vector", "result": out}
+                else:
+                    v = float(vals.reshape(-1)[-1])
+                    payload = {"resultType": "scalar",
+                               "value": [t, _fmt_float(v)]}
+            self._send(200, {"status": "success", "data": payload})
 
     def _handle_labels(self):
         params = self._form_or_query()
@@ -787,15 +846,21 @@ def _records_json(r: QueryResult) -> dict:
 
 
 def _matrix_json(times: np.ndarray, sm) -> dict:
-    vals = np.asarray(sm.values)
+    from greptimedb_tpu.promql.engine import d2h
+    from greptimedb_tpu.utils import tracing
+
+    # the evaluation's device work ends here: the readback waits for it
+    with tracing.stage("readback"):
+        vals = d2h(sm.values)
     out = []
-    for i, lab in enumerate(sm.labels):
-        metric = dict(lab)
-        if sm.metric:
-            metric["__name__"] = sm.metric
-        series_vals = _values_json(times, vals[i])
-        if series_vals:
-            out.append({"metric": metric, "values": series_vals})
+    with tracing.stage("encode", series=len(sm.labels)):
+        for i, lab in enumerate(sm.labels):
+            metric = dict(lab)
+            if sm.metric:
+                metric["__name__"] = sm.metric
+            series_vals = _values_json(times, vals[i])
+            if series_vals:
+                out.append({"metric": metric, "values": series_vals})
     return {"resultType": "matrix", "result": out}
 
 

@@ -63,7 +63,12 @@ _NODE = "local"
 _HZ = 19.0
 _WINDOW_S = 30.0
 
-_IDLE_MARKS = ("wait", "select", "poll", "accept", "read (")
+#: leaf frames of a thread that is parked, not computing. The last one is
+#: a ThreadPoolExecutor worker blocked in its queue's (C-level) get: its
+#: innermost Python frame is `_worker` itself (the scan-decode pool's
+#: threads outlive the query that started them)
+_IDLE_MARKS = ("wait", "select", "poll", "accept", "read (",
+               "_worker (thread.py")
 
 
 # ---- hot-path hooks (called from tracing.span / executor) ------------------
@@ -377,16 +382,13 @@ def summary(top: int = 10, node: Optional[str] = None) -> dict:
 
 
 def _ledger_rollup() -> dict:
-    """Cumulative node-level byte/query totals riding along the digest."""
+    """Cumulative node-level byte totals riding along the digest."""
     try:
-        from greptimedb_tpu.utils.metrics import (DEVICE_TRANSFER_BYTES,
-                                                  QUERY_ACHIEVED_GBPS)
+        from greptimedb_tpu.utils.metrics import DEVICE_TRANSFER_BYTES
         out = {}
         for labels, val in DEVICE_TRANSFER_BYTES.series():
             d = labels.get("direction", "?")
             out[f"{d}_bytes"] = int(out.get(f"{d}_bytes", 0) + val)
-        out["queries_accounted"] = int(QUERY_ACHIEVED_GBPS.total_count())
-        out["gbps_sum"] = float(QUERY_ACHIEVED_GBPS.total_sum())
         return out
     except Exception:
         return {}

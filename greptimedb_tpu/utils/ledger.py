@@ -18,8 +18,12 @@ never drift):
 - scan: rows scanned and host bytes decoded (fed from scan spans /
   the decode seam, including scan-pool worker threads via
   `tracing.propagate`)
-- device: H2D/D2H bytes (the device_telemetry seams), host-vs-device
-  aggregation milliseconds (fed from span completion)
+- device: H2D/D2H bytes (the device_telemetry seams)
+- stages: every closed segment of a flat serving stage (tracing.stage)
+  adds its milliseconds under ``<stage>_ms`` and ``stages_ms`` — the
+  same durations query_stage_seconds observes, so EXPLAIN ANALYZE, the
+  slow-query record and the histogram agree; the request root derives
+  `other` from ``stages_ms``
 
 `GTPU_TRACING=off` disables the ledger together with span recording —
 the observability plane A/Bs as one unit (the bench's overhead gate).
@@ -35,18 +39,6 @@ from typing import Optional
 
 _current: contextvars.ContextVar[Optional["Ledger"]] = \
     contextvars.ContextVar("gtpu_ledger", default=None)
-
-#: span name -> ledger key for duration feeds. `agg_ms` is the whole
-#: aggregation wall (host + device); `device_ms` the device-kernel
-#: portion nested inside it — `host_ms` is DERIVED as their difference
-#: at export time (a nested span must not double-count)
-_SPAN_MS_KEYS = {
-    "device_agg": "device_ms",
-    "vmapped_fragments": "device_ms",
-    "aggregate": "agg_ms",
-    "range_agg": "agg_ms",
-}
-
 
 def enabled() -> bool:
     """The GTPU_TRACING master switch — the CANONICAL parse for the
@@ -75,16 +67,16 @@ class Ledger:
 
     def note_span(self, span) -> None:
         """Span-completion feed (called by tracing._record): scan rows
-        and the host-vs-device time split fall out of spans that already
+        and the per-stage time split fall out of spans that already
         exist — no extra instrumentation at those sites. Piggybacked
         remote copies (node set) are skipped: the frontend's own scan
         span already covers the distributed gather, and counting the
         merged datanode span too would double every row."""
         if span.node is not None:
             return
-        key = _SPAN_MS_KEYS.get(span.name)
-        if key is not None:
-            self.add(key, span.duration_ms)
+        if span.stage:
+            self.add(span.name + "_ms", span.duration_ms)
+            self.add("stages_ms", span.duration_ms)
         if span.name in ("scan", "region_scan"):
             rows = span.attrs.get("rows")
             if isinstance(rows, (int, float)):
@@ -95,12 +87,11 @@ class Ledger:
             return dict(self._data)
 
     def to_dict(self) -> dict[str, float]:
-        d = derive(self.snapshot())
-        return {k: round(v, 3) for k, v in sorted(d.items())}
+        return {k: round(v, 3) for k, v in sorted(self.snapshot().items())}
 
     def summary(self) -> str:
         """Compact ``k=v`` rendering for span attrs and log lines."""
-        return format_dict(derive(self.snapshot()))
+        return format_dict(self.snapshot())
 
 
 def _fmt(v: float) -> str:
@@ -112,28 +103,15 @@ def format_dict(d: dict) -> str:
     return " ".join(f"{k}={_fmt(v)}" for k, v in sorted(d.items()))
 
 
-def derive(d: dict) -> dict:
-    """Derived fields over raw counters: the host share of aggregation
-    time is agg_ms minus the device-kernel spans nested inside it."""
-    agg = d.get("agg_ms")
-    if agg is not None:
-        host = agg - d.get("device_ms", 0.0)
-        if host > 0:
-            d = dict(d)
-            d["host_ms"] = round(host, 3)
-    return d
-
-
 def diff(before: dict, after: dict) -> dict[str, float]:
     """after - before, dropping zero deltas — the per-statement slice of
-    a request-scoped ledger (multi-statement requests share one).
-    Derived fields are computed over the slice."""
+    a request-scoped ledger (multi-statement requests share one)."""
     out = {}
     for k, v in after.items():
         d = v - before.get(k, 0.0)
         if d:
             out[k] = round(d, 3)
-    return derive(out)
+    return out
 
 
 def active() -> Optional[Ledger]:
@@ -176,13 +154,22 @@ def attach():
 @contextlib.contextmanager
 def attach_fresh():
     """Force a new ledger (EXPLAIN ANALYZE: the report must cover the
-    inner statement alone, not the whole connection's request)."""
+    inner statement alone, not the whole connection's request). What the
+    inner statement spent in serving stages still belongs to the
+    enclosing request: on exit the `*_ms` keys are added to the ledger
+    that was active before, so the request root's `other` stays what no
+    stage covered."""
     if not enabled():
         yield None
         return
+    outer = _current.get()
     led = Ledger()
     token = _current.set(led)
     try:
         yield led
     finally:
         _current.reset(token)
+        if outer is not None:
+            for key, value in led.snapshot().items():
+                if key.endswith("_ms"):
+                    outer.add(key, value)

@@ -531,7 +531,11 @@ REQUEST_BUDGET_REMAINING = REGISTRY.histogram(
 # utils/export_metrics.py like every other series
 XLA_COMPILES = REGISTRY.counter(
     "greptimedb_tpu_xla_compile_total",
-    "XLA compilations observed via jax.monitoring, by backend")
+    "XLA compilations observed via jax.monitoring, by backend, fn (the "
+    "compiled program's stable kernel name, or eager for an op "
+    "dispatched outside the named steps) and thread (request = a "
+    "request thread waited for it, warmup = start-up pre-warm or the "
+    "device warm-up hedge ran it beside the request)")
 XLA_CACHE_RETRIEVALS = REGISTRY.counter(
     "greptimedb_tpu_xla_cache_retrieval_total",
     "Executables served by JAX's persistent compilation cache instead "
@@ -539,7 +543,8 @@ XLA_CACHE_RETRIEVALS = REGISTRY.counter(
     "no xla_compile_total growth)")
 XLA_COMPILE_SECONDS = REGISTRY.histogram(
     "greptimedb_tpu_xla_compile_duration_seconds",
-    "XLA backend-compile wall time per compilation, by backend")
+    "XLA backend-compile wall time per compilation, by backend, fn and "
+    "thread (as xla_compile_total)")
 DEVICE_MEMORY = REGISTRY.gauge(
     "greptimedb_tpu_device_memory_bytes",
     "Accelerator memory by kind (in_use/limit summed over the local "
@@ -553,6 +558,11 @@ DEVICE_CACHE_EVENTS = REGISTRY.counter(
     "greptimedb_tpu_device_cache_events_total",
     "HBM block cache events by kind (hit/miss/evict/prefetch_join — a "
     "join is an upload the background prefetch worker already did)")
+PROMQL_LOAD_CACHE_EVENTS = REGISTRY.counter(
+    "greptimedb_tpu_promql_load_cache_events_total",
+    "PromQL loaded-series cache events by kind (hit = a selector's "
+    "sorted device arrays and label sets reused for this scan snapshot, "
+    "miss = matcher masks, series factorization and upload ran)")
 DEVICE_HOT_SET_EVENTS = REGISTRY.counter(
     "greptimedb_tpu_device_hot_set_events_total",
     "HBM-resident columnar hot set events by kind (hit/miss/evict/pin — "
@@ -593,6 +603,12 @@ TIER_ADMISSION = REGISTRY.counter(
     "host_hot = routed to the tier already holding the scan's "
     "file-anchored blocks, cold = no tier holds them, off = the "
     "GREPTIMEDB_TPU_TIER_ADMISSION knob disabled the probe)")
+QUERY_TIER = REGISTRY.counter(
+    "greptimedb_tpu_query_tier_total",
+    "Statements the executor answered, by the tier that answered: host "
+    "(CPU backend of an accelerator process), device, mesh, or cache "
+    "(every part served from the partial-aggregate cache, no kernel "
+    "ran); counted once per statement where the tier becomes final")
 SLOW_QUERIES = REGISTRY.counter(
     "greptimedb_tpu_slow_queries_total",
     "Statements slower than the slow-query threshold, by kind")
@@ -733,13 +749,16 @@ FAST_LANE_EVENTS = REGISTRY.sharded_counter(
     "requests that rode another request's in-flight execution)")
 STAGE_SECONDS = REGISTRY.histogram(
     "greptimedb_tpu_query_stage_seconds",
-    "Per-request serving-stage wall time by stage (parse / plan = "
-    "plan-cache lookup + substitution probe + plan_select / execute on "
-    "the slow lane; fast_bind / fast_execute on the fast lane) — with "
-    "admission_wait_seconds and encode_seconds this makes the QPS "
-    "breakdown attributable per stage instead of inferred; buckets "
-    "carry OpenMetrics trace_id exemplars — a slow bucket links "
-    "straight to a trace to pull via /v1/traces/<id>", exemplars=True)
+    "Per-request serving-stage wall time by stage, observed by the "
+    "stage spans of utils/tracing.py as they close. Flat stages, of "
+    "which no two overlap in one request: parse, plan, fast_bind, "
+    "admission_wait, scan, host_agg, upload, device, readback, "
+    "assemble, encode, send; other = the request root's duration minus "
+    "their sum. Enclosing labels, not part of that sum: execute and "
+    "fast_execute (the executor call on the slow / fast lane), request "
+    "(the root). Buckets carry OpenMetrics trace_id exemplars — a slow "
+    "bucket links straight to a trace to pull via /v1/traces/<id>",
+    exemplars=True)
 COUNTER_SHARDS = REGISTRY.gauge(
     "greptimedb_tpu_metrics_counter_shards",
     "Live per-thread shard cells across all sharded hot counters "
@@ -809,25 +828,14 @@ PARTIAL_AGG_DELTA_ROWS = REGISTRY.counter(
     "(delta = uncached part + memtable rows that ran through kernels, "
     "cached = rows whose partial plane was served from the cache)")
 
-# continuous profiling & roofline (utils/flame.py + utils/roofline.py):
-# the always-on sampler's attribution counts and the per-query achieved
-# memory bandwidth the roofline accountant folds out of the resource
-# ledger — ROADMAP item 1's headline capture metric, now a live series
+# continuous profiling (utils/flame.py): the always-on sampler's
+# attribution counts
 PROFILE_SAMPLES = REGISTRY.counter(
     "greptimedb_tpu_profile_samples_total",
     "Continuous-profiler stack samples by coarse stage (http/stmt/scan/"
     "device_agg/... from the innermost active span; host = a busy "
     "thread outside any span) — attributed/total ratio is the sampler's "
     "own health metric")
-QUERY_ACHIEVED_GBPS = REGISTRY.histogram(
-    "greptimedb_tpu_query_achieved_gbps",
-    "Per-statement achieved memory bandwidth in GB/s from the roofline "
-    "accountant ((h2d + d2h + decoded bytes) / device span time); "
-    "compare against the chip peak (819 GB/s on v5e) for the roofline "
-    "fraction; buckets carry trace_id exemplars so an anomalous "
-    "bandwidth bin links straight to its trace",
-    buckets=(0.1, 0.5, 1.0, 5.0, 10.0, 25.0, 50.0, 100.0, 200.0, 400.0,
-             819.0), exemplars=True)
 
 # ---- static analysis (tools/gtpu_lint.py, tier-1) --------------------------
 

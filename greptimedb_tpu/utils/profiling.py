@@ -5,10 +5,17 @@ the pprof crate's sampling profiler) and `http/mem_prof.rs` (jemalloc heap
 profiles): here a wall-clock stack sampler over `sys._current_frames()`
 produces folded-stack output (the flamegraph.pl / speedscope "collapsed"
 format), and tracemalloc snapshots provide allocation profiles. Both are
-pull-style: hit the endpoint, get a self-contained text artifact."""
+pull-style: hit the endpoint, get a self-contained text artifact.
+
+`device_trace` is the accelerator's counterpart: it brackets a few
+seconds with `jax.profiler`, host tracer on, so the `.xplane.pb` it
+leaves holds the device's operations AND the program's spans (every
+`tracing.span` is a TraceAnnotation) on one clock; `tools/trace_gaps.py`
+reduces it."""
 
 from __future__ import annotations
 
+import os
 import sys
 import threading
 import time
@@ -113,3 +120,45 @@ def mem_profile_stop() -> str:
             tracemalloc.stop()
             return "# tracemalloc stopped\n"
         return "# tracemalloc was not running\n"
+
+
+class ProfilerBusy(RuntimeError):
+    """A profiler session is already running in this process (another
+    caller's, or one the launcher holds): one at a time."""
+
+
+_device_lock = threading.Lock()
+
+
+def device_trace(seconds: float, base_dir: str) -> dict:
+    """Trace the next `seconds` seconds with jax.profiler (device planes
+    + host tracer, python tracer off) into a new directory under
+    `base_dir`; returns {dir, t_start_ns, t_stop_ns} (unix clock, around
+    the session). Only the process that holds the chip can trace it, so
+    this runs in the server. Raises ProfilerBusy while any session is
+    open."""
+    import jax
+
+    if not _device_lock.acquire(blocking=False):
+        raise ProfilerBusy("a device profile is already being taken")
+    try:
+        out = os.path.join(
+            base_dir, time.strftime("device-%Y%m%dT%H%M%S")
+            + f"-{time.time_ns() % 1_000_000:06d}")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        try:
+            jax.profiler.start_trace(out, profiler_options=opts)
+        except RuntimeError as e:
+            # jax allows one session per process: someone else's is open
+            raise ProfilerBusy(str(e)) from e
+        t_start = time.time_ns()
+        try:
+            time.sleep(seconds)
+        finally:
+            t_stop = time.time_ns()
+            jax.profiler.stop_trace()
+        return {"dir": out, "t_start_ns": t_start, "t_stop_ns": t_stop}
+    finally:
+        _device_lock.release()

@@ -100,10 +100,6 @@ class SlowQuery:
     #: the statement's slice of the per-query resource ledger (cache
     #: hits, H2D bytes, admission wait, rows scanned — utils/ledger.py)
     ledger: dict = field(default_factory=dict)
-    #: roofline fold over that same ledger slice (utils/roofline.py) —
-    #: None when the statement moved no bytes (host-only work)
-    achieved_gbps: Optional[float] = None
-    roofline_fraction: Optional[float] = None
 
     def to_dict(self) -> dict:
         return {
@@ -120,8 +116,6 @@ class SlowQuery:
                 for n, s, d in self.stages
             ],
             "ledger": dict(self.ledger),
-            "achieved_gbps": self.achieved_gbps,
-            "roofline_fraction": self.roofline_fraction,
         }
 
 
@@ -208,13 +202,6 @@ def _record(kind, query, db, dur_ms, thr, w, started, sink,
         stages=[(s.node or "local", s.name, s.duration_ms) for s in sink],
         ledger=led_slice or {},
     )
-    if led_slice:
-        from greptimedb_tpu.utils import roofline
-
-        rf = roofline.account(led_slice, duration_ms=dur_ms)
-        if rf is not None:
-            rec.achieved_gbps = round(rf["achieved_gbps"], 6)
-            rec.roofline_fraction = round(rf["roofline_fraction"], 9)
     with _lock:
         _ring.append(rec)
     SLOW_QUERIES.inc(kind=kind)

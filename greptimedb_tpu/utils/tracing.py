@@ -39,8 +39,11 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Optional
 
+import sys
+
 from greptimedb_tpu.utils import flame as _flame
-from greptimedb_tpu.utils import ledger, roofline
+from greptimedb_tpu.utils import ledger
+from greptimedb_tpu.utils.metrics import STAGE_SECONDS
 
 _current: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
     "gtpu_trace_id", default=None)
@@ -55,6 +58,22 @@ _parent: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
 #: without diffing the shared ring
 _collector: contextvars.ContextVar[Optional[list]] = contextvars.ContextVar(
     "gtpu_span_collector", default=None)
+
+#: the flat serving stages (PERF.md section 3): every instant of a served
+#: request belongs to at most one of them, the rest is `other`
+STAGES = ("parse", "plan", "fast_bind", "admission_wait", "scan",
+          "host_agg", "upload", "device", "readback", "assemble",
+          "encode", "send")
+
+#: innermost open stage of this context (None = none open); a thread
+#: that works FOR a request beside its own thread (warm-up, scan pool)
+#: carries _BACKGROUND: its stages are plain spans and count nowhere
+_stage: contextvars.ContextVar = contextvars.ContextVar(
+    "gtpu_stage", default=None)
+_BACKGROUND = object()
+#: this context runs work no request waits for (device warm-up)
+_warmup: contextvars.ContextVar[bool] = contextvars.ContextVar(
+    "gtpu_warmup", default=False)
 
 _RING_CAP = 4096
 _SPANS: deque = deque()
@@ -89,6 +108,8 @@ class Span:
     #: 16-hex span identity + parent linkage (None = a root span)
     span_id: str = ""
     parent_id: Optional[str] = None
+    #: one segment of a flat serving stage (see `stage`)
+    stage: bool = False
 
 
 def new_trace_id() -> str:
@@ -156,40 +177,168 @@ def _record(span: Span) -> None:
         exp.on_span(span)
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs):
+def _annotation(name: str, trace_id, span_id):
+    """The profiler's clock: when jax is already loaded in this process
+    the span is also a `jax.profiler.TraceAnnotation`, so a profiler
+    session with the host tracer on holds it on the device trace's
+    timeline. Never imports jax (a jax-free process stays jax-free);
+    outside a session the annotation costs one flag test."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        ann = jax.profiler.TraceAnnotation(
+            name, trace_id=trace_id or "", span_id=span_id)
+        ann.__enter__()
+        return ann
+    except Exception:  # noqa: BLE001 — a half-imported jax must not fail a span
+        return None
+
+
+class _Span:
+    """One timed span nested under the innermost open one; the context
+    manager `span()` returns. `name` may be changed before exit (the
+    compile listener learns only at the end whether the persistent
+    cache served the executable). `on_close(duration_ms, attrs)` runs
+    before the span is recorded."""
+
+    __slots__ = ("name", "attrs", "on_close", "stage", "_on", "_prof",
+                 "_sid", "_parent_id", "_token", "_t0", "_started", "_ann")
+
+    def __init__(self, name: str, attrs: dict, on_close=None,
+                 stage: bool = False):
+        self.name = name
+        self.attrs = attrs
+        self.on_close = on_close
+        self.stage = stage
+        self._on = False
+
+    def __enter__(self) -> dict:
+        # the continuous profiler's stage attribution rides span
+        # entry/exit (a thread-id-keyed registry the sampler thread can
+        # read — the contextvar stack is invisible cross-thread);
+        # guarded by flame's fast flag so the cost with profiling off is
+        # one attribute read, and kept alive even with GTPU_TRACING=off
+        # so flames stay staged during tracing A/B runs
+        self._prof = _flame._ENABLED
+        if self._prof:
+            _flame.push_stage(self.name)
+        if not enabled():
+            return self.attrs
+        self._on = True
+        self._sid = new_span_id()
+        self._parent_id = _parent.get()
+        self._token = _parent.set(self._sid)
+        self._ann = _annotation(self.name, _current.get(), self._sid)
+        self._t0 = time.perf_counter()
+        self._started = time.time()
+        return self.attrs
+
+    def __exit__(self, *exc) -> bool:
+        if self._on:
+            dur_ms = (time.perf_counter() - self._t0) * 1000.0
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+            _parent.reset(self._token)
+            if self.on_close is not None:
+                self.on_close(dur_ms, self.attrs)
+            _record(Span(_current.get(), self.name, dur_ms, self._started,
+                         self.attrs, span_id=self._sid,
+                         parent_id=self._parent_id, stage=self.stage))
+        if self._prof:
+            _flame.pop_stage()
+        return False
+
+
+def span(name: str, **attrs) -> _Span:
     """Record a timed span nested under the innermost open one. Yields
     the (mutable) attrs dict so the body can attach result stats it only
     knows at the end (rows, bytes, pruning counts) — they land on the
     recorded span."""
-    # the continuous profiler's stage attribution rides span entry/exit
-    # (a thread-id-keyed registry the sampler thread can read — the
-    # contextvar stack is invisible cross-thread); guarded by flame's
-    # fast flag so the cost with profiling off is one attribute read,
-    # and kept alive even with GTPU_TRACING=off so flames stay staged
-    # during tracing A/B runs
-    prof = _flame._ENABLED
-    if prof:
-        _flame.push_stage(name)
-    try:
-        if not enabled():
-            yield attrs
-            return
-        sid = new_span_id()
-        parent = _parent.get()
-        token = _parent.set(sid)
-        t0 = time.perf_counter()
-        started = time.time()
-        try:
-            yield attrs
-        finally:
-            _parent.reset(token)
-            _record(Span(_current.get(), name,
-                         (time.perf_counter() - t0) * 1000.0,
-                         started, attrs, span_id=sid, parent_id=parent))
-    finally:
-        if prof:
-            _flame.pop_stage()
+    return _Span(name, attrs)
+
+
+def _observe_as(label: str):
+    """An `on_close` that observes the span into query_stage_seconds."""
+    return lambda ms, _attrs: STAGE_SECONDS.observe(ms / 1000.0, stage=label)
+
+
+class _Stage:
+    """One flat serving stage. Stages never nest: opening one inside
+    another ENDS the outer stage's current segment and starts a new
+    segment of it when the inner stage closes, so every segment is a
+    direct child of the enclosing plain span (statement or request
+    root), the segments of one request never overlap, and their sum
+    plus `other` is the root's duration. Each segment is a span that
+    observes its duration into query_stage_seconds{stage} as it closes
+    and feeds the ledger (`<stage>_ms`, `stages_ms`) through the span
+    ring."""
+
+    __slots__ = ("name", "attrs", "_outer", "_seg")
+
+    def __init__(self, name: str, attrs: dict):
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self) -> dict:
+        self._outer = _stage.get()
+        if self._outer is not None:
+            self._outer._pause()
+        _stage.set(self)
+        self._resume()
+        return self.attrs
+
+    def __exit__(self, *exc) -> bool:
+        self._seg.__exit__(None, None, None)
+        _stage.set(self._outer)
+        if self._outer is not None:
+            self._outer._resume()
+        return False
+
+    def _resume(self) -> None:
+        self._seg = _Span(self.name, self.attrs, _observe_as(self.name),
+                          stage=True)
+        self._seg.__enter__()
+
+    def _pause(self) -> None:
+        # an interrupted segment keeps the attrs it had: the body may
+        # still write to the dict, and a recorded span is never mutated
+        self._seg.attrs = dict(self.attrs)
+        self._seg.__exit__(None, None, None)
+
+
+def stage(name: str, **attrs):
+    """Open the flat serving stage `name` (one of STAGES) on the request
+    thread. With GTPU_TRACING=off it is a span that records nothing; on
+    a thread that works beside the request's own (`propagate`) it is a
+    plain span `bg:<name>` and counts nowhere."""
+    if name not in STAGES:
+        # the histogram's label set and PERF.md's vocabulary are one list
+        raise ValueError(f"unknown serving stage {name!r}")
+    if _stage.get() is _BACKGROUND:
+        return _Span("bg:" + name, dict(attrs, background=True))
+    if not enabled():
+        return _Span(name, attrs)
+    return _Stage(name, attrs)
+
+
+def enclosing_stage(name: str, **attrs) -> _Span:
+    """A label of query_stage_seconds that ENCLOSES flat stages
+    (`execute`, `fast_execute`, `request`): a plain span whose whole
+    duration is observed; it is not part of the flat sum."""
+    return _Span(name, attrs, _observe_as(name))
+
+
+def in_warmup() -> bool:
+    """Whether this context runs work that no request waits for."""
+    return _warmup.get()
+
+
+#: a request that ran a statement entered one of these (the slow lane
+#: and PromQL parse, the fast lane binds): only such a root observes
+#: `other` and `request`, so writes and debug routes stay out of the
+#: query stage histogram
+_QUERY_MARKS = ("parse_ms", "fast_bind_ms")
 
 
 @contextlib.contextmanager
@@ -199,30 +348,40 @@ def request_span(name: str, traceparent: Optional[str] = None, **attrs):
     resource ledger — then restore the connection thread's previous
     context so keep-alive reuse can't leak one request's trace into the
     next. Every protocol front door (HTTP, MySQL, Postgres, Flight SQL)
-    enters through here; the span_coverage lint checker enforces it."""
+    enters through here; the span_coverage lint checker enforces it.
+    When the root closes it observes `request` (its duration) and
+    `other` (its duration minus the flat stages the ledger summed)."""
     parsed = parse_traceparent(traceparent) if traceparent else None
     tid, remote_parent = parsed if parsed else (new_trace_id(), None)
     tok_tid = _current.set(tid)
     tok_par = _parent.set(remote_parent)
+    tok_stage = _stage.set(None)
     try:
         with ledger.attach() as led:
-            with span(name, **attrs) as a:
-                try:
-                    yield a
-                finally:
-                    # stamp INSIDE the span block: the span is recorded
-                    # (and handed to the OTLP exporter) at __exit__, so
-                    # a later mutation would race the export serializer
-                    # and leave the exported copy ledger-less
-                    if led is not None:
-                        counts = ledger.derive(led.snapshot())
-                        if counts:
-                            a["ledger"] = ledger.format_dict(counts)
-                            # roofline fold on the request root: same
-                            # ledger dict, so the stamped numbers agree
-                            # with the byte counts by construction
-                            roofline.stamp(a, counts)
+            led0 = led.snapshot() if led is not None else {}
+
+            def close(dur_ms: float, a: dict) -> None:
+                # stamp BEFORE the span is recorded (and handed to the
+                # OTLP exporter): a later mutation would race the export
+                # serializer and leave the exported copy ledger-less
+                if led is None:
+                    return
+                snap = led.snapshot()
+                if snap:
+                    a["ledger"] = ledger.format_dict(snap)
+                if any(snap.get(k, 0.0) > led0.get(k, 0.0)
+                       for k in _QUERY_MARKS):
+                    staged = snap.get("stages_ms", 0.0) \
+                        - led0.get("stages_ms", 0.0)
+                    other = max(dur_ms - staged, 0.0)
+                    a["other_ms"] = round(other, 3)
+                    STAGE_SECONDS.observe(other / 1000.0, stage="other")
+                    STAGE_SECONDS.observe(dur_ms / 1000.0, stage="request")
+
+            with _Span(name, attrs, on_close=close) as a:
+                yield a
     finally:
+        _stage.reset(tok_stage)
         _parent.reset(tok_par)
         _current.reset(tok_tid)
 
@@ -257,13 +416,17 @@ def collect_spans():
         _collector.reset(token)
 
 
-def propagate(fn):
+def propagate(fn, background: bool = False):
     """Carry the caller's trace id, open-span parent, span sink, AND
     resource ledger across a thread-pool boundary (contextvars don't
     cross threads): the returned wrapper re-installs all four around
     each invocation. The sink is appended from worker threads —
     list.append is atomic, so concurrent region RPCs interleave
-    safely; the ledger takes its own lock."""
+    safely; the ledger takes its own lock. Serving stages belong to the
+    request thread alone: the wrapper marks its thread as background,
+    so a stage opened there is a plain span. `background=True` says the
+    request does not WAIT for this work either (the device warm-up): a
+    compile there is labelled thread="warmup"."""
     tid = _current.get()
     parent = _parent.get()
     sink = _collector.get()
@@ -274,9 +437,13 @@ def propagate(fn):
         t2 = _collector.set(sink)
         t3 = _parent.set(parent)
         t4 = ledger._current.set(led)
+        t5 = _stage.set(_BACKGROUND)
+        t6 = _warmup.set(background or _warmup.get())
         try:
             return fn(*args, **kwargs)
         finally:
+            _warmup.reset(t6)
+            _stage.reset(t5)
             ledger._current.reset(t4)
             _parent.reset(t3)
             _collector.reset(t2)

@@ -1,9 +1,9 @@
-"""Continuous profiling & roofline plane (ISSUE 17): the always-on
-flame sampler (stage/path attribution, bounded windows, profiler-thread
-exclusion), the roofline accountant (golden folds, span/slow-query/
-ANALYZE stamps, ledger agreement), the /v1/profile endpoints (auth,
-content types), deterministic cluster merge, heartbeat piggyback, and
-the OTLP log lane riding the trace exporter.
+"""Continuous profiling plane (ISSUE 17): the always-on flame sampler
+(stage/path attribution, bounded windows, profiler-thread exclusion),
+the per-statement ledger stamps (span / slow-query / ANALYZE, keyed on
+the serving stages), the /v1/profile endpoints (auth, content types),
+deterministic cluster merge, heartbeat piggyback, and the OTLP log lane
+riding the trace exporter.
 """
 
 from __future__ import annotations
@@ -22,7 +22,7 @@ from greptimedb_tpu.query import QueryEngine
 from greptimedb_tpu.storage import RegionEngine
 from greptimedb_tpu.storage.engine import EngineConfig
 from greptimedb_tpu.utils import (flame, ledger, otlp_trace, profiling,
-                                  roofline, slow_query, tracing)
+                                  slow_query, tracing)
 
 
 @pytest.fixture
@@ -60,77 +60,6 @@ def _spin_ms(ms: float) -> float:
     while (time.perf_counter() - t0) * 1000 < ms:
         x += sum(i * i for i in range(200))
     return x
-
-
-# ---- roofline accountant (golden, hand-computed) ----------------------------
-
-
-class TestRooflineAccountant:
-    def test_golden_fold(self, monkeypatch):
-        monkeypatch.setenv("GTPU_ROOFLINE_PEAK_GBPS", "100")
-        led = {"h2d_bytes": 6_000_000, "d2h_bytes": 1_000_000,
-               "bytes_decoded": 3_000_000, "device_ms": 20.0,
-               "rows_scanned": 1000}
-        rf = roofline.account(led)
-        # 10 MB over 20 ms = 0.5 GB/s; peak pinned to 100 GB/s
-        assert rf["bytes_total"] == 10_000_000
-        assert rf["achieved_gbps"] == pytest.approx(0.5)
-        assert rf["roofline_fraction"] == pytest.approx(0.005)
-        # 2 FLOPs/row * 1000 rows / 10 MB
-        assert rf["arithmetic_intensity"] == pytest.approx(2e-4)
-        assert rf["window_ms"] == 20.0
-        assert rf["peak_gbps"] == 100.0
-
-    def test_time_preference_device_then_agg_then_duration(self):
-        base = {"h2d_bytes": 1_000_000_000}
-        assert roofline.account({**base, "device_ms": 100.0,
-                                 "agg_ms": 999.0}, duration_ms=5555.0,
-                                peak=100.0)["window_ms"] == 100.0
-        assert roofline.account({**base, "agg_ms": 200.0},
-                                duration_ms=5555.0,
-                                peak=100.0)["window_ms"] == 200.0
-        assert roofline.account(base, duration_ms=400.0,
-                                peak=100.0)["window_ms"] == 400.0
-
-    def test_host_only_statement_stamps_nothing(self):
-        # no bytes, or no time window -> None, never a misleading zero
-        assert roofline.account({"device_ms": 10.0}) is None
-        assert roofline.account({"h2d_bytes": 1024}) is None
-        assert roofline.account({}) is None
-        attrs = {}
-        assert roofline.stamp(attrs, {"agg_ms": 3.0}) is None
-        assert attrs == {}
-
-    def test_stamp_writes_rounded_attrs(self, monkeypatch):
-        monkeypatch.setenv("GTPU_ROOFLINE_PEAK_GBPS", "819")
-        attrs = {}
-        rf = roofline.stamp(
-            attrs, {"h2d_bytes": 819_000_000, "device_ms": 1000.0})
-        assert attrs["achieved_gbps"] == pytest.approx(0.819)
-        assert attrs["roofline_fraction"] == pytest.approx(0.001)
-        assert rf["bytes_total"] == 819_000_000
-
-    def test_peak_by_device_kind_unknown_device_has_none(self, monkeypatch):
-        monkeypatch.setenv("GTPU_ROOFLINE_PEAK_GBPS", "123.5")
-        assert roofline.peak_gbps() == 123.5
-        monkeypatch.delenv("GTPU_ROOFLINE_PEAK_GBPS")
-        assert roofline.peak_gbps("TPU v5 lite") == 819.0
-        # a device the table does not know has no peak — and no fold,
-        # never a default (the tests' CPU device is one)
-        assert roofline.peak_gbps("some future chip") is None
-        assert roofline.peak_gbps() is None
-        assert roofline.account(
-            {"h2d_bytes": 1_000_000, "device_ms": 1.0}) is None
-        monkeypatch.setenv("GTPU_ROOFLINE_PEAK_GBPS", "not-a-number")
-        assert roofline.peak_gbps("TPU v5 lite") == 819.0
-
-    def test_format_line_stable(self):
-        rf = roofline.account({"h2d_bytes": 2_000_000, "device_ms": 4.0},
-                              peak=100.0)
-        line = roofline.format_line(rf)
-        assert "achieved_gbps=0.5" in line
-        assert "bytes=2000000" in line
-        assert "peak_gbps=100" in line
 
 
 # ---- continuous sampler -----------------------------------------------------
@@ -264,77 +193,68 @@ class TestSampleCpuExclusion:
 
 
 class TestQueryStamps:
-    def test_analyze_roofline_agrees_with_ledger(self, qe, monkeypatch):
-        monkeypatch.setenv("GTPU_ROOFLINE_PEAK_GBPS", "100")
+    """The statement's ledger slice — stamped on its span, printed by
+    EXPLAIN ANALYZE, kept in the slow-query record — carries the same
+    stage milliseconds the stage spans (and the histogram) recorded."""
+
+    @staticmethod
+    def _kv(line: str, head: str) -> dict:
+        return dict(kv.split("=") for kv in line.split(head)[1].split())
+
+    def test_analyze_ledger_agrees_with_stage_spans(self, qe):
         _seed(qe)
         r = qe.execute_one(
             "EXPLAIN ANALYZE SELECT host, avg(v) FROM cpu GROUP BY host")
         text = "\n".join(row[0] for row in r.rows())
-        assert "resource ledger:" in text
-        assert "roofline:" in text
-        led_line = next(ln for ln in text.splitlines()
-                        if "resource ledger:" in ln)
-        rf_line = next(ln for ln in text.splitlines() if "roofline:" in ln)
-        led_kv = dict(kv.split("=") for kv in
-                      led_line.split("resource ledger:")[1].split())
-        rf_kv = dict(kv.split("=") for kv in
-                     rf_line.split("roofline:")[1].split())
-        ledger_bytes = sum(float(led_kv.get(k, 0)) for k in
-                           ("h2d_bytes", "d2h_bytes", "bytes_decoded"))
-        # the acceptance bound: stamped numbers agree with the ledger's
-        # byte counts within 1%
-        assert float(rf_kv["bytes"]) == pytest.approx(ledger_bytes,
-                                                      rel=0.01)
-        recomputed = (float(rf_kv["bytes"])
-                      / (float(rf_kv["window_ms"]) / 1e3) / 1e9)
-        assert float(rf_kv["achieved_gbps"]) == pytest.approx(
-            recomputed, rel=0.01)
-        assert float(rf_kv["roofline_fraction"]) == pytest.approx(
-            float(rf_kv["achieved_gbps"]) / 100.0, rel=0.01)
+        assert "roofline:" not in text
+        led = self._kv(next(ln for ln in text.splitlines()
+                            if "resource ledger:" in ln),
+                       "resource ledger:")
+        stage_keys = [k for k in led
+                      if k.endswith("_ms") and k != "stages_ms"
+                      and k[:-3] in tracing.STAGES]
+        assert {"scan_ms", "device_ms", "assemble_ms"} <= set(stage_keys)
+        assert float(led["stages_ms"]) == pytest.approx(
+            sum(float(led[k]) for k in stage_keys), abs=0.01)
+        # every stage in the ledger is a span of the printed tree
+        for k in stage_keys:
+            assert f"{k[:-3]}: " in text
 
-    def test_root_span_and_histogram_stamped(self, qe, monkeypatch):
-        monkeypatch.setenv("GTPU_ROOFLINE_PEAK_GBPS", "100")
-        from greptimedb_tpu.utils.metrics import QUERY_ACHIEVED_GBPS
+    def test_statement_span_and_histogram_stamped(self, qe):
+        from greptimedb_tpu.session import QueryContext
+        from greptimedb_tpu.utils.metrics import STAGE_SECONDS
 
         _seed(qe)
-        n0 = QUERY_ACHIEVED_GBPS.total_count(stmt="Select")
-        from greptimedb_tpu.session import QueryContext
-
+        n0 = STAGE_SECONDS.count(stage="scan")
         ctx = QueryContext()
         qe.execute_sql("SELECT host, avg(v) FROM cpu GROUP BY host", ctx)
-        spans = {s.name: s for s in tracing.spans_for(ctx.trace_id)}
-        stmt = spans["stmt:Select"]
-        assert stmt.attrs.get("achieved_gbps", 0) > 0
-        assert 0 < stmt.attrs["roofline_fraction"] < 1e6
-        assert QUERY_ACHIEVED_GBPS.total_count(stmt="Select") == n0 + 1
+        spans = tracing.spans_for(ctx.trace_id)
+        stmt = next(s for s in spans if s.name == "stmt:Select")
+        led = self._kv("x " + stmt.attrs["ledger"], "x")
+        scans = [s for s in spans if s.name == "scan" and s.stage]
+        assert float(led["scan_ms"]) == pytest.approx(
+            sum(s.duration_ms for s in scans), abs=0.01)
+        assert "achieved_gbps" not in stmt.attrs
+        assert STAGE_SECONDS.count(stage="scan") == n0 + len(scans)
 
-    def test_ddl_statement_not_stamped(self, qe):
-        from greptimedb_tpu.session import QueryContext
-
-        ctx = QueryContext()
-        qe.execute_sql(
-            "CREATE TABLE t0 (ts TIMESTAMP TIME INDEX)", ctx)
-        spans = [s for s in tracing.spans_for(ctx.trace_id)
-                 if s.name.startswith("stmt:")]
-        assert spans
-        assert all("achieved_gbps" not in s.attrs for s in spans)
-
-    def test_slow_query_record_carries_roofline(self, qe, monkeypatch):
+    def test_slow_query_record_carries_stage_ledger(self, qe, monkeypatch):
         monkeypatch.setenv("GTPU_SLOW_QUERY_MS", "0.0001")
-        monkeypatch.setenv("GTPU_ROOFLINE_PEAK_GBPS", "100")
         slow_query.clear()
         try:
             _seed(qe)
             qe.execute_one("SELECT host, avg(v) FROM cpu GROUP BY host")
             rec = next(r for r in slow_query.records(50)
                        if r.query.startswith("SELECT"))
-            assert rec.achieved_gbps is not None
-            assert rec.achieved_gbps > 0
-            assert rec.roofline_fraction == pytest.approx(
-                rec.achieved_gbps / 100.0, rel=0.02)
+            assert rec.ledger.get("scan_ms", 0) > 0
+            by_stage: dict = {}
+            for _node, name, ms in rec.stages:
+                by_stage[name] = by_stage.get(name, 0.0) + ms
+            for key in ("parse", "scan", "device", "assemble"):
+                assert rec.ledger[key + "_ms"] == pytest.approx(
+                    by_stage[key], abs=0.01)
             d = rec.to_dict()
-            assert d["achieved_gbps"] == rec.achieved_gbps
-            assert d["roofline_fraction"] == rec.roofline_fraction
+            assert "achieved_gbps" not in d
+            assert "roofline_fraction" not in d
         finally:
             slow_query.clear()
 
@@ -345,9 +265,13 @@ class TestQueryStamps:
             _seed(qe)
             qe.execute_one("SELECT count(*) FROM cpu")
             r = qe.execute_one(
-                "SELECT achieved_gbps, roofline_fraction "
+                "SELECT stages, ledger "
                 "FROM information_schema.slow_queries")
-            assert r.rows()
+            assert any("scan=" in row[0] and "scan_ms=" in row[1]
+                       for row in r.rows())
+            with pytest.raises(Exception, match="achieved_gbps"):
+                qe.execute_one("SELECT achieved_gbps "
+                               "FROM information_schema.slow_queries")
         finally:
             slow_query.clear()
 
