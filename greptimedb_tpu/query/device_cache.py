@@ -173,6 +173,13 @@ class DeviceCache:
         self._store(key, arr, epoch=epoch)
         return arr
 
+    def resident(self, key: tuple) -> bool:
+        """Whether `get(key, ...)` would be served without running its
+        build: the block is cached, or a prefetch is building it. A
+        peek — it counts no hit and leaves the LRU order alone."""
+        with self._lock:
+            return key in self._lru or key in self._inflight
+
     def prefetch(self, key: tuple, build: Callable[[], jax.Array]) -> None:
         """Schedule `build` on the background worker so a later `get`
         finds the block resident (or joins the in-flight build). No-op

@@ -54,7 +54,11 @@ from greptimedb_tpu.query.expr import (
 from greptimedb_tpu.query.result import QueryResult
 from greptimedb_tpu.sql import ast
 from greptimedb_tpu.storage.engine import RegionEngine
-from greptimedb_tpu.storage.region import ScanData
+from greptimedb_tpu.storage.region import (
+    ScanData,
+    ScanExpired,
+    scan_io_counters,
+)
 from greptimedb_tpu.utils import device_telemetry, tracing
 from greptimedb_tpu.utils import flame as _flame
 
@@ -109,6 +113,25 @@ _PRIMITIVES = {
     "stddev": ("sum", "sumsq", "count"),
     "variance": ("sum", "sumsq", "count"),
 }
+
+
+def _is_time_bucket(kexpr, ts_name: str) -> bool:
+    """date_bin / time_bucket of a constant interval over the time index."""
+    return (isinstance(kexpr, ast.FuncCall)
+            and kexpr.name in ("date_bin", "time_bucket")
+            and isinstance(kexpr.args[0], ast.Interval)
+            and isinstance(kexpr.args[1], ast.Column)
+            and kexpr.args[1].name == ts_name)
+
+
+def _key_from_plan(kexpr, ctx) -> bool:
+    """Whether a group key is planned from the scan's metadata alone (a
+    tag column: its dictionary; a time bucket: the statement's range or
+    the snapshot's extent) — the shapes `_plan_key` serves without
+    reading rows."""
+    if isinstance(kexpr, ast.Column):
+        return kexpr.name in ctx.tag_names
+    return _is_time_bucket(kexpr, ctx.schema.time_index.name)
 
 
 def _needs_host_agg(spec, schema) -> bool:
@@ -169,6 +192,10 @@ class _BlockEntry(NamedTuple):
 #: dispatches into one jit — beyond this the scan falls back to the
 #: uniform (version-keyed) block layout and lets compaction catch up
 _MAX_PLAN_BLOCKS = 64
+
+#: how often a statement retakes a scan whose snapshot expired under it
+#: (each retake needs another DROP/TRUNCATE to land inside the statement)
+_SCAN_RETAKES = 4
 
 
 def _block_plan(scan) -> list[_BlockEntry]:
@@ -1603,15 +1630,47 @@ class PhysicalExecutor:
         (query_tier_total), once, where that is final: `cache` when the
         partial-aggregate cache served every part and no kernel ran,
         else the effective last_tier."""
-        from greptimedb_tpu.utils.metrics import QUERY_TIER
+        from greptimedb_tpu.utils.metrics import AGG_SCAN, QUERY_TIER
 
         self._tls.__dict__.pop("last_tier", None)
+        self._tls.__dict__.pop("agg_scan_mode", None)
         self.last_partial_stats = None
         res = self._execute(plan)
         stats = self.last_partial_stats
         QUERY_TIER.inc(tier="cache" if stats and not stats["delta_rows"]
                        else self.last_tier)
+        mode = self._tls.__dict__.get("agg_scan_mode")
+        if mode is not None:
+            AGG_SCAN.inc(mode=mode)
         return res
+
+    def _whole_columns(self, scan, table) -> None:
+        """The consumer ahead reads whole columns: build them now (every
+        missing part decoded in one fan-out, columns concatenated in
+        parallel — storage/region.py ScanData.materialize) and as what
+        it is, scan work: a `scan` stage segment wherever in the
+        statement the need arises, so the decode never hides in the
+        stage that happened to touch a column first."""
+        if scan is None or scan.materialized:
+            return
+        before = scan_io_counters()[1]
+        with tracing.stage("scan", table=table.name, regions=1,
+                           whole=True) as attrs:
+            scan.materialize()
+            attrs["rows_decoded"] = scan_io_counters()[1] - before
+
+    def _note_agg_scan(self, mode: str) -> None:
+        """What an aggregate statement asked of its scan, for
+        greptimedb_tpu_agg_scan_total: `none` (the incremental path
+        fetched no SST part: every partial was cached), `parts` (it
+        fetched the parts it missed, and only those), `whole` (whole
+        columns were built or read: every other path). A statement
+        that scans more than once (bucket top-k) counts its most
+        expensive scan."""
+        rank = ("none", "parts", "whole")
+        prev = self._tls.__dict__.get("agg_scan_mode")
+        if prev is None or rank.index(mode) > rank.index(prev):
+            self._tls.agg_scan_mode = mode
 
     def _execute(self, plan: lp.LogicalPlan) -> QueryResult:
         # unwrap the linear chain
@@ -1653,6 +1712,28 @@ class PhysicalExecutor:
         tag_preds = extract_tag_predicates(where, table.schema) or None
 
         def run(ts_range):
+            # one scan = one snapshot; a snapshot whose files died
+            # between plan and fetch (DROP / TRUNCATE under the
+            # statement) is retaken, so the answer is that of a later,
+            # whole snapshot — never an error, never part of one
+            self.last_partial_stats = None
+            parts0 = scan_io_counters()[0]
+            for attempt in range(_SCAN_RETAKES):
+                try:
+                    res = run_once(ts_range)
+                    break
+                except ScanExpired:
+                    if attempt == _SCAN_RETAKES - 1:
+                        raise
+            if agg is not None:
+                stats = self.last_partial_stats
+                self._note_agg_scan(
+                    "whole" if stats is None
+                    else "parts" if scan_io_counters()[0] > parts0
+                    else "none")
+            return res
+
+        def run_once(ts_range):
             # lastpoint pruning: an all-`last` aggregate grouped by one
             # tag only needs each series' newest rows — the region walks
             # SSTs newest-first and stops early (Region.scan_last) in
@@ -1692,9 +1773,18 @@ class PhysicalExecutor:
                     and len(table.region_ids) == 1):
                 from greptimedb_tpu import config
 
-                stream = self.engine.scan_stream(
-                    table.region_ids[0], ts_range, scan_node.columns,
-                    tag_preds)
+                # the row estimate is metadata; only a scan big enough
+                # to stream takes the stream's snapshot (a second
+                # memtable copy and pin) — every other request's one
+                # snapshot is the scan below
+                estimate = getattr(self.engine, "estimate_rows", None)
+                stream = None
+                if estimate is None or estimate(
+                        table.region_ids[0], ts_range) \
+                        >= config.stream_threshold_rows():
+                    stream = self.engine.scan_stream(
+                        table.region_ids[0], ts_range, scan_node.columns,
+                        tag_preds)
                 if stream is not None:
                     if stream.est_rows >= config.stream_threshold_rows():
                         tier = self.tier_for(agg, stream.est_rows,
@@ -1715,6 +1805,7 @@ class PhysicalExecutor:
                     else:
                         stream.close()
 
+            decoded0 = scan_io_counters()[1]
             with tracing.stage("scan", table=table.name,
                                regions=len(table.region_ids)) as scan_attrs:
                 if len(table.region_ids) == 1:
@@ -1733,23 +1824,35 @@ class PhysicalExecutor:
                         ]
                     )
                 # rows land on the span (and, through it, the resource
-                # ledger's rows_scanned)
+                # ledger's rows_scanned): the snapshot's rows, and beside
+                # them the rows this stage decoded from SSTs — a full
+                # scan is a plan, its parts decode when first asked for
+                # (then in a `scan` segment of their own)
                 scan_attrs["rows"] = 0 if scan is None else scan.num_rows
+                scan_attrs["rows_decoded"] = \
+                    scan_io_counters()[1] - decoded0
 
             nrows = 0 if scan is None else scan.num_rows
-            if agg is not None:
-                # tier decision happens INSIDE _execute_agg, after the
-                # boundary fast path has (possibly) shrunk the scan
-                with tracing.span("aggregate", rows=nrows):
-                    return self._execute_agg(scan, table, where, agg,
-                                             having, project, sort, limit,
-                                             offset, scan_node)
-            tier = self.tier_for(None, nrows)
-            self.last_tier = tier
-            with tracing.span("filter_project", rows=nrows, tier=tier), \
-                    _TierCtx(tier):
-                return self._execute_raw(scan, table, where, project, sort,
-                                         limit, offset)
+            try:
+                if agg is not None:
+                    # tier decision happens INSIDE _execute_agg, after
+                    # the boundary fast path has (possibly) shrunk the
+                    # scan
+                    with tracing.span("aggregate", rows=nrows):
+                        return self._execute_agg(
+                            scan, table, where, agg, having, project,
+                            sort, limit, offset, scan_node)
+                self._whole_columns(scan, table)
+                tier = self.tier_for(None, nrows)
+                self.last_tier = tier
+                with tracing.span("filter_project", rows=nrows,
+                                  tier=tier), _TierCtx(tier):
+                    return self._execute_raw(scan, table, where, project,
+                                             sort, limit, offset)
+            finally:
+                if scan is not None:
+                    # a plan nobody read to the end gives its pins back
+                    scan.close()
 
         # bucket-top-k narrowing: ORDER BY <time bucket> DESC/ASC LIMIT k
         # only needs the k newest/oldest buckets — scan those, and widen
@@ -1992,6 +2095,8 @@ class PhysicalExecutor:
         extra_cols: dict[str, np.ndarray] = {}
         # host factorization of the group keys (date_bin over every
         # scanned row included) is aggregation work done on the host
+        if not all(_key_from_plan(kexpr, ctx) for _, kexpr in agg.keys):
+            self._whole_columns(scan, table)  # factorized over the rows
         with tracing.stage("host_agg", step="group_keys"):
             for i, (name, kexpr) in enumerate(agg.keys):
                 dk, decode = self._plan_key(i, kexpr, ctx, scan, scan_node,
@@ -2055,6 +2160,7 @@ class PhysicalExecutor:
                 sort, limit, offset, spec_slot, sparse)
             if res is not None:
                 return res
+            self._whole_columns(scan, table)  # the classic kernels' input
         if reduced is not None:
             scan = reduced
         # tier re-decision on the POST-reduction row count: the
@@ -2110,6 +2216,8 @@ class PhysicalExecutor:
         except pc.PartialCacheIneligible:
             PARTIAL_AGG_CACHE_EVENTS.inc(event="fallback")
             return None
+        except ScanExpired:
+            raise  # not a failure of this path: run() retakes the scan
         except PlanError:
             # a planning error (e.g. a substituted rollup plan probing a
             # column the companion scan lacks) is the GUARDED-FALLBACK
@@ -2182,14 +2290,20 @@ class PhysicalExecutor:
         # falling back (value-keyed partials never materialize [G, F])
         use_sparse = sparse or num_groups > pc.groups_max()
         # DELETE voids the decomposition exactly like scan_last: a
-        # tombstone may mask rows in a different part (memoized on the
-        # snapshot, shared with the boundary fast path)
-        has_delete = getattr(scan, "_has_delete", None)
-        if has_delete is None:
-            from greptimedb_tpu.storage.region import OP_PUT
-
-            has_delete = bool((scan.op_type != OP_PUT).any())
-            scan._has_delete = has_delete
+        # tombstone may mask rows in a different part
+        # (memoized on the snapshot, shared with the boundary fast path).
+        # Decided per part, and for a part whose bytes this request
+        # never reads from what the region noted when the file was
+        # written or first decoded (ScanData.has_delete): SSTs are
+        # immutable, so a file once known delete-free stays so. A DELETE
+        # acknowledged after a partial was cached sits in the memtable
+        # slice of this snapshot, or in a file flushed since — a new
+        # file, whose flag was noted at its flush — so it is seen here
+        # and the typed fallback below answers exactly; an all-hit
+        # answer never passes a tombstone by.
+        with tracing.stage("scan", table=table.name, regions=1,
+                           step="tombstone_probe"):
+            has_delete = scan.has_delete()
         if has_delete:
             raise pc.PartialCacheIneligible("tombstones reachable")
 
@@ -2217,11 +2331,11 @@ class PhysicalExecutor:
         # part/memtable ts extents prove the dedup part-local — the
         # sliced global mask then equals the part's own LWW mask
         # bit-for-bit. Overlapping extents (late writes) fall back.
-        dedup_mask = None
-        if not table.append_mode and scan.needs_dedup:
-            if not self._parts_ts_disjoint(scan, ts_name):
-                raise pc.PartialCacheIneligible("cross-part dedup")
-            dedup_mask = self._maybe_dedup(scan, table, ctx)
+        # The mask itself is whole-scan work over whole columns, so it
+        # is only built when a part actually has to be computed.
+        if not table.append_mode and scan.needs_dedup \
+                and not self._parts_ts_disjoint(scan, ts_name):
+            raise pc.PartialCacheIneligible("cross-part dedup")
 
         acc_dtype = jnp.dtype(config.compute_dtype())
         ops_t = tuple(sorted(ops))
@@ -2274,13 +2388,16 @@ class PhysicalExecutor:
                   acc_dtype=acc_dtype)
         strides = _strides([k.size for k in keys])
 
+        def cast_of(name):
+            return acc_dtype if name in float_fields else None
+
         def fetch_cols(entry):
-            return {name: self._device_block(
-                        scan, name, entry, extra_cols,
-                        acc_dtype if name in float_fields else None)
+            return {name: self._device_block(scan, name, entry, extra_cols,
+                                             cast_of(name))
                     for name in col_names}
 
         def entry_dmask(entry):
+            dedup_mask = self._maybe_dedup(scan, table, ctx)
             return None if dedup_mask is None else _pad_device_mask(
                 dedup_mask, entry.start, entry.end, entry.block)
 
@@ -2349,15 +2466,51 @@ class PhysicalExecutor:
                 else mem_entries[0],
                 compute_partial)
 
+        # bytes on demand: only a missed part whose column blocks are
+        # not all in the HBM hot set needs its rows. Those decode a wave
+        # of the scan pool's width at a time — in parallel, as the
+        # whole-scan decode ran — and are held just while the wave's
+        # partials compute; nothing is concatenated. It is scan work
+        # wherever it happens: the fetch is a `scan` stage segment
+        # inside the fold
+        def needs_rows(entry):
+            with place(entry.pkey[0]):
+                return any(
+                    not self.cache.resident(self._hot_key(
+                        scan, entry, name, str(cast_of(name))))
+                    for name in col_names if name not in extra_cols)
+
+        def fetch_wave(wave):
+            ranges = [(e.start, e.end) for _k, e in wave if needs_rows(e)]
+            if not ranges or scan.materialized:
+                return None
+            before = scan_io_counters()[1]
+            with tracing.stage("scan", table=table.name, regions=1,
+                               on_demand=True) as attrs:
+                handle = scan.hold_rows(ranges)
+                attrs["rows_decoded"] = scan_io_counters()[1] - before
+            return handle
+
+        missed = [(key, entry) for key, entry, p in probed if p is None]
+        width = scan.fetch_width(len(missed))
+        computed: dict[tuple, dict] = {}
+        for at in range(0, len(missed), width):
+            wave = missed[at:at + width]
+            handle = fetch_wave(wave)
+            try:
+                for key, entry in wave:
+                    epoch = cache.epoch(scan.region_id)
+                    with place(key[2]):
+                        computed[key] = compute_partial(entry)
+                    cache.put(key, computed[key], epoch=epoch)
+            finally:
+                scan.release_rows(handle)
         partials: list[dict] = []
         hits = misses = 0
         delta_rows = cached_rows = 0
         for key, entry, p in probed:
             if p is None:
-                epoch = cache.epoch(scan.region_id)
-                with place(key[2]):
-                    p = compute_partial(entry)
-                cache.put(key, p, epoch=epoch)
+                p = computed[key]
                 misses += 1
                 delta_rows += entry.end - entry.start
             else:
@@ -2419,6 +2572,11 @@ class PhysicalExecutor:
                     compute_partial(entry)
                 with self._warm_lock:
                     self._device_warm.add(fp)
+            except ScanExpired:
+                # the request is over and the snapshot died before this
+                # thread read its part: nothing was learned about the
+                # device — a later request's hedge warms the shape
+                pass
             except Exception:  # noqa: BLE001 — hedge must not raise
                 _note_degradation(
                     "warmup_failed",
@@ -2438,21 +2596,12 @@ class PhysicalExecutor:
     def _parts_ts_disjoint(self, scan, ts_name: str) -> bool:
         """Whether every SST part's ts extent (and the memtable tail's)
         is pairwise disjoint — the proof that LWW dedup cannot cross a
-        part seam. One O(N) min/max pass, memoized on the snapshot."""
+        part seam. From FileMeta for a part not read yet, else one O(N)
+        min/max pass; memoized on the snapshot."""
         cached = getattr(scan, "_parts_ts_disjoint_cache", None)
         if cached is not None:
             return cached
-        offs = list(scan.sorted_part_offsets) or [0]
-        if offs[-1] < scan.num_rows:
-            offs.append(scan.num_rows)  # memtable tail interval
-        ts = scan.columns[ts_name]
-        spans = []
-        for i in range(len(offs) - 1):
-            s0, s1 = offs[i], offs[i + 1]
-            if s1 > s0:
-                seg = ts[s0:s1]
-                spans.append((int(seg.min()), int(seg.max())))
-        spans.sort()
+        spans = sorted(scan.segment_ts_extents(ts_name))
         ok = all(spans[i][1] < spans[i + 1][0]
                  for i in range(len(spans) - 1))
         scan._parts_ts_disjoint_cache = ok
@@ -2651,13 +2800,8 @@ class PhysicalExecutor:
         cached = getattr(scan, "_boundary_fl_cache", None)
         if cached is not None:
             return cached if cached is not False else None
-        has_delete = getattr(scan, "_has_delete", None)
-        if has_delete is None:
-            from greptimedb_tpu.storage.region import OP_PUT
-
-            has_delete = bool((scan.op_type != OP_PUT).any())
-            scan._has_delete = has_delete
-        if has_delete:
+        self._whole_columns(scan, table)  # run boundaries over the rows
+        if scan.has_delete():
             scan._boundary_fl_cache = False
             return None
 
@@ -2958,14 +3102,11 @@ class PhysicalExecutor:
                 return out, DataType.STRING
 
             return DeviceKey("tag", name, len(values) + 1), decode_tag
-        if (isinstance(kexpr, ast.FuncCall) and kexpr.name in ("date_bin", "time_bucket")
-                and isinstance(kexpr.args[0], ast.Interval)
-                and isinstance(kexpr.args[1], ast.Column)
-                and kexpr.args[1].name == ts_col.name):
+        if _is_time_bucket(kexpr, ts_col.name):
             unit = ts_col.dtype.time_unit.nanos_per_unit
             step = max(kexpr.args[0].nanos // unit, 1)
-            lo, hi = self._ts_bounds(scan_node, None,
-                                     fallback=(stream.ts_min, stream.ts_max))
+            lo, hi = self._ts_bounds(
+                scan_node, lambda: (stream.ts_min, stream.ts_max))
             base = int(np.floor_divide(lo, step))
             size = int(np.floor_divide(hi, step)) - base + 1
 
@@ -3048,15 +3189,11 @@ class PhysicalExecutor:
                 return out, DataType.STRING
 
             return DeviceKey("tag", name, card + 1), decode_tag
-        if (isinstance(kexpr, ast.FuncCall) and kexpr.name in ("date_bin", "time_bucket")
-                and isinstance(kexpr.args[0], ast.Interval)
-                and isinstance(kexpr.args[1], ast.Column)
-                and kexpr.args[1].name == ts_col.name):
+        if _is_time_bucket(kexpr, ts_col.name):
             unit = ts_col.dtype.time_unit.nanos_per_unit
             step = max(kexpr.args[0].nanos // unit, 1)
-            ts_arr = scan.columns[ts_col.name]
-            lo, hi = self._ts_bounds(scan_node, ts_arr)
-            base = lo // step - (1 if lo % step and lo < 0 else 0)
+            lo, hi = self._ts_bounds(
+                scan_node, lambda: scan.ts_extent(ts_col.name))
             base = int(np.floor_divide(lo, step))
             size = int(np.floor_divide(hi, step)) - base + 1
 
@@ -3087,15 +3224,18 @@ class PhysicalExecutor:
 
         return DeviceKey("pre", colname, max(len(uniq), 1)), decode_pre
 
-    def _ts_bounds(self, scan_node, ts_arr, fallback=None) -> tuple[int, int]:
+    def _ts_bounds(self, scan_node, extent) -> tuple[int, int]:
+        """Bucket-key bounds: the statement's own range where it gives
+        one; `extent()` -> (min, max) of the scanned rows is only asked
+        for a side the statement leaves open."""
         lo = hi = None
         if scan_node.ts_range is not None:
             lo, hi0 = scan_node.ts_range
             hi = None if hi0 is None else hi0 - 1
-        if lo is None:
-            lo = int(ts_arr.min()) if ts_arr is not None else fallback[0]
-        if hi is None:
-            hi = int(ts_arr.max()) if ts_arr is not None else fallback[1]
+        if lo is None or hi is None:
+            data_lo, data_hi = extent()
+            lo = data_lo if lo is None else lo
+            hi = data_hi if hi is None else hi
         return lo, hi
 
     def _stream_agg(self, scan: ScanData, table, bound_where, keys, arg_exprs,
@@ -3871,8 +4011,9 @@ class PhysicalExecutor:
         start, end, block = entry.start, entry.end, entry.block
 
         def build():
-            src = extra_cols[name] if name in extra_cols else scan.columns[name]
-            arr = pad_rows(src[start:end], block)
+            rows = extra_cols[name][start:end] if name in extra_cols \
+                else scan.rows(name, start, end)
+            arr = pad_rows(rows, block)
             if cast_dtype is not None and arr.dtype != cast_dtype:
                 arr = arr.astype(cast_dtype)
             return jnp.asarray(arr)

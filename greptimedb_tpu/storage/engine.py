@@ -395,6 +395,10 @@ class RegionEngine:
         fn = getattr(region, "scan_last", None)
         return None if fn is None else fn(group_tag, projection)
 
+    def estimate_rows(self, region_id: int, ts_range=None) -> int:
+        """Upper bound on a scan's rows from metadata only."""
+        return self.region(region_id).estimate_rows(ts_range)
+
     def ts_extent(self, region_id: int):
         """(min, max) data timestamps from metadata only (no data read)."""
         return self.region(region_id).ts_extent()

@@ -13,12 +13,16 @@ edit, WAL truncation.
 
 from __future__ import annotations
 
+import bisect
+import contextlib
 import itertools
 import os
 import threading
 import time
+import weakref
 from collections import OrderedDict
-from dataclasses import dataclass
+from dataclasses import dataclass, field
+from collections.abc import Mapping
 from typing import Optional, Sequence
 
 import numpy as np
@@ -54,9 +58,14 @@ class _PartEntry:
 
 
 def _scan_nbytes(sd: "ScanData") -> int:
-    """Host bytes a whole-scan snapshot holds (column arrays + seq/op).
-    Object columns undercount their string payload — the budget errs
-    permissive there, like the part cache does."""
+    """Host bytes a whole-scan snapshot holds (column arrays + seq/op;
+    for a snapshot still in parts, the parts it holds). Object columns
+    undercount their string payload — the budget errs permissive there,
+    like the part cache does."""
+    parts = sd._parts
+    if parts is not None:
+        return sum(_part_nbytes(p) for p in parts.loaded if p is not None) \
+            + (_part_nbytes(parts.mem) if parts.mem is not None else 0)
     n = 0
     for v in sd.columns.values():
         if isinstance(v, np.ndarray):
@@ -76,9 +85,230 @@ def _part_nbytes(part: Optional[tuple]) -> int:
         + int(seq.nbytes) + int(op.nbytes)
 
 
+class ScanExpired(RuntimeError):
+    """A scan's bytes were asked for after its snapshot died: the region
+    was dropped or truncated (which deletes SSTs whatever pins them), or
+    a file left the purge queue while no pin held it. The rows the plan
+    promised cannot be read any more; the consumer takes a fresh scan
+    (query/physical.py re-runs the statement's scan) — never a partial
+    answer."""
+
+
+#: per-thread scan IO tally: (SST parts fetched through the part cache,
+#: rows decoded from SSTs). The executor reads the difference around a
+#: statement to say what the scan really cost it (the `scan` stage's
+#: rows_decoded, greptimedb_tpu_agg_scan_total's mode); pool workers
+#: decode on other threads, so the request thread counts for them in
+#: _cached_parts
+_SCAN_IO = threading.local()
+
+
+def scan_io_counters() -> tuple[int, int]:
+    return (getattr(_SCAN_IO, "parts", 0), getattr(_SCAN_IO, "rows", 0))
+
+
+class _PlanPins:
+    """The SST pins a scan plan holds from plan to fetch (the reference's
+    FilePurger refcount held by a scan's file handles): compaction may
+    swap the files out meanwhile, and the purge waits. Released exactly
+    once — when every part has been fetched, on close(), or when the
+    plan is garbage (weakref.finalize), so a plan nobody reads cannot
+    hold a file for ever."""
+
+    def __init__(self, region: "Region", metas: list):
+        self._region = region
+        self._metas = metas
+        self._lock = threading.Lock()
+
+    def release(self) -> None:
+        with self._lock:
+            metas, self._metas = self._metas, None
+        if metas:
+            self._region._unpin_files(metas)
+
+
+class _ScanParts:
+    """One scan snapshot BEFORE concatenation: its row segments in scan
+    order — one per contributing SST, then the memtable slice. A segment
+    of a window- or predicate-pruned scan is decoded when the scan is
+    taken (its row count is only known then); a segment of a full scan
+    is the whole immutable file, so its row count comes from FileMeta
+    and its bytes are fetched — through the per-file part cache and the
+    decode pool — only when a consumer asks for them. Fetched segments
+    are not kept here (the part cache is the memo, within its budget);
+    `hold`/`release` keep a working set for a consumer walking parts."""
+
+    def __init__(self, region: "Region", names: list, pred_key,
+                 metas: list, rows: list, loaded: list, mem, cache_key):
+        self.region = region
+        self.names = names
+        self.pred_key = pred_key
+        self.metas = metas
+        self.loaded = loaded
+        self.mem = mem
+        self.cache_key = cache_key
+        lens = list(rows) + ([len(mem[1])] if mem is not None else [])
+        self.offsets = [0]
+        for n in lens:
+            self.offsets.append(self.offsets[-1] + int(n))
+        self._lock = threading.Lock()
+        self._held: dict[int, tuple] = {}
+        self.pins: Optional[_PlanPins] = None
+        # serializes ScanData.materialize (a parked snapshot is shared)
+        self.build_lock = threading.Lock()
+        # the owning ScanData's stats dict: fetches add what they cost
+        self.stats: Optional[dict] = None
+
+    def _segment(self, i: int) -> Optional[tuple]:
+        if i == len(self.metas):
+            return self.mem
+        part = self.loaded[i]
+        if part is None:
+            with self._lock:
+                part = self._held.get(i)
+        return part
+
+    def hold(self, idxs) -> None:
+        """Fetch the not-yet-loaded segments among `idxs` in ONE parallel
+        decode (part-cache hits are free) and keep them until release."""
+        need = [i for i in dict.fromkeys(idxs)
+                if i < len(self.metas) and self._segment(i) is None]
+        if not need:
+            return
+        parts, cost = self.region._fetch_parts(
+            [self.metas[i] for i in need], self.names, self.pred_key)
+        st = self.stats
+        if st is not None:
+            with self._lock:
+                st["part_hits"] += cost["part_hits"]
+                st["files_decoded"] += cost["files_decoded"]
+                st["decode_s"] = round(st["decode_s"] + cost["decode_s"], 4)
+                st["decode_workers"] = max(st["decode_workers"],
+                                           cost["decode_workers"])
+        for i, part in zip(need, parts):
+            want = self.offsets[i + 1] - self.offsets[i]
+            if part is None or len(part[1]) != want:
+                raise ScanExpired(
+                    f"sst {self.metas[i].file_id} no longer holds the "
+                    f"{want} rows its plan counted")
+        with self._lock:
+            self._held.update(zip(need, parts))
+
+    def release(self, idxs=None) -> None:
+        with self._lock:
+            if idxs is None:
+                self._held.clear()
+            else:
+                for i in idxs:
+                    self._held.pop(i, None)
+
+    def part(self, i: int) -> tuple:
+        part = self._segment(i)
+        if part is None:
+            self.hold([i])
+            part = self._segment(i)
+            if part is None:  # released under us by another thread
+                return self.part(i)
+        return part
+
+    def segment_of(self, start: int, end: int) -> Optional[int]:
+        """Index of the one segment holding rows [start, end), or None
+        when the range crosses a seam."""
+        i = bisect.bisect_right(self.offsets, start) - 1
+        if 0 <= i < len(self.offsets) - 1 and end <= self.offsets[i + 1]:
+            return i
+        return None
+
+    def all_parts(self) -> list:
+        """Every non-empty segment in order, fetched in one fan-out."""
+        n = len(self.metas)
+        self.hold(range(n))
+        out = [self.part(i) for i in range(n)
+               if self.offsets[i + 1] > self.offsets[i]]
+        if self.mem is not None:
+            out.append(self.mem)
+        return out
+
+    def has_delete(self) -> bool:
+        """Whether any row of the snapshot is a tombstone, WITHOUT
+        reading a file whose answer the region already knows: SSTs are
+        immutable, so `Region._file_deletes` (noted when a file is
+        written or first decoded whole) stands for the file's life.
+        Unknown means read it."""
+        if self.mem is not None and bool((self.mem[2] != OP_PUT).any()):
+            return True
+        unknown = []
+        for i, meta in enumerate(self.metas):
+            part = self._segment(i)
+            if part is not None:
+                if bool((part[2] != OP_PUT).any()):
+                    return True
+                continue
+            known = self.region._file_deletes.get(meta.file_id)
+            if known:
+                return True
+            if known is None:
+                unknown.append(i)
+        return bool(unknown) and self.region._files_have_delete(
+            [self.metas[i] for i in unknown])
+
+    def ts_extents(self, ts_name: str) -> list:
+        """(min, max) timestamp of every non-empty segment: a whole
+        file's from its FileMeta (exact: written from the same rows), a
+        decoded segment's from its rows."""
+        spans = []
+        for i in range(len(self.offsets) - 1):
+            if self.offsets[i + 1] <= self.offsets[i]:
+                continue
+            part = self._segment(i)
+            if part is None:
+                spans.append((self.metas[i].ts_min, self.metas[i].ts_max))
+            else:
+                ts = part[0][ts_name]
+                spans.append((int(ts.min()), int(ts.max())))
+        return spans
+
+    def close(self) -> None:
+        self.release()
+        if self.pins is not None:
+            self.pins.release()
+
+
+class _LazyColumns(Mapping):
+    """`ScanData.columns` of a snapshot still in parts: the names are
+    known, a value is read by concatenating — which builds every column
+    at once, fanned across the scan pool, exactly the whole-scan
+    assembly `Region.scan` used to do up front."""
+
+    def __init__(self, scan: "ScanData", names: list):
+        self._scan = scan
+        self._names = names
+
+    def __getitem__(self, name):
+        if name not in self._names:
+            raise KeyError(name)
+        return self._scan.materialize().columns[name]
+
+    def __iter__(self):
+        return iter(self._names)
+
+    def __len__(self):
+        return len(self._names)
+
+
 @dataclass
 class ScanData:
-    """Host-side scan output: concatenated columns ready for device blocks.
+    """Host-side scan output: one consistent snapshot of a region's rows.
+
+    A scan is a plan first and bytes on demand. What every consumer can
+    read for free is the plan: row counts, part offsets and identities,
+    tag dictionaries, version. `columns` / `seq` / `op_type` are the
+    concatenated whole-scan arrays, ready for device blocks; on a
+    snapshot still in parts (`_parts`) they are built at first touch —
+    the same parallel decode, the same concatenation, the same snapshot
+    cache as before, so a consumer of whole columns pays what it always
+    paid. A consumer that walks parts (`rows`, `hold_rows`) never
+    causes the concatenation, and reads only the parts it asks for.
 
     Tags are int32 codes against `tag_dicts`; rows are NOT yet deduplicated
     or exactly time-filtered — `seq`/`op_type` ride along so the device
@@ -86,10 +316,10 @@ class ScanData:
     the reference's MergeReader output contract, read.rs:59-73)."""
 
     schema: Schema
-    columns: dict[str, np.ndarray]
-    seq: np.ndarray
-    op_type: np.ndarray
-    tag_dicts: dict[str, np.ndarray]
+    columns: Mapping = field(repr=False)
+    seq: Optional[np.ndarray] = field(repr=False)
+    op_type: Optional[np.ndarray] = field(repr=False)
+    tag_dicts: dict[str, np.ndarray] = field(repr=False)
     num_rows: int
     needs_dedup: bool = True
     # identity for the device block cache: (region_id, incarnation,
@@ -118,15 +348,166 @@ class ScanData:
     # () = no per-part identity (merged/synthetic/seq-sliced scans).
     part_keys: tuple = ()
     # observability: how this snapshot was built (ssts considered /
-    # pruned, scan-cache reuse count) — piggybacked on the region wire
-    # protocol so distributed EXPLAIN ANALYZE shows datanode-side IO.
-    # None for synthetic/merged scans. Mutated only under the region
-    # lock (cache_hits bumps on each cached reuse).
+    # pruned, scan-cache reuse count, files decoded so far) — piggybacked
+    # on the region wire protocol so distributed EXPLAIN ANALYZE shows
+    # datanode-side IO. None for synthetic/merged scans. Mutated only
+    # under the region lock (cache_hits bumps on each cached reuse).
     stats: Optional[dict] = None
+
+    #: the snapshot's un-concatenated form, while it has one
+    _parts = None
 
     @property
     def tag_cardinalities(self) -> dict[str, int]:
         return {k: len(v) for k, v in self.tag_dicts.items()}
+
+    @property
+    def materialized(self) -> bool:
+        return self._parts is None
+
+    def materialize(self) -> "ScanData":
+        """Build the whole-scan columns (idempotent; returns self). Every
+        missing part decodes in one fan-out across the scan pool and the
+        columns concatenate in parallel; the snapshot then joins the
+        region's snapshot cache, as a scan decoded up front always did."""
+        parts = self._parts
+        if parts is None:
+            return self
+        with parts.build_lock:
+            if self._parts is None:
+                return self
+            try:
+                segs = parts.all_parts()
+            except BaseException:
+                # a failed read leaves no file pinned behind it (a
+                # retry pins again, or finds the snapshot expired)
+                parts.close()
+                raise
+            if len(segs) == 1:
+                # single part (one big SST, or memtable only): a concat
+                # would copy ~the whole table for nothing — cold scans at
+                # the TSBS 17M-row scale spend seconds here otherwise
+                cols, seq, op = segs[0]
+                columns = {n: cols[n] for n in parts.names}
+            else:
+                columns = parts.region._concat_columns(
+                    parts.names, [p[0] for p in segs])
+                seq = np.concatenate([p[1] for p in segs])
+                op = np.concatenate([p[2] for p in segs])
+            self.columns = columns
+            self.seq = seq
+            self.op_type = op
+            self._parts = None
+            parts.close()
+            if parts.cache_key is not None:
+                with parts.region._lock:
+                    parts.region._scan_cache_put(parts.cache_key, self)
+        return self
+
+    def close(self) -> None:
+        """Release what a plan holds for fetches to come (file pins, the
+        held working set). Idempotent; the snapshot stays readable — a
+        later fetch pins again, or raises ScanExpired."""
+        parts = self._parts
+        if parts is not None:
+            parts.close()
+
+    def rows(self, name: str, start: int, end: int) -> np.ndarray:
+        """Rows [start, end) of one column. Inside one part of a
+        snapshot still in parts this reads that part alone."""
+        parts = self._parts
+        if parts is not None:
+            i = parts.segment_of(start, end)
+            if i is not None:
+                off = parts.offsets[i]
+                return parts.part(i)[0][name][start - off:end - off]
+        return self.columns[name][start:end]
+
+    def hold_rows(self, ranges) -> Optional[list]:
+        """Fetch, in one parallel decode, the parts holding the given
+        (start, end) row ranges, and keep them until `release_rows`
+        gets the returned handle. No-op on a materialized snapshot."""
+        parts = self._parts
+        if parts is None:
+            return None
+        idxs = [i for i in (parts.segment_of(s, e) for s, e in ranges)
+                if i is not None]
+        parts.hold(idxs)
+        return idxs
+
+    def fetch_width(self, n: int) -> int:
+        """How many parts one `hold_rows` should ask for at a time: the
+        decode fan-out the region's scan pool gives `n` files."""
+        parts = self._parts
+        if parts is None:
+            return max(n, 1)
+        from greptimedb_tpu.storage import scan_pool
+
+        return scan_pool.resolve(parts.region.decode_threads, max(n, 1))
+
+    def release_rows(self, handle) -> None:
+        parts = self._parts
+        if parts is not None and handle:
+            parts.release(handle)
+
+    def has_delete(self) -> bool:
+        """Whether the snapshot holds any tombstone (memoized)."""
+        memo = self.__dict__.get("_has_delete")
+        if memo is None:
+            parts = self._parts
+            memo = parts.has_delete() if parts is not None \
+                else bool((self.op_type != OP_PUT).any())
+            self._has_delete = memo
+        return memo
+
+    def ts_extent(self, ts_name: str) -> tuple[int, int]:
+        """(min, max) timestamp over the snapshot's rows."""
+        spans = self.segment_ts_extents(ts_name)
+        return (min(s[0] for s in spans), max(s[1] for s in spans))
+
+    def segment_ts_extents(self, ts_name: str) -> list:
+        """(min, max) timestamp per non-empty SST part, then the
+        memtable tail's."""
+        parts = self._parts
+        if parts is not None:
+            return parts.ts_extents(ts_name)
+        offs = list(self.sorted_part_offsets) or [0]
+        if offs[-1] < self.num_rows:
+            offs.append(self.num_rows)  # memtable tail interval
+        ts = self.columns[ts_name]
+        spans = []
+        for i in range(len(offs) - 1):
+            s0, s1 = offs[i], offs[i + 1]
+            if s1 > s0:
+                seg = ts[s0:s1]
+                spans.append((int(seg.min()), int(seg.max())))
+        return spans
+
+
+def _built_at_first_touch(attr: str) -> property:
+    """`columns` / `seq` / `op_type` of a ScanData: plain attributes on
+    a materialized snapshot; on one still in parts, `seq` / `op_type`
+    materialize it and `columns` is a view that does so when a value is
+    read (its names are free). The view is made per access and not
+    kept, so a plan and its view form no reference cycle and a dropped
+    plan releases its pins at once."""
+
+    def get(self):
+        if self._parts is not None:
+            if attr == "_columns":
+                return _LazyColumns(self, self._parts.names)
+            self.materialize()
+        return self.__dict__[attr]
+
+    def put(self, value):
+        self.__dict__[attr] = value
+
+    return property(get, put)
+
+
+ScanData.columns = _built_at_first_touch("_columns")
+ScanData.seq = _built_at_first_touch("_seq")
+ScanData.op_type = _built_at_first_touch("_op_type")
 
 
 @dataclass
@@ -230,6 +611,14 @@ class Region:
         self._part_cache: "OrderedDict[tuple, _PartEntry]" = OrderedDict()
         self._part_cache_bytes = 0
         self.part_cache_budget = 1 << 30  # overridden from EngineConfig
+        # file_id -> does the SST hold any non-PUT row? Noted when the
+        # file is written (flush, compaction) or first decoded whole;
+        # SSTs are immutable, so the answer stands until the file dies
+        # (_invalidate_file_parts). Lets a scan plan answer "any
+        # tombstone?" without reading op_type of files it will not
+        # otherwise touch. Absent = unknown (files from before a
+        # restart): the asker reads the file.
+        self._file_deletes: dict[str, bool] = {}
         # SST decode fan-out cap; 0 = auto (storage/scan_pool.py)
         self.decode_threads = 0
         # ---- group-commit ingest pipeline (storage/group_commit.py) ----
@@ -424,6 +813,8 @@ class Region:
         for k in [k for k in self._part_cache if k[0] in gone]:
             ent = self._part_cache.pop(k)
             self._part_cache_bytes -= ent.nbytes
+        for fid in gone:
+            self._file_deletes.pop(fid, None)
         # the HBM columnar hot set keys device blocks by the same file
         # identity — the seams that kill host parts kill device blocks
         self._notify_device_cache("invalidate_files", gone)
@@ -689,6 +1080,7 @@ class Region:
             decoded, workers = self._decode_parts(
                 [file_list[i] for i in missing], ts_range, names,
                 tag_predicates)
+            whole = ts_range is None and not tag_predicates
             with self._lock:
                 for i, part in zip(missing, decoded):
                     ent = _PartEntry(part, _part_nbytes(part))
@@ -697,8 +1089,16 @@ class Region:
                     # may have been removed (and invalidated) while it
                     # decoded — inserting then would strand dead
                     # entries in the budget forever
-                    if insert and file_list[i].file_id in self.files:
+                    if file_list[i].file_id not in self.files:
+                        continue
+                    if insert:
                         self._part_cache_put(keys[i], ent)
+                    if whole and part is not None:
+                        self._file_deletes[file_list[i].file_id] = \
+                            bool((part[2] != OP_PUT).any())
+            _SCAN_IO.rows = getattr(_SCAN_IO, "rows", 0) + sum(
+                len(p[1]) for p in decoded if p is not None)
+        _SCAN_IO.parts = getattr(_SCAN_IO, "parts", 0) + len(file_list)
         from greptimedb_tpu.utils import ledger
 
         if hits:
@@ -719,6 +1119,59 @@ class Region:
             "decode_workers": workers,
             "decode_s": round(time.perf_counter() - t0, 4),
         }
+
+    @contextlib.contextmanager
+    def _reading_planned(self, metas):
+        """Pin a scan plan's files for a read made some time after the
+        plan was taken under the region lock. They are readable if they
+        still exist — live, or swapped out by compaction and waiting in
+        the purge queue (the same rows the snapshot saw). Otherwise the
+        snapshot is dead: ScanExpired, and the consumer scans afresh;
+        likewise when DROP/TRUNCATE, which deletes files whatever pins
+        them, lands under the read."""
+        with self._lock:
+            queued = {fid for fid, _ in self._purge_queue}
+            if self.dropped or any(
+                    m.file_id not in self.files and m.file_id not in queued
+                    for m in metas):
+                raise ScanExpired(
+                    f"region {self.region_id}: a planned sst is gone")
+            self._pin_files(metas)
+        try:
+            yield
+        except Exception as e:
+            if self.dropped:
+                raise ScanExpired(
+                    f"region {self.region_id} dropped under a scan") from e
+            raise
+        finally:
+            self._unpin_files(metas)
+
+    def _fetch_parts(self, metas, names, pred_key) -> tuple[list, dict]:
+        """Bytes on demand for a scan plan: the whole-file parts of
+        `metas`, through the part cache and the decode pool."""
+        with self._reading_planned(metas):
+            entries, cost = self._cached_parts(metas, None, names,
+                                               pred_key, None)
+        return [e.part for e in entries], cost
+
+    def _files_have_delete(self, metas) -> bool:
+        """Whether any of a plan's files holds a non-PUT row, for files
+        `_file_deletes` does not know yet (written before this process
+        opened the region): reads each file's op_type column alone —
+        not the scan's projection — and notes the answer for good."""
+        found = False
+        with self._reading_planned(metas):
+            for meta in metas:
+                table = self.sst_reader.read(meta, self.schema, None, [])
+                flag = table is not None and bool(
+                    (table.column(OP_COL).to_numpy(zero_copy_only=False)
+                     != OP_PUT).any())
+                with self._lock:
+                    if meta.file_id in self.files:
+                        self._file_deletes[meta.file_id] = flag
+                found = found or flag
+        return found
 
     # ---- write -------------------------------------------------------------
 
@@ -906,6 +1359,7 @@ class Region:
         }
         meta = self.sst_writer.write(sorted_cols, tag_dicts, seq[order], op[order])
         self.files[meta.file_id] = meta
+        self._file_deletes[meta.file_id] = bool((op != OP_PUT).any())
         self.manifest.record_flush([meta], flushed_seq=self.next_seq,
                                    tag_dicts=self.registry.snapshot())
         self.memtable = Memtable(self.schema, self.registry)
@@ -1016,6 +1470,8 @@ class Region:
             for fid in removed:
                 self.files.pop(fid, None)
             self.files[meta.file_id] = meta
+            self._file_deletes[meta.file_id] = \
+                bool((op[order] != OP_PUT).any())
             # the inputs' decoded parts die with them — a later scan
             # must decode the merged output, never concat stale inputs
             self._invalidate_file_parts(removed)
@@ -1118,8 +1574,9 @@ class Region:
         if not tag_predicates:
             ts_range = self._widen_covering_range(ts_range)
         # snapshot phase under the region lock: version + file list +
-        # memtable rows form one consistent view; SST decode (the slow
-        # part) runs outside, on immutable grace-protected files
+        # memtable rows form one consistent view (an acknowledged row is
+        # in a listed file or in `mem`); SST decode (the slow part) runs
+        # outside, on immutable pinned files
         with self._lock:
             version = self.data_version
             cache_key = (version, ts_range, tuple(names), pred_key)
@@ -1132,54 +1589,36 @@ class Region:
             file_list = list(self.files.values())
             self._pin_files(file_list)
             mem = self.memtable.concat(ts_range)
-        # parallel decode through the per-file part cache: misses fan
-        # across the shared pool, hits are free, and the assembly below
-        # preserves the exact serial part order (so LWW dedup, the
-        # sorted part_offsets contract, and fault propagation order all
-        # behave as the old one-file-at-a-time loop did)
-        try:
-            part_entries, decode_stats = self._cached_parts(
-                file_list, ts_range, names, pred_key, tag_predicates)
-        finally:
-            self._unpin_files(file_list)
-        parts_cols: list[dict[str, np.ndarray]] = []
-        parts_seq: list[np.ndarray] = []
-        parts_op: list[np.ndarray] = []
-        sst_part_lens: list[int] = []
-        part_keys: list[tuple] = []
-        for meta, ent in zip(file_list, part_entries):
-            if ent.part is None:
-                continue
-            cols, seq_col, op_col = ent.part
-            parts_cols.append(cols)
-            parts_seq.append(seq_col)
-            parts_op.append(op_col)
-            sst_part_lens.append(len(seq_col))
-            # device hot-set identity: a part's rows depend only on the
-            # immutable file + the window/predicate key (the inset
-            # filter below keeps whole series deterministically)
-            part_keys.append((meta.file_id, ts_range, pred_key))
-
+        # a FULL scan (no window left after widening, no predicates) is
+        # a plan: each part is a whole immutable file, so its row count
+        # is FileMeta's and nothing has to be decoded to lay the
+        # snapshot out. Its bytes come per part when a consumer asks
+        # (_ScanParts), the files pinned until then. A pruned scan only
+        # learns its row counts by decoding, so it decodes now — in
+        # parallel through the per-file part cache: misses fan across
+        # the shared pool, hits are free — and keeps the parts; neither
+        # concatenates before a consumer reads whole columns. Part order
+        # is the serial file order either way (so LWW dedup, the sorted
+        # part_offsets contract, and fault propagation order all behave
+        # as the old one-file-at-a-time loop did)
+        lazy = ts_range is None and not tag_predicates
+        if lazy:
+            metas = [m for m in file_list if m.num_rows > 0]
+            loaded: list = [None] * len(metas)
+            decode_stats = {"part_hits": 0, "files_decoded": 0,
+                            "decode_workers": 0, "decode_s": 0.0}
+        else:
+            try:
+                part_entries, decode_stats = self._cached_parts(
+                    file_list, ts_range, names, pred_key, tag_predicates)
+            finally:
+                self._unpin_files(file_list)
+            metas = [m for m, e in zip(file_list, part_entries)
+                     if e.part is not None]
+            loaded = [e.part for e in part_entries if e.part is not None]
         if mem is not None:
             mcols, mseq, mop = mem
-            parts_cols.append({n: mcols[n] for n in names})
-            parts_seq.append(mseq)
-            parts_op.append(mop)
-
-        if not parts_cols:
-            return None
-        if len(parts_cols) == 1:
-            # single part (one big SST, or memtable only): concatenate
-            # would copy ~the whole table for nothing — cold scans at the
-            # TSBS 17M-row scale spend seconds here otherwise
-            columns = dict(parts_cols[0])
-            seq = parts_seq[0]
-            op = parts_op[0]
-        else:
-            columns = self._concat_columns(names, parts_cols)
-            seq = np.concatenate(parts_seq)
-            op = np.concatenate(parts_op)
-        part_offsets = np.cumsum([0] + sst_part_lens)
+            mem = ({n: mcols[n] for n in names}, mseq, mop)
         if tag_predicates:
             # exact row filter for equality/IN tag predicates: the
             # inverted index prunes row groups, but one row group holds
@@ -1188,20 +1627,25 @@ class Region:
             # SELECTED series. Whole series keep/drop together, so LWW
             # dedup and tombstones stay intact; the device WHERE still
             # evaluates the predicate exactly (incl. NULL semantics).
-            keep = self._tag_inset_mask(tag_predicates, columns)
-            if keep is not None and not keep.all():
-                idx = np.flatnonzero(keep)
-                if idx.size == 0:
-                    # preserve the "no rows" contract: consumers
-                    # None-check, they never expect a 0-row ScanData
-                    return None
-                columns = {n: v[idx] for n, v in columns.items()}
-                seq = seq[idx]
-                op = op[idx]
-                # ascending-index gather preserves within-part order; the
-                # part boundaries just shift to the count of kept rows
-                # before each original offset
-                part_offsets = np.searchsorted(idx, part_offsets)
+            # Row-wise, so it runs per part (an emptied SST part keeps
+            # its place as a zero-row segment; ascending-index gathers
+            # preserve within-part order)
+            loaded = [self._inset_filter(tag_predicates, p) for p in loaded]
+            if mem is not None:
+                mem = self._inset_filter(tag_predicates, mem)
+                if not len(mem[1]):
+                    mem = None
+        rows = [m.num_rows for m in metas] if lazy \
+            else [len(p[1]) for p in loaded]
+        num_rows = sum(rows) + (len(mem[1]) if mem is not None else 0)
+        if num_rows == 0:
+            # preserve the "no rows" contract: consumers None-check,
+            # they never expect a 0-row ScanData
+            if lazy:
+                self._unpin_files(file_list)
+            return None
+        parts = _ScanParts(self, names, pred_key, metas, rows, loaded, mem,
+                           cache_key)
         tag_dicts = {
             c.name: self.registry.dict_array(c.name)
             for c in self.schema.tag_columns
@@ -1209,25 +1653,49 @@ class Region:
         }
         result = ScanData(
             schema=self.schema,
-            columns=columns,
-            seq=seq,
-            op_type=op,
+            columns=None,
+            seq=None,
+            op_type=None,
             tag_dicts=tag_dicts,
-            num_rows=len(seq),
+            num_rows=num_rows,
             region_id=self.region_id,
             data_version=version,
             incarnation=self.incarnation,
             scan_fingerprint=(ts_range, tuple(names), pred_key),
-            sorted_part_offsets=tuple(int(o) for o in part_offsets),
-            part_keys=tuple(part_keys),
+            sorted_part_offsets=tuple(parts.offsets[:len(metas) + 1]),
+            # device hot-set identity: a part's rows depend only on the
+            # immutable file + the window/predicate key (the inset
+            # filter keeps whole series deterministically)
+            part_keys=tuple((m.file_id, ts_range, pred_key)
+                            for m in metas),
             stats={"ssts": len(file_list),
-                   "ssts_pruned": len(file_list) - len(sst_part_lens),
+                   "ssts_pruned": len(file_list) - len(metas),
                    "cache_hits": 0,
                    **decode_stats},
         )
-        with self._lock:
-            self._scan_cache_put(cache_key, result)
+        result._parts = parts
+        parts.stats = result.stats
+        if lazy:
+            # the plan keeps its files pinned until its bytes are read
+            # (or it is closed, or dropped as garbage): it is NOT parked
+            # in the snapshot cache — a plan costs nothing to take
+            # again, and a parked one would hold its pins for as long
+            # as it stayed
+            parts.pins = _PlanPins(self, file_list)
+            weakref.finalize(parts, parts.pins.release)
+        else:
+            with self._lock:
+                self._scan_cache_put(cache_key, result)
         return result
+
+    def _inset_filter(self, tag_predicates, part: tuple) -> tuple:
+        """One part's rows that pass the InSet tag predicates."""
+        cols, seq, op = part
+        keep = self._tag_inset_mask(tag_predicates, cols)
+        if keep is None or keep.all():
+            return part
+        idx = np.flatnonzero(keep)
+        return ({n: v[idx] for n, v in cols.items()}, seq[idx], op[idx])
 
     def scan_last(self, group_tag: str,
                   projection: Optional[Sequence[str]] = None,
@@ -1324,10 +1792,15 @@ class Region:
         try:
             from greptimedb_tpu.storage import scan_pool
 
-            while not aborted and visited < len(file_list):
-                # decode in waves of the pool width: parallelism inside
-                # a wave, the early-stop check between waves (a wave may
-                # over-read at most threads-1 files past the stop point)
+            stop = False
+            while not (aborted or stop) and visited < len(file_list):
+                # decode in waves of the pool width (parallelism inside
+                # a wave), but take the wave's files one at a time and
+                # test the stop condition after each: what the pruned
+                # scan visits — and whether a tombstone voids it — must
+                # not depend on how wide the pool is. Files the wave
+                # decoded past the stop point are dropped unread (they
+                # stay in the part cache)
                 threads = scan_pool.resolve(self.decode_threads,
                                             len(file_list) - visited)
                 wave = file_list[visited:visited + max(1, threads)]
@@ -1338,21 +1811,21 @@ class Region:
                 workers = max(workers, st["decode_workers"])
                 for ent in parts:
                     visited_entries.append(ent)
-                    if ent.part is None:
-                        continue
-                    cols, _seq_col, op_col = ent.part
-                    if bool((op_col != OP_PUT).any()):
-                        aborted = True
+                    visited += 1
+                    if ent.part is not None:
+                        cols, _seq_col, op_col = ent.part
+                        if bool((op_col != OP_PUT).any()):
+                            aborted = True
+                            break
+                        fold(np.asarray(cols[group_tag]),
+                             np.asarray(cols[ts_name]))
+                    if visited >= len(file_list):
                         break
-                    fold(np.asarray(cols[group_tag]),
-                         np.asarray(cols[ts_name]))
-                visited += len(wave)
-                if aborted or visited >= len(file_list):
-                    break
-                nxt = file_list[visited].ts_max
-                if bool((best[1:] > nxt).all()) and \
-                        (not suffix_null[visited] or best[0] > nxt):
-                    break
+                    nxt = file_list[visited].ts_max
+                    if bool((best[1:] > nxt).all()) and \
+                            (not suffix_null[visited] or best[0] > nxt):
+                        stop = True
+                        break
         finally:
             self._unpin_files(file_list)
         if aborted:
@@ -1744,6 +2217,23 @@ class Region:
     @property
     def num_sst_rows(self) -> int:
         return sum(f.num_rows for f in self.files.values())
+
+    def estimate_rows(self, ts_range=None) -> int:
+        """Rows a scan of `ts_range` may return, from metadata only (the
+        files and memtable whose extent overlaps it; what
+        ScanStream.est_rows counts) — decides streaming without taking
+        a snapshot."""
+        with self._lock:
+            metas = list(self.files.values())
+            mem = self.memtable
+            mem_rows, mem_lo, mem_hi = mem.num_rows, mem.ts_min, mem.ts_max
+        if ts_range is not None:
+            metas = [m for m in metas if m.ts_max >= ts_range[0]
+                     and m.ts_min < ts_range[1]]
+            if mem_lo is not None and (mem_hi < ts_range[0]
+                                       or mem_lo >= ts_range[1]):
+                mem_rows = 0
+        return sum(m.num_rows for m in metas) + mem_rows
 
     def ts_extent(self) -> Optional[tuple[int, int]]:
         """(min, max) timestamp over SST metas + memtable, or None when

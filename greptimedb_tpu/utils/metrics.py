@@ -609,6 +609,13 @@ QUERY_TIER = REGISTRY.counter(
     "(CPU backend of an accelerator process), device, mesh, or cache "
     "(every part served from the partial-aggregate cache, no kernel "
     "ran); counted once per statement where the tier becomes final")
+AGG_SCAN = REGISTRY.counter(
+    "greptimedb_tpu_agg_scan_total",
+    "Aggregate statements by what they asked of their scan: none (the "
+    "incremental path found every part's partial cached and fetched no "
+    "SST part), parts (it fetched only the parts it missed), whole "
+    "(whole columns were built or read: every other path); counted once "
+    "per statement, a statement that scans twice counts its costliest")
 SLOW_QUERIES = REGISTRY.counter(
     "greptimedb_tpu_slow_queries_total",
     "Statements slower than the slow-query threshold, by kind")

@@ -125,19 +125,100 @@ class TestWriteScanRaces:
         assert qe.execute_one("SELECT count(*) FROM m").rows()[0][0] == \
             50 + 10 * 20
 
+    def test_on_demand_part_fetches_race_compaction(self, tmp_path):
+        """ISSUE 25: aggregates whose scan is a plan fetch their parts
+        AFTER the region lock is released — here with a cold partial
+        cache every time, more threads than cores and a short switch
+        interval, while a maintainer flushes new files and compacts
+        them away. Every answer is one whole snapshot's (every row has
+        v = 1, so sum(v) == count(*), never fewer than the rows
+        acknowledged before it was asked), and when the dust settles no
+        file is pinned and the purge queue drains."""
+        import os
+        import sys
+
+        from greptimedb_tpu.query import partial_cache as pc
+
+        engine = RegionEngine(EngineConfig(data_dir=str(tmp_path),
+                                           maintenance_workers=0))
+        q = QueryEngine(Catalog(MemoryKv()), engine)
+        q.execute_one(
+            "CREATE TABLE m (host STRING, v DOUBLE, ts TIMESTAMP TIME "
+            "INDEX, PRIMARY KEY(host)) WITH (append_mode='true')")
+        rid = q.catalog.table("public", "m").region_ids[0]
+        acked = [0]
+
+        def load(batch):
+            q.execute_one("INSERT INTO m VALUES " + ", ".join(
+                f"('h{j % 7}', 1.0, {batch * 1000 + j})"
+                for j in range(40)))
+            acked[0] += 40
+            engine.flush(rid)
+
+        for b in range(3):
+            load(b)
+        stop = threading.Event()
+
+        def maintainer():
+            for b in range(3, 11):
+                load(b)
+                if b % 2:
+                    engine.compact(rid)
+            stop.set()
+
+        def scanner(k):
+            # a text of its own: identical concurrent statements share
+            # one execution (fast-lane single flight), and a follower's
+            # answer is then as old as its leader's snapshot
+            sql = f"SELECT count(*) AS c{k}, sum(v) FROM m"
+
+            def run():
+                while not stop.is_set():
+                    scan_once(sql)
+            return run
+
+        def scan_once(sql):
+            floor = acked[0]
+            pc.global_cache().clear()  # every part must be fetched
+            total, summed = q.execute_one(sql).rows()[0]
+            assert total == summed, (total, summed)
+            assert total >= floor, (total, floor)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-4)
+        try:
+            _run_threads([maintainer] + [
+                scanner(k) for k in range((os.cpu_count() or 2) + 2)])
+        finally:
+            sys.setswitchinterval(old)
+        assert q.execute_one("SELECT count(*) FROM m").rows()[0][0] == 440
+        region = engine.region(rid)
+        import gc
+
+        gc.collect()
+        assert not region._file_refs
+        engine.close()
+        assert not region._purge_queue
+
     def test_compacted_files_purged_on_close(self, qe, tmp_path):
         import glob
 
-        qe.execute_one("INSERT INTO m VALUES ('a', 1.0, 1000)")
-        qe.execute_one("ADMIN flush_table('m')")
-        qe.execute_one("INSERT INTO m VALUES ('b', 2.0, 2000)")
-        qe.execute_one("ADMIN flush_table('m')")
-        r = qe.execute_one("ADMIN compact_table('m')")
-        # ADMIN is async job submission now — wait for the compact job
-        # before asserting its side effects
         maint = qe.region_engine.maintenance
-        for row in r.rows():
-            maint.wait(int(row[0]), timeout=30)
+
+        def admin(sql):
+            # ADMIN is async job submission — wait for each job before
+            # the next step: a flush still QUEUED when the second one
+            # is submitted collapses with it into one job (one SST, so
+            # the compaction below has nothing to merge and nothing to
+            # purge), which is how this test failed on a loaded machine
+            for row in qe.execute_one(sql).rows():
+                maint.wait(int(row[0]), timeout=30)
+
+        qe.execute_one("INSERT INTO m VALUES ('a', 1.0, 1000)")
+        admin("ADMIN flush_table('m')")
+        qe.execute_one("INSERT INTO m VALUES ('b', 2.0, 2000)")
+        admin("ADMIN flush_table('m')")
+        admin("ADMIN compact_table('m')")
         info = qe.catalog.table("public", "m")
         region = qe.region_engine.region(info.region_ids[0])
         # old files grace-held, not yet deleted

@@ -280,6 +280,248 @@ class TestInvalidationSeams:
         assert pc.global_cache().part_keys(rid) == []
 
 
+def _io_counters():
+    """(part-cache misses, part-cache hits, decode bytes): what a scan
+    costs the storage layer."""
+    from greptimedb_tpu.utils.metrics import (
+        SCAN_DECODE_BYTES,
+        SCAN_PART_CACHE_EVENTS,
+    )
+
+    return (SCAN_PART_CACHE_EVENTS.get(event="miss"),
+            SCAN_PART_CACHE_EVENTS.get(event="hit"),
+            SCAN_DECODE_BYTES.get())
+
+
+def _modes():
+    from greptimedb_tpu.utils.metrics import AGG_SCAN
+
+    return {m: AGG_SCAN.get(mode=m) for m in ("none", "parts", "whole")}
+
+
+def _mode_delta(before):
+    return {m: int(v - before[m]) for m, v in _modes().items()}
+
+
+BUCKET_SQL = ("SELECT date_bin(INTERVAL '1 second', ts) AS sec, host, "
+              "avg(v), max(w) FROM cpu WHERE ts >= 0 AND ts < 90000000 "
+              "GROUP BY sec, host ORDER BY sec, host")
+
+
+class TestScanOnDemand:
+    """ISSUE 25: a scan is a plan first and bytes on demand — the
+    aggregate probes the partial cache from region metadata and decodes
+    only the parts it misses."""
+
+    @pytest.mark.parametrize("append", [True, False],
+                             ids=["append", "lww"])
+    @pytest.mark.parametrize("sql", [AGG_SQL, BUCKET_SQL],
+                             ids=["by_host", "double_groupby"])
+    def test_all_hit_decodes_nothing(self, db, monkeypatch, append, sql):
+        """(a) every partial cached: no SST part is fetched or decoded,
+        nothing is concatenated, op_type is never read — and the answer
+        equals the materialized one bit for bit."""
+        from greptimedb_tpu.storage.region import Region, ScanData
+        from greptimedb_tpu.utils.metrics import QUERY_TIER
+
+        eng, qe = db
+        rid = mk(qe, append=append)
+        fill(qe, eng, rid, mem=0)
+        classic, cold, _ = run_both(qe, sql)
+        region = eng.region(rid)
+        with region._lock:  # drop the classic run's parked snapshot
+            region._scan_cache.clear()
+            region._scan_cache_sizes.clear()
+            region._scan_cache_bytes = 0
+        built = []
+        orig_mat, orig_cat = ScanData.materialize, Region._concat_columns
+        monkeypatch.setattr(
+            ScanData, "materialize",
+            lambda self: built.append("materialize") or orig_mat(self))
+        monkeypatch.setattr(
+            Region, "_concat_columns",
+            lambda self, *a: built.append("concat") or orig_cat(self, *a))
+        io0, modes0 = _io_counters(), _modes()
+        cache0 = QUERY_TIER.get(tier="cache")
+        warm = qe.execute_one(sql, CTX)
+        st = qe.executor.last_partial_stats
+        assert st["part_hits"] == 3 and st["part_misses"] == 0
+        assert _io_counters() == io0
+        assert _mode_delta(modes0) == {"none": 1, "parts": 0, "whole": 0}
+        assert QUERY_TIER.get(tier="cache") == cache0 + 1
+        assert built == []
+        assert not region._file_refs  # the plan gave its pins back
+        assert_same(classic, cold)
+        assert_same(classic, warm)
+
+    def test_flush_decodes_only_the_new_file(self, db):
+        """(b) + (c): a row acknowledged after the cache is warm is in
+        the next answer straight from the memtable (nothing decoded),
+        and after the flush only the new file is decoded."""
+        eng, qe = db
+        rid = mk(qe)
+        fill(qe, eng, rid, mem=0)
+        qe.execute_one(AGG_SQL, CTX)
+        qe.execute_one("INSERT INTO cpu VALUES (77, 'h1', 1e6, 3.0), "
+                       "(78, 'fresh', 5.0, 1.0)", CTX)
+        io0, modes0 = _io_counters(), _modes()
+        classic, inc, st = run_both(qe, AGG_SQL)
+        assert st["part_hits"] == 3 and st["memtable_rows"] == 2
+        assert "fresh" in list(np.asarray(inc.columns[0]))
+        assert_same(classic, inc)
+        # the classic twin decoded; the incremental run did not
+        assert _mode_delta(modes0) == {"none": 1, "parts": 0, "whole": 1}
+        eng.flush(rid)
+        io0, modes0 = _io_counters(), _modes()
+        after = qe.execute_one(AGG_SQL, CTX)
+        st = qe.executor.last_partial_stats
+        assert st["part_hits"] == 3 and st["part_misses"] == 1
+        assert st["delta_rows"] == 2
+        io1 = _io_counters()
+        assert io1[0] == io0[0] + 1  # one part-cache miss: the new file
+        assert io1[1] == io0[1]  # no other part was even looked up
+        assert io1[2] > io0[2]
+        assert _mode_delta(modes0) == {"none": 0, "parts": 1, "whole": 0}
+        assert_same(classic, after)  # the flush does not change it
+
+    def test_delete_after_warm_falls_back_exact(self, db):
+        """(d) a DELETE acknowledged after the cache is warm is seen
+        from the memtable slice, and after its flush from the new
+        file's noted flag: typed fallback, whole columns, exact."""
+        eng, qe = db
+        rid = mk(qe, name="lww", append=False)
+        for f in range(2):
+            vals = ", ".join(
+                f"({f * 100000 + i * 10}, 'h{i % 4}', {f * 100 + i}, 0.0)"
+                for i in range(60))
+            qe.execute_one(f"INSERT INTO lww VALUES {vals}", CTX)
+            eng.flush(rid)
+        sql = "SELECT host, sum(v) FROM lww GROUP BY host ORDER BY host"
+        qe.execute_one(sql, CTX)
+        qe.execute_one(sql, CTX)
+        assert qe.executor.last_partial_stats["part_hits"] == 2
+        qe.execute_one("DELETE FROM lww WHERE host = 'h1'", CTX)
+        for where in ("memtable", "flushed"):
+            modes0 = _modes()
+            classic, inc, _ = run_both(qe, sql)
+            assert qe.executor.last_path != "incremental", where
+            assert _mode_delta(modes0)["whole"] == 2, where
+            assert_same(classic, inc)
+            assert "h1" not in list(np.asarray(inc.columns[0])), where
+            eng.flush(rid)
+
+    @pytest.mark.parametrize("seam", ["compact", "truncate"])
+    def test_snapshot_dies_between_plan_and_fetch(self, db, monkeypatch,
+                                                  seam):
+        """(e) the files of a plan are swapped out (compaction) or
+        deleted (TRUNCATE) after the plan is taken and before its bytes
+        are read: the answer is a whole snapshot's, and nothing stays
+        pinned — the purge queue drains."""
+        import glob
+
+        eng, qe = db
+        rid = mk(qe)
+        fill(qe, eng, rid, mem=0)
+        expected = qe.execute_one(AGG_SQL, CTX)
+        pc.global_cache().clear()  # cold: every part has to be fetched
+        region = eng.region(rid)
+        orig, fired = eng.scan, []
+
+        def scan_then_mutate(*a, **k):
+            out = orig(*a, **k)
+            if not fired:
+                fired.append(1)
+                assert not out.materialized  # a plan, nothing read yet
+                if seam == "compact":
+                    eng.compact(rid)
+                else:
+                    qe.execute_one("TRUNCATE TABLE cpu", CTX)
+            return out
+
+        monkeypatch.setattr(eng, "scan", scan_then_mutate)
+        got = qe.execute_one(AGG_SQL, CTX)
+        assert fired
+        if seam == "compact":
+            # the plan's pins kept the swapped-out files readable
+            assert_same(expected, got)
+        else:
+            # the snapshot expired; the retaken one is the empty table
+            assert got.num_rows == 0
+        del got
+        assert not region._file_refs
+        assert not region._purge_queue
+        live = set(eng.region(
+            qe.catalog.table("public", "cpu").region_ids[0]).files)
+        on_disk = {os.path.basename(f)[:-len(".parquet")]
+                   for f in glob.glob(os.path.join(
+                       eng.config.data_dir, "**", "sst", "*.parquet"),
+                       recursive=True)}
+        assert on_disk == live
+        monkeypatch.setattr(eng, "scan", orig)
+        again = qe.execute_one(AGG_SQL, CTX)
+        if seam == "compact":
+            assert_same(expected, again)
+
+    @pytest.mark.parametrize("sql,flag", [
+        (AGG_SQL, "on_demand"),
+        ("SELECT host, median(v) FROM cpu GROUP BY host", "whole"),
+    ], ids=["parts", "whole"])
+    def test_decode_on_demand_is_scan_stage_time(self, db, sql, flag):
+        """The plan's `scan` segment decodes nothing; the rows are
+        decoded in a later `scan` segment — inside the fold for missed
+        parts, before the classic kernels for whole columns — so the
+        stage keeps meaning what it meant."""
+        from greptimedb_tpu.utils import tracing
+
+        eng, qe = db
+        rid = mk(qe)
+        fill(qe, eng, rid, mem=0)
+        ctx = QueryContext()  # a trace of its own
+        with tracing.request_span("test:scan_on_demand"):
+            qe.execute_one(sql, ctx)
+        segs = sorted((s for s in tracing.spans_for(ctx.trace_id)
+                       if s.stage and s.name == "scan"),
+                      key=lambda s: s.started_at)
+        plan = segs[0]
+        assert plan.attrs["rows"] == 360
+        assert plan.attrs["rows_decoded"] == 0
+        later = [s for s in segs[1:] if s.attrs.get(flag)]
+        assert later, [s.attrs for s in segs]
+        assert sum(s.attrs.get("rows_decoded", 0) for s in later) == 360
+
+    @pytest.mark.parametrize("shape", ["host_agg", "multi_region"])
+    def test_ineligible_shapes_materialize(self, db, shape):
+        """(f) a shape the per-part decomposition cannot serve reads
+        whole columns as before, and says so."""
+        eng, qe = db
+        if shape == "host_agg":
+            rid = mk(qe)
+            fill(qe, eng, rid)
+            sql = "SELECT host, median(v) FROM cpu GROUP BY host " \
+                  "ORDER BY host"
+        else:
+            qe.execute_one(
+                "CREATE TABLE cpu (ts TIMESTAMP(3) TIME INDEX, host "
+                "STRING, v DOUBLE, w DOUBLE, PRIMARY KEY(host)) "
+                "PARTITION ON COLUMNS (host) (host < 'h2', host >= 'h2') "
+                "WITH (append_mode='true')", CTX)
+            rids = qe.catalog.table("public", "cpu").region_ids
+            assert len(rids) == 2
+            vals = ", ".join(f"({i * 10}, 'h{i % 5}', {i}.5, 1.0)"
+                             for i in range(100))
+            qe.execute_one(f"INSERT INTO cpu VALUES {vals}", CTX)
+            for rid in rids:
+                eng.flush(rid)
+            sql = AGG_SQL
+        modes0 = _modes()
+        first = qe.execute_one(sql, CTX)
+        assert qe.executor.last_path != "incremental"
+        second = qe.execute_one(sql, CTX)
+        assert _mode_delta(modes0) == {"none": 0, "parts": 0, "whole": 2}
+        assert_same(first, second)
+        assert first.num_rows == 5
+
+
 class TestEligibilityFallbacks:
     def test_host_agg_falls_back(self, db):
         from greptimedb_tpu.utils.metrics import PARTIAL_AGG_CACHE_EVENTS

@@ -89,11 +89,11 @@ class TestParallelDecode:
         region = engine.region(1)
         monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", "1")
         clear_scan_caches(region)
-        seq = engine.scan(1)
+        seq = engine.scan(1).materialize()
         assert seq.stats["decode_workers"] == 1
         monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", "4")
         clear_scan_caches(region)
-        par = engine.scan(1)
+        par = engine.scan(1).materialize()
         assert scans_equal(seq, par)
         # ts-ranged and projected scans too
         for kwargs in ({"ts_range": (1_000_000, 2_000_500)},
@@ -118,7 +118,7 @@ class TestParallelDecode:
         # one worker before the second picks a task up
         for _ in range(5):
             clear_scan_caches(region)
-            scan = engine.scan(1)
+            scan = engine.scan(1).materialize()
             if scan.stats["decode_workers"] > 1:
                 break
         assert scan.stats["decode_workers"] > 1, scan.stats
@@ -136,13 +136,13 @@ class TestParallelDecode:
         fill_files(engine, 1, n_files=1, rows_per_file=900)
         monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", "1")
         clear_scan_caches(region)
-        seq = engine.scan(1)
+        seq = engine.scan(1).materialize()
         assert seq.stats["decode_workers"] == 1
         assert seq.num_rows == 900
         monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", "4")
         for _ in range(5):
             clear_scan_caches(region)
-            par = engine.scan(1)
+            par = engine.scan(1).materialize()
             if par.stats["decode_workers"] > 1:
                 break
         assert par.stats["decode_workers"] > 1, par.stats
@@ -177,21 +177,21 @@ class TestParallelDecode:
 
         monkeypatch.setattr(region.sst_reader, "read", spy)
         clear_scan_caches(region)
-        scan = engine.scan(1)
+        scan = engine.scan(1).materialize()
         assert scan.num_rows == 300
         assert calls, "whole-file read() was bypassed"
 
     def test_compaction_reads_through_part_cache(self, engine):
         engine.create_region(1, schema3())
         fill_files(engine, 1)
-        warm = engine.scan(1)  # fills per-file parts
+        warm = engine.scan(1).materialize()  # fills per-file parts
         from greptimedb_tpu.utils.metrics import SCAN_PART_CACHE_EVENTS
 
         before = SCAN_PART_CACHE_EVENTS.get(event="hit")
         engine.compact(1)
         assert SCAN_PART_CACHE_EVENTS.get(event="hit") >= before + 4
         # merged output equals the pre-compaction rows (append region)
-        after = engine.scan(1)
+        after = engine.scan(1).materialize()
         assert after.num_rows == warm.num_rows
 
 
@@ -200,13 +200,13 @@ class TestPartCacheMutation:
         engine.create_region(1, schema3())
         fill_files(engine, 1, n_files=3)
         region = engine.region(1)
-        engine.scan(1)
+        engine.scan(1).materialize()
         assert len(region._part_cache) == 3
         # unrelated flush: a NEW file appears, old entries stay
         engine.put(1, make_batch(region.schema, ["h0"], [99_000_000],
                                  [5.0]))
         engine.flush(1)
-        scan = engine.scan(1)
+        scan = engine.scan(1).materialize()
         assert scan.stats["files_decoded"] == 1
         assert scan.stats["part_hits"] == 3
         # and the incremental assembly is correct
@@ -216,12 +216,12 @@ class TestPartCacheMutation:
         engine.create_region(1, schema3())
         fill_files(engine, 1, n_files=3)
         region = engine.region(1)
-        engine.scan(1)
+        engine.scan(1).materialize()
         old_ids = set(region.files)
         engine.compact(1)  # full merge
         cached_files = {k[0] for k in region._part_cache}
         assert not (cached_files & old_ids)
-        scan = engine.scan(1)
+        scan = engine.scan(1).materialize()
         assert scan.num_rows == 3 * 300
 
     def test_expiry_invalidates_parts(self, engine):
@@ -230,7 +230,7 @@ class TestPartCacheMutation:
         engine.create_region(1, schema3())
         fill_files(engine, 1, n_files=3)
         region = engine.region(1)
-        engine.scan(1)
+        engine.scan(1).materialize()
         assert len(region._part_cache) == 3
         # cutoff between file 0 and file 1 (file ts in units of ms)
         ttl_ms = 1
@@ -240,7 +240,7 @@ class TestPartCacheMutation:
         assert res["removed"] >= 1
         cached_files = {k[0] for k in region._part_cache}
         assert cached_files <= set(region.files)
-        scan = engine.scan(1)
+        scan = engine.scan(1).materialize()
         assert scan.stats["ssts"] == len(region.files)
 
     def test_delete_served_from_memtable_delta(self, engine):
@@ -252,9 +252,9 @@ class TestPartCacheMutation:
         engine.create_region(1, schema3())
         fill_files(engine, 1, n_files=2)
         region = engine.region(1)
-        engine.scan(1)
+        engine.scan(1).materialize()
         engine.delete(1, make_batch(region.schema, ["h0"], [0], [0.0]))
-        scan = engine.scan(1)
+        scan = engine.scan(1).materialize()
         assert scan.stats["files_decoded"] == 0  # parts reused
         assert (scan.op_type == OP_DELETE).sum() == 1
 
@@ -262,7 +262,7 @@ class TestPartCacheMutation:
         engine.create_region(1, schema3())
         fill_files(engine, 1, n_files=2)
         region = engine.region(1)
-        engine.scan(1)
+        engine.scan(1).materialize()
         assert region._part_cache
         from greptimedb_tpu.storage.engine import RegionRequest, RequestType
 
@@ -274,7 +274,7 @@ class TestPartCacheMutation:
         engine.create_region(1, schema3())
         fill_files(engine, 1, n_files=4)
         region = engine.region(1)
-        full = engine.scan(1)
+        full = engine.scan(1).materialize()
         one_part = region._part_cache[next(iter(region._part_cache))]
         # budget for ~2 parts: older entries must age out
         region.part_cache_budget = one_part.nbytes * 2 + 1
@@ -282,7 +282,7 @@ class TestPartCacheMutation:
 
         before = SCAN_PART_CACHE_EVENTS.get(event="evict")
         clear_scan_caches(region)
-        scan = engine.scan(1)
+        scan = engine.scan(1).materialize()
         assert SCAN_PART_CACHE_EVENTS.get(event="evict") > before
         assert region._part_cache_bytes <= region.part_cache_budget
         assert scan.num_rows == full.num_rows  # eviction never drops rows
@@ -297,7 +297,7 @@ class TestPartCacheMutation:
         engine.create_region(1, schema3())
         fill_files(engine, 1, n_files=4)
         region = engine.region(1)
-        engine.scan(1)
+        engine.scan(1).materialize()
         assert region._scan_cache_bytes > 0  # snapshots are accounted
         assert region._host_cache_bytes == (region._part_cache_bytes
                                             + region._scan_cache_bytes)
@@ -307,7 +307,7 @@ class TestPartCacheMutation:
         clear_scan_caches(region)
         region._scan_cache_sizes.clear()
         region._scan_cache_bytes = 0
-        scan = engine.scan(1)
+        scan = engine.scan(1).materialize()
         assert scan.num_rows == 1200
         assert not region._part_cache
         assert len(region._scan_cache) == 1
@@ -334,7 +334,7 @@ class TestFaultedDecode:
         FAULTS.arm("objectstore.read", Fault(kind="fail", prob=1.0))
         try:
             with pytest.raises(FaultError):
-                engine.scan(1)
+                engine.scan(1).materialize()
         finally:
             FAULTS.disarm("objectstore.read")
         # pin discipline: every worker finished before the unpin; no
@@ -342,7 +342,7 @@ class TestFaultedDecode:
         assert not region._file_refs
         # disarmed: the same scan succeeds (and decodes all files)
         clear_scan_caches(region)
-        scan = engine.scan(1)
+        scan = engine.scan(1).materialize()
         assert scan.stats["files_decoded"] == 4
 
     def test_latency_fault_keeps_results_identical(self, engine,
@@ -354,13 +354,13 @@ class TestFaultedDecode:
         region = engine.region(1)
         monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", "1")
         clear_scan_caches(region)
-        oracle = engine.scan(1)
+        oracle = engine.scan(1).materialize()
         monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", "4")
         FAULTS.arm("objectstore.read",
                    Fault(kind="latency", arg=0.01, prob=0.5, seed=7))
         try:
             clear_scan_caches(region)
-            jittered = engine.scan(1)
+            jittered = engine.scan(1).materialize()
         finally:
             FAULTS.disarm("objectstore.read")
         assert scans_equal(oracle, jittered)
@@ -396,7 +396,7 @@ class TestScanLast:
         engine.create_region(1, schema3())
         fill_files(engine, 1, n_files=3)
         region = engine.region(1)
-        full = engine.scan(1)
+        full = engine.scan(1).materialize()
         pruned = engine.scan_last(1, "host")
         ts_f = np.asarray(full.columns["ts"])
         ts_p = np.asarray(pruned.columns["ts"])
@@ -580,7 +580,7 @@ class TestStreamAndSeqMinParallel:
         engine.create_region(1, schema3())
         fill_files(engine, 1, n_files=5)
         region = engine.region(1)
-        full = engine.scan(1)
+        full = engine.scan(1).materialize()
         boundaries = [0, int(full.seq.min()),
                       int(np.median(full.seq)), int(full.seq.max())]
         for s in boundaries:
@@ -614,7 +614,7 @@ class TestStreamAndSeqMinParallel:
         assert SCAN_PART_CACHE_EVENTS.get(event="hit") > hits0
         assert SCAN_PART_CACHE_EVENTS.get(event="miss") == miss0
         assert scans_equal(first, again)
-        full = engine.scan(1)
+        full = engine.scan(1).materialize()
         assert full.num_rows == 900  # cached parts stayed whole
 
 
