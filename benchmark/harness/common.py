@@ -1,9 +1,9 @@
 """Paths, the manifest, and look-up of per-cell files by name.
 
 Whatever belongs to one configuration, one traffic mix, one template
-family, one dataset, one reader or one metric is a file of its own under
-benchmark/, found here by the name BENCHMARK.json (or the file that
-refers to it) gives. Nothing in the harness lists them.
+family, one dataset, one loader, one reader or one metric is a file of
+its own under benchmark/, found here by the name BENCHMARK.json (or the
+file that refers to it) gives. Nothing in the harness lists them.
 """
 
 from __future__ import annotations
@@ -75,3 +75,24 @@ def cell_metrics(man: dict, workload: str, which: str) -> list:
 
 def make_dataset(config: dict, seed: int, scale: dict):
     return load_module("datasets", config["dataset"]).Dataset(seed, scale)
+
+
+def tables(ds) -> list:
+    """The dataset's tables, each a view with the single-table
+    interface (`table`, `rows`, `create_sql()`, `series_tags()`,
+    `fields`, `slices()`); a dataset of one table is its own view."""
+    return list(ds.tables()) if hasattr(ds, "tables") else [ds]
+
+
+def loader_path(config: dict) -> str:
+    """The configuration's set-up route. `setup.loader` names
+    benchmark/loaders/<name>.py; the one loader the harness brings,
+    harness/bulk_load.py, answers to `bulk` and is what a configuration
+    without the key gets."""
+    name = config.get("setup", {}).get("loader", "bulk")
+    path = os.path.join(BENCH_DIR, "harness", "bulk_load.py") \
+        if name == "bulk" else os.path.join(BENCH_DIR, "loaders",
+                                            name + ".py")
+    if not os.path.isfile(path):
+        raise BenchFailure(f"no loader file {path}")
+    return path

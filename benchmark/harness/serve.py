@@ -9,8 +9,10 @@ of the measured window without editing the program, since only the
 process that holds the chip can trace it. Commands, one per line,
 `<seq> <command> [argument]`:
 
-    trace_start <dir>   jax.profiler.start_trace(dir), host and python
-                        tracers off (device planes are what is reduced)
+    trace_start <dir>   jax.profiler.start_trace(dir): python tracer off,
+                        host tracer at level 1, the lowest that records
+                        the program's spans (each a TraceAnnotation)
+                        beside the device planes, on their clock
     trace_stop          jax.profiler.stop_trace()
     memory              per-device allocator stats
 
@@ -48,7 +50,7 @@ def control_loop(control_dir: str) -> None:
             if cmd == "trace_start":
                 opts = jax.profiler.ProfileOptions()
                 opts.python_tracer_level = 0
-                opts.host_tracer_level = 0
+                opts.host_tracer_level = 1
                 t0 = time.time()
                 jax.profiler.start_trace(arg[0], profiler_options=opts)
                 out.update(t_call=t0, t_started=time.time())

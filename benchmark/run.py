@@ -10,7 +10,9 @@ cell's traffic mix for --seconds from closed-loop clients over the wire,
 compares a seeded sample of the answers with the templates' numpy
 references, and prints as the LAST stdout line one JSON object with the
 keys correct, attempted, failed, metrics, device (and breakdown when
-traced). Everything else the run learned is on earlier lines.
+traced) and, last, compared: every number `correct` hangs on beside its
+limit (the last lines of stderr say the same). Everything else the run
+learned is on earlier lines.
 
 Fails (non-zero exit, no result line) when the serving process is not on
 a TPU. `--rehearse` runs every phase on the CPU at the configuration's
@@ -40,7 +42,7 @@ import numpy as np  # noqa: E402
 from benchmark.harness import procs, traffic, wire  # noqa: E402
 from benchmark.harness.common import (  # noqa: E402
     BENCH_DIR, T_START, BenchFailure, cell, cell_metrics, load_json,
-    load_module, log, make_dataset, manifest)
+    load_module, loader_path, log, make_dataset, manifest, tables)
 
 TRACE_SPAN_S = 5.0
 DEGRADATION = "greptimedb_tpu_device_degradation_total"
@@ -132,24 +134,32 @@ def prune_window_entries(before: set, since: float) -> int:
 # ---- set-up ------------------------------------------------------------------
 
 
-def bulk_load(config: dict, scale: dict, seed: int, data_home: str):
+def start_loader(config: dict, scale: dict, seed: int, data_home: str):
+    """The configuration's loader (`common.loader_path`), as a child
+    pinned to the CPU that builds the data home before the server
+    starts."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     return subprocess.Popen(
-        [sys.executable, os.path.join(BENCH_DIR, "harness", "bulk_load.py"),
+        [sys.executable, loader_path(config),
          "--config", config["name"], "--scale", json.dumps(scale),
          "--seed", str(seed), "--data-home", os.path.join(data_home, "db"),
          "--parent", str(os.getpid())],
         cwd=ROOT, env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE)
 
 
-def finish_bulk(proc) -> dict:
+def finish_loader(proc) -> dict:
     out, err = proc.communicate()
     if proc.returncode != 0:
         sys.stderr.write(err.decode(errors="replace")[-3000:])
-        raise BenchFailure(f"bulk_load exited {proc.returncode}")
+        raise BenchFailure(f"the loader exited {proc.returncode}")
     return json.loads(out.decode().strip().splitlines()[-1])
+
+
+def read_back(client, ds) -> dict:
+    """count(*) of every table of the dataset."""
+    return {v.table: wire.count_rows(client, v.table) for v in tables(ds)}
 
 
 def warm_up(client, mix, dtype: str) -> list:
@@ -294,12 +304,13 @@ def run(args, data_home: str, guard) -> tuple:
          trace=args.trace, scale=scale, rehearsal=args.rehearse)
 
     # -- data: the helper loads while this process generates its copy
-    helper = bulk_load(config, scale, args.seed, data_home)
+    helper = start_loader(config, scale, args.seed, data_home)
     t0 = time.monotonic()
     ds = make_dataset(config, args.seed, scale)
     mix = traffic.Mix(wl["traffic"], ds, rehearsal.get("clients"))
-    log(f"{ds.rows} rows generated in {time.monotonic() - t0:.1f}s")
-    load = finish_bulk(helper)
+    log(f"{sum(v.rows for v in tables(ds))} rows generated in "
+        f"{time.monotonic() - t0:.1f}s")
+    load = finish_loader(helper)
 
     server = wire.Server(platform, data_home)
     client = wire.Client(server.port)
@@ -320,14 +331,15 @@ def run(args, data_home: str, guard) -> tuple:
                 f"{dev['count']} chips, the cell asks for {wl['chips']}")
         dtype = dev["compute_dtype"]
         wire.wait_maintenance_idle(client)
-        n0 = wire.count_rows(client, ds.table)
-        log(f"bulk load: {load['rows']} rows acknowledged in "
+        n0, rows = read_back(client, ds), {v.table: v.rows
+                                           for v in tables(ds)}
+        log(f"loader: {load['rows']} rows acknowledged in "
             f"{load['put_s']:.1f}s ({load['rows'] / load['put_s']:.0f} "
             f"rows/s), flush {load['flush_s']:.1f}s, read back {n0}")
-        if n0 != load["rows"] or n0 != ds.rows:
+        if not n0 == load["tables"] == rows:
             raise BenchFailure(
-                f"{ds.table}: {load['rows']} rows acknowledged of {ds.rows}"
-                f" but count(*) reads {n0}")
+                f"rows acknowledged {load['tables']} of {rows} but "
+                f"count(*) reads {n0}")
 
         m_boot = client.metrics()
         warm = warm_up(client, mix, dtype)
@@ -337,7 +349,8 @@ def run(args, data_home: str, guard) -> tuple:
         setup_s = time.monotonic() - T_START
         guard.set_up_done(seconds)
         emit("setup", setup_s=setup_s, server_ready_s=ready_s, load=load,
-             rows=ds.rows, read_back=n0, warm_up=warm,
+             rows=sum(rows.values()), read_back=sum(n0.values()),
+             tables=n0, warm_up=warm,
              compiles=wire.metric_sum(m0, COMPILES),
              cache_retrievals=wire.metric_sum(m0, RETRIEVALS),
              compiles_in_warm_up=wire.metric_sum(m0, COMPILES)
@@ -374,7 +387,7 @@ def run(args, data_home: str, guard) -> tuple:
         reqs = win["requests"]
 
         # -- checks, once the window has closed
-        n1 = wire.count_rows(client, ds.table)
+        n1 = read_back(client, ds)
         degraded = wire.metric_sum(m1, DEGRADATION) \
             - wire.metric_sum(m_boot, DEGRADATION)
         checks, within = verify(mix, reqs, args.seed, dtype)
@@ -389,9 +402,10 @@ def run(args, data_home: str, guard) -> tuple:
     pruned = 0 if args.rehearse else prune_window_entries(in_cache, t_window)
 
     failed = sum(1 for r in reqs if not r.ok)
-    correct = bool(within and failed == 0 and n1 == ds.rows
+    correct = bool(within and failed == 0 and n1 == rows
                    and degraded == 0 and dev["platform"] == platform)
-    emit("checks", read_back_after_window=n1, rows=ds.rows,
+    emit("checks", read_back_after_window=sum(n1.values()),
+         rows=sum(rows.values()), tables_after_window=n1, tables=rows,
          degradations=degraded, degradation_log=dev1["degradations"][-5:],
          templates=checks, errors=sorted({r.error for r in reqs
                                           if r.error})[:5])
@@ -419,6 +433,14 @@ def run(args, data_home: str, guard) -> tuple:
         result["metrics"] = read_metrics(man, args.workload, "end_to_end",
                                          ctx)
     result["device"] = device
+    # every number `correct` compared, beside its limit: last in the line
+    # (a count has to EQUAL its limit; a gap may not pass it)
+    compared = {c["template"]: (c["compared"], c["limit"]) for c in checks}
+    compared.update({f"rows.{t}": (n1[t], rows[t]) for t in rows})
+    compared.update(failed_requests=(failed, 0), degradations=(degraded, 0))
+    result["compared"] = {
+        k: {"value": min(float(v), 1e300), "limit": float(lim)}
+        for k, (v, lim) in compared.items()}
     by_t = {}
     for r in reqs:
         by_t.setdefault(r.entry.name, []).append(r.ms)
@@ -465,6 +487,10 @@ def main(argv=None) -> int:
     if guard.killed:
         log(f"processes still there at the end, killed: {guard.killed}")
     print(json.dumps(result), flush=True)
+    for k, c in result["compared"].items():
+        print(f"compared {k}: {c['value']:.6g} (limit {c['limit']:.6g})",
+              file=sys.stderr)
+    print(f"correct: {result['correct']}", file=sys.stderr, flush=True)
     return 3 if args.rehearse else 0
 
 

@@ -1,5 +1,5 @@
 """`agg_scan_skipped_share` (ISSUE 25): a data file on PR 23's
-`prom_delta` reader, appended to the manifest for `tsbs-scan-heavy`. It
+`prom_delta` reader, in the manifest for the two TSBS cells. It
 reads the share from expositions the program's registry rendered, and
 0.0 — not nothing — from expositions shaped like the parent's, which
 have the denominator and lack the counter.
@@ -74,8 +74,8 @@ def test_agg_scan_skipped_share_reads_the_counter_or_zero(tmp_path, shape):
     assert entry == {
         "name": "agg_scan_skipped_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "Storage",
-        "moves": "queries_per_s", "workloads": ["tsbs-scan-heavy"]}
-    assert MAN["per_layer"][-1] is entry  # appended, nothing moved
+        "moves": "queries_per_s",
+        "workloads": ["tsbs-scan-heavy", "tsbs-point-dash"]}
     spec = load_json("metrics", "agg_scan_skipped_share.json")
     assert spec["reader"] == "prom_delta"
     text0, text1 = _recorded_expositions(tmp_path)

@@ -36,7 +36,9 @@ TSBS_SCALE = {"hosts": 6, "hours": 2, "step_s": 10}
 PROM_SCALE = {"instances": 2, "cpus": 1, "modes": 8, "hours": 2,
               "step_s": 15}
 TSBS_TEMPLATES = ["single-groupby-1-1-1", "single-groupby-5-8-1",
-                  "single-groupby-5-1-12", "cpu-max-all-1", "cpu-max-all-8",
+                  "single-groupby-5-1-12", "single-groupby-1-1-12",
+                  "single-groupby-1-8-1", "single-groupby-5-1-1",
+                  "cpu-max-all-1", "cpu-max-all-8",
                   "double-groupby-1", "double-groupby-5",
                   "double-groupby-all", "groupby-orderby-limit", "lastpoint"]
 PANELS = load_json("traffic", "prom-board.json")["mix"]
@@ -91,7 +93,8 @@ def test_prom_counters_strictly_increase():
 
 
 @pytest.mark.parametrize("mix_name,maker", [
-    ("tsbs-scan-heavy", tsbs), ("prom-board", prom)])
+    ("tsbs-scan-heavy", tsbs), ("tsbs-point-dash", tsbs),
+    ("prom-board", prom)])
 def test_draws_depend_on_seed_only_and_never_repeat_warm_up(mix_name, maker):
     ds = maker(3, {"hosts": 50, "hours": 24, "step_s": 10}) \
         if maker is tsbs else maker(3)
@@ -491,9 +494,9 @@ def test_reduce_planes_busy_idle_top_ops_and_gaps():
     out = trace_reduce.reduce_planes(planes)
     assert out["busy_s"] == pytest.approx(5.0)
     assert out["window_s"] == pytest.approx(10.0)
-    assert out["device_ops"][0] == ["fusion.1", pytest.approx(3.0)]
-    assert out["idle_gaps"][0][1] == pytest.approx(3.0)
-    assert out["idle_gaps"][0][0].startswith("device_idle@+3000.0ms")
+    assert out["hlo_ops"][0] == ["fusion.1", pytest.approx(3.0)]
+    # the longest gap: (where it starts, how long), both in ns
+    assert out["gaps"][0] == (pytest.approx(3e9), pytest.approx(3e9))
     wider = trace_reduce.reduce_planes(planes, window_ns=20e9)
     assert wider["window_s"] == pytest.approx(20.0)
     assert trace_reduce.reduce_planes([("/device:TPU:0", [])])["busy_s"] == 0
@@ -504,17 +507,23 @@ def test_trace_reduce_on_a_trace_recorded_on_the_chip():
     benchmark's first traced run (PR 23), trimmed to under 1 MB."""
     path = os.path.join(FIXTURES, "chip_trace.xplane.pb")
     assert os.path.getsize(path) <= 1 << 20
-    planes, seen = trace_reduce.read_xplane(path)   # imports jax here
+    tr = trace_reduce.read_xplane(path)   # imports jax here
+    planes, seen = tr["planes"], tr["seen"]
     assert any(s["plane"].startswith("/device:TPU") for s in seen)
-    out = trace_reduce.reduce_planes(planes)
+    out = trace_reduce.reduce_trace(tr)
     with open(os.path.join(FIXTURES, "chip_trace.expected.json")) as f:
         want = json.load(f)
     assert out["planes"] == want["planes"]
     assert out["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
     assert out["window_s"] == pytest.approx(want["window_s"], rel=1e-9)
     assert 0 < out["busy_s"] <= out["window_s"]
-    assert [n for n, _ in out["device_ops"]] == \
+    assert [n for n, _ in out["hlo_ops"]] == \
         [n for n, _ in want["device_ops"]]
+    # recorded with the host tracer off (PR 23): kernels by name all the
+    # same, no span to name a gap by
+    assert [n for n, _ in out["device_ops"]] == ["_agg_block",
+                                                 "convert_element_type"]
+    assert {n for n, _ in out["idle_gaps"]} == {"none_open"}
     # busy is a union: never more than the sum of the ops' own times
     total = sum(d for _p, evs in planes for _n, _s, d in evs) / 1e9
     assert out["busy_s"] <= total / out["planes"] + 1e-12
@@ -539,8 +548,12 @@ def test_rehearsal_runs_every_phase_and_exits_3():
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
     assert p.returncode == 3, p.stderr[-3000:]
     out = last_line(p.stdout)
-    assert set(out) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(out) == ["correct", "attempted", "failed", "metrics",
+                         "device", "compared"]
     assert out["correct"] is True and out["failed"] == 0
+    for c in out["compared"].values():      # each number beside its limit
+        assert set(c) == {"value", "limit"} and c["value"] <= c["limit"]
+    assert out["compared"]["rows.cpu"] == {"value": 7200.0, "limit": 7200.0}
     assert out["attempted"] > 0
     assert out["device"]["platform"] == "cpu"
     assert set(out["device"]) == {"platform", "kind", "count",
@@ -556,7 +569,9 @@ def test_rehearsal_runs_every_phase_and_exits_3():
     assert kinds == ["cell", "setup", "checks", "window"]
     setup = records[1]
     assert setup["read_back"] == setup["rows"] == 20 * 360
+    assert setup["tables"] == setup["load"]["tables"] == {"cpu": 20 * 360}
     assert records[2]["read_back_after_window"] == 20 * 360
+    assert records[2]["tables_after_window"] == {"cpu": 20 * 360}
     assert 0 <= records[3]["generator_share"] < 0.5
 
 

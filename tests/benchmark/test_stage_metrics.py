@@ -27,17 +27,21 @@ from benchmark.harness.common import load_json, manifest  # noqa: E402
 
 MAN = manifest()
 
-#: ISSUE 24's table: metric -> the cells that report it
+#: ISSUE 24's table: metric -> the cells that report it (ISSUE 26 added
+#: `tsbs-point-dash` wherever `tsbs-scan-heavy` stands, and the one
+#: stage that had no metric)
+TSBS = {"tsbs-scan-heavy", "tsbs-point-dash"}
 STAGE_METRICS = {
-    "compile_ms_per_query": {"tsbs-scan-heavy", "prom-board"},
-    "host_tier_share": {"tsbs-scan-heavy"},
-    "scan_ms_per_query": {"tsbs-scan-heavy", "prom-board"},
-    "host_agg_ms_per_query": {"tsbs-scan-heavy"},
-    "upload_ms_per_query": {"tsbs-scan-heavy", "prom-board"},
-    "device_wait_ms_per_query": {"tsbs-scan-heavy", "prom-board"},
-    "assemble_ms_per_query": {"tsbs-scan-heavy", "prom-board"},
-    "encode_ms_per_query": {"tsbs-scan-heavy", "prom-board"},
-    "unattributed_ms_per_query": {"tsbs-scan-heavy", "prom-board"},
+    "compile_ms_per_query": TSBS | {"prom-board"},
+    "host_tier_share": TSBS,
+    "scan_ms_per_query": TSBS | {"prom-board"},
+    "host_agg_ms_per_query": TSBS,
+    "upload_ms_per_query": TSBS | {"prom-board"},
+    "device_wait_ms_per_query": TSBS | {"prom-board"},
+    "assemble_ms_per_query": TSBS | {"prom-board"},
+    "encode_ms_per_query": TSBS | {"prom-board"},
+    "unattributed_ms_per_query": TSBS | {"prom-board"},
+    "admission_wait_ms_per_query": TSBS,
 }
 
 
@@ -70,7 +74,8 @@ def test_stage_metric_is_data_on_an_existing_reader(name):
         assert stage is None or stage in tracing.STAGES + ("other",)
 
 
-@pytest.mark.parametrize("cell", ["prom-board", "tsbs-scan-heavy"])
+@pytest.mark.parametrize("cell", ["prom-board", "tsbs-scan-heavy",
+                                  "tsbs-point-dash"])
 def test_rehearsed_traced_run_reports_every_stage_metric(cell):
     """A reader that finds no series drops its metric without a word: run
     the cell (rehearsed, --trace 1) and see every metric of the table."""
@@ -86,6 +91,12 @@ def test_rehearsed_traced_run_reports_every_stage_metric(cell):
     got = out["metrics"]
     want = {n for n, cells in STAGE_METRICS.items() if cell in cells}
     assert want <= set(got), sorted(want - set(got))
+    # ... and every other per-layer metric the manifest lists for it
+    listed = {m["name"] for m in MAN["per_layer"]
+              if cell in m.get("workloads", [cell])}
+    assert listed == set(got), sorted(listed ^ set(got))
+    # traced on the CPU: no device plane, so no kernel ran and no gap
+    assert out["breakdown"] == {"device_ops": [], "idle_gaps": []}
     # stages every request of the cell passes through were observed
     for name in ("scan_ms_per_query", "device_wait_ms_per_query",
                  "encode_ms_per_query", "unattributed_ms_per_query"):
@@ -95,7 +106,11 @@ def test_rehearsed_traced_run_reports_every_stage_metric(cell):
         assert got["h2d_bytes_per_query"]["value"] > 0
         assert got["upload_ms_per_query"]["value"] > 0
         assert got["compile_ms_per_query"]["value"] == 0
+        # the kernel metrics read a zero there, not nothing
+        assert got["counter_adjust_ms_per_query"]["value"] == 0
+        assert got["cumsum_ms_per_query"]["value"] == 0
     else:
+        assert got["admission_wait_ms_per_query"]["value"] >= 0
         assert got["host_agg_ms_per_query"]["value"] > 0
         # on the CPU backend nothing routes to a host tier
         assert got["host_tier_share"]["value"] == 0
