@@ -219,6 +219,39 @@ def _ts_to_float(t_int):
     return t_int.astype(jnp.float64) / 1000.0
 
 
+#: rows a block of `running_sum` holds: its inner loop takes this many
+#: steps over all blocks at once, its outer loop one step a block
+_SUM_BLOCK = 2048
+
+
+def _scan_sums(cols: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(totals, inclusive running sums) along axis 0, one loop step a
+    row. `jnp.cumsum` lowers to a reduce-window, which in the chip's
+    emulated float64 takes XLA 170-370 s to compile PER SHAPE (PERF.md,
+    PR 27: 1020 of the 1034 s a cold prom-fleet-board spent compiling);
+    a loop whose body is one add compiles in seconds."""
+    def step(acc, row):
+        acc = acc + row
+        return acc, acc
+
+    return jax.lax.scan(step, jnp.zeros(cols.shape[1:], cols.dtype), cols)
+
+
+def running_sum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum of a flat array: blocks of _SUM_BLOCK rows
+    summed side by side, then the blocks' totals carried across."""
+    n = x.shape[0]
+    blocks = -(-n // _SUM_BLOCK)
+    padded = jnp.pad(x, (0, blocks * _SUM_BLOCK - n))
+    totals, within = _scan_sums(padded.reshape(blocks, _SUM_BLOCK).T)
+
+    def carry(acc, total):
+        return acc + total, acc
+
+    _, before = jax.lax.scan(carry, jnp.zeros((), x.dtype), totals)
+    return (within.T + before[:, None]).reshape(-1)[:n]
+
+
 @jax.jit
 @kernel_name("counter_adjust")
 def counter_adjust(sidx_sorted: jax.Array, values_sorted: jax.Array) -> jax.Array:
@@ -230,9 +263,9 @@ def counter_adjust(sidx_sorted: jax.Array, values_sorted: jax.Array) -> jax.Arra
     prev_s = jnp.concatenate([sidx_sorted[:1], sidx_sorted[:-1]])
     same = sidx_sorted == prev_s
     reset = jnp.where(same & (values_sorted < prev_v), prev_v, 0.0)
-    # global cumsum is per-series-correct for *differences* because rows
+    # global running sum is per-series-correct for *differences* because rows
     # are series-contiguous
-    return values_sorted + jnp.cumsum(reset)
+    return values_sorted + running_sum(reset)
 
 
 @functools.partial(jax.jit, static_argnames=("is_counter", "is_rate"))
@@ -400,11 +433,21 @@ def window_sums_grid(
     return {"sum": out_sum, "count": count_st}
 
 
+@jax.jit
+@kernel_name("cumsum")
+def _cumsum_axis1(mat: jax.Array) -> jax.Array:
+    """`jnp.cumsum(mat, axis=1)` as a loop (see `_scan_sums`). It keeps
+    the name of the eager program it replaces, `jit_cumsum`, which the
+    benchmark's `cumsum_ms_per_query` reads."""
+    _, sums = _scan_sums(jnp.moveaxis(mat, 1, 0))
+    return jnp.moveaxis(sums, 0, 1)
+
+
 @kernel_name("exclusive_cumsum")
 def exclusive_cumsum(mat: jax.Array) -> jax.Array:
     """[S, P, C] -> [S, P+1, C] exclusive prefix sums along axis 1 (the
     shared idiom of window_stats' window sums and window_sums_grid)."""
     S, _, C = mat.shape
     return jnp.concatenate(
-        [jnp.zeros((S, 1, C), mat.dtype), jnp.cumsum(mat, axis=1)],
+        [jnp.zeros((S, 1, C), mat.dtype), _cumsum_axis1(mat)],
         axis=1)

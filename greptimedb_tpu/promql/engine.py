@@ -589,20 +589,12 @@ class PromqlEngine:
                 combined = codes[0].astype(np.int64) + 1
                 for c, sz in zip(codes[1:], sizes[1:]):
                     combined = combined * sz + (c.astype(np.int64) + 1)
-                uniq, sidx = np.unique(combined, return_inverse=True)
-                # decode labels per unique series
-                labels = []
-                strides = [1] * len(sizes)
-                for i in range(len(sizes) - 2, -1, -1):
-                    strides[i] = strides[i + 1] * sizes[i + 1]
-                for u in uniq:
-                    lab = {}
-                    for t_name, stride, size in zip(tag_names, strides,
-                                                    sizes):
-                        code = int(u // stride % size) - 1
-                        if code >= 0:
-                            lab[t_name] = str(scan.tag_dicts[t_name][code])
-                    labels.append(lab)
+                uniq, sidx, regroup = _factorize_series(combined)
+                if regroup is not None:
+                    rows, ts_raw, vals = (rows[regroup], ts_raw[regroup],
+                                          vals[regroup])
+                labels = _series_labels(uniq, tag_names, sizes,
+                                        scan.tag_dicts)
             else:
                 sidx = np.zeros(len(rows), dtype=np.int64)
                 labels = [{}]
@@ -615,42 +607,33 @@ class PromqlEngine:
             # factorize in tag order — prove sortedness on host and skip
             # the device lexsort chain (round-5: forcing that chain was
             # 5.5 s of a 22 s first eval at 28.8M rows)
-            host_sorted = False
-            if info.append_mode:
-                ds = np.diff(sidx)
-                host_sorted = bool(np.all(
-                    (ds > 0) | ((ds == 0) & (np.diff(ts_sec) >= 0))))
+            ds, dt = np.diff(sidx), np.diff(ts_sec)
+            host_sorted = bool(np.all((ds > 0) | ((ds == 0) & (dt >= 0))))
+            # last-write-wins has nothing to decide where the rows are
+            # already in (series, ts) order, no (series, ts) repeats
+            # (or the scan says it cannot) and no tombstone is among
+            # them: a flushed, compacted region. Then seq / op_type stay
+            # on the host and the device sorts nothing
+            settled = info.append_mode or (
+                host_sorted
+                and (not scan.needs_dedup
+                     or bool(np.all((ds > 0) | (dt > 0))))
+                and not scan.has_delete())
         with tracing.stage("upload"):
             d_sidx = h2d(sidx.astype(np.int32))
             d_ts = h2d(ts_sec)
             d_vals = h2d(vals)
-            if not info.append_mode:
+            if not settled:
                 d_seq = h2d(scan.seq[rows].astype(np.int64))
                 d_op = h2d(scan.op_type[rows].astype(np.int8))
-        if info.append_mode:
+        if settled:
             if not host_sorted:
                 order = jnp.lexsort((d_ts, d_sidx))
                 d_sidx, d_ts, d_vals = (d_sidx[order], d_ts[order],
                                         d_vals[order])
         else:
-            # non-append tables: last-write-wins by SEQ, not by scan
-            # position — compaction re-inserts merged files after newer
-            # flushes, so concat order is NOT write order. Sort with
-            # seq as the tiebreaker, keep each duplicate run's last
-            # row, and suppress it entirely when that winner is a
-            # DELETE tombstone (the same contract ops/dedup.py's
-            # sort_dedup enforces for SQL scans).
-            from greptimedb_tpu.storage.region import OP_PUT
-
-            order = jnp.lexsort((d_seq, d_ts, d_sidx))
-            d_sidx, d_ts, d_vals, d_op = (d_sidx[order], d_ts[order],
-                                          d_vals[order], d_op[order])
-            nxt_s = jnp.concatenate([d_sidx[1:],
-                                     jnp.full((1,), -1, d_sidx.dtype)])
-            nxt_t = jnp.concatenate([d_ts[1:], jnp.full((1,), -jnp.inf)])
-            dup_next = (d_sidx == nxt_s) & (d_ts == nxt_t)
-            keep = ~dup_next & (d_op == OP_PUT)
-            d_vals = jnp.where(keep, d_vals, jnp.nan)
+            d_sidx, d_ts, d_vals = _promql_dedup(d_sidx, d_ts, d_vals,
+                                                 d_seq, d_op)
 
         channels = self._make_channels(d_sidx, d_ts, d_vals,
                                        extra_channels, p)
@@ -1045,9 +1028,18 @@ class PromqlEngine:
                     kept = {}
                 sigs.append(tuple(sorted(kept.items())))
                 out_labels.append(kept)
-            uniq = sorted(set(sigs))
-            gidx = np.asarray([uniq.index(s) for s in sigs],
-                              dtype=np.int32)
+            # factorise the signatures: one dictionary pass numbers
+            # them as first seen, the distinct ones are ranked, and the
+            # group index is a gather (no search per series)
+            seen: dict = {}
+            first_seen = np.fromiter(
+                (seen.setdefault(s, len(seen)) for s in sigs),
+                dtype=np.int32, count=len(sigs))
+            uniq = sorted(seen)
+            rank = np.empty(len(uniq), dtype=np.int32)
+            rank[[seen[u] for u in uniq]] = np.arange(len(uniq),
+                                                      dtype=np.int32)
+            gidx = rank[first_seen]
             G = len(uniq)
             glabels = [dict(u) for u in uniq]
 
@@ -1364,6 +1356,74 @@ def _edges_enabled() -> bool:
 
     return os.environ.get("GREPTIMEDB_TPU_PROMQL_EDGES",
                           "on").lower() not in ("off", "0", "false")
+
+
+@jax.jit
+@device_telemetry.kernel_name("promql_dedup")
+def _promql_dedup(d_sidx, d_ts, d_vals, d_seq, d_op):
+    """Non-append tables: last-write-wins by SEQ, not by scan position —
+    compaction re-inserts merged files after newer flushes, so concat
+    order is NOT write order. Sort by (series, ts) with seq as the
+    tiebreaker, keep each duplicate run's last row, and blank it when
+    that winner is a DELETE tombstone (the contract ops/dedup.py's
+    sort_dedup enforces for SQL scans)."""
+    from greptimedb_tpu.storage.region import OP_PUT
+
+    order = jnp.lexsort((d_seq, d_ts, d_sidx))
+    d_sidx, d_ts, d_vals, d_op = (d_sidx[order], d_ts[order],
+                                  d_vals[order], d_op[order])
+    nxt_s = jnp.concatenate([d_sidx[1:], jnp.full((1,), -1, d_sidx.dtype)])
+    nxt_t = jnp.concatenate([d_ts[1:], jnp.full((1,), -jnp.inf)])
+    dup_next = (d_sidx == nxt_s) & (d_ts == nxt_t)
+    keep = ~dup_next & (d_op == OP_PUT)
+    return d_sidx, d_ts, jnp.where(keep, d_vals, jnp.nan)
+
+
+def _factorize_series(combined: np.ndarray) -> tuple:
+    """(sorted distinct series keys, each row's index among them, a row
+    permutation or None). Rows of one SST come series by series: where
+    every series is ONE run of rows, the runs are ranked instead of the
+    rows sorted, and if the runs do not ascend (a metric-engine table:
+    label-set order is not tag order) the permutation that makes them
+    ascend is returned for the caller to apply to its row arrays — the
+    series index is then non-decreasing along the rows."""
+    n = len(combined)
+    cuts = np.flatnonzero(combined[1:] != combined[:-1]) + 1
+    starts = np.concatenate([np.zeros(1, dtype=np.int64), cuts])
+    uniq, rank = np.unique(combined[starts], return_inverse=True)
+    if len(uniq) != len(starts):
+        uniq, sidx = np.unique(combined, return_inverse=True)
+        return uniq, sidx, None
+    lens = np.diff(np.append(starts, n))
+    if len(rank) < 2 or bool(np.all(np.diff(rank) > 0)):
+        return uniq, np.repeat(rank, lens), None
+    by_rank = np.argsort(rank)
+    lens = lens[by_rank]
+    ends = np.cumsum(lens)
+    regroup = np.repeat(starts[by_rank] - (ends - lens), lens) \
+        + np.arange(n, dtype=np.int64)
+    return uniq, np.repeat(np.arange(len(uniq), dtype=np.int64), lens), \
+        regroup
+
+
+def _series_labels(uniq: np.ndarray, tag_names, sizes, tag_dicts) -> list:
+    """The label set of each distinct series key: the mixed-radix key
+    split per tag as arrays, the values gathered from the tag
+    dictionaries; an absent tag (code -1) is left out of the set."""
+    cols = []
+    stride = 1
+    for t, size in zip(reversed(tag_names), reversed(sizes)):
+        code = (uniq // stride % size) - 1
+        stride *= size
+        vals = np.full(len(uniq), None, dtype=object)
+        ok = code >= 0
+        vals[ok] = np.asarray(tag_dicts[t], dtype=object)[code[ok]]
+        cols.append(vals.tolist())
+    cols.reverse()
+    if all(None not in c for c in cols):
+        return [dict(zip(tag_names, combo)) for combo in zip(*cols)]
+    return [{t: v for t, v in zip(tag_names, combo) if v is not None}
+            for combo in zip(*cols)]
 
 
 def _matcher_mask(m: Matcher, scan, tag_names) -> np.ndarray:

@@ -1262,27 +1262,38 @@ class QueryEngine:
     def _create_metric_table(self, db, name, schema: Schema, stmt, ctx) -> QueryResult:
         """CREATE TABLE ... ENGINE=metric: a logical table multiplexed onto
         the shared physical region (reference metric-engine, SURVEY §2.3)."""
+        if self.catalog.table_exists(db, name):
+            if stmt.if_not_exists:
+                return QueryResult.of_affected(0)
+            raise CatalogError(f"table {db}.{name} already exists")
+        self.create_metric_table(
+            db, name, schema, options=dict(stmt.options),
+            column_order=[c.name for c in stmt.columns] or None)
+        return QueryResult.of_affected(0)
+
+    def create_metric_table(self, db: str, name: str, schema: Schema,
+                            options: Optional[dict] = None,
+                            column_order: Optional[list] = None) -> TableInfo:
+        """A logical table on the database's physical region, in the
+        catalog as `engine=metric` (the statement's route, and the one
+        Prometheus remote write takes for a metric name it has not
+        seen)."""
         if self.metric_engine is None:
             raise PlanError("metric engine not configured")
         fields = schema.field_columns
         if len(fields) != 1:
             raise PlanError("metric engine tables need exactly one field column")
-        if self.catalog.table_exists(db, name):
-            if stmt.if_not_exists:
-                return QueryResult.of_affected(0)
-            raise CatalogError(f"table {db}.{name} already exists")
         meta = self.metric_engine.create_logical_table(
             db, name, [c.name for c in schema.tag_columns],
             ts_name=schema.time_index.name, value_name=fields[0].name,
         )
-        self.catalog.create_table(
-            db, name, schema, options={**dict(stmt.options), "engine": "metric"},
-            if_not_exists=True,
-            column_order=[c.name for c in stmt.columns] or None,
+        info = self.catalog.create_table(
+            db, name, schema, options={**(options or {}), "engine": "metric"},
+            if_not_exists=True, column_order=column_order,
             region_ids=[meta.logical_region],
         )
         self._open_regions.add(meta.logical_region)
-        return QueryResult.of_affected(0)
+        return info
 
     def _drop_table(self, stmt: ast.DropTable, ctx: QueryContext) -> QueryResult:
         db = ctx.db

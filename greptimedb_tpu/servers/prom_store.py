@@ -93,6 +93,7 @@ def handle_remote_write(query_engine, body: bytes, db: str = "public") -> int:
         # regardless of series arrival order), via the shared schema
         # bootstrap every front door uses
         slab.tags = {k: slab.tags[k] for k in sorted(slab.tags)}
+        _ensure_logical_table(query_engine, ctx, table, slab)
         info = ensure_table(query_engine, ctx, table, slab,
                             time_index=GREPTIME_TIMESTAMP,
                             value_field=GREPTIME_VALUE)
@@ -100,6 +101,29 @@ def handle_remote_write(query_engine, body: bytes, db: str = "public") -> int:
         total += query_engine._sharded_write(info, batch, delete=False)
     INGEST_ROWS.inc(total)
     return total
+
+
+def _ensure_logical_table(query_engine, ctx, table: str, slab) -> None:
+    """A metric name seen for the first time becomes a logical table of
+    the metric engine on the database's one physical region (the
+    reference's default, `[prom_store] with_metric_engine = true`), not
+    a region of its own. A table that exists is whatever it is; a
+    process without a metric engine (no local region engine to
+    multiplex onto) keeps creating plain tables."""
+    from greptimedb_tpu.datatypes.schema import ColumnSchema, Schema
+    from greptimedb_tpu.datatypes.types import DataType, SemanticType
+
+    qe = query_engine
+    if qe.metric_engine is None or qe.catalog.table_exists(ctx.db, table):
+        return
+    cols = [ColumnSchema(t, DataType.STRING, SemanticType.TAG)
+            for t in slab.tags]
+    cols.append(ColumnSchema(GREPTIME_TIMESTAMP,
+                             DataType.TIMESTAMP_MILLISECOND,
+                             SemanticType.TIMESTAMP, nullable=False))
+    cols.append(ColumnSchema(GREPTIME_VALUE, DataType.FLOAT64,
+                             SemanticType.FIELD))
+    qe.create_metric_table(ctx.db, table, Schema(cols))
 
 
 def _sanitize(metric: str) -> str:

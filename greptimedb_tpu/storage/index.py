@@ -32,6 +32,8 @@ Blob binary layout (little-endian, blob type "gtpu-inverted-index-v1"):
 
 from __future__ import annotations
 
+import hashlib
+import logging
 import os
 import re
 import struct
@@ -43,8 +45,12 @@ import numpy as np
 from greptimedb_tpu.objectstore import default_store
 from greptimedb_tpu.storage.puffin import PuffinReader, PuffinWriter
 
+logger = logging.getLogger(__name__)
+
 BLOB_TYPE = "gtpu-inverted-index-v1"
 DEFAULT_SEGMENT_ROWS = 8192
+#: terms x segments above which a tag's blob is not written (8 MiB packed)
+MAX_BITMAP_CELLS = 1 << 26
 
 
 # ---- predicates ------------------------------------------------------------
@@ -80,7 +86,29 @@ class Regex:
     pattern: str
 
 
-Predicate = Union[InSet, Range, Regex]
+@dataclass(frozen=True, eq=False)
+class CodeSet:
+    """value's code in the region's tag registry is one of `codes` — an
+    `=`-set the caller already resolved against the dictionary (the
+    metric engine's label matchers: ten thousand label sets are ten
+    thousand ints, not strings to sort and look up). Local to one
+    region: it drives the scan's exact row filter, never an SST's index
+    (which knows terms, not codes) and never the wire."""
+
+    codes: np.ndarray  # sorted int64
+    digest: str
+
+    @staticmethod
+    def of(codes) -> "CodeSet":
+        codes = np.unique(np.asarray(codes, dtype=np.int64))
+        return CodeSet(codes, hashlib.blake2b(
+            codes.tobytes(), digest_size=12).hexdigest())
+
+    def __repr__(self) -> str:  # the scan caches' key
+        return f"CodeSet({len(self.codes)}:{self.digest})"
+
+
+Predicate = Union[InSet, Range, Regex, CodeSet]
 
 # A predicate map is tag name -> tuple of Predicates (ANDed), but a plain
 # set of values (the historical form, still produced by callers like
@@ -92,7 +120,7 @@ PredicateMap = dict[str, object]
 def _norm_preds(v) -> tuple[Predicate, ...]:
     if isinstance(v, (set, frozenset, list)) and not isinstance(v, tuple):
         return (InSet.of(v),)
-    if isinstance(v, (InSet, Range, Regex)):
+    if isinstance(v, (InSet, Range, Regex, CodeSet)):
         return (v,)
     out = []
     for p in v:
@@ -130,7 +158,7 @@ def serialize_predicates(preds: Optional[PredicateMap]) -> Optional[dict]:
                 ser.append({"in": list(p.values)})
             elif isinstance(p, Range):
                 ser.append({"range": [p.lo, p.hi, p.lo_inc, p.hi_inc]})
-            else:
+            elif isinstance(p, Regex):
                 ser.append({"regex": p.pattern})
         out[k] = ser
     return out
@@ -213,11 +241,17 @@ class InvertedIndexWriter:
         for tag, codes in tag_codes.items():
             blob = self._build_blob(
                 np.asarray(codes), np.asarray(tag_dicts[tag]), n_segments)
-            w.add_blob(BLOB_TYPE, blob, {"column": tag})
+            if blob is None:
+                logger.info(
+                    "sst %s: no index blob for tag %s (terms x %d segments "
+                    "> %d): scans prune nothing on it in this file",
+                    file_id, tag, n_segments, MAX_BITMAP_CELLS)
+            else:
+                w.add_blob(BLOB_TYPE, blob, {"column": tag})
         self.store.write(self.path(file_id), w.finish())
 
     def _build_blob(self, codes: np.ndarray, values: np.ndarray,
-                    n_segments: int) -> bytes:
+                    n_segments: int) -> Optional[bytes]:
         seg = self.segment_rows
         n = len(codes)
         seg_ids = np.arange(n, dtype=np.int64) // seg
@@ -227,6 +261,14 @@ class InvertedIndexWriter:
         # distinct codes present, mapped to their sorted-term order
         present = np.unique(codes[~null_rows]) if (~null_rows).any() \
             else np.empty(0, dtype=codes.dtype)
+        if len(present) * n_segments > MAX_BITMAP_CELLS:
+            # one bitmap row per term: a tag with a term per series (the
+            # metric engine's `__labels`) would cost more to build, keep
+            # and search than the row groups it could rule out. A file
+            # without the blob prunes nothing on this tag (IndexApplier:
+            # "tag not indexed in this file") and the scan's exact row
+            # filter decides alone
+            return None
         terms = np.asarray([str(values[c]) for c in present], dtype=object)
         order = np.argsort(terms, kind="stable")
         terms = terms[order]
@@ -276,8 +318,8 @@ class _TagIndex:
     (one byte row per 8 segments); only the term rows a predicate actually
     hits are unpacked — O(hits), not O(n_terms * n_segments)."""
 
-    __slots__ = ("terms", "_packed", "_n_terms", "_has_null", "n_segments",
-                 "segment_rows")
+    __slots__ = ("_terms", "_term_blob", "_term_offsets", "_packed",
+                 "_n_terms", "_has_null", "n_segments", "segment_rows")
 
     def __init__(self, data: bytes):
         n_terms, n_segments, seg_rows, has_null = \
@@ -286,11 +328,12 @@ class _TagIndex:
         offsets = np.frombuffer(data, dtype=np.uint32, count=n_terms + 1,
                                 offset=off)
         off += 4 * (n_terms + 1)
-        blob = data[off:off + int(offsets[-1])]
-        self.terms = [
-            blob[offsets[i]:offsets[i + 1]].decode()
-            for i in range(n_terms)
-        ]
+        # decoded when a predicate first asks for terms: a scan that
+        # selects on another tag (or by code: CodeSet) never pays for a
+        # blob that holds a term per series
+        self._terms = None
+        self._term_offsets = offsets
+        self._term_blob = data[off:off + int(offsets[-1])]
         off += int(offsets[-1])
         width = (n_segments + 7) // 8
         rows = n_terms + (1 if has_null else 0)
@@ -302,6 +345,14 @@ class _TagIndex:
         self.n_segments = n_segments
         self.segment_rows = seg_rows
 
+    @property
+    def terms(self) -> list:
+        if self._terms is None:
+            blob, offsets = self._term_blob, self._term_offsets
+            self._terms = [blob[offsets[i]:offsets[i + 1]].decode()
+                           for i in range(self._n_terms)]
+        return self._terms
+
     # each evaluator returns a bool[n_segments] of segments that MAY match
 
     def eval(self, pred: Predicate) -> np.ndarray:
@@ -309,7 +360,9 @@ class _TagIndex:
             return self._eval_in(pred.values)
         if isinstance(pred, Range):
             return self._eval_range(pred)
-        return self._eval_regex(pred.pattern)
+        if isinstance(pred, Regex):
+            return self._eval_regex(pred.pattern)
+        return np.ones(self.n_segments, dtype=bool)  # CodeSet: no terms
 
     def _or_rows(self, rows: np.ndarray, with_null: bool) -> np.ndarray:
         idx = list(np.asarray(rows, dtype=np.int64))
@@ -390,18 +443,17 @@ class IndexApplier:
     index / nothing is pruned (scan everything), or [] when provably
     empty."""
 
-    CACHE_FILES = 64  # parsed per-file indexes kept (LRU)
-
     def __init__(self, sst_dir: str, store=None):
-        from collections import OrderedDict
-
         self.sst_dir = sst_dir
         self.store = default_store(store)
-        self._cache: "OrderedDict[str, Optional[dict]]" = OrderedDict()
+        # the parsed index of every file asked about, until the file is
+        # deleted (`invalidate`): a scan asks every file of the region,
+        # so a cache of fewer slots than the region has files re-reads
+        # them all on every scan
+        self._cache: dict[str, Optional[dict]] = {}
 
     def _load(self, file_id: str) -> Optional[dict]:
         if file_id in self._cache:
-            self._cache.move_to_end(file_id)
             return self._cache[file_id]
         entry = None
         path = _index_path(self.sst_dir, file_id)
@@ -412,8 +464,6 @@ class IndexApplier:
                 entry["tags"][blob.properties.get("column")] = \
                     _TagIndex(reader.read_blob(blob))
         self._cache[file_id] = entry
-        while len(self._cache) > self.CACHE_FILES:
-            self._cache.popitem(last=False)
         return entry
 
     def select(self, file_id: str,

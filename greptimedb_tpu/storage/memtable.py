@@ -39,6 +39,7 @@ class TagRegistry:
         # and scan-time SST dictionary remapping (no region lock, by
         # design): the registry guards itself
         self._lock = threading.Lock()
+        self._arrays: dict[str, np.ndarray] = {}
 
     def encode(self, name: str, strings: np.ndarray) -> np.ndarray:
         """Vectorized: unique the batch (O(n log n) in C), then walk only
@@ -68,8 +69,41 @@ class TagRegistry:
         return self.encode(name, file_values)
 
     def dict_array(self, name: str) -> np.ndarray:
+        """The tag's dictionary as an object array (read-only, shared).
+        Codes are append-only, so the array built for a length stays
+        right for it: a scan of a million-value dictionary does not
+        rebuild it per request."""
         with self._lock:
-            return np.asarray(self.values[name], dtype=object)
+            vals = self.values[name]
+            arr = self._arrays.get(name)
+            if arr is None or len(arr) != len(vals):
+                arr = np.asarray(vals, dtype=object)
+                arr.flags.writeable = False
+                self._arrays[name] = arr
+            return arr
+
+    def values_from(self, name: str, start: int) -> list:
+        """The dictionary's values from code `start` on."""
+        with self._lock:
+            return self.values[name][start:]
+
+    def codes_of(self, name: str, values) -> list[int]:
+        """Codes of the given values that the dictionary holds."""
+        with self._lock:
+            table = self.tables[name]
+            return [c for c in (table.get(v) for v in values)
+                    if c is not None]
+
+    def restore(self, name: str, values) -> None:
+        """Append a persisted dictionary in its order (code = position),
+        skipping what is already there."""
+        with self._lock:
+            table = self.tables[name]
+            vals = self.values[name]
+            for v in values:
+                if v not in table:
+                    table[v] = len(vals)
+                    vals.append(v)
 
     def cardinality(self, name: str) -> int:
         with self._lock:

@@ -9,9 +9,13 @@ TPU-native re-design: the physical data region stores exactly two tag
 columns — `__table` (logical table name) and `__labels` (the canonical
 serialized label set, i.e. THE SERIES ID as one dictionary code) — plus
 `greptime_timestamp` / `greptime_value`. Logical tag columns are virtual:
-at scan time each distinct label-set value is parsed once (dictionary-sized
-work, not row-sized) and per-tag code columns are derived by mapping label-
-set codes through a small lookup table — a single numpy gather. This keeps
+each label-set value of the region's `__labels` dictionary is parsed ONCE
+per process into per-tag code columns over the dictionary (`_LabelCatalog`,
+extended when the dictionary grows, never rebuilt), and a scan derives a
+table's tag columns by one numpy gather through them. A scan of one
+logical table therefore costs what that table's rows cost, whatever else
+the region holds; `=` / `=~` predicates on virtual tags become a set of
+`__labels` codes pushed to the physical scan beside `__table`. This keeps
 the device kernel ABI identical to normal tables while the storage side
 collapses arbitrary table counts into one LSM region.
 
@@ -22,6 +26,10 @@ backend under `__metric_engine/`.
 from __future__ import annotations
 
 import json
+import re
+import threading
+import time
+import weakref
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -34,6 +42,13 @@ from greptimedb_tpu.datatypes.types import DataType, SemanticType
 from greptimedb_tpu.datatypes.vector import DictVector
 from greptimedb_tpu.storage.engine import RegionEngine
 from greptimedb_tpu.storage.region import ScanData
+from greptimedb_tpu.utils import tracing
+from greptimedb_tpu.utils.metrics import (
+    METRIC_ENGINE_LABEL_SETS_PARSED,
+    METRIC_ENGINE_ROWS,
+    METRIC_ENGINE_SCAN_SECONDS,
+    METRIC_ENGINE_WRITE_ROWS,
+)
 
 TABLE_COL = "__table"
 LABELS_COL = "__labels"
@@ -70,6 +85,139 @@ def decode_labels(s: str) -> dict[str, str]:
     return out
 
 
+class _TagColumn:
+    """One virtual tag over a region's label sets: `codes[label_code]` is
+    the tag's value code in that label set (-1: the set lacks the tag),
+    against an append-only value list (codes never move)."""
+
+    def __init__(self, size: int):
+        self.values: list[str] = []
+        self.index: dict[str, int] = {}
+        self.codes = np.full(size, -1, dtype=np.int32)
+        self._array: Optional[np.ndarray] = None
+
+    def intern(self, value: str) -> int:
+        code = self.index.get(value)
+        if code is None:
+            code = self.index[value] = len(self.values)
+            self.values.append(value)
+        return code
+
+    def values_array(self) -> np.ndarray:
+        if self._array is None or len(self._array) != len(self.values):
+            self._array = np.asarray(self.values, dtype=object)
+        return self._array
+
+
+class _LabelCatalog:
+    """A physical region's `__labels` dictionary, parsed: per tag name
+    one `_TagColumn` over the dictionary's codes. Registry codes are
+    append-only, so the catalog is right for the prefix it has parsed
+    and `sync` parses only what the dictionary has grown by."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.parsed = 0
+        self.tags: dict[str, _TagColumn] = {}
+
+    def sync(self, registry) -> tuple[str, int]:
+        """Bring the catalog up to the dictionary. Returns (hit | extend
+        | miss, label sets parsed by this call)."""
+        if registry.cardinality(LABELS_COL) == self.parsed:
+            return "hit", 0
+        with self._lock:
+            new = registry.values_from(LABELS_COL, self.parsed)
+            if not new:
+                return "hit", 0
+            state = "extend" if self.parsed else "miss"
+            self._parse(new)
+            METRIC_ENGINE_LABEL_SETS_PARSED.inc(len(new))
+            return state, len(new)
+
+    def _parse(self, new: list) -> None:
+        """decode_labels over `new`, column-wise (pyarrow's string
+        kernels; Python touches distinct tag values only)."""
+        import pyarrow as pa
+        import pyarrow.compute as pc
+
+        base, n = self.parsed, len(new)
+        size = base + n
+        for col in self.tags.values():
+            col.codes = np.concatenate(
+                [col.codes, np.full(n, -1, dtype=np.int32)])
+        parts = pc.split_pattern(pa.array(new, type=pa.string()), "\x1f")
+        owner = np.repeat(np.arange(n, dtype=np.int64),
+                          np.diff(parts.offsets.to_numpy()))
+        flat = parts.flatten()
+        # "" (no tags at all) splits to one part without a pair
+        pair = pc.match_substring(flat, "=")
+        flat = flat.filter(pair)
+        owner = owner[pair.to_numpy(zero_copy_only=False)]
+        if len(flat):
+            kv = pc.split_pattern(flat, "=", max_splits=1)
+            keys = pc.list_element(kv, 0).dictionary_encode()
+            vals = pc.list_element(kv, 1)
+            key_codes = keys.indices.to_numpy()
+            for kid, tag in enumerate(keys.dictionary.to_pylist()):
+                col = self.tags.get(tag)
+                if col is None:
+                    col = self.tags[tag] = _TagColumn(size)
+                sel = np.flatnonzero(key_codes == kid)
+                enc = vals.take(pa.array(sel)).dictionary_encode()
+                mapping = np.asarray(
+                    [col.intern(v) for v in enc.dictionary.to_pylist()],
+                    dtype=np.int32)
+                col.codes[base + owner[sel]] = \
+                    mapping[enc.indices.to_numpy()]
+        self.parsed = size
+
+    def column(self, tag: str) -> tuple[np.ndarray, np.ndarray]:
+        """(codes over the dictionary, value dictionary) of a tag."""
+        col = self.tags.get(tag)
+        if col is None:
+            return (np.full(self.parsed, -1, dtype=np.int32),
+                    np.asarray([], dtype=object))
+        return col.codes, col.values_array()
+
+    def matching(self, tag: str, pred) -> Optional[np.ndarray]:
+        """bool[label sets] for one `=`-set or regex predicate on a
+        virtual tag (an absent tag is the empty string, as in PromQL's
+        data model), or None for a predicate that cannot prune."""
+        from greptimedb_tpu.storage.index import InSet, Regex
+
+        codes, values = self.column(tag)
+        lut = np.zeros(len(values) + 1, dtype=bool)  # slot -1: tag absent
+        if isinstance(pred, InSet):
+            index = self.tags[tag].index if tag in self.tags else {}
+            lut[np.asarray([index[v] for v in pred.values if v in index],
+                           dtype=np.int64)] = True
+            lut[-1] = "" in pred.values
+        elif isinstance(pred, Regex):
+            try:
+                rx = re.compile(pred.pattern)
+            except re.error:
+                return None
+            lut[:-1] = [rx.fullmatch(v) is not None for v in values]
+            lut[-1] = rx.fullmatch("") is not None
+        else:
+            return None
+        return lut[codes]
+
+
+#: physical Region instance -> its catalog (a TRUNCATE recreates the
+#: region object, and with it the dictionary: the catalog goes with it)
+_CATALOGS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+_CATALOGS_LOCK = threading.Lock()
+
+
+def _catalog_of(phys) -> _LabelCatalog:
+    with _CATALOGS_LOCK:
+        cat = _CATALOGS.get(phys)
+        if cat is None:
+            cat = _CATALOGS[phys] = _LabelCatalog()
+        return cat
+
+
 @dataclass
 class LogicalTableMeta:
     name: str
@@ -98,36 +246,72 @@ class LogicalRegion:
         self.engine = engine
         self.region_id = meta.logical_region
         self.schema = logical_schema(meta.tag_names, meta.ts_name, meta.value_name)
+        # (data_version, {tag: values}) of the last label-values answer
+        self._label_values: Optional[tuple] = None
 
     # -- write: logical batch -> physical rows --
     def write(self, batch: RecordBatch, op: int) -> int:
+        return self.write_many([(batch, op)])[0]
+
+    def write_many(self, items: list[tuple[RecordBatch, int]]) -> list[int]:
+        """Several mutations of this table as ONE group commit of the
+        physical region (what the write workers hand a region)."""
         phys = self.engine.region(self.meta.physical_region)
-        n = batch.num_rows
-        tag_cols = {}
-        for t in self.meta.tag_names:
-            col = batch.columns.get(t)
-            tag_cols[t] = (
-                col.decode() if isinstance(col, DictVector) else
-                (np.asarray(col) if col is not None else np.full(n, None, dtype=object))
-            )
-        labels = []
-        for i in range(n):
-            labels.append(encode_labels(
-                {t: (None if tag_cols[t][i] is None else str(tag_cols[t][i]))
-                 for t in self.meta.tag_names}
-            ))
-        cols = {
-            TABLE_COL: DictVector.encode([self.meta.name] * n),
-            LABELS_COL: DictVector.encode(labels),
-            TS_COL: np.asarray(batch.columns[self.meta.ts_name], dtype=np.int64),
-            VALUE_COL: np.asarray(batch.columns[self.meta.value_name],
-                                  dtype=np.float64),
-        }
-        written = phys.write(RecordBatch(physical_schema(), cols), op)
+        written = phys.write_many(
+            [(self._physical_batch(batch), op) for batch, op in items])
+        METRIC_ENGINE_WRITE_ROWS.inc(sum(written))
         if phys.memtable_bytes >= self.engine.config.flush_threshold_bytes:
             phys.flush()
             phys.compact()
         return written
+
+    def _physical_batch(self, batch: RecordBatch) -> RecordBatch:
+        n = batch.num_rows
+        return RecordBatch(physical_schema(), {
+            # one dictionary value, whatever the batch's size
+            TABLE_COL: DictVector(np.zeros(n, dtype=np.int32),
+                                  np.asarray([self.meta.name], dtype=object)),
+            LABELS_COL: self._label_column(batch, n),
+            TS_COL: np.asarray(batch.columns[self.meta.ts_name],
+                               dtype=np.int64),
+            VALUE_COL: np.asarray(batch.columns[self.meta.value_name],
+                                  dtype=np.float64),
+        })
+
+    def _label_column(self, batch: RecordBatch, n: int) -> DictVector:
+        """The batch's `__labels` column: the tag columns factorised into
+        their distinct combinations, `encode_labels` once per
+        combination (its string, key by sorted key), codes gathered."""
+        key = np.zeros(n, dtype=np.int64)
+        span = 1
+        codes_of, pairs_of = [], []
+        for t in self.meta.tag_names:  # sorted at creation
+            col = batch.columns.get(t)
+            if col is None:
+                continue
+            if not isinstance(col, DictVector):
+                col = DictVector.encode(np.asarray(col, dtype=object))
+            card = len(col.values) + 1
+            if span * card >= 1 << 62:
+                # keep the mixed radix inside int64
+                _, key = np.unique(key, return_inverse=True)
+                span = int(key.max()) + 1 if n else 1
+            key = key * card + (col.codes.astype(np.int64) + 1)
+            span *= card
+            codes_of.append(col.codes)
+            # slot 0 is NULL: encode_labels drops the pair
+            pairs_of.append([None] + [f"{t}={v}" for v in col.values])
+        if not codes_of or n == 0:
+            return DictVector(np.zeros(n, dtype=np.int32),
+                              np.asarray([""], dtype=object))
+        _, first, inverse = np.unique(key, return_index=True,
+                                      return_inverse=True)
+        picked = [[pairs[c] for c in (codes[first] + 1).tolist()]
+                  for codes, pairs in zip(codes_of, pairs_of)]
+        labels = ["\x1f".join(p for p in combo if p is not None)
+                  for combo in zip(*picked)]
+        return DictVector(inverse.astype(np.int32),
+                          np.asarray(labels, dtype=object))
 
     @property
     def memtable_bytes(self) -> int:
@@ -165,74 +349,122 @@ class LogicalRegion:
             raise NotImplementedError(
                 "seq_min scans are not supported on metric-engine "
                 "logical regions")
+        with tracing.span("metric_engine_scan",
+                          table=self.meta.name) as attrs:
+            return self._scan(ts_range, projection, tag_predicates, attrs)
+
+    def _scan(self, ts_range, projection, tag_predicates,
+              attrs: dict) -> Optional[ScanData]:
+        from greptimedb_tpu.storage.index import CodeSet, normalize_predicates
+
         phys = self.engine.region(self.meta.physical_region)
-        # push the table selector down; label predicates are mapped to
-        # label-set values that contain the wanted pair (dictionary-sized)
-        phys_preds: dict[str, set] = {TABLE_COL: {self.meta.name}}
+        catalog = _catalog_of(phys)
+        seconds = {"physical": 0.0, "labels": 0.0, "project": 0.0}
+        attrs.update(physical_rows=0, logical_rows=0, label_sets_parsed=0,
+                     label_cache="hit")
+
+        def sync() -> None:
+            t0 = time.perf_counter()
+            state, parsed = catalog.sync(phys.registry)
+            seconds["labels"] += time.perf_counter() - t0
+            if parsed:
+                attrs["label_sets_parsed"] += parsed
+                attrs["label_cache"] = state
+
+        # push the table selector down, and with it the label sets that
+        # can match the `=` / `=~` predicates on virtual tags
+        sync()
+        phys_preds: dict = {TABLE_COL: {self.meta.name}}
+        allowed = None
+        for tag, preds in normalize_predicates(tag_predicates).items():
+            if tag not in self.meta.tag_names:
+                continue
+            for p in preds:
+                m = catalog.matching(tag, p)
+                if m is not None:
+                    allowed = m if allowed is None else (allowed & m)
+        if allowed is not None and not allowed.all():
+            phys_preds[LABELS_COL] = CodeSet.of(np.flatnonzero(allowed))
+        t0 = time.perf_counter()
         scan = phys.scan(ts_range, None, phys_preds)
-        if scan is None:
+        tcodes = phys.registry.codes_of(TABLE_COL, [self.meta.name])
+        if scan is None or not tcodes:
             return None
-        table_dict = scan.tag_dicts[TABLE_COL]
-        tcodes = np.where(np.asarray(table_dict).astype(str) == self.meta.name)[0]
-        if len(tcodes) == 0:
+        attrs["physical_rows"] = decoded = \
+            (scan.stats or {}).get("rows_prefilter", scan.num_rows)
+        # the physical scan filters rows exactly on `=`-sets; whatever
+        # else it may hand back (a snapshot without the row filter)
+        # drops here
+        keep = scan.columns[TABLE_COL] == tcodes[0]
+        idx = None if keep.all() else np.flatnonzero(keep)
+
+        def rows(arr):
+            return arr if idx is None else arr[idx]
+
+        label_codes = rows(scan.columns[LABELS_COL])
+        seconds["physical"] = time.perf_counter() - t0
+        if not len(label_codes):
             return None
-        mask = scan.columns[TABLE_COL] == tcodes[0]
-        if not mask.any():
-            return None
-        idx = np.nonzero(mask)[0]
-        labels_dict = np.asarray(scan.tag_dicts[LABELS_COL]).astype(str)
-        label_codes = scan.columns[LABELS_COL][idx]
-        # dictionary-sized parse: label-set value -> per-tag value
-        parsed = [decode_labels(v) for v in labels_dict]
+        sync()  # label sets written between the first sync and the snapshot
+
+        t0 = time.perf_counter()
         columns: dict[str, np.ndarray] = {}
         tag_dicts: dict[str, np.ndarray] = {}
         names = projection or self.schema.names
         # all tags always materialize (dedup needs the full primary key,
-        # Region._scan_columns invariant); each is one dictionary-sized
-        # parse + one numpy gather
+        # Region._scan_columns invariant); each is one numpy gather
         for t in self.meta.tag_names:
-            per_set = np.asarray([p.get(t) for p in parsed], dtype=object)
-            present = np.asarray([v for v in per_set if v is not None], dtype=object)
-            uniq = np.unique(present.astype(str)) if len(present) else np.asarray([], dtype=object)
-            lookup = {v: i for i, v in enumerate(uniq)}
-            remap = np.asarray(
-                [(-1 if v is None else lookup[str(v)]) for v in per_set],
-                dtype=np.int32,
-            )
-            columns[t] = remap[label_codes]
-            tag_dicts[t] = uniq.astype(object)
-        columns[self.meta.ts_name] = scan.columns[TS_COL][idx]
+            codes, tag_dicts[t] = catalog.column(t)
+            columns[t] = codes[label_codes]
+        columns[self.meta.ts_name] = rows(scan.columns[TS_COL])
         if self.meta.value_name in names:
-            columns[self.meta.value_name] = scan.columns[VALUE_COL][idx]
-        # series key for dedup: the label-set code itself (denser and
-        # cheaper than re-combining the virtual tags)
-        return ScanData(
+            columns[self.meta.value_name] = rows(scan.columns[VALUE_COL])
+        out = ScanData(
             schema=self.schema,
             columns=columns,
-            seq=scan.seq[idx],
-            op_type=scan.op_type[idx],
+            seq=rows(scan.seq),
+            op_type=rows(scan.op_type),
             tag_dicts=tag_dicts,
-            num_rows=int(len(idx)),
+            num_rows=int(len(label_codes)),
             needs_dedup=scan.needs_dedup,
             region_id=self.region_id,
             data_version=scan.data_version,
+            incarnation=scan.incarnation,
             scan_fingerprint=("metric", self.meta.name, ts_range,
                               tuple(names or ()), scan.scan_fingerprint),
         )
+        seconds["project"] = time.perf_counter() - t0
+        for phase, took in seconds.items():
+            METRIC_ENGINE_SCAN_SECONDS.observe(took, phase=phase)
+        METRIC_ENGINE_ROWS.inc(float(decoded), kind="physical_decoded")
+        METRIC_ENGINE_ROWS.inc(float(out.num_rows), kind="logical_returned")
+        attrs["logical_rows"] = out.num_rows
+        return out
 
 
 class _VirtualRegistry:
-    """Registry-shaped accessor for label values (HTTP label-values API)."""
+    """Registry-shaped accessor for label values (HTTP label-values API):
+    per tag, the values the table's label sets carry, read off the
+    region's label catalog."""
 
     def __init__(self, region: LogicalRegion):
         self._region = region
 
     @property
     def values(self) -> dict[str, list[str]]:
-        scan = self._region.scan()
-        if scan is None:
-            return {t: [] for t in self._region.meta.tag_names}
-        return {t: list(v) for t, v in scan.tag_dicts.items()}
+        region = self._region
+        memo = region._label_values
+        if memo is not None and memo[0] == region.data_version:
+            return memo[1]
+        version = region.data_version
+        out: dict[str, list[str]] = {t: [] for t in region.meta.tag_names}
+        scan = region.scan(projection=[region.meta.ts_name])
+        if scan is not None:
+            for t in region.meta.tag_names:
+                used = np.unique(scan.columns[t])
+                out[t] = scan.tag_dicts[t][used[used >= 0]].tolist()
+        region._label_values = (version, out)
+        return out
 
 
 def logical_schema(tag_names: list[str], ts_name: str = TS_COL,

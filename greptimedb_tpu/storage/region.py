@@ -564,6 +564,8 @@ class Region:
         self.sst_reader = SstReader(os.path.join(region_dir, "sst"), store)
         tag_names = [c.name for c in schema.tag_columns]
         self.registry = TagRegistry(tag_names)
+        # dictionary sizes as of the last manifest edit that held them
+        self._recorded_dict_sizes: Optional[dict] = None
         self.memtable = Memtable(schema, self.registry)
         self.next_seq = 0
         self.files: dict[str, FileMeta] = {}
@@ -671,8 +673,9 @@ class Region:
         # restore the tag registry snapshot taken at last flush; WAL replay
         # below re-adds any values seen since
         for name, values in st.tag_dicts.items():
-            for v in values:
-                region.registry.encode(name, np.asarray([v], dtype=object))
+            region.registry.restore(name, values)
+        region._recorded_dict_sizes = {
+            name: len(values) for name, values in st.tag_dicts.items()}
         region.next_seq = st.flushed_seq
         for entry in wal.replay(region_id, from_seq=st.flushed_seq):
             n = region.memtable.write(entry.batch, entry.seq, entry.op_type)
@@ -1361,11 +1364,23 @@ class Region:
         self.files[meta.file_id] = meta
         self._file_deletes[meta.file_id] = bool((op != OP_PUT).any())
         self.manifest.record_flush([meta], flushed_seq=self.next_seq,
-                                   tag_dicts=self.registry.snapshot())
+                                   tag_dicts=self._dicts_to_record())
         self.memtable = Memtable(self.schema, self.registry)
         self.wal.obsolete(self.region_id, self.next_seq)
         self.data_version += 1
         return meta
+
+    def _dicts_to_record(self) -> Optional[dict]:
+        """The tag dictionaries for a manifest edit, or None where they
+        have not grown since the last edit that carried them (replay
+        keeps the last ones it saw): a region whose dictionary is a
+        million label sets does not rewrite it with every flush."""
+        sizes = {c.name: self.registry.cardinality(c.name)
+                 for c in self.schema.tag_columns}
+        if sizes == self._recorded_dict_sizes:
+            return None
+        self._recorded_dict_sizes = sizes
+        return self.registry.snapshot()
 
     def _sort_order(self, cols: dict[str, np.ndarray], seq: np.ndarray) -> np.ndarray:
         keys = [seq, cols[self.schema.time_index.name]]
@@ -1480,7 +1495,7 @@ class Region:
             # replay-obsolete (acked-write loss on crash)
             self.manifest.record_flush(
                 [meta], flushed_seq=None,
-                tag_dicts=self.registry.snapshot(), removed=removed)
+                tag_dicts=self._dicts_to_record(), removed=removed)
             # defer physical deletion: concurrent scans may still hold
             # the pre-compaction file list
             now = _time.monotonic()
@@ -1489,10 +1504,14 @@ class Region:
         return meta
 
     def _tag_inset_mask(self, tag_predicates, columns):
-        """Row mask for the InSet (=/IN) parts of the tag predicates over
-        global-code columns, or None when no InSet applies. Regex/Range
-        predicates stay with the device filter."""
-        from greptimedb_tpu.storage.index import InSet, normalize_predicates
+        """Row mask for the InSet (=/IN) and CodeSet parts of the tag
+        predicates over global-code columns, or None when neither
+        applies. Regex/Range predicates stay with the device filter."""
+        from greptimedb_tpu.storage.index import (
+            CodeSet,
+            InSet,
+            normalize_predicates,
+        )
 
         keep = None
         for tag, preds in normalize_predicates(tag_predicates).items():
@@ -1501,14 +1520,17 @@ class Region:
             allowed = None
             for p in preds:
                 if isinstance(p, InSet):
-                    s = set(p.values)
-                    allowed = s if allowed is None else (allowed & s)
+                    codes = np.asarray(
+                        self.registry.codes_of(tag, p.values), dtype=np.int64)
+                elif isinstance(p, CodeSet):
+                    codes = p.codes
+                else:
+                    continue
+                allowed = codes if allowed is None \
+                    else np.intersect1d(allowed, codes)
             if allowed is None:
                 continue
-            d = self.registry.dict_array(tag)
-            codes = [c for v in allowed
-                     for c in np.flatnonzero(d == v).tolist()]
-            m = np.isin(columns[tag], np.asarray(codes, dtype=np.int64))
+            m = np.isin(columns[tag], allowed)
             keep = m if keep is None else (keep & m)
         return keep
 
@@ -1619,6 +1641,13 @@ class Region:
         if mem is not None:
             mcols, mseq, mop = mem
             mem = ({n: mcols[n] for n in names}, mseq, mop)
+        # rows read before the exact tag filter below (what index
+        # pruning left to decode): the scan's IO, which a caller that
+        # pushes predicates compares with the rows it got back
+        if not lazy:
+            decode_stats["rows_prefilter"] = \
+                sum(len(p[1]) for p in loaded) \
+                + (len(mem[1]) if mem is not None else 0)
         if tag_predicates:
             # exact row filter for equality/IN tag predicates: the
             # inverted index prunes row groups, but one row group holds

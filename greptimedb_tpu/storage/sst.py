@@ -85,25 +85,43 @@ class SstWriter:
         (tags..., ts, seq) — flush runs the device sort-dedup first."""
         ts_name = self.schema.time_index.name
         n = len(columns[ts_name])
-        arrays, fields = [], []
-        for c in self.schema.columns:
-            if c.semantic is SemanticType.TAG:
-                codes = np.asarray(columns[c.name], dtype=np.int32)
-                dv = DictVector(codes, tag_dicts[c.name])
-                arrays.append(dv.to_arrow())
-            else:
-                arrays.append(pa.array(columns[c.name], type=c.dtype.to_arrow()))
-            fields.append(pa.field(c.name, arrays[-1].type, nullable=c.nullable))
-        arrays.append(pa.array(np.asarray(seq, dtype=np.int64), type=pa.int64()))
+        tag_cols = [c.name for c in self.schema.tag_columns]
+        # parquet writes a DictionaryArray's WHOLE dictionary into every
+        # row group's dictionary page (the metric engine's `__labels`: a
+        # region-wide dictionary of every series, of which a group holds
+        # a few thousand), so each group is written with the dictionary
+        # of the values it uses: a read of some row groups decodes those
+        # values alone. Where a group uses every value, that is the
+        # whole dictionary as before
+        def arrow_columns(lo: int, hi: int) -> list:
+            out = []
+            for c in self.schema.columns:
+                col = columns[c.name][lo:hi]
+                if c.semantic is SemanticType.TAG:
+                    dv = DictVector(np.asarray(col, dtype=np.int32),
+                                    tag_dicts[c.name])
+                    out.append(dv.compact().to_arrow())
+                else:
+                    out.append(pa.array(col, type=c.dtype.to_arrow()))
+            out.append(pa.array(np.asarray(seq[lo:hi], dtype=np.int64),
+                                type=pa.int64()))
+            out.append(pa.array(np.asarray(op_type[lo:hi], dtype=np.int8),
+                                type=pa.int8()))
+            return out
+
+        fields = [pa.field(c.name,
+                           pa.dictionary(pa.int32(), pa.string())
+                           if c.semantic is SemanticType.TAG
+                           else c.dtype.to_arrow(), nullable=c.nullable)
+                  for c in self.schema.columns]
         fields.append(pa.field(SEQ_COL, pa.int64(), nullable=False))
-        arrays.append(pa.array(np.asarray(op_type, dtype=np.int8), type=pa.int8()))
         fields.append(pa.field(OP_COL, pa.int8(), nullable=False))
 
         from greptimedb_tpu.storage.format import FORMAT_VERSIONS
 
         meta = {METADATA_KEY: json.dumps(self.schema.to_dict()).encode(),
                 FORMAT_KEY: str(FORMAT_VERSIONS["sst"]).encode()}
-        table = pa.Table.from_arrays(arrays, schema=pa.schema(fields, metadata=meta))
+        pa_schema = pa.schema(fields, metadata=meta)
 
         file_id = uuid.uuid4().hex
         path = os.path.join(self.sst_dir, f"{file_id}.parquet")
@@ -129,16 +147,15 @@ class SstWriter:
                      if c.dtype.is_float}
         encodings[ts_name] = "DELTA_BINARY_PACKED"
         encodings[SEQ_COL] = "DELTA_BINARY_PACKED"
-        tag_cols = [c.name for c in self.schema.tag_columns]
-        pq.write_table(
-            table,
-            sink,
-            row_group_size=self.row_group_size,
-            compression="lz4",
-            use_dictionary=tag_cols,
-            column_encoding=encodings,
-            write_statistics=True,
-        )
+        with pq.ParquetWriter(
+                sink, pa_schema, compression="lz4", use_dictionary=tag_cols,
+                column_encoding=encodings, write_statistics=True) as w:
+            for lo in range(0, max(n, 1), self.row_group_size):
+                hi = min(lo + self.row_group_size, n)
+                w.write_table(
+                    pa.Table.from_arrays(arrow_columns(lo, hi),
+                                         schema=pa_schema),
+                    row_group_size=self.row_group_size)
         self.store.write(path, sink.getvalue())  # pa.Buffer, zero extra copy
         # build the per-file inverted index (tag value -> row-group bitmap)
         from greptimedb_tpu.storage.index import (

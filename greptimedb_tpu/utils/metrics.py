@@ -616,6 +616,27 @@ AGG_SCAN = REGISTRY.counter(
     "SST part), parts (it fetched only the parts it missed), whole "
     "(whole columns were built or read: every other path); counted once "
     "per statement, a statement that scans twice counts its costliest")
+METRIC_ENGINE_SCAN_SECONDS = REGISTRY.histogram(
+    "greptimedb_tpu_metric_engine_scan_seconds",
+    "Metric-engine logical scan wall time by phase: physical (the shared "
+    "region's scan under the __table / __labels predicates), labels "
+    "(bringing the parsed label-set catalog up to the dictionary), "
+    "project (virtual tag columns gathered from the catalog)")
+METRIC_ENGINE_ROWS = REGISTRY.counter(
+    "greptimedb_tpu_metric_engine_rows_total",
+    "Rows through metric-engine logical scans by kind: physical_decoded "
+    "(rows of the shared region the scan read before its exact __table "
+    "/ __labels row filter) and logical_returned (rows of the logical "
+    "table handed to the query)")
+METRIC_ENGINE_LABEL_SETS_PARSED = REGISTRY.counter(
+    "greptimedb_tpu_metric_engine_label_sets_parsed_total",
+    "Label-set strings of a physical region's __labels dictionary "
+    "parsed into virtual tag columns (once per label set per process: "
+    "a steady scan parses none)")
+METRIC_ENGINE_WRITE_ROWS = REGISTRY.counter(
+    "greptimedb_tpu_metric_engine_write_rows_total",
+    "Rows written through metric-engine logical tables onto their "
+    "physical region (puts and deletes)")
 SLOW_QUERIES = REGISTRY.counter(
     "greptimedb_tpu_slow_queries_total",
     "Statements slower than the slow-query threshold, by kind")

@@ -116,11 +116,20 @@ def new_trace_id() -> str:
     # os.urandom(8).hex() is ~3x cheaper than uuid4 and ids are minted
     # per request AND per span — this is hot-path cost (the <3% bench
     # overhead budget)
-    return os.urandom(8).hex()
+    return new_span_id()
+
+
+_NOT_A_NUMBER = frozenset("abcdf")
 
 
 def new_span_id() -> str:
-    return os.urandom(8).hex()
+    # the profiler reads an annotation's stat that parses as a number AS
+    # one ("123e456789012345" comes back as inf), so an id holds a hex
+    # letter no float literal has; about one draw in a thousand repeats
+    while True:
+        sid = os.urandom(8).hex()
+        if not _NOT_A_NUMBER.isdisjoint(sid):
+            return sid
 
 
 def set_trace(trace_id: Optional[str] = None) -> str:

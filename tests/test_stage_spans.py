@@ -442,7 +442,8 @@ def test_kernels_carry_their_stable_names():
     import jax
     import jax.numpy as jnp
 
-    from greptimedb_tpu.ops import window
+    from greptimedb_tpu.ops import pallas_segment, window  # noqa: F401
+    from greptimedb_tpu.promql import engine  # noqa: F401 — registers
     from greptimedb_tpu.query import physical  # noqa: F401 — registers
 
     assert {"agg_scan", "agg_scan_prepared", "agg_scan_fused",
@@ -452,7 +453,8 @@ def test_kernels_carry_their_stable_names():
             "sort_compact", "sparse_segment_agg", "sort_dedup",
             "dedup_mask", "segment_agg", "window_stats", "window_edges",
             "window_edges_grid", "window_sums_grid", "counter_adjust",
-            "extrapolated_delta"} <= device_telemetry.KERNEL_NAMES
+            "extrapolated_delta", "promql_dedup"} \
+        <= device_telemetry.KERNEL_NAMES
     lowered = window.counter_adjust.lower(
         jnp.zeros(8, jnp.int32), jnp.arange(8.0))
     # the module is jit_<name>; the scope prefixes the ops' metadata
