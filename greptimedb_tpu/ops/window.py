@@ -272,12 +272,15 @@ def counter_adjust(sidx_sorted: jax.Array, values_sorted: jax.Array) -> jax.Arra
 @kernel_name("extrapolated_delta")
 def extrapolated_delta(
     first_val, first_ts, last_val, last_ts, count, window_start, window_end,
-    is_counter: bool, is_rate: bool, range_s: float = 1.0,
+    is_counter: bool, is_rate: bool, range_s: float = 1.0, first_raw=None,
 ):
     """PromQL extrapolation (reference extrapolate_rate.rs:85-92): the raw
     last-first delta is extrapolated toward the window edges, limited to
     half an average sample interval when the edge is far. All inputs
-    [S, T] (vals [S, T, 1-channel already selected])."""
+    [S, T] (vals [S, T, 1-channel already selected]). `first_raw`: a
+    counter's first sample as written, where first_val is reset-adjusted:
+    the zero crossing is the raw value's, whatever resets the load that
+    adjusted it began before."""
     sampled = last_ts - first_ts
     delta = last_val - first_val
     cnt = count.astype(first_val.dtype)
@@ -290,7 +293,10 @@ def extrapolated_delta(
         # zero crossing
         with jax.numpy_dtype_promotion("standard"):
             slope = delta / jnp.maximum(sampled, 1e-10)
-            zero_limit = jnp.where(slope > 0, first_val / slope, jnp.inf)
+            zero_limit = jnp.where(
+                slope > 0,
+                (first_val if first_raw is None else first_raw) / slope,
+                jnp.inf)
             to_start = jnp.minimum(to_start, zero_limit)
     threshold = avg_interval * 1.1
     ext_start = jnp.where(to_start < threshold, to_start, avg_interval / 2)
@@ -431,6 +437,31 @@ def window_sums_grid(
     count_st = jnp.broadcast_to(
         count.astype(jnp.int64)[None, :, None], (S, T, 1))
     return {"sum": out_sum, "count": count_st}
+
+
+def _grid_points(grid, mat, i0, n: int) -> tuple:
+    return (jax.lax.dynamic_slice_in_dim(grid, i0, n),
+            jax.lax.dynamic_slice_in_dim(mat, i0, n, axis=1))
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+@kernel_name("grid_window")
+def grid_window(grid: jax.Array, mat: jax.Array, i0, n: int) -> tuple:
+    """Points [i0, i0 + n) of a pivot: (grid [n], mat [S, n, C]). A
+    request's own range of a pivot that spans more: the prefix sums of
+    window_sums_grid then run over that range and no longer one."""
+    return _grid_points(grid, mat, i0, n)
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+@kernel_name("grid_window_flat")
+def grid_window_flat(grid: jax.Array, mat: jax.Array, i0, n: int) -> tuple:
+    """The same points as (series, ts)-sorted samples, (sidx [S*n],
+    ts [S*n], channels [S*n, C]): what window_stats takes."""
+    g, m = _grid_points(grid, mat, i0, n)
+    S, _, C = mat.shape
+    return (jnp.repeat(jnp.arange(S, dtype=jnp.int32), n), jnp.tile(g, S),
+            m.reshape(S * n, C))
 
 
 @jax.jit

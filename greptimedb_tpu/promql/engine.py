@@ -26,7 +26,22 @@ import numpy as np
 
 from greptimedb_tpu.datatypes.types import DataType
 from greptimedb_tpu.ops.segment import segment_agg
-from greptimedb_tpu.ops.window import counter_adjust, extrapolated_delta, window_stats
+from greptimedb_tpu.ops.window import (
+    counter_adjust,
+    exclusive_cumsum,
+    extrapolated_delta,
+    window_edges_grid,
+    window_stats,
+    window_sums_grid,
+)
+from greptimedb_tpu.promql.loaded import (
+    Loaded,
+    LoadedSeries,
+    SeriesCache,
+    covered,
+    d2h,
+    h2d,
+)
 from greptimedb_tpu.promql.parser import (
     DEFAULT_LOOKBACK_S,
     Aggregate,
@@ -101,26 +116,6 @@ def _fmt_prom_value(v: float) -> str:
     if v == int(v) and abs(v) < 1e15:
         return str(int(v))
     return np.format_float_positional(v, trim="-")
-
-
-def h2d(x, dtype=None) -> jax.Array:
-    """Host -> device copy of a numpy array or python value, counted in
-    device_transfer_bytes_total{h2d} (a jax array passes through)."""
-    if isinstance(x, jax.Array):
-        return x
-    arr = jnp.asarray(x, dtype=dtype)
-    device_telemetry.count_h2d(arr.nbytes)
-    return arr
-
-
-def d2h(x, dtype=None) -> np.ndarray:
-    """Device -> host readback, counted in
-    device_transfer_bytes_total{d2h}; host values pass through
-    uncounted. Blocks until the device has produced `x`."""
-    arr = np.asarray(x, dtype=dtype)
-    if isinstance(x, jax.Array):
-        device_telemetry.count_d2h(arr.nbytes)
-    return arr
 
 
 @dataclass
@@ -267,10 +262,10 @@ class PromqlEngine:
         loaded = self._load(sel, p, ctx, window=lookback)
         if loaded is None:
             return SeriesMatrix([], jnp.zeros((0, p.T)))
-        sidx, ts, chans, labels, metric = loaded
+        sidx, ts, chans = loaded.flat()
         w = max(1, int(math.ceil(lookback / p.step)))
         st = window_stats(sidx, ts, chans, ~jnp.isnan(chans[:, 0]),
-                          p.start, p.step, len(labels), p.T, w,
+                          p.start, p.step, len(loaded.labels), p.T, w,
                           stats=("count", "last"),
                           sorted_input=_sorted_ws())
         vals = st["last"][:, :, 0]
@@ -278,7 +273,7 @@ class PromqlEngine:
         # exact lookback: bucket window may overcover; validate sample ts
         ok = lts > (h2d(p.times)[None, :] - lookback)
         vals = jnp.where(ok, vals, jnp.nan)
-        return SeriesMatrix(labels, vals, metric,
+        return SeriesMatrix(loaded.labels, vals, loaded.metric,
                             sample_ts=jnp.where(ok, lts, jnp.nan))
 
     def _range_stats(self, sel, p: EvalParams, ctx,
@@ -299,100 +294,43 @@ class PromqlEngine:
                                 extra_channels=extra_channels)
         if loaded is None:
             return None
-        sidx, ts, chans, labels, metric = loaded
+        grid_ok = not isinstance(sel, Subquery) and _edges_enabled()
         st = None
-        if "sum" in stats and set(stats) <= {"sum", "count"} \
-                and not isinstance(sel, Subquery) \
-                and _edges_enabled():
-            # sum/avg_over_time fast path: one cached cumulative sum
-            # over the pivot turns every window sum into a two-gather
-            # difference (window_sums_grid). Count-only stats skip this
-            # — the edges path below derives counts from probes alone,
-            # without materializing a pivot-sized cumsum.
-            pivot = self._grid_pivot(sidx, ts, chans, len(labels))
+        if grid_ok and "sum" in stats and set(stats) <= {"sum", "count"}:
+            # sum/avg_over_time fast path: one cumulative sum over the
+            # pivot turns every window sum into a two-gather difference
+            # (window_sums_grid). It runs over the request's OWN range of
+            # a pivot that spans more, so no difference of two prefixes
+            # is taken over a longer prefix than the range needs: a
+            # resident multi-day span costs the answer no digits.
+            # Count-only stats skip this — the edges path below derives
+            # counts from probes alone, without a pivot-sized cumsum.
+            pivot = loaded.pivot(own_range=True)
             if pivot is not None:
-                from greptimedb_tpu.ops.window import window_sums_grid
-
                 grid, mat = pivot
-                st = window_sums_grid(grid, self._grid_cumsum(mat),
+                st = window_sums_grid(grid, exclusive_cumsum(mat),
                                       p.start, p.step, p.T, w)
-        if st is None and set(stats) <= {"count", "first", "last"} \
-                and not isinstance(sel, Subquery) \
-                and _edges_enabled():
+        if st is None and grid_ok and set(stats) <= {"count", "first",
+                                                     "last"}:
             # rate-family fast path: scrape-aligned series share ONE
             # complete sample grid, so window edges are T probes into
             # the grid + column gathers from a pivoted [S, P, C] matrix
             # (ops/window.py window_edges_grid — the asymmetry the
             # numpy straw-man anchor exploits, now on device). The
-            # pivot (plus its NaN-free check: LWW tombstones ride as
-            # NaN the probes cannot mask) is cached with the loaded
-            # series, so repeated evals pay only the probes.
-            pivot = self._grid_pivot(sidx, ts, chans, len(labels))
+            # pivot is kept with the loaded series, however long the
+            # span they were loaded for: the probes find a window by
+            # its time, so repeated evals pay only the probes.
+            pivot = loaded.pivot()
             if pivot is not None:
-                from greptimedb_tpu.ops.window import window_edges_grid
-
                 grid, mat = pivot
                 st = window_edges_grid(grid, mat, p.start, p.step,
                                        p.T, w)
         if st is None:
+            sidx, ts, chans = loaded.flat()
             st = window_stats(sidx, ts, chans, ~jnp.isnan(chans[:, 0]),
-                              p.start, p.step, len(labels), p.T, w,
+                              p.start, p.step, len(loaded.labels), p.T, w,
                               stats=stats, sorted_input=_sorted_ws())
-        return st, labels, metric, w, range_s
-
-    def _grid_pivot(self, sidx, ts, chans, n_series):
-        """(grid [P], mat [S, P, C]) when every series has exactly the
-        same complete, NaN-free sample grid; None otherwise. Identity-
-        cached against the loaded arrays (which the load cache pins),
-        so detection + pivot run once per scan snapshot."""
-        ex = getattr(self.qe, "executor", None)
-        cache = getattr(ex, "_promql_pivot_cache", None) if ex else None
-        if cache is None and ex is not None:
-            cache = ex._promql_pivot_cache = []
-        if cache is not None:
-            for c_sidx, c_chans, result in cache:
-                if c_sidx is sidx and c_chans is chans:
-                    return result
-        result = None
-        n = int(chans.shape[0])
-        S = n_series
-        if S > 0 and n % S == 0:
-            P = n // S
-            ts_np = d2h(ts)
-            grid = ts_np[:P]
-            if (ts_np.reshape(S, P) == grid[None, :]).all() \
-                    and not bool(d2h(jnp.isnan(chans).any())):
-                result = (h2d(grid), chans.reshape(S, P,
-                                                           chans.shape[1]))
-        if cache is not None:
-            cache.append((sidx, chans, result))
-            del cache[:-2]  # two live scans at most (load cache holds 4)
-        return result
-
-    #: pivots larger than this don't cache their prefix sums (the
-    #: cumsum doubles the pivot's memory; recompute instead)
-    _CUMSUM_CACHE_BYTES = 512 << 20
-
-    def _grid_cumsum(self, mat):
-        """Exclusive prefix sums [S, P+1, C] over a pivoted matrix,
-        identity-cached beside the pivot (window_sums_grid consumes
-        them). Oversized pivots compute fresh each eval rather than
-        doubling resident memory."""
-        from greptimedb_tpu.ops.window import exclusive_cumsum
-
-        ex = getattr(self.qe, "executor", None)
-        cache = getattr(ex, "_promql_cumsum_cache", None) if ex else None
-        if cache is None and ex is not None:
-            cache = ex._promql_cumsum_cache = []
-        if cache is not None:
-            for c_mat, cs in cache:
-                if c_mat is mat:
-                    return cs
-        cs = exclusive_cumsum(mat)
-        if cache is not None and cs.nbytes <= self._CUMSUM_CACHE_BYTES:
-            cache.append((mat, cs))
-            del cache[:-2]
-        return cs
+        return st, loaded.labels, loaded.metric, w, range_s
 
     def _load_any(self, sel, p: EvalParams, ctx, window: float,
                   extra_channels=()):
@@ -434,7 +372,8 @@ class PromqlEngine:
         d_vals = h2d(flat[keep])
         channels = self._make_channels(d_sidx, d_ts, d_vals,
                                        extra_channels, p)
-        return d_sidx, d_ts, channels, v.labels, v.metric
+        return Loaded(LoadedSeries(v.labels, d_sidx, d_ts, channels),
+                      v.metric)
 
     def _make_channels(self, d_sidx, d_ts, d_vals, extra_channels, p):
         """Derived per-sample channels riding the window kernel alongside
@@ -461,12 +400,14 @@ class PromqlEngine:
         return jnp.stack(chans, axis=1)
 
     def _load(self, sel: VectorSelector, p: EvalParams, ctx, window: float,
-              extra_channels=()):
-        """Scan + matcher-filter + series factorization. Returns device
-        arrays sorted by (series, ts): sidx [N], ts seconds [N],
-        channels [N, C], labels, metric. Channel 0 is the raw value;
-        extra_channels in {"adjusted", "changes", "resets", "deriv"} append
-        derived channels."""
+              extra_channels=()) -> Optional[Loaded]:
+        """A selector's samples for this request: device arrays sorted
+        by (series, ts) — sidx [N], ts seconds [N], channels [N, C] —
+        or their pivot onto a shared grid, with labels and metric.
+        Channel 0 is the raw value; extra_channels in {"adjusted",
+        "changes", "resets", "prev", "deriv"} append derived channels.
+        The loaded-series cache (promql/loaded.py) is asked first, from
+        region metadata; a region is scanned only for what it lacks."""
         matchers = list(sel.matchers)
         metric = sel.metric
         field_name = None
@@ -508,140 +449,219 @@ class PromqlEngine:
         elif field_name not in {f.name for f in fields}:
             raise PromqlError(f"no field {field_name!r} in {metric!r}")
 
-        ts_col = schema.time_index
-        unit = ts_col.dtype.time_unit.nanos_per_unit
+        unit = schema.time_index.dtype.time_unit.nanos_per_unit
         offset = sel.offset_s
         lo = int((p.start - window - offset) * 1e9) // unit
         hi = int((p.end - offset) * 1e9) // unit + 1
+        region_id = info.region_ids[0]
+
+        def load(ts_range, resident: str):
+            return self._scan_series(info, metric, field_name, rest,
+                                     ts_range, offset, extra_channels, p,
+                                     resident)
+
+        # stage `scan`: the cache's probe and, for what it lacks, the
+        # region scan (SST read, decode, merge) and everything the host
+        # derives from it — matcher masks, series factorization, label
+        # decode. The uploads and the device's sort and channels cut
+        # themselves out (`upload`, `device`)
+        with tracing.stage("scan", metric=metric, field=field_name) as sa:
+            cache, identity = self._series_cache(region_id)
+            event, series = "miss", None
+            if cache is not None:
+                # what the samples depend on beside the data version;
+                # "deriv" channels embed p.start and key on it
+                key = (region_id, field_name, offset,
+                       tuple(sorted((m.label, m.op, m.value)
+                                    for m in rest)),
+                       tuple(extra_channels), not info.append_mode,
+                       p.start if "deriv" in extra_channels else None)
+                # a channel beside the reset-adjusted value depends, at
+                # a range's first sample, on where the load began: such
+                # samples serve their own range only
+                sliceable = set(extra_channels) <= {"adjusted"}
+                event, series = cache.probe(key, identity, lo, hi,
+                                            sliceable)
+                if event == "promote":
+                    series = self._promote(cache, key, identity, load,
+                                           lo, hi)
+                    if series is None:
+                        event = "ineligible"
+                PROMQL_LOAD_CACHE_EVENTS.inc(event=event)
+            sa["resident"] = event
+            if event == "hit":
+                with tracing.span("promql_scan", metric=metric,
+                                  field=field_name, resident="hit"):
+                    pass  # nothing is scanned
+            elif series is None:
+                got = load((lo, hi), event)
+                if got is None:
+                    return None
+                series, version = got
+                if cache is not None:
+                    extent = identity[2] or (lo, hi - 1)
+                    if sliceable and lo <= extent[0] and hi > extent[1] \
+                            and version == identity[:2]:
+                        # the range covered all the region held: these
+                        # samples are its whole span at that version
+                        series.span = None
+                        series.pivot()
+                    cache.store(key, version, series,
+                                requested=covered(lo, hi, extent))
+            cut = None
+            if series.span is None and series.grid_host is not None:
+                # a slice of the resident matrix: the points the
+                # request's own scan would have returned
+                sec = unit / 1e9
+                cut = series.cut(lo * sec + offset, hi * sec + offset)
+                if cut is not None and cut[1] == 0:
+                    return None  # no sample in range: no series either
+        return Loaded(series, metric, cut)
+
+    def _series_cache(self, region_id: int) -> tuple:
+        """(the executor's loaded-series cache, the region's data
+        identity); (None, None) where the region cannot say what it
+        holds without a scan (a remote or an external one): its samples
+        are loaded per request and kept nowhere."""
+        ex = getattr(self.qe, "executor", None)
+        identify = getattr(self.qe.region_engine, "data_identity", None)
+        identity = identify(region_id) if ex is not None and identify \
+            else None
+        if identity is None:
+            return None, None
+        cache = getattr(ex, "_promql_series", None)
+        if cache is None:
+            # the device budget the block cache has (config.
+            # device_cache_bytes): PromQL's resident series draw on the
+            # same number
+            cache = ex._promql_series = SeriesCache(ex.cache.budget)
+        return cache, identity
+
+    def _promote(self, cache: SeriesCache, key: tuple, identity: tuple,
+                 load, lo: int, hi: int) -> Optional[LoadedSeries]:
+        """Load the selector's whole retained span (the region's
+        canonical full scan) and keep it where every series shares one
+        complete grid: every later request of this data version is then
+        a slice of it. Flat, it is kept only if it serves this request
+        as a scan of its own range would (Region.scan's canonical
+        sharing); else None, and the key stays on its own ranges."""
+        version = identity[:2]
+        try:
+            got = load(None, "promote")
+        except BaseException:
+            cache.refuse(key, version)
+            raise
+        if got is not None:
+            series, version = got
+            series.pivot()
+            if series.grid_complete or series.serves(lo, hi):
+                cache.store(key, version, series)
+                return series
+        cache.refuse(key, version)
+        return None
+
+    def _scan_series(self, info, metric: str, field_name: str, rest: list,
+                     ts_range: Optional[tuple], offset: float,
+                     extra_channels, p: EvalParams,
+                     resident: str) -> Optional[tuple]:
+        """One region scan of `ts_range` (None: everything) -> (the
+        selector's LoadedSeries, the (incarnation, data_version) of the
+        scan's snapshot); None without a matching row. Runs inside
+        stage `scan`."""
+        schema = info.schema
+        ts_col = schema.time_index
+        unit = ts_col.dtype.time_unit.nanos_per_unit
         # push =/=~ matchers into the inverted index (reference applies
         # index predicates at sst/parquet/reader.rs:335-425); != and !~
         # can't prune (a segment bitmap proves presence, not absence).
         # The exact matcher masks below still run on everything scanned.
         from greptimedb_tpu.storage.index import InSet, Regex
         idx_preds: dict[str, list] = {}
-        tag_set = {c.name for c in schema.tag_columns}
+        tag_names = [c.name for c in schema.tag_columns]
         for m in rest:
-            if m.label not in tag_set:
+            if m.label not in tag_names:
                 continue
             if m.op == "=":
                 idx_preds.setdefault(m.label, []).append(InSet.of([m.value]))
             elif m.op == "=~":
                 idx_preds.setdefault(m.label, []).append(Regex(m.value))
-        # stage `scan`: the region scan (SST read, decode, merge) and,
-        # on a loaded-series cache miss, everything the host derives
-        # from it — matcher masks, series factorization, label decode
-        with tracing.stage("scan", metric=metric, field=field_name) as sa:
-            with tracing.span("promql_scan", metric=metric,
-                              field=field_name):
-                scan = qe.region_engine.scan(
-                    info.region_ids[0], (lo, hi), [field_name],
-                    tag_predicates={k: tuple(v)
-                                    for k, v in idx_preds.items()} or None)
+        with tracing.span("promql_scan", metric=metric, field=field_name,
+                          resident=resident) as ps:
+            scan = self.qe.region_engine.scan(
+                info.region_ids[0], ts_range, [field_name],
+                tag_predicates={k: tuple(v)
+                                for k, v in idx_preds.items()} or None)
             if scan is None or scan.num_rows == 0:
                 return None
-            sa["rows"] = scan.num_rows
+            ps["rows"] = scan.num_rows
 
-            # loaded-series cache: everything below (matcher masks,
-            # series factorization + label decode, the 9.6M-row device
-            # lexsort, channel building) is query-invariant for a given
-            # scan snapshot + selector — the PromQL analog of the
-            # prepared planes. Keyed on the scan identity, so
-            # data_version changes invalidate; "deriv" channels embed
-            # p.start and key on it.
-            ex = getattr(self.qe, "executor", None)
-            lcache = None
-            ckey = None
-            if ex is not None and scan.region_id >= 0:
-                lcache = getattr(ex, "_promql_load_cache", None)
-                if lcache is None:
-                    from collections import OrderedDict
+        mask = np.ones(scan.num_rows, dtype=bool)
+        for m in rest:
+            mask &= _matcher_mask(m, scan, tag_names)
+            if not mask.any():
+                return None
+        # dedup for non-append tables rides the same sort below
+        rows = np.flatnonzero(mask)
+        codes = [scan.columns[t][rows] for t in tag_names]
+        ts_raw = scan.columns[ts_col.name][rows]
+        vals = np.asarray(scan.columns[field_name][rows],
+                          dtype=np.float64)
 
-                    lcache = ex._promql_load_cache = OrderedDict()
-                ckey = (scan.region_id, scan.data_version,
-                        scan.scan_fingerprint, field_name, offset,
-                        tuple(sorted((m.label, m.op, m.value)
-                                     for m in rest)),
-                        tuple(extra_channels), not info.append_mode,
-                        p.start if "deriv" in extra_channels else None)
-                hit = lcache.get(ckey)
-                PROMQL_LOAD_CACHE_EVENTS.inc(
-                    event="miss" if hit is None else "hit")
-                if hit is not None:
-                    lcache.move_to_end(ckey)
-                    d_sidx, d_ts, channels, labels = hit
-                    return d_sidx, d_ts, channels, labels, metric
-
-            tag_names = [c.name for c in schema.tag_columns]
-            mask = np.ones(scan.num_rows, dtype=bool)
-            for m in rest:
-                mask &= _matcher_mask(m, scan, tag_names)
-                if not mask.any():
-                    return None
-            # dedup for non-append tables rides the same sort below
-            rows = np.flatnonzero(mask)
-            codes = [scan.columns[t][rows] for t in tag_names]
-            ts_raw = scan.columns[ts_col.name][rows]
-            vals = np.asarray(scan.columns[field_name][rows],
-                              dtype=np.float64)
-
-            if tag_names:
-                sizes = [len(scan.tag_dicts[t]) + 1 for t in tag_names]
-                combined = codes[0].astype(np.int64) + 1
-                for c, sz in zip(codes[1:], sizes[1:]):
-                    combined = combined * sz + (c.astype(np.int64) + 1)
-                uniq, sidx, regroup = _factorize_series(combined)
-                if regroup is not None:
-                    rows, ts_raw, vals = (rows[regroup], ts_raw[regroup],
-                                          vals[regroup])
-                labels = _series_labels(uniq, tag_names, sizes,
-                                        scan.tag_dicts)
-            else:
-                sidx = np.zeros(len(rows), dtype=np.int64)
-                labels = [{}]
-
-            ts_sec = ts_raw.astype(np.float64) * (unit / 1e9) + offset
-            # sort by (series, ts): required by counter_adjust /
-            # indicator channels, and makes segment ids sorted for the
-            # kernel. The storage scan already yields (tags..., ts)-
-            # sorted rows for a single flushed SST and series codes
-            # factorize in tag order — prove sortedness on host and skip
-            # the device lexsort chain (round-5: forcing that chain was
-            # 5.5 s of a 22 s first eval at 28.8M rows)
-            ds, dt = np.diff(sidx), np.diff(ts_sec)
-            host_sorted = bool(np.all((ds > 0) | ((ds == 0) & (dt >= 0))))
-            # last-write-wins has nothing to decide where the rows are
-            # already in (series, ts) order, no (series, ts) repeats
-            # (or the scan says it cannot) and no tombstone is among
-            # them: a flushed, compacted region. Then seq / op_type stay
-            # on the host and the device sorts nothing
-            settled = info.append_mode or (
-                host_sorted
-                and (not scan.needs_dedup
-                     or bool(np.all((ds > 0) | (dt > 0))))
-                and not scan.has_delete())
-        with tracing.stage("upload"):
-            d_sidx = h2d(sidx.astype(np.int32))
-            d_ts = h2d(ts_sec)
-            d_vals = h2d(vals)
-            if not settled:
-                d_seq = h2d(scan.seq[rows].astype(np.int64))
-                d_op = h2d(scan.op_type[rows].astype(np.int8))
-        if settled:
-            if not host_sorted:
-                order = jnp.lexsort((d_ts, d_sidx))
-                d_sidx, d_ts, d_vals = (d_sidx[order], d_ts[order],
-                                        d_vals[order])
+        if tag_names:
+            sizes = [len(scan.tag_dicts[t]) + 1 for t in tag_names]
+            combined = codes[0].astype(np.int64) + 1
+            for c, sz in zip(codes[1:], sizes[1:]):
+                combined = combined * sz + (c.astype(np.int64) + 1)
+            uniq, sidx, regroup = _factorize_series(combined)
+            if regroup is not None:
+                rows, ts_raw, vals = (rows[regroup], ts_raw[regroup],
+                                      vals[regroup])
+            labels = _series_labels(uniq, tag_names, sizes,
+                                    scan.tag_dicts)
         else:
-            d_sidx, d_ts, d_vals = _promql_dedup(d_sidx, d_ts, d_vals,
-                                                 d_seq, d_op)
+            sidx = np.zeros(len(rows), dtype=np.int64)
+            labels = [{}]
 
-        channels = self._make_channels(d_sidx, d_ts, d_vals,
-                                       extra_channels, p)
-        if lcache is not None:
-            lcache[ckey] = (d_sidx, d_ts, channels, labels)
-            while len(lcache) > 4:
-                lcache.popitem(last=False)
-        return d_sidx, d_ts, channels, labels, metric
+        ts_sec = ts_raw.astype(np.float64) * (unit / 1e9) + offset
+        # sort by (series, ts): required by counter_adjust /
+        # indicator channels, and makes segment ids sorted for the
+        # kernel. The storage scan already yields (tags..., ts)-
+        # sorted rows for a single flushed SST and series codes
+        # factorize in tag order — prove sortedness on host and skip
+        # the device lexsort chain (round-5: forcing that chain was
+        # 5.5 s of a 22 s first eval at 28.8M rows)
+        ds, dt = np.diff(sidx), np.diff(ts_sec)
+        host_sorted = bool(np.all((ds > 0) | ((ds == 0) & (dt >= 0))))
+        # last-write-wins has nothing to decide where the rows are
+        # already in (series, ts) order, no (series, ts) repeats
+        # (or the scan says it cannot) and no tombstone is among
+        # them: a flushed, compacted region. Then seq / op_type stay
+        # on the host and the device sorts nothing
+        settled = info.append_mode or (
+            host_sorted
+            and (not scan.needs_dedup
+                 or bool(np.all((ds > 0) | (dt > 0))))
+            and not scan.has_delete())
+        d_sidx = h2d(sidx.astype(np.int32))
+        d_ts = h2d(ts_sec)
+        d_vals = h2d(vals)
+        if not settled:
+            d_seq = h2d(scan.seq[rows].astype(np.int64))
+            d_op = h2d(scan.op_type[rows].astype(np.int8))
+        with tracing.stage("device"):
+            if settled:
+                if not host_sorted:
+                    order = jnp.lexsort((d_ts, d_sidx))
+                    d_sidx, d_ts, d_vals = (d_sidx[order], d_ts[order],
+                                            d_vals[order])
+            else:
+                d_sidx, d_ts, d_vals = _promql_dedup(d_sidx, d_ts, d_vals,
+                                                     d_seq, d_op)
+            channels = self._make_channels(d_sidx, d_ts, d_vals,
+                                           extra_channels, p)
+        series = LoadedSeries(labels, d_sidx, d_ts, channels, span=ts_range,
+                              extent=(int(ts_raw.min()), int(ts_raw.max())))
+        return series, (scan.incarnation, scan.data_version)
 
     # ---- calls -------------------------------------------------------------
 
@@ -769,6 +789,7 @@ class PromqlEngine:
                 st["count"][:, :, 0],
                 times[None, :] - range_s, times[None, :],
                 is_counter=counter, is_rate=(fn == "rate"), range_s=range_s,
+                first_raw=st["first"][:, :, 0] if counter else None,
             )
             return SeriesMatrix(labels, vals)
 
@@ -961,7 +982,8 @@ class PromqlEngine:
         loaded = self._load_any(sel, p, ctx, window=range_s)
         if loaded is None:
             return SeriesMatrix([], jnp.zeros((0, p.T)))
-        sidx, ts, chans, labels, metric = loaded
+        sidx, ts, chans = loaded.flat()
+        labels = loaded.labels
         sidx = d2h(sidx)
         ts = d2h(ts)
         vals = d2h(chans[:, 0])
@@ -995,13 +1017,13 @@ class PromqlEngine:
         loaded = self._load_any(sel, p, ctx, window=range_s)
         if loaded is None:
             return None
-        sidx, ts, chans, labels, metric = loaded
+        sidx, ts, chans = loaded.flat()
         chans = jnp.concatenate([chans, chans[:, :1] ** 2], axis=1)
         st = window_stats(sidx, ts, chans, ~jnp.isnan(chans[:, 0]),
-                          p.start, p.step, len(labels), p.T, w,
+                          p.start, p.step, len(loaded.labels), p.T, w,
                           stats=("sum", "count"),
                           sorted_input=_sorted_ws())
-        return st, labels, metric, w, range_s
+        return st, loaded.labels, loaded.metric, w, range_s
 
     # ---- aggregation -------------------------------------------------------
 

@@ -403,6 +403,13 @@ class RegionEngine:
         """(min, max) data timestamps from metadata only (no data read)."""
         return self.region(region_id).ts_extent()
 
+    def data_identity(self, region_id: int) -> Optional[tuple]:
+        """(incarnation, data_version, ts extent) from metadata only
+        (Region.data_identity); None for a kind of region that cannot
+        say."""
+        fn = getattr(self.region(region_id), "data_identity", None)
+        return None if fn is None else fn()
+
     def alter_region_schema(self, region_id: int, schema: Schema) -> None:
         """Apply an ALTER'd schema to a region: flush under the old schema,
         then swap and record (reference worker/handle_alter.rs)."""

@@ -325,6 +325,11 @@ class LogicalRegion:
     def data_version(self) -> int:
         return self.engine.region(self.meta.physical_region).data_version
 
+    def data_identity(self) -> tuple:
+        """The physical region's (see Region.data_identity): every
+        logical table moves with it, and its extent bounds theirs."""
+        return self.engine.region(self.meta.physical_region).data_identity()
+
     def flush(self):
         self.engine.region(self.meta.physical_region).flush()
 

@@ -2276,6 +2276,14 @@ class Region:
             return None
         return (min(b[0] for b in bounds), max(b[1] for b in bounds))
 
+    def data_identity(self) -> tuple:
+        """(incarnation, data_version, ts extent or None), read under
+        the lock a scan takes its snapshot under: what a cache built
+        from this region's rows is valid for, known without a scan. A
+        write acknowledged before this call has moved the version."""
+        with self._lock:
+            return (self.incarnation, self.data_version, self.ts_extent())
+
     @property
     def memtable_bytes(self) -> int:
         return self.memtable.bytes_estimate

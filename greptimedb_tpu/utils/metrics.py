@@ -560,9 +560,13 @@ DEVICE_CACHE_EVENTS = REGISTRY.counter(
     "join is an upload the background prefetch worker already did)")
 PROMQL_LOAD_CACHE_EVENTS = REGISTRY.counter(
     "greptimedb_tpu_promql_load_cache_events_total",
-    "PromQL loaded-series cache events by kind (hit = a selector's "
-    "sorted device arrays and label sets reused for this scan snapshot, "
-    "miss = matcher masks, series factorization and upload ran)")
+    "PromQL loaded-series cache events, one per selector load (hit = "
+    "answered from resident samples with no region scan: a slice of the "
+    "selector's whole span, or a range asked for before; miss = the "
+    "request's own range was scanned, factorised and uploaded; promote = "
+    "the whole retained span was, and kept; ineligible = the own range, "
+    "for a selector whose whole span has no complete sample grid or "
+    "would not fit the device budget at this data version)")
 DEVICE_HOT_SET_EVENTS = REGISTRY.counter(
     "greptimedb_tpu_device_hot_set_events_total",
     "HBM-resident columnar hot set events by kind (hit/miss/evict/pin — "

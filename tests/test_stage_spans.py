@@ -363,7 +363,16 @@ def test_promql_slow_query_record_carries_its_stage_tree(server,
     slow_query.clear()
     try:
         server.promql("sum by (host) (rate(m[2m]))")
-        rec = next(r for r in slow_query.records(20) if r.kind == "promql")
+        # the watch covers the socket write, so the record is made
+        # after the response's last byte: wait for it as spans_of waits
+        # for the root
+        for _ in range(200):
+            rec = next((r for r in slow_query.records(20)
+                        if r.kind == "promql"), None)
+            if rec is not None:
+                break
+            time.sleep(0.01)
+        assert rec is not None, "the slow-query record never appeared"
         names = {name for _node, name, _ms in rec.stages}
         assert {"parse", "scan", "device", "readback", "encode",
                 "send"} <= names
