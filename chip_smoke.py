@@ -264,7 +264,7 @@ def metric_sum(m: dict, name: str, **labels) -> float:
 
 
 def make_cpu_data(seed: int, hosts: int, hours: int) -> dict:
-    """{field: [points, hosts] float64}, uniform(0, 100) like bench.py's
+    """{field: [points, hosts] float64}, uniform(0, 100) like TSBS's
     generator; row (p, h) is host_h at T0 + p*10 s."""
     rng = np.random.default_rng(seed)
     points = hours * 3600 * 1000 // STEP_MS
@@ -418,8 +418,8 @@ def check_rtol(name, got: np.ndarray, ref: np.ndarray, rtol: float) -> float:
 
 def build_queries(data: dict, hosts: int, hours: int, dtype: str,
                   table: str = "cpu") -> list:
-    """[(name, sql, check(rows) -> max_rel_err)] for the five TSBS types
-    bench.py carries, with references from the seeded arrays."""
+    """[(name, sql, check(rows) -> max_rel_err)] for five TSBS query
+    types, with references from the seeded arrays."""
     t_end = T0_MS + hours * 3600_000
     ppm, pph = 60_000 // STEP_MS, 3600_000 // STEP_MS  # points per min/hour
     f32 = dtype == "float32"
@@ -736,8 +736,8 @@ def run(args, data_home: str) -> dict:
         if not dev["link"]["colocated"]:
             raise SmokeFailure(
                 "the link probe says the accelerator is not attached to "
-                f"this host ({dev['link']}): the tier router would keep "
-                "interactive queries on the CPU")
+                f"this host ({dev['link']}): a chip off its host is not a "
+                "supported deployment")
         dtype = dev["compute_dtype"]
 
         # ---- load ----------------------------------------------------------

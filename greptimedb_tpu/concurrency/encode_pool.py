@@ -1,6 +1,6 @@
 """Bounded result-encode pool: serving I/O off the engine threads.
 
-The 50-client qps bench is parse/JSON-bound on host threads: every
+A many-client query load is parse/JSON-bound on host threads: every
 connection thread that just finished executing re-enters the GIL to
 materialize Python row objects and JSON-encode them, convoying with the
 threads still executing queries. The pool bounds that contention:

@@ -109,17 +109,6 @@ def sparse_groups_min() -> int:
     return int(os.environ.get("GREPTIMEDB_TPU_SPARSE_GROUPS_MIN", "0"))
 
 
-def tier_admission() -> bool:
-    """Hot-set-aware tier admission: before the latency-history router,
-    consult which tier's device/HBM hot set already holds the scan's
-    file-anchored blocks and route there (re-uploading a hot scan to
-    the OTHER tier pays the full H2D cost for nothing).
-    GREPTIMEDB_TPU_TIER_ADMISSION=off restores pure history/heuristic
-    routing — the A/B benching override."""
-    return os.environ.get("GREPTIMEDB_TPU_TIER_ADMISSION", "on").lower() \
-        not in ("0", "false", "off")
-
-
 def stream_threshold_rows() -> int:
     """Aggregate scans at or above this row estimate run the streaming
     (bounded-memory) path: lazy row-group chunks -> fixed-shape device
@@ -179,11 +168,12 @@ def device_cache_bytes() -> int:
 
 
 def host_tier_mode() -> str:
-    """Tiered execution policy: "auto" routes interactive queries to the
-    host (CPU) tier when the probed host<->accelerator link is slow
-    (physical.accelerator_link(): a chip that is not attached to this
-    host), "off" pins everything to the default backend, "force" pins
-    everything to the host tier (A/B measurement + emergency bypass)."""
+    """Tiered execution policy (query/tier.py), on an accelerator
+    without a mesh: "auto" runs on the chip and serves a shape's first
+    touch from the host (CPU) tier while its device executable compiles,
+    "off" pins everything to the chip (the first touch waits for the
+    compile), "force" pins everything to the host tier (A/B measurement
+    + emergency bypass)."""
     return os.environ.get("GREPTIMEDB_TPU_HOST_TIER", "auto").lower()
 
 
@@ -196,19 +186,3 @@ def prewarm_enabled() -> bool:
     if env is not None:
         return env.lower() not in ("0", "false", "off")
     return _platform() == "tpu"
-
-
-def tier_adaptive() -> bool:
-    """Measured tier routing: consult per-tier latency history so a
-    tier that is losing stops being chosen (GREPTIMEDB_TPU_TIER_ADAPTIVE
-    =off pins the static heuristic — the benching override)."""
-    return os.environ.get("GREPTIMEDB_TPU_TIER_ADAPTIVE", "on").lower() \
-        not in ("0", "false", "off")
-
-
-def device_tier_rows() -> int:
-    """Aggregate scans at or above this row count run on the accelerator
-    even over a slow link (the resident-plane fold amortizes readback);
-    smaller interactive queries take the host tier."""
-    return int(os.environ.get("GREPTIMEDB_TPU_DEVICE_TIER_ROWS",
-                              str(4 << 20)))

@@ -332,8 +332,8 @@ def window_edges(
     first/last/count are two binary-search probes into one monotone
     composite key. At the tracked scale (10k series x 1 day @15s =
     57.6M samples, 240 eval points) this replaces an O(N)-per-eval
-    57.6M-row pass with 4.8M probes — the same asymmetry the numpy
-    straw-man anchor exploits (bench.py promql_anchor), now on device.
+    57.6M-row pass with 4.8M probes — the same asymmetry a numpy
+    searchsorted reference exploits, now on device.
 
     Window j covers (t0 + (j-w)·step, t0 + j·step], matching
     window_stats. Requires NaN-free channels (callers gate — LWW

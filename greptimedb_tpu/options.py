@@ -261,17 +261,12 @@ class QueryOptions:
     # key products at/above this also take the sparse sort-compact
     # path even when dense would fit; 0 = off (dense wins while it fits)
     sparse_groups_min: int = 0
-    # hot-set-aware tier admission: route to the tier already holding
-    # the scan's file-anchored blocks; off = history/heuristic only
-    tier_admission: bool = True
     stream_threshold_rows: int = 32 << 20
     stream_block_rows: int = 2 << 20
     # HBM budget for the device columnar hot set; 0 = auto
     device_cache_bytes: int = 0
     # background Pallas kernel pre-warm at open; "" = auto (TPU only)
     prewarm: str = ""
-    # measured per-tier routing history; off = static heuristic only
-    tier_adaptive: bool = True
     # per-part partial-aggregate cache: immutable SST parts' [G, F]
     # planes memoized so repeated aggregates fold only the delta
     partial_cache: bool = True
@@ -337,7 +332,7 @@ class TracingOptions:
     GTPU_OTLP_QUEUE, GTPU_OTLP_FLUSH_S."""
 
     # master switch for span recording + the resource ledger (trace-id
-    # minting/log correlation stays on); the bench A/B knob
+    # minting/log correlation stays on); the A/B knob
     enabled: bool = True
     # OTLP/HTTP collector base URL (e.g. "http://jaeger:4318");
     # empty = no export
@@ -360,7 +355,7 @@ class ProfilingOptions:
     inherit): GTPU_PROFILE, GTPU_PROFILE_HZ, GTPU_PROFILE_WINDOW_S,
     GTPU_PROFILE_WINDOWS."""
 
-    # master switch; overhead budget is <= 2% on the qps bench A/B
+    # master switch; overhead budget is <= 2% of served qps
     enabled: bool = True
     # sampling rate; 19 Hz is deliberately co-prime with common 10/20/
     # 100 Hz periodic loops so flames don't alias against them
@@ -591,8 +586,6 @@ def apply_query_env(opts: "StandaloneOptions") -> None:
             (str(q.sparse_groups_max), str(dq.sparse_groups_max)),
         "GREPTIMEDB_TPU_SPARSE_GROUPS_MIN":
             (str(q.sparse_groups_min), str(dq.sparse_groups_min)),
-        "GREPTIMEDB_TPU_TIER_ADMISSION":
-            ("" if q.tier_admission else "off", ""),
         "GREPTIMEDB_TPU_STREAM_THRESHOLD_ROWS":
             (str(q.stream_threshold_rows), str(dq.stream_threshold_rows)),
         "GREPTIMEDB_TPU_STREAM_BLOCK_ROWS":
@@ -601,8 +594,6 @@ def apply_query_env(opts: "StandaloneOptions") -> None:
             (str(q.device_cache_bytes) if q.device_cache_bytes else "",
              ""),
         "GREPTIMEDB_TPU_PREWARM": (q.prewarm, dq.prewarm),
-        "GREPTIMEDB_TPU_TIER_ADAPTIVE":
-            ("" if q.tier_adaptive else "off", ""),
         "GREPTIMEDB_TPU_PARTIAL_CACHE":
             ("" if q.partial_cache else "off", ""),
         "GREPTIMEDB_TPU_PARTIAL_CACHE_BYTES":
@@ -955,10 +946,6 @@ _DOC = {
         "Key products at/above this also take the sparse sort-compact "
         "path even when the dense planes would fit (keeps huge "
         "date_bin bucket domains on the tiled fused kernel); 0 = off.",
-    "query.tier_admission":
-        "Hot-set-aware tier admission: route a query to the tier whose "
-        "device/HBM hot set already holds its file-anchored blocks; "
-        "false = latency-history/heuristic routing only (A/B knob).",
     "query.stream_threshold_rows":
         "Stream scans in blocks above this row count.",
     "query.stream_block_rows": "Rows per streamed device block.",
@@ -966,8 +953,6 @@ _DOC = {
         "HBM budget for the device columnar hot set; 0 = auto.",
     "query.prewarm":
         "Background kernel pre-warm at open: on | off; empty = auto (TPU).",
-    "query.tier_adaptive":
-        "Route tiers by measured history; false = static heuristic only.",
     "query.partial_cache":
         "Per-part partial-aggregate cache: immutable SST parts' [G, F] "
         "partials are memoized so repeated aggregates fold only the "

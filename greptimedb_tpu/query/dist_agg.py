@@ -468,9 +468,9 @@ def combine_partials(partials: list, n_keys: int, ops: tuple) -> Optional[dict]:
     Fully vectorized: all partials' groups stack into one [R, F] matrix,
     group identity resolves with one np.unique pass per key column, and
     every plane combines with a single scatter (np.add.at / np.fmin.at /
-    lexsort for first/last) — no per-group Python. At bench scale
-    (48k groups x N regions) the former dict-per-group loop dominated
-    the distributed win (round-2 VERDICT weak #5)."""
+    lexsort for first/last) — no per-group Python. At TSBS scale
+    (48k groups x N regions) a dict-per-group loop would dominate
+    the distributed win."""
     partials = [p for p in partials if p is not None]
     if not partials:
         return None

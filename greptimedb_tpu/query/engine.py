@@ -1868,25 +1868,12 @@ class QueryEngine:
     # ---- TQL (PromQL embedded in SQL) --------------------------------------
 
     def _tql(self, stmt: ast.Tql, ctx: QueryContext) -> QueryResult:
-        from greptimedb_tpu.promql.engine import PromqlEngine
-        from greptimedb_tpu.query.physical import (_TierCtx,
-                                                   accelerator_link)
-        from greptimedb_tpu import config as _cfg
-        import jax as _jax
+        from greptimedb_tpu.query.tier import TierCtx
 
-        # PromQL evaluation materializes intermediate series matrices on
-        # host between stages — over a remote accelerator link that
-        # readback dominates every evaluation, so the whole TQL pipeline
-        # takes the host tier unless the chip is co-located (same policy
-        # as PhysicalExecutor.tier_for, including mode force/off)
-        tier = "device"
-        if _jax.default_backend() != "cpu":
-            mode = _cfg.host_tier_mode()
-            if mode == "force":
-                tier = "host"
-            elif mode != "off" and not accelerator_link()["colocated"]:
-                tier = "host"
-        with _TierCtx(tier):
+        # the whole TQL pipeline takes one tier: PromQL evaluation is no
+        # SQL aggregate, so the router gives it the device, or the host
+        # under GREPTIMEDB_TPU_HOST_TIER=force
+        with TierCtx(self.executor.tier_for(None, 0)):
             return self._tql_inner(stmt, ctx)
 
     def _tql_inner(self, stmt: ast.Tql, ctx: QueryContext) -> QueryResult:

@@ -52,6 +52,14 @@ from greptimedb_tpu.query.expr import (
     eval_host,
 )
 from greptimedb_tpu.query.result import QueryResult
+from greptimedb_tpu.query.tier import (
+    ACTIVE_TIER,
+    TierCtx,
+    TierRouter,
+    incremental_key,
+    part_placement,
+    whole_scan_key,
+)
 from greptimedb_tpu.sql import ast
 from greptimedb_tpu.storage.engine import RegionEngine
 from greptimedb_tpu.storage.region import (
@@ -91,7 +99,7 @@ def _kstage(name: str, **attrs):
     `device` (dispatch of jitted steps) or `readback` on the device and
     mesh tiers. On the host tier the same steps run on the CPU backend
     of this process and are the host's aggregation work: `host_agg`."""
-    if _ACTIVE_TIER_VAR.get() == "host":
+    if ACTIVE_TIER.get() == "host":
         name = "host_agg"
     return tracing.stage(name, **attrs)
 
@@ -1218,86 +1226,6 @@ def _snap_version(scan) -> tuple:
     orders lexicographically for the cache's generation retirement."""
     return (getattr(scan, "incarnation", 0), scan.data_version)
 
-_LINK: Optional[dict] = None
-# contextvar, NOT a module global: queries run concurrently under the
-# threaded servers, and jax.default_device is itself thread-local — the
-# cache-key tier must track the same scope or tiers cross-contaminate
-import contextvars as _contextvars
-
-_ACTIVE_TIER_VAR = _contextvars.ContextVar("gtpu_tier", default="device")
-
-
-def accelerator_link() -> dict:
-    """Measured host<->accelerator link profile, probed once per process:
-    the round trip of a tiny compiled call and the D2H rate of a freshly
-    computed 4 MB array, each the BEST of three readings — a capability
-    probe must not mistake a busy host for a slow link. On a v5e
-    attached to its host the probe reads ~1 ms and 185-860 MB/s (a 4 MB
-    fetch is mostly fixed cost), a chip reached over a network two
-    orders of magnitude worse on both; `colocated: false` means the
-    latter, and tier_for then keeps interactive queries off it."""
-    global _LINK
-    if _LINK is not None:
-        return _LINK
-    backend = jax.default_backend()
-    if backend == "cpu":
-        _LINK = {"backend": "cpu", "rtt_ms": 0.0,
-                 "d2h_mbps": float("inf"), "colocated": True}
-        return _LINK
-    f = jax.jit(lambda x: (x * 2.0).sum())
-    g = jax.jit(lambda v, k: v + k)
-    x = jnp.ones((8, 128), jnp.float32)
-    y0 = jnp.ones((1 << 20,), jnp.float32)
-    float(f(x))  # compile outside the clock
-    rtt_s, d2h_s = [], []
-    for k in range(3):
-        t0 = time.perf_counter()
-        float(f(x))
-        rtt_s.append(time.perf_counter() - t0)
-        # D2H must fetch a freshly COMPUTED array: an uploaded one can
-        # be served from a host-side copy the runtime kept
-        y = g(y0, float(k))
-        y.block_until_ready()
-        t0 = time.perf_counter()
-        np.asarray(y)
-        d2h_s.append(time.perf_counter() - t0)
-    rtt_ms = min(rtt_s) * 1e3
-    d2h_mbps = 4.0 / max(min(d2h_s), 1e-9)
-    _LINK = {"backend": backend, "rtt_ms": round(rtt_ms, 2),
-             "d2h_mbps": round(d2h_mbps, 1),
-             "colocated": rtt_ms < 5.0 and d2h_mbps > 100.0}
-    return _LINK
-
-
-@functools.lru_cache(maxsize=1)
-def _host_device():
-    return jax.local_devices(backend="cpu")[0]
-
-
-class _TierCtx:
-    """Route the enclosed jax work to the host tier: compilations and
-    new arrays land on the CPU backend (which coexists with the
-    accelerator backend), so small queries skip the link entirely."""
-
-    def __init__(self, tier: str):
-        self.tier = tier
-        self._dd = None
-        self._token = None
-
-    def __enter__(self):
-        if self.tier == "host" and jax.default_backend() != "cpu":
-            self._token = _ACTIVE_TIER_VAR.set("host")
-            self._dd = jax.default_device(_host_device())
-            self._dd.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        if self._token is not None:
-            _ACTIVE_TIER_VAR.reset(self._token)
-        if self._dd is not None:
-            self._dd.__exit__(*exc)
-        return False
-
 
 # ---- executor --------------------------------------------------------------
 
@@ -1309,31 +1237,17 @@ class PhysicalExecutor:
         from greptimedb_tpu.query.device_cache import DeviceCache
 
         self.cache = DeviceCache()
-        # multi-device: row-shard the scan over the mesh and combine
-        # partial aggregates with collectives (None on a single chip)
-        self.mesh = config.query_mesh()
+        # where work runs (query/tier.py): the router owns the mesh —
+        # multi-device: row-shard the scan over it and combine partial
+        # aggregates with collectives (None on a single chip) — and the
+        # first-touch hedge's state
+        self.router = TierRouter(config.query_mesh(), _note_degradation)
         # last_path (which aggregate path served the last query:
         # dense | sparse | sharded | stream) and last_tier live behind
-        # thread-local properties below
-        # hedged device warm-up: shape keys whose device executable is
-        # compiled (first-touch queries serve host-side while the
-        # accelerator compile runs in the background)
-        self._device_warm: set = set()
-        self._device_warming: set = set()
-        self._device_warm_failed: set = set()
-        self._warm_lock = threading.Lock()
-        # last_path/last_tier are THREAD-LOCAL: the background warm
-        # thread runs the same _stream_agg machinery and must not
-        # clobber the foreground query's reported path/tier
+        # thread-local properties below: the background warm thread runs
+        # the same _stream_agg machinery and must not clobber the
+        # foreground query's reported path/tier
         self._tls = threading.local()
-        # measured per-tier latency history (the span-ring feed): keyed
-        # by (tier, log2 rows bucket) so the router can stop choosing a
-        # tier that is measurably losing for a workload class
-        from collections import deque as _deque
-
-        self._tier_hist: dict[tuple, "_deque"] = {}
-        self._tier_explore: dict[int, int] = {}
-        self._tier_lock = threading.Lock()
         # warmup amortization: a background kernel pre-warm compiles
         # the dominant Pallas shapes at open time instead of under the
         # first query (the persistent compilation cache is wired once,
@@ -1342,6 +1256,14 @@ class PhysicalExecutor:
             threading.Thread(target=self._prewarm_kernels,
                              daemon=True,
                              name="gtpu-device-prewarm").start()
+
+    @property
+    def mesh(self):
+        return self.router.mesh
+
+    @mesh.setter
+    def mesh(self, v):
+        self.router.mesh = v
 
     @property
     def last_path(self):
@@ -1395,27 +1317,19 @@ class PhysicalExecutor:
                 "bytes_in_use": st.get("bytes_in_use"),
                 "peak_bytes_in_use": st.get("peak_bytes_in_use"),
                 "bytes_limit": st.get("bytes_limit")})
-        with self._warm_lock:
-            warm = {"warm": len(self._device_warm),
-                    "warming": len(self._device_warming),
-                    "failed": len(self._device_warm_failed)}
         return {
+            **self.router.status(),
             "platform": jax.devices()[0].platform,
             "device_kind": jax.devices()[0].device_kind,
             "count": len(jax.devices()),
             "devices": devices,
             "mesh": dict(self.mesh.shape) if self.mesh is not None else None,
             "compute_dtype": str(config.compute_dtype()),
-            # JSON has no infinity (the CPU backend's d2h rate)
-            "link": {k: (None if v == float("inf") else v)
-                     for k, v in accelerator_link().items()},
-            "host_tier_mode": config.host_tier_mode(),
             "pallas": {"mode": _pallas_mode(),
                        "dispatch_mode": ps.dispatch_mode(),
                        "canaries": ps.canary_status(),
                        "fused_disabled": _FUSED_DISABLED["flag"],
                        "partial_disabled": _PARTIAL_DISABLED["flag"]},
-            "warmup": warm,
             "degradations": list(_DEGRADATION_LOG),
             "compile_cache_dir": jax.config.jax_compilation_cache_dir,
             "native_available": native.AVAILABLE,
@@ -1432,198 +1346,40 @@ class PhysicalExecutor:
         (device_status()["warmup"]["warming"]): a client that waits for
         a warm server waits for these compiles too, instead of finding
         them in its first minute of traffic."""
-        with self._warm_lock:
-            self._device_warming.add("prewarm")
-        try:
-            from greptimedb_tpu.ops import pallas_segment as ps
+        with self.router.compiling("prewarm"):
+            try:
+                from greptimedb_tpu.ops import pallas_segment as ps
 
-            ps.tpu_compile_ok()
-            ps.fused_tpu_compile_ok()
-            # NB: G is the query's GROUP count — the kernels get G+1
-            # segments (dead segment), so the largest routable G is
-            # MAX_SEGMENTS-1 (4095), not 4096; an ineligible shape
-            # would burn Mosaic compile on an executable _fused_ok can
-            # never route
-            shapes = os.environ.get("GREPTIMEDB_TPU_PREWARM_SHAPES",
-                                    "64,10;4095,10")
-            for part in shapes.split(";"):
-                g, f = (int(x) for x in part.split(","))
-                if ps.fused_eligible(f, g + 1):
-                    ps.pallas_fused_segment_agg(
-                        jnp.zeros((512, f), jnp.float32),
-                        jnp.zeros(512, jnp.int32), g + 1,
-                        want_min=True, want_max=True, block_rows=256)
-                    ps.pallas_fused_segment_agg(
-                        jnp.zeros((512, f), jnp.float32),
-                        jnp.zeros(512, jnp.int32), g + 1)
-                if ps.eligible((512, 2 * f + 1), g + 1):
-                    ps.pallas_dense_segment_sum(
-                        jnp.zeros((512, 2 * f + 1), jnp.float32),
-                        jnp.zeros(512, jnp.int32), g + 1)
-        except Exception:  # noqa: BLE001 — pre-warm must never take a node down
-            _note_degradation("prewarm_failed",
-                              "background Pallas kernel pre-warm")
-        finally:
-            with self._warm_lock:
-                self._device_warming.discard("prewarm")
+                ps.tpu_compile_ok()
+                ps.fused_tpu_compile_ok()
+                # NB: G is the query's GROUP count — the kernels get G+1
+                # segments (dead segment), so the largest routable G is
+                # MAX_SEGMENTS-1 (4095), not 4096; an ineligible shape
+                # would burn Mosaic compile on an executable _fused_ok can
+                # never route
+                shapes = os.environ.get("GREPTIMEDB_TPU_PREWARM_SHAPES",
+                                        "64,10;4095,10")
+                for part in shapes.split(";"):
+                    g, f = (int(x) for x in part.split(","))
+                    if ps.fused_eligible(f, g + 1):
+                        ps.pallas_fused_segment_agg(
+                            jnp.zeros((512, f), jnp.float32),
+                            jnp.zeros(512, jnp.int32), g + 1,
+                            want_min=True, want_max=True, block_rows=256)
+                        ps.pallas_fused_segment_agg(
+                            jnp.zeros((512, f), jnp.float32),
+                            jnp.zeros(512, jnp.int32), g + 1)
+                    if ps.eligible((512, 2 * f + 1), g + 1):
+                        ps.pallas_dense_segment_sum(
+                            jnp.zeros((512, 2 * f + 1), jnp.float32),
+                            jnp.zeros(512, jnp.int32), g + 1)
+            except Exception:  # noqa: BLE001 — pre-warm must never take a node down
+                _note_degradation("prewarm_failed",
+                                  "background Pallas kernel pre-warm")
 
-    def _note_tier(self, tier: str, num_rows: int, seconds: float) -> None:
-        """Feed one measured execution into the per-tier history ring
-        (the device_agg span's duration, bucketed by scan size)."""
-        if tier not in ("device", "host", "mesh"):
-            return
-        from collections import deque as _deque
-
-        b = max(int(num_rows), 1).bit_length()
-        with self._tier_lock:
-            self._tier_hist.setdefault((tier, b),
-                                       _deque(maxlen=16)).append(seconds)
-
-    def _tier_from_history(self, num_rows: int) -> Optional[str]:
-        """Measured-routing verdict for this scan-size class, or None
-        when either tier lacks samples. Every 16th decision explores
-        the losing tier so a regression (or recovery) on the unused
-        tier is re-measured instead of frozen in."""
-        from greptimedb_tpu import config
-
-        if not config.tier_adaptive():
-            return None
-        b = max(int(num_rows), 1).bit_length()
-        with self._tier_lock:
-            dev = sorted(self._tier_hist.get(("device", b), ()))
-            host = sorted(self._tier_hist.get(("host", b), ()))
-            if len(dev) < 3 or len(host) < 3:
-                return None
-            med_d = dev[len(dev) // 2]
-            med_h = host[len(host) // 2]
-            winner = "device" if med_d <= med_h else "host"
-            n = self._tier_explore.get(b, 0) + 1
-            self._tier_explore[b] = n
-        if n % 16 == 0:
-            return "host" if winner == "device" else "device"
-        return winner
-
-    def _mesh_from_history(self, num_rows: int) -> str:
-        """Measured mesh-vs-single-device verdict for this scan-size
-        class. Defaults to "mesh" until both tiers hold >=3 real samples
-        (the mesh must get its first measurements from somewhere); every
-        16th decision explores the loser so a regression on the unused
-        tier is re-measured instead of frozen in. GREPTIMEDB_TPU_
-        TIER_ADAPTIVE=off pins the static always-mesh routing."""
-        from greptimedb_tpu import config
-
-        if not config.tier_adaptive():
-            return "mesh"
-        b = max(int(num_rows), 1).bit_length()
-        with self._tier_lock:
-            mesh = sorted(self._tier_hist.get(("mesh", b), ()))
-            dev = sorted(self._tier_hist.get(("device", b), ()))
-            n = self._tier_explore.get(("mesh", b), 0) + 1
-            self._tier_explore[("mesh", b)] = n
-            if len(mesh) < 3 or len(dev) < 3:
-                # seed the underfilled ring: mesh-eligible shapes never
-                # reach the single-device paths on their own, so without
-                # this forced sample the >=3 gate would hold forever and
-                # the measured arbitration below would be unreachable
-                if len(dev) < 3 and n % 8 == 0:
-                    return "device"
-                return "mesh"
-            med_m = mesh[len(mesh) // 2]
-            med_d = dev[len(dev) // 2]
-            winner = "mesh" if med_m <= med_d else "device"
-        if n % 16 == 0:
-            return "device" if winner == "mesh" else "mesh"
-        return winner
-
-    def tier_for(self, agg, num_rows: int, streaming: bool = False,
-                 scan=None) -> str:
-        """Tiered execution. On an accelerator attached to this host
-        (accelerator_link() colocated — the only kind a deployment has)
-        everything runs on the device, or on the mesh when there is one.
-        The rest of this function is the slow-link branch: when the
-        probe measures a chip that is NOT attached to this host, every
-        interactive query is readback-bound, so only work that
-        amortizes the link goes to the chip (large aggregations whose
-        planes stay HBM-resident and whose results are small); raw
-        row-returning queries and streaming folds stay host-side, and
-        hot-set admission plus the measured latency history arbitrate
-        the middle. Nothing in this round runs on such a link; the
-        branch is kept for the tier-router design PR to judge."""
-        from greptimedb_tpu import config
-
-        if self.mesh is not None:
-            # measured "mesh" tier: aggregate scans big enough to
-            # amortize per-shard dispatch ride the mesh, unless the
-            # latency history says single-device wins this size class
-            if (agg is not None and not streaming
-                    and num_rows >= config.mesh_min_rows()
-                    and self._mesh_from_history(num_rows) == "mesh"):
-                return "mesh"
-            return "device"
-        if jax.default_backend() == "cpu":
-            return "device"
-        mode = config.host_tier_mode()
-        if mode == "off":
-            return "device"
-        if mode == "force":
-            return "host"
-        if accelerator_link()["colocated"]:
-            return "device"
-        # ---- slow-link branch ----
-        # measured routing beats the static heuristic: when both tiers
-        # have real samples for this scan-size class, the one that is
-        # actually losing stops being chosen. GREPTIMEDB_TPU_TIER_ADAPTIVE
-        # =off restores the pure heuristic for A/B benching.
-        if agg is not None and not streaming:
-            # hot-set-aware admission runs BEFORE the latency history:
-            # a tier already holding the scan's file-anchored blocks
-            # serves warm (zero H2D), which no size-class average sees
-            adv = self._hot_set_admission(scan)
-            if adv is not None:
-                return adv
-            adv = self._tier_from_history(num_rows)
-            if adv is not None:
-                return adv
-        if not streaming and agg is not None \
-                and num_rows >= config.device_tier_rows():
-            return "device"
-        return "host"
-
-    def _hot_set_admission(self, scan) -> Optional[str]:
-        """Hot-set-aware tier admission: which tier's block cache already
-        holds this scan's file-anchored blocks? Routing a warm scan to
-        the OTHER tier re-uploads the whole working set for nothing —
-        the history router can't see that (it averages a size class, not
-        a residency state). Returns the hot tier, or None to fall
-        through to history/heuristic routing. Decisions are counted on
-        greptimedb_tpu_tier_admission_total{reason}; the
-        GREPTIMEDB_TPU_TIER_ADMISSION knob is the A/B override."""
-        from greptimedb_tpu import config
-        from greptimedb_tpu.utils.metrics import TIER_ADMISSION
-
-        if scan is None or getattr(scan, "region_id", -1) < 0:
-            return None
-        if not config.tier_admission():
-            TIER_ADMISSION.inc(reason="off")
-            return None
-        fids = {e.pkey[0] for e in _block_plan(scan) if e.pkey is not None}
-        if not fids:
-            return None  # memtable/synthetic-only: nothing file-anchored
-        per_tier: dict[str, int] = {}
-        try:
-            resident = self.cache.file_keys(scan.region_id)
-        except Exception:
-            return None
-        for k in resident:
-            if len(k) > 3 and k[2] in fids and k[3] in ("device", "host"):
-                per_tier[k[3]] = per_tier.get(k[3], 0) + 1
-        if not per_tier:
-            TIER_ADMISSION.inc(reason="cold")
-            return None
-        # ties go to the device tier (its planes also serve the kernels)
-        best = max(per_tier, key=lambda t: (per_tier[t], t == "device"))
-        TIER_ADMISSION.inc(reason=f"{best}_hot")
-        return best
+    def tier_for(self, agg, num_rows: int, streaming: bool = False) -> str:
+        """Where this work runs: TierRouter.choose (query/tier.py)."""
+        return self.router.choose(agg, num_rows, streaming)
 
     def execute(self, plan: lp.LogicalPlan) -> QueryResult:
         """Run one statement's plan and count the tier that answered it
@@ -1791,7 +1547,7 @@ class PhysicalExecutor:
                                              streaming=True)
                         self.last_tier = tier
                         try:
-                            with _TierCtx(tier):
+                            with TierCtx(tier):
                                 return self._execute_agg_stream(
                                     stream, table, where, agg, having,
                                     project, sort, limit, offset,
@@ -1846,7 +1602,7 @@ class PhysicalExecutor:
                 tier = self.tier_for(None, nrows)
                 self.last_tier = tier
                 with tracing.span("filter_project", rows=nrows,
-                                  tier=tier), _TierCtx(tier):
+                                  tier=tier), TierCtx(tier):
                     return self._execute_raw(scan, table, where, project,
                                              sort, limit, offset)
             finally:
@@ -2163,26 +1919,36 @@ class PhysicalExecutor:
             self._whole_columns(scan, table)  # the classic kernels' input
         if reduced is not None:
             scan = reduced
-        # tier re-decision on the POST-reduction row count: the
-        # boundary fast path shrinks a 17M-row lastpoint to a few
-        # thousand candidate rows — routing those to a remote chip
-        # would pay the link RTT for microseconds of compute
-        tier = self.tier_for(agg, scan.num_rows, scan=scan)
-        stream_args = (scan, table, bound_where, tuple(keys),
-                       tuple(arg_exprs), tuple(sorted(ops)), num_groups,
-                       ts_name, ctx, extra_cols, sparse)
-        tier = self._hedge_device_warmup(tier, stream_args)
+        # tier decision on the POST-reduction row count: the boundary
+        # fast path shrinks a 17M-row lastpoint to a few thousand
+        # candidate rows, which no mesh dispatch amortizes
+        tier = self.tier_for(agg, scan.num_rows)
+        keys_t, args_t, ops_t = tuple(keys), tuple(arg_exprs), \
+            tuple(sorted(ops))
+        stream_args = (scan, table, bound_where, keys_t, args_t, ops_t,
+                       num_groups, ts_name, ctx, extra_cols, sparse)
+        # first-touch hedge: serve THIS query host-side while the device
+        # fold of its shape compiles on a background thread
+        if self.router.hedges(tier):
+            wkey = whole_scan_key(scan, bound_where, keys_t, args_t, ops_t,
+                                  num_groups, sparse)
+            if self.router.needed(wkey):
+                self.router.kick(
+                    wkey, lambda: self._stream_agg(*stream_args),
+                    "device warm-up failed for this query shape; it "
+                    "stays on the host tier")
+                tier = "host"
         self.last_tier = tier
         t0 = time.perf_counter()
-        with _TierCtx(tier):
+        with TierCtx(tier):
             acc, sparse_gids = self._stream_agg(*stream_args)
         # measured-routing feed: what this tier actually cost for this
         # scan size (results are materialized host-side by here, so the
         # clock covers upload + kernels + readback). last_tier is the
         # EFFECTIVE tier — a mesh-routed query that degraded to the
         # single-device paths must feed the device history, not mesh's
-        self._note_tier(self.last_tier, scan.num_rows,
-                        time.perf_counter() - t0)
+        self.router.note(self.last_tier, scan.num_rows,
+                         time.perf_counter() - t0)
         if reduced is not None:
             self.last_path = "boundary+" + (self.last_path or "")
         host_info = (scan, extra_cols, bound_where, ctx, num_groups)
@@ -2250,8 +2016,8 @@ class PhysicalExecutor:
         # rows in a millisecond and misroute non-cacheable queries of
         # the same size class. Pure-cache serves feed nothing.
         if stats["delta_rows"]:
-            self._note_tier(tier, stats["delta_rows"],
-                            time.perf_counter() - t0)
+            self.router.note(tier, stats["delta_rows"],
+                             time.perf_counter() - t0)
         self.last_path = "incremental_sparse" if stats.get("sparse") \
             else "incremental"
         self.last_partial_stats = stats
@@ -2348,10 +2114,9 @@ class PhysicalExecutor:
             fp = fp + ("sparse",)
         cache = pc.global_cache()
         # probe the cache BEFORE routing: only the delta (uncached parts
-        # + memtable) runs kernels, and routing a 50-row warm delta to a
-        # remote accelerator would pay the link RTT for microseconds of
-        # compute — the same argument as the boundary fast path's
-        # post-reduction tier re-decision
+        # + memtable) runs kernels, and a 50-row warm delta amortizes no
+        # mesh dispatch — the same argument as the boundary fast path's
+        # post-reduction tier decision
         probed: list[tuple] = []
         delta_est = sum(e.end - e.start for e in mem_entries)
         first_uncached = None
@@ -2363,17 +2128,17 @@ class PhysicalExecutor:
                 delta_est += entry.end - entry.start
                 if first_uncached is None:
                     first_uncached = entry
-        tier = self.tier_for(agg, delta_est, scan=scan)
+        tier = self.tier_for(agg, delta_est)
         # first-touch hedge (the classic paths' 40s-cold-start fix must
         # not regress here): until this shape's per-part kernel has
         # compiled on the accelerator, folds serve host-side and a
-        # background thread warms the device — same contract as
-        # _hedge_device_warmup, keyed by the incremental fingerprint
-        hedge = delta_est > 0 and self._incremental_hedge_needed(tier, fp)
+        # background thread warms the device
+        hedge = delta_est > 0 and self.router.hedges(tier) \
+            and self.router.needed(incremental_key(fp))
         if hedge:
             tier = "host"
         self.last_tier = tier
-        place = self._incremental_placement(tier, scan)
+        place = part_placement(self.mesh, tier, scan)
 
         tag_names = frozenset(ctx.tag_names)
         float_fields = {c.name for c in schema.field_columns
@@ -2460,11 +2225,13 @@ class PhysicalExecutor:
             else compute_partial_dense
 
         if hedge:
-            self._kick_incremental_warm(
-                fp,
-                first_uncached if first_uncached is not None
-                else mem_entries[0],
-                compute_partial)
+            # ONE part's fold is enough to compile the per-part kernel
+            warm_entry = first_uncached if first_uncached is not None \
+                else mem_entries[0]
+            self.router.kick(
+                incremental_key(fp), lambda: compute_partial(warm_entry),
+                "device warm-up of the incremental per-part kernel "
+                "failed; the shape's delta folds stay on the host tier")
 
         # bytes on demand: only a missed part whose column blocks are
         # not all in the HBM hot set needs its rows. Those decode a wave
@@ -2537,62 +2304,6 @@ class PhysicalExecutor:
             SPARSE_DISPATCHES.inc(path="incremental")
         return partials, stats, tier
 
-    def _incremental_hedge_needed(self, tier: str, fp: tuple) -> bool:
-        """Whether this incremental fold must serve host-side while the
-        accelerator compile of its per-part kernel warms in the
-        background (auto host-tier mode on a real accelerator only —
-        mode=off means the caller wants the device NOW and will wait,
-        and the mesh tier has its own placement)."""
-        from greptimedb_tpu import config
-
-        if tier != "device" or jax.default_backend() == "cpu" \
-                or self.mesh is not None \
-                or config.host_tier_mode() != "auto":
-            return False
-        with self._warm_lock:
-            return fp not in self._device_warm
-
-    def _kick_incremental_warm(self, fp: tuple, entry, compute_partial):
-        """Background device compile of the incremental per-part kernel
-        for this shape: runs ONE part's fold on the accelerator and
-        DISCARDS the result (the host-computed partials are already
-        cached — a device-computed twin could differ in the last ulp on
-        emulated f64, and warm/cold serves must stay bit-identical).
-        Once it lands, the shape joins `_device_warm` and later delta
-        folds run on the chip."""
-        with self._warm_lock:
-            if fp in self._device_warming or fp in self._device_warm \
-                    or fp in self._device_warm_failed:
-                return
-            self._device_warming.add(fp)
-
-        def warm():
-            try:
-                with _TierCtx("device"):
-                    compute_partial(entry)
-                with self._warm_lock:
-                    self._device_warm.add(fp)
-            except ScanExpired:
-                # the request is over and the snapshot died before this
-                # thread read its part: nothing was learned about the
-                # device — a later request's hedge warms the shape
-                pass
-            except Exception:  # noqa: BLE001 — hedge must not raise
-                _note_degradation(
-                    "warmup_failed",
-                    "device warm-up of the incremental per-part kernel "
-                    "failed; the shape's delta folds stay on the host tier")
-                with self._warm_lock:
-                    self._device_warm_failed.add(fp)
-            finally:
-                with self._warm_lock:
-                    self._device_warming.discard(fp)
-
-        # under the request's trace: the warm-up's compile hangs off the
-        # request that kicked it, marked thread="warmup"
-        threading.Thread(target=tracing.propagate(warm, background=True),
-                         daemon=True, name="gtpu-incremental-warm").start()
-
     def _parts_ts_disjoint(self, scan, ts_name: str) -> bool:
         """Whether every SST part's ts extent (and the memtable tail's)
         is pairwise disjoint — the proof that LWW dedup cannot cross a
@@ -2606,54 +2317,6 @@ class PhysicalExecutor:
                  for i in range(len(spans) - 1))
         scan._parts_ts_disjoint_cache = ok
         return ok
-
-    def _incremental_placement(self, tier: str, scan):
-        """Compute-placement context per part for the incremental fold:
-        host tier pins the CPU backend; the mesh tier computes each
-        part's partial on the shard `plan_shards` assigns the part's
-        FIRST chunk to (the dispatch's deterministic greedy balance, so
-        uncached folds spread across the mesh the way the classic
-        dispatch's load does). The per-block uploads key under
-        tier="mesh" — a namespace deliberately distinct from both the
-        single-device tiers and the classic dispatch's per-segment
-        "mshard" entries (which chunk parts ACROSS shards and can't be
-        reused at part granularity); all classes share the one
-        DeviceCache byte budget, so duplicates are bounded by LRU, not
-        leaked. Cached partials are host numpy either way — the warm
-        path never touches a device."""
-        if tier == "mesh" and self.mesh is not None:
-            from greptimedb_tpu.parallel import sharded_dispatch as sd
-
-            if sd.eligible(self.mesh):
-                devs = sd.shard_devices(self.mesh)
-                plan = sd.plan_shards(scan, len(devs))
-                owner_of = {}
-                for s, segs in enumerate(plan.segs):
-                    for seg in segs:
-                        if seg.pkey is not None and seg.start == \
-                                seg.part_start:
-                            owner_of[seg.pkey[0]] = s
-                tok = _ACTIVE_TIER_VAR
-
-                class _OnShard:
-                    def __init__(self, fid):
-                        owner = owner_of.get(fid, 0) if fid is not None \
-                            else 0
-                        self._dd = jax.default_device(devs[owner])
-                        self._token = None
-
-                    def __enter__(self):
-                        self._token = tok.set("mesh")
-                        self._dd.__enter__()
-                        return self
-
-                    def __exit__(self, *exc):
-                        self._dd.__exit__(*exc)
-                        tok.reset(self._token)
-                        return False
-
-                return _OnShard
-        return lambda fid: _TierCtx(tier)
 
     @_staged("assemble")
     def _agg_tail(self, acc, sparse_gids, agg, keys, decoders, spec_slot,
@@ -2699,71 +2362,6 @@ class PhysicalExecutor:
 
         return self._post_process(env, agg, having, project, sort, limit, offset,
                                   table, len(present))
-
-    def _hedge_device_warmup(self, tier: str, stream_args) -> str:
-        """First-touch hedge: an accelerator's first compile of a query
-        shape costs seconds to tens of seconds — instead of blocking the
-        first query on it, kick the device fold on a background thread
-        and serve THIS query host-side; once the background compile
-        lands, the shape joins `_device_warm` and later queries run on
-        the chip. Applies only in auto mode on a real accelerator
-        backend (explicit mode=off means the caller wants the device
-        NOW and will wait). A warm-up that fails leaves the shape on
-        the host tier — counted and logged, never silent."""
-        from greptimedb_tpu import config
-
-        if tier != "device" or jax.default_backend() == "cpu" \
-                or self.mesh is not None \
-                or config.host_tier_mode() != "auto":
-            return tier
-        scan = stream_args[0]
-        # repr() folds the full query shape in: WHERE expression, group
-        # keys, and arg expressions each change the compiled HLO — a
-        # key missing them would declare a DIFFERENT program warm and
-        # block the foreground on its cold compile
-        wkey = (scan.region_id, scan.data_version, scan.scan_fingerprint,
-                repr(stream_args[2]), repr(stream_args[3]),
-                repr(stream_args[4]), stream_args[5], stream_args[6],
-                stream_args[10])
-        with self._warm_lock:
-            if wkey in self._device_warm:
-                return "device"
-            if wkey in self._device_warm_failed:
-                return "host"  # don't re-kick a known-failing compile
-            already = wkey in self._device_warming
-            if not already:
-                self._device_warming.add(wkey)
-        if not already:
-            def warm():
-                try:
-                    t0 = time.perf_counter()
-                    with _TierCtx("device"):
-                        self._stream_agg(*stream_args)
-                    # first device sample includes the compile; later
-                    # foreground runs will pull the median down — but a
-                    # device tier that stays slow now shows up in the
-                    # router's history instead of being assumed fast
-                    self._note_tier("device", stream_args[0].num_rows,
-                                    time.perf_counter() - t0)
-                    with self._warm_lock:
-                        self._device_warm.add(wkey)
-                except Exception:  # noqa: BLE001 — hedge must not raise
-                    _note_degradation(
-                        "warmup_failed",
-                        "device warm-up failed for this query shape; it "
-                        "stays on the host tier")
-                    with self._warm_lock:
-                        self._device_warm_failed.add(wkey)
-                finally:
-                    with self._warm_lock:
-                        self._device_warming.discard(wkey)
-
-            # under the request's trace: the warm-up's compile hangs off
-            # the request that kicked it, marked thread="warmup"
-            threading.Thread(
-                target=tracing.propagate(warm, background=True),
-                daemon=True, name="gtpu-device-warm").start()
-        return "host"
 
     def _boundary_firstlast(self, scan, table, agg, bound_where, keys,
                             extra_cols) -> Optional[ScanData]:
@@ -3498,7 +3096,7 @@ class PhysicalExecutor:
                 else:
                     # whole-scan arrays cannot be file-anchored: snapshot key
                     key = ("snap", scan.region_id, _snap_version(scan),
-                           _ACTIVE_TIER_VAR.get(), scan.scan_fingerprint,
+                           ACTIVE_TIER.get(), scan.scan_fingerprint,
                            name, "whole", n_pad, str(cast))
                     cols[name] = self.cache.get(key, build)
         base = np.arange(n_pad) < n
@@ -3614,7 +3212,7 @@ class PhysicalExecutor:
             raise sd.MeshIneligible("sparse path needs part-aligned dispatch")
         n_shard = mesh.shape["shard"]
         plan = sd.plan_shards(scan, n_shard)
-        tier = _ACTIVE_TIER_VAR.get()
+        tier = ACTIVE_TIER.get()
         snap_v = _snap_version(scan)
         cols = {}
         for name in device_col_names:
@@ -3712,7 +3310,7 @@ class PhysicalExecutor:
 
         n_shard = mesh.shape["shard"]
         plan = sd.plan_shards(scan, n_shard)
-        tier = _ACTIVE_TIER_VAR.get()
+        tier = ACTIVE_TIER.get()
         snap_v = _snap_version(scan)
         cache = self.cache
         prepared = self._prepared_ok(arg_exprs, ops, (), schema, extra_cols)
@@ -3819,7 +3417,7 @@ class PhysicalExecutor:
                 device_telemetry.count_h2d(cols[name].nbytes)
             else:
                 key = ("snap", scan.region_id, _snap_version(scan),
-                       _ACTIVE_TIER_VAR.get(), scan.scan_fingerprint,
+                       ACTIVE_TIER.get(), scan.scan_fingerprint,
                        name, "sharded", n_pad, n_shard, str(cast))
                 cols[name] = self.cache.get(key, build)
         base = np.arange(n_pad) < n
@@ -3852,7 +3450,7 @@ class PhysicalExecutor:
                     cols[plane_name] = build_plane()
                 else:
                     key = ("snap", scan.region_id, _snap_version(scan),
-                           _ACTIVE_TIER_VAR.get(), scan.scan_fingerprint,
+                           ACTIVE_TIER.get(), scan.scan_fingerprint,
                            plane_name, arg_names, "sharded", n_pad,
                            n_shard, str(pdt), has_nan)
                     cols[plane_name] = self.cache.get(key, build_plane)
@@ -3876,7 +3474,7 @@ class PhysicalExecutor:
         from greptimedb_tpu.query.device_cache import upload_prefetch_enabled
 
         return (upload_prefetch_enabled() and scan.region_id >= 0
-                and _ACTIVE_TIER_VAR.get() != "host")
+                and ACTIVE_TIER.get() != "host")
 
     def _gather_blocks(self, scan, plan, fetch, dedup_mask):
         """Walk the block plan through `fetch`, double-buffering block
@@ -4038,7 +3636,7 @@ class PhysicalExecutor:
         invalidated by file death (compaction/expiry/DROP), not by every
         memtable write; everything else is snapshot-anchored and retires
         with its data version."""
-        tier = _ACTIVE_TIER_VAR.get()
+        tier = ACTIVE_TIER.get()
         if entry.pkey is not None:
             fid, ts_r, pred_key = entry.pkey
             return ("file", scan.region_id, fid, tier, ts_r, pred_key,

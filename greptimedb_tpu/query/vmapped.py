@@ -50,6 +50,7 @@ import numpy as np
 
 from greptimedb_tpu.query import logical as lp
 from greptimedb_tpu.query import physical as ph
+from greptimedb_tpu.query.tier import ACTIVE_TIER, TierCtx
 from greptimedb_tpu.query.expr import (
     BindContext,
     bind_expr,
@@ -428,7 +429,7 @@ def run_vmapped(executor, sel: ast.Select, info, pspecs,
         if name not in device_col_names:
             device_col_names.append(name)
 
-    tier = executor.tier_for(agg, scan.num_rows, scan=scan)
+    tier = executor.tier_for(agg, scan.num_rows)
     executor.last_tier = tier
 
     def fetch_block(entry, prefetch_only=False):
@@ -456,7 +457,7 @@ def run_vmapped(executor, sel: ast.Select, info, pspecs,
             tag_names, schema, ts_name, need_ts, arg_exprs, ops, cap,
             float_ops, widths, pack_dtype, tier, num_groups)
 
-    with ph._TierCtx(tier):
+    with TierCtx(tier):
         blocks, n_valids, dmasks = executor._gather_blocks(
             scan, block_plan, fetch_block, dedup_mask)
         packed = _vmapped_agg_scan(
@@ -526,14 +527,14 @@ def _run_vmapped_sparse(executor, scan, agg, project, table, keys, decoders,
             cols[name] = build()
         else:
             key = ("snap", scan.region_id, ph._snap_version(scan),
-                   ph._ACTIVE_TIER_VAR.get(), scan.scan_fingerprint,
+                   ACTIVE_TIER.get(), scan.scan_fingerprint,
                    name, "whole", n_pad, str(cast))
             cols[name] = executor.cache.get(key, build)
     base = np.arange(n_pad) < n
     if dedup_mask is not None:
         base[:n] &= np.asarray(dedup_mask)[:n]
 
-    with ph._TierCtx(tier):
+    with TierCtx(tier):
         packed, uniq, n_obs = _vmapped_sparse_agg_scan(
             cols, jnp.asarray(base), tuple(params),
             shared_where=bound_shared, param_specs=tuple(cols_ops),

@@ -32,7 +32,7 @@ _pool_size = 0
 def resolve(decode_threads: int, num_files: int) -> int:
     """Effective worker count for one scan: the configured cap (0 =
     auto) bounded by the files actually needing decode. The env var
-    (set by bench A/B runs and tests) wins over the config object."""
+    (set by A/B runs and tests) wins over the config object."""
     env = os.environ.get("GREPTIMEDB_TPU_SCAN_DECODE_THREADS")
     if env:
         try:

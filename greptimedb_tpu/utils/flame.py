@@ -284,7 +284,7 @@ def _merged() -> collections.Counter:
 
 
 def reset() -> None:
-    """Drop all windows and remote summaries (tests / bench A/B)."""
+    """Drop all windows and remote summaries (tests / A/B runs)."""
     with _lock:
         _WINDOWS.clear()
         _WINDOWS.append(_new_window())
@@ -348,7 +348,7 @@ def speedscope() -> dict:
 
 
 def summary(top: int = 10, node: Optional[str] = None) -> dict:
-    """Compact digest for piggyback/heartbeat/bench: bounded, mergeable."""
+    """Compact digest for piggyback/heartbeat: bounded, mergeable."""
     merged = _merged()
     total = sum(merged.values())
     attributed = 0

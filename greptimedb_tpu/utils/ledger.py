@@ -26,7 +26,7 @@ never drift):
   `other` from ``stages_ms``
 
 `GTPU_TRACING=off` disables the ledger together with span recording —
-the observability plane A/Bs as one unit (the bench's overhead gate).
+the observability plane A/Bs as one unit.
 """
 
 from __future__ import annotations

@@ -601,12 +601,6 @@ SPARSE_COMPACTION_RATIO = REGISTRY.gauge(
     "greptimedb_tpu_sparse_compaction_ratio",
     "Observed groups per scanned row in the last sparse aggregation "
     "(1.0 = every row its own group, no compaction win)")
-TIER_ADMISSION = REGISTRY.counter(
-    "greptimedb_tpu_tier_admission_total",
-    "Hot-set-aware tier admission decisions by reason (device_hot/"
-    "host_hot = routed to the tier already holding the scan's "
-    "file-anchored blocks, cold = no tier holds them, off = the "
-    "GREPTIMEDB_TPU_TIER_ADMISSION knob disabled the probe)")
 QUERY_TIER = REGISTRY.counter(
     "greptimedb_tpu_query_tier_total",
     "Statements the executor answered, by the tier that answered: host "
@@ -646,8 +640,8 @@ SLOW_QUERIES = REGISTRY.counter(
     "Statements slower than the slow-query threshold, by kind")
 
 # scan pipeline (storage/region.py + query/device_cache.py): the cold
-# scan is the wall on first-touch queries (BENCH r03: 20.2s of a 27.5s
-# statement inside scan) — these series prove the three pipeline stages
+# scan is the wall on first-touch queries — these series prove the
+# three pipeline stages
 # (parallel SST decode, per-file part cache, upload prefetch) are doing
 # their jobs
 SCAN_DECODE_SECONDS = REGISTRY.histogram(

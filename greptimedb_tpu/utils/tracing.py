@@ -114,7 +114,7 @@ class Span:
 
 def new_trace_id() -> str:
     # os.urandom(8).hex() is ~3x cheaper than uuid4 and ids are minted
-    # per request AND per span — this is hot-path cost (the <3% bench
+    # per request AND per span — this is hot-path cost (the <3%
     # overhead budget)
     return new_span_id()
 

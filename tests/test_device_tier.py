@@ -515,70 +515,3 @@ class TestFusedDegradation:
             np.testing.assert_allclose(
                 [float(x) for x in a[1:]], [float(y) for y in b[1:]],
                 rtol=1e-9)
-
-
-# ---- measured tier routing -------------------------------------------------
-
-
-@pytest.fixture
-def remote_executor(tmp_path, monkeypatch):
-    """A remote-link-shaped executor (the static heuristic routes small
-    aggregates to host) with no mesh interference."""
-    engine = RegionEngine(EngineConfig(data_dir=str(tmp_path / "r")))
-    qe = QueryEngine(Catalog(MemoryKv()), engine)
-    ex = qe.executor
-    monkeypatch.setattr(ph, "_LINK", {
-        "backend": "tpu", "rtt_ms": 66.0, "d2h_mbps": 11.0,
-        "colocated": False})
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(ex, "mesh", None)
-    yield ex
-    ph._LINK = None
-    engine.close()
-
-
-class TestMeasuredRouting:
-    N = 1000
-
-    def _feed(self, ex, device_s, host_s, n=3):
-        for _ in range(n):
-            ex._note_tier("device", self.N, device_s)
-            ex._note_tier("host", self.N, host_s)
-
-    def test_measured_winner_overrides_heuristic(self, remote_executor):
-        ex = remote_executor
-        # static heuristic for a small aggregate over a slow link: host
-        assert ex.tier_for(object(), self.N) == "host"
-        # but the DEVICE measures faster -> routing follows the numbers
-        self._feed(ex, device_s=0.05, host_s=0.40)
-        assert ex.tier_for(object(), self.N) == "device"
-
-    def test_losing_tier_stops_being_chosen(self, remote_executor):
-        ex = remote_executor
-        self._feed(ex, device_s=0.61, host_s=0.40)  # the r05 anchor shape
-        assert ex.tier_for(object(), self.N) == "host"
-
-    def test_insufficient_history_falls_back(self, remote_executor):
-        ex = remote_executor
-        ex._note_tier("device", self.N, 0.1)  # one sample only
-        assert ex.tier_for(object(), self.N) == "host"  # heuristic
-
-    def test_env_override_pins_heuristic(self, remote_executor,
-                                         monkeypatch):
-        ex = remote_executor
-        self._feed(ex, device_s=0.05, host_s=0.40)
-        monkeypatch.setenv("GREPTIMEDB_TPU_TIER_ADAPTIVE", "off")
-        assert ex.tier_for(object(), self.N) == "host"  # heuristic wins
-
-    def test_periodic_exploration_revisits_loser(self, remote_executor):
-        ex = remote_executor
-        self._feed(ex, device_s=0.05, host_s=0.40)
-        seen = {ex.tier_for(object(), self.N) for _ in range(16)}
-        assert seen == {"device", "host"}  # the 16th decision explores
-
-    def test_size_classes_are_independent(self, remote_executor):
-        ex = remote_executor
-        self._feed(ex, device_s=0.05, host_s=0.40)
-        # a different size class has no samples -> heuristic
-        assert ex.tier_for(object(), 20_000_000) == "device"
-        assert ex.tier_for(object(), 1000) == "device"  # same bucket as N

@@ -241,12 +241,12 @@ class TestRoutingAndDegradation:
         ex = qe.executor
         n = 200_000
         for _ in range(4):
-            ex._note_tier("mesh", n, 0.100)
-            ex._note_tier("device", n, 0.010)
-        picks = {ex._mesh_from_history(n) for _ in range(15)}
+            ex.router.note("mesh", n, 0.100)
+            ex.router.note("device", n, 0.010)
+        picks = {ex.tier_for(object(), n) for _ in range(15)}
         assert picks == {"device"}
         # the periodic exploration re-tries the loser eventually
-        picks = [ex._mesh_from_history(n) for _ in range(16)]
+        picks = [ex.tier_for(object(), n) for _ in range(16)]
         assert "mesh" in picks
 
     def test_mesh_ineligible_is_typed(self):

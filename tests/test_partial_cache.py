@@ -659,19 +659,16 @@ class TestDeviceHedge:
         monkeypatch.setattr(ex, "mesh", None)
         monkeypatch.setattr(
             ex, "tier_for",
-            lambda agg, n, streaming=False, scan=None: "device")
+            lambda agg, n, streaming=False: "device")
         res = qe.execute_one(AGG_SQL, CTX)
         assert qe.executor.last_path == "incremental"
         assert qe.executor.last_tier == "host"  # hedged: no compile stall
         for _ in range(100):  # the background warm lands
-            with ex._warm_lock:
-                if not ex._device_warming:
-                    break
+            if not ex.router.status()["warmup"]["warming"]:
+                break
             _time.sleep(0.05)
-        with ex._warm_lock:
-            warmed = any(isinstance(k, tuple) and len(k) == 5
-                         for k in ex._device_warm)
-        assert warmed
+        assert ex.router.status()["warmup"] == {
+            "warm": 1, "warming": 0, "failed": 0}
         res2 = qe.execute_one(AGG_SQL, CTX)
         assert qe.executor.last_tier == "device"  # warm: device serves
         assert_same(res, res2)

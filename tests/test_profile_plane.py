@@ -144,8 +144,7 @@ class TestContinuousSampler:
     @pytest.mark.slow
     def test_overhead_budget_2pct(self, sampler_off):
         """A/B the busy loop with the sampler on vs off: the always-on
-        budget is <=2% (median of alternating rounds, like bench.py's
-        qps A/B)."""
+        budget is <=2% (median of alternating rounds)."""
         def _round():
             t0 = time.perf_counter()
             _spin_ms(250)

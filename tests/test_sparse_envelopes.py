@@ -307,53 +307,6 @@ class TestTypedFallbacks:
             db.execute_one(SQL)
 
 
-class TestTierAdmission:
-    """Hot-set-aware tier admission (satellite): the router consults
-    which tier already holds the scan's file-anchored blocks. The CPU
-    backend's tier_for short-circuits to "device" before the probe, so
-    the probe is pinned directly."""
-
-    def _scan(self, qe, name="hc"):
-        info = qe.catalog.table("public", name)
-        return qe.region_engine.scan(info.region_ids[0], None,
-                                     list(info.schema.names), None), \
-            info.region_ids[0]
-
-    def test_device_hot_set_attracts(self, db, monkeypatch):
-        from greptimedb_tpu.utils.metrics import TIER_ADMISSION
-
-        monkeypatch.setenv("GREPTIMEDB_TPU_PARTIAL_CACHE", "off")
-        fill_highcard(db, 64)
-        db.execute_one(SQL)  # warms file-anchored device blocks
-        scan, rid = self._scan(db)
-        assert db.executor.cache.file_keys(rid), \
-            "query should have cached file-anchored blocks"
-        before = TIER_ADMISSION.get(reason="device_hot")
-        assert db.executor._hot_set_admission(scan) == "device"
-        assert TIER_ADMISSION.get(reason="device_hot") == before + 1
-
-    def test_cold_scan_defers_to_history(self, db, monkeypatch):
-        from greptimedb_tpu.utils.metrics import TIER_ADMISSION
-
-        fill_highcard(db, 64)
-        scan, rid = self._scan(db)  # nothing executed: cache is cold
-        before = TIER_ADMISSION.get(reason="cold")
-        assert db.executor._hot_set_admission(scan) is None
-        assert TIER_ADMISSION.get(reason="cold") == before + 1
-
-    def test_knob_disables_probe(self, db, monkeypatch):
-        from greptimedb_tpu.utils.metrics import TIER_ADMISSION
-
-        monkeypatch.setenv("GREPTIMEDB_TPU_PARTIAL_CACHE", "off")
-        fill_highcard(db, 64)
-        db.execute_one(SQL)
-        monkeypatch.setenv("GREPTIMEDB_TPU_TIER_ADMISSION", "off")
-        scan, _rid = self._scan(db)
-        before = TIER_ADMISSION.get(reason="off")
-        assert db.executor._hot_set_admission(scan) is None
-        assert TIER_ADMISSION.get(reason="off") == before + 1
-
-
 class TestSortCompactUnit:
     """ops-level seams of the shared sparse plane."""
 

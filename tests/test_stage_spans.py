@@ -311,10 +311,14 @@ def test_compile_on_the_warm_up_thread_hangs_off_its_request():
 
 
 def test_device_hedge_warm_up_runs_under_propagate():
+    # the one hedge both call sites use (query/tier.py TierRouter.kick)
     src = open(os.path.join(ROOT, "greptimedb_tpu", "query",
-                            "physical.py")).read()
-    assert src.count("tracing.propagate(warm, background=True)") == 2
+                            "tier.py")).read()
+    assert src.count("tracing.propagate(warm, background=True)") == 1
     assert not re.search(r"Thread\(target=warm\b", src)
+    executor = open(os.path.join(ROOT, "greptimedb_tpu", "query",
+                                 "physical.py")).read()
+    assert "target=warm" not in executor
 
 
 def test_query_tier_total_moves_once_per_statement(server):

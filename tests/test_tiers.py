@@ -1,12 +1,13 @@
-"""Tiered execution routing (physical.tier_for / accelerator_link):
-policy decisions under different link shapes and modes. Tests run on
-the CPU backend, so the link is co-located by construction; remote-link
-policy is exercised by stubbing the probe."""
+"""Tiered execution routing (PhysicalExecutor.tier_for, the delegate of
+query/tier.py's TierRouter.choose): the decision under each backend and
+GREPTIMEDB_TPU_HOST_TIER mode. Tests run on the CPU backend; an
+accelerator is exercised by stubbing jax.default_backend. The link
+probe is reported, never routed on, so no test stubs it."""
 
 import jax
 import pytest
 
-import greptimedb_tpu.query.physical as ph
+import greptimedb_tpu.query.tier as tiering
 from greptimedb_tpu.catalog import Catalog, MemoryKv
 from greptimedb_tpu.query import QueryEngine
 from greptimedb_tpu.storage import RegionEngine
@@ -28,37 +29,19 @@ def test_cpu_backend_always_device(executor):
 
 
 def test_link_probe_on_cpu_is_colocated():
-    link = ph.accelerator_link()
+    link = tiering.accelerator_link()
     assert link["colocated"] is True
 
 
-class TestRemoteLinkPolicy:
-    """Stub a slow, not-attached link and a non-cpu backend."""
+class TestAcceleratorPolicy:
+    """Stub a non-cpu backend."""
 
     @pytest.fixture(autouse=True)
-    def remote_link(self, monkeypatch, executor):
-        monkeypatch.setattr(ph, "_LINK", {
-            "backend": "tpu", "rtt_ms": 66.0, "d2h_mbps": 11.0,
-            "colocated": False})
+    def accelerator(self, monkeypatch, executor):
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        # the test conftest builds an 8-device CPU mesh; a mesh pins the
-        # device tier, which is not what these policy tests exercise
+        # the test conftest builds an 8-device CPU mesh; a mesh never
+        # consults the host-tier mode, which is what these tests do
         monkeypatch.setattr(executor, "mesh", None)
-        yield
-        ph._LINK = None
-
-    def test_small_aggregate_takes_host(self, executor):
-        assert executor.tier_for(object(), 1000) == "host"
-
-    def test_large_aggregate_takes_device(self, executor):
-        assert executor.tier_for(object(), 20_000_000) == "device"
-
-    def test_raw_queries_take_host(self, executor):
-        assert executor.tier_for(None, 20_000_000) == "host"
-
-    def test_streaming_takes_host(self, executor):
-        assert executor.tier_for(object(), 100_000_000,
-                                 streaming=True) == "host"
 
     def test_off_mode_pins_device(self, executor, monkeypatch):
         monkeypatch.setenv("GREPTIMEDB_TPU_HOST_TIER", "off")
@@ -74,13 +57,11 @@ class TestRemoteLinkPolicy:
 
 
 def test_colocated_link_pins_device(executor, monkeypatch):
-    monkeypatch.setattr(ph, "_LINK", {
-        "backend": "tpu", "rtt_ms": 0.2, "d2h_mbps": 10_000.0,
-        "colocated": True})
+    """An attached chip in auto mode: everything is chosen for the
+    device, whatever the size and whether or not it aggregates."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(executor, "mesh", None)
-    try:
-        assert executor.tier_for(None, 100) == "device"
-        assert executor.tier_for(object(), 100) == "device"
-    finally:
-        ph._LINK = None
+    assert executor.tier_for(None, 100) == "device"
+    assert executor.tier_for(object(), 100) == "device"
+    assert executor.tier_for(object(), 100_000_000,
+                             streaming=True) == "device"
