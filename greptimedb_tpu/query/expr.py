@@ -6,8 +6,10 @@ never sees strings (SURVEY.md §7 hard part #2):
   - LIKE on a tag becomes an InList of matching codes (pattern evaluated
     against the small dictionary on host)
   - timestamp literals are coerced to the column's storage unit
-Bound expressions are frozen/hashable, so they ride into jit as *static*
-arguments and the evaluator below is plain traced JAX.
+Bound expressions are frozen/hashable. Before one reaches a jitted step
+`split_operands` cuts it into a literal-free *shape* (the static
+argument: one executable per shape) and a flat tuple of *operands* (the
+literals, traced), and the evaluator below is plain traced JAX.
 
 The host evaluator mirrors device semantics over numpy and additionally
 handles aggregate-result substitution (post-aggregation HAVING/ORDER BY/
@@ -225,6 +227,163 @@ def _like_to_regex(pattern: str) -> re.Pattern:
     return re.compile("".join(out), re.IGNORECASE | re.DOTALL)
 
 
+# ---- shape / operands (what of a bound predicate is static under jit) ------
+
+
+@dataclass(frozen=True)
+class Operand(ast.Expr):
+    """Where a literal stood in a predicate's shape: its position in the
+    operand tuple, and for an IN list the padded width (part of the
+    shape: the operand is one [width] array)."""
+
+    index: int
+    width: int = 0
+
+
+def _in_width(n: int) -> int:
+    """IN lists pad to the next power of two: 1-, 2- and 8-host panels
+    are three shapes, a 5-host one shares the 8's."""
+    return 1 << max(n - 1, 0).bit_length()
+
+
+def _number(e) -> Optional[ast.Literal]:
+    """The numeric literal an expression is — a bare one, or one under
+    a minus sign (the parser's form of a negative number) — else None."""
+    neg = isinstance(e, ast.UnaryOp) and e.op == "-"
+    lit = e.operand if neg else e
+    if not isinstance(lit, ast.Literal) \
+            or not isinstance(lit.value, (int, float, np.integer,
+                                          np.floating)) \
+            or isinstance(lit.value, (bool, np.bool_)):
+        return None
+    return ast.Literal(-lit.value) if neg else lit
+
+
+def split_operands(bound_where: Optional[ast.Expr],
+                   schema: Optional[Schema] = None) -> tuple:
+    """(shape, operands, static_literal) of a BOUND predicate. Every
+    numeric literal compared with a column (`=`, `!=`, `<`..`>=`,
+    BETWEEN, IN) leaves the expression for the operand tuple and an
+    `Operand` takes its place, so two requests that differ in their
+    literals have equal shapes and share one executable. An operand's
+    dtype follows its column, not its spelling (`v > 10` and `v > 95.5`
+    are one shape): tag codes int32, a float column's literals float64,
+    an integer or time-index column's int64 (time-index values in the
+    column's storage unit), or float64 where the literal has a fraction;
+    `eval_device` casts each to its column's dtype, as the constant it
+    replaces was. An IN list is one operand, padded to `_in_width` with
+    a value that changes no membership (MISSING_CODE on a tag, else its
+    first item). What the split cannot prove safe to trace (NULL, a
+    boolean, a string, a literal inside arithmetic, a function call or
+    CASE, an Interval) stays in the shape as it is: such a predicate
+    runs as before and shares no executable; `static_literal` says the
+    shape still holds one."""
+    operands: list = []
+    tags = {c.name for c in schema.tag_columns} if schema is not None \
+        else set()
+
+    def dtype_of(e) -> Optional[np.dtype]:
+        """The dtype the column's literals are held in, or None for what
+        is no column the split knows to be numeric on the device."""
+        if not isinstance(e, ast.Column):
+            return None
+        if e.name in tags:
+            return np.dtype(np.int32)
+        if schema is None:
+            return np.dtype(np.int64)
+        if e.name not in schema:
+            return None
+        dt = schema.column(e.name).dtype
+        if dt.is_timestamp or dt.is_numeric:
+            return dt.to_numpy()
+        return None
+
+    def operand_of(col, e):
+        """The value `e` holds as an operand of `col`'s comparisons, or
+        None where it stays a constant: no number, or one the operand's
+        dtype cannot hold as the column's own would."""
+        dt, lit = dtype_of(col), _number(e)
+        if dt is None or lit is None:
+            return None
+        v = lit.value
+        if isinstance(v, (float, np.floating)):
+            return None if col.name in tags else np.float64(v)
+        if dt.kind == "f":
+            # exact in float64, so the cast to the column's dtype rounds
+            # once, as the integer constant did
+            return np.float64(v) if abs(int(v)) <= 1 << 53 else None
+        info = np.iinfo(dt)
+        if not info.min <= int(v) <= min(info.max, (1 << 63) - 1):
+            return None
+        return np.int32(v) if col.name in tags else np.int64(v)
+
+    def place(v) -> Operand:
+        operands.append(v)
+        return Operand(len(operands) - 1)
+
+    def walk(e):
+        if isinstance(e, ast.BinaryOp):
+            if e.op in ("=", "!=", "<", "<=", ">", ">="):
+                v = operand_of(e.left, e.right)
+                if v is not None:
+                    return ast.BinaryOp(e.op, e.left, place(v))
+                v = operand_of(e.right, e.left)
+                if v is not None:
+                    return ast.BinaryOp(e.op, place(v), e.right)
+                return e
+            if e.op in ("and", "or"):
+                return ast.BinaryOp(e.op, walk(e.left), walk(e.right))
+            return e
+        if isinstance(e, ast.UnaryOp) and e.op == "not":
+            return ast.UnaryOp(e.op, walk(e.operand))
+        if isinstance(e, ast.Between):
+            lo, hi = operand_of(e.expr, e.low), operand_of(e.expr, e.high)
+            if lo is None or hi is None:
+                return e
+            return ast.Between(e.expr, place(lo), place(hi), e.negated)
+        if isinstance(e, ast.InList):
+            tag = isinstance(e.expr, ast.Column) and e.expr.name in tags
+            vals = [operand_of(e.expr, i) for i in e.items]
+            if any(v is None for v in vals) or not (vals or tag):
+                return e
+            width = _in_width(len(vals))
+            vals += [np.int32(MISSING_CODE) if tag else vals[0]] \
+                * (width - len(vals))
+            # one dtype for the list: int32 codes, int64, or float64
+            # where an item has a fraction
+            operands.append(np.asarray(vals))
+            return ast.InList(e.expr, (Operand(len(operands) - 1, width),),
+                              e.negated)
+        return e
+
+    if bound_where is None:
+        return None, (), False
+    shape = walk(bound_where)
+    return shape, tuple(operands), _holds_literal(shape)
+
+
+def _holds_literal(e) -> bool:
+    """Whether a literal is left anywhere in an expression."""
+    if isinstance(e, (ast.Literal, ast.Interval)):
+        return True
+    if isinstance(e, (tuple, list)):
+        return any(_holds_literal(x) for x in e)
+    if isinstance(e, ast.Expr):
+        return any(_holds_literal(getattr(e, f))
+                   for f in e.__dataclass_fields__)
+    return False
+
+
+def _as_literal(x, v):
+    """An operand compares as the constant it replaced would: a Python
+    scalar is weakly typed and takes the column's dtype, unless it is a
+    float against an integer column."""
+    if jnp.issubdtype(v.dtype, jnp.floating) \
+            and not jnp.issubdtype(x.dtype, jnp.floating):
+        return v
+    return v.astype(x.dtype)
+
+
 # ---- device evaluation (traced JAX; expr must be bound) --------------------
 
 _DEVICE_FUNCS = {
@@ -239,13 +398,18 @@ _DEVICE_FUNCS = {
 }
 
 
-def eval_device(e: ast.Expr, cols: dict, ctx_tags: frozenset, schema: Schema):
-    """Evaluate a bound expression over device column arrays. `e` is static
-    under jit; this runs at trace time."""
+def eval_device(e: ast.Expr, cols: dict, ctx_tags: frozenset, schema: Schema,
+                operands: tuple = ()):
+    """Evaluate a bound expression (or the shape `split_operands` made
+    of one, with its `operands`) over device column arrays. `e` is
+    static under jit and this runs at trace time; the operands are
+    traced."""
 
     def ev(x):
-        return eval_device(x, cols, ctx_tags, schema)
+        return eval_device(x, cols, ctx_tags, schema, operands)
 
+    if isinstance(e, Operand):
+        return jnp.asarray(operands[e.index])
     if isinstance(e, ast.Column):
         if e.name not in cols:
             raise PlanError(f"column {e.name!r} not available on device")
@@ -264,6 +428,10 @@ def eval_device(e: ast.Expr, cols: dict, ctx_tags: frozenset, schema: Schema):
         if e.op == "or":
             return _as_bool(ev(e.left)) | _as_bool(ev(e.right))
         a, b = ev(e.left), ev(e.right)
+        if isinstance(e.right, Operand):
+            b = _as_literal(a, b)
+        elif isinstance(e.left, Operand):
+            a = _as_literal(b, a)
         if e.op == "+":
             return a + b
         if e.op == "-":
@@ -294,12 +462,18 @@ def eval_device(e: ast.Expr, cols: dict, ctx_tags: frozenset, schema: Schema):
         return ~_as_bool(v) if e.op == "not" else -v
     if isinstance(e, ast.Between):
         x = ev(e.expr)
-        res = (x >= ev(e.low)) & (x <= ev(e.high))
+        lo, hi = ev(e.low), ev(e.high)
+        if isinstance(e.low, Operand):
+            lo, hi = _as_literal(x, lo), _as_literal(x, hi)
+        res = (x >= lo) & (x <= hi)
         return ~res if e.negated else res
     if isinstance(e, ast.InList):
         x = ev(e.expr)
         if not e.items:
             res = jnp.zeros(x.shape, dtype=bool)
+        elif isinstance(e.items[0], Operand):
+            # one [width] operand compared by broadcast
+            res = (x[..., None] == _as_literal(x, ev(e.items[0]))).any(-1)
         else:
             res = x == ev(e.items[0])
             for item in e.items[1:]:

@@ -17,6 +17,7 @@ not append-mode.
 from __future__ import annotations
 
 import collections
+import dataclasses
 import functools
 import itertools
 import logging
@@ -50,6 +51,7 @@ from greptimedb_tpu.query.expr import (
     collect_columns,
     eval_device,
     eval_host,
+    split_operands,
 )
 from greptimedb_tpu.query.result import QueryResult
 from greptimedb_tpu.query.tier import (
@@ -172,13 +174,17 @@ def _needs_host_agg(spec, schema) -> bool:
 
 @dataclass(frozen=True)
 class DeviceKey:
-    """One group-by key computed on device (static under jit)."""
+    """One group-by key computed on device. Static under jit but for
+    `base`, which is no part of the key's identity: it comes from the
+    statement's own time bounds, so it reaches a kernel as an operand
+    (`_operands`) and only host code reads it here."""
 
     kind: str  # "tag" | "bucket" | "pre"
     column: str
     size: int
     step: int = 0  # bucket width in the column's storage unit
-    base: int = 0  # minimum bucket index (offsets ids to 0)
+    # minimum bucket index (offsets ids to 0)
+    base: int = dataclasses.field(default=0, compare=False, repr=False)
 
 
 class _BlockEntry(NamedTuple):
@@ -257,17 +263,34 @@ def _value_planes(agg_args, cols, tag_names, schema, shape, acc_dtype):
     return jnp.stack(vals, axis=1)
 
 
-def _group_ids(cols: dict, keys, n: int) -> jax.Array:
+def _where_mask(mask, where, where_args, cols: dict, tag_names, schema):
+    """`mask` narrowed by the predicate: its shape (static) evaluated
+    over the block with its operands (traced)."""
+    if where is None:
+        return mask
+    w = eval_device(where, cols, tag_names, schema, where_args)
+    return mask & (w if w.dtype == jnp.bool_ else w != 0)
+
+
+def _bucket_bases(keys, where_args):
+    """The time-bucket keys' bases, in key order: the operands after the
+    predicate's own (`_operands` put them there)."""
+    nb = sum(k.kind == "bucket" for k in keys)
+    return iter(where_args[len(where_args) - nb:])
+
+
+def _group_ids(cols: dict, keys, n: int, where_args=()) -> jax.Array:
     """Dense group ids from the key columns (shared by every agg path)."""
     if not keys:
         return jnp.zeros(n, dtype=jnp.int32)
     key_arrays = []
+    bases = _bucket_bases(keys, where_args)
     for k in keys:
         c = cols[k.column]
         if k.kind == "tag":
             arr = (c + 1).astype(jnp.int32)
         elif k.kind == "bucket":
-            arr = (c // k.step - k.base).astype(jnp.int32)
+            arr = (c // k.step - next(bases)).astype(jnp.int32)
         else:
             arr = c.astype(jnp.int32)
         key_arrays.append(jnp.clip(arr, 0, k.size - 1))
@@ -280,6 +303,7 @@ def _agg_block(
     dedup_mask,  # Optional[jax.Array]: survivors of last-write-wins
     *,
     where,
+    where_args: tuple = (),  # traced: the predicate's operands, bucket bases
     keys: tuple[DeviceKey, ...],
     agg_args: tuple,
     ops: tuple[str, ...],
@@ -296,7 +320,8 @@ def _agg_block(
     if dedup_mask is not None:
         mask = mask & dedup_mask
     return _agg_block_masked(
-        cols, mask, where=where, keys=keys, agg_args=agg_args, ops=ops,
+        cols, mask, where=where, where_args=where_args, keys=keys,
+        agg_args=agg_args, ops=ops,
         num_segments=num_segments, ts_name=ts_name, tag_names=tag_names,
         schema=schema, need_ts=need_ts, acc_dtype=acc_dtype,
     )
@@ -307,6 +332,7 @@ def _agg_block_masked(
     mask: jax.Array,  # [N] base validity (padding & dedup), pre-filter
     *,
     where,
+    where_args: tuple = (),
     keys: tuple[DeviceKey, ...],
     agg_args: tuple,
     ops: tuple[str, ...],
@@ -317,10 +343,8 @@ def _agg_block_masked(
     need_ts: bool,
     acc_dtype=jnp.float64,
 ):
-    if where is not None:
-        w = eval_device(where, cols, tag_names, schema)
-        mask = mask & (w if w.dtype == jnp.bool_ else w != 0)
-    gid = _group_ids(cols, keys, mask.shape[0])
+    mask = _where_mask(mask, where, where_args, cols, tag_names, schema)
+    gid = _group_ids(cols, keys, mask.shape[0], where_args)
     if agg_args:
         values = _value_planes(agg_args, cols, tag_names, schema,
                                mask.shape, acc_dtype)
@@ -343,7 +367,7 @@ def _agg_scan_prepared(
     dedup_masks,
     *,
     where, keys, nf, has_nan, finite, num_segments, tag_names, schema,
-    float_ops, pack_dtype,
+    float_ops, pack_dtype, where_args=(),
 ):
     """Dense fast path for sum/count/mean/rows over plain field columns.
 
@@ -366,10 +390,9 @@ def _agg_scan_prepared(
         mask = jnp.arange(plane.shape[0]) < n_valids[i]
         if dedup_masks is not None:
             mask = mask & dedup_masks[i]
-        if where is not None:
-            w = eval_device(where, cols, tag_names, schema)
-            mask = mask & (w if w.dtype == jnp.bool_ else w != 0)
-        gid = _group_ids(cols, keys, plane.shape[0])
+        mask = _where_mask(mask, where, where_args, cols, tag_names,
+                           schema)
+        gid = _group_ids(cols, keys, plane.shape[0], where_args)
         ids = jnp.where(mask, gid, jnp.int32(G))
         part = dense_segment_sum(plane, ids, G + 1, finite=finite)[:G]
         total = part if total is None else total + part
@@ -447,7 +470,7 @@ def _agg_scan_fused(
     *,
     where, keys, arg_names, num_segments, ts_name, tag_names, schema,
     float_ops, int_ops, pack_dtype, acc_dtype, want_min, want_max,
-    want_sumsq,
+    want_sumsq, where_args=(),
 ):
     """Fused-kernel twin of _agg_scan_prepared: the hot set holds only
     the RAW value columns — validity masks, the [vals|valid|rows]
@@ -477,10 +500,9 @@ def _agg_scan_fused(
         mask = jnp.arange(nrows) < n_valids[i]
         if dedup_masks is not None:
             mask = mask & dedup_masks[i]
-        if where is not None:
-            w = eval_device(where, cols, tag_names, schema)
-            mask = mask & (w if w.dtype == jnp.bool_ else w != 0)
-        gid = _group_ids(cols, keys, nrows)
+        mask = _where_mask(mask, where, where_args, cols, tag_names,
+                           schema)
+        gid = _group_ids(cols, keys, nrows, where_args)
         ids = jnp.where(mask, gid, jnp.int32(G))
         vals = jnp.stack([cols[a].astype(acc_dtype) for a in arg_names],
                          axis=1)
@@ -527,7 +549,7 @@ def _agg_scan(
     dedup_masks,  # Optional[tuple of per-block masks]
     *,
     where, keys, agg_args, ops, num_segments, ts_name, tag_names, schema,
-    need_ts, acc_dtype, float_ops, int_ops, pack_dtype,
+    need_ts, acc_dtype, float_ops, int_ops, pack_dtype, where_args=(),
 ):
     """The WHOLE aggregation as one device program: per-block fused
     filter+group+reduce, on-device partial combine, and a packed result —
@@ -537,7 +559,8 @@ def _agg_scan(
         partial = _agg_block(
             cols, n_valids[i],
             dedup_masks[i] if dedup_masks is not None else None,
-            where=where, keys=keys, agg_args=agg_args, ops=ops,
+            where=where, where_args=where_args, keys=keys,
+            agg_args=agg_args, ops=ops,
             num_segments=num_segments, ts_name=ts_name, tag_names=tag_names,
             schema=schema, need_ts=need_ts, acc_dtype=acc_dtype,
         )
@@ -568,7 +591,7 @@ def _agg_scan_sharded(
     base_mask: jax.Array,  # [N_pad] bool, sharded: padding & dedup survivors
     *,
     mesh, where, keys, agg_args, ops, num_segments, ts_name, tag_names,
-    schema, acc_dtype, float_ops, pack_dtype,
+    schema, acc_dtype, float_ops, pack_dtype, where_args=(),
 ):
     """Multi-device aggregation: each shard runs the same fused
     filter+group+reduce over its rows, partials combine with psum/pmin/pmax
@@ -582,17 +605,18 @@ def _agg_scan_sharded(
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    in_specs = ({k: P("shard") for k in cols}, P("shard"))
+    # the operands are replicated: every shard compares with the same
+    in_specs = ({k: P("shard") for k in cols}, P("shard"), P())
     need_ts = bool({"first", "last"} & set(ops))
 
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P(), check_vma=False)
-    def step(local_cols, local_mask):
+    def step(local_cols, local_mask, operands):
         from greptimedb_tpu.ops.segment import combine_partial_aggs
 
         part = _agg_block_masked(
-            local_cols, local_mask, where=where, keys=keys,
-            agg_args=agg_args, ops=ops, num_segments=num_segments,
+            local_cols, local_mask, where=where, where_args=operands,
+            keys=keys, agg_args=agg_args, ops=ops, num_segments=num_segments,
             ts_name=ts_name, tag_names=tag_names, schema=schema,
             need_ts=need_ts, acc_dtype=acc_dtype,
         )
@@ -602,7 +626,7 @@ def _agg_scan_sharded(
         return jnp.concatenate(
             [combined[k].astype(pack_dtype) for k in float_ops], axis=1)
 
-    return step(cols, base_mask)
+    return step(cols, base_mask, where_args)
 
 
 @functools.partial(
@@ -617,7 +641,7 @@ def _agg_scan_sharded_sparse(
     base_mask: jax.Array,  # [N_pad] bool, sharded
     *,
     mesh, where, keys, agg_args, ops, cap, ts_name, tag_names, schema,
-    need_ts, acc_dtype, float_ops, int_ops, pack_dtype,
+    need_ts, acc_dtype, float_ops, int_ops, pack_dtype, where_args=(),
 ):
     """Multi-device SPARSE aggregation: each shard sort-compacts the
     group ids IT observes and ships [cap, W] value-keyed partials plus
@@ -630,17 +654,15 @@ def _agg_scan_sharded_sparse(
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    in_specs = ({k: P("shard") for k in cols}, P("shard"))
+    in_specs = ({k: P("shard") for k in cols}, P("shard"), P())
     out_specs = (P("shard"), P("shard"), P("shard"), P("shard"))
 
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    def step(local_cols, local_mask):
-        mask = local_mask
-        if where is not None:
-            w = eval_device(where, local_cols, tag_names, schema)
-            mask = mask & (w if w.dtype == jnp.bool_ else w != 0)
-        gid = _sparse_gid(local_cols, keys)
+    def step(local_cols, local_mask, operands):
+        mask = _where_mask(local_mask, where, operands, local_cols,
+                           tag_names, schema)
+        gid = _sparse_gid(local_cols, keys, operands)
         if agg_args:
             values = _value_planes(agg_args, local_cols, tag_names, schema,
                                    mask.shape, acc_dtype)
@@ -653,7 +675,7 @@ def _agg_scan_sharded_sparse(
         return (packed_f, packed_i, uniq,
                 n_groups.astype(jnp.int64)[None])
 
-    return step(cols, base_mask)
+    return step(cols, base_mask, where_args)
 
 
 def _build_prep(scan, arg_names, start, end, out_rows, acc_dtype, has_nan,
@@ -732,7 +754,7 @@ def _agg_scan_sharded_prepared(
     base_mask: jax.Array,
     *,
     mesh, where, keys, nf, has_nan, num_segments, tag_names, schema,
-    float_ops, pack_dtype,
+    float_ops, pack_dtype, where_args=(),
 ):
     """Sharded twin of _agg_scan_prepared: each shard reduces its slice of
     the cached planes with the dead-segment id trick, then partials ride
@@ -742,17 +764,15 @@ def _agg_scan_sharded_prepared(
     from jax.sharding import PartitionSpec as P
 
     G = num_segments
-    in_specs = ({k: P("shard") for k in cols}, P("shard"))
+    in_specs = ({k: P("shard") for k in cols}, P("shard"), P())
 
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P(), check_vma=False)
-    def step(local_cols, local_mask):
+    def step(local_cols, local_mask, operands):
         plane = local_cols["__prep__"]
-        mask = local_mask
-        if where is not None:
-            w = eval_device(where, local_cols, tag_names, schema)
-            mask = mask & (w if w.dtype == jnp.bool_ else w != 0)
-        gid = _group_ids(local_cols, keys, plane.shape[0])
+        mask = _where_mask(local_mask, where, operands, local_cols,
+                           tag_names, schema)
+        gid = _group_ids(local_cols, keys, plane.shape[0], operands)
         ids = jnp.where(mask, gid, jnp.int32(G))
         total = jax.lax.psum(
             jax.ops.segment_sum(plane, ids, num_segments=G + 1)[:G],
@@ -794,11 +814,11 @@ def _agg_scan_sharded_prepared(
         return jnp.concatenate(
             [acc[k].astype(pack_dtype) for k in float_ops], axis=1)
 
-    return step(cols, base_mask)
+    return step(cols, base_mask, where_args)
 
 
 def _prep_stream_step_impl(acc, cols, n_valid, *, where, keys, num_segments,
-                           tag_names, schema):
+                           tag_names, schema, where_args=()):
     """One streaming step on the PREPARED planes: a single dead-segment
     segment-sum per chunk folded into the device accumulator — the
     streaming twin of _agg_scan_prepared (none of the [N, F] masking
@@ -806,10 +826,8 @@ def _prep_stream_step_impl(acc, cols, n_valid, *, where, keys, num_segments,
     G = num_segments
     plane = cols["__prep__"]
     mask = jnp.arange(plane.shape[0]) < n_valid
-    if where is not None:
-        w = eval_device(where, cols, tag_names, schema)
-        mask = mask & (w if w.dtype == jnp.bool_ else w != 0)
-    gid = _group_ids(cols, keys, plane.shape[0])
+    mask = _where_mask(mask, where, where_args, cols, tag_names, schema)
+    gid = _group_ids(cols, keys, plane.shape[0], where_args)
     ids = jnp.where(mask, gid, jnp.int32(G))
     out = {"total": jax.ops.segment_sum(plane, ids, num_segments=G + 1)[:G]}
     if "__prep_min__" in cols:
@@ -962,7 +980,7 @@ def _agg_block_sparse(
     dedup_mask,
     *,
     where, keys, agg_args, ops, cap, ts_name, tag_names, schema, need_ts,
-    acc_dtype,
+    acc_dtype, where_args=(),
 ):
     """Sparse twin of _agg_block for the incremental per-part fold:
     sort-compact the part's observed group ids and segment-reduce over
@@ -974,10 +992,8 @@ def _agg_block_sparse(
     mask = jnp.arange(some.shape[0]) < n_valid
     if dedup_mask is not None:
         mask = mask & dedup_mask
-    if where is not None:
-        w = eval_device(where, cols, tag_names, schema)
-        mask = mask & (w if w.dtype == jnp.bool_ else w != 0)
-    gid = _sparse_gid(cols, keys)
+    mask = _where_mask(mask, where, where_args, cols, tag_names, schema)
+    gid = _sparse_gid(cols, keys, where_args)
     if agg_args:
         values = _value_planes(agg_args, cols, tag_names, schema,
                                mask.shape, acc_dtype)
@@ -990,11 +1006,12 @@ def _agg_block_sparse(
 
 def _agg_step_impl(acc, cols, n_valid, *, where, keys, agg_args, ops,
                    num_segments, ts_name, tag_names, schema, need_ts,
-                   acc_dtype):
+                   acc_dtype, where_args=()):
     """One streaming step: fold a chunk's partial aggregate into the
     device-resident accumulator (constant HBM; one dispatch per chunk)."""
-    part = _agg_block(cols, n_valid, None, where=where, keys=keys,
-                      agg_args=agg_args, ops=ops, num_segments=num_segments,
+    part = _agg_block(cols, n_valid, None, where=where,
+                      where_args=where_args, keys=keys, agg_args=agg_args,
+                      ops=ops, num_segments=num_segments,
                       ts_name=ts_name, tag_names=tag_names, schema=schema,
                       need_ts=need_ts, acc_dtype=acc_dtype)
     return _combine_partials(acc, part)
@@ -1015,17 +1032,18 @@ _agg_step_donated = functools.partial(
 _GID_SENTINEL = sparse_ops.GID_SENTINEL  # > any real combined group id
 
 
-def _sparse_gid(cols: dict, keys) -> jax.Array:
+def _sparse_gid(cols: dict, keys, where_args=()) -> jax.Array:
     """Combined int64 group id per row — shard-invariant (tag dictionary
     codes and bucket bases don't depend on which rows a shard holds), so
     gids computed per shard / per part merge globally."""
     key_arrays, sizes = [], []
+    bases = _bucket_bases(keys, where_args)
     for k in keys:
         c = cols[k.column]
         if k.kind == "tag":
             arr = (c + 1).astype(jnp.int64)
         elif k.kind == "bucket":
-            arr = (c // k.step - k.base).astype(jnp.int64)
+            arr = (c // k.step - next(bases)).astype(jnp.int64)
         else:
             arr = c.astype(jnp.int64)
         key_arrays.append(jnp.clip(arr, 0, k.size - 1))
@@ -1062,7 +1080,7 @@ def _agg_scan_sparse(
     base_mask: jax.Array,  # [N] bool: padding & dedup survivors
     *,
     where, keys, agg_args, ops, cap, ts_name, tag_names, schema, need_ts,
-    acc_dtype, float_ops, int_ops, pack_dtype,
+    acc_dtype, float_ops, int_ops, pack_dtype, where_args=(),
 ):
     """Sparse (high-cardinality) aggregation: when the dense key product
     won't fit as [G, F] planes, sort the observed int64 group ids, compact
@@ -1074,11 +1092,9 @@ def _agg_scan_sparse(
     The sort-compact core lives in ops/sparse_segment.py, shared with the
     fused/sharded/incremental/vmapped sparse flavors.
     """
-    mask = base_mask
-    if where is not None:
-        w = eval_device(where, cols, tag_names, schema)
-        mask = mask & (w if w.dtype == jnp.bool_ else w != 0)
-    gid = _sparse_gid(cols, keys)
+    mask = _where_mask(base_mask, where, where_args, cols, tag_names,
+                       schema)
+    gid = _sparse_gid(cols, keys, where_args)
     if agg_args:
         values = _value_planes(agg_args, cols, tag_names, schema,
                                mask.shape, acc_dtype)
@@ -1103,7 +1119,7 @@ def _agg_scan_sparse_fused(
     base_mask: jax.Array,
     *,
     where, keys, arg_names, ops, cap, tag_names, schema, acc_dtype,
-    float_ops, pack_dtype,
+    float_ops, pack_dtype, where_args=(),
 ):
     """Sparse aggregation with the reductions on the fused Pallas kernel:
     sort-compact once, then tile the compacted segment axis in FUSED_TILE
@@ -1112,11 +1128,9 @@ def _agg_scan_sparse_fused(
     and tag products far past it stay fused instead of falling back to
     the XLA scatter chain. Eligibility (plain finite field columns, op
     subset, mode gates) is the caller's job, mirroring _fused_ok."""
-    mask = base_mask
-    if where is not None:
-        w = eval_device(where, cols, tag_names, schema)
-        mask = mask & (w if w.dtype == jnp.bool_ else w != 0)
-    gid = _sparse_gid(cols, keys)
+    mask = _where_mask(base_mask, where, where_args, cols, tag_names,
+                       schema)
+    gid = _sparse_gid(cols, keys, where_args)
     order, ids, valid_s, uniq, n_groups = sparse_ops.sort_compact(
         gid, mask, cap)
     vals = jnp.stack([cols[a].astype(acc_dtype) for a in arg_names],
@@ -1134,15 +1148,12 @@ def _agg_scan_sparse_fused(
 @functools.partial(jax.jit, static_argnames=("where", "tag_names", "schema"))
 @device_telemetry.kernel_name("filter_block")
 def _filter_block(cols: dict, n_valid: jax.Array, dedup_mask, *, where,
-                  tag_names, schema):
+                  tag_names, schema, where_args=()):
     some = next(iter(cols.values()))
     mask = jnp.arange(some.shape[0]) < n_valid
     if dedup_mask is not None:
         mask = mask & dedup_mask
-    if where is not None:
-        w = eval_device(where, cols, tag_names, schema)
-        mask = mask & (w if w.dtype == jnp.bool_ else w != 0)
-    return mask
+    return _where_mask(mask, where, where_args, cols, tag_names, schema)
 
 
 @jax.jit
@@ -1180,6 +1191,59 @@ def _combine_partials(acc: Optional[dict], p: dict) -> dict:
         out["first"] = jnp.where(older[:, None], p["first"], acc["first"])
         out["first_ts"] = jnp.where(older, p["first_ts"], acc["first_ts"])
     return out
+
+
+# ---- dispatch: a predicate's literals are operands -------------------------
+
+#: every program dispatched so far (`_aggregate`'s signature of it, its
+#: backend included): what agg_program_events_total calls `reuse`
+_PROGRAMS_SEEN: set = set()
+_PROGRAMS_LOCK = threading.Lock()
+
+
+def _operands(bound_where, keys, schema) -> tuple:
+    """(shape, operands, static_literal) for one dispatch: the bound
+    predicate split (query/expr.py `split_operands`) and, after its
+    operands, the base of every time-bucket key in key order — a base is
+    floor(lo / step) of the statement's own bounds, a literal under
+    another name (`_bucket_bases` reads them back)."""
+    shape, operands, static_literal = split_operands(bound_where, schema)
+    bases = tuple(np.int64(k.base) for k in keys or ()
+                  if k.kind == "bucket")
+    return shape, operands + bases, static_literal
+
+
+def _aggregate(step, *args, where, schema, keys=None, **statics):
+    """Dispatch one jitted aggregate or filter step with the bound
+    predicate `where`: the step gets its shape as the static `where` and
+    the literals as the traced `where_args`, so requests that differ in
+    their literals or their window's start run one executable, on either
+    backend. Counts the dispatch on agg_program_events_total and names
+    it on the open stage's span (`program=`): `reuse` when this program
+    — step, static arguments, argument shapes and dtypes, backend — was
+    dispatched before in this process, `new` when not, `static_literal`
+    when the shape still holds a literal."""
+    from greptimedb_tpu.utils.metrics import AGG_PROGRAM_EVENTS
+
+    shape, operands, static_literal = _operands(where, keys, schema)
+    if keys is not None:
+        statics["keys"] = keys
+    if static_literal:
+        event = "static_literal"
+    else:
+        leaves, treedef = jax.tree_util.tree_flatten(args)
+        sig = (step, shape, schema, frozenset(statics.items()), treedef,
+               tuple((np.shape(x), getattr(x, "dtype", None))
+                     for x in leaves),
+               tuple(o.dtype for o in operands),
+               jax.config.jax_default_device)
+        with _PROGRAMS_LOCK:
+            event = "reuse" if sig in _PROGRAMS_SEEN else "new"
+            _PROGRAMS_SEEN.add(sig)
+    AGG_PROGRAM_EVENTS.inc(event=event)
+    tracing.note_stage(program=event)
+    return step(*args, where=shape, where_args=operands, schema=schema,
+                **statics)
 
 
 # ---- execution tiers -------------------------------------------------------
@@ -1930,8 +1994,13 @@ class PhysicalExecutor:
         # first-touch hedge: serve THIS query host-side while the device
         # fold of its shape compiles on a background thread
         if self.router.hedges(tier):
-            wkey = whole_scan_key(scan, bound_where, keys_t, args_t, ops_t,
-                                  num_groups, sparse)
+            wkey = whole_scan_key(
+                schema, split_operands(bound_where, schema)[0], keys_t,
+                args_t, ops_t, num_groups, sparse,
+                (block_size_for(scan.num_rows),) if sparse
+                else tuple(e.block for e in _block_plan(scan)),
+                self._dedup_rows(scan, table),
+                self._value_flags(scan, args_t))
             if self.router.needed(wkey):
                 self.router.kick(
                     wkey, lambda: self._stream_agg(*stream_args),
@@ -2119,22 +2188,31 @@ class PhysicalExecutor:
         # post-reduction tier decision
         probed: list[tuple] = []
         delta_est = sum(e.end - e.start for e in mem_entries)
-        first_uncached = None
         for pk, (entry,) in parts.items():
             key = ("part", scan.region_id, pk[0], pk[1], pk[2], fp)
             p = cache.get(key)
             probed.append((key, entry, p))
             if p is None:
                 delta_est += entry.end - entry.start
-                if first_uncached is None:
-                    first_uncached = entry
         tier = self.tier_for(agg, delta_est)
         # first-touch hedge (the classic paths' 40s-cold-start fix must
         # not regress here): until this shape's per-part kernel has
         # compiled on the accelerator, folds serve host-side and a
-        # background thread warms the device
-        hedge = delta_est > 0 and self.router.hedges(tier) \
-            and self.router.needed(incremental_key(fp))
+        # background thread warms the device. One key, and one warm-up
+        # fold, per block size this request dispatches: a part's program
+        # knows its own block, not the request's other parts
+        cold: dict[tuple, _BlockEntry] = {}
+        if delta_est > 0 and self.router.hedges(tier):
+            shape = split_operands(bound_where, schema)[0]
+            for e in [entry for _k, entry, p in probed if p is None] \
+                    + mem_entries:
+                hkey = incremental_key(
+                    schema, shape, tuple(keys), tuple(arg_exprs), ops_t,
+                    acc_dtype, num_groups, use_sparse, e.block,
+                    self._dedup_rows(scan, table))
+                if hkey not in cold and self.router.needed(hkey):
+                    cold[hkey] = e
+        hedge = bool(cold)
         if hedge:
             tier = "host"
         self.last_tier = tier
@@ -2170,9 +2248,9 @@ class PhysicalExecutor:
             with _kstage("upload"):
                 cols = fetch_cols(entry)
             with _kstage("device"):
-                out = _agg_block_jit(cols,
-                                     jnp.asarray(entry.end - entry.start),
-                                     entry_dmask(entry), **kw)
+                out = _aggregate(_agg_block_jit, cols,
+                                 jnp.asarray(entry.end - entry.start),
+                                 entry_dmask(entry), **kw)
             with _kstage("readback"):
                 planes = {op: _readback(v) for op, v in out.items()}
             rows = planes["rows"]
@@ -2201,8 +2279,9 @@ class PhysicalExecutor:
             with _kstage("upload"):
                 cols = fetch_cols(entry)
             with _kstage("device"):
-                out, uniq, n_groups = _agg_block_sparse(
-                    cols, jnp.asarray(entry.end - entry.start),
+                out, uniq, n_groups = _aggregate(
+                    _agg_block_sparse, cols,
+                    jnp.asarray(entry.end - entry.start),
                     entry_dmask(entry), cap=cap, **sparse_kw)
             with _kstage("readback"):
                 u = int(n_groups)
@@ -2224,12 +2303,10 @@ class PhysicalExecutor:
         compute_partial = compute_partial_sparse if use_sparse \
             else compute_partial_dense
 
-        if hedge:
-            # ONE part's fold is enough to compile the per-part kernel
-            warm_entry = first_uncached if first_uncached is not None \
-                else mem_entries[0]
+        for hkey, entry in cold.items():
+            # ONE part's fold compiles the per-part kernel of its block
             self.router.kick(
-                incremental_key(fp), lambda: compute_partial(warm_entry),
+                hkey, functools.partial(compute_partial, entry),
                 "device warm-up of the incremental per-part kernel "
                 "failed; the shape's delta folds stay on the host tier")
 
@@ -2554,9 +2631,11 @@ class PhysicalExecutor:
                 device_telemetry.count_h2d(
                     sum(a.nbytes for a in dev.values()))
                 if acc_dev is None:
-                    acc_dev = _agg_block_jit(dev, n_valid, None, **kw)
+                    acc_dev = _aggregate(_agg_block_jit, dev, n_valid,
+                                         None, **kw)
                 else:
-                    acc_dev = step(acc_dev, dev, n_valid, **kw)
+                    acc_dev = _aggregate(step, acc_dev, dev, n_valid,
+                                         **kw)
         finally:
             # stop the producer BEFORE the caller's stream.close() drops
             # SST pins: a generator left suspended would only clean up at
@@ -2642,7 +2721,7 @@ class PhysicalExecutor:
             for dev, n_valid in gen:
                 device_telemetry.count_h2d(
                     sum(a.nbytes for a in dev.values()))
-                acc_dev = step(acc_dev, dev, n_valid, **kw)
+                acc_dev = _aggregate(step, acc_dev, dev, n_valid, **kw)
         finally:
             gen.close()  # see _fold_stream: producer must die before unpin
         G = num_groups
@@ -3021,8 +3100,9 @@ class PhysicalExecutor:
             finite = not self._scan_has_inf(scan, arg_names,
                                             dtype=prep_dtype)
             with _kstage("device", kernel="agg_scan_prepared"):
-                packed_f, packed_i = _agg_scan_prepared(
-                    tuple(blocks), jnp.asarray(np.asarray(n_valids)),
+                packed_f, packed_i = _aggregate(
+                    _agg_scan_prepared, tuple(blocks),
+                    jnp.asarray(np.asarray(n_valids)),
                     tuple(dmasks) if dmasks is not None else None,
                     where=bound_where, keys=keys, nf=nf, has_nan=has_nan,
                     finite=finite, num_segments=num_groups,
@@ -3048,8 +3128,9 @@ class PhysicalExecutor:
             blocks, n_valids, dmasks = self._gather_blocks(
                 scan, plan, fetch_block, dedup_mask)
             with _kstage("device", kernel="agg_scan"):
-                packed_f, packed_i = _agg_scan(
-                    tuple(blocks), jnp.asarray(np.asarray(n_valids)),
+                packed_f, packed_i = _aggregate(
+                    _agg_scan, tuple(blocks),
+                    jnp.asarray(np.asarray(n_valids)),
                     tuple(dmasks) if dmasks is not None else None,
                     where=bound_where, keys=keys, agg_args=arg_exprs,
                     ops=ops, num_segments=num_groups, ts_name=ts_name,
@@ -3110,8 +3191,9 @@ class PhysicalExecutor:
                 from greptimedb_tpu.utils.metrics import PALLAS_DISPATCHES
 
                 try:
-                    packed_f, packed_i, uniq, n_groups = _agg_scan_sparse_fused(
-                        cols, jnp.asarray(base), where=bound_where, keys=keys,
+                    packed_f, packed_i, uniq, n_groups = _aggregate(
+                        _agg_scan_sparse_fused, cols, jnp.asarray(base),
+                        where=bound_where, keys=keys,
                         arg_names=tuple(a.name for a in arg_exprs), ops=ops,
                         cap=cap, tag_names=tag_names, schema=schema,
                         acc_dtype=acc_dtype, float_ops=float_ops,
@@ -3130,8 +3212,9 @@ class PhysicalExecutor:
                     _FUSED_DISABLED["flag"] = True
                     PALLAS_DISPATCHES.inc(kernel="fused_agg_failed")
             if packed is None:
-                packed = _agg_scan_sparse(
-                    cols, jnp.asarray(base), where=bound_where, keys=keys,
+                packed = _aggregate(
+                    _agg_scan_sparse, cols, jnp.asarray(base),
+                    where=bound_where, keys=keys,
                     agg_args=arg_exprs, ops=ops, cap=cap, ts_name=ts_name,
                     tag_names=tag_names, schema=schema,
                     need_ts=bool({"first", "last"} & set(ops)),
@@ -3236,8 +3319,9 @@ class PhysicalExecutor:
         shard_rows = base_s.shape[0] // n_shard
         cap = min(shard_rows, config.sparse_groups_max())
         sd.note_dispatch("sharded_sparse", plan)
-        packed_f, packed_i, uniqs, ns = _agg_scan_sharded_sparse(
-            cols, base_s, mesh=mesh, where=bound_where, keys=keys,
+        packed_f, packed_i, uniqs, ns = _aggregate(
+            _agg_scan_sharded_sparse, cols, base_s, mesh=mesh,
+            where=bound_where, keys=keys,
             agg_args=arg_exprs, ops=ops, cap=cap, ts_name=ts_name,
             tag_names=tag_names, schema=schema,
             need_ts=bool({"first", "last"} & set(ops)),
@@ -3369,14 +3453,16 @@ class PhysicalExecutor:
                     tier=tier, snap_version=snap_v,
                     extra=(str(pdt), has_nan), pad_fill=fill)
             sd.note_dispatch("sharded_prepared", plan)
-            return _agg_scan_sharded_prepared(
-                cols, base_s, mesh=mesh, where=bound_where, keys=keys,
+            return _aggregate(
+                _agg_scan_sharded_prepared, cols, base_s, mesh=mesh,
+                where=bound_where, keys=keys,
                 nf=nf, has_nan=has_nan, num_segments=num_groups,
                 tag_names=tag_names, schema=schema, float_ops=float_ops,
                 pack_dtype=pack_dtype)
         sd.note_dispatch("sharded", plan)
-        return _agg_scan_sharded(
-            cols, base_s, mesh=mesh, where=bound_where, keys=keys,
+        return _aggregate(
+            _agg_scan_sharded, cols, base_s, mesh=mesh,
+            where=bound_where, keys=keys,
             agg_args=arg_exprs, ops=ops, num_segments=num_groups,
             ts_name=ts_name, tag_names=tag_names, schema=schema,
             acc_dtype=acc_dtype, float_ops=float_ops, pack_dtype=pack_dtype)
@@ -3454,13 +3540,15 @@ class PhysicalExecutor:
                            plane_name, arg_names, "sharded", n_pad,
                            n_shard, str(pdt), has_nan)
                     cols[plane_name] = self.cache.get(key, build_plane)
-            return _agg_scan_sharded_prepared(
-                cols, base_s, mesh=mesh, where=bound_where, keys=keys,
+            return _aggregate(
+                _agg_scan_sharded_prepared, cols, base_s, mesh=mesh,
+                where=bound_where, keys=keys,
                 nf=nf, has_nan=has_nan, num_segments=num_groups,
                 tag_names=tag_names, schema=schema, float_ops=float_ops,
                 pack_dtype=pack_dtype)
-        return _agg_scan_sharded(
-            cols, base_s, mesh=mesh, where=bound_where, keys=keys,
+        return _aggregate(
+            _agg_scan_sharded, cols, base_s, mesh=mesh,
+            where=bound_where, keys=keys,
             agg_args=arg_exprs, ops=ops, num_segments=num_groups,
             ts_name=ts_name, tag_names=tag_names, schema=schema,
             acc_dtype=acc_dtype, float_ops=float_ops, pack_dtype=pack_dtype)
@@ -3573,8 +3661,9 @@ class PhysicalExecutor:
             scan, plan, fetch_block, dedup_mask)
         try:
             with _kstage("device", kernel="agg_scan_fused"):
-                packed_f, packed_i = _agg_scan_fused(
-                    tuple(blocks), jnp.asarray(np.asarray(n_valids)),
+                packed_f, packed_i = _aggregate(
+                    _agg_scan_fused, tuple(blocks),
+                    jnp.asarray(np.asarray(n_valids)),
                     tuple(dmasks) if dmasks is not None else None,
                     where=bound_where, keys=keys, arg_names=arg_names,
                     num_segments=num_groups, ts_name=ts_name,
@@ -3771,11 +3860,36 @@ class PhysicalExecutor:
             raise PlanError(f"columns missing from scan: {sorted(missing)}")
         return sorted(needed)
 
+    @staticmethod
+    def _dedups(scan, table) -> bool:
+        """Whether this scan's kernels take a last-write-wins mask."""
+        return not table.append_mode and bool(scan.needs_dedup)
+
+    def _dedup_rows(self, scan, table) -> int:
+        """What of the last-write-wins mask a hedge key has to hold: the
+        mask is built by programs over the scan's unpadded rows, so they
+        are compiled per row count; 0 where no mask is passed."""
+        return scan.num_rows if self._dedups(scan, table) else 0
+
+    def _value_flags(self, scan, arg_exprs) -> tuple:
+        """The value columns' (has NULL, has Inf) flags where the
+        aggregate's arguments are plain columns: static inputs of the
+        prepared and fused kernels' choice, memoized on the snapshot."""
+        from greptimedb_tpu import config
+
+        names = tuple(a.name for a in arg_exprs if isinstance(a, ast.Column))
+        if len(names) != len(arg_exprs) or not scan.materialized \
+                or any(n not in scan.columns for n in names):
+            return ()
+        return (self._scan_has_nan(scan, names),
+                self._scan_has_inf(
+                    scan, names, dtype=jnp.dtype(config.compute_dtype())))
+
     def _maybe_dedup(self, scan: ScanData, table, ctx) -> Optional[jax.Array]:
         """Device-resident last-write-wins mask (stays on device; sliced
         per block without a host round-trip). Memoized per ScanData so a
         query mixing device and host aggregates computes it once."""
-        if table.append_mode or not scan.needs_dedup:
+        if not self._dedups(scan, table):
             return None
         cached = getattr(scan, "_dedup_mask_cache", None)
         if cached is not None:
@@ -3846,9 +3960,10 @@ class PhysicalExecutor:
             dmask = None
             if dedup_mask is not None:
                 dmask = _pad_device_mask(dedup_mask, start, end, block)
-            mask = _filter_block(cols, jnp.asarray(end - start), dmask,
-                                 where=bound_where,
-                                 tag_names=tag_names, schema=schema)
+            mask = _aggregate(_filter_block, cols,
+                              jnp.asarray(end - start), dmask,
+                              where=bound_where, tag_names=tag_names,
+                              schema=schema)
             picked.append(np.flatnonzero(np.asarray(mask)) + start)
         return np.concatenate(picked) if picked else np.empty(0, dtype=np.int64)
 

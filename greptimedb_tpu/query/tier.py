@@ -155,22 +155,38 @@ def part_placement(mesh, tier: str, scan) -> Callable:
     return lambda fid: TierCtx(tier)
 
 
-def whole_scan_key(scan, where, keys, agg_args, ops, num_groups,
-                   sparse) -> tuple:
-    """Hedge key of a classic whole-scan aggregate. repr() folds the
-    full query shape in: WHERE expression, group keys, and arg
-    expressions each change the compiled HLO — a key missing them would
-    declare a DIFFERENT program warm and block the foreground on its
-    cold compile."""
-    return (scan.region_id, scan.data_version, scan.scan_fingerprint,
-            repr(where), repr(keys), repr(agg_args), ops, num_groups,
-            sparse)
+def whole_scan_key(schema, shape, keys, agg_args, ops, num_groups, sparse,
+                   blocks: tuple, dedup_rows: int,
+                   value_flags: tuple) -> tuple:
+    """Hedge key of a classic whole-scan aggregate: every static input
+    of the programs the device would run, and nothing a request draws.
+    The predicate enters by its literal-free `shape` (query/expr.py
+    `split_operands`) and a time-bucket key without its base (both are
+    operands), so two requests that differ in hosts or in their window's
+    start share the key as they share the executable. In place of the
+    region, its data version and the scan's fingerprint stand what those
+    stood for in the programs: the table's schema, the block layout of
+    the scan, the row count the last-write-wins mask is built over (0
+    where none rides along: its programs take the scan's unpadded rows),
+    and the value columns' NULL / Inf flags the kernel choice reads. A
+    key missing one of them would declare a DIFFERENT program warm and
+    block the foreground on its cold compile."""
+    return (schema, repr(shape), repr(keys), repr(agg_args), ops,
+            num_groups, sparse, blocks, dedup_rows, value_flags)
 
 
-def incremental_key(fingerprint: tuple) -> tuple:
-    """Hedge key of an incremental fold: the per-part kernel's shape
-    fingerprint, the one its cached partials are keyed by."""
-    return fingerprint
+def incremental_key(schema, shape, keys, agg_args, ops, acc_dtype,
+                    num_groups, sparse, block: int,
+                    dedup_rows: int) -> tuple:
+    """Hedge key of one part's incremental fold: the static inputs of
+    the per-part kernel — `whole_scan_key`'s, with the one block size
+    this part pads to (a request is warm once every block size it folds
+    is). NOT the partial cache's `shape_fingerprint`: a cached partial's
+    VALUES depend on the literals, so that one keeps
+    `repr(bound_where)`; a compiled program does not, so this one holds
+    the shape."""
+    return (schema, repr(shape), repr(keys), repr(agg_args), ops,
+            str(acc_dtype), num_groups, sparse, block, dedup_rows)
 
 
 class TierRouter:

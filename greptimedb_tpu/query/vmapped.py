@@ -28,10 +28,11 @@ to the stacked/serial paths) when they don't hold:
   at the same part seams — inserting identity elements into a left fold
   preserves every partial sum exactly);
 - the member's whole predicate decomposes into shared conjuncts plus
-  `column <op> literal` parameter conjuncts the kernel can evaluate
-  from a stacked array (tag equality by dictionary code, time-index
-  comparisons in storage units — bound through the SAME `bind_expr`
-  the serial path uses, so literal coercion cannot drift).
+  `column <op> literal` parameter conjuncts whose literals the kernel
+  can take from a stacked array: bound through the SAME `bind_expr` and
+  split by the SAME `split_operands` (query/expr.py) the serial path
+  uses — every member's parameter conjuncts must split to one shape
+  with no literal left in it — so literal coercion cannot drift.
 
 Window-union batching falls out for free: members with different time
 windows share the one full scan and differ only in their ts-comparison
@@ -54,9 +55,10 @@ from greptimedb_tpu.query.tier import ACTIVE_TIER, TierCtx
 from greptimedb_tpu.query.expr import (
     BindContext,
     bind_expr,
-    eval_device,
+    collect_columns,
     extract_ts_bounds,
     split_conjuncts,
+    split_operands,
 )
 from greptimedb_tpu.ops.segment import segment_agg
 from greptimedb_tpu.sql import ast
@@ -89,33 +91,45 @@ def _rebuild_conjunction(conjuncts: list) -> Optional[ast.Expr]:
     return e
 
 
-def _member_mask(cols, base_mask, shared_where, param_specs, pvals,
-                 tag_names, schema):
-    """One member's row mask: shared conjuncts plus its stacked
-    parameter comparisons (shared by the single-region and region-
+def _member_mask(cols, base_mask, shared_where, shared_args, member_where,
+                 pvals, tag_names, schema):
+    """One member's row mask: the shared conjuncts with their operands,
+    then the parameter conjuncts' one shape with this member's slice of
+    the stacked operands (shared by the single-region and region-
     partial kernels)."""
-    mask = base_mask
-    if shared_where is not None:
-        w = eval_device(shared_where, cols, tag_names, schema)
-        mask = mask & (w if w.dtype == jnp.bool_ else w != 0)
-    for (name, op), pv in zip(param_specs, pvals):
-        c = cols[name]
-        if op == "=":
-            mask = mask & (c == pv)
-        elif op == "<":
-            mask = mask & (c < pv)
-        elif op == "<=":
-            mask = mask & (c <= pv)
-        elif op == ">":
-            mask = mask & (c > pv)
-        else:  # ">="
-            mask = mask & (c >= pv)
-    return mask
+    mask = ph._where_mask(base_mask, shared_where, shared_args, cols,
+                          tag_names, schema)
+    return ph._where_mask(mask, member_where, pvals, cols, tag_names, schema)
+
+
+def _member_operands(specs, member_values, bctx, width: int) -> tuple:
+    """(shape, stacked operands) of the members' parameter conjuncts
+    `col <op> value`, `specs` giving (col, op) per conjunct: each
+    member's conjunction is bound and split as a serial WHERE would be,
+    all must come to ONE literal-free shape, and operand k of every
+    member stacks into one [width, ...] array (the last member repeated
+    up to the padded batch width)."""
+    shape, rows = None, []
+    for values in member_values:
+        conj = _rebuild_conjunction([
+            ast.BinaryOp(op, ast.Column(col), ast.Literal(v))
+            for (col, op), v in zip(specs, values)])
+        s, operands, static_literal = split_operands(
+            bind_expr(conj, bctx), bctx.schema)
+        if static_literal:
+            raise VmapIneligible(f"unbindable parameter in {specs}")
+        if rows and (s != shape or [o.dtype for o in operands]
+                     != [o.dtype for o in rows[0]]):
+            raise VmapIneligible("parameter spec drift across members")
+        shape = s
+        rows.append(operands)
+    rows += [rows[-1]] * (width - len(rows))
+    return shape, tuple(jnp.asarray(np.stack(col)) for col in zip(*rows))
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("shared_where", "param_specs", "keys", "agg_args",
+    static_argnames=("shared_where", "member_where", "keys", "agg_args",
                      "ops", "num_segments", "ts_name", "need_ts",
                      "tag_names", "schema", "acc_dtype", "float_ops",
                      "pack_dtype"),
@@ -125,9 +139,10 @@ def _vmapped_agg_scan(
     blocks: tuple,  # per-block col dicts (member-invariant)
     n_valids: jax.Array,
     dedup_masks,
-    params: tuple,  # per-spec [M] stacked parameter arrays
+    params: tuple,  # the member shape's operands, stacked [M, ...]
+    shared_args: tuple,  # the shared shape's operands, then bucket bases
     *,
-    shared_where, param_specs, keys, agg_args, ops, num_segments,
+    shared_where, member_where, keys, agg_args, ops, num_segments,
     ts_name, need_ts, tag_names, schema, acc_dtype, float_ops,
     pack_dtype,
 ):
@@ -146,9 +161,9 @@ def _vmapped_agg_scan(
             mask = jnp.arange(some.shape[0]) < n_valids[i]
             if dedup_masks is not None:
                 mask = mask & dedup_masks[i]
-            mask = _member_mask(cols, mask, shared_where, param_specs,
-                                pvals, tag_names, schema)
-            gid = ph._group_ids(cols, keys, mask.shape[0])
+            mask = _member_mask(cols, mask, shared_where, shared_args,
+                                member_where, pvals, tag_names, schema)
+            gid = ph._group_ids(cols, keys, mask.shape[0], shared_args)
             if agg_args:
                 values = ph._value_planes(agg_args, cols, tag_names,
                                           schema, mask.shape, acc_dtype)
@@ -170,7 +185,7 @@ def _vmapped_agg_scan(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("shared_where", "param_specs", "keys", "agg_args",
+    static_argnames=("shared_where", "member_where", "keys", "agg_args",
                      "ops", "cap", "ts_name", "need_ts", "tag_names",
                      "schema", "acc_dtype", "float_ops", "pack_dtype"),
 )
@@ -178,9 +193,10 @@ def _vmapped_agg_scan(
 def _vmapped_sparse_agg_scan(
     cols: dict,  # whole-scan padded col arrays (member-invariant)
     base_mask: jax.Array,  # [N] padding & dedup survivors
-    params: tuple,  # per-spec [M] stacked parameter arrays
+    params: tuple,  # the member shape's operands, stacked [M, ...]
+    shared_args: tuple,  # the shared shape's operands, then bucket bases
     *,
-    shared_where, param_specs, keys, agg_args, ops, cap, ts_name,
+    shared_where, member_where, keys, agg_args, ops, cap, ts_name,
     need_ts, tag_names, schema, acc_dtype, float_ops, pack_dtype,
 ):
     """Sparse (sort-compact) twin of _vmapped_agg_scan: ONE shared
@@ -196,11 +212,9 @@ def _vmapped_sparse_agg_scan(
     identities."""
     from greptimedb_tpu.ops import sparse_segment as sparse_ops
 
-    mask0 = base_mask
-    if shared_where is not None:
-        w = eval_device(shared_where, cols, tag_names, schema)
-        mask0 = mask0 & (w if w.dtype == jnp.bool_ else w != 0)
-    gid = ph._sparse_gid(cols, keys)
+    mask0 = ph._where_mask(base_mask, shared_where, shared_args, cols,
+                           tag_names, schema)
+    gid = ph._sparse_gid(cols, keys, shared_args)
     order, ids, valid_s, uniq, n_groups = sparse_ops.sort_compact(
         gid, mask0, cap)
     if agg_args:
@@ -211,10 +225,10 @@ def _vmapped_sparse_agg_scan(
     values_s = values[order]
     ts_s = cols[ts_name][order] if need_ts else None
     param_cols_s = {name: cols[name][order]
-                    for name, _op in dict.fromkeys(param_specs)}
+                    for name in sorted(collect_columns(member_where, set()))}
 
     def member(pvals):
-        mask = _member_mask(param_cols_s, valid_s, None, param_specs,
+        mask = _member_mask(param_cols_s, valid_s, None, (), member_where,
                             pvals, tag_names, schema)
         part = segment_agg(values_s, ids, mask, cap, ops=ops, ts=ts_s,
                            indices_are_sorted=True)
@@ -227,23 +241,6 @@ def _vmapped_sparse_agg_scan(
         return jnp.concatenate(parts, axis=1)
 
     return jax.vmap(member)(params), uniq, n_groups
-
-
-def _bind_param(pspec, value, bctx) -> tuple:
-    """One member's value for one parameter conjunct, bound through the
-    engine's own literal coercion. Returns (device column name, op,
-    bound int). Tag equality binds to a dictionary code, time-index
-    comparisons coerce to storage units — identical to what the serial
-    path's bound WHERE would compare against."""
-    conj = ast.BinaryOp(pspec.op, ast.Column(pspec.col), ast.Literal(value))
-    bound = bind_expr(conj, bctx)
-    if not (isinstance(bound, ast.BinaryOp)
-            and isinstance(bound.left, ast.Column)
-            and isinstance(bound.right, ast.Literal)
-            and isinstance(bound.right.value, (int, np.integer))
-            and not isinstance(bound.right.value, bool)):
-        raise VmapIneligible(f"unbindable parameter {pspec.col} {pspec.op}")
-    return bound.left.name, bound.op, int(bound.right.value)
 
 
 def run_vmapped(executor, sel: ast.Select, info, pspecs,
@@ -338,17 +335,10 @@ def run_vmapped(executor, sel: ast.Select, info, pspecs,
     bound_shared = bind_expr(shared_where_ast, bctx) \
         if shared_where_ast is not None else None
 
-    # stacked parameter matrix: [n_specs][M] bound ints
-    cols_ops: list[tuple] = []
-    matrix: list[list[int]] = [[] for _ in pspecs]
-    for values in member_values:
-        for j, (p, v) in enumerate(zip(pspecs, values)):
-            name, op, bval = _bind_param(p, v, bctx)
-            if len(cols_ops) <= j:
-                cols_ops.append((name, op))
-            elif cols_ops[j] != (name, op):
-                raise VmapIneligible("parameter spec drift across members")
-            matrix[j].append(bval)
+    # the members' parameters: one shape, operands stacked [M, ...]
+    m = len(member_values)
+    member_where, params = _member_operands(
+        [(p.col, p.op) for p in pspecs], member_values, bctx, _pad_width(m))
 
     # group keys over the union scan; decode is value-based, so a base
     # shift against a member's narrower serial window is invisible
@@ -425,9 +415,10 @@ def run_vmapped(executor, sel: ast.Select, info, pspecs,
                     if c.dtype.is_float}
     device_col_names = executor._device_columns(
         scan, bound_shared, keys, tuple(arg_exprs), ts_name, extra_cols)
-    for name, _op in cols_ops:
+    for name in sorted(collect_columns(member_where, set())):
         if name not in device_col_names:
             device_col_names.append(name)
+    shared_where, shared_args, _ = ph._operands(bound_shared, keys, schema)
 
     tier = executor.tier_for(agg, scan.num_rows)
     executor.last_tier = tier
@@ -441,18 +432,11 @@ def run_vmapped(executor, sel: ast.Select, info, pspecs,
                 prefetch_only=prefetch_only)
         return out
 
-    m = len(member_values)
-    mp = _pad_width(m)
-    params = []
-    for j, (name, _op) in enumerate(cols_ops):
-        dt = np.int64 if name == ts_name else np.int32
-        vals = matrix[j] + [matrix[j][-1]] * (mp - m)
-        params.append(jnp.asarray(np.asarray(vals, dtype=dt)))
-
     if sparse:
         return _run_vmapped_sparse(
             executor, scan, agg, project, table, keys, decoders, spec_slot,
-            extra_cols, bound_shared, bctx, cols_ops, params, m,
+            extra_cols, bound_shared, bctx,
+            (shared_where, shared_args, member_where), params, m,
             device_col_names, float_fields, acc_dtype, dedup_mask,
             tag_names, schema, ts_name, need_ts, arg_exprs, ops, cap,
             float_ops, widths, pack_dtype, tier, num_groups)
@@ -463,8 +447,8 @@ def run_vmapped(executor, sel: ast.Select, info, pspecs,
         packed = _vmapped_agg_scan(
             tuple(blocks), jnp.asarray(np.asarray(n_valids)),
             tuple(dmasks) if dmasks is not None else None,
-            tuple(params),
-            shared_where=bound_shared, param_specs=tuple(cols_ops),
+            params, shared_args,
+            shared_where=shared_where, member_where=member_where,
             keys=tuple(keys), agg_args=tuple(arg_exprs),
             ops=tuple(sorted(ops)), num_segments=num_groups,
             ts_name=ts_name, need_ts=need_ts,
@@ -492,7 +476,7 @@ def run_vmapped(executor, sel: ast.Select, info, pspecs,
 
 
 def _run_vmapped_sparse(executor, scan, agg, project, table, keys, decoders,
-                        spec_slot, extra_cols, bound_shared, bctx, cols_ops,
+                        spec_slot, extra_cols, bound_shared, bctx, shapes,
                         params, m, device_col_names, float_fields, acc_dtype,
                         dedup_mask, tag_names, schema, ts_name, need_ts,
                         arg_exprs, ops, cap, float_ops, widths, pack_dtype,
@@ -534,10 +518,11 @@ def _run_vmapped_sparse(executor, scan, agg, project, table, keys, decoders,
     if dedup_mask is not None:
         base[:n] &= np.asarray(dedup_mask)[:n]
 
+    shared_where, shared_args, member_where = shapes
     with TierCtx(tier):
         packed, uniq, n_obs = _vmapped_sparse_agg_scan(
-            cols, jnp.asarray(base), tuple(params),
-            shared_where=bound_shared, param_specs=tuple(cols_ops),
+            cols, jnp.asarray(base), params, shared_args,
+            shared_where=shared_where, member_where=member_where,
             keys=tuple(keys), agg_args=tuple(arg_exprs),
             ops=tuple(sorted(ops)), cap=cap, ts_name=ts_name,
             need_ts=need_ts, tag_names=tag_names, schema=schema,
@@ -611,7 +596,7 @@ def _union_member_range(template_where, pspecs, member_values, ts_name,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("shared_where", "param_specs", "keys", "agg_args",
+    static_argnames=("shared_where", "member_where", "keys", "agg_args",
                      "ops", "num_segments", "ts_name", "need_ts",
                      "tag_names", "schema", "acc_dtype"),
 )
@@ -619,8 +604,9 @@ def _vmapped_partial_scan(
     cols: dict,  # whole-scan padded column arrays (member-invariant)
     base_mask: jax.Array,
     params: tuple,
+    shared_args: tuple,
     *,
-    shared_where, param_specs, keys, agg_args, ops, num_segments,
+    shared_where, member_where, keys, agg_args, ops, num_segments,
     ts_name, need_ts, tag_names, schema, acc_dtype,
 ):
     """Region-side member batch: ONE whole-scan segment reduction per
@@ -631,9 +617,9 @@ def _vmapped_partial_scan(
     interleaved — bit-for-bit the same per-group result."""
 
     def member(pvals):
-        mask = _member_mask(cols, base_mask, shared_where, param_specs,
-                            pvals, tag_names, schema)
-        gid = ph._group_ids(cols, keys, mask.shape[0])
+        mask = _member_mask(cols, base_mask, shared_where, shared_args,
+                            member_where, pvals, tag_names, schema)
+        gid = ph._group_ids(cols, keys, mask.shape[0], shared_args)
         if agg_args:
             values = ph._value_planes(agg_args, cols, tag_names, schema,
                                       mask.shape, acc_dtype)
@@ -674,7 +660,6 @@ def _region_partial_inner(executor, region_id, vm, schema, append_mode,
 
     from greptimedb_tpu import config
     from greptimedb_tpu.ops.blocks import block_size_for, pad_rows
-    from greptimedb_tpu.query.expr import collect_columns
 
     eng = executor.engine
     probe = eng.region(region_id)
@@ -712,17 +697,8 @@ def _region_partial_inner(executor, region_id, vm, schema, append_mode,
     # stacked parameters bound through the engine's own literal
     # coercion (identical to what each member's serial WHERE would
     # compare against on THIS region's dictionaries)
-    cols_ops: list[tuple] = []
-    matrix: list[list[int]] = [[] for _ in pspecs]
-    for vals in values:
-        for j, ((col, op), v) in enumerate(zip(pspecs, vals)):
-            name, bop, bval = _bind_param(
-                SimpleNamespace(col=col, op=op), v, bctx)
-            if len(cols_ops) <= j:
-                cols_ops.append((name, bop))
-            elif cols_ops[j] != (name, bop):
-                raise VmapIneligible("parameter spec drift across members")
-            matrix[j].append(bval)
+    mp = _pad_width(m)
+    member_where, params = _member_operands(pspecs, values, bctx, mp)
 
     shim_node = SimpleNamespace(ts_range=None, columns=proj)
     keys: list = []
@@ -739,7 +715,6 @@ def _region_partial_inner(executor, region_id, vm, schema, append_mode,
     if num_groups > config.dense_groups_max() \
             or num_groups >= ph._GID_SENTINEL:
         raise VmapIneligible(f"group domain {num_groups} needs sparse path")
-    mp = _pad_width(m)
     if keys and mp * num_groups > config.dense_groups_max():
         raise VmapIneligible("stacked accumulator exceeds dense budget")
 
@@ -757,7 +732,7 @@ def _region_partial_inner(executor, region_id, vm, schema, append_mode,
     names = executor._device_columns(scan, bound_shared, keys,
                                      tuple(bound_args), ts_name,
                                      extra_cols)
-    for pname, _op in cols_ops:
+    for pname in sorted(collect_columns(member_where, set())):
         if pname not in names:
             names.append(pname)
     n_pad = block_size_for(n)
@@ -775,15 +750,10 @@ def _region_partial_inner(executor, region_id, vm, schema, append_mode,
     if dedup_mask is not None:
         base = base & jnp.concatenate(
             [dedup_mask, jnp.zeros(n_pad - n, dtype=bool)])
-    params = []
-    for j, (pname, _op) in enumerate(cols_ops):
-        dt = np.int64 if pname == ts_name else np.int32
-        vals = matrix[j] + [matrix[j][-1]] * (mp - m)
-        params.append(jnp.asarray(np.asarray(vals, dtype=dt)))
-
+    shared_where, shared_args, _ = ph._operands(bound_shared, keys, schema)
     out = _vmapped_partial_scan(
-        dev_cols, base, tuple(params),
-        shared_where=bound_shared, param_specs=tuple(cols_ops),
+        dev_cols, base, params, shared_args,
+        shared_where=shared_where, member_where=member_where,
         keys=tuple(keys), agg_args=tuple(bound_args), ops=ops,
         num_segments=num_groups, ts_name=ts_name, need_ts=need_ts,
         tag_names=tag_names, schema=schema, acc_dtype=acc_dtype)

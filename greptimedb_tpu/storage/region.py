@@ -55,6 +55,9 @@ class _PartEntry:
 
     part: Optional[tuple]
     nbytes: int
+    #: rows of the file inside the scan's window, BEFORE the exact tag
+    #: filter cut them to the selected series: what the read decoded
+    rows_read: int = 0
 
 
 def _scan_nbytes(sd: "ScanData") -> int:
@@ -83,6 +86,18 @@ def _part_nbytes(part: Optional[tuple]) -> int:
     cols, seq, op = part
     return sum(int(a.nbytes) for a in cols.values()) \
         + int(seq.nbytes) + int(op.nbytes)
+
+
+def _concat_parts(chunks: list) -> Optional[tuple]:
+    """Decoded chunks of one file, in row order, as one part."""
+    if not chunks:
+        return None
+    if len(chunks) == 1:
+        return chunks[0]
+    return ({n: np.concatenate([c[0][n] for c in chunks])
+             for n in chunks[0][0]},
+            np.concatenate([c[1] for c in chunks]),
+            np.concatenate([c[2] for c in chunks]))
 
 
 class ScanExpired(RuntimeError):
@@ -842,25 +857,97 @@ class Region:
                     pass
 
     def _decode_file_part(self, meta: FileMeta, ts_range, names,
-                          tag_predicates) -> Optional[tuple]:
+                          tag_predicates, plan=None
+                          ) -> tuple[Optional[tuple], int]:
         """Read+decode one SST into host columns (the per-file body the
-        old scan loop ran serially). Returns (cols, seq, op) or None
-        when pruning/filtering leaves nothing."""
+        old scan loop ran serially). Returns ((cols, seq, op) or None
+        when pruning/filtering leaves nothing, rows read inside the
+        window before the exact tag filter). A pruned read (a window or
+        tag predicates) goes row group by row group, each cut to the
+        rows it keeps before the next is read (`_decode_groups`); a
+        whole-file read keeps every row, so it reads them at once."""
         from greptimedb_tpu.utils.metrics import (
             SCAN_DECODE_BYTES,
             SCAN_DECODE_SECONDS,
         )
 
         with SCAN_DECODE_SECONDS.time():
-            table = self.sst_reader.read(meta, self.schema, ts_range, names,
-                                         tag_predicates=tag_predicates)
-            if table is None or table.num_rows == 0:
-                return None
-            part = self._decode_table_part(table, ts_range, names)
+            if ts_range is not None or tag_predicates:
+                if plan is None:
+                    plan = self.sst_reader.plan_groups(
+                        meta, self.schema, ts_range, names,
+                        tag_predicates=tag_predicates)
+                if plan is None:
+                    return None, 0
+                pf, groups, cols_proj = plan
+                chunks, rows_read = self._decode_groups(
+                    meta, groups, cols_proj, ts_range, names,
+                    tag_predicates, pf)
+                part = _concat_parts(chunks)
+            else:
+                table = self.sst_reader.read(meta, self.schema, ts_range,
+                                             names,
+                                             tag_predicates=tag_predicates)
+                if table is None or table.num_rows == 0:
+                    return None, 0
+                part = self._decode_table_part(table, ts_range, names)
+                rows_read = 0 if part is None else len(part[1])
         if part is None:
-            return None
+            return None, rows_read
         SCAN_DECODE_BYTES.inc(float(_part_nbytes(part)))
-        return part
+        return part, rows_read
+
+    def _decode_groups(self, meta: FileMeta, groups, cols_proj, ts_range,
+                       names, tag_predicates, pf=None
+                       ) -> tuple[list, int]:
+        """The planned row groups of one SST, decoded one at a time and
+        each cut — on the arrow table, before any column is converted —
+        to the rows inside the window whose =/IN tag predicates hold.
+        SSTs sort by (pk, ts), so a row group a point query needs a few
+        hundred rows of holds a million: converting and keeping them
+        all until the file was read made a request's transient a copy
+        of every file its window touched. Whole series keep/drop
+        together, so LWW dedup and tombstones stay intact; the device
+        WHERE still evaluates the predicate exactly. Returns (decoded
+        chunks in group order, rows inside the window before the tag
+        filter); rows and order are those of the whole-file decode."""
+        ts_name = self.schema.time_index.name
+        probe = [ts_name] if ts_range is not None else []
+        probe += [t for t in (tag_predicates or {})
+                  if t in names and t != ts_name]
+        chunks: list = []
+        rows_read = 0
+        for table in self.sst_reader.iter_groups(meta, groups, cols_proj,
+                                                 pf):
+            if table.num_rows == 0:
+                continue
+            keep = None
+            if probe:
+                # the time index rides along: it gives a column the
+                # file lacks (backfilled) the group's row count
+                have = [n for n in dict.fromkeys([ts_name] + probe)
+                        if n in table.column_names]
+                pre = self._decode_sst(table.select(have), probe)
+                if ts_range is not None:
+                    tsv = pre[ts_name]
+                    keep = (tsv >= ts_range[0]) & (tsv < ts_range[1])
+                    if not keep.any():
+                        continue
+                in_window = table.num_rows if keep is None \
+                    else int(keep.sum())
+                tag_keep = self._tag_inset_mask(tag_predicates, pre) \
+                    if tag_predicates else None
+                if tag_keep is not None:
+                    keep = tag_keep if keep is None else keep & tag_keep
+            else:
+                in_window = table.num_rows
+            rows_read += in_window
+            if keep is not None and not keep.all():
+                table = table.take(np.flatnonzero(keep))
+            part = self._decode_table_part(table, ts_range, names)
+            if part is not None:
+                chunks.append(part)
+        return chunks, rows_read
 
     def _decode_table_part(self, table, ts_range, names) -> Optional[tuple]:
         """Arrow table -> (cols, seq, op) with the exact ts row filter —
@@ -895,14 +982,15 @@ class Region:
         return (cols, seq_col, op_col)
 
     def _decode_file_part_split(self, meta: FileMeta, ts_range, names,
-                                tag_predicates,
-                                threads: int) -> tuple[Optional[tuple], int]:
+                                tag_predicates, threads: int
+                                ) -> tuple[Optional[tuple], int, int]:
         """One SST decoded by SEVERAL workers: the surviving row groups
         split into contiguous runs, each run read through its own
         parquet handle + decoded on the shared pool, reassembled in
         group order — byte-for-byte the single-worker result (ISSUE 5
         carry-over: one huge file used to serialize the decode stage).
-        Returns (part or None, workers observed)."""
+        Returns (part or None, rows read as `_decode_file_part` counts
+        them, workers observed)."""
         from greptimedb_tpu.storage import scan_pool
         from greptimedb_tpu.utils.metrics import (
             SCAN_DECODE_BYTES,
@@ -912,13 +1000,17 @@ class Region:
         plan = self.sst_reader.plan_groups(meta, self.schema, ts_range,
                                            names,
                                            tag_predicates=tag_predicates)
+        pruned = ts_range is not None or bool(tag_predicates)
         k = 0 if plan is None else min(threads, len(plan[1]))
         if k <= 1:
             # nothing to split (pruned empty / one row group): the
             # classic whole-file path, so read()-level test spies and
             # fault seams see exactly the pre-split behavior
-            return (self._decode_file_part(meta, ts_range, names,
-                                           tag_predicates), 1)
+            if plan is None and pruned:
+                return None, 0, 1
+            return (*self._decode_file_part(
+                meta, ts_range, names, tag_predicates,
+                plan if pruned else None), 1)
         pf0, groups, cols_proj = plan
         with SCAN_DECODE_SECONDS.time():
             # contiguous runs preserve row order under reassembly
@@ -928,19 +1020,24 @@ class Region:
             seen: set = set()
 
             def work(run, pf=None):
+                # the planning handle already parsed the footer —
+                # exactly ONE worker may reuse it (pyarrow readers
+                # are not safe for concurrent reads on one handle)
                 seen.add(threading.get_ident())
+                if pruned:
+                    return self._decode_groups(
+                        meta, run, cols_proj, ts_range, names,
+                        tag_predicates, pf)
                 if pf is not None:
-                    # the planning handle already parsed the footer —
-                    # exactly ONE worker may reuse it (pyarrow readers
-                    # are not safe for concurrent reads on one handle)
                     table = pf.read_row_groups(list(run),
                                                columns=cols_proj)
                 else:
                     table = self.sst_reader.read_groups(meta, run,
                                                         cols_proj)
                 if table.num_rows == 0:
-                    return None
-                return self._decode_table_part(table, ts_range, names)
+                    return [], 0
+                part = self._decode_table_part(table, ts_range, names)
+                return ([], 0) if part is None else ([part], len(part[1]))
 
             from greptimedb_tpu.utils import tracing
 
@@ -952,39 +1049,33 @@ class Region:
                                      pf0 if i == 0 else None)
                     for i, run in enumerate(live_runs)]
             chunks: list = []
+            rows_read = 0
             first_err = None
             for f in futs:
                 try:
-                    chunks.append(dl.wait_future(f, "scan gather"))
+                    got, n = dl.wait_future(f, "scan gather")
+                    chunks.extend(got)
+                    rows_read += n
                 except BaseException as e:  # noqa: BLE001 — re-raised below
-                    chunks.append(None)
                     if first_err is None:
                         first_err = e
             if first_err is not None:
                 raise first_err
-            live = [c for c in chunks if c is not None]
-            if not live:
-                return None, max(1, len(seen))
-            if len(live) == 1:
-                part = live[0]
-            else:
-                part = (
-                    {n: np.concatenate([c[0][n] for c in live])
-                     for n in live[0][0]},
-                    np.concatenate([c[1] for c in live]),
-                    np.concatenate([c[2] for c in live]),
-                )
+            part = _concat_parts(chunks)
+        if part is None:
+            return None, rows_read, max(1, len(seen))
         SCAN_DECODE_BYTES.inc(float(_part_nbytes(part)))
-        return part, max(1, len(seen))
+        return part, rows_read, max(1, len(seen))
 
     def _decode_parts(self, metas, ts_range, names,
                       tag_predicates) -> tuple[list, int]:
         """Decode several SSTs, fanning across the shared per-datanode
-        pool (storage/scan_pool.py). Returns (parts in `metas` order,
-        distinct workers observed). decode_threads=1 decodes inline,
-        byte-for-byte the sequential path; a SINGLE multi-row-group
-        file splits its row groups across the pool instead of
-        serializing on one worker (order-preserving reassembly).
+        pool (storage/scan_pool.py). Returns ((part, rows read) pairs
+        in `metas` order, distinct workers observed). decode_threads=1
+        decodes inline, byte-for-byte the sequential path; a SINGLE
+        multi-row-group file splits its row groups across the pool
+        instead of serializing on one worker (order-preserving
+        reassembly).
 
         Fault discipline: every submitted future is WAITED ON before
         this returns or raises, so no worker touches SST bytes after
@@ -1000,9 +1091,9 @@ class Region:
         threads = scan_pool.resolve(self.decode_threads,
                                     max(len(metas), 1_000_000))
         if len(metas) == 1 and threads > 1:
-            part, workers = self._decode_file_part_split(
+            part, rows_read, workers = self._decode_file_part_split(
                 metas[0], ts_range, names, tag_predicates, threads)
-            return [part], workers
+            return [(part, rows_read)], workers
         threads = min(threads, len(metas))
         if threads <= 1 or len(metas) <= 1:
             return ([self._decode_file_part(m, ts_range, names,
@@ -1029,7 +1120,7 @@ class Region:
             try:
                 results.append(dl.wait_future(f, "decode gather"))
             except BaseException as e:  # noqa: BLE001 — re-raised below
-                results.append(None)
+                results.append((None, 0))
                 if first_err is None:
                     first_err = e
         if first_err is not None:
@@ -1085,8 +1176,8 @@ class Region:
                 tag_predicates)
             whole = ts_range is None and not tag_predicates
             with self._lock:
-                for i, part in zip(missing, decoded):
-                    ent = _PartEntry(part, _part_nbytes(part))
+                for i, (part, rows_read) in zip(missing, decoded):
+                    ent = _PartEntry(part, _part_nbytes(part), rows_read)
                     parts[i] = ent
                     # a scan races compaction/expiry: its pinned files
                     # may have been removed (and invalidated) while it
@@ -1100,7 +1191,7 @@ class Region:
                         self._file_deletes[file_list[i].file_id] = \
                             bool((part[2] != OP_PUT).any())
             _SCAN_IO.rows = getattr(_SCAN_IO, "rows", 0) + sum(
-                len(p[1]) for p in decoded if p is not None)
+                rows_read for _part, rows_read in decoded)
         _SCAN_IO.parts = getattr(_SCAN_IO, "parts", 0) + len(file_list)
         from greptimedb_tpu.utils import ledger
 
@@ -1641,29 +1732,27 @@ class Region:
         if mem is not None:
             mcols, mseq, mop = mem
             mem = ({n: mcols[n] for n in names}, mseq, mop)
-        # rows read before the exact tag filter below (what index
-        # pruning left to decode): the scan's IO, which a caller that
-        # pushes predicates compares with the rows it got back
+        # rows read before the exact tag filter (what index pruning
+        # left to decode): the scan's IO, which a caller that pushes
+        # predicates compares with the rows it got back
         if not lazy:
             decode_stats["rows_prefilter"] = \
-                sum(len(p[1]) for p in loaded) \
+                sum(e.rows_read for e in part_entries) \
                 + (len(mem[1]) if mem is not None else 0)
-        if tag_predicates:
+        if tag_predicates and mem is not None:
             # exact row filter for equality/IN tag predicates: the
             # inverted index prunes row groups, but one row group holds
-            # hundreds of series — dropping non-matching rows here keeps
-            # the cached scan (and device compute) proportional to the
+            # hundreds of series — dropping non-matching rows keeps the
+            # cached scan (and device compute) proportional to the
             # SELECTED series. Whole series keep/drop together, so LWW
             # dedup and tombstones stay intact; the device WHERE still
             # evaluates the predicate exactly (incl. NULL semantics).
-            # Row-wise, so it runs per part (an emptied SST part keeps
-            # its place as a zero-row segment; ascending-index gathers
-            # preserve within-part order)
-            loaded = [self._inset_filter(tag_predicates, p) for p in loaded]
-            if mem is not None:
-                mem = self._inset_filter(tag_predicates, mem)
-                if not len(mem[1]):
-                    mem = None
+            # An SST part was cut when it was decoded, row group by row
+            # group (_decode_groups; an emptied part keeps its place as
+            # a zero-row segment); the memtable slice is cut here
+            mem = self._inset_filter(tag_predicates, mem)
+            if not len(mem[1]):
+                mem = None
         rows = [m.num_rows for m in metas] if lazy \
             else [len(p[1]) for p in loaded]
         num_rows = sum(rows) + (len(mem[1]) if mem is not None else 0)

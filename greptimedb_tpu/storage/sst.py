@@ -269,6 +269,19 @@ class SstReader:
         pf = pq.ParquetFile(self.store.open_input(self.path(meta.file_id)))
         return pf.read_row_groups(list(groups), columns=columns)
 
+    def iter_groups(self, meta: FileMeta, groups: Sequence[int],
+                    columns: Optional[Sequence[str]], pf=None):
+        """Yield the planned row groups ONE table at a time through one
+        handle (`pf` from `plan_groups`, else a fresh one: a handle
+        serves one reader at a time) — the caller cuts each to the rows
+        it keeps before the next is read, so a pruned read never holds
+        more than a row group of rows it will drop."""
+        if pf is None:
+            pf = pq.ParquetFile(
+                self.store.open_input(self.path(meta.file_id)))
+        for g in groups:
+            yield pf.read_row_group(g, columns=columns)
+
     def iter_chunks(
         self,
         meta: FileMeta,

@@ -614,6 +614,15 @@ AGG_SCAN = REGISTRY.counter(
     "SST part), parts (it fetched only the parts it missed), whole "
     "(whole columns were built or read: every other path); counted once "
     "per statement, a statement that scans twice counts its costliest")
+AGG_PROGRAM_EVENTS = REGISTRY.counter(
+    "greptimedb_tpu_agg_program_events_total",
+    "Dispatches of a jitted aggregate or filter step, by what the "
+    "dispatch asked of the compiler: reuse (this program — the step, its "
+    "static arguments with the predicate's literal-free shape, its "
+    "arguments' shapes and dtypes — was dispatched to this backend "
+    "before in this process), new (it was not), static_literal (the "
+    "predicate's shape still holds a literal, so the program is shared "
+    "only by requests that repeat it)")
 METRIC_ENGINE_SCAN_SECONDS = REGISTRY.histogram(
     "greptimedb_tpu_metric_engine_scan_seconds",
     "Metric-engine logical scan wall time by phase: physical (the shared "

@@ -331,6 +331,15 @@ def stage(name: str, **attrs):
     return _Stage(name, attrs)
 
 
+def note_stage(**attrs) -> None:
+    """Attach attributes to the serving stage open on this thread, if
+    one is: what the work inside learns about itself (which program a
+    dispatch ran) lands on the stage's span."""
+    st = _stage.get()
+    if isinstance(st, _Stage):
+        st.attrs.update(attrs)
+
+
 def enclosing_stage(name: str, **attrs) -> _Span:
     """A label of query_stage_seconds that ENCLOSES flat stages
     (`execute`, `fast_execute`, `request`): a plain span whose whole

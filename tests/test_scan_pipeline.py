@@ -195,6 +195,114 @@ class TestParallelDecode:
         assert after.num_rows == warm.num_rows
 
 
+class TestPrunedReadByRowGroup:
+    """A pruned read (a window, =/IN tag predicates) goes row group by
+    row group and cuts each to the rows it keeps before the next is
+    read: the rows, their order, the stats and the cached part are
+    those of decoding the whole file and filtering after."""
+
+    @staticmethod
+    def region_of(engine, n_files=3):
+        engine.create_region(1, schema3())
+        region = engine.region(1)
+        region.sst_writer.row_group_size = 100  # 900 rows -> 9 groups
+        fill_files(engine, 1, n_files=n_files, rows_per_file=900)
+        return region
+
+    @staticmethod
+    def expected(full, ts_range, hosts):
+        names = np.asarray(full.tag_dicts["host"], dtype=object)[
+            np.asarray(full.columns["host"])]
+        ts = np.asarray(full.columns["ts"])
+        keep = np.ones(len(ts), dtype=bool)
+        if ts_range is not None:
+            keep &= (ts >= ts_range[0]) & (ts < ts_range[1])
+        in_window = int(keep.sum())
+        if hosts is not None:
+            keep &= np.isin(names, sorted(hosts))
+        return keep, in_window
+
+    @pytest.mark.parametrize("threads", ["1", "4"])
+    @pytest.mark.parametrize("ts_range,hosts", [
+        ((1_000_000, 1_004_005), None),
+        (None, {"h1"}),
+        (None, {"h0", "h5"}),
+        ((500, 2_003_000), {"h2", "h3"}),
+        ((1_002_000, 1_002_050), {"h4"}),
+        ((0, 3_000_000), {"h1", "nobody"}),
+    ])
+    def test_rows_and_order_are_the_filtered_whole_decode(
+            self, engine, monkeypatch, threads, ts_range, hosts):
+        region = self.region_of(engine)
+        monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", threads)
+        full = engine.scan(1).materialize()
+        keep, _ = self.expected(full, ts_range, hosts)
+        preds = None if hosts is None else {"host": hosts}
+        clear_scan_caches(region)
+        got = engine.scan(1, ts_range=ts_range, tag_predicates=preds)
+        assert got.num_rows == int(keep.sum())
+        for k in ("ts", "host", "v"):
+            assert np.array_equal(np.asarray(got.columns[k]),
+                                  np.asarray(full.columns[k])[keep]), k
+        assert np.array_equal(got.seq, full.seq[keep])
+        assert np.array_equal(got.op_type, full.op_type[keep])
+
+    @pytest.mark.parametrize("threads", ["1", "4"])
+    def test_one_file_splits_its_pruned_groups_alike(self, engine,
+                                                     monkeypatch, threads):
+        region = self.region_of(engine, n_files=1)
+        monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", threads)
+        full = engine.scan(1).materialize()
+        keep, _ = self.expected(full, (1_000, 8_000), {"h1", "h4"})
+        clear_scan_caches(region)
+        got = engine.scan(1, ts_range=(1_000, 8_000),
+                          tag_predicates={"host": {"h1", "h4"}})
+        assert np.array_equal(np.asarray(got.columns["v"]),
+                              np.asarray(full.columns["v"])[keep])
+        assert np.array_equal(got.seq, full.seq[keep])
+
+    def test_one_row_group_is_read_at_a_time_and_parts_cache_cut(
+            self, engine, monkeypatch):
+        region = self.region_of(engine)
+        monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", "1")
+        full = engine.scan(1).materialize()
+        ts_range, hosts = (1_000_000, 2_000_000), {"h3"}
+        keep, _ = self.expected(full, ts_range, hosts)
+        seen = []
+        orig = region.sst_reader.iter_groups
+
+        def spy(*a, **k):
+            for table in orig(*a, **k):
+                seen.append(table.num_rows)
+                yield table
+
+        monkeypatch.setattr(region.sst_reader, "iter_groups", spy)
+        clear_scan_caches(region)
+        got = engine.scan(1, ts_range=ts_range,
+                          tag_predicates={"host": hosts})
+        assert seen and max(seen) <= 100
+        assert got.num_rows == int(keep.sum()) == 150
+        # what the read decoded inside the window, before the tag cut:
+        # the index left the two 100-row groups that hold h3's 150 rows
+        assert seen == [100, 100] and got.stats["rows_prefilter"] == 200
+        # the part cache holds the cut parts, not the window's rows
+        with region._lock:
+            ents = [e for k, e in region._part_cache.items()
+                    if k[1] == ts_range and e.part is not None]
+        assert sum(len(e.part[1]) for e in ents) == 150
+        assert sum(e.rows_read for e in ents) == 200
+        # and a second scan of the same shape is served from them
+        with region._lock:
+            region._scan_cache.clear()
+            region._scan_cache_sizes.clear()
+            region._scan_cache_bytes = 0
+        del seen[:]
+        again = engine.scan(1, ts_range=ts_range,
+                            tag_predicates={"host": hosts})
+        assert not seen and scans_equal(got, again)
+        assert again.stats["rows_prefilter"] == 200
+
+
 class TestPartCacheMutation:
     def test_parts_survive_unrelated_flush(self, engine):
         engine.create_region(1, schema3())

@@ -206,6 +206,159 @@ def test_scan_expired_in_a_warmup_leaves_the_shape_unmarked(hedged):
     assert len(hedged.threads) == 2
 
 
+# ---- (b2) shape keys: one table's program answers another's request ---------
+
+METRIC_DDL = ("CREATE TABLE {name} (host STRING, job STRING, val DOUBLE, "
+              "ts TIMESTAMP TIME INDEX, PRIMARY KEY(host, job)) "
+              "ENGINE=metric")
+PANEL = ("SELECT date_bin(INTERVAL '10 second', ts) AS b, max(v) FROM plain "
+         "WHERE host IN {hosts!r} AND ts >= {lo} AND ts < {hi} "
+         "GROUP BY b ORDER BY b")
+
+
+@pytest.fixture
+def fleet(db, monkeypatch):
+    """One metric-engine physical region under four logical tables —
+    `m_a` and its twin `m_a2` (equal schema and row count), `m_b` with a
+    row written twice, `m_c` with a tombstone — beside a plain append
+    table of three small SSTs, all flushed; then the accelerator stub of
+    `hedged`. `rows` is what last-write-wins leaves in each table."""
+    eng, qe = db
+    rows = {}
+    for name, n in (("m_a", 50), ("m_a2", 50), ("m_b", 300), ("m_c", 2000)):
+        qe.execute_one(METRIC_DDL.format(name=name), CTX)
+        qe.execute_one(
+            f"INSERT INTO {name} (host, job, val, ts) VALUES " + ", ".join(
+                f"('h{i % 7}', 'j', {i}.0, {1000 + i * 10})"
+                for i in range(n)), CTX)
+        rows[name] = n
+    qe.execute_one("INSERT INTO m_b (host, job, val, ts) VALUES "
+                   "('h0', 'j', 99.0, 1000)", CTX)  # written twice
+    qe.execute_one("DELETE FROM m_c WHERE host = 'h1' AND job = 'j' "
+                   "AND ts = 1010", CTX)
+    rows["m_c"] -= 1
+    qe.execute_one(
+        "CREATE TABLE plain (ts TIMESTAMP(3) TIME INDEX, host STRING, "
+        "v DOUBLE, PRIMARY KEY(host)) WITH (append_mode='true')", CTX)
+    plain = []
+    for f in range(3):
+        part = [(f * 100_000 + i * 1000 + h, f"h{h}",
+                 float((f * 131 + i * 17 + h * 29) % 97))
+                for i in range(100) for h in range(6)]
+        qe.execute_one("INSERT INTO plain VALUES " + ", ".join(
+            f"({t}, '{h}', {v})" for t, h, v in part), CTX)
+        eng.flush(qe.catalog.table("public", "plain").region_ids[0])
+        plain += part
+    rows["plain"] = len(plain)
+    for rid in list(eng.regions):
+        eng.flush(rid)
+    monkeypatch.setenv("GREPTIMEDB_TPU_PARTIAL_CACHE", "on")
+    monkeypatch.setenv("GREPTIMEDB_TPU_HOST_TIER", "auto")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ex = qe.executor
+    monkeypatch.setattr(ex, "mesh", None)
+
+    class Fleet:
+        def __init__(self):
+            self.rows, self.plain = rows, plain
+
+        def ask(self, sql):
+            """(rows, tier, compiles on the request's thread, the
+            program event of its last dispatch) of one statement, once
+            the warm-ups it kicked have settled."""
+            from greptimedb_tpu.utils.metrics import (
+                AGG_PROGRAM_EVENTS,
+                XLA_COMPILES,
+            )
+
+            events = ("reuse", "new", "static_literal")
+            n0 = XLA_COMPILES.total(thread="request")
+            e0 = {e: AGG_PROGRAM_EVENTS.get(event=e) for e in events}
+            got = qe.execute_one(sql, CTX).rows()
+            tier = ex.last_tier
+            compiled = XLA_COMPILES.total(thread="request") - n0
+            moved = {e for e in events
+                     if AGG_PROGRAM_EVENTS.get(event=e) > e0[e]}
+            for _ in range(600):
+                if not ex.router.status()["warmup"]["warming"]:
+                    break
+                time.sleep(0.05)
+            else:
+                raise AssertionError("warm-up never finished")
+            assert ex.router.status()["warmup"]["failed"] == 0
+            return got, tier, compiled, moved
+
+    return Fleet()
+
+
+def test_a_program_compiled_for_one_table_answers_another(fleet):
+    """What PR 28 could not see before a chip call: once hedge keys are
+    shapes, a `count(*)` is answered by a device program that another
+    table's warm-up compiled — if, and only if, every static input of
+    its programs is equal (the last-write-wins mask is built over the
+    scan's own row count, so a non-append table of another size is
+    hedged again). Never a cold compile in the foreground of a request
+    the device answers, every count what last-write-wins leaves, no
+    degradation."""
+    degraded = DEVICE_DEGRADATIONS.total()
+    tables = ["m_a", "m_a2", "m_b", "m_c", "plain"]
+    first = {}
+    for name in tables:
+        got, tier, compiled, _ = fleet.ask(f"SELECT count(*) FROM {name}")
+        assert got == [[fleet.rows[name]]], name
+        assert tier == "host" or compiled == 0, (name, tier, compiled)
+        first[name] = tier
+    # equal static inputs: the twin rides the first table's program;
+    # another row count under a dedup mask, another block: hedged again
+    assert first == {"m_a": "host", "m_a2": "device", "m_b": "host",
+                     "m_c": "host", "plain": "host"}
+    for name in tables:
+        got, tier, compiled, moved = fleet.ask(
+            f"SELECT count(*) FROM {name}")
+        assert got == [[fleet.rows[name]]], name
+        # (the append table's parts come from the partial cache: no
+        # dispatch at all)
+        assert (tier, compiled) == ("device", 0) and moved <= {"reuse"}, name
+    assert DEVICE_DEGRADATIONS.total() == degraded
+
+
+def _panel_reference(plain, hosts, lo, hi):
+    out = {}
+    for t, h, v in plain:
+        if h in hosts and lo <= t < hi:
+            b = t // 10_000 * 10_000
+            out[b] = max(out.get(b, float("-inf")), v)
+    return [[b, out[b]] for b in sorted(out)]
+
+
+def test_two_host_sets_and_two_windows_share_the_warm_shape(fleet):
+    """A `single-groupby`-shaped panel: the first request of the shape
+    is hedged; another host set over another ms-granular window of the
+    same length is the same shape, so the device answers it with the
+    program the warm-up compiled — its own hosts' rows, its own
+    buckets."""
+    degraded = DEVICE_DEGRADATIONS.total()
+    sets = [("h1", "h2"), ("h4", "h5")]
+    windows = [(20_123, 80_123), (130_777, 190_777)]
+    asked = [(sets[0], windows[0]), (sets[1], windows[1]),
+             (sets[0], windows[1]), (sets[1], windows[0])]
+    answers = {}
+    for at, (hosts, (lo, hi)) in enumerate(asked):
+        got, tier, compiled, moved = fleet.ask(
+            PANEL.format(hosts=hosts, lo=lo, hi=hi))
+        got = [[int(b), float(v)] for b, v in got]
+        assert got == _panel_reference(fleet.plain, hosts, lo, hi)
+        assert got[0][0] == lo // 10_000 * 10_000 and len(got) == 7
+        if at == 0:
+            assert tier == "host"
+        else:
+            assert (tier, compiled, moved) == ("device", 0, {"reuse"}), at
+        answers[hosts, lo] = got
+    for lo, _hi in windows:
+        assert answers[sets[0], lo] != answers[sets[1], lo]
+    assert DEVICE_DEGRADATIONS.total() == degraded
+
+
 # ---- (c) TQL asks the router ------------------------------------------------
 
 
