@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import math
 import re
+import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from greptimedb_tpu.datatypes.types import DataType
+from greptimedb_tpu.ops.histogram import histogram_fold
 from greptimedb_tpu.ops.segment import segment_agg
 from greptimedb_tpu.ops.window import (
     counter_adjust,
@@ -35,6 +37,7 @@ from greptimedb_tpu.ops.window import (
     window_sums_grid,
 )
 from greptimedb_tpu.promql.loaded import (
+    LabelSets,
     Loaded,
     LoadedSeries,
     SeriesCache,
@@ -58,8 +61,16 @@ from greptimedb_tpu.promql.parser import (
 )
 from greptimedb_tpu.query.result import QueryResult
 from greptimedb_tpu.utils import device_telemetry, tracing
-from greptimedb_tpu.utils.metrics import PROMQL_LOAD_CACHE_EVENTS
+from greptimedb_tpu.utils.metrics import (
+    PROMQL_HISTOGRAM_FOLD_SECONDS,
+    PROMQL_HISTOGRAM_FOLDS,
+    PROMQL_LOAD_CACHE_EVENTS,
+)
 
+#: samples a selector's load sends to the device at a time
+#: (PromqlEngine._upload_sorted): the size of the largest loads the
+#: device has been seen to take whole (8.0M samples: PERF.md section 4)
+_LOAD_BLOCK = 1 << 23
 
 _CALENDAR = frozenset({
     "minute", "hour", "day_of_week", "day_of_month", "day_of_year",
@@ -623,45 +634,78 @@ class PromqlEngine:
             labels = [{}]
 
         ts_sec = ts_raw.astype(np.float64) * (unit / 1e9) + offset
-        # sort by (series, ts): required by counter_adjust /
+        # rows in (series, ts) order: required by counter_adjust / the
         # indicator channels, and makes segment ids sorted for the
-        # kernel. The storage scan already yields (tags..., ts)-
-        # sorted rows for a single flushed SST and series codes
-        # factorize in tag order — prove sortedness on host and skip
-        # the device lexsort chain (round-5: forcing that chain was
-        # 5.5 s of a 22 s first eval at 28.8M rows)
+        # kernel. A single flushed SST already yields them so and
+        # series codes factorize in tag order; several SSTs (time
+        # slices of one table, each in that order) or a memtable beside
+        # them are merged here, sorted runs on the host (2 s at 38.4M
+        # rows), not sorted on the device: its float64 sort over a whole
+        # selector took minutes at that size on the chip (PERF.md
+        # section 6, PR 32)
         ds, dt = np.diff(sidx), np.diff(ts_sec)
-        host_sorted = bool(np.all((ds > 0) | ((ds == 0) & (dt >= 0))))
-        # last-write-wins has nothing to decide where the rows are
-        # already in (series, ts) order, no (series, ts) repeats
-        # (or the scan says it cannot) and no tombstone is among
-        # them: a flushed, compacted region. Then seq / op_type stay
-        # on the host and the device sorts nothing
+        if not np.all((ds > 0) | ((ds == 0) & (dt >= 0))):
+            order = np.lexsort((ts_raw, sidx))
+            rows, sidx, ts_raw, ts_sec, vals = (
+                a[order] for a in (rows, sidx, ts_raw, ts_sec, vals))
+            ds, dt = np.diff(sidx), np.diff(ts_sec)
+        # last-write-wins has nothing to decide where no (series, ts)
+        # repeats (or the scan says it cannot) and no tombstone is
+        # among the rows: a flushed region nothing was written twice
+        # to. Then seq / op_type stay on the host and the device sorts
+        # nothing
         settled = info.append_mode or (
-            host_sorted
-            and (not scan.needs_dedup
-                 or bool(np.all((ds > 0) | (dt > 0))))
+            (not scan.needs_dedup or bool(np.all((ds > 0) | (dt > 0))))
             and not scan.has_delete())
-        d_sidx = h2d(sidx.astype(np.int32))
-        d_ts = h2d(ts_sec)
-        d_vals = h2d(vals)
-        if not settled:
+        if settled:
+            d_sidx, d_ts, channels = self._upload_sorted(
+                sidx, ts_sec, vals, extra_channels, p)
+        else:
+            d_sidx = h2d(sidx.astype(np.int32))
+            d_ts = h2d(ts_sec)
+            d_vals = h2d(vals)
             d_seq = h2d(scan.seq[rows].astype(np.int64))
             d_op = h2d(scan.op_type[rows].astype(np.int8))
-        with tracing.stage("device"):
-            if settled:
-                if not host_sorted:
-                    order = jnp.lexsort((d_ts, d_sidx))
-                    d_sidx, d_ts, d_vals = (d_sidx[order], d_ts[order],
-                                            d_vals[order])
-            else:
+            with tracing.stage("device"):
                 d_sidx, d_ts, d_vals = _promql_dedup(d_sidx, d_ts, d_vals,
                                                      d_seq, d_op)
-            channels = self._make_channels(d_sidx, d_ts, d_vals,
-                                           extra_channels, p)
+                channels = self._make_channels(d_sidx, d_ts, d_vals,
+                                               extra_channels, p)
         series = LoadedSeries(labels, d_sidx, d_ts, channels, span=ts_range,
                               extent=(int(ts_raw.min()), int(ts_raw.max())))
         return series, (scan.incarnation, scan.data_version)
+
+    def _upload_sorted(self, sidx: np.ndarray, ts_sec: np.ndarray,
+                       vals: np.ndarray, extra_channels,
+                       p: EvalParams) -> tuple:
+        """Host rows in (series, ts) order -> device (sidx, ts,
+        channels), in blocks of whole series of at most _LOAD_BLOCK
+        samples. Every derived channel reads its own series only, so a
+        block's channels are what the whole load's would be — and what
+        the device needs while it derives them (several times the
+        samples' own bytes) is a block's, not the selector's: a
+        selector of tens of millions of samples loads beside what is
+        already resident. A small load is one block."""
+        n = len(sidx)
+        blocks = -(-n // _LOAD_BLOCK)
+        cuts = [0, n]
+        if blocks > 1:
+            num_series = int(sidx[-1]) + 1
+            per = -(-num_series // blocks)
+            cuts = np.searchsorted(
+                sidx, np.arange(0, num_series, per)).tolist() + [n]
+        parts = []
+        for a, b in zip(cuts[:-1], cuts[1:]):
+            d_sidx = h2d(sidx[a:b].astype(np.int32))
+            d_ts = h2d(ts_sec[a:b])
+            d_vals = h2d(vals[a:b])
+            with tracing.stage("device"):
+                parts.append((d_sidx, d_ts, self._make_channels(
+                    d_sidx, d_ts, d_vals, extra_channels, p)))
+        if len(parts) == 1:
+            return parts[0]
+        with tracing.stage("device"):
+            return tuple(jnp.concatenate(x) for x in zip(*parts))
 
     # ---- calls -------------------------------------------------------------
 
@@ -896,75 +940,32 @@ class PromqlEngine:
 
     def _histogram_quantile(self, call: Call, p: EvalParams, ctx):
         """φ-quantile over `le`-bucketed classic histograms (reference
-        extension_plan/histogram_fold.rs:61: group by labels-minus-le,
-        cumulative buckets, linear interpolation within the bucket)."""
+        extension_plan/histogram_fold.rs:61): the input series gathered
+        into [groups, buckets, steps] by the fold index and folded by
+        ONE kernel (ops/histogram.py), φ an operand."""
         phi = _scalar_of(self._eval(call.args[0], p, ctx))
         v = self._eval(call.args[1], p, ctx)
         if not isinstance(v, SeriesMatrix):
             raise PromqlError("histogram_quantile needs an instant vector")
-        groups: dict[tuple, list[tuple[float, int]]] = {}
-        glabels: dict[tuple, dict] = {}
-        for i, lab in enumerate(v.labels):
-            le_s = lab.get("le")
-            if le_s is None:
-                continue
-            try:
-                le = float(le_s.replace("+Inf", "inf")) \
-                    if isinstance(le_s, str) else float(le_s)
-            except ValueError:
-                continue
-            rest = {k: x for k, x in lab.items() if k != "le"}
-            sig = tuple(sorted(rest.items()))
-            groups.setdefault(sig, []).append((le, i))
-            glabels[sig] = rest
-        if not groups:
-            return SeriesMatrix([], jnp.zeros((0, p.T)))
-        out_labels, outs = [], []
-        vals = v.values
-        for sig, buckets in sorted(groups.items()):
-            buckets.sort()
-            les = np.asarray([b[0] for b in buckets])
-            idx = np.asarray([b[1] for b in buckets])
-            if not np.isinf(les[-1]):
-                # no +Inf bucket: quantile undefined (Prometheus -> NaN)
-                out_labels.append(glabels[sig])
-                outs.append(jnp.full(p.T, jnp.nan))
-                continue
-            counts = vals[idx]  # [B, T] cumulative by construction
-            # enforce monotonicity like Prometheus (scrape races)
-            counts = jax.lax.cummax(jnp.nan_to_num(counts), axis=0)
-            total = counts[-1]
-            rank = phi * total
-            # first bucket whose cumulative count reaches the rank
-            reached = counts >= rank[None, :]
-            b = jnp.argmax(reached, axis=0)
-            B = len(les)
-            d_les = h2d(les)
-            upper = d_les[b]
-            lower = jnp.where(b > 0, d_les[jnp.maximum(b - 1, 0)], 0.0)
-            cum_prev = jnp.where(b > 0,
-                                 jnp.take_along_axis(
-                                     counts, jnp.maximum(b - 1, 0)[None, :],
-                                     axis=0)[0], 0.0)
-            cum_b = jnp.take_along_axis(counts, b[None, :], axis=0)[0]
-            in_bucket = jnp.maximum(cum_b - cum_prev, 1e-300)
-            frac = (rank - cum_prev) / in_bucket
-            interp = lower + (upper - lower) * jnp.clip(frac, 0.0, 1.0)
-            # highest bucket (= +Inf): return the highest finite bound
-            highest_finite = d_les[B - 2] if B >= 2 else jnp.nan
-            res = jnp.where(b >= B - 1, highest_finite, interp)
-            # first bucket with non-positive upper bound: no interpolation
-            res = jnp.where((b == 0) & (upper <= 0), upper, res)
-            res = jnp.where(total > 0, res, jnp.nan)
-            if phi < 0:
-                res = jnp.full(p.T, -jnp.inf)
-            elif phi > 1:
-                res = jnp.full(p.T, jnp.inf)
-            elif math.isnan(phi):
-                res = jnp.full(p.T, jnp.nan)
-            out_labels.append(glabels[sig])
-            outs.append(res)
-        return SeriesMatrix(out_labels, jnp.stack(outs, axis=0))
+        t0 = time.perf_counter()
+        index, how = _fold_index(v.labels)
+        t1 = time.perf_counter()
+        PROMQL_HISTOGRAM_FOLD_SECONDS.observe(t1 - t0, phase="index")
+        PROMQL_HISTOGRAM_FOLDS.inc(index=how)
+        G = len(index.labels)
+        d_phi = h2d(np.float64(phi))  # an operand: one program for every φ
+        with tracing.span("histogram_fold", groups=G,
+                          buckets=int(index.src.shape[1]), steps=p.T,
+                          index=how, skipped=index.skipped):
+            if G == 0:
+                return SeriesMatrix([], jnp.zeros((0, p.T)))
+            counts = jnp.take(v.values, index.src, axis=0)  # [G', B, T]
+            out = histogram_fold(counts, index.bounds, index.valid, d_phi)
+            if out.shape[0] != G:
+                out = out[:G]  # G' is G padded to a power of two
+            PROMQL_HISTOGRAM_FOLD_SECONDS.observe(
+                time.perf_counter() - t1, phase="dispatch")
+        return SeriesMatrix(index.labels, out)
 
     def _holt_winters(self, call: Call, sel, p: EvalParams, ctx):
         """Double exponential smoothing (reference functions/
@@ -1064,6 +1065,11 @@ class PromqlEngine:
             gidx = rank[first_seen]
             G = len(uniq)
             glabels = [dict(u) for u in uniq]
+            if isinstance(v.labels, LabelSets):
+                # the groups' label sets depend on the input's and on
+                # the grouping alone
+                glabels = v.labels.step(
+                    ("group", agg.by, agg.without), glabels)
 
         vals = v.values  # [S, T]
         if agg.op in ("sum", "avg", "min", "max", "count", "group",
@@ -1378,6 +1384,74 @@ def _edges_enabled() -> bool:
 
     return os.environ.get("GREPTIMEDB_TPU_PROMQL_EDGES",
                           "on").lower() not in ("off", "0", "false")
+
+
+@dataclass
+class _FoldIndex:
+    """Where histogram_quantile's input series go in the [groups,
+    buckets, steps] block: a function of the input's label sets."""
+
+    labels: list  # [G] the groups' label sets: the input's minus `le`
+    src: jax.Array  # [G', B] int32 input series of each bucket slot
+    bounds: jax.Array  # [G', B] float64 `le` of each slot, ascending
+    valid: jax.Array  # [G', B] bool: a bucket, not padding
+    skipped: int  # input series whose `le` is no number
+
+
+def _fold_index(labels: list) -> tuple:
+    """(the fold index of `labels`, "hit" | "build"): kept beside the
+    loaded series the label sets derive from (LabelSets.derived), so
+    built once per (input label sets, data version); label sets of no
+    known origin get theirs built per request."""
+    root = getattr(labels, "root", None)
+    if root is None:
+        return _build_fold_index(labels), "build"
+    key = ("histogram_fold", labels.path)
+    with root.lock:
+        index = root.derived.get(key)
+    if index is not None:
+        return index, "hit"
+    index = _build_fold_index(labels)
+    with root.lock:
+        root.derived[key] = index
+    return index, "build"
+
+
+def _build_fold_index(labels: list) -> _FoldIndex:
+    """Group the series by their labels minus `le` (groups in signature
+    order), rank each group's buckets by `le` parsed as a float (`+Inf`
+    as Prometheus spells it), pad the groups to the widest and their
+    number to a power of two."""
+    groups: dict = {}
+    skipped = 0
+    for i, lab in enumerate(labels):
+        le_s = lab.get("le")
+        if le_s is None:
+            continue  # not a bucket series
+        try:
+            le = float(le_s)
+        except (TypeError, ValueError):
+            le = math.nan
+        if math.isnan(le):
+            skipped += 1
+            continue
+        rest = tuple(sorted((k, x) for k, x in lab.items() if k != "le"))
+        groups.setdefault(rest, []).append((le, i))
+    sigs = sorted(groups)
+    G = len(sigs)
+    B = max((len(groups[s]) for s in sigs), default=1)
+    padded = 1 << max(G - 1, 0).bit_length()
+    src = np.zeros((padded, B), dtype=np.int32)
+    bounds = np.zeros((padded, B), dtype=np.float64)
+    valid = np.zeros((padded, B), dtype=bool)
+    for g, sig in enumerate(sigs):
+        buckets = sorted(groups[sig])
+        n = len(buckets)
+        bounds[g, :n] = [b[0] for b in buckets]
+        src[g, :n] = [b[1] for b in buckets]
+        valid[g, :n] = True
+    return _FoldIndex([dict(s) for s in sigs], h2d(src), h2d(bounds),
+                      h2d(valid), skipped)
 
 
 @jax.jit

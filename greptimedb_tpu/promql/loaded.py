@@ -9,8 +9,9 @@ asked BEFORE any scan, from region metadata alone:
 
 - the samples a request loaded for its own range serve that range again;
 - once the ranges requested at one data version add up to the region's
-  retained span, the next request loads the whole span instead
-  (`promote`), and where every series shares one complete sample grid
+  retained span, or at once where a range covers half of it (the region
+  then reads all it holds anyway), the request loads the whole span
+  instead (`promote`), and where every series shares one complete sample grid
   there, every later request of that version is a slice of the resident
   [S, P, C] matrix (`hit`): nothing is scanned, decoded, factorised or
   uploaded;
@@ -63,6 +64,35 @@ def covered(lo: int, hi: int, extent: tuple) -> int:
     return max(0, min(hi, extent[1] + 1) - max(lo, extent[0]))
 
 
+class LabelSets(list):
+    """The label sets of a vector, knowing which loaded series they
+    derive from and how.
+
+    A selector's label sets live as long as its `LoadedSeries`: one
+    selector at one data version. What an evaluation derives from them
+    by a rule that reads nothing else (`sum by (le, handler)`'s output
+    label sets) is named by the root list and the steps taken from it,
+    so whatever depends on the label sets alone — histogram_quantile's
+    fold index — is kept in the root's `derived`, found again by the
+    next request, and dropped with the samples. A plain list has no
+    such name, and what is derived from it is derived per request."""
+
+    def __init__(self, labels=(), root: Optional["LabelSets"] = None,
+                 path: tuple = ()):
+        super().__init__(labels)
+        self.root = self if root is None else root
+        #: the steps from the root's label sets to these
+        self.path = path
+        if root is None:
+            #: (what, path) -> whatever was derived; the root's only
+            self.derived: dict = {}
+            self.lock = threading.Lock()
+
+    def step(self, how: tuple, labels: list) -> "LabelSets":
+        """`labels`, derived from these by the rule `how` names."""
+        return LabelSets(labels, self.root, self.path + (how,))
+
+
 class LoadedSeries:
     """One selector's samples on the device, sorted by (series, ts).
 
@@ -73,7 +103,7 @@ class LoadedSeries:
 
     def __init__(self, labels: list, sidx, ts, chans,
                  span: Optional[tuple] = None, extent: tuple = (0, 0)):
-        self.labels = labels
+        self.labels = LabelSets(labels)
         #: the scan range it was loaded for, in the time index's units;
         #: None = everything the region held at its version
         self.span = span
@@ -234,7 +264,11 @@ class SeriesCache:
             if not sliceable or slot.promoting or extent is None:
                 return "miss", None
             span = extent[1] + 1 - extent[0]
-            if slot.paid + covered(lo, hi, extent) < span:
+            asked = covered(lo, hi, extent)
+            # a range over half the span makes the region read all it
+            # holds (Region.scan's canonical sharing): the whole span
+            # is then the load to make, at the first request
+            if slot.paid + asked < span and 2 * asked < span:
                 return "miss", None  # its own range is still the cheaper
             ranged = slot.ranged
             if slot.bytes_per_unit * span > self.budget or (
@@ -269,7 +303,8 @@ class SeriesCache:
                 slot.promoting = False
                 # flat, it serves the requests that cover half of it
                 # and no promotion can better it
-                slot.ineligible = not series.grid_complete
+                slot.ineligible = not series.grid_complete \
+                    or nbytes > self.budget
                 self._drop(key, "ranged")  # the whole span holds it too
             self._drop(key, kind)
             if nbytes > self.budget:

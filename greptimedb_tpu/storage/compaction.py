@@ -7,9 +7,9 @@ merges are wasted work and churn write amplification). The active (latest)
 window tolerates `max_active_files` L0 files before compacting; inactive
 windows compact as soon as they hold more than one file.
 
-The merge itself is the device sort-dedup kernel (Region._merge_files) —
-compaction is the same computation as query-time dedup, run once and
-persisted (SURVEY.md §7 design stance).
+The merge itself (Region._merge_files) is the computation of query-time
+dedup, run once and persisted (SURVEY.md §7 design stance): its inputs
+are (tags, ts, seq)-sorted runs, which the host's stable sort merges.
 """
 
 from __future__ import annotations

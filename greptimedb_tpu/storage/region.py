@@ -1484,9 +1484,9 @@ class Region:
     def compact(self, strategy: str = "twcs") -> list[FileMeta]:
         """Compact SSTs. "twcs": time-window groups picked by TwcsPicker
         (reference compaction/twcs.rs); "full": everything into one file
-        (manual strict-window analog, ADMIN compact_table). The merge runs
-        the device sort-dedup kernel — compaction is the same computation
-        as query-time dedup, persisted (SURVEY.md §7)."""
+        (manual strict-window analog, ADMIN compact_table). The merge is the
+        computation of query-time dedup, persisted (SURVEY.md §7): the
+        inputs are sorted runs, so the host merges them."""
         from greptimedb_tpu.storage.compaction import TwcsPicker
 
         with self._compact_lock:
@@ -1504,8 +1504,9 @@ class Region:
             return out
 
     def _merge_files(self, group: list[FileMeta]) -> Optional[FileMeta]:
-        """Read `group`'s SSTs, sort-dedup on device, rewrite as one L1
-        file, swap in the manifest (compaction/task.rs analog)."""
+        """Read `group`'s SSTs, merge their sorted runs last-write-wins,
+        rewrite as one L1 file, swap in the manifest (compaction/task.rs
+        analog)."""
         names = self.schema.names
         from greptimedb_tpu.storage.index import predicates_cache_key
 
@@ -1536,28 +1537,24 @@ class Region:
         op = np.concatenate(parts_op)
         n_rows = len(seq)
 
-        import jax.numpy as jnp
-        from greptimedb_tpu.ops.dedup import sort_dedup
-        from greptimedb_tpu.ops.segment import combine_group_ids
-
         tag_names = [c.name for c in self.schema.tag_columns]
-        sizes = [max(len(self.registry.dict_array(n)), 1) + 1 for n in tag_names]
-        if tag_names:
-            # int64: the cardinality product of several tags can exceed 2^31
-            sid = combine_group_ids(
-                [jnp.asarray(columns[n] + 1) for n in tag_names], sizes,
-                dtype=jnp.int64,
-            )
-        else:
-            sid = jnp.zeros(n_rows, dtype=jnp.int64)
-        ts = jnp.asarray(columns[self.schema.time_index.name])
-        covers_all = len(group) == len(self.files)
-        order, keep = sort_dedup(
-            sid, ts, jnp.asarray(seq), jnp.asarray(op),
-            jnp.ones(n_rows, dtype=bool),
-            keep_tombstones=not covers_all,
-        )
-        order = np.asarray(order)[np.asarray(keep)]
+        # the inputs are (tags, ts, seq)-sorted runs, as flush wrote
+        # them: the host's stable sort merges presorted runs in seconds
+        # where a device sort of the whole group takes minutes at tens
+        # of millions of rows; last write wins per (series, ts)
+        order = self._sort_order(columns, seq)
+        same = np.ones(max(n_rows - 1, 0), dtype=bool)
+        for name in tag_names + [self.schema.time_index.name]:
+            v = columns[name][order]
+            same &= v[1:] == v[:-1]
+        keep = np.ones(n_rows, dtype=bool)
+        keep[:-1] = ~same
+        if len(group) == len(self.files):
+            # winning tombstones go only where the group is every file:
+            # a partial (windowed) compaction retains them, since an
+            # older shadowed PUT may live in a file outside the group
+            keep &= op[order] != OP_DELETE
+        order = order[keep]
         cols = {k: v[order] for k, v in columns.items()}
         tag_dicts = {n: self.registry.dict_array(n) for n in tag_names}
         meta = self.sst_writer.write(

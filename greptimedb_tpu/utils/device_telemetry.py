@@ -33,6 +33,7 @@ import weakref
 
 from greptimedb_tpu.utils import ledger, tracing
 from greptimedb_tpu.utils.metrics import (
+    DEVICE_INFO,
     DEVICE_MEMORY,
     DEVICE_TRANSFER_BYTES,
     REGISTRY,
@@ -166,7 +167,10 @@ def _collect_device_memory() -> None:
     DEVICE_MEMORY.set(float(cache_bytes), kind="cache")
     import jax
 
-    stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    devices = jax.local_devices()
+    DEVICE_INFO.set(float(len(devices)), platform=devices[0].platform,
+                    device_kind=devices[0].device_kind)
+    stats = [d.memory_stats() or {} for d in devices]
     if any("bytes_in_use" in st for st in stats):
         # every local device counts: a mesh spreads the hot set
         DEVICE_MEMORY.set(

@@ -550,6 +550,11 @@ DEVICE_MEMORY = REGISTRY.gauge(
     "Accelerator memory by kind (in_use/limit summed over the local "
     "devices' PJRT allocators when they report, cache = bytes pinned "
     "by the device block cache; per-device figures at /v1/device)")
+DEVICE_INFO = REGISTRY.gauge(
+    "greptimedb_tpu_device_info",
+    "Local devices of the serving process, labelled by jax's platform "
+    "and device_kind (what /v1/device reports): a share of a chip's "
+    "published peak is taken against the kind named here")
 DEVICE_TRANSFER_BYTES = REGISTRY.counter(
     "greptimedb_tpu_device_transfer_bytes_total",
     "Host<->device bytes moved by the query engine, by direction "
@@ -567,6 +572,18 @@ PROMQL_LOAD_CACHE_EVENTS = REGISTRY.counter(
     "the whole retained span was, and kept; ineligible = the own range, "
     "for a selector whose whole span has no complete sample grid or "
     "would not fit the device budget at this data version)")
+PROMQL_HISTOGRAM_FOLD_SECONDS = REGISTRY.histogram(
+    "greptimedb_tpu_promql_histogram_fold_seconds",
+    "histogram_quantile's host wall time by phase: index (finding or "
+    "building the fold index: each input series' group and bucket rank, "
+    "the groups' bounds) and dispatch (the device gather of the buckets "
+    "and the histogram_fold kernel's dispatch)")
+PROMQL_HISTOGRAM_FOLDS = REGISTRY.counter(
+    "greptimedb_tpu_promql_histogram_fold_total",
+    "histogram_quantile evaluations by where the fold index came from "
+    "(hit = kept beside the loaded series the input's label sets derive "
+    "from, at their data version; build = built from the label sets for "
+    "this request)")
 DEVICE_HOT_SET_EVENTS = REGISTRY.counter(
     "greptimedb_tpu_device_hot_set_events_total",
     "HBM-resident columnar hot set events by kind (hit/miss/evict/pin — "

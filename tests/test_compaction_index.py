@@ -130,6 +130,59 @@ class TestRegionCompaction:
         assert meta.num_rows == 0 or meta.num_rows == 1  # winner-only content
 
 
+class TestMergeAgainstAModel:
+    """The merge of a compaction's sorted runs on the host, held to a
+    dict that applies the same writes in order: last write wins per
+    (series, ts), a winning tombstone hides the row, and a partial
+    group keeps its tombstones for the files outside it."""
+
+    @pytest.mark.parametrize("partial", [False, True])
+    @pytest.mark.parametrize("seed", [1, 2, 3])
+    def test_merge_is_last_write_wins(self, tmp_path, seed, partial):
+        engine = RegionEngine(EngineConfig(data_dir=str(tmp_path)))
+        q = QueryEngine(Catalog(MemoryKv()), engine)
+        q.execute_one(
+            "CREATE TABLE m (host STRING, dc STRING, v DOUBLE, "
+            "ts TIMESTAMP TIME INDEX, PRIMARY KEY(host, dc))")
+        try:
+            rng = np.random.default_rng(seed)
+            region = region_of(q, "m")
+            model = {}
+            for f in range(6):
+                rows = {}
+                for _ in range(40):
+                    key = (f"h{rng.integers(5)}", f"d{rng.integers(3)}",
+                           1000 + int(rng.integers(12)))
+                    rows[key] = float(rng.integers(1000))
+                q.execute_one(
+                    "INSERT INTO m (host, dc, v, ts) VALUES " + ", ".join(
+                        f"('{h}', '{d}', {v}, {ts})"
+                        for (h, d, ts), v in rows.items()))
+                model.update(rows)
+                if f in (2, 4):
+                    gone = f"h{rng.integers(5)}"
+                    q.execute_one(f"DELETE FROM m WHERE host = '{gone}'")
+                    model = {k: v for k, v in model.items() if k[0] != gone}
+                region.flush()
+            files = sorted(region.files.values(), key=lambda x: x.max_seq)
+            assert len(files) == 6
+            if partial:
+                # the newest four only: the first two stay beside them
+                region._merge_files(files[2:])
+                assert len(region.files) == 3
+            else:
+                region.compact(strategy="full")
+                assert len(region.files) == 1
+                merged = list(region.files.values())[0]
+                assert merged.num_rows == len(model)
+            got = q.execute_one(
+                "SELECT host, dc, ts, v FROM m ORDER BY host, dc, ts").rows()
+            want = [[h, d, ts, v] for (h, d, ts), v in sorted(model.items())]
+            assert [[r[0], r[1], int(r[2]), r[3]] for r in got] == want
+        finally:
+            engine.close()
+
+
 class TestInvertedIndex:
     def test_index_prunes_row_groups(self, tmp_path):
         engine = RegionEngine(EngineConfig(data_dir=str(tmp_path)))

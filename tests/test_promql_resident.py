@@ -188,6 +188,31 @@ def test_a_range_that_covers_the_region_is_the_whole_span_at_once(db):
     assert [db.ask(q, end)[1] for end in ENDS] == ["hit"] * 5
 
 
+def test_a_range_over_half_the_span_loads_the_whole_span_at_once(db):
+    # a trailing 30 min range with its 5 min window is over half of the
+    # hour: the region reads all it holds for it, so the first request
+    # at a version is the promotion, and a second load is never made
+    q = QUERIES["rate"]
+    want = {}
+    for end in (T0 + 2400, T0 + 3585):
+        db.cold()
+        _, sm = db.prom.eval_matrix(q, end - 900, end, 15.0)
+        want[end] = np.asarray(sm.values)
+    db.cold()
+    before = _events()
+    _, sm = db.prom.eval_matrix(q, T0 + 3585 - 1800, T0 + 3585, 15.0)
+    assert len(sm.labels) == 6
+    after = _events()
+    assert {e: after[e] - before[e] for e in EVENTS} == {
+        "hit": 0, "miss": 0, "promote": 1, "ineligible": 0}
+    for end in want:
+        before = _events()
+        _, sm = db.prom.eval_matrix(q, end - 900, end, 15.0)
+        assert _events()["hit"] == before["hit"] + 1
+        np.testing.assert_allclose(np.asarray(sm.values), want[end],
+                                   rtol=1e-12, atol=0, equal_nan=True)
+
+
 # ---- (b) a series outside the request's range -----------------------------
 
 

@@ -344,13 +344,14 @@ def test_promql_moves_the_transfer_counters(server):
         return (DEVICE_TRANSFER_BYTES.total(direction="h2d"),
                 DEVICE_TRANSFER_BYTES.total(direction="d2h"),
                 PROMQL_LOAD_CACHE_EVENTS.total(event="hit"),
-                PROMQL_LOAD_CACHE_EVENTS.total(event="miss"))
+                PROMQL_LOAD_CACHE_EVENTS.total(event="promote"))
 
     a = moved()
     server.promql("sum by (host) (avg_over_time(m[2m]))")
     b = moved()
-    # a new selector: masks, factorization and the upload of its
-    # 1600 samples (int32 series index, float64 time and value)
+    # a new selector over more than half of what the table holds: its
+    # whole span is loaded at once: masks, factorization and the upload
+    # of its 1600 samples (int32 series index, float64 time and value)
     assert b[3] == a[3] + 1
     assert b[0] - a[0] >= 1600 * (4 + 8 + 8)
     assert b[1] > a[1]
@@ -468,7 +469,7 @@ def test_kernels_carry_their_stable_names():
             "sort_compact", "sparse_segment_agg", "sort_dedup",
             "dedup_mask", "segment_agg", "window_stats", "window_edges",
             "window_edges_grid", "window_sums_grid", "counter_adjust",
-            "extrapolated_delta", "promql_dedup"} \
+            "extrapolated_delta", "promql_dedup", "histogram_fold"} \
         <= device_telemetry.KERNEL_NAMES
     lowered = window.counter_adjust.lower(
         jnp.zeros(8, jnp.int32), jnp.arange(8.0))
@@ -492,7 +493,9 @@ class TestDeviceProfileEndpoint:
         # a second caller, while the first session is open
         st, body, _ = server.get("/debug/pprof/device?seconds=0.1")
         assert st == 409 and b"already" in body
-        server.promql("sum by (host) (rate(m[1m]))")
+        # a selector no other test asks for: its samples are loaded (and
+        # their grid read back) inside the session, whatever ran before
+        server.promql('sum by (host) (rate(m{host!="none"}[1m]))')
         t.join(timeout=120)
         assert not t.is_alive()
         st, body, _ = box["first"]
