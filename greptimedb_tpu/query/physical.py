@@ -68,6 +68,7 @@ from greptimedb_tpu.storage.region import (
     ScanData,
     ScanExpired,
     scan_io_counters,
+    scan_io_since,
 )
 from greptimedb_tpu.utils import device_telemetry, tracing
 from greptimedb_tpu.utils import flame as _flame
@@ -1473,11 +1474,11 @@ class PhysicalExecutor:
         stage that happened to touch a column first."""
         if scan is None or scan.materialized:
             return
-        before = scan_io_counters()[1]
+        before = scan_io_counters()
         with tracing.stage("scan", table=table.name, regions=1,
                            whole=True) as attrs:
             scan.materialize()
-            attrs["rows_decoded"] = scan_io_counters()[1] - before
+            attrs.update(scan_io_since(before))
 
     def _note_agg_scan(self, mode: str) -> None:
         """What an aggregate statement asked of its scan, for
@@ -1625,7 +1626,7 @@ class PhysicalExecutor:
                     else:
                         stream.close()
 
-            decoded0 = scan_io_counters()[1]
+            io0 = scan_io_counters()
             with tracing.stage("scan", table=table.name,
                                regions=len(table.region_ids)) as scan_attrs:
                 if len(table.region_ids) == 1:
@@ -1649,8 +1650,7 @@ class PhysicalExecutor:
                 # scan is a plan, its parts decode when first asked for
                 # (then in a `scan` segment of their own)
                 scan_attrs["rows"] = 0 if scan is None else scan.num_rows
-                scan_attrs["rows_decoded"] = \
-                    scan_io_counters()[1] - decoded0
+                scan_attrs.update(scan_io_since(io0))
 
             nrows = 0 if scan is None else scan.num_rows
             try:
@@ -2328,11 +2328,11 @@ class PhysicalExecutor:
             ranges = [(e.start, e.end) for _k, e in wave if needs_rows(e)]
             if not ranges or scan.materialized:
                 return None
-            before = scan_io_counters()[1]
+            before = scan_io_counters()
             with tracing.stage("scan", table=table.name, regions=1,
                                on_demand=True) as attrs:
                 handle = scan.hold_rows(ranges)
-                attrs["rows_decoded"] = scan_io_counters()[1] - before
+                attrs.update(scan_io_since(before))
             return handle
 
         missed = [(key, entry) for key, entry, p in probed if p is None]

@@ -421,19 +421,23 @@ class SegmentSelection:
     def all_set(self) -> bool:
         return bool(self.mask.all())
 
-    def row_groups(self, group_row_counts: Sequence[int]) -> list[int]:
-        """Map surviving segments onto parquet row groups given each
-        group's row count (reference row-selection analog)."""
-        keep = []
-        start = 0
+    def group_mask(self, group_row_counts: Sequence[int]) -> np.ndarray:
+        """bool[groups]: which parquet row groups hold a surviving
+        segment, given each group's row count (reference row-selection
+        analog) — one pass over arrays, whatever the group count."""
+        rows = np.asarray(group_row_counts, dtype=np.int64)
+        start = np.cumsum(rows) - rows
         seg = self.segment_rows
-        for g, rows in enumerate(group_row_counts):
-            s0 = start // seg
-            s1 = (start + rows - 1) // seg + 1 if rows else s0
-            if self.mask[s0:min(s1, len(self.mask))].any():
-                keep.append(g)
-            start += rows
-        return keep
+        n = len(self.mask)
+        s0 = np.minimum(start // seg, n)
+        s1 = np.minimum(np.where(rows > 0,
+                                 (start + rows - 1) // seg + 1, s0), n)
+        covered = np.concatenate(([0], np.cumsum(self.mask)))
+        return covered[s1] > covered[s0]
+
+    def row_groups(self, group_row_counts: Sequence[int]) -> list[int]:
+        """`group_mask` as the surviving groups' indices."""
+        return np.flatnonzero(self.group_mask(group_row_counts)).tolist()
 
 
 class IndexApplier:

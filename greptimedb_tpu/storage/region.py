@@ -23,7 +23,7 @@ import weakref
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from collections.abc import Mapping
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 import pyarrow as pa
@@ -34,7 +34,14 @@ from greptimedb_tpu.datatypes.types import SemanticType
 from greptimedb_tpu.datatypes.vector import DictVector
 from greptimedb_tpu.storage.manifest import ManifestManager
 from greptimedb_tpu.storage.memtable import Memtable, TagRegistry
-from greptimedb_tpu.storage.sst import OP_COL, SEQ_COL, FileMeta, SstReader, SstWriter
+from greptimedb_tpu.storage.sst import (
+    OP_COL,
+    SEQ_COL,
+    FileMeta,
+    SstReader,
+    SstWriter,
+    cut_batches,
+)
 from greptimedb_tpu.storage.wal import Wal
 from greptimedb_tpu.utils import deadline as dl
 
@@ -109,17 +116,49 @@ class ScanExpired(RuntimeError):
     answer."""
 
 
+class _ReadCount(NamedTuple):
+    """What reading one file cost. `in_window`: rows inside the window
+    before the exact tag filter (`rows_prefilter`, `rows_decoded`).
+    `read` and `batches`: the rows a PRUNED read's batches brought in
+    (in_window plus what they dropped outside the window) and how many
+    batches; a whole-file read leaves them 0."""
+
+    in_window: int = 0
+    read: int = 0
+    batches: int = 0
+
+    def plus(self, other: "_ReadCount") -> "_ReadCount":
+        return _ReadCount(*(a + b for a, b in zip(self, other)))
+
+
 #: per-thread scan IO tally: (SST parts fetched through the part cache,
-#: rows decoded from SSTs). The executor reads the difference around a
-#: statement to say what the scan really cost it (the `scan` stage's
-#: rows_decoded, greptimedb_tpu_agg_scan_total's mode); pool workers
-#: decode on other threads, so the request thread counts for them in
-#: _cached_parts
+#: rows decoded from SSTs, then of the pruned reads among them: rows
+#: their batches read, rows they kept, batches). The executor reads the
+#: difference around a statement to say what the scan really cost it
+#: (the `scan` stage's rows_decoded / rows_read / rows_kept / batches,
+#: greptimedb_tpu_agg_scan_total's mode); pool workers decode on other
+#: threads, so the request thread counts for them in _cached_parts
 _SCAN_IO = threading.local()
+_SCAN_IO_FIELDS = ("parts", "rows", "read", "kept", "batches")
 
 
-def scan_io_counters() -> tuple[int, int]:
-    return (getattr(_SCAN_IO, "parts", 0), getattr(_SCAN_IO, "rows", 0))
+def scan_io_counters() -> tuple[int, ...]:
+    return tuple(getattr(_SCAN_IO, f, 0) for f in _SCAN_IO_FIELDS)
+
+
+def scan_io_since(before: tuple[int, ...]) -> dict:
+    """What this thread's scans cost since `before` (an earlier
+    `scan_io_counters()`), under the names a `scan` stage span carries:
+    rows_decoded, and of the pruned reads among them rows_read,
+    rows_kept and batches."""
+    d = [a - b for a, b in zip(scan_io_counters(), before)]
+    return {"rows_decoded": d[1], "rows_read": d[2], "rows_kept": d[3],
+            "batches": d[4]}
+
+
+def _scan_io_add(**by: int) -> None:
+    for f, n in by.items():
+        setattr(_SCAN_IO, f, getattr(_SCAN_IO, f, 0) + n)
 
 
 class _PlanPins:
@@ -858,14 +897,14 @@ class Region:
 
     def _decode_file_part(self, meta: FileMeta, ts_range, names,
                           tag_predicates, plan=None
-                          ) -> tuple[Optional[tuple], int]:
+                          ) -> tuple[Optional[tuple], _ReadCount]:
         """Read+decode one SST into host columns (the per-file body the
         old scan loop ran serially). Returns ((cols, seq, op) or None
-        when pruning/filtering leaves nothing, rows read inside the
-        window before the exact tag filter). A pruned read (a window or
-        tag predicates) goes row group by row group, each cut to the
-        rows it keeps before the next is read (`_decode_groups`); a
-        whole-file read keeps every row, so it reads them at once."""
+        when pruning/filtering leaves nothing, what the read cost). A
+        pruned read (a window or tag predicates) goes batch by batch of
+        the row groups its plan left, each cut to the rows it keeps
+        before the next is read (`_decode_batches`); a whole-file read
+        keeps every row, so it reads them at once."""
         from greptimedb_tpu.utils.metrics import (
             SCAN_DECODE_BYTES,
             SCAN_DECODE_SECONDS,
@@ -878,53 +917,56 @@ class Region:
                         meta, self.schema, ts_range, names,
                         tag_predicates=tag_predicates)
                 if plan is None:
-                    return None, 0
-                pf, groups, cols_proj = plan
-                chunks, rows_read = self._decode_groups(
-                    meta, groups, cols_proj, ts_range, names,
-                    tag_predicates, pf)
+                    return None, _ReadCount()
+                fp, groups, cols_proj = plan
+                chunks, count = self._decode_batches(
+                    meta, fp, cut_batches(groups, fp.group_rows),
+                    cols_proj, ts_range, names, tag_predicates)
                 part = _concat_parts(chunks)
             else:
                 table = self.sst_reader.read(meta, self.schema, ts_range,
                                              names,
                                              tag_predicates=tag_predicates)
                 if table is None or table.num_rows == 0:
-                    return None, 0
+                    return None, _ReadCount()
                 part = self._decode_table_part(table, ts_range, names)
-                rows_read = 0 if part is None else len(part[1])
+                count = _ReadCount(0 if part is None else len(part[1]))
         if part is None:
-            return None, rows_read
+            return None, count
         SCAN_DECODE_BYTES.inc(float(_part_nbytes(part)))
-        return part, rows_read
+        return part, count
 
-    def _decode_groups(self, meta: FileMeta, groups, cols_proj, ts_range,
-                       names, tag_predicates, pf=None
-                       ) -> tuple[list, int]:
-        """The planned row groups of one SST, decoded one at a time and
-        each cut — on the arrow table, before any column is converted —
-        to the rows inside the window whose =/IN tag predicates hold.
-        SSTs sort by (pk, ts), so a row group a point query needs a few
-        hundred rows of holds a million: converting and keeping them
-        all until the file was read made a request's transient a copy
-        of every file its window touched. Whole series keep/drop
-        together, so LWW dedup and tombstones stay intact; the device
-        WHERE still evaluates the predicate exactly. Returns (decoded
-        chunks in group order, rows inside the window before the tag
-        filter); rows and order are those of the whole-file decode."""
+    def _decode_batches(self, meta: FileMeta, fp, batches, cols_proj,
+                        ts_range, names, tag_predicates
+                        ) -> tuple[list, _ReadCount]:
+        """Batches of one SST's planned row groups (`sst.cut_batches`),
+        decoded one at a time and each cut — on the arrow table, before
+        any column is converted — to the rows inside the window whose
+        =/IN tag predicates hold. SSTs sort by (pk, ts), so the groups
+        a point query needs a few hundred rows of hold tens of
+        thousands each: the probe, the mask, the `take` and the
+        conversion run once a batch, whatever it keeps, and a read
+        never holds more than a batch of rows it will drop. Whole
+        series keep/drop together, so LWW dedup and tombstones stay
+        intact; the device WHERE still evaluates the predicate exactly.
+        Returns (decoded chunks in batch order, the read's cost); rows
+        and order are those of the whole-file decode."""
         ts_name = self.schema.time_index.name
         probe = [ts_name] if ts_range is not None else []
         probe += [t for t in (tag_predicates or {})
                   if t in names and t != ts_name]
         chunks: list = []
-        rows_read = 0
-        for table in self.sst_reader.iter_groups(meta, groups, cols_proj,
-                                                 pf):
+        in_window = rows_read = n_batches = 0
+        for table in self.sst_reader.iter_batches(meta, fp, batches,
+                                                  cols_proj):
+            n_batches += 1
+            rows_read += table.num_rows
             if table.num_rows == 0:
                 continue
             keep = None
             if probe:
                 # the time index rides along: it gives a column the
-                # file lacks (backfilled) the group's row count
+                # file lacks (backfilled) the batch's row count
                 have = [n for n in dict.fromkeys([ts_name] + probe)
                         if n in table.column_names]
                 pre = self._decode_sst(table.select(have), probe)
@@ -933,21 +975,20 @@ class Region:
                     keep = (tsv >= ts_range[0]) & (tsv < ts_range[1])
                     if not keep.any():
                         continue
-                in_window = table.num_rows if keep is None \
+                in_window += table.num_rows if keep is None \
                     else int(keep.sum())
                 tag_keep = self._tag_inset_mask(tag_predicates, pre) \
                     if tag_predicates else None
                 if tag_keep is not None:
                     keep = tag_keep if keep is None else keep & tag_keep
             else:
-                in_window = table.num_rows
-            rows_read += in_window
+                in_window += table.num_rows
             if keep is not None and not keep.all():
                 table = table.take(np.flatnonzero(keep))
             part = self._decode_table_part(table, ts_range, names)
             if part is not None:
                 chunks.append(part)
-        return chunks, rows_read
+        return chunks, _ReadCount(in_window, rows_read, n_batches)
 
     def _decode_table_part(self, table, ts_range, names) -> Optional[tuple]:
         """Arrow table -> (cols, seq, op) with the exact ts row filter —
@@ -983,14 +1024,14 @@ class Region:
 
     def _decode_file_part_split(self, meta: FileMeta, ts_range, names,
                                 tag_predicates, threads: int
-                                ) -> tuple[Optional[tuple], int, int]:
-        """One SST decoded by SEVERAL workers: the surviving row groups
-        split into contiguous runs, each run read through its own
-        parquet handle + decoded on the shared pool, reassembled in
-        group order — byte-for-byte the single-worker result (ISSUE 5
-        carry-over: one huge file used to serialize the decode stage).
-        Returns (part or None, rows read as `_decode_file_part` counts
-        them, workers observed)."""
+                                ) -> tuple[Optional[tuple], _ReadCount, int]:
+        """One SST decoded by SEVERAL workers: the batches of its
+        surviving row groups split into contiguous runs, each run read
+        through its own parquet handle + decoded on the shared pool,
+        reassembled in order — byte-for-byte the single-worker result
+        (ISSUE 5 carry-over: one huge file used to serialize the decode
+        stage). Returns (part or None, the read's cost as
+        `_decode_file_part` counts it, workers observed)."""
         from greptimedb_tpu.storage import scan_pool
         from greptimedb_tpu.utils.metrics import (
             SCAN_DECODE_BYTES,
@@ -1001,61 +1042,56 @@ class Region:
                                            names,
                                            tag_predicates=tag_predicates)
         pruned = ts_range is not None or bool(tag_predicates)
-        k = 0 if plan is None else min(threads, len(plan[1]))
+        batches = [] if plan is None \
+            else cut_batches(plan[1], plan[0].group_rows)
+        k = min(threads, len(batches))
         if k <= 1:
-            # nothing to split (pruned empty / one row group): the
-            # classic whole-file path, so read()-level test spies and
-            # fault seams see exactly the pre-split behavior
+            # nothing to split (pruned empty / one batch): the classic
+            # whole-file path, so read()-level test spies and fault
+            # seams see exactly the pre-split behavior
             if plan is None and pruned:
-                return None, 0, 1
+                return None, _ReadCount(), 1
             return (*self._decode_file_part(
                 meta, ts_range, names, tag_predicates,
                 plan if pruned else None), 1)
-        pf0, groups, cols_proj = plan
+        fp, _groups, cols_proj = plan
         with SCAN_DECODE_SECONDS.time():
             # contiguous runs preserve row order under reassembly
-            bounds = [len(groups) * i // k for i in range(k + 1)]
-            runs = [groups[bounds[i]:bounds[i + 1]] for i in range(k)]
+            bounds = [len(batches) * i // k for i in range(k + 1)]
+            runs = [batches[bounds[i]:bounds[i + 1]] for i in range(k)]
             pool = scan_pool.get(k)
             seen: set = set()
 
-            def work(run, pf=None):
-                # the planning handle already parsed the footer —
-                # exactly ONE worker may reuse it (pyarrow readers
-                # are not safe for concurrent reads on one handle)
+            def work(run):
                 seen.add(threading.get_ident())
                 if pruned:
-                    return self._decode_groups(
-                        meta, run, cols_proj, ts_range, names,
-                        tag_predicates, pf)
-                if pf is not None:
-                    table = pf.read_row_groups(list(run),
-                                               columns=cols_proj)
-                else:
-                    table = self.sst_reader.read_groups(meta, run,
-                                                        cols_proj)
+                    return self._decode_batches(
+                        meta, fp, run, cols_proj, ts_range, names,
+                        tag_predicates)
+                table = self.sst_reader.open(
+                    meta.file_id, fp).read_row_groups(
+                        [g for batch in run for g in batch],
+                        columns=cols_proj)
                 if table.num_rows == 0:
-                    return [], 0
+                    return [], _ReadCount()
                 part = self._decode_table_part(table, ts_range, names)
-                return ([], 0) if part is None else ([part], len(part[1]))
+                return ([], _ReadCount()) if part is None \
+                    else ([part], _ReadCount(len(part[1])))
 
             from greptimedb_tpu.utils import tracing
 
-            live_runs = [run for run in runs if run]
             run_one = tracing.propagate(work)
             # scan_pool.submit re-adopts the query's CancelToken in the
             # worker: queued units for a dead query unwind typed
-            futs = [scan_pool.submit(pool, run_one, run,
-                                     pf0 if i == 0 else None)
-                    for i, run in enumerate(live_runs)]
+            futs = [scan_pool.submit(pool, run_one, run) for run in runs]
             chunks: list = []
-            rows_read = 0
+            count = _ReadCount()
             first_err = None
             for f in futs:
                 try:
                     got, n = dl.wait_future(f, "scan gather")
                     chunks.extend(got)
-                    rows_read += n
+                    count = count.plus(n)
                 except BaseException as e:  # noqa: BLE001 — re-raised below
                     if first_err is None:
                         first_err = e
@@ -1063,17 +1099,17 @@ class Region:
                 raise first_err
             part = _concat_parts(chunks)
         if part is None:
-            return None, rows_read, max(1, len(seen))
+            return None, count, max(1, len(seen))
         SCAN_DECODE_BYTES.inc(float(_part_nbytes(part)))
-        return part, rows_read, max(1, len(seen))
+        return part, count, max(1, len(seen))
 
     def _decode_parts(self, metas, ts_range, names,
                       tag_predicates) -> tuple[list, int]:
         """Decode several SSTs, fanning across the shared per-datanode
-        pool (storage/scan_pool.py). Returns ((part, rows read) pairs
+        pool (storage/scan_pool.py). Returns ((part, _ReadCount) pairs
         in `metas` order, distinct workers observed). decode_threads=1
         decodes inline, byte-for-byte the sequential path; a SINGLE
-        multi-row-group file splits its row groups across the pool
+        file of several batches splits them across the pool
         instead of serializing on one worker (order-preserving
         reassembly).
 
@@ -1091,9 +1127,9 @@ class Region:
         threads = scan_pool.resolve(self.decode_threads,
                                     max(len(metas), 1_000_000))
         if len(metas) == 1 and threads > 1:
-            part, rows_read, workers = self._decode_file_part_split(
+            part, count, workers = self._decode_file_part_split(
                 metas[0], ts_range, names, tag_predicates, threads)
-            return [(part, rows_read)], workers
+            return [(part, count)], workers
         threads = min(threads, len(metas))
         if threads <= 1 or len(metas) <= 1:
             return ([self._decode_file_part(m, ts_range, names,
@@ -1120,7 +1156,7 @@ class Region:
             try:
                 results.append(dl.wait_future(f, "decode gather"))
             except BaseException as e:  # noqa: BLE001 — re-raised below
-                results.append((None, 0))
+                results.append((None, _ReadCount()))
                 if first_err is None:
                     first_err = e
         if first_err is not None:
@@ -1154,7 +1190,10 @@ class Region:
         (compaction reads its soon-to-be-removed inputs once — caching
         them would evict warm query parts for zero retained value).
         Returns (list of _PartEntry aligned with file_list, stats)."""
-        from greptimedb_tpu.utils.metrics import SCAN_PART_CACHE_EVENTS
+        from greptimedb_tpu.utils.metrics import (
+            SCAN_PART_CACHE_EVENTS,
+            SCAN_ROWS,
+        )
 
         keys = [(m.file_id, ts_range, tuple(names), pred_key)
                 for m in file_list]
@@ -1176,8 +1215,9 @@ class Region:
                 tag_predicates)
             whole = ts_range is None and not tag_predicates
             with self._lock:
-                for i, (part, rows_read) in zip(missing, decoded):
-                    ent = _PartEntry(part, _part_nbytes(part), rows_read)
+                for i, (part, count) in zip(missing, decoded):
+                    ent = _PartEntry(part, _part_nbytes(part),
+                                     count.in_window)
                     parts[i] = ent
                     # a scan races compaction/expiry: its pinned files
                     # may have been removed (and invalidated) while it
@@ -1190,9 +1230,18 @@ class Region:
                     if whole and part is not None:
                         self._file_deletes[file_list[i].file_id] = \
                             bool((part[2] != OP_PUT).any())
-            _SCAN_IO.rows = getattr(_SCAN_IO, "rows", 0) + sum(
-                rows_read for _part, rows_read in decoded)
-        _SCAN_IO.parts = getattr(_SCAN_IO, "parts", 0) + len(file_list)
+            total = _ReadCount()
+            kept = 0
+            for part, count in decoded:
+                total = total.plus(count)
+                if count.batches and part is not None:
+                    kept += len(part[1])
+            _scan_io_add(rows=total.in_window, read=total.read, kept=kept,
+                         batches=total.batches)
+            if total.batches:
+                SCAN_ROWS.inc(float(total.read), kind="read")
+                SCAN_ROWS.inc(float(kept), kind="kept")
+        _scan_io_add(parts=len(file_list))
         from greptimedb_tpu.utils import ledger
 
         if hits:
@@ -1744,9 +1793,9 @@ class Region:
             # SELECTED series. Whole series keep/drop together, so LWW
             # dedup and tombstones stay intact; the device WHERE still
             # evaluates the predicate exactly (incl. NULL semantics).
-            # An SST part was cut when it was decoded, row group by row
-            # group (_decode_groups; an emptied part keeps its place as
-            # a zero-row segment); the memtable slice is cut here
+            # An SST part was cut when it was decoded, batch by batch
+            # (_decode_batches; an emptied part keeps its place as a
+            # zero-row segment); the memtable slice is cut here
             mem = self._inset_filter(tag_predicates, mem)
             if not len(mem[1]):
                 mem = None
@@ -2090,7 +2139,6 @@ class Region:
         ts_range: Optional[tuple[int, int]] = None,
         projection: Optional[Sequence[str]] = None,
         tag_predicates: Optional[dict[str, set]] = None,
-        groups_per_chunk: int = 8,
     ) -> Optional["ScanStream"]:
         """Lazy bounded-memory scan (see ScanStream). Returns None when the
         time range prunes everything."""
@@ -2135,15 +2183,13 @@ class Region:
                     for meta in files:
                         for table in self.sst_reader.iter_chunks(
                                 meta, self.schema, ts_range, names,
-                                tag_predicates=tag_predicates,
-                                groups_per_chunk=groups_per_chunk):
+                                tag_predicates=tag_predicates):
                             if table.num_rows:
                                 yield (self._decode_sst(table, names),
                                        table.num_rows)
                 else:
                     yield from self._stream_files_parallel(
-                        files, ts_range, names, tag_predicates,
-                        groups_per_chunk, workers)
+                        files, ts_range, names, tag_predicates, workers)
                 if mem is not None and len(mem[1]):
                     yield {n: mem[0][n] for n in names}, len(mem[1])
             finally:
@@ -2166,8 +2212,7 @@ class Region:
         )
 
     def _stream_files_parallel(self, files, ts_range, names,
-                               tag_predicates, groups_per_chunk,
-                               workers: int):
+                               tag_predicates, workers: int):
         """Streaming-scan decode pipeline: up to `workers` files decode
         concurrently, each producing into its own small bounded queue;
         the consumer drains queues in file order, so chunks come out in
@@ -2199,8 +2244,7 @@ class Region:
             try:
                 for table in self.sst_reader.iter_chunks(
                         meta, self.schema, ts_range, names,
-                        tag_predicates=tag_predicates,
-                        groups_per_chunk=groups_per_chunk):
+                        tag_predicates=tag_predicates):
                     if stop.is_set():
                         return
                     if not table.num_rows:

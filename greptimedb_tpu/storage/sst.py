@@ -6,10 +6,19 @@ reader row-group pruning at reader.rs:335-447): rows sorted by
 (PUT/DELETE) ride alongside; region schema JSON is stored in the parquet
 key-value metadata (analog of PARQUET_METADATA_KEY, sst/parquet.rs:37).
 
-TPU-first deltas from the reference: tags are stored as per-column parquet
+TPU-first delta from the reference: tags are stored as per-column parquet
 dictionary columns (not one memcomparable key blob) because the kernel ABI
-wants dense per-tag codes; row groups default to 1M rows so a single row
-group fills a device block.
+wants dense per-tag codes.
+
+Row groups hold 32,768 rows (the reference writes 102,400; both were
+measured, PERF.md PR 34): files sort by (pk, ts), so a pruned read decodes
+the groups the inverted index selects and its cost follows the rows
+selected. What a read needs from a file's footer — group row counts,
+per-group time-index min / max — is parsed once per file (`_FilePlan`, kept
+until the file is deleted), and the surviving groups are read in contiguous
+batches of at most `READ_BATCH_ROWS` rows, so the fixed cost of a read is
+per file and per batch, not per row group. A file's layout is read from its
+own footer: files of any group size keep reading.
 """
 
 from __future__ import annotations
@@ -34,7 +43,11 @@ OP_COL = "__op_type"
 METADATA_KEY = b"greptimedb_tpu:region_schema"
 # sst format version stamp; files without it predate versioning (= v1)
 FORMAT_KEY = b"greptimedb_tpu:sst_format"
-DEFAULT_ROW_GROUP = 1 << 20
+DEFAULT_ROW_GROUP = 32_768  # mito2 writes 102,400: PERF.md, PR 34
+#: most rows one read of a file's row groups brings in at once: a
+#: pruned read holds no more rows it will drop, a stream no larger chunk
+READ_BATCH_ROWS = 1 << 20
+_I64 = np.iinfo(np.int64)
 
 
 @dataclass
@@ -192,6 +205,43 @@ class SstWriter:
         )
 
 
+@dataclass
+class _FilePlan:
+    """What every read of one file needs from its footer. A file never
+    changes once written, so this is parsed at the first plan and kept
+    until the file is deleted: a request parses no footer and casts no
+    statistic."""
+
+    metadata: pq.FileMetaData
+    schema_arrow: pa.Schema
+    group_rows: np.ndarray  # int64[groups]
+    # the time index per group, in its storage unit; a group without
+    # statistics spans every instant, so no window prunes it
+    ts_min: np.ndarray  # int64[groups]
+    ts_max: np.ndarray  # int64[groups]
+
+
+def cut_batches(groups: Sequence[int],
+                group_rows: np.ndarray) -> list[list[int]]:
+    """A plan's surviving row groups, in order, cut into contiguous
+    batches of at most READ_BATCH_ROWS rows each; a group larger than
+    that is a batch of its own. One read and one decode a batch: every
+    read path bounds what it holds at once with this."""
+    out: list[list[int]] = []
+    cur: list[int] = []
+    held = 0
+    for g in groups:
+        rows = int(group_rows[g])
+        if cur and held + rows > READ_BATCH_ROWS:
+            out.append(cur)
+            cur, held = [], 0
+        cur.append(int(g))
+        held += rows
+    if cur:
+        out.append(cur)
+    return out
+
+
 class SstReader:
     def __init__(self, sst_dir: str, store=None):
         from greptimedb_tpu.storage.index import IndexApplier
@@ -199,9 +249,49 @@ class SstReader:
         self.sst_dir = sst_dir
         self.store = default_store(store)
         self.index_applier = IndexApplier(sst_dir, self.store)
+        # beside the applier's parsed indexes, and for as long: one
+        # _FilePlan per file asked about, until `delete`
+        self._plans: dict[str, _FilePlan] = {}
 
     def path(self, file_id: str) -> str:
         return os.path.join(self.sst_dir, f"{file_id}.parquet")
+
+    def file_plan(self, file_id: str, ts_name: str) -> _FilePlan:
+        """The file's kept footer, parsed on first use."""
+        fp = self._plans.get(file_id)
+        if fp is not None:
+            return fp
+        pf = pq.ParquetFile(self.store.open_input(self.path(file_id)))
+        _check_sst_format(pf, file_id)
+        md, schema_arrow = pf.metadata, pf.schema_arrow
+        n = md.num_row_groups
+        group_rows = np.empty(n, dtype=np.int64)
+        ts_min = np.full(n, _I64.min, dtype=np.int64)
+        ts_max = np.full(n, _I64.max, dtype=np.int64)
+        ts_idx = schema_arrow.get_field_index(ts_name)
+        ts_type = schema_arrow.field(ts_idx).type if ts_idx >= 0 else None
+        for g in range(n):
+            rg = md.row_group(g)
+            group_rows[g] = rg.num_rows
+            stats = rg.column(ts_idx).statistics if ts_idx >= 0 else None
+            if stats is None or not stats.has_min_max:
+                continue
+            try:
+                lo, hi = (_ts_stat(stats.min, ts_type),
+                          _ts_stat(stats.max, ts_type))
+            except (OverflowError, ValueError):
+                continue  # an instant no datetime holds: never pruned
+            ts_min[g], ts_max[g] = lo, hi
+        fp = _FilePlan(md, schema_arrow, group_rows, ts_min, ts_max)
+        self._plans[file_id] = fp
+        return fp
+
+    def open(self, file_id: str, fp: _FilePlan) -> pq.ParquetFile:
+        """A handle on the file that parses no footer. Concurrent
+        workers each open their own (pyarrow readers are not safe for
+        concurrent reads on one handle)."""
+        return pq.ParquetFile(self.store.open_input(self.path(file_id)),
+                              metadata=fp.metadata)
 
     def plan_groups(
         self,
@@ -211,36 +301,38 @@ class SstReader:
         projection: Optional[Sequence[str]] = None,
         tag_predicates: Optional[dict[str, set]] = None,
     ) -> Optional[tuple]:
-        """Pruning phase of `read`, factored out so the scan layer can
-        split the surviving row groups across decode workers (one huge
-        SST no longer serializes the parallel decode stage). Returns
-        (ParquetFile, row-group indices, projected column names) or
-        None when pruning rules the whole file out."""
+        """Pruning phase of every read: the row groups whose time range
+        meets the window (one comparison over the kept per-group
+        statistics, reference reader.rs:427-447) and whose segments the
+        inverted index selects (reader.rs:335-425). Returns (the file's
+        kept footer, surviving row-group indices in order, projected
+        column names) or None when pruning rules the whole file out."""
         if ts_range is not None and (meta.ts_max < ts_range[0] or meta.ts_min >= ts_range[1]):
             return None
-        # inverted-index pruning first: may rule the file out with no
-        # parquet metadata read at all (reference reader.rs:335-425)
-        idx_groups = None
+        # the index first: it may rule the file out with no parquet
+        # metadata read at all
+        sel = None
         if tag_predicates:
-            idx_groups = self.index_applier.apply(meta.file_id, tag_predicates)
-            if idx_groups == []:
+            sel = self.index_applier.select(meta.file_id, tag_predicates)
+            if sel is not None and sel.is_empty:
                 return None
-        pf = pq.ParquetFile(self.store.open_input(self.path(meta.file_id)))
-        _check_sst_format(pf, meta.file_id)
         ts_name = schema.time_index.name
-        groups = self._prune_row_groups(pf, ts_name, ts_range)
-        if idx_groups is not None:
-            allowed = set(idx_groups)
-            groups = [g for g in groups if g in allowed]
+        fp = self.file_plan(meta.file_id, ts_name)
+        keep = np.ones(len(fp.group_rows), dtype=bool)
+        if ts_range is not None:
+            keep &= (fp.ts_max >= ts_range[0]) & (fp.ts_min < ts_range[1])
+        if sel is not None and not sel.all_set:
+            keep &= sel.group_mask(fp.group_rows)
+        groups = np.flatnonzero(keep).tolist()
         if not groups:
             return None
         cols = None
         if projection is not None:
             cols = list(dict.fromkeys(list(projection) + [ts_name, SEQ_COL, OP_COL]))
             # tolerate schema evolution: drop columns the file predates
-            avail = set(pf.schema_arrow.names)
+            avail = set(fp.schema_arrow.names)
             cols = [c for c in cols if c in avail]
-        return pf, groups, cols
+        return fp, groups, cols
 
     def read(
         self,
@@ -250,37 +342,27 @@ class SstReader:
         projection: Optional[Sequence[str]] = None,
         tag_predicates: Optional[dict[str, set]] = None,
     ) -> Optional[pa.Table]:
-        """Read an SST with row-group pruning on the time index (reference
-        reader.rs:427-447 min/max stats pruning). Returns None if fully
-        pruned. Internal columns are always materialized."""
+        """Read an SST's surviving row groups at once. Returns None if
+        fully pruned. Internal columns are always materialized."""
         plan = self.plan_groups(meta, schema, ts_range, projection,
                                 tag_predicates)
         if plan is None:
             return None
-        pf, groups, cols = plan
-        return pf.read_row_groups(groups, columns=cols)
+        fp, groups, cols = plan
+        return self.open(meta.file_id, fp).read_row_groups(groups,
+                                                           columns=cols)
 
-    def read_groups(self, meta: FileMeta, groups: Sequence[int],
-                    columns: Optional[Sequence[str]]) -> pa.Table:
-        """Read specific row groups through a FRESH ParquetFile handle —
-        concurrent workers each open their own (pyarrow readers are not
-        safe for concurrent reads on one handle). `groups`/`columns`
-        come from a prior `plan_groups` call."""
-        pf = pq.ParquetFile(self.store.open_input(self.path(meta.file_id)))
-        return pf.read_row_groups(list(groups), columns=columns)
-
-    def iter_groups(self, meta: FileMeta, groups: Sequence[int],
-                    columns: Optional[Sequence[str]], pf=None):
-        """Yield the planned row groups ONE table at a time through one
-        handle (`pf` from `plan_groups`, else a fresh one: a handle
-        serves one reader at a time) — the caller cuts each to the rows
-        it keeps before the next is read, so a pruned read never holds
-        more than a row group of rows it will drop."""
-        if pf is None:
-            pf = pq.ParquetFile(
-                self.store.open_input(self.path(meta.file_id)))
-        for g in groups:
-            yield pf.read_row_group(g, columns=columns)
+    def iter_batches(self, meta: FileMeta, fp: _FilePlan,
+                     batches: Sequence[Sequence[int]],
+                     columns: Optional[Sequence[str]]):
+        """Yield one table a batch of row groups (`cut_batches` of a
+        `plan_groups` result, or a worker's share of them) through one
+        handle of its own — the caller cuts each to the rows it keeps
+        before the next is read, so a read never holds more than
+        READ_BATCH_ROWS rows it will drop."""
+        pf = self.open(meta.file_id, fp)
+        for batch in batches:
+            yield pf.read_row_groups(list(batch), columns=columns)
 
     def iter_chunks(
         self,
@@ -289,66 +371,31 @@ class SstReader:
         ts_range: Optional[tuple[int, int]] = None,
         projection: Optional[Sequence[str]] = None,
         tag_predicates: Optional[dict[str, set]] = None,
-        groups_per_chunk: int = 8,
     ):
         """Lazily yield row-group batches of an SST (reference
         sst/parquet/row_group.rs lazy InMemoryRowGroup + reader.rs
         FileRange streaming) — bounded memory for beyond-RAM scans. Same
-        pruning as `read`; each yield decodes only `groups_per_chunk`
-        row groups."""
-        if ts_range is not None and (meta.ts_max < ts_range[0]
-                                     or meta.ts_min >= ts_range[1]):
+        pruning as `read`; each yield decodes one batch of at most
+        READ_BATCH_ROWS rows."""
+        plan = self.plan_groups(meta, schema, ts_range, projection,
+                                tag_predicates)
+        if plan is None:
             return
-        idx_groups = None
-        if tag_predicates:
-            idx_groups = self.index_applier.apply(meta.file_id, tag_predicates)
-            if idx_groups == []:
-                return
-        pf = pq.ParquetFile(self.store.open_input(self.path(meta.file_id)))
-        _check_sst_format(pf, meta.file_id)
-        ts_name = schema.time_index.name
-        groups = self._prune_row_groups(pf, ts_name, ts_range)
-        if idx_groups is not None:
-            allowed = set(idx_groups)
-            groups = [g for g in groups if g in allowed]
-        if not groups:
-            return
-        cols = None
-        if projection is not None:
-            cols = list(dict.fromkeys(list(projection) + [ts_name, SEQ_COL, OP_COL]))
-            avail = set(pf.schema_arrow.names)
-            cols = [c for c in cols if c in avail]
-        for i in range(0, len(groups), groups_per_chunk):
-            yield pf.read_row_groups(groups[i:i + groups_per_chunk],
-                                     columns=cols)
-
-    def _prune_row_groups(
-        self, pf: pq.ParquetFile, ts_name: str, ts_range: Optional[tuple[int, int]]
-    ) -> list[int]:
-        n = pf.metadata.num_row_groups
-        if ts_range is None:
-            return list(range(n))
-        ts_idx = pf.schema_arrow.get_field_index(ts_name)
-        ts_type = pf.schema_arrow.field(ts_idx).type
-        keep = []
-        for g in range(n):
-            col = pf.metadata.row_group(g).column(ts_idx)
-            stats = col.statistics
-            if stats is None or not stats.has_min_max:
-                keep.append(g)
-                continue
-            lo, hi = _ts_stat(stats.min, ts_type), _ts_stat(stats.max, ts_type)
-            if hi < ts_range[0] or lo >= ts_range[1]:
-                continue
-            keep.append(g)
-        return keep
+        fp, groups, cols = plan
+        yield from self.iter_batches(
+            meta, fp, cut_batches(groups, fp.group_rows), cols)
 
     def delete(self, file_id: str) -> None:
         self.store.delete(self.path(file_id))
         from greptimedb_tpu.storage.index import InvertedIndexWriter
 
         InvertedIndexWriter(self.sst_dir, self.store).delete(file_id)
+        self.invalidate(file_id)
+
+    def invalidate(self, file_id: str) -> None:
+        """Forget what is kept of a file (its parsed index, its plan)."""
         self.index_applier.invalidate(file_id)
+        self._plans.pop(file_id, None)
 
 
 def _check_sst_format(pf: pq.ParquetFile, file_id: str) -> None:

@@ -682,6 +682,13 @@ SCAN_PART_CACHE_EVENTS = REGISTRY.counter(
 SCAN_DECODE_BYTES = REGISTRY.counter(
     "greptimedb_tpu_scan_decode_bytes_total",
     "Host bytes materialized by SST scan decode (part-cache misses)")
+SCAN_ROWS = REGISTRY.counter(
+    "greptimedb_tpu_scan_rows_total",
+    "Rows through pruned SST reads (a window or tag predicates; "
+    "part-cache misses only) by kind: read (rows of the row-group "
+    "batches the read decoded: what the time statistics and the "
+    "inverted index left) and kept (rows the read returned, inside the "
+    "window and under its =/IN tag predicates)")
 SCAN_PIPELINE_OVERLAP = REGISTRY.gauge(
     "greptimedb_tpu_scan_pipeline_overlap",
     "Fraction of prefetched device block uploads already built when the "

@@ -55,6 +55,26 @@ def test_open_r3_dir_and_read(old_dir):
         engine.close()
 
 
+def test_pruned_read_of_r3_file_plans_from_its_own_footer(old_dir):
+    """A file written before row groups shrank (and before the read
+    plan was kept per file) goes through the same plan: its layout is
+    whatever its own footer says."""
+    engine, qe = _open(old_dir)
+    try:
+        r = qe.execute_one(
+            "SELECT host, usage FROM cpu WHERE host = 'a' AND ts >= 0 "
+            "AND ts < 10000000 ORDER BY ts")
+        assert r.rows() == [["a", 1.5], ["a", 2.5], ["a", 3.5]]
+        region = next(iter(engine.regions.values()))
+        (fid,) = region.files
+        fp = region.sst_reader.file_plan(fid, "ts")
+        assert fp.group_rows.tolist() == [region.files[fid].num_rows]
+        assert (fp.ts_min[0], fp.ts_max[0]) == (
+            region.files[fid].ts_min, region.files[fid].ts_max)
+    finally:
+        engine.close()
+
+
 def test_write_new_into_r3_dir(old_dir):
     engine, qe = _open(old_dir)
     try:

@@ -17,7 +17,7 @@ from greptimedb_tpu.datatypes import (
     Schema,
     SemanticType,
 )
-from greptimedb_tpu.storage import RegionEngine
+from greptimedb_tpu.storage import RegionEngine, sst
 from greptimedb_tpu.storage.engine import EngineConfig
 
 
@@ -133,6 +133,8 @@ class TestParallelDecode:
         engine.create_region(1, schema3())
         region = engine.region(1)
         region.sst_writer.row_group_size = 100  # 1 flush -> 9 groups
+        # ... that a bound of 200 rows a batch cuts into 5 batches
+        monkeypatch.setattr(sst, "READ_BATCH_ROWS", 200)
         fill_files(engine, 1, n_files=1, rows_per_file=900)
         monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", "1")
         clear_scan_caches(region)
@@ -261,22 +263,24 @@ class TestPrunedReadByRowGroup:
                               np.asarray(full.columns["v"])[keep])
         assert np.array_equal(got.seq, full.seq[keep])
 
-    def test_one_row_group_is_read_at_a_time_and_parts_cache_cut(
+    def test_one_batch_is_read_at_a_time_and_parts_cache_cut(
             self, engine, monkeypatch):
         region = self.region_of(engine)
         monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", "1")
+        # a bound of 100 rows: a batch is one of the 100-row groups
+        monkeypatch.setattr(sst, "READ_BATCH_ROWS", 100)
         full = engine.scan(1).materialize()
         ts_range, hosts = (1_000_000, 2_000_000), {"h3"}
         keep, _ = self.expected(full, ts_range, hosts)
         seen = []
-        orig = region.sst_reader.iter_groups
+        orig = region.sst_reader.iter_batches
 
         def spy(*a, **k):
             for table in orig(*a, **k):
                 seen.append(table.num_rows)
                 yield table
 
-        monkeypatch.setattr(region.sst_reader, "iter_groups", spy)
+        monkeypatch.setattr(region.sst_reader, "iter_batches", spy)
         clear_scan_caches(region)
         got = engine.scan(1, ts_range=ts_range,
                           tag_predicates={"host": hosts})
@@ -301,6 +305,212 @@ class TestPrunedReadByRowGroup:
                             tag_predicates={"host": hosts})
         assert not seen and scans_equal(got, again)
         assert again.stats["rows_prefilter"] == 200
+
+    @pytest.mark.parametrize("bound,batches", [(1 << 20, [200]),
+                                               (100, [100, 100])])
+    def test_scan_rows_total_counts_what_batches_read_and_kept(
+            self, engine, monkeypatch, bound, batches):
+        """`scan_rows_total{kind}`: `read` = the rows of the batches a
+        pruned read decoded, `kept` = the rows it returned; both equal
+        the oracle's, whole reads and cache hits count nothing, and the
+        `scan` stage's tally says the same."""
+        from greptimedb_tpu.storage.region import (
+            scan_io_counters,
+            scan_io_since,
+        )
+        from greptimedb_tpu.utils.metrics import SCAN_ROWS
+
+        region = self.region_of(engine)
+        monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", "1")
+        monkeypatch.setattr(sst, "READ_BATCH_ROWS", bound)
+        read0, kept0 = (SCAN_ROWS.get(kind=k) for k in ("read", "kept"))
+        full = engine.scan(1).materialize()  # a whole read: not counted
+        assert SCAN_ROWS.get(kind="read") == read0
+        ts_range, hosts = (1_000_000, 2_000_000), {"h3"}
+        keep, _ = self.expected(full, ts_range, hosts)
+        seen = []
+        orig = region.sst_reader.iter_batches
+
+        def spy(*a, **k):
+            for table in orig(*a, **k):
+                seen.append(table.num_rows)
+                yield table
+
+        monkeypatch.setattr(region.sst_reader, "iter_batches", spy)
+        clear_scan_caches(region)
+        before = scan_io_counters()
+        got = engine.scan(1, ts_range=ts_range,
+                          tag_predicates={"host": hosts})
+        # one file meets the window; the index leaves the two groups
+        # that hold h3
+        assert seen == batches
+        read = SCAN_ROWS.get(kind="read") - read0
+        kept = SCAN_ROWS.get(kind="kept") - kept0
+        assert read == sum(seen) == 200
+        assert kept == got.num_rows == int(keep.sum()) == 150
+        assert read >= kept
+        assert scan_io_since(before) == {
+            "rows_decoded": 200, "rows_read": 200, "rows_kept": 150,
+            "batches": len(batches)}
+        # served again from the part cache: nothing read, nothing counted
+        with region._lock:
+            region._scan_cache.clear()
+            region._scan_cache_sizes.clear()
+            region._scan_cache_bytes = 0
+        engine.scan(1, ts_range=ts_range, tag_predicates={"host": hosts})
+        assert SCAN_ROWS.get(kind="read") - read0 == 200
+
+
+class TestBatchedReadAcrossLayouts:
+    """Files of today's group size (sst.DEFAULT_ROW_GROUP),
+    files written with 2^20-row groups before it, and a region holding
+    both: a read takes each file's layout from the file's own footer,
+    returns the whole-file decode's rows in its order, never asks
+    parquet for more than READ_BATCH_ROWS rows at once, and parses a
+    file's footer once."""
+
+    HOSTS = 5
+    POINTS = 260_000  # a file: 1.3M rows, 2 groups of 2^20 or many small
+
+    @classmethod
+    def region_of(cls, engine, layout):
+        engine.create_region(1, schema3())
+        region = engine.region(1)
+        sizes = {"new": [sst.DEFAULT_ROW_GROUP],
+                 "old": [1 << 20],
+                 "both": [1 << 20, sst.DEFAULT_ROW_GROUP]}[layout]
+        schema = region.schema
+        n = cls.HOSTS * cls.POINTS
+        for f, size in enumerate(sizes):
+            region.sst_writer.row_group_size = size
+            ts = f * 10_000_000 + np.repeat(
+                np.arange(cls.POINTS, dtype=np.int64) * 10, cls.HOSTS)
+            engine.put(1, RecordBatch(schema, {
+                "ts": ts,
+                "host": DictVector(
+                    np.tile(np.arange(cls.HOSTS, dtype=np.int32),
+                            cls.POINTS),
+                    np.asarray([f"h{i}" for i in range(cls.HOSTS)],
+                               dtype=object)),
+                "v": np.arange(n, dtype=np.float64) + f,
+            }))
+            engine.flush(1)
+        # one far row: no window below is half the region's span, so
+        # none is widened to a whole scan
+        engine.put(1, make_batch(schema, ["h0"], [100_000_000], [0.5]))
+        engine.flush(1)
+        groups = [-(-n // size) for size in sizes] + [1]
+        assert [region.sst_reader.file_plan(m.file_id, "ts").group_rows.size
+                for m in region.files.values()] == groups
+        return region
+
+    @staticmethod
+    def spy_reads(monkeypatch):
+        """Rows every parquet read asks for, and every footer parse."""
+        import pyarrow.parquet as pq
+
+        asked, parsed = [], []
+        orig_read = pq.ParquetFile.read_row_groups
+        orig_init = pq.ParquetFile.__init__
+
+        def read_row_groups(self, row_groups, *a, **k):
+            asked.append(sum(self.metadata.row_group(g).num_rows
+                             for g in row_groups))
+            return orig_read(self, row_groups, *a, **k)
+
+        def init(self, source, *a, metadata=None, **k):
+            if metadata is None:
+                parsed.append(1)
+            return orig_init(self, source, *a, metadata=metadata, **k)
+
+        monkeypatch.setattr(pq.ParquetFile, "read_row_groups",
+                            read_row_groups)
+        monkeypatch.setattr(pq.ParquetFile, "__init__", init)
+        return asked, parsed
+
+    @pytest.mark.parametrize("threads", ["1", "4"])
+    @pytest.mark.parametrize("layout", ["new", "old", "both"])
+    def test_rows_order_and_bound_hold_for_every_layout(
+            self, engine, monkeypatch, layout, threads):
+        region = self.region_of(engine, layout)
+        monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", threads)
+        full = engine.scan(1).materialize()
+        asked, _parsed = self.spy_reads(monkeypatch)
+        for ts_range, hosts in [
+                ((1_000, 2_500_000), None),        # window-only: > 2^20 rows
+                (None, {"h1", "h3"}),              # the index's selection
+                ((10_200_000, 10_900_000), {"h4"}),
+                ((2_000_000, 12_000_000), {"h0", "nobody"})]:
+            keep, _ = TestPrunedReadByRowGroup.expected(full, ts_range,
+                                                        hosts)
+            preds = None if hosts is None else {"host": hosts}
+            clear_scan_caches(region)
+            del asked[:]
+            got = engine.scan(1, ts_range=ts_range, tag_predicates=preds)
+            if not keep.any():
+                assert got is None
+                continue
+            assert asked and max(asked) <= sst.READ_BATCH_ROWS, asked
+            assert got.num_rows == int(keep.sum())
+            for k in ("ts", "host", "v"):
+                assert np.array_equal(
+                    np.asarray(got.columns[k]),
+                    np.asarray(full.columns[k])[keep]), (k, ts_range)
+            assert np.array_equal(got.seq, full.seq[keep])
+
+    @pytest.mark.parametrize("layout", ["new", "old", "both"])
+    def test_stream_chunks_are_batches_of_at_most_the_bound(
+            self, engine, monkeypatch, layout):
+        region = self.region_of(engine, layout)
+        full = engine.scan(1).materialize()
+        asked, _parsed = self.spy_reads(monkeypatch)
+        for threads in ("1", "4"):
+            monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS",
+                               threads)
+            del asked[:]
+            stream = engine.scan_stream(1, ts_range=(0, 200_000_000))
+            chunks = list(stream.chunks())
+            assert sum(n for _c, n in chunks) == full.num_rows
+            assert sorted(asked) == sorted(n for _c, n in chunks)
+            assert max(asked) <= sst.READ_BATCH_ROWS
+            # a file's groups come in as few batches as the bound
+            # allows, not eight groups (or one) at a time: two a large
+            # file, one the far row's
+            assert len(asked) == 2 * len(region.files) - 1
+            assert np.array_equal(
+                np.concatenate([c["v"] for c, _n in chunks]),
+                np.asarray(full.columns["v"]))
+
+    def test_a_second_plan_parses_nothing_and_delete_drops_the_plan(
+            self, engine, monkeypatch):
+        region = self.region_of(engine, "both")
+        reader = region.sst_reader
+        casts = []
+        orig = sst._ts_stat
+        monkeypatch.setattr(
+            sst, "_ts_stat",
+            lambda *a: casts.append(1) or orig(*a))
+        _asked, parsed = self.spy_reads(monkeypatch)
+        reader._plans.clear()
+        first = engine.scan(1, ts_range=(0, 10_005_000),
+                            tag_predicates={"host": {"h2"}})
+        groups = sum(len(fp.group_rows) for fp in reader._plans.values())
+        big = [m.file_id for m in region.files.values() if m.num_rows > 1]
+        assert set(reader._plans) == set(big)
+        # a footer a file, two statistics a group: once
+        assert len(parsed) == 2 and len(casts) == 2 * groups > 4
+        del parsed[:], casts[:]
+        again = engine.scan(1, ts_range=(100, 10_007_000),
+                            tag_predicates={"host": {"h2", "h3"}})
+        stream = engine.scan_stream(1, ts_range=(100, 10_007_000))
+        assert sum(n for _c, n in stream.chunks()) > 0
+        assert first.num_rows == 260_000 + 500
+        assert again.num_rows == 2 * (260_000 - 10) + 2 * 700
+        assert not parsed and not casts
+        # the kept plan lives as long as the file
+        reader.delete(big[0])
+        assert set(reader._plans) == {big[1]}
+        assert big[0] not in reader.index_applier._cache
 
 
 class TestPartCacheMutation:

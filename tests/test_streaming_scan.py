@@ -137,9 +137,13 @@ class TestStreamingScan:
 
 
 class TestScanStreamUnit:
-    def test_chunks_bounded(self, tmp_path):
+    def test_chunks_bounded(self, tmp_path, monkeypatch):
         """The stream yields multiple chunks for a multi-row-group SST and
-        never materializes the whole region at once."""
+        never materializes the whole region at once: a chunk is one
+        batch of row groups, cut by rows (sst.READ_BATCH_ROWS)."""
+        from greptimedb_tpu.storage import sst
+
+        monkeypatch.setattr(sst, "READ_BATCH_ROWS", 3500)
         from greptimedb_tpu.datatypes import (
             ColumnSchema, DataType, DictVector, RecordBatch, Schema,
             SemanticType)
@@ -168,7 +172,8 @@ class TestScanStreamUnit:
         sizes = [nrows for _, nrows in stream.chunks()]
         assert sum(sizes) == n
         assert len(sizes) > 1  # actually chunked
-        assert max(sizes) <= 8 * 1000  # groups_per_chunk * row_group_size
+        # whole groups of 1000 rows under a bound of 3500 rows a batch
+        assert sizes == [3000, 3000, 3000, 1000]
         eng.close()
 
 
