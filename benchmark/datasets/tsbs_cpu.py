@@ -11,6 +11,10 @@ the config file's `assumed` block.
 Everything here is a function of (seed, scale) only. The harness
 process holds one Dataset for the references; the bulk-load helper
 process builds its own from the same seed.
+
+The table can be written to inside a window: `tick(i)` is every host's
+next sample after the loaded span (README, "A writer inside the
+window").
 """
 
 from __future__ import annotations
@@ -37,6 +41,7 @@ class Dataset:
     table = "cpu"
 
     def __init__(self, seed: int, scale: dict):
+        self.seed = int(seed)
         self.hosts = int(scale["hosts"])
         self.hours = int(scale["hours"])
         self.step_ms = int(scale["step_s"]) * 1000
@@ -92,6 +97,15 @@ class Dataset:
                 self.hosts)
             yield p0, p1, ts, {f: v[p0:p1].reshape(-1)
                                for f, v in self.fields.items()}
+
+    def tick(self, i: int) -> tuple:
+        """(ts_ms, {field: float64[hosts]}): every host's sample at
+        t_end_ms + i * step_ms, i >= 0, uniform(0, 100) as the loaded
+        rows, from a stream of the tick's own."""
+        rng = np.random.default_rng([self.seed, 3, int(i)])
+        vals = rng.uniform(0.0, 100.0, (len(FIELDS), self.hosts))
+        return (self.t_end_ms + int(i) * self.step_ms,
+                dict(zip(FIELDS, vals)))
 
     @property
     def series(self) -> int:

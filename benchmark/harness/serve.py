@@ -15,9 +15,12 @@ process that holds the chip can trace it. Commands, one per line,
                         beside the device planes, on their clock
     trace_stop          jax.profiler.stop_trace()
     memory              per-device allocator stats
+    memtable            bytes each open region's memtable holds (the
+                        program exposes no gauge of it; a run with a
+                        writer reads it when its window has closed)
 
 Each is answered by `<control-dir>/<seq>.json`. With `--trace 0` the
-harness sends only `memory`.
+harness sends only `memory` (and `memtable` under a writer).
 """
 
 from __future__ import annotations
@@ -64,6 +67,16 @@ def control_loop(control_dir: str) -> None:
                                     (d.memory_stats() or {}).items()
                                     if isinstance(v, (int, float))}}
                     for d in jax.local_devices()]
+            elif cmd == "memtable":
+                import gc
+
+                from greptimedb_tpu.storage import RegionEngine
+
+                out["regions"] = {
+                    str(rid): int(region.memtable_bytes)
+                    for eng in gc.get_objects()
+                    if isinstance(eng, RegionEngine)
+                    for rid, region in list(eng.regions.items())}
             else:
                 out["error"] = f"unknown command {cmd!r}"
         except Exception as e:  # noqa: BLE001 — reported to the harness

@@ -13,6 +13,7 @@ from __future__ import annotations
 import http.client
 import json
 import os
+import re
 import signal
 import socket
 import subprocess
@@ -238,6 +239,54 @@ def wait_maintenance_idle(client: Client, timeout_s: float = 600.0) -> None:
                 f"maintenance still busy after {timeout_s:.0f}s: "
                 f"queue {st.get('queue_depth')}, {len(busy)} jobs")
         time.sleep(0.2)
+
+
+class LineProtocol:
+    """Bodies for `POST /v1/influxdb/write?precision=ms`, the door
+    Telegraf and TSBS's loader write through: one line a row,
+    `<table>,<tag>=<value>,... <field>=<x>,... <ts_ms>`, built
+    column-wise by Arrow's C kernels (copied from chip_smoke.py
+    `_lp_body`). Floats print in their shortest round-trip form, so the
+    server parses back exactly the float64 the reference holds."""
+
+    PATH = "/v1/influxdb/write?precision=ms"
+    CTYPE = "text/plain"
+
+    def __init__(self, table: str, series_tags: dict):
+        import pyarrow as pa
+
+        def esc(v) -> str:
+            return re.sub(r"([,= ])", r"\\\1", str(v))
+
+        n = len(next(iter(series_tags.values())))
+        self._prefix = pa.array([
+            table + "".join(f",{t}={esc(vs[i])}"
+                            for t, vs in series_tags.items())
+            for i in range(n)])
+
+    def body(self, series, ts_ms, fields: dict) -> bytes:
+        """Rows k = 0..n-1: series[k] (its index in `series_tags`),
+        ts_ms[k], {field: values[k]}."""
+        import numpy as np
+        import pyarrow as pa
+        import pyarrow.compute as pc
+
+        parts = [self._prefix.take(pa.array(np.asarray(series, np.int64)))]
+        for i, (key, arr) in enumerate(fields.items()):
+            parts.append(pa.scalar((" " if i == 0 else ",") + key + "="))
+            parts.append(pc.cast(pa.array(np.asarray(arr, np.float64)),
+                                 pa.string()))
+        parts.append(pa.scalar(" "))
+        parts.append(pc.cast(pa.array(np.asarray(ts_ms, np.int64)),
+                             pa.string()))
+        parts.append(pa.scalar("\n"))
+        lines = pc.binary_join_element_wise(*parts, "")
+        n = len(lines)
+        if n == 0:
+            return b""
+        end = int(np.frombuffer(lines.buffers()[1], dtype=np.int32,
+                                count=n + 1)[n])
+        return lines.buffers()[2].slice(0, end).to_pybytes()
 
 
 def count_rows(client: Client, table: str) -> int:

@@ -56,6 +56,7 @@ class Context:
     def __init__(self):
         self.notes: list = []
         self.trace = None
+        self.writer = None
 
     def note(self, msg: str) -> None:
         self.notes.append(msg)
@@ -227,9 +228,12 @@ def warm_up(client, mix, dtype: str) -> list:
 # ---- after the window --------------------------------------------------------
 
 
-def verify(mix, reqs: list, seed: int, dtype: str) -> tuple:
+def verify(mix, reqs: list, seed: int, dtype: str, writer=None) -> tuple:
     """Compare a seeded sample of up to MAX_CHECKED kept answers per
-    template with its reference. Returns (records, all within limits)."""
+    template with its reference; under a writer, a `fresh` template's
+    answer with the reference at a front between the newest tick
+    acknowledged before the request was sent and the newest sent before
+    its answer arrived. Returns (records, all within limits)."""
     rng = np.random.default_rng([int(seed), 9])
     recs, good = [], True
     for e in mix.entries:
@@ -242,10 +246,12 @@ def verify(mix, reqs: list, seed: int, dtype: str) -> tuple:
                                   replace=False).tolist()) | {slow}
             kept = [kept[i] for i in sorted(pick)]
         worst, limit = 0.0, e.template.limit(dtype)
+        fresh = writer is not None and getattr(e.template, "fresh", False)
         for r in kept:
             parsed = e.template.parse(200, r.body)[0]
+            at = {"front": writer.front(r.t_send, r.t_done)} if fresh else {}
             worst = max(worst, e.template.compare(parsed, r.params, mix.ds,
-                                                  dtype))
+                                                  dtype, **at))
             r.body = None
         bad_rows = sum(1 for r in mine if not r.ok)
         ok = worst <= limit
@@ -343,6 +349,10 @@ def run(args, data_home: str, guard) -> tuple:
 
         m_boot = client.metrics()
         warm = warm_up(client, mix, dtype)
+        writer = traffic.Writer(mix.writer_spec, tables(ds)[0], args.seed) \
+            if mix.writer_spec else None
+        if writer:
+            writer.warm_up(client)
         wire.wait_maintenance_idle(client)
         m0 = client.metrics()
         in_cache, t_window = cache_listing(), time.time()
@@ -379,8 +389,9 @@ def run(args, data_home: str, guard) -> tuple:
             tracer.start()
         host_memory = HostMemory()
         host_memory.start()
-        win = traffic.run_window(client, mix, args.seed, seconds)
+        win = traffic.run_window(client, mix, args.seed, seconds, writer)
         host_memory_peak = host_memory.stop()
+        memtable = server.control("memtable")["regions"] if writer else None
         if tracer:
             tracer.join(timeout=400)
         m1 = client.metrics()
@@ -388,9 +399,12 @@ def run(args, data_home: str, guard) -> tuple:
 
         # -- checks, once the window has closed
         n1 = read_back(client, ds)
+        if writer:
+            # every acknowledged write is there with the loader's rows
+            rows[writer.ds.table] += writer.acked_rows
         degraded = wire.metric_sum(m1, DEGRADATION) \
             - wire.metric_sum(m_boot, DEGRADATION)
-        checks, within = verify(mix, reqs, args.seed, dtype)
+        checks, within = verify(mix, reqs, args.seed, dtype, writer)
         dev1 = wire.wait_warm(client)
         memory = server.control("memory")
     except BenchFailure:
@@ -402,20 +416,30 @@ def run(args, data_home: str, guard) -> tuple:
     pruned = 0 if args.rehearse else prune_window_entries(in_cache, t_window)
 
     failed = sum(1 for r in reqs if not r.ok)
-    correct = bool(within and failed == 0 and n1 == rows
+    attempted, stale = len(reqs), 0
+    if writer:
+        # a write that was not acknowledged and a read-after-acknowledge
+        # check that got no answer are failed operations
+        w = writer.stats(win["t0"], seconds)
+        attempted += w["batches"] + w["checks"]
+        failed += w["batches_failed"] + w["checks_failed"]
+        stale = w["stale_reads"]
+    correct = bool(within and failed == 0 and n1 == rows and stale == 0
                    and degraded == 0 and dev["platform"] == platform)
     emit("checks", read_back_after_window=sum(n1.values()),
          rows=sum(rows.values()), tables_after_window=n1, tables=rows,
          degradations=degraded, degradation_log=dev1["degradations"][-5:],
-         templates=checks, errors=sorted({r.error for r in reqs
-                                          if r.error})[:5])
+         templates=checks, errors=sorted(
+             {r.error for r in reqs if r.error}
+             | (writer.errors() if writer else set()))[:5],
+         **({"writer": w, "stale": writer.stale()[:5]} if writer else {}))
 
-    ctx.requests, ctx.m0, ctx.m1 = reqs, m0, m1
+    ctx.requests, ctx.m0, ctx.m1, ctx.writer = reqs, m0, m1, writer
     ctx.t0, ctx.seconds, ctx.setup_s = win["t0"], seconds, setup_s
     peaks = [d.get("peak_bytes_in_use") or 0 for d in memory["devices"]]
     device = {"platform": dev["platform"], "kind": dev["device_kind"],
               "count": dev["count"], "memory_peak_bytes": int(max(peaks))}
-    result = {"correct": correct, "attempted": len(reqs), "failed": failed}
+    result = {"correct": correct, "attempted": attempted, "failed": failed}
     if args.trace:
         window_s = trace_times["stop"]["t_call"] \
             - trace_times["start"]["t_started"]
@@ -433,11 +457,15 @@ def run(args, data_home: str, guard) -> tuple:
         result["metrics"] = read_metrics(man, args.workload, "end_to_end",
                                          ctx)
     result["device"] = device
+    if writer:
+        result["writer"] = w
     # every number `correct` compared, beside its limit: last in the line
     # (a count has to EQUAL its limit; a gap may not pass it)
     compared = {c["template"]: (c["compared"], c["limit"]) for c in checks}
     compared.update({f"rows.{t}": (n1[t], rows[t]) for t in rows})
     compared.update(failed_requests=(failed, 0), degradations=(degraded, 0))
+    if writer:
+        compared.update(stale_reads=(stale, 0))
     result["compared"] = {
         k: {"value": min(float(v), 1e300), "limit": float(lim)}
         for k, (v, lim) in compared.items()}
@@ -457,7 +485,8 @@ def run(args, data_home: str, guard) -> tuple:
                    "sent_at_s": r.t_send - win["t0"], "params": r.params}
                   for r in sorted(reqs, key=lambda r: -r.ms)[:3]],
          notes=ctx.notes, cache_entries_pruned=pruned,
-         host_memory_peak_bytes=host_memory_peak)
+         host_memory_peak_bytes=host_memory_peak,
+         **({"memtable_bytes_at_close": memtable} if writer else {}))
     return result, correct
 
 

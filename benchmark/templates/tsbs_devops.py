@@ -280,6 +280,10 @@ class _GroupbyOrderbyLimit(_Sql):
 
 
 class _Lastpoint(_Sql):
+    #: the answer depends on the front of a table that is written to:
+    #: under a writer it is compared at the request's own front
+    fresh = True
+
     def draw(self, rng, ds) -> dict:
         return {}
 
@@ -295,6 +299,32 @@ class _Lastpoint(_Sql):
 
     def expected_rows(self, p: dict, ds) -> int:
         return ds.hosts
+
+    def compare(self, rows: list, params: dict, ds, dtype: str,
+                lowered: bool = False, front=None) -> float:
+        """Under a writer (`front`, harness/traffic.py `Front`): every
+        host's ten values must equal the seeded values of ONE of its
+        rows no older than the newest acknowledged before the request
+        was sent, no newer than the newest sent before its answer
+        arrived, and sent by then. The answer carries no ts; ten
+        uniform doubles name their tick. The number compared is the
+        count of values that differ from the host's nearest admissible
+        row, limit 0 as without a writer."""
+        if front is None:
+            return super().compare(rows, params, ds, dtype, lowered)
+        keys, got = self.decode(rows, params, ds)
+        if keys != list(range(ds.hosts)) or not np.isfinite(got).all():
+            return float("inf")
+        best = np.full(ds.hosts, len(FIELDS), np.int64)
+        for i in range(int(front.lower.min()), int(front.upper.max()) + 1):
+            vals = ds.tick(i)[1] if i >= 0 else \
+                {f: ds.fields[f][i] for f in FIELDS}
+            ref = round_to(dtype, np.stack([vals[f] for f in FIELDS],
+                                           axis=1))
+            ok = (front.lower <= i) & (i <= front.upper) & front.sent(i)
+            best = np.where(ok, np.minimum(best, (got != ref).sum(axis=1)),
+                            best)
+        return float(best.sum())
 
     def decode(self, rows: list, p: dict, ds) -> tuple:
         # GROUP BY without ORDER BY: any order

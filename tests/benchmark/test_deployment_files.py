@@ -160,7 +160,11 @@ def test_a_configuration_without_setup_gets_the_bulk_loader(c, monkeypatch):
     from benchmark import run as bench_run
 
     conf = load_json("configs", c["name"] + ".json")
-    assert "setup" not in conf and isinstance(conf["loader"], str)
+    if "setup" in conf:     # a route of its own: a file under loaders/
+        assert loader_path(conf) == os.path.join(
+            BENCH_DIR, "loaders", conf["setup"]["loader"] + ".py")
+        return
+    assert isinstance(conf["loader"], str)
     helper = os.path.join(BENCH_DIR, "harness", "bulk_load.py")
     assert loader_path(conf) == loader_path({"setup": {"loader": "bulk"}}) \
         == helper
@@ -355,10 +359,13 @@ def test_kernel_reader():
     ("counter_adjust_ms_per_query", "counter_adjust"),
     ("cumsum_ms_per_query", "cumsum")])
 def test_kernel_metric_is_data_on_the_kernel_reader(name, kernel):
-    entry = next(m for m in MAN["per_layer"] if m["name"] == name)
+    entry = dict(next(m for m in MAN["per_layer"] if m["name"] == name))
+    cells = entry.pop("workloads")
     assert entry == {"name": name, "unit": "ms", "better": "lower",
                      "source": "device_trace", "layer": "Kernels",
-                     "moves": "queries_per_s", "workloads": ["prom-board"]}
+                     "moves": "queries_per_s"}
+    assert "prom-board" in cells \
+        and set(cells) <= {w["name"] for w in MAN["workloads"]}
     spec = load_json("metrics", name + ".json")
     assert spec["reader"] == "kernel"
     assert spec["args"] == {"kernel": kernel, "stat": "ms_per_query"}
@@ -374,6 +381,13 @@ def test_peaks_table_is_keyed_by_device_kind_with_its_source():
     v5e = peaks["TPU v5 lite"]
     assert v5e["flops_per_s"]["bfloat16"] == 197e12
     assert v5e["hbm_bytes_per_s"] == 819e9 and "Google Cloud" in v5e["source"]
-    # no share of a peak is reported yet
-    assert not any("roofline" in m["name"] or "mfu" in m["name"]
-                   for m in MAN["per_layer"] + MAN["end_to_end"])
+    # a share of a roofline is a `%` on the `roofline` reader, with a
+    # cost function beside it (histogram_fold_peak_share is the first)
+    shares = [m for m in MAN["per_layer"]
+              if m["name"].endswith(("_roofline", "_peak_share"))]
+    assert shares
+    for m in shares:
+        spec = load_json("metrics", m["name"] + ".json")
+        assert m["unit"] == "%" and spec["reader"] == "roofline"
+        assert os.path.isfile(os.path.join(
+            BENCH_DIR, "costs", spec["args"]["cost"] + ".py"))

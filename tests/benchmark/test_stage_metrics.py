@@ -27,9 +27,10 @@ from benchmark.harness.common import load_json, manifest  # noqa: E402
 
 MAN = manifest()
 
-#: ISSUE 24's table: metric -> the cells that report it (ISSUE 26 added
+#: ISSUE 24's table: metric -> cells that report it (ISSUE 26 added
 #: `tsbs-point-dash` wherever `tsbs-scan-heavy` stands, and the one
-#: stage that had no metric)
+#: stage that had no metric). A later cell joins a list by adding its
+#: name: the lists are held to CONTAIN these, not to equal them.
 TSBS = {"tsbs-scan-heavy", "tsbs-point-dash"}
 STAGE_METRICS = {
     "compile_ms_per_query": TSBS | {"prom-board"},
@@ -48,7 +49,8 @@ STAGE_METRICS = {
 @pytest.mark.parametrize("name", sorted(STAGE_METRICS))
 def test_stage_metric_is_data_on_an_existing_reader(name):
     entry = next(m for m in MAN["per_layer"] if m["name"] == name)
-    assert set(entry["workloads"]) == STAGE_METRICS[name]
+    assert STAGE_METRICS[name] <= set(entry["workloads"])
+    assert set(entry["workloads"]) <= {w["name"] for w in MAN["workloads"]}
     assert entry["moves"] == "queries_per_s"
     assert entry["source"] == "program_counter"
     spec = load_json("metrics", name + ".json")
@@ -114,7 +116,10 @@ def test_rehearsed_traced_run_reports_every_stage_metric(cell):
         assert got["host_agg_ms_per_query"]["value"] > 0
         # on the CPU backend nothing routes to a host tier
         assert got["host_tier_share"]["value"] == 0
-        assert got["compile_ms_per_query"]["value"] > 0
+        # since PR 31 a rehearsed window's literals are operands of
+        # programs the warm-up compiled: it may compile nothing
+        assert got["compile_ms_per_query"]["value"] >= 0
+        assert got["agg_program_reuse_share"]["value"] > 50
 
 
 def _trace_gaps():
