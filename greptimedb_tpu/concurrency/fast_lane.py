@@ -741,14 +741,33 @@ class FastLane:
         shape, and the collection window is pure latency here). The
         session timezone is part of the key: naive string timestamp
         literals bind under it, so same-text requests from differently
-        zoned sessions must not share an execution."""
-        fkey = (id(entry), params, tz)
+        zoned sessions must not share an execution.
+
+        So is the data the request saw when it arrived: the table's
+        regions' `data_identity`, read here, before the flight is looked
+        up. A leader's execution takes its snapshot after the leader
+        arrived, so it is no older than the version in the key; a
+        request that arrives after an acknowledged write reads a newer
+        version, finds no flight under it and starts (or joins) a newer
+        one — it never gets the answer of an execution whose snapshot
+        predates a write it could already have seen acknowledged. In a
+        window that writes nothing the version never moves and every
+        follower joins."""
+        seen = self._data_seen(qe, entry)
+        fkey = (id(entry), params, tz, seen)
         with self._flight_lock:
             flight = self._flights.get(fkey)
             leader = flight is None
             if leader:
                 flight = _Flight()
                 self._flights[fkey] = flight
+                if seen is not None and any(
+                        k[:3] == fkey[:3] for k in self._flights
+                        if k is not fkey):
+                    # an identical execution is in flight over an older
+                    # version of the data: the join this request was
+                    # refused
+                    FAST_LANE_EVENTS.inc(event="stale_flight")
         if not leader:
             from greptimedb_tpu.utils import deadline as dl
 
@@ -780,6 +799,21 @@ class FastLane:
             with self._flight_lock:
                 self._flights.pop(fkey, None)
             flight.event.set()
+
+    @staticmethod
+    def _data_seen(qe, entry) -> Optional[tuple]:
+        """The data version of the entry's table as this moment sees it:
+        every region's (incarnation, data_version, ts extent), metadata
+        only. None where a region cannot say (a remote one): such
+        requests coalesce as they always did."""
+        identify = getattr(qe.region_engine, "data_identity", None)
+        if identify is None:
+            return None
+        try:
+            seen = tuple(identify(rid) for rid in entry.info.region_ids)
+        except Exception:  # a region dropped under the entry: the
+            return None    # execution itself reports it
+        return None if any(v is None for v in seen) else seen
 
     def _bind_execute(self, qe, entry, params):
         from greptimedb_tpu.utils import deadline as dl

@@ -34,6 +34,24 @@ def block_size_for(n: int, min_rows: int = MIN_BLOCK_ROWS) -> int:
     return 1 << math.ceil(math.log2(n))
 
 
+#: the block sizes of a memtable's tail below the coarse ones. A table
+#: under ingest grows its tail between any two requests and every block
+#: size is a program: by powers of two a tail that grows from one 4,000
+#: row batch to 280,000 rows in a window passes eight sizes, a compile
+#: per aggregate shape for each (PERF.md, PR 38); by these it passes three
+_TAIL_BLOCK_ROWS = (MIN_BLOCK_ROWS, 1 << 14, 1 << 18)
+
+
+def tail_block_size_for(n: int) -> int:
+    """Block shape bucket for the n memtable rows a scan ends in: few
+    sizes, far apart (padding costs microseconds a row block, a size a
+    compile), then `block_size_for`'s."""
+    for size in _TAIL_BLOCK_ROWS:
+        if n <= size:
+            return size
+    return block_size_for(n)
+
+
 def pad_rows(arr: np.ndarray, size: int, fill=0) -> np.ndarray:
     """Pad axis 0 of `arr` to `size` with `fill`."""
     n = arr.shape[0]

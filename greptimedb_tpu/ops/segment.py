@@ -60,6 +60,46 @@ def _masked(values: jax.Array, mask: jax.Array, fill) -> jax.Array:
     return jnp.where(mask, values, jnp.asarray(fill, dtype=values.dtype))
 
 
+#: rows a float sum adds one after the other before the partial sum
+#: joins the others. A running float32 sum rounds every addend to the
+#: sum's last place, and equal addends (a gauge that holds its value)
+#: round the same way each time: 423,458 rows of one group, 720 equal
+#: neighbours at a time, read 2.06e-4 low in one pass (PERF.md, PR 38).
+#: In two levels no running sum grows past this many addends before it
+#: is added to sums of its own size.
+SUM_CHUNK_ROWS = 256
+#: most first-level partial sums (chunks x segments x columns) one
+#: reduction may hold; past it the groups are many, a group's rows in a
+#: block few, and one pass adds them
+_SUM_PARTIALS = 1 << 22
+
+
+def float_segment_sum(values: jax.Array, ids: jax.Array, num_segments: int,
+                      indices_are_sorted: bool = False) -> jax.Array:
+    """`jax.ops.segment_sum` of a float32 (or narrower) plane in two
+    levels: each chunk of SUM_CHUNK_ROWS rows scatters into segments of
+    its own, then the chunks are added. The choice is one of shapes and
+    dtype alone, so a program is still one per block size. A float64
+    plane keeps one pass: its sum carries 29 more bits (the same rows
+    drift 1e-13 there), and on the CPU backend two paths that block the
+    same rows differently keep answering bit for bit alike."""
+    n = values.shape[0]
+    width = 1
+    for d in values.shape[1:]:
+        width *= d
+    chunks = -(-n // SUM_CHUNK_ROWS)
+    if chunks < 2 or chunks * num_segments * width > _SUM_PARTIALS \
+            or not jnp.issubdtype(values.dtype, jnp.floating) \
+            or values.dtype.itemsize >= 8:
+        return jax.ops.segment_sum(values, ids, num_segments=num_segments,
+                                   indices_are_sorted=indices_are_sorted)
+    chunk_of = jnp.arange(n, dtype=jnp.int32) // SUM_CHUNK_ROWS
+    parts = jax.ops.segment_sum(
+        values, chunk_of * num_segments + ids.astype(jnp.int32),
+        num_segments=chunks * num_segments)
+    return parts.reshape((chunks, num_segments) + values.shape[1:]).sum(axis=0)
+
+
 def _pallas_mode() -> str:
     import os
 
@@ -96,7 +136,7 @@ def dense_segment_sum(plane: jax.Array, ids: jax.Array,
                 mode == "on" or (mode == "auto" and backend == "tpu"
                                  and ps.tpu_compile_ok())):
             return ps.pallas_dense_segment_sum(plane, ids, num_segments)
-    return jax.ops.segment_sum(plane, ids, num_segments=num_segments)
+    return float_segment_sum(plane, ids, num_segments)
 
 
 @functools.partial(
@@ -153,8 +193,9 @@ def segment_agg(
         moment_vals = values.astype(jnp.float64)
     sums = counts = None
     if need_sum or "sumsq" in ops:
-        sums = seg_sum(
-            jnp.where(elem_mask, moment_vals, 0).astype(moment_vals.dtype))
+        sums = float_segment_sum(
+            jnp.where(elem_mask, moment_vals, 0).astype(moment_vals.dtype),
+            ids, gsz, indices_are_sorted)
     if need_count:
         # int32: exact per-block (block rows << 2^31); cross-block combine
         # upcasts to int64
@@ -167,9 +208,9 @@ def segment_agg(
         # [G, 1]: per-group, not per-field
         out["rows"] = seg_sum(row_mask.astype(jnp.int32)[:, None])
     if "sumsq" in ops:
-        out["sumsq"] = seg_sum(
+        out["sumsq"] = float_segment_sum(
             jnp.where(elem_mask, moment_vals * moment_vals, 0)
-            .astype(moment_vals.dtype))
+            .astype(moment_vals.dtype), ids, gsz, indices_are_sorted)
     if "mean" in ops:
         denom = jnp.maximum(counts, 1).astype(values.dtype)
         mean = sums.astype(values.dtype) / denom

@@ -162,6 +162,13 @@ def bind_expr(e: ast.Expr, ctx: BindContext) -> ast.Expr:
     if isinstance(e, ast.FuncCall):
         return ast.FuncCall(e.name, tuple(bind_expr(a, ctx) for a in e.args), e.distinct)
     if isinstance(e, ast.Cast):
+        if isinstance(e.expr, ast.Column) and e.expr.name in ctx.tag_names \
+                and e.type_name.lower() in _NUMERIC_CASTS:
+            # a tag's numeric value: the bound columns hold codes, so the
+            # cast is a lookup in the dictionary's numbers
+            return TagNumber(e.expr, tuple(
+                _tag_number(v) for v in ctx.tag_dicts.get(e.expr.name, ())),
+                e.type_name)
         return ast.Cast(bind_expr(e.expr, ctx), e.type_name)
     if isinstance(e, ast.Case):
         return ast.Case(
@@ -170,6 +177,45 @@ def bind_expr(e: ast.Expr, ctx: BindContext) -> ast.Expr:
             bind_expr(e.else_, ctx) if e.else_ else None,
         )
     return e
+
+
+_NUMERIC_CASTS = {
+    "double": np.float64, "float64": np.float64, "float": np.float32,
+    "float32": np.float32, "real": np.float32, "bigint": np.int64,
+    "int64": np.int64, "int": np.int32, "integer": np.int32,
+    "int32": np.int32}
+
+
+@dataclass(frozen=True)
+class TagNumber(ast.Expr):
+    """CAST(<tag> AS <numeric type>) over a bound (dictionary-coded)
+    tag column: `values[code]` is the number the tag's string spells,
+    NaN where it spells none; a NULL tag (code -1) reads NaN."""
+
+    column: ast.Column
+    values: tuple
+    type_name: str
+
+    def lookup(self, codes, xp):
+        table = xp.asarray(self.values + (_NAN,), dtype=xp.float64)
+        k = len(self.values)
+        out = table[xp.where((codes < 0) | (codes >= k), k, codes)]
+        return out.astype(_NUMERIC_CASTS[self.type_name.lower()])
+
+
+#: the one NaN of every TagNumber's values: a tuple compares its items
+#: by identity first and a NaN hashes by it, so two binds of one CAST
+#: over a tag with a value that spells no number are equal statics and
+#: share one program (a fresh float("nan") a bind would compile each)
+_NAN = float("nan")
+
+
+def _tag_number(v) -> float:
+    try:
+        out = float(v)
+    except (TypeError, ValueError):
+        return _NAN
+    return _NAN if out != out else out
 
 
 def _lit(e: ast.Expr):
@@ -493,6 +539,8 @@ def eval_device(e: ast.Expr, cols: dict, ctx_tags: frozenset, schema: Schema,
                 f"ORDER BY inside {e.name}() is only supported for "
                 "first_value/last_value")
         return _eval_device_func(e, ev, cols, schema)
+    if isinstance(e, TagNumber):
+        return e.lookup(ev(e.column), jnp)
     if isinstance(e, ast.Cast):
         v = ev(e.expr)
         t = e.type_name.lower()
@@ -718,6 +766,8 @@ def eval_host(
                 f"ORDER BY inside {e.name}() is only supported for "
                 "first_value/last_value")
         return _eval_host_func(e, ev, schema)
+    if isinstance(e, TagNumber):
+        return e.lookup(np.asarray(ev(e.column)), np)
     if isinstance(e, ast.Cast):
         v = ev(e.expr)
         t = e.type_name.lower()
@@ -1099,6 +1149,8 @@ def collect_columns(e: Optional[ast.Expr], out: set[str]) -> set[str]:
             collect_columns(a, out)
     elif isinstance(e, ast.Cast):
         collect_columns(e.expr, out)
+    elif isinstance(e, TagNumber):
+        out.add(e.column.name)
     elif isinstance(e, ast.Case):
         if e.operand:
             collect_columns(e.operand, out)

@@ -630,119 +630,193 @@ def _contains_agg(e) -> bool:
     return False
 
 
-def _agg_value(name: str, vals: np.ndarray):
-    clean = np.asarray([v for v in vals
-                        if v is not None and not _is_nan(v)])
+def _null_mask(arr: np.ndarray) -> np.ndarray:
+    """Where a column holds NULL: None, or NaN (NaN is NULL here)."""
+    if arr.dtype == object:
+        return np.asarray(arr == None, dtype=bool) \
+            | np.asarray(arr != arr, dtype=bool)  # noqa: E711
+    if arr.dtype.kind == "f":
+        return np.isnan(arr)
+    return np.zeros(len(arr), dtype=bool)
+
+
+def _factorize(arr: np.ndarray) -> tuple:
+    """(codes[n], groups) of one group key: equal values share a code,
+    codes ascend with the values, every NULL shares the last one (SQL
+    GROUP BY: one NULL group, sorted last)."""
+    null = _null_mask(arr)
+    vals = arr[~null] if null.any() else arr
+    if vals.dtype == object:
+        text = vals.astype("U")
+        if len(vals) and not np.asarray(text.astype(object) == vals,
+                                        dtype=bool).all():
+            text = None  # not all strings: compare the objects
+        try:
+            _, inv = np.unique(vals if text is None else text,
+                               return_inverse=True)
+        except TypeError:  # values no order holds together
+            seen: dict = {}
+            inv = np.asarray([seen.setdefault(v, len(seen)) for v in vals],
+                             dtype=np.int64)
+    else:
+        _, inv = np.unique(vals, return_inverse=True)
+    inv = np.asarray(inv, dtype=np.int64).reshape(-1)
+    k = int(inv.max()) + 1 if len(inv) else 0
+    if not null.any():
+        return inv, k
+    codes = np.full(len(arr), k, dtype=np.int64)
+    codes[~null] = inv
+    return codes, k + 1
+
+
+def _group_rows(key_arrays: list, n: int) -> tuple:
+    """(group of each row, groups, first row of each group); groups
+    ascend by (key 1, key 2, ...), NULL last in each component."""
+    if not key_arrays:
+        return np.zeros(n, dtype=np.int64), 1, np.zeros(1, dtype=np.int64)
+    gid = np.zeros(n, dtype=np.int64)
+    for a in key_arrays:
+        codes, k = _factorize(a)
+        if k and int(gid.max(initial=0)) >= (1 << 62) // max(k, 1):
+            # the radix outgrew an int64: rank what there is first
+            gid = np.unique(gid, return_inverse=True)[1].reshape(-1)
+        gid = gid * max(k, 1) + codes
+    _, first, inv = np.unique(gid, return_index=True, return_inverse=True)
+    return inv.reshape(-1), len(first), first
+
+
+def _reduce_groups(name: str, vals: np.ndarray, gid: np.ndarray,
+                   groups: int):
+    """One aggregate of one argument per group, NULLs (None / NaN)
+    skipped: (values[groups], NULL where a group held no value)."""
+    null = _null_mask(vals)
+    count = np.bincount(gid[~null], minlength=groups)
     if name == "count":
-        return len(clean)
-    if len(clean) == 0:
-        return None
-    if name == "sum":
-        return float(np.sum(clean.astype(np.float64)))
-    if name == "min":
-        return clean.min()
-    if name == "max":
-        return clean.max()
-    return float(np.mean(clean.astype(np.float64)))
+        return count, None
+    empty = count == 0
+    if name in ("sum", "avg"):
+        x = vals.copy()
+        x[null] = 0
+        total = np.bincount(gid, weights=x.astype(np.float64),
+                            minlength=groups)
+        if name == "avg":
+            total = total / np.maximum(count, 1)
+        return total, empty
+    # min / max: sort rows by group, reduce each group's slice
+    keep = ~null
+    order = np.argsort(gid[keep], kind="stable")
+    g, v = gid[keep][order], vals[keep][order]
+    starts = np.flatnonzero(np.r_[True, g[1:] != g[:-1]]) if len(g) \
+        else np.empty(0, dtype=np.int64)
+    out = np.empty(groups, dtype=v.dtype if v.dtype != object else object)
+    if v.dtype == object:
+        out[:] = None
+        ends = np.r_[starts[1:], len(g)]
+        pick = min if name == "min" else max
+        for s0, s1 in zip(starts, ends):  # a loop over groups, not rows
+            out[g[s0]] = pick(v[s0:s1])
+    elif len(g):
+        red = np.minimum if name == "min" else np.maximum
+        out[:] = 0
+        out[g[starts]] = red.reduceat(v, starts)
+    return out, empty
 
 
 def _aggregate(sel, cols, dtypes, n, resolve) -> QueryResult:
-    group_exprs = [resolve(g) for g in sel.group_by]
-    key_arrays = []
-    for g in group_exprs:
-        v = eval_host(g, cols, None, None, n)
-        key_arrays.append(np.asarray([v] * n) if np.ndim(v) == 0
-                          else np.asarray(v))
-    groups: dict = {}
-    if key_arrays:
-        for i in range(n):
-            # NaN is NULL here and NaN != NaN — normalize so all NULL
-            # rows land in ONE group (SQL GROUP BY semantics)
-            key = tuple(None if _is_nan(a[i]) else a[i]
-                        for a in key_arrays)
-            groups.setdefault(key, []).append(i)
-    else:
-        groups[()] = list(range(n))
+    """GROUP BY / aggregates / HAVING of a select over in-memory columns
+    (a derived table, a CTE, a view, a join's output), as arrays: the
+    keys are factorized, each aggregate is one reduction over all rows,
+    and the select items and HAVING are evaluated over the G groups."""
+    from greptimedb_tpu.utils import tracing
+    from greptimedb_tpu.utils.metrics import DERIVED_SELECT_SECONDS
 
-    def agg_for(expr, idx):
-        """Evaluate one select item for one group."""
-        def rec(e):
-            if isinstance(e, ast.FuncCall) and e.name.lower() in _AGGS:
-                fname = e.name.lower()
-                if fname == "count" and (not e.args or isinstance(
-                        e.args[0], ast.Star)):
-                    return len(idx)
-                arg = resolve(e.args[0])
-                vals = eval_host(arg, {k: v[idx] for k, v in cols.items()},
-                                 None, None, len(idx))
-                vals = np.asarray([vals] * len(idx)) if np.ndim(vals) == 0 \
-                    else np.asarray(vals)
-                return _agg_value(fname, vals)
-            if isinstance(e, ast.Column):
-                rv = eval_host(resolve(e), cols, None, None, n)
-                return np.asarray(rv)[idx[0]] if len(idx) else None
-            if isinstance(e, ast.Literal):
-                return e.value
-            if isinstance(e, ast.BinaryOp):
-                import operator as op
+    with DERIVED_SELECT_SECONDS.time(), tracing.stage("host_agg"), \
+            tracing.span("derived_select", rows_in=n,
+                         path="numpy") as attrs:
+        r = _aggregate_arrays(sel, cols, n, resolve)
+        attrs["groups_out"] = r.num_rows
+    return _post(sel, r, resolve)
 
-                if e.op == "and":
-                    return bool(rec(e.left)) and bool(rec(e.right))
-                if e.op == "or":
-                    return bool(rec(e.left)) or bool(rec(e.right))
-                f = {"+": op.add, "-": op.sub, "*": op.mul,
-                     "/": op.truediv, "%": op.mod,
-                     "=": op.eq, "!=": op.ne, "<": op.lt, "<=": op.le,
-                     ">": op.gt, ">=": op.ge}.get(e.op)
-                if f is None:
-                    raise PlanError(
-                        f"unsupported op {e.op!r} over join aggregates")
-                return f(rec(e.left), rec(e.right))
-            raise PlanError(
-                f"unsupported expression over join aggregates: {e}")
-        return rec(expr)
 
-    if group_exprs:
-        # None keys (LEFT JOIN null-extended rows) aren't comparable to
-        # strings — sort NULL groups last, per component
-        keys = sorted(groups, key=lambda k: tuple(
-            (v is None, v) for v in k))
-    else:
-        keys = list(groups)
-    out_names, rows_by_col = [], []
+def _aggregate_arrays(sel, cols, n, resolve) -> QueryResult:
+    def column(e) -> np.ndarray:
+        v = eval_host(e, cols, None, None, n)
+        return np.asarray([v] * n) if np.ndim(v) == 0 else np.asarray(v)
+
+    gid, groups, first = _group_rows(
+        [column(resolve(g)) for g in sel.group_by], n)
+    if not sel.group_by and n == 0:
+        first = np.empty(0, dtype=np.int64)  # one group, and no row in it
+
+    def rec(e):
+        """(value per group, NULL mask or None) of an expression over
+        aggregates, group keys and literals."""
+        if isinstance(e, ast.FuncCall) and e.name.lower() in _AGGS:
+            fname = e.name.lower()
+            if fname == "count" and (not e.args or isinstance(
+                    e.args[0], ast.Star)):
+                return np.bincount(gid, minlength=groups), None
+            return _reduce_groups(fname, column(resolve(e.args[0])), gid,
+                                  groups)
+        if isinstance(e, ast.Column):
+            vals = column(resolve(e))
+            if len(first) < groups:  # the one empty group
+                return np.full(groups, None, dtype=object), None
+            return vals[first], None
+        if isinstance(e, ast.Literal):
+            return np.full(groups, e.value,
+                           dtype=object if e.value is None
+                           or isinstance(e.value, str) else None), None
+        if isinstance(e, ast.BinaryOp):
+            import operator as op
+
+            (a, na), (b, nb) = rec(e.left), rec(e.right)
+            if e.op in ("and", "or"):
+                f = np.logical_and if e.op == "and" else np.logical_or
+                return f(_truth(a, na), _truth(b, nb)), None
+            f = {"+": op.add, "-": op.sub, "*": op.mul,
+                 "/": op.truediv, "%": op.mod,
+                 "=": op.eq, "!=": op.ne, "<": op.lt, "<=": op.le,
+                 ">": op.gt, ">=": op.ge}.get(e.op)
+            if f is None:
+                raise PlanError(
+                    f"unsupported op {e.op!r} over join aggregates")
+            # NULL in, NULL out (a comparison with NULL is not true)
+            null = na if nb is None else nb if na is None else na | nb
+            with np.errstate(divide="ignore", invalid="ignore"):
+                return f(a, b), null
+        raise PlanError(
+            f"unsupported expression over join aggregates: {e}")
+
+    out_names = []
     for it in sel.items:
         if isinstance(it.expr, ast.Star):
             raise PlanError("SELECT * with GROUP BY over a join")
         out_names.append(it.alias or _expr_name(it.expr))
-    table_rows = []
-    for key in keys:
-        idx = groups[key]
-        if sel.having is not None:
-            hv = agg_for(resolve(sel.having), idx)
-            if not bool(hv):
-                continue
-        table_rows.append([agg_for(it.expr, idx) for it in sel.items])
-    cols_out = [np.asarray([r[i] for r in table_rows], dtype=object)
-                for i in range(len(out_names))] if table_rows else \
-        [np.empty(0, dtype=object) for _ in out_names]
-    # tighten numeric dtypes: all-int columns (counts) stay integer like
-    # the single-table path; mixed numerics become float64
-    tightened = []
-    for c in cols_out:
-        try:
-            if len(c) and all(isinstance(v, (int, np.integer))
-                              and not isinstance(v, bool) for v in c):
-                tightened.append(c.astype(np.int64))
-            elif len(c) and all(isinstance(v, (int, float, np.floating,
-                                               np.integer))
-                                and v is not None for v in c):
-                tightened.append(c.astype(np.float64))
-            else:
-                tightened.append(c)
-        except (TypeError, ValueError):
-            tightened.append(c)
-    r = QueryResult(out_names, [None] * len(out_names), tightened)
-    return _post(sel, r, resolve)
+    keep = None
+    if sel.having is not None:
+        keep = np.flatnonzero(_truth(*rec(resolve(sel.having))))
+    cols_out = []
+    for it in sel.items:
+        vals, null = rec(it.expr)
+        vals = np.asarray(vals)
+        if null is not None and null.any():
+            vals = vals.astype(object)
+            vals[null] = None
+        elif vals.dtype.kind in "iu":
+            vals = vals.astype(np.int64)
+        elif vals.dtype.kind == "f":
+            vals = vals.astype(np.float64)
+        cols_out.append(vals if keep is None else vals[keep])
+    return QueryResult(out_names, [None] * len(out_names), cols_out)
+
+
+def _truth(vals, null) -> np.ndarray:
+    """A predicate's value per group as a bool: NULL is not true."""
+    vals = np.asarray(vals)
+    out = vals.astype(bool) if vals.dtype != object \
+        else np.asarray([bool(v) for v in vals], dtype=bool)
+    return out if null is None else out & ~null
 
 
 def _post(sel, r: QueryResult, resolve,

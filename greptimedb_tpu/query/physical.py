@@ -10,8 +10,10 @@ async streams, SURVEY.md §7):
 
 Everything static (expressions, key specs, ops) rides into jit as hashable
 static arguments, so each query shape compiles once and is cached by jax.
-Dedup (last-write-wins) runs as a whole-scan device sort when the table is
-not append-mode.
+Dedup (last-write-wins) of a table that is not append-mode is a mask over
+the scan's rows, made on the host by merging the scan's sorted runs
+(query/lww.py): no program per row count, no device sort, and no mask at
+all where no row repeats.
 """
 
 from __future__ import annotations
@@ -19,6 +21,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import hashlib
 import itertools
 import logging
 import os
@@ -33,14 +36,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from greptimedb_tpu.datatypes.types import DataType, SemanticType
-from greptimedb_tpu.ops.blocks import DEFAULT_BLOCK_ROWS, block_size_for, pad_rows
-from greptimedb_tpu.ops.dedup import sort_dedup
+from greptimedb_tpu.ops.blocks import (
+    DEFAULT_BLOCK_ROWS,
+    block_size_for,
+    pad_rows,
+    tail_block_size_for,
+)
 from greptimedb_tpu.ops import sparse_segment as sparse_ops
 from greptimedb_tpu.ops.segment import (
     _type_max as _seg_type_max,
     _type_min as _seg_type_min,
     combine_group_ids,
     dense_segment_sum,
+    float_segment_sum,
     segment_agg,
 )
 from greptimedb_tpu.query import logical as lp
@@ -111,6 +119,8 @@ def _kstage(name: str, **attrs):
 # this candidate fraction the subset would roughly duplicate the cached
 # columns for no kernel savings (tests patch this to force the path on)
 _BOUNDARY_MAX_FRACTION = 0.5
+# last-write-wins masks kept by snapshot identity (a byte a row each)
+_LWW_MASKS_KEPT = 16
 
 _PRIMITIVES = {
     "sum": ("sum", "count"),  # count detects all-NULL groups -> NULL sum
@@ -224,25 +234,35 @@ def _block_plan(scan) -> list[_BlockEntry]:
     offs = scan.sorted_part_offsets
     pkeys = getattr(scan, "part_keys", ())
     segs: list[tuple] = []
+    tail = object()  # the memtable's rows after the files': they grow
+    # between requests, so their block sizes are few and far apart
+
+    def rows_of(pk, rows: int) -> int:
+        size = tail_block_size_for(rows) if pk is tail \
+            else block_size_for(rows)
+        return min(size, DEFAULT_BLOCK_ROWS)
+
     if pkeys and len(offs) == len(pkeys) + 1 and offs[-1] <= n:
         segs = [(pkeys[i], offs[i], offs[i + 1]) for i in range(len(pkeys))]
-        if offs[-1] < n:  # memtable tail: version-keyed, no part identity
-            segs.append((None, offs[-1], n))
-        est = sum(
-            -(-max(s1 - s0, 1) // min(block_size_for(s1 - s0),
-                                      DEFAULT_BLOCK_ROWS))
-            for _, s0, s1 in segs if s1 > s0)
+        if offs[-1] < n:  # version-keyed, no part identity
+            segs.append((tail, offs[-1], n))
+        est = sum(-(-max(s1 - s0, 1) // rows_of(pk, s1 - s0))
+                  for pk, s0, s1 in segs if s1 > s0)
         if est > _MAX_PLAN_BLOCKS:
             segs = []
     if not segs:
-        segs = [(None, 0, n)]
+        # a scan gathered from another's rows (`_boundary_firstlast`)
+        # says where the memtable's begin
+        head = getattr(scan, "_tail_start", n)
+        segs = [(None, 0, head), (tail, head, n)]
     plan: list[_BlockEntry] = []
     for pk, s0, s1 in segs:
         if s1 <= s0:
             continue
-        pb = min(block_size_for(s1 - s0), DEFAULT_BLOCK_ROWS)
+        pb = rows_of(pk, s1 - s0)
         for st in range(s0, s1, pb):
-            plan.append(_BlockEntry(pk, s0, st, min(st + pb, s1), pb))
+            plan.append(_BlockEntry(None if pk is tail else pk, s0, st,
+                                    min(st + pb, s1), pb))
     return plan
 
 
@@ -776,7 +796,7 @@ def _agg_scan_sharded_prepared(
         gid = _group_ids(local_cols, keys, plane.shape[0], operands)
         ids = jnp.where(mask, gid, jnp.int32(G))
         total = jax.lax.psum(
-            jax.ops.segment_sum(plane, ids, num_segments=G + 1)[:G],
+            float_segment_sum(plane, ids, G + 1)[:G],
             "shard")
         sums = total[:, :nf]
         if has_nan:
@@ -807,8 +827,8 @@ def _agg_scan_sharded_prepared(
                 acc[k] = jnp.where(tmax == small, jnp.nan, tmax)
             elif k == "sumsq":
                 acc[k] = jax.lax.psum(
-                    jax.ops.segment_sum(local_cols["__prep_sq__"], ids,
-                                        num_segments=G + 1)[:G], "shard")
+                    float_segment_sum(local_cols["__prep_sq__"], ids,
+                                      G + 1)[:G], "shard")
             else:
                 denom = jnp.maximum(cnts, 1.0)
                 acc[k] = jnp.where(cnts > 0, sums / denom, jnp.nan)
@@ -830,7 +850,7 @@ def _prep_stream_step_impl(acc, cols, n_valid, *, where, keys, num_segments,
     mask = _where_mask(mask, where, where_args, cols, tag_names, schema)
     gid = _group_ids(cols, keys, plane.shape[0], where_args)
     ids = jnp.where(mask, gid, jnp.int32(G))
-    out = {"total": jax.ops.segment_sum(plane, ids, num_segments=G + 1)[:G]}
+    out = {"total": float_segment_sum(plane, ids, G + 1)[:G]}
     if "__prep_min__" in cols:
         out["min"] = jax.ops.segment_min(cols["__prep_min__"], ids,
                                          num_segments=G + 1)[:G]
@@ -838,8 +858,7 @@ def _prep_stream_step_impl(acc, cols, n_valid, *, where, keys, num_segments,
         out["max"] = jax.ops.segment_max(cols["__prep_max__"], ids,
                                          num_segments=G + 1)[:G]
     if "__prep_sq__" in cols:
-        out["sq"] = jax.ops.segment_sum(cols["__prep_sq__"], ids,
-                                        num_segments=G + 1)[:G]
+        out["sq"] = float_segment_sum(cols["__prep_sq__"], ids, G + 1)[:G]
     if acc is not None:
         out["total"] = out["total"] + acc["total"]
         if "min" in out:
@@ -1157,14 +1176,6 @@ def _filter_block(cols: dict, n_valid: jax.Array, dedup_mask, *, where,
     return _where_mask(mask, where, where_args, cols, tag_names, schema)
 
 
-@jax.jit
-@device_telemetry.kernel_name("dedup_mask")
-def _dedup_mask(sid, ts, seq, op_type, valid):
-    order, keep = sort_dedup(sid, ts, seq, op_type, valid)
-    mask = jnp.zeros(valid.shape, dtype=bool)
-    return mask.at[order].set(keep)
-
-
 def _combine_partials(acc: Optional[dict], p: dict) -> dict:
     if acc is None:
         return p
@@ -1307,6 +1318,9 @@ class PhysicalExecutor:
         # aggregates with collectives (None on a single chip) — and the
         # first-touch hedge's state
         self.router = TierRouter(config.query_mesh(), _note_degradation)
+        # last-write-wins masks by snapshot identity (_maybe_dedup)
+        self._lww_masks: collections.OrderedDict = collections.OrderedDict()
+        self._lww_lock = threading.Lock()
         # last_path (which aggregate path served the last query:
         # dense | sparse | sharded | stream) and last_tier live behind
         # thread-local properties below: the background warm thread runs
@@ -1999,7 +2013,7 @@ class PhysicalExecutor:
                 args_t, ops_t, num_groups, sparse,
                 (block_size_for(scan.num_rows),) if sparse
                 else tuple(e.block for e in _block_plan(scan)),
-                self._dedup_rows(scan, table),
+                self._lww_masked(scan, table, ctx),
                 self._value_flags(scan, args_t))
             if self.router.needed(wkey):
                 self.router.kick(
@@ -2160,17 +2174,20 @@ class PhysicalExecutor:
                 # the classic block-sequential association bit-for-bit
                 raise pc.PartialCacheIneligible("multi-block part")
         # LWW dedup is whole-scan: a newer duplicate in part Q can kill
-        # a row in part P, so a masked per-part partial is only
-        # file-pure when no duplicate can CROSS a part seam. Duplicates
-        # share an exact (series, ts) instant, so pairwise-disjoint
-        # part/memtable ts extents prove the dedup part-local — the
-        # sliced global mask then equals the part's own LWW mask
-        # bit-for-bit. Overlapping extents (late writes) fall back.
-        # The mask itself is whole-scan work over whole columns, so it
-        # is only built when a part actually has to be computed.
+        # a row in part P. Duplicates share an exact (series, ts)
+        # instant, so pairwise-disjoint part/memtable ts extents prove
+        # the dedup part-local — the sliced global mask then equals the
+        # part's own LWW mask bit-for-bit, and the mask (whole-scan work
+        # over whole columns) is only built when a part actually has to
+        # be computed. Where extents overlap (late writes, a resent
+        # backlog) a part's partial is still a function of the file's
+        # rows and of the rows of it that lost: the mask is made first
+        # (once a data version, _maybe_dedup) and the slice that falls
+        # on a part that lost rows is part of that partial's key
+        whole = None
         if not table.append_mode and scan.needs_dedup \
                 and not self._parts_ts_disjoint(scan, ts_name):
-            raise pc.PartialCacheIneligible("cross-part dedup")
+            whole = self._maybe_dedup(scan, table, ctx)
 
         acc_dtype = jnp.dtype(config.compute_dtype())
         ops_t = tuple(sorted(ops))
@@ -2189,7 +2206,8 @@ class PhysicalExecutor:
         probed: list[tuple] = []
         delta_est = sum(e.end - e.start for e in mem_entries)
         for pk, (entry,) in parts.items():
-            key = ("part", scan.region_id, pk[0], pk[1], pk[2], fp)
+            key = ("part", scan.region_id, pk[0], pk[1], pk[2], fp,
+                   _lost_rows_digest(whole, entry))
             p = cache.get(key)
             probed.append((key, entry, p))
             if p is None:
@@ -2209,7 +2227,7 @@ class PhysicalExecutor:
                 hkey = incremental_key(
                     schema, shape, tuple(keys), tuple(arg_exprs), ops_t,
                     acc_dtype, num_groups, use_sparse, e.block,
-                    self._dedup_rows(scan, table))
+                    self._lww_masked(scan, table, ctx))
                 if hkey not in cold and self.router.needed(hkey):
                     cold[hkey] = e
         hedge = bool(cold)
@@ -2519,6 +2537,7 @@ class PhysicalExecutor:
             data_version=scan.data_version,
             scan_fingerprint=scan.scan_fingerprint + ("__boundary_fl__",),
         )
+        reduced._tail_start = int(np.searchsorted(idx, send))
         scan._boundary_fl_cache = reduced
         return reduced
 
@@ -3865,11 +3884,13 @@ class PhysicalExecutor:
         """Whether this scan's kernels take a last-write-wins mask."""
         return not table.append_mode and bool(scan.needs_dedup)
 
-    def _dedup_rows(self, scan, table) -> int:
-        """What of the last-write-wins mask a hedge key has to hold: the
-        mask is built by programs over the scan's unpadded rows, so they
-        are compiled per row count; 0 where no mask is passed."""
-        return scan.num_rows if self._dedups(scan, table) else 0
+    def _lww_masked(self, scan, table, ctx) -> bool:
+        """What of the last-write-wins mask a hedge key has to hold:
+        whether the kernels are handed one (a static choice of theirs).
+        The mask is made on the host whatever the row count, so no row
+        count enters a key; it is made here, before the key, because
+        only the merge knows whether a row repeats."""
+        return self._maybe_dedup(scan, table, ctx) is not None
 
     def _value_flags(self, scan, arg_exprs) -> tuple:
         """The value columns' (has NULL, has Inf) flags where the
@@ -3885,34 +3906,56 @@ class PhysicalExecutor:
                 self._scan_has_inf(
                     scan, names, dtype=jnp.dtype(config.compute_dtype())))
 
-    def _maybe_dedup(self, scan: ScanData, table, ctx) -> Optional[jax.Array]:
-        """Device-resident last-write-wins mask (stays on device; sliced
-        per block without a host round-trip). Memoized per ScanData so a
-        query mixing device and host aggregates computes it once."""
+    def _maybe_dedup(self, scan: ScanData, table, ctx) -> Optional[np.ndarray]:
+        """The scan's last-write-wins mask, a host bool per row (blocks
+        of it are uploaded as the kernels ask), or None where every row
+        stays. Kept per snapshot — on the ScanData, and for a region's
+        scan under (region, incarnation, data version, fingerprint), so
+        that a full scan, whose plan is taken anew by every request, is
+        merged once a data version."""
         if not self._dedups(scan, table):
             return None
         cached = getattr(scan, "_dedup_mask_cache", None)
         if cached is not None:
-            return cached
-        mask = self._compute_dedup(scan, table)
-        scan._dedup_mask_cache = mask
-        return mask
-
-    @_staged("device", kernel_step=True)
-    def _compute_dedup(self, scan: ScanData, table) -> jax.Array:
-        tag_names = [c.name for c in table.schema.tag_columns]
-        if tag_names:
-            sizes = [len(scan.tag_dicts[t]) + 1 for t in tag_names]
-            sid = combine_group_ids(
-                [jnp.asarray(scan.columns[t]) + 1 for t in tag_names],
-                sizes, dtype=jnp.int64,
-            )
+            return cached[0]
+        key = None
+        if scan.region_id >= 0 and scan.scan_fingerprint:
+            key = (scan.region_id, scan.incarnation, scan.data_version,
+                   scan.scan_fingerprint)
+            with self._lww_lock:
+                held = self._lww_masks.get(key)
+                if held is not None:
+                    self._lww_masks.move_to_end(key)
         else:
-            sid = jnp.zeros(scan.num_rows, dtype=jnp.int64)
-        ts = jnp.asarray(scan.columns[table.schema.time_index.name])
-        return _dedup_mask(sid, ts, jnp.asarray(scan.seq),
-                           jnp.asarray(scan.op_type),
-                           jnp.ones(scan.num_rows, dtype=bool))
+            held = None
+        if held is None:
+            held = (self._compute_dedup(scan, table),)
+            if key is not None:
+                with self._lww_lock:
+                    self._lww_masks[key] = held
+                    while len(self._lww_masks) > _LWW_MASKS_KEPT:
+                        self._lww_masks.popitem(last=False)
+        scan._dedup_mask_cache = held
+        return held[0]
+
+    def _compute_dedup(self, scan: ScanData, table) -> Optional[np.ndarray]:
+        """Merge the scan's sorted runs on the host (query/lww.py)."""
+        from greptimedb_tpu.query import lww
+        from greptimedb_tpu.utils.metrics import (
+            LWW_MASK_EVENTS,
+            LWW_MASK_SECONDS,
+        )
+
+        tag_names = [c.name for c in table.schema.tag_columns]
+        ts_name = table.schema.time_index.name
+        t0 = time.perf_counter()
+        with tracing.stage("host_agg"), tracing.span(
+                "lww_mask", rows=scan.num_rows) as attrs:
+            mask, path, dups = lww.keep_mask(scan, tag_names, ts_name)
+            attrs.update(path=path, duplicates=dups)
+        LWW_MASK_EVENTS.inc(path=path)
+        LWW_MASK_SECONDS.observe(time.perf_counter() - t0)
+        return mask
 
     # ---- raw (non-aggregate) path ------------------------------------------
 
@@ -4079,11 +4122,23 @@ class PhysicalExecutor:
 # ---- helpers ---------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("start", "end", "block"))
-@device_telemetry.kernel_name("pad_device_mask")
-def _pad_device_mask(mask: jax.Array, start: int, end: int, block: int) -> jax.Array:
-    sl = jax.lax.dynamic_slice_in_dim(mask, start, end - start)
-    return jnp.pad(sl, (0, block - (end - start)), constant_values=False)
+def _lost_rows_digest(mask: Optional[np.ndarray], entry) -> Optional[str]:
+    """What a part's cached partial depends on besides its file: which
+    of its rows lost to a later write elsewhere in the scan. None where
+    none did (the partial is the file's own)."""
+    if mask is None or mask[entry.start:entry.end].all():
+        return None
+    rows = mask[entry.start:entry.end]
+    return hashlib.blake2b(np.packbits(rows).tobytes(),
+                           digest_size=8).hexdigest()
+
+
+def _pad_device_mask(mask: np.ndarray, start: int, end: int, block: int) -> jax.Array:
+    """One block of the host's last-write-wins mask, padded False, on
+    the device: the block's shape, whatever the scan's row count."""
+    out = np.zeros(block, dtype=bool)
+    out[:end - start] = mask[start:end]
+    return jnp.asarray(out)
 
 
 def _unpack_acc(packed_f, packed_i, float_ops, int_ops, widths):

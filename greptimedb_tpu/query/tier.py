@@ -156,7 +156,7 @@ def part_placement(mesh, tier: str, scan) -> Callable:
 
 
 def whole_scan_key(schema, shape, keys, agg_args, ops, num_groups, sparse,
-                   blocks: tuple, dedup_rows: int,
+                   blocks: tuple, lww_masked: bool,
                    value_flags: tuple) -> tuple:
     """Hedge key of a classic whole-scan aggregate: every static input
     of the programs the device would run, and nothing a request draws.
@@ -166,18 +166,18 @@ def whole_scan_key(schema, shape, keys, agg_args, ops, num_groups, sparse,
     start share the key as they share the executable. In place of the
     region, its data version and the scan's fingerprint stand what those
     stood for in the programs: the table's schema, the block layout of
-    the scan, the row count the last-write-wins mask is built over (0
-    where none rides along: its programs take the scan's unpadded rows),
-    and the value columns' NULL / Inf flags the kernel choice reads. A
+    the scan, whether a last-write-wins mask rides along (the host
+    makes it, whatever the row count: query/lww.py), and the value
+    columns' NULL / Inf flags the kernel choice reads. A
     key missing one of them would declare a DIFFERENT program warm and
     block the foreground on its cold compile."""
     return (schema, repr(shape), repr(keys), repr(agg_args), ops,
-            num_groups, sparse, blocks, dedup_rows, value_flags)
+            num_groups, sparse, blocks, lww_masked, value_flags)
 
 
 def incremental_key(schema, shape, keys, agg_args, ops, acc_dtype,
                     num_groups, sparse, block: int,
-                    dedup_rows: int) -> tuple:
+                    lww_masked: bool) -> tuple:
     """Hedge key of one part's incremental fold: the static inputs of
     the per-part kernel — `whole_scan_key`'s, with the one block size
     this part pads to (a request is warm once every block size it folds
@@ -186,7 +186,7 @@ def incremental_key(schema, shape, keys, agg_args, ops, acc_dtype,
     `repr(bound_where)`; a compiled program does not, so this one holds
     the shape."""
     return (schema, repr(shape), repr(keys), repr(agg_args), ops,
-            str(acc_dtype), num_groups, sparse, block, dedup_rows)
+            str(acc_dtype), num_groups, sparse, block, lww_masked)
 
 
 class TierRouter:

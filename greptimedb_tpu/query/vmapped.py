@@ -746,10 +746,9 @@ def _region_partial_inner(executor, region_id, vm, schema, append_mode,
             arr = arr.astype(acc_dtype)
         dev_cols[name] = jnp.asarray(arr)
     base = np.arange(n_pad) < n
-    base = jnp.asarray(base)
     if dedup_mask is not None:
-        base = base & jnp.concatenate(
-            [dedup_mask, jnp.zeros(n_pad - n, dtype=bool)])
+        base[:n] &= np.asarray(dedup_mask)[:n]
+    base = jnp.asarray(base)
     shared_where, shared_args, _ = ph._operands(bound_shared, keys, schema)
     out = _vmapped_partial_scan(
         dev_cols, base, params, shared_args,

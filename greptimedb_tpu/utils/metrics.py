@@ -631,6 +631,22 @@ AGG_SCAN = REGISTRY.counter(
     "SST part), parts (it fetched only the parts it missed), whole "
     "(whole columns were built or read: every other path); counted once "
     "per statement, a statement that scans twice counts its costliest")
+LWW_MASK_EVENTS = REGISTRY.counter(
+    "greptimedb_tpu_lww_mask_events_total",
+    "Last-write-wins masks made for scans of tables that are not "
+    "append-mode, by path: none (one pass over the scan's keys proved "
+    "that no row repeats: no mask), host_merge (the host merged the "
+    "scan's sorted runs)")
+LWW_MASK_SECONDS = REGISTRY.histogram(
+    "greptimedb_tpu_lww_mask_seconds",
+    "Host wall time of making one scan's last-write-wins mask: packing "
+    "the rows' (primary key, ts) into keys, the pass that proves them "
+    "ascending, and the merge of the sorted runs where they are not")
+DERIVED_SELECT_SECONDS = REGISTRY.histogram(
+    "greptimedb_tpu_derived_select_seconds",
+    "Host wall time of the outer select over a derived table, CTE or "
+    "join: factorizing its group keys and reducing the inner result's "
+    "columns as arrays")
 AGG_PROGRAM_EVENTS = REGISTRY.counter(
     "greptimedb_tpu_agg_program_events_total",
     "Dispatches of a jitted aggregate or filter step, by what the "
@@ -805,7 +821,10 @@ FAST_LANE_EVENTS = REGISTRY.sharded_counter(
     "ambiguous literals, comments, non-SELECT verbs, plugins, pending "
     "rollup-substitution probes — invalidate = entries dropped by DDL "
     "or a TableInfo drift check, coalesced = concurrent identical "
-    "requests that rode another request's in-flight execution)")
+    "requests that rode another request's in-flight execution, "
+    "stale_flight = a request that found an identical execution in "
+    "flight over an older version of the table's data than it saw on "
+    "arrival, and started one of its own)")
 STAGE_SECONDS = REGISTRY.histogram(
     "greptimedb_tpu_query_stage_seconds",
     "Per-request serving-stage wall time by stage, observed by the "

@@ -146,12 +146,22 @@ class TestParity:
         assert qe.executor.last_path == "incremental"
         assert_same(classic, inc)
         # a late write INSIDE an old part's extent voids disjointness:
-        # typed fallback, still correct
+        # the whole-scan mask is made first, and a part that lost a row
+        # to it keys its partial by the rows it lost (PR 38) — still the
+        # cache's path, still correct
         qe.execute_one("INSERT INTO lww VALUES (15, 'h0', 999, 0.0)",
                        CTX)
         classic2, inc2, _ = run_both(qe, sql)
-        assert qe.executor.last_path != "incremental"
+        assert qe.executor.last_path == "incremental"
         assert_same(classic2, inc2)
+        # the late write REPEATS an instant of the first file: that
+        # part's cached partial must not answer for the row that lost
+        qe.execute_one("INSERT INTO lww VALUES (10, 'h1', 777, 0.0)",
+                       CTX)
+        classic3, inc3, _ = run_both(qe, sql)
+        assert qe.executor.last_path == "incremental"
+        assert_same(classic3, inc3)
+        assert classic3.rows() != classic2.rows()
 
 
 class TestDeltaFold:

@@ -467,7 +467,7 @@ def test_kernels_carry_their_stable_names():
             "agg_block", "agg_block_sparse", "prep_stream_step", "agg_step",
             "pallas_fused_segment_agg", "pallas_dense_segment_sum",
             "sort_compact", "sparse_segment_agg", "sort_dedup",
-            "dedup_mask", "segment_agg", "window_stats", "window_edges",
+            "segment_agg", "window_stats", "window_edges",
             "window_edges_grid", "window_sums_grid", "counter_adjust",
             "extrapolated_delta", "promql_dedup", "histogram_fold"} \
         <= device_telemetry.KERNEL_NAMES
