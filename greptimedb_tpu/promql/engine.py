@@ -43,6 +43,7 @@ from greptimedb_tpu.promql.loaded import (
     SeriesCache,
     covered,
     d2h,
+    derive,
     h2d,
 )
 from greptimedb_tpu.promql.parser import (
@@ -62,6 +63,7 @@ from greptimedb_tpu.promql.parser import (
 from greptimedb_tpu.query.result import QueryResult
 from greptimedb_tpu.utils import device_telemetry, tracing
 from greptimedb_tpu.utils.metrics import (
+    PROMQL_GROUP_INDEXES,
     PROMQL_HISTOGRAM_FOLD_SECONDS,
     PROMQL_HISTOGRAM_FOLDS,
     PROMQL_LOAD_CACHE_EVENTS,
@@ -948,7 +950,7 @@ class PromqlEngine:
         if not isinstance(v, SeriesMatrix):
             raise PromqlError("histogram_quantile needs an instant vector")
         t0 = time.perf_counter()
-        index, how = _fold_index(v.labels)
+        index, how = derive(v.labels, "histogram_fold", _build_fold_index)
         t1 = time.perf_counter()
         PROMQL_HISTOGRAM_FOLD_SECONDS.observe(t1 - t0, phase="index")
         PROMQL_HISTOGRAM_FOLDS.inc(index=how)
@@ -1035,41 +1037,16 @@ class PromqlEngine:
         if v.num_series == 0:
             return SeriesMatrix([], jnp.zeros((0, p.T)))
 
-        # group signatures: label sets of the output, built on the host
-        with tracing.stage("assemble", step="group_labels"):
-            sigs = []
-            out_labels = []
-            for lab in v.labels:
-                if agg.by:
-                    kept = {k: lab.get(k, "") for k in agg.by if k in lab}
-                elif agg.without:
-                    kept = {k: x for k, x in lab.items()
-                            if k not in agg.without}
-                elif agg.grouping:
-                    kept = {}
-                else:
-                    kept = {}
-                sigs.append(tuple(sorted(kept.items())))
-                out_labels.append(kept)
-            # factorise the signatures: one dictionary pass numbers
-            # them as first seen, the distinct ones are ranked, and the
-            # group index is a gather (no search per series)
-            seen: dict = {}
-            first_seen = np.fromiter(
-                (seen.setdefault(s, len(seen)) for s in sigs),
-                dtype=np.int32, count=len(sigs))
-            uniq = sorted(seen)
-            rank = np.empty(len(uniq), dtype=np.int32)
-            rank[[seen[u] for u in uniq]] = np.arange(len(uniq),
-                                                      dtype=np.int32)
-            gidx = rank[first_seen]
-            G = len(uniq)
-            glabels = [dict(u) for u in uniq]
-            if isinstance(v.labels, LabelSets):
-                # the groups' label sets depend on the input's and on
-                # the grouping alone
-                glabels = v.labels.step(
-                    ("group", agg.by, agg.without), glabels)
+        # the group index depends on the input's label sets and the
+        # grouping alone: found beside the loaded series they derive
+        # from, or built (for a list of no known origin, per request)
+        with tracing.stage("assemble", step="group_labels",
+                           series=v.num_series) as attrs:
+            grp, how = derive(v.labels, "group_index", _build_group_index,
+                              agg.by, agg.without)
+            attrs["index"] = how
+        PROMQL_GROUP_INDEXES.inc(index=how)
+        gidx, G, glabels = grp.gidx, grp.G, grp.labels
 
         vals = v.values  # [S, T]
         if agg.op in ("sum", "avg", "min", "max", "count", "group",
@@ -1082,8 +1059,7 @@ class PromqlEngine:
                 "stdvar": ("sum", "sumsq", "count"),
             }[agg.op]
             need = set(ops) | {"count"}
-            st = segment_agg(vals, h2d(gidx),
-                             jnp.ones(v.num_series, bool), G,
+            st = segment_agg(vals, grp.d_gidx, grp.mask, G,
                              ops=tuple(sorted(need)))
             cnt = st["count"]
             present = cnt > 0
@@ -1386,6 +1362,51 @@ def _edges_enabled() -> bool:
                           "on").lower() not in ("off", "0", "false")
 
 
+@dataclass(frozen=True)
+class _GroupIndex:
+    """Which group each input series of an aggregation falls in: a
+    function of the input's label sets and the grouping. Shared between
+    requests and threads where it is kept: nothing writes to it."""
+
+    gidx: np.ndarray  # [S] int32 group of each input series (read-only)
+    d_gidx: jax.Array  # [S] the same on the device
+    mask: jax.Array  # [S] bool on the device, all true
+    G: int
+    labels: list  # [G] the groups' label sets, in signature order
+
+
+def _build_group_index(labels: list, by: tuple, without: tuple) -> _GroupIndex:
+    """Keep `by`'s labels of each series, or all but `without`'s, or
+    none; number the distinct signatures as first seen in one dictionary
+    pass, rank them, and the group index is a gather (no search per
+    series)."""
+    sigs = []
+    for lab in labels:
+        if by:
+            kept = {k: lab.get(k, "") for k in by if k in lab}
+        elif without:
+            kept = {k: x for k, x in lab.items() if k not in without}
+        else:
+            kept = {}
+        sigs.append(tuple(sorted(kept.items())))
+    seen: dict = {}
+    first_seen = np.fromiter(
+        (seen.setdefault(s, len(seen)) for s in sigs),
+        dtype=np.int32, count=len(sigs))
+    uniq = sorted(seen)
+    rank = np.empty(len(uniq), dtype=np.int32)
+    rank[[seen[u] for u in uniq]] = np.arange(len(uniq), dtype=np.int32)
+    gidx = rank[first_seen]
+    gidx.setflags(write=False)
+    glabels = [dict(u) for u in uniq]
+    if isinstance(labels, LabelSets):
+        # the groups' label sets depend on the input's and on the
+        # grouping alone
+        glabels = labels.step(("group", by, without), glabels)
+    return _GroupIndex(gidx, h2d(gidx), jnp.ones(len(sigs), bool),
+                       len(uniq), glabels)
+
+
 @dataclass
 class _FoldIndex:
     """Where histogram_quantile's input series go in the [groups,
@@ -1396,25 +1417,6 @@ class _FoldIndex:
     bounds: jax.Array  # [G', B] float64 `le` of each slot, ascending
     valid: jax.Array  # [G', B] bool: a bucket, not padding
     skipped: int  # input series whose `le` is no number
-
-
-def _fold_index(labels: list) -> tuple:
-    """(the fold index of `labels`, "hit" | "build"): kept beside the
-    loaded series the label sets derive from (LabelSets.derived), so
-    built once per (input label sets, data version); label sets of no
-    known origin get theirs built per request."""
-    root = getattr(labels, "root", None)
-    if root is None:
-        return _build_fold_index(labels), "build"
-    key = ("histogram_fold", labels.path)
-    with root.lock:
-        index = root.derived.get(key)
-    if index is not None:
-        return index, "hit"
-    index = _build_fold_index(labels)
-    with root.lock:
-        root.derived[key] = index
-    return index, "build"
 
 
 def _build_fold_index(labels: list) -> _FoldIndex:

@@ -72,10 +72,14 @@ class LabelSets(list):
     selector at one data version. What an evaluation derives from them
     by a rule that reads nothing else (`sum by (le, handler)`'s output
     label sets) is named by the root list and the steps taken from it,
-    so whatever depends on the label sets alone — histogram_quantile's
-    fold index — is kept in the root's `derived`, found again by the
-    next request, and dropped with the samples. A plain list has no
-    such name, and what is derived from it is derived per request."""
+    so whatever depends on the label sets alone — an aggregation's
+    group index, histogram_quantile's fold index — is kept in the
+    root's `derived` (`derive`), found again by the next request, and
+    dropped with the samples. A plain list has no such name, and what
+    is derived from it is derived per request. What is kept is shared
+    between requests and threads: whatever changes a label set copies
+    it first, and a step that `how` does not name completely hands on
+    a plain list."""
 
     def __init__(self, labels=(), root: Optional["LabelSets"] = None,
                  path: tuple = ()):
@@ -84,13 +88,47 @@ class LabelSets(list):
         #: the steps from the root's label sets to these
         self.path = path
         if root is None:
-            #: (what, path) -> whatever was derived; the root's only
+            #: (what, path, *args) -> whatever was derived, or the Event
+            #: of the thread deriving it now; the root's only
             self.derived: dict = {}
             self.lock = threading.Lock()
 
     def step(self, how: tuple, labels: list) -> "LabelSets":
         """`labels`, derived from these by the rule `how` names."""
         return LabelSets(labels, self.root, self.path + (how,))
+
+
+def derive(labels: list, what: str, build, *args) -> tuple:
+    """(`build(labels, *args)`, "hit" | "build"): what depends on the
+    label sets and `args` alone. Label sets that know their origin keep
+    it beside the loaded series they derive from, so it is built once
+    per (label sets, args, data version): a request that arrives while
+    another builds waits for that build and does not run its own. A
+    plain list's is built for this request."""
+    if not isinstance(labels, LabelSets):
+        return build(labels, *args), "build"
+    root = labels.root
+    key = (what, labels.path) + args
+    while True:
+        with root.lock:
+            entry = root.derived.get(key)
+            if entry is None:
+                built = root.derived[key] = threading.Event()
+                break
+        if not isinstance(entry, threading.Event):
+            return entry, "hit"
+        entry.wait()  # then look again: a build that failed left nothing
+    try:
+        value = build(labels, *args)
+        with root.lock:
+            root.derived[key] = value
+        return value, "build"
+    except BaseException:
+        with root.lock:
+            del root.derived[key]
+        raise
+    finally:
+        built.set()
 
 
 class LoadedSeries:

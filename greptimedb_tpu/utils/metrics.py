@@ -584,6 +584,13 @@ PROMQL_HISTOGRAM_FOLDS = REGISTRY.counter(
     "(hit = kept beside the loaded series the input's label sets derive "
     "from, at their data version; build = built from the label sets for "
     "this request)")
+PROMQL_GROUP_INDEXES = REGISTRY.counter(
+    "greptimedb_tpu_promql_group_index_total",
+    "PromQL aggregations by where the group index came from (hit = kept "
+    "beside the loaded series the input's label sets derive from, on the "
+    "host and on the device, at their data version; build = built from "
+    "the label sets and uploaded for this request: a first touch, or "
+    "label sets of no known origin)")
 DEVICE_HOT_SET_EVENTS = REGISTRY.counter(
     "greptimedb_tpu_device_hot_set_events_total",
     "HBM-resident columnar hot set events by kind (hit/miss/evict/pin — "

@@ -358,10 +358,31 @@ def test_promql_moves_the_transfer_counters(server):
     server.promql("sum by (host) (avg_over_time(m[2m]))")
     c = moved()
     assert c[2] == b[2] + 1 and c[3] == b[3]
-    assert 0 < c[0] - b[0] < 1600 * 8  # the grid and the group index only
+    # nothing goes up again: the samples are resident and, since
+    # ISSUE 39, so is the aggregation's group index
+    assert c[0] == b[0]
     # the answer alone: 8 series x 121 steps of float64 (the first
     # evaluation also read the sample grid back to pivot it)
     assert c[1] - b[1] == 8 * 121 * 8 < b[1] - a[1]
+
+
+def test_the_group_labels_segment_carries_index_and_series(server):
+    """ISSUE 39: the `assemble` segment of an aggregation's group index
+    says how many input series it grouped and whether the index was
+    kept beside the loaded series (`hit`) or built for the request."""
+    q = "count without (zone) (avg_over_time(m[3m]))"  # this test's alone
+
+    def segments():
+        return [s for s in server.spans_of(server.promql(q))
+                if s.stage and s.name == "assemble"
+                and s.attrs.get("step") == "group_labels"]
+
+    first, again = segments(), segments()
+    assert all(s.attrs["series"] == 8 for s in first + again)
+    # a build uploads the index: stage `upload` cuts the segment in two
+    # and the closing one says which
+    assert [s.attrs.get("index") for s in first][-1] == "build"
+    assert [s.attrs.get("index") for s in again] == ["hit"]
 
 
 def test_promql_slow_query_record_carries_its_stage_tree(server,
