@@ -246,42 +246,6 @@ def _running_queries(qe, ctx):
     return cols
 
 
-@_virtual("cluster_profile")
-def _cluster_profile(qe, ctx):
-    """Merged continuous-profiling view (utils/flame.py): one row per
-    (node × coarse stage) from the local sampler plus every datanode
-    digest that rode in on Flight piggybacks or heartbeats. Empty when
-    profiling is disabled everywhere. The `share` column is that
-    stage's fraction of the node's samples; `top_frames` names the
-    node's hottest self-time frames."""
-    from greptimedb_tpu.utils import flame
-
-    cols = {k: [] for k in (
-        "node", "stage", "stage_samples", "share", "node_samples",
-        "attributed_ratio", "hz", "window_s", "captured_at",
-        "top_frames")}
-    view = flame.cluster_view()
-    for node in sorted(view["nodes"]):
-        summ = view["nodes"][node]
-        total = summ.get("samples", 0) or 0
-        top = "; ".join(f"{r['frame']} x{r['self']}"
-                        for r in (summ.get("top") or [])[:3])
-        for stage, n in sorted((summ.get("stages") or {}).items()):
-            cols["node"].append(node)
-            cols["stage"].append(stage)
-            cols["stage_samples"].append(int(n))
-            cols["share"].append(round(n / total, 4) if total else 0.0)
-            cols["node_samples"].append(int(total))
-            cols["attributed_ratio"].append(
-                round(summ.get("attributed", 0) / total, 4) if total
-                else 0.0)
-            cols["hz"].append(float(summ.get("hz", 0.0)))
-            cols["window_s"].append(float(summ.get("window_s", 0.0)))
-            cols["captured_at"].append(int(summ.get("ts_ms", 0)))
-            cols["top_frames"].append(top)
-    return cols
-
-
 @_virtual("cluster_faults")
 def _cluster_faults(qe, ctx):
     """Armed chaos state + fire counts (fault/ package): one row per

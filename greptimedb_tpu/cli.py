@@ -179,19 +179,29 @@ def cmd_standalone(args):
     try:
         _wait_stop()
     finally:
-        if task is not None:
-            task.stop()
-        if telemetry is not None:
-            telemetry.stop()
-        for s in servers:
-            try:
-                s.stop()
-            except AttributeError:
-                s.shutdown()
-        # reclaim encode workers deterministically (spawn-mode worker
-        # PROCESSES especially must not outlive a clean shutdown)
-        qe.concurrency.shutdown()
-        engine.close()
+        stop_standalone(engine, qe, servers,
+                        [t for t in (task, telemetry) if t is not None])
+
+
+def stop_standalone(engine, qe, servers=(), tasks=()) -> None:
+    """Stop what `cmd_standalone` started, in its order: background
+    tasks, servers, the encode workers, the engine, and the
+    interpreter-lock probe `build_standalone` started with the
+    observability plane."""
+    from greptimedb_tpu.utils import lock_probe
+
+    for t in tasks:
+        t.stop()
+    for s in servers:
+        try:
+            s.stop()
+        except AttributeError:
+            s.shutdown()
+    # reclaim encode workers deterministically (spawn-mode worker
+    # PROCESSES especially must not outlive a clean shutdown)
+    qe.concurrency.shutdown()
+    engine.close()
+    lock_probe.shutdown()
 
 
 def threading_start(flight_server):

@@ -29,7 +29,6 @@ def main() -> None:
 
     from greptimedb_tpu.servers.flight import FlightServer
     from greptimedb_tpu.storage.engine import EngineConfig, RegionEngine
-    from greptimedb_tpu.utils import flame
     from greptimedb_tpu.utils.otlp_trace import maybe_install
     from greptimedb_tpu.utils.tracing import install_trace_logging
 
@@ -37,9 +36,6 @@ def main() -> None:
     # inherited GTPU_OTLP_ENDPOINT: datanode children export their own
     # spans under the same trace ids the frontend propagates
     maybe_install()
-    # inherited GTPU_PROFILE*: the child samples itself and its digest
-    # rides Flight piggybacks into the frontend's cluster profile
-    flame.maybe_install()
 
     def _env_num(name, default, cast):
         try:

@@ -35,7 +35,7 @@ import threading
 from concurrent.futures import ProcessPoolExecutor, ThreadPoolExecutor
 from typing import Optional
 
-from greptimedb_tpu.utils import deadline
+from greptimedb_tpu.utils import deadline, tracing
 from greptimedb_tpu.utils.metrics import (
     ENCODE_POOL_EVENTS,
     ENCODE_POOL_QUEUE_DEPTH,
@@ -87,6 +87,17 @@ def worker_jax_platforms() -> str:
     import jax
 
     return jax.config.jax_platforms
+
+
+def _beside_the_request(fn, *args):
+    """A THREAD worker's encode runs under `tracing.propagate`, as a
+    `bg:encode` span of the request it works for: the request thread
+    parked on the future is off the CPU without waiting for the
+    interpreter lock, and this thread's CPU — which does take that lock
+    — counts under stage="background". (A worker PROCESS's CPU is
+    outside this process and is not seen.)"""
+    with tracing.stage("encode"):
+        return fn(*args)
 
 
 class EncodePool:
@@ -213,8 +224,11 @@ class EncodePool:
                 if shm_results is not None:
                     fut = self._pool(process).submit(
                         shm_results.shm_encode, fn, *args)
-                else:
+                elif process:
                     fut = self._pool(process).submit(fn, *args)
+                else:
+                    fut = self._pool(process).submit(
+                        tracing.propagate(_beside_the_request), fn, *args)
             except RuntimeError:
                 # executor torn down concurrently (submit after
                 # shutdown): the request still gets its bytes. Errors

@@ -72,16 +72,9 @@ class HeartbeatTask:
             # no lease renewal, the failure detector's phi keeps climbing
             return None
         stats = self.stats_fn()
-        # profiling digest rides the beat (the second rollup seam next
-        # to the Flight piggyback): the metasrv aggregates a cluster
-        # profile view even for nodes the frontend never scanned through
-        from greptimedb_tpu.utils import flame
-
-        profile = flame.summary(node=str(self.node_id)) \
-            if flame.running() else None
         resp = self.metasrv.handle_heartbeat(
             HeartbeatRequest(node_id=self.node_id, region_stats=stats,
-                             now_ms=now_ms, profile=profile)
+                             now_ms=now_ms)
         )
         if not resp.leader:
             # redirected by a follower: no lease grant in this response —

@@ -61,9 +61,6 @@ class HeartbeatRequest:
     node_id: str
     region_stats: list[RegionStat] = field(default_factory=list)
     now_ms: Optional[float] = None
-    #: optional continuous-profiling digest (utils/flame.summary()) —
-    #: the heartbeat half of the cluster profile rollup
-    profile: Optional[dict] = None
 
 
 @dataclass
@@ -94,7 +91,6 @@ class Metasrv:
         self.selector: Selector = SELECTORS[self.opts.selector]()
         self._detectors: dict[str, PhiAccrualFailureDetector] = {}
         self._node_stats: dict[str, dict] = {}
-        self._node_profiles: dict[str, dict] = {}
         self._node_regions: dict[str, dict[int, RegionStat]] = {}
         self._pending: dict[str, list[Instruction]] = {}
         self._failed_over: set[str] = set()  # nodes already handled
@@ -227,12 +223,6 @@ class Metasrv:
         with self._lock:
             return dict(self._node_stats)
 
-    def node_profiles(self) -> dict[str, dict]:
-        """Latest continuous-profiling digest per node (heartbeat-fed;
-        nodes with profiling off simply never appear)."""
-        with self._lock:
-            return dict(self._node_profiles)
-
     # ------------------------------------------------------------ heartbeat
     def handle_heartbeat(self, req: HeartbeatRequest) -> HeartbeatResponse:
         """The heartbeat handler pipeline (meta-srv/src/handler.rs):
@@ -271,8 +261,6 @@ class Metasrv:
                 "write_bytes": sum(s.memtable_bytes for s in req.region_stats),
                 "last_heartbeat_ms": now_ms,
             }
-            if req.profile is not None:
-                self._node_profiles[req.node_id] = req.profile
             instructions = self._pending.pop(req.node_id, [])
             lease = now_ms + self.opts.region_lease_s * 1000
             if self.election is not None:

@@ -347,27 +347,6 @@ class TracingOptions:
 
 
 @dataclass
-class ProfilingOptions:
-    """Continuous profiling plane (utils/flame.py): a bounded always-on
-    stack sampler attributing samples to query stage + execution path,
-    served at /v1/profile/flame (folded stacks / speedscope) and rolled
-    up cluster-wide at /v1/profile/cluster. Env twins (children
-    inherit): GTPU_PROFILE, GTPU_PROFILE_HZ, GTPU_PROFILE_WINDOW_S,
-    GTPU_PROFILE_WINDOWS."""
-
-    # master switch; overhead budget is <= 2% of served qps
-    enabled: bool = True
-    # sampling rate; 19 Hz is deliberately co-prime with common 10/20/
-    # 100 Hz periodic loops so flames don't alias against them
-    hz: float = 19.0
-    # one rolling flame window's span (seconds)
-    window_s: float = 30.0
-    # windows retained; the flame view covers hz*window_s*windows
-    # seconds of history in bounded memory
-    windows: int = 10
-
-
-@dataclass
 class SlowQueryOptions:
     """Slow-query log (reference slow-query timer options): statements
     slower than the threshold land in a bounded ring surfaced via
@@ -440,7 +419,6 @@ class StandaloneOptions:
     parallel: ParallelOptions = field(default_factory=ParallelOptions)
     partition: PartitionOptions = field(default_factory=PartitionOptions)
     tracing: TracingOptions = field(default_factory=TracingOptions)
-    profiling: ProfilingOptions = field(default_factory=ProfilingOptions)
     slow_query: SlowQueryOptions = field(default_factory=SlowQueryOptions)
     metrics: MetricsOptions = field(default_factory=MetricsOptions)
     telemetry: TelemetryOptions = field(default_factory=TelemetryOptions)
@@ -694,12 +672,18 @@ def apply_shm(opts: "StandaloneOptions") -> None:
 def apply_observability(opts: "StandaloneOptions") -> None:
     """Feed [slow_query] into the process-global slow-query ring,
     install the trace-id logging filter, and apply the [tracing] plane
-    (span recording switch + OTLP exporter). An operator-set env var
+    (span recording switch + OTLP exporter + the interpreter-lock
+    probe, which runs where span recording is on). An operator-set env var
     (GTPU_SLOW_QUERY_MS, GTPU_TRACING, GTPU_OTLP_*) wins over the
     config file (documented layering: env beats file for kernel/runtime
     knobs); the [tracing] values are written THROUGH env so child
     datanode processes inherit them."""
-    from greptimedb_tpu.utils import otlp_trace, slow_query, tracing
+    from greptimedb_tpu.utils import (
+        lock_probe,
+        otlp_trace,
+        slow_query,
+        tracing,
+    )
 
     tracing.install_trace_logging()
     dq = SlowQueryOptions()
@@ -725,20 +709,6 @@ def apply_observability(opts: "StandaloneOptions") -> None:
             (str(t.flush_interval_s)
              if t.flush_interval_s != dt.flush_interval_s else "", ""),
     }
-    # [profiling] rides the same env-is-truth layering: the continuous
-    # sampler's knobs are written through GTPU_PROFILE_* so child
-    # datanode processes sample (and piggyback digests) identically
-    from greptimedb_tpu.utils import flame
-
-    p, dp = opts.profiling, ProfilingOptions()
-    env_tracing.update({
-        "GTPU_PROFILE": ("" if p.enabled else "off", ""),
-        "GTPU_PROFILE_HZ": (str(p.hz) if p.hz != dp.hz else "", ""),
-        "GTPU_PROFILE_WINDOW_S":
-            (str(p.window_s) if p.window_s != dp.window_s else "", ""),
-        "GTPU_PROFILE_WINDOWS":
-            (str(p.windows) if p.windows != dp.windows else "", ""),
-    })
     for k, (v, default) in env_tracing.items():
         if v and v != default and (k not in os.environ
                                    or k in _APPLIED_ENV_KEYS):
@@ -748,7 +718,7 @@ def apply_observability(opts: "StandaloneOptions") -> None:
             os.environ.pop(k, None)
             _APPLIED_ENV_KEYS.discard(k)
     otlp_trace.maybe_install()
-    flame.maybe_install()
+    lock_probe.maybe_install()
 
 
 def engine_config(opts: "StandaloneOptions", data_dir: str):
@@ -993,16 +963,6 @@ _DOC = {
         "Bounded OTLP exporter queue; overflow drops spans (counted).",
     "tracing.flush_interval_s":
         "Max seconds a completed span waits before its batch is posted.",
-    "profiling.enabled":
-        "Always-on background stack sampler feeding /v1/profile/flame "
-        "(A/B knob: GTPU_PROFILE).",
-    "profiling.hz":
-        "Sampler frequency; the overhead budget is <= 2% at the default.",
-    "profiling.window_s":
-        "Seconds per rolling flame window (GTPU_PROFILE_WINDOW_S).",
-    "profiling.windows":
-        "Rolling windows retained; older samples age out "
-        "(GTPU_PROFILE_WINDOWS).",
     "slow_query.threshold_ms": "Log statements slower than this; <= 0 disables.",
     "slow_query.ring_size": "Bounded slow-query ring capacity.",
     "metrics.enable": "Self-scrape /metrics into a table periodically.",

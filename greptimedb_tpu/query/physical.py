@@ -79,7 +79,6 @@ from greptimedb_tpu.storage.region import (
     scan_io_since,
 )
 from greptimedb_tpu.utils import device_telemetry, tracing
-from greptimedb_tpu.utils import flame as _flame
 
 # XLA compile + device memory telemetry rides jax.monitoring: one
 # listener covers every jax.jit entry point in this module and ops/
@@ -1351,11 +1350,6 @@ class PhysicalExecutor:
     @last_path.setter
     def last_path(self, v):
         self._tls.last_path = v
-        # the continuous profiler attributes samples by execution path;
-        # this setter is the single choke point every path tag flows
-        # through (one attribute read when profiling is off)
-        if _flame._ENABLED:
-            _flame.note_path(v)
 
     @property
     def last_tier(self):

@@ -372,15 +372,6 @@ class FlightServer(fl.FlightServerBase):
         meta = dict(table.schema.metadata or {})
         meta[b"spans"] = json.dumps(tracing.spans_to_wire(sink)).encode()
         meta[b"node"] = str(self.node_id).encode()
-        # continuous-profiling rollup rides the same seam: a compact
-        # flame/ledger digest per response, so the frontend's
-        # /v1/profile/cluster view covers every datanode it talked to
-        # without a second RPC
-        from greptimedb_tpu.utils import flame
-
-        if flame.running():
-            meta[b"profile"] = json.dumps(
-                flame.summary(node=f"datanode-{self.node_id}")).encode()
         return table.replace_schema_metadata(meta)
 
     def _region_scan(self, req: dict):
@@ -782,19 +773,12 @@ class RemoteRegionEngine:
             if isinstance(meta, dict) and b"spans" in meta:
                 wire = json.loads(meta[b"spans"].decode())
                 node = meta.get(b"node", b"").decode() or self.addr
-                prof = meta.get(b"profile")
-                prof = json.loads(prof.decode()) if prof else None
             elif isinstance(meta, dict) and "spans" in meta:
                 wire = meta["spans"]
                 node = meta.get("node") or self.addr
-                prof = meta.get("profile")
             else:
                 return
             tracing.merge_spans(wire, node=node)
-            if prof:
-                from greptimedb_tpu.utils import flame
-
-                flame.note_node_summary(prof.get("node") or node, prof)
         except (ValueError, KeyError, AttributeError):
             pass  # a mangled piggyback must never fail the query
 

@@ -355,28 +355,6 @@ class _Handler(BaseHTTPRequestHandler):
                     "jobs": [j.to_dict()
                              for j in (maint.jobs() if maint else [])[:n]],
                 })
-            if path == "/v1/profile/flame":
-                # continuous profiler's rolling flame windows (auth-
-                # gated like /v1/traces: stack frames leak code layout).
-                # Default folded stacks (text); ?format=speedscope for
-                # the JSON document; ?stage= filters to one stage
-                from greptimedb_tpu.utils import flame
-
-                params = self._params()
-                if not flame.running():
-                    return self._send(503, {
-                        "error": "continuous profiling is disabled "
-                                 "(enable [profiling] / GTPU_PROFILE)"})
-                if params.get("format", "folded") == "speedscope":
-                    return self._send(200, flame.speedscope())
-                out = flame.folded(stage=params.get("stage") or None)
-                return self._send(200, out.encode(), "text/plain")
-            if path == "/v1/profile/cluster":
-                # merged cluster profile: this node + every digest that
-                # rode in on Flight piggybacks / heartbeats
-                from greptimedb_tpu.utils import flame
-
-                return self._send(200, flame.cluster_view())
             if path == "/v1/slow_queries":
                 # debug surface of the slow-query ring; behind the auth
                 # gate (query text is sensitive, unlike /metrics)

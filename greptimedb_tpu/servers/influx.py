@@ -25,6 +25,7 @@ from typing import Optional
 import numpy as np
 
 from greptimedb_tpu.ingest import TableSlab, write_slabs
+from greptimedb_tpu.utils import ledger
 from greptimedb_tpu.utils.metrics import INGEST_ROWS
 
 __all__ = ["LineProtocolError", "Point", "parse_line_protocol",
@@ -488,6 +489,8 @@ def write_lines(query_engine, db: str, text: str,
 
     from greptimedb_tpu.query.engine import QueryContext
 
+    # the request root observes ingest_request_cpu_seconds by this mark
+    ledger.add("ingest_requests")
     scale = _PRECISION_TO_MS.get(precision)
     if scale is None:
         raise LineProtocolError(f"bad precision {precision!r}")

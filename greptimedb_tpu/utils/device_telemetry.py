@@ -117,7 +117,7 @@ def _on_scalar(event: str, value, **kwargs) -> None:
         fn = "eager"
     thread = "request" if tracing.current_trace_id() is not None \
         and not tracing.in_warmup() else "warmup"
-    sp = tracing.span("compile", fn=fn, thread=thread)
+    sp = tracing.annotated_span("compile", fn=fn, thread=thread)
     sp.__enter__()
     stack = getattr(_compile_tls, "open", None)
     if stack is None:

@@ -23,7 +23,11 @@ never drift):
   adds its milliseconds under ``<stage>_ms`` and ``stages_ms`` — the
   same durations query_stage_seconds observes, so EXPLAIN ANALYZE, the
   slow-query record and the histogram agree; the request root derives
-  `other` from ``stages_ms``
+  `other` from ``stages_ms`` — and its thread's CPU milliseconds under
+  ``<stage>_cpu_ms`` and ``stages_cpu_ms``, what
+  query_stage_cpu_seconds_total counts (``<stage>_ms`` minus
+  ``<stage>_cpu_ms``: the stage's time off the CPU)
+- ingest: the line-protocol door marks its request (``ingest_requests``)
 
 `GTPU_TRACING=off` disables the ledger together with span recording —
 the observability plane A/Bs as one unit.
@@ -75,8 +79,12 @@ class Ledger:
         if span.node is not None:
             return
         if span.stage:
-            self.add(span.name + "_ms", span.duration_ms)
-            self.add("stages_ms", span.duration_ms)
+            with self._lock:
+                for key, value in ((span.name + "_ms", span.duration_ms),
+                                   ("stages_ms", span.duration_ms),
+                                   (span.name + "_cpu_ms", span.cpu_ms),
+                                   ("stages_cpu_ms", span.cpu_ms)):
+                    self._data[key] = self._data.get(key, 0.0) + value
         if span.name in ("scan", "region_scan"):
             rows = span.attrs.get("rows")
             if isinstance(rows, (int, float)):

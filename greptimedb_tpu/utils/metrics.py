@@ -844,6 +844,37 @@ STAGE_SECONDS = REGISTRY.histogram(
     "(the root). Buckets carry OpenMetrics trace_id exemplars — a slow "
     "bucket links straight to a trace to pull via /v1/traces/<id>",
     exemplars=True)
+STAGE_CPU_SECONDS = REGISTRY.sharded_counter(
+    "greptimedb_tpu_query_stage_cpu_seconds_total",
+    "CPU seconds of the thread that ran a serving stage "
+    "(time.thread_time_ns), added as each stage segment closes, under "
+    "the labels of query_stage_seconds: the twelve flat stages, other = "
+    "the request root's CPU minus theirs, and the enclosing execute, "
+    "fast_execute and request. A stage's wall seconds minus these are "
+    "the time its thread was off the CPU: waiting for the interpreter "
+    "lock, the device, a socket, a disk or another lock. CPU spent with "
+    "the interpreter lock released (arrow decode, numpy, an XLA:CPU "
+    "compile) counts as CPU. One more label, background: the CPU of "
+    "threads that work for a request beside its own (everything run "
+    "under tracing.propagate, bg:<stage> spans included: scan pool, "
+    "part workers, the hedge's warm-up, encode-pool threads): they take "
+    "the same lock; their wall time counts nowhere")
+INGEST_REQUEST_CPU_SECONDS = REGISTRY.histogram(
+    "greptimedb_tpu_ingest_request_cpu_seconds",
+    "CPU seconds of the request thread over one line-protocol write "
+    "request (the root span's thread_time): a write runs no statement "
+    "and observes no request stage",
+    buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+             1.0, 2.5))
+LOCK_WAIT_SECONDS = REGISTRY.histogram(
+    "greptimedb_tpu_interpreter_lock_wait_seconds",
+    "How much later than asked the interpreter-lock probe "
+    "(utils/lock_probe.py) was running again after a 20 ms sleep: "
+    "time.sleep gives the lock up and takes it back before it returns, "
+    "so the lateness samples what one re-acquisition costs at that "
+    "moment, plus the timer's own lateness (what an idle server reads)",
+    buckets=(0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
+             0.1, 0.25))
 COUNTER_SHARDS = REGISTRY.gauge(
     "greptimedb_tpu_metrics_counter_shards",
     "Live per-thread shard cells across all sharded hot counters "
@@ -912,15 +943,6 @@ PARTIAL_AGG_DELTA_ROWS = REGISTRY.counter(
     "Rows actually folded by incremental aggregate executions, by kind "
     "(delta = uncached part + memtable rows that ran through kernels, "
     "cached = rows whose partial plane was served from the cache)")
-
-# continuous profiling (utils/flame.py): the always-on sampler's
-# attribution counts
-PROFILE_SAMPLES = REGISTRY.counter(
-    "greptimedb_tpu_profile_samples_total",
-    "Continuous-profiler stack samples by coarse stage (http/stmt/scan/"
-    "device_agg/... from the innermost active span; host = a busy "
-    "thread outside any span) — attributed/total ratio is the sampler's "
-    "own health metric")
 
 # ---- static analysis (tools/gtpu_lint.py, tier-1) --------------------------
 

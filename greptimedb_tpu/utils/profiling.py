@@ -1,10 +1,10 @@
 """On-demand profiling over HTTP — the pprof analog.
 
-Mirrors the reference's `servers/src/http/pprof.rs` (CPU flamegraphs via
+Mirrors the reference's `servers/src/http/pprof.rs` (CPU profiles via
 the pprof crate's sampling profiler) and `http/mem_prof.rs` (jemalloc heap
 profiles): here a wall-clock stack sampler over `sys._current_frames()`
-produces folded-stack output (the flamegraph.pl / speedscope "collapsed"
-format), and tracemalloc snapshots provide allocation profiles. Both are
+produces folded-stack output (the "collapsed" format speedscope and
+other stack-graph renderers read), and tracemalloc snapshots provide allocation profiles. Both are
 pull-style: hit the endpoint, get a self-contained text artifact.
 
 `device_trace` is the accelerator's counterpart: it brackets a few
@@ -22,10 +22,10 @@ import time
 import tracemalloc
 from collections import Counter
 
-#: thread idents of every live profiler/sampler thread — each sampler
-#: (this module's on-demand one, utils/flame.py's continuous one)
-#: registers itself so no flame is ever polluted by the instruments
-#: observing each other. Plain set mutations are GIL-atomic.
+#: thread idents of every live instrument thread — this module's
+#: on-demand sampler, utils/lock_probe.py's probe — each registers
+#: itself so no profile is ever polluted by the instruments observing
+#: each other. Plain set mutations are GIL-atomic.
 _PROFILER_TIDS: set = set()
 
 
@@ -42,12 +42,13 @@ def sample_cpu(seconds: float = 5.0, hz: float = 99.0,
     """Sample every thread's Python stack for `seconds` at `hz`.
 
     Returns folded stacks: `frame;frame;...;leaf count` per line, leaf
-    last — feed to any flamegraph renderer. Threads blocked in epoll/GIL
+    last — feed to any stack-graph renderer. Threads blocked in epoll/GIL
     waits are skipped unless include_idle (matching pprof's on-CPU view
-    as closely as a wall sampler can). Profiler threads — this one and
-    any registered continuous sampler — are excluded: an earlier version
-    counted its own sampling loop when invoked off the serving thread,
-    so every flame carried a phantom `sample_cpu` tower."""
+    as closely as a wall sampler can). Instrument threads — this one and
+    any registered one (the interpreter-lock probe) — are excluded: an
+    earlier version counted its own sampling loop when invoked off the
+    serving thread, so every profile carried a phantom `sample_cpu`
+    tower."""
     deadline = time.monotonic() + seconds
     interval = 1.0 / hz
     stacks: Counter = Counter()

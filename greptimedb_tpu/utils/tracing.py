@@ -20,6 +20,18 @@ exporter (utils/otlp_trace.py) and the per-query resource ledger
 (utils/ledger.py) when either is active. `GTPU_TRACING=off` turns span
 recording (and the ledger) into a no-op for A/B overhead runs.
 
+Every span reads TWO clocks: `time.perf_counter()` for its duration and
+`time.thread_time_ns()` for `cpu_ms`, the CPU time of the thread that
+ran it. `duration_ms - cpu_ms` is the time that thread was off the CPU:
+waiting for the interpreter lock, for the device, for a socket or a
+disk, or for another lock. It is NOT only the interpreter lock, and CPU
+time spent with the lock RELEASED (arrow decode, numpy, an XLA:CPU
+compile) counts as CPU: `cpu_ms` bounds the time a span held the lock
+from above, and `duration_ms - cpu_ms` the time it waited for it from
+above. The stage spans add their CPU seconds to
+query_stage_cpu_seconds_total{stage}; utils/lock_probe.py samples what
+re-taking the lock costs.
+
 Logs join the same id: `TraceIdFilter` stamps every log record with the
 current trace id (`trace_id=<id>`), so logs, metrics, and spans
 correlate on one key — and histogram exemplars (utils/metrics.py) close
@@ -41,9 +53,12 @@ from typing import Optional
 
 import sys
 
-from greptimedb_tpu.utils import flame as _flame
 from greptimedb_tpu.utils import ledger
-from greptimedb_tpu.utils.metrics import STAGE_SECONDS
+from greptimedb_tpu.utils.metrics import (
+    INGEST_REQUEST_CPU_SECONDS,
+    STAGE_CPU_SECONDS,
+    STAGE_SECONDS,
+)
 
 _current: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
     "gtpu_trace_id", default=None)
@@ -67,7 +82,8 @@ STAGES = ("parse", "plan", "fast_bind", "admission_wait", "scan",
 
 #: innermost open stage of this context (None = none open); a thread
 #: that works FOR a request beside its own thread (warm-up, scan pool)
-#: carries _BACKGROUND: its stages are plain spans and count nowhere
+#: carries _BACKGROUND: its stages are plain `bg:` spans that count
+#: nowhere; the thread's CPU counts under stage="background" (propagate)
 _stage: contextvars.ContextVar = contextvars.ContextVar(
     "gtpu_stage", default=None)
 _BACKGROUND = object()
@@ -110,6 +126,10 @@ class Span:
     parent_id: Optional[str] = None
     #: one segment of a flat serving stage (see `stage`)
     stage: bool = False
+    #: CPU time of the thread that ran the span (the module docstring
+    #: says what `duration_ms - cpu_ms` is and is not); 0 for a span
+    #: merged from a peer that predates the second clock
+    cpu_ms: float = 0.0
 
 
 def new_trace_id() -> str:
@@ -186,76 +206,77 @@ def _record(span: Span) -> None:
         exp.on_span(span)
 
 
-def _annotation(name: str, trace_id, span_id):
+def _annotation(name: str, trace_id, span_id, stats: dict):
     """The profiler's clock: when jax is already loaded in this process
     the span is also a `jax.profiler.TraceAnnotation`, so a profiler
     session with the host tracer on holds it on the device trace's
-    timeline. Never imports jax (a jax-free process stays jax-free);
-    outside a session the annotation costs one flag test."""
+    timeline, with `stats` beside its ids. Never imports jax (a
+    jax-free process stays jax-free); outside a session the annotation
+    costs one flag test."""
     jax = sys.modules.get("jax")
     if jax is None:
         return None
     try:
         ann = jax.profiler.TraceAnnotation(
-            name, trace_id=trace_id or "", span_id=span_id)
+            name, trace_id=trace_id or "", span_id=span_id, **stats)
         ann.__enter__()
         return ann
     except Exception:  # noqa: BLE001 — a half-imported jax must not fail a span
         return None
 
 
+_NO_STATS: dict = {}
+
+
 class _Span:
     """One timed span nested under the innermost open one; the context
     manager `span()` returns. `name` may be changed before exit (the
     compile listener learns only at the end whether the persistent
-    cache served the executable). `on_close(duration_ms, attrs)` runs
-    before the span is recorded."""
+    cache served the executable). `on_close(duration_ms, cpu_ms, attrs)`
+    runs before the span is recorded. `stats` are the attributes, known
+    when the span opens, that its TraceAnnotation carries."""
 
-    __slots__ = ("name", "attrs", "on_close", "stage", "_on", "_prof",
-                 "_sid", "_parent_id", "_token", "_t0", "_started", "_ann")
+    __slots__ = ("name", "attrs", "on_close", "stage", "stats", "_on",
+                 "_sid", "_parent_id", "_token", "_t0", "_c0", "_started",
+                 "_ann")
 
     def __init__(self, name: str, attrs: dict, on_close=None,
-                 stage: bool = False):
+                 stage: bool = False, stats: dict = _NO_STATS):
         self.name = name
         self.attrs = attrs
         self.on_close = on_close
         self.stage = stage
+        self.stats = stats
         self._on = False
 
     def __enter__(self) -> dict:
-        # the continuous profiler's stage attribution rides span
-        # entry/exit (a thread-id-keyed registry the sampler thread can
-        # read — the contextvar stack is invisible cross-thread);
-        # guarded by flame's fast flag so the cost with profiling off is
-        # one attribute read, and kept alive even with GTPU_TRACING=off
-        # so flames stay staged during tracing A/B runs
-        self._prof = _flame._ENABLED
-        if self._prof:
-            _flame.push_stage(self.name)
         if not enabled():
             return self.attrs
         self._on = True
         self._sid = new_span_id()
         self._parent_id = _parent.get()
         self._token = _parent.set(self._sid)
-        self._ann = _annotation(self.name, _current.get(), self._sid)
-        self._t0 = time.perf_counter()
+        self._ann = _annotation(self.name, _current.get(), self._sid,
+                                self.stats)
         self._started = time.time()
+        # the wall interval encloses the CPU interval: cpu_ms <= dur_ms
+        self._t0 = time.perf_counter()
+        self._c0 = time.thread_time_ns()
         return self.attrs
 
     def __exit__(self, *exc) -> bool:
         if self._on:
+            cpu_ms = (time.thread_time_ns() - self._c0) / 1e6
             dur_ms = (time.perf_counter() - self._t0) * 1000.0
             if self._ann is not None:
                 self._ann.__exit__(None, None, None)
             _parent.reset(self._token)
             if self.on_close is not None:
-                self.on_close(dur_ms, self.attrs)
+                self.on_close(dur_ms, cpu_ms, self.attrs)
             _record(Span(_current.get(), self.name, dur_ms, self._started,
                          self.attrs, span_id=self._sid,
-                         parent_id=self._parent_id, stage=self.stage))
-        if self._prof:
-            _flame.pop_stage()
+                         parent_id=self._parent_id, stage=self.stage,
+                         cpu_ms=cpu_ms))
         return False
 
 
@@ -267,9 +288,21 @@ def span(name: str, **attrs) -> _Span:
     return _Span(name, attrs)
 
 
+def annotated_span(name: str, **attrs) -> _Span:
+    """A `span` whose attributes, all known as it opens, are also stats
+    of its TraceAnnotation: a reader of the `.xplane.pb` sees them on
+    the event (a `compile` span's `fn` and `thread`). A value that reads
+    as a number comes back from the profiler as one."""
+    return _Span(name, attrs, stats=dict(attrs))
+
+
 def _observe_as(label: str):
-    """An `on_close` that observes the span into query_stage_seconds."""
-    return lambda ms, _attrs: STAGE_SECONDS.observe(ms / 1000.0, stage=label)
+    """An `on_close` that observes the span into query_stage_seconds and
+    adds its thread's CPU to query_stage_cpu_seconds_total."""
+    def observe(ms: float, cpu_ms: float, _attrs: dict) -> None:
+        STAGE_SECONDS.observe(ms / 1000.0, stage=label)
+        STAGE_CPU_SECONDS.inc(cpu_ms / 1000.0, stage=label)
+    return observe
 
 
 class _Stage:
@@ -278,10 +311,13 @@ class _Stage:
     segment of it when the inner stage closes, so every segment is a
     direct child of the enclosing plain span (statement or request
     root), the segments of one request never overlap, and their sum
-    plus `other` is the root's duration. Each segment is a span that
-    observes its duration into query_stage_seconds{stage} as it closes
-    and feeds the ledger (`<stage>_ms`, `stages_ms`) through the span
-    ring."""
+    plus `other` is the root's duration — on both clocks: a stage
+    opened inside another takes its CPU out of the outer's as it takes
+    its wall time. Each segment is a span that observes its duration
+    into query_stage_seconds{stage} and adds its CPU to
+    query_stage_cpu_seconds_total{stage} as it closes, and feeds the
+    ledger (`<stage>_ms`, `stages_ms`, `<stage>_cpu_ms`,
+    `stages_cpu_ms`) through the span ring."""
 
     __slots__ = ("name", "attrs", "_outer", "_seg")
 
@@ -320,7 +356,8 @@ def stage(name: str, **attrs):
     """Open the flat serving stage `name` (one of STAGES) on the request
     thread. With GTPU_TRACING=off it is a span that records nothing; on
     a thread that works beside the request's own (`propagate`) it is a
-    plain span `bg:<name>` and counts nowhere."""
+    plain span `bg:<name>`: its wall time adds nothing to the request's
+    latency and counts nowhere (its thread's CPU does: `propagate`)."""
     if name not in STAGES:
         # the histogram's label set and PERF.md's vocabulary are one list
         raise ValueError(f"unknown serving stage {name!r}")
@@ -357,6 +394,9 @@ def in_warmup() -> bool:
 #: `other` and `request`, so writes and debug routes stay out of the
 #: query stage histogram
 _QUERY_MARKS = ("parse_ms", "fast_bind_ms")
+#: the line-protocol door marks its request (servers/influx.py
+#: `write_lines`): its root observes ingest_request_cpu_seconds
+_INGEST_MARK = "ingest_requests"
 
 
 @contextlib.contextmanager
@@ -368,7 +408,9 @@ def request_span(name: str, traceparent: Optional[str] = None, **attrs):
     next. Every protocol front door (HTTP, MySQL, Postgres, Flight SQL)
     enters through here; the span_coverage lint checker enforces it.
     When the root closes it observes `request` (its duration) and
-    `other` (its duration minus the flat stages the ledger summed)."""
+    `other` (its duration minus the flat stages the ledger summed), on
+    both clocks; the root of a line-protocol write observes its CPU
+    seconds into ingest_request_cpu_seconds instead."""
     parsed = parse_traceparent(traceparent) if traceparent else None
     tid, remote_parent = parsed if parsed else (new_trace_id(), None)
     tok_tid = _current.set(tid)
@@ -378,7 +420,7 @@ def request_span(name: str, traceparent: Optional[str] = None, **attrs):
         with ledger.attach() as led:
             led0 = led.snapshot() if led is not None else {}
 
-            def close(dur_ms: float, a: dict) -> None:
+            def close(dur_ms: float, cpu_ms: float, a: dict) -> None:
                 # stamp BEFORE the span is recorded (and handed to the
                 # OTLP exporter): a later mutation would race the export
                 # serializer and leave the exported copy ledger-less
@@ -387,14 +429,21 @@ def request_span(name: str, traceparent: Optional[str] = None, **attrs):
                 snap = led.snapshot()
                 if snap:
                     a["ledger"] = ledger.format_dict(snap)
-                if any(snap.get(k, 0.0) > led0.get(k, 0.0)
-                       for k in _QUERY_MARKS):
-                    staged = snap.get("stages_ms", 0.0) \
-                        - led0.get("stages_ms", 0.0)
-                    other = max(dur_ms - staged, 0.0)
+
+                def since(key: str) -> float:
+                    return snap.get(key, 0.0) - led0.get(key, 0.0)
+
+                if any(since(k) > 0.0 for k in _QUERY_MARKS):
+                    other = max(dur_ms - since("stages_ms"), 0.0)
+                    other_cpu = max(cpu_ms - since("stages_cpu_ms"), 0.0)
                     a["other_ms"] = round(other, 3)
+                    a["other_cpu_ms"] = round(other_cpu, 3)
                     STAGE_SECONDS.observe(other / 1000.0, stage="other")
+                    STAGE_CPU_SECONDS.inc(other_cpu / 1000.0, stage="other")
                     STAGE_SECONDS.observe(dur_ms / 1000.0, stage="request")
+                    STAGE_CPU_SECONDS.inc(cpu_ms / 1000.0, stage="request")
+                if since(_INGEST_MARK) > 0.0:
+                    INGEST_REQUEST_CPU_SECONDS.observe(cpu_ms / 1000.0)
 
             with _Span(name, attrs, on_close=close) as a:
                 yield a
@@ -444,11 +493,18 @@ def propagate(fn, background: bool = False):
     request thread alone: the wrapper marks its thread as background,
     so a stage opened there is a plain span. `background=True` says the
     request does not WAIT for this work either (the device warm-up): a
-    compile there is labelled thread="warmup"."""
+    compile there is labelled thread="warmup". A thread beside the
+    request's adds nothing to its latency, but its CPU takes the
+    interpreter lock the request threads take: the wrapper adds the
+    thread's CPU seconds over the call, inside a `bg:` span or not, to
+    query_stage_cpu_seconds_total{stage="background"} (a wrapper run
+    inline on the thread that made it is that thread's own CPU, and
+    counts nothing here)."""
     tid = _current.get()
     parent = _parent.get()
     sink = _collector.get()
     led = ledger.active()
+    maker = threading.get_ident()
 
     def wrapper(*args, **kwargs):
         t1 = _current.set(tid)
@@ -457,9 +513,14 @@ def propagate(fn, background: bool = False):
         t4 = ledger._current.set(led)
         t5 = _stage.set(_BACKGROUND)
         t6 = _warmup.set(background or _warmup.get())
+        beside = enabled() and threading.get_ident() != maker
+        c0 = time.thread_time_ns() if beside else 0
         try:
             return fn(*args, **kwargs)
         finally:
+            if beside:
+                STAGE_CPU_SECONDS.inc(
+                    (time.thread_time_ns() - c0) / 1e9, stage="background")
             _warmup.reset(t6)
             _stage.reset(t5)
             ledger._current.reset(t4)
@@ -533,7 +594,8 @@ def spans_to_wire(spans: list[Span]) -> list[dict]:
     return [
         {"name": s.name, "duration_ms": round(s.duration_ms, 4),
          "started_at": s.started_at, "attrs": _wire_attrs(s.attrs),
-         "span_id": s.span_id, "parent_id": s.parent_id}
+         "span_id": s.span_id, "parent_id": s.parent_id,
+         "cpu_ms": round(s.cpu_ms, 4)}
         for s in spans
     ]
 
@@ -569,7 +631,8 @@ def merge_spans(wire: list[dict], node: Optional[str] = None,
                      float(w.get("started_at", 0.0)),
                      dict(w.get("attrs") or {}), node=node,
                      span_id=str(w.get("span_id") or ""),
-                     parent_id=w.get("parent_id") or None)
+                     parent_id=w.get("parent_id") or None,
+                     cpu_ms=float(w.get("cpu_ms") or 0.0))
         except (KeyError, TypeError, ValueError):
             continue  # a mangled record must not kill the query
         if s.span_id and s.span_id in existing_ids:
@@ -660,7 +723,8 @@ def render_tree(spans: list[Span], indent: str = "  ") -> list[str]:
         has_kids = any(d == depth + 1 and p.parent_id == s.span_id
                        for d, p, _ in rows)
         self_part = f" (self {self_ms:.2f} ms)" if has_kids else ""
-        lines.append(f"{pad}{s.name}: {s.duration_ms:.2f} ms{self_part}"
+        lines.append(f"{pad}{s.name}: {s.duration_ms:.2f} ms"
+                     f" (cpu {s.cpu_ms:.2f} ms){self_part}"
                      + (f" [{attrs}]" if attrs else ""))
     return lines
 

@@ -1,9 +1,8 @@
-"""Continuous profiling plane (ISSUE 17): the always-on flame sampler
-(stage/path attribution, bounded windows, profiler-thread exclusion),
-the per-statement ledger stamps (span / slow-query / ANALYZE, keyed on
-the serving stages), the /v1/profile endpoints (auth, content types),
-deterministic cluster merge, heartbeat piggyback, and the OTLP log lane
-riding the trace exporter.
+"""What is left of the profiling plane (ISSUE 17; the continuous sampler
+went in PR 40): /debug/pprof/cpu leaves its own thread out, the
+per-statement ledger stamps (span / slow-query / ANALYZE, keyed on the
+serving stages), the OTLP log lane riding the trace exporter, and the
+exemplar lint.
 """
 
 from __future__ import annotations
@@ -12,8 +11,6 @@ import json
 import logging
 import threading
 import time
-import urllib.error
-import urllib.request
 
 import pytest
 
@@ -21,8 +18,8 @@ from greptimedb_tpu.catalog import Catalog, MemoryKv
 from greptimedb_tpu.query import QueryEngine
 from greptimedb_tpu.storage import RegionEngine
 from greptimedb_tpu.storage.engine import EngineConfig
-from greptimedb_tpu.utils import (flame, ledger, otlp_trace, profiling,
-                                  slow_query, tracing)
+from greptimedb_tpu.utils import (otlp_trace, profiling, slow_query,
+                                  tracing)
 
 
 @pytest.fixture
@@ -42,123 +39,14 @@ def _seed(qe, rows=64):
     qe.execute_one(f"INSERT INTO cpu VALUES {vals}")
 
 
-@pytest.fixture
-def sampler_off():
-    """Every test leaves the process sampler stopped and windows empty."""
-    flame.shutdown()
-    flame.reset()
-    yield
-    flame.shutdown()
-    flame.reset()
-
-
 def _spin_ms(ms: float) -> float:
-    """Busy CPU loop the sampler can land on (no sleeps: sleeps are
+    """Busy CPU loop a sampler can land on (no sleeps: sleeps are
     idle-filtered)."""
     t0 = time.perf_counter()
     x = 0.0
     while (time.perf_counter() - t0) * 1000 < ms:
         x += sum(i * i for i in range(200))
     return x
-
-
-# ---- continuous sampler -----------------------------------------------------
-
-
-class TestContinuousSampler:
-    def test_attributes_stage_and_path(self, sampler_off):
-        flame.configure(enabled=True, hz=250.0, window_s=30.0)
-        tracing.set_trace(None)
-        with tracing.span("stmt:Select"):
-            flame.note_path("dense_fused")
-            _spin_ms(600)
-        folded = flame.folded()
-        assert folded.startswith("# flame:")
-        body = [ln for ln in folded.splitlines()[1:] if ln]
-        assert body, "sampler captured nothing in 600 ms @ 250 Hz"
-        attributed = [ln for ln in body
-                      if ln.startswith("stage:stmt:Select;path:dense_fused;")]
-        assert attributed, f"no attributed stacks in:\n{folded[:500]}"
-        # the ISSUE acceptance: >=90% of samples attribute to the busy
-        # stage in a controlled single-busy-thread scenario
-        summ = flame.summary()
-        assert summ["samples"] > 0
-        assert summ["attributed"] / summ["samples"] >= 0.9
-        assert summ["stages"].get("stmt", 0) > 0
-        assert summ["paths"].get("dense_fused", 0) > 0
-
-    def test_stage_filter_and_speedscope_document(self, sampler_off):
-        flame.configure(enabled=True, hz=250.0)
-        with tracing.span("stmt:Select"):
-            _spin_ms(300)
-        only = flame.folded(stage="stmt")
-        assert all(ln.startswith(("#", "stage:stmt"))
-                   for ln in only.splitlines() if ln)
-        doc = flame.speedscope()
-        assert doc["$schema"].endswith("file-format-schema.json")
-        prof, = doc["profiles"]
-        assert prof["type"] == "sampled"
-        assert len(prof["samples"]) == len(prof["weights"])
-        assert prof["endValue"] == sum(prof["weights"])
-        names = {f["name"] for f in doc["shared"]["frames"]}
-        assert any(n.startswith("stage:stmt") for n in names)
-
-    def test_sampler_excludes_itself(self, sampler_off):
-        flame.configure(enabled=True, hz=250.0)
-        _spin_ms(300)
-        folded = flame.folded()
-        assert "_tick" not in folded
-        assert "gtpu-flame-sampler" not in folded
-
-    def test_disabled_hooks_are_cheap_noops(self, sampler_off):
-        assert not flame.enabled()
-        flame.push_stage("x")  # must not record anything while off
-        flame.pop_stage()
-        flame.note_path("y")
-        assert flame.summary()["samples"] == 0
-
-    def test_configure_retunes_and_shutdown_stops(self, sampler_off):
-        flame.configure(enabled=True, hz=200.0)
-        assert flame.running()
-        t = next(th for th in threading.enumerate()
-                 if th.name == "gtpu-flame-sampler")
-        flame.configure(enabled=True, hz=200.0)  # idempotent: same thread
-        t2 = next(th for th in threading.enumerate()
-                  if th.name == "gtpu-flame-sampler")
-        assert t is t2
-        flame.shutdown()
-        assert not flame.running()
-        t.join(timeout=2.0)
-        assert not t.is_alive()
-
-    def test_maybe_install_env_twins(self, sampler_off, monkeypatch):
-        monkeypatch.setenv("GTPU_PROFILE", "off")
-        flame.maybe_install()
-        assert not flame.running()
-        monkeypatch.setenv("GTPU_PROFILE", "1")
-        monkeypatch.setenv("GTPU_PROFILE_HZ", "55")
-        flame.maybe_install()
-        assert flame.running()
-        assert flame._SAMPLER.period == pytest.approx(1.0 / 55)
-
-    @pytest.mark.slow
-    def test_overhead_budget_2pct(self, sampler_off):
-        """A/B the busy loop with the sampler on vs off: the always-on
-        budget is <=2% (median of alternating rounds)."""
-        def _round():
-            t0 = time.perf_counter()
-            _spin_ms(250)
-            return time.perf_counter() - t0
-
-        on, off = [], []
-        for _ in range(5):
-            flame.configure(enabled=True, hz=19.0)
-            on.append(_round())
-            flame.shutdown()
-            off.append(_round())
-        on.sort(), off.sort()
-        overhead = on[2] / off[2] - 1.0
-        assert overhead <= 0.02, f"sampler overhead {overhead:.1%} > 2%"
 
 
 # ---- sample_cpu profiler-thread exclusion -----------------------------------
@@ -180,12 +68,6 @@ class TestSampleCpuExclusion:
         # invoked off the serving thread
         assert "_sample_loop" not in out["folded"]
         assert "sample_cpu" not in out["folded"]
-
-    def test_continuous_sampler_excluded_from_sample_cpu(self, sampler_off):
-        flame.configure(enabled=True, hz=200.0)
-        folded = profiling.sample_cpu(seconds=0.2, hz=100,
-                                      include_idle=True)
-        assert "_tick" not in folded
 
 
 # ---- per-query stamps (engine / ANALYZE / slow query) -----------------------
@@ -273,205 +155,6 @@ class TestQueryStamps:
                                "FROM information_schema.slow_queries")
         finally:
             slow_query.clear()
-
-
-# ---- HTTP endpoints ---------------------------------------------------------
-
-
-class TestProfileEndpoints:
-    def _get(self, port, path, auth=None):
-        req = urllib.request.Request(f"http://127.0.0.1:{port}{path}")
-        if auth:
-            import base64
-            cred = base64.b64encode(auth.encode()).decode()
-            req.add_header("Authorization", f"Basic {cred}")
-        return urllib.request.urlopen(req, timeout=10)
-
-    def test_flame_endpoint_auth_and_content_types(self, qe, sampler_off):
-        from greptimedb_tpu.auth import StaticUserProvider
-        from greptimedb_tpu.servers import HttpServer
-
-        flame.configure(enabled=True, hz=250.0)
-        with tracing.span("stmt:Select"):
-            _spin_ms(400)
-        srv = HttpServer(qe, port=0,
-                         user_provider=StaticUserProvider({"u": "pw"}))
-        port = srv.start()
-        try:
-            with pytest.raises(urllib.error.HTTPError) as ei:
-                self._get(port, "/v1/profile/flame")
-            assert ei.value.code == 401
-            with self._get(port, "/v1/profile/flame", auth="u:pw") as resp:
-                assert "text/plain" in resp.headers["Content-Type"]
-                body = resp.read().decode()
-            assert body.startswith("# flame:")
-            assert "stage:stmt:Select;" in body
-            with self._get(port, "/v1/profile/flame?format=speedscope",
-                           auth="u:pw") as resp:
-                assert "application/json" in resp.headers["Content-Type"]
-                doc = json.loads(resp.read())
-            assert doc["profiles"][0]["type"] == "sampled"
-            with self._get(port, "/v1/profile/cluster",
-                           auth="u:pw") as resp:
-                view = json.loads(resp.read())
-            assert view["merged"]["samples"] >= 1
-        finally:
-            srv.stop()
-
-    def test_flame_endpoint_503_when_disabled(self, qe, sampler_off):
-        from greptimedb_tpu.servers import HttpServer
-
-        srv = HttpServer(qe, port=0)
-        port = srv.start()
-        try:
-            with pytest.raises(urllib.error.HTTPError) as ei:
-                self._get(port, "/v1/profile/flame")
-            assert ei.value.code == 503
-            assert "GTPU_PROFILE" in json.loads(ei.value.read())["error"]
-        finally:
-            srv.stop()
-
-    def test_flame_dump_tool(self, qe, sampler_off):
-        import os
-        import sys
-
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        from tools.flame_dump import fetch, render_cluster
-
-        from greptimedb_tpu.servers import HttpServer
-
-        flame.configure(enabled=True, hz=250.0)
-        with tracing.span("stmt:Select"):
-            _spin_ms(300)
-        srv = HttpServer(qe, port=0)
-        port = srv.start()
-        try:
-            body, ctype = fetch(f"127.0.0.1:{port}", "/v1/profile/flame")
-            assert "text/plain" in ctype
-            assert body.decode().startswith("# flame:")
-            body, _ = fetch(f"127.0.0.1:{port}", "/v1/profile/cluster")
-            out = render_cluster(json.loads(body))
-            assert "cluster profile:" in out
-        finally:
-            srv.stop()
-
-
-# ---- cluster rollup ---------------------------------------------------------
-
-
-def _digest(node, stages, paths=None, samples=None, top=None):
-    total = samples if samples is not None else sum(stages.values())
-    return {"node": node, "ts_ms": 1700000000000, "hz": 19.0,
-            "window_s": 30.0, "samples": total,
-            "attributed": sum(stages.values()),
-            "stages": dict(stages), "paths": dict(paths or {}),
-            "top": list(top or [])}
-
-
-class TestClusterRollup:
-    def test_merge_is_order_independent(self, sampler_off):
-        a = _digest("dn-0", {"stmt": 30, "scan": 10},
-                    top=[{"frame": "decode (sst.py:1)", "self": 25}])
-        b = _digest("dn-1", {"stmt": 5, "flush": 7},
-                    top=[{"frame": "decode (sst.py:1)", "self": 3},
-                         {"frame": "fsync (wal.py:9)", "self": 6}])
-        flame.note_node_summary("dn-0", a)
-        flame.note_node_summary("dn-1", b)
-        v1 = flame.cluster_view()
-        flame.reset()
-        flame.note_node_summary("dn-1", b)
-        flame.note_node_summary("dn-0", a)
-        v2 = flame.cluster_view()
-        # deterministic merge: identical whatever order digests arrived
-        # (only the local node's ts_ms may differ between calls)
-        assert v1["merged"] == v2["merged"]
-        assert sorted(v1["nodes"]) == sorted(v2["nodes"])
-        assert v1["merged"]["stages"] == {"flush": 7, "scan": 10,
-                                          "stmt": 35}
-        assert v1["merged"]["top"][0] == {
-            "frame": "decode (sst.py:1)", "self": 28}
-
-    def test_rollup_bounded(self, sampler_off):
-        for i in range(flame._CLUSTER_CAP + 40):
-            flame.note_node_summary(f"dn-{i}", _digest(f"dn-{i}",
-                                                       {"stmt": 1}))
-        view = flame.cluster_view()
-        # cap + the local node
-        assert len(view["nodes"]) <= flame._CLUSTER_CAP + 1
-        assert "dn-0" not in view["nodes"]  # oldest evicted first
-
-    def test_heartbeat_carries_profile(self, sampler_off):
-        from greptimedb_tpu.meta.heartbeat import HeartbeatTask
-        from greptimedb_tpu.meta.metasrv import Metasrv
-
-        flame.configure(enabled=True, hz=250.0)
-        with tracing.span("stmt:Select"):
-            _spin_ms(300)
-        ms = Metasrv(MemoryKv())
-        task = HeartbeatTask("dn-7", ms, stats_fn=lambda: [],
-                             on_instruction=lambda inst: None)
-        assert task.beat() is not None
-        prof = ms.node_profiles().get("dn-7")
-        assert prof is not None and prof["samples"] > 0
-        # sampler stopped: the beat carries no profile, the last one
-        # sticks (a restarting node must not blank the cluster view)
-        flame.shutdown()
-        assert task.beat() is not None
-        assert ms.node_profiles().get("dn-7") == prof
-
-    @pytest.mark.slow
-    def test_process_cluster_flame_merge_deterministic(self, tmp_path,
-                                                       sampler_off,
-                                                       monkeypatch):
-        """Real child-process datanodes: each samples itself (inherited
-        GTPU_PROFILE*), digests ride the Flight piggyback, and the
-        frontend's merged view is identical whatever order they
-        arrived in."""
-        from greptimedb_tpu.cluster.process_cluster import ProcessCluster
-
-        monkeypatch.setenv("GTPU_PROFILE", "1")
-        monkeypatch.setenv("GTPU_PROFILE_HZ", "500")
-        c = ProcessCluster(str(tmp_path), num_datanodes=2)
-        try:
-            c.sql(
-                "CREATE TABLE cpu (host STRING, v DOUBLE, ts TIMESTAMP(3) "
-                "NOT NULL, TIME INDEX (ts), PRIMARY KEY(host)) "
-                "PARTITION ON COLUMNS (host) (host < 'host3', "
-                "host >= 'host3')")
-            rows = [f"('host{h}', {float(h)}, {1000 + h})"
-                    for h in range(6)]
-            c.sql("INSERT INTO cpu (host, v, ts) VALUES " + ", ".join(rows))
-            for _ in range(3):
-                c.sql("SELECT host, avg(v) FROM cpu GROUP BY host")
-            view = flame.cluster_view()
-            remote = [n for n in view["nodes"] if n.startswith("datanode-")]
-            assert len(remote) == 2, sorted(view["nodes"])
-            # replay the same digests in reverse order: identical merge
-            digests = {n: view["nodes"][n] for n in remote}
-            flame.reset()
-            for n in sorted(digests, reverse=True):
-                flame.note_node_summary(n, digests[n])
-            v2 = flame.cluster_view()
-            assert {n: v2["nodes"][n] for n in remote} == digests
-            assert v2["merged"]["stages"] == {
-                k: v for k, v in view["merged"]["stages"].items()}
-        finally:
-            c.close()
-
-    def test_information_schema_cluster_profile(self, qe, sampler_off):
-        flame.configure(enabled=True, hz=250.0, node="frontend-0")
-        with tracing.span("stmt:Select"):
-            _spin_ms(400)
-        flame.note_node_summary("dn-1", _digest("dn-1", {"scan": 12}))
-        r = qe.execute_one(
-            "SELECT node, stage, stage_samples, share "
-            "FROM information_schema.cluster_profile ORDER BY node, stage")
-        rows = r.rows()
-        nodes = {row[0] for row in rows}
-        assert {"frontend-0", "dn-1"} <= nodes
-        dn1 = next(row for row in rows if row[0] == "dn-1")
-        assert dn1[1] == "scan" and dn1[2] == 12 and dn1[3] == 1.0
 
 
 # ---- OTLP log lane ----------------------------------------------------------
@@ -607,38 +290,6 @@ class TestOtlpLogLane:
         assert len(exp._logq) <= exp._log_rate + 1
         t1 = OTLP_LOG_RECORDS.get(event="throttled")
         assert t1 - t0 >= 150
-
-
-# ---- options / config plumbing ----------------------------------------------
-
-
-class TestProfilingOptions:
-    def test_apply_observability_env_twins(self, sampler_off, monkeypatch):
-        from greptimedb_tpu.options import (ProfilingOptions,
-                                            StandaloneOptions,
-                                            apply_observability)
-
-        for k in ("GTPU_PROFILE", "GTPU_PROFILE_HZ",
-                  "GTPU_PROFILE_WINDOW_S", "GTPU_PROFILE_WINDOWS"):
-            monkeypatch.delenv(k, raising=False)
-        opts = StandaloneOptions()
-        opts.profiling = ProfilingOptions(enabled=False, hz=7.0)
-        apply_observability(opts)
-        import os
-        assert os.environ.get("GTPU_PROFILE") == "off"
-        assert os.environ.get("GTPU_PROFILE_HZ") == "7.0"
-        assert not flame.running()
-        opts.profiling = ProfilingOptions()  # defaults: on @ 19 Hz
-        apply_observability(opts)
-        assert os.environ.get("GTPU_PROFILE", "") == ""
-        assert flame.running()
-
-    def test_example_toml_documents_profiling(self):
-        from greptimedb_tpu.options import example_toml
-
-        toml = example_toml()
-        assert "[profiling]" in toml
-        assert "hz = 19.0" in toml
 
 
 # ---- lint: exemplar rule ----------------------------------------------------
