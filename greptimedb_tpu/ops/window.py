@@ -464,6 +464,63 @@ def grid_window_flat(grid: jax.Array, mat: jax.Array, i0, n: int) -> tuple:
             m.reshape(S * n, C))
 
 
+def rate_of_edges(st: dict, times: jax.Array, range_s, is_counter: bool,
+                  is_rate: bool) -> jax.Array:
+    """rate / increase / delta from window edges (`window_edges*` or
+    `window_stats` with count, first, last) at the step times [T]: the
+    channel a counter's reset adjustment rides in, and the
+    extrapolation. [S, T]."""
+    ch = 1 if is_counter else 0
+    return extrapolated_delta(
+        st["first"][:, :, ch], st["first_ts"],
+        st["last"][:, :, ch], st["last_ts"], st["count"][:, :, 0],
+        times[None, :] - range_s, times[None, :],
+        is_counter=is_counter, is_rate=is_rate, range_s=range_s,
+        first_raw=st["first"][:, :, 0] if is_counter else None)
+
+
+def over_time_of_stats(st: dict, fn: str) -> jax.Array:
+    """sum_over_time / avg_over_time / count_over_time from window
+    sums and counts, NaN where a window holds no sample. [S, T]."""
+    cnt = st["count"][:, :, 0]
+    if fn == "count_over_time":
+        out = cnt.astype(jnp.float64)
+    else:
+        out = st["sum"][:, :, 0]
+        if fn == "avg_over_time":
+            out = out / jnp.maximum(cnt, 1)
+    return jnp.where(cnt > 0, out, jnp.nan)
+
+
+def grid_rate(grid: jax.Array, mat: jax.Array, t0, step, range_s,
+              num_steps: int, w: int, is_counter: bool,
+              is_rate: bool) -> jax.Array:
+    """rate / increase / delta of every series of a pivot, [S, P, C] ->
+    [S, T]: the window edges, the step times (made here from `t0` and
+    `step`, as the edges are) and `rate_of_edges`. Pure: traced inside
+    the program that calls it."""
+    st = window_edges_grid(grid, mat, t0, step, num_steps=num_steps, w=w)
+    times = t0 + jnp.arange(num_steps, dtype=jnp.float64) * step
+    return rate_of_edges(st, times, range_s, is_counter, is_rate)
+
+
+def grid_over_time(grid: jax.Array, mat: jax.Array, i0, t0, step, n: int,
+                   num_steps: int, w: int, fn: str) -> jax.Array:
+    """sum_over_time / avg_over_time / count_over_time of every series
+    of a pivot, [S, P, C] -> [S, T]. The sums run over the request's
+    OWN `n` grid points from `i0` on, so no difference of two prefixes
+    is taken over a longer prefix than the range needs; a count needs
+    the probes alone. Pure."""
+    if fn == "count_over_time":
+        st = window_edges_grid(grid, mat, t0, step, num_steps=num_steps,
+                               w=w)
+    else:
+        own_grid, own = _grid_points(grid, mat, i0, n)
+        st = window_sums_grid(own_grid, exclusive_cumsum(own), t0, step,
+                              num_steps=num_steps, w=w)
+    return over_time_of_stats(st, fn)
+
+
 @jax.jit
 @kernel_name("cumsum")
 def _cumsum_axis1(mat: jax.Array) -> jax.Array:

@@ -15,6 +15,7 @@ signatures on host (S is small; T×S math stays on device).
 
 from __future__ import annotations
 
+import functools
 import math
 import re
 import time
@@ -31,7 +32,10 @@ from greptimedb_tpu.ops.segment import segment_agg
 from greptimedb_tpu.ops.window import (
     counter_adjust,
     exclusive_cumsum,
-    extrapolated_delta,
+    grid_over_time,
+    grid_rate,
+    over_time_of_stats,
+    rate_of_edges,
     window_edges_grid,
     window_stats,
     window_sums_grid,
@@ -63,6 +67,7 @@ from greptimedb_tpu.promql.parser import (
 from greptimedb_tpu.query.result import QueryResult
 from greptimedb_tpu.utils import device_telemetry, tracing
 from greptimedb_tpu.utils.metrics import (
+    PROMQL_EVAL_PROGRAMS,
     PROMQL_GROUP_INDEXES,
     PROMQL_HISTOGRAM_FOLD_SECONDS,
     PROMQL_HISTOGRAM_FOLDS,
@@ -161,6 +166,21 @@ _RANGE_FUNCS = {
     "stddev_over_time", "stdvar_over_time", "present_over_time",
     "changes", "resets", "deriv", "predict_linear", "irate", "idelta",
     "absent_over_time", "holt_winters",
+}
+
+#: range functions a pivot answers in one program (`_run_on_grid`), and
+#: the aggregation operators that program can fold their result with
+_RATE_FUNCS = ("rate", "increase", "delta")
+_COUNTER_FUNCS = ("rate", "increase")
+_GRID_FUNCS = _RATE_FUNCS + ("sum_over_time", "avg_over_time",
+                             "count_over_time")
+#: operator -> the segment statistics it is finished from
+_AGG_STATS = {
+    "sum": ("sum",), "avg": ("sum", "count"),
+    "min": ("min",), "max": ("max",),
+    "count": ("count",), "group": ("count",),
+    "stddev": ("sum", "sumsq", "count"),
+    "stdvar": ("sum", "sumsq", "count"),
 }
 
 _ELEMENTWISE = {
@@ -289,11 +309,10 @@ class PromqlEngine:
         return SeriesMatrix(loaded.labels, vals, loaded.metric,
                             sample_ts=jnp.where(ok, lts, jnp.nan))
 
-    def _range_stats(self, sel, p: EvalParams, ctx,
-                     stats: tuple[str, ...], extra_channels=()):
-        """Evaluate a range selector OR subquery into window stats.
-        Returns (stats dict, labels, metric, w, range_s) or None when
-        empty."""
+    @staticmethod
+    def _window_steps(sel, p: EvalParams) -> tuple:
+        """(w, range_s): a range selector's or subquery's window in
+        whole steps and in seconds."""
         range_s = getattr(sel, "range_s", None)
         if range_s is None:
             raise PromqlError("expected a range vector (metric[duration])")
@@ -303,10 +322,24 @@ class PromqlEngine:
             raise PromqlError(
                 f"range {range_s}s must be a positive multiple of step {p.step}s "
                 "(blocked-window evaluation)")
+        return w, range_s
+
+    def _range_stats(self, sel, p: EvalParams, ctx,
+                     stats: tuple[str, ...], extra_channels=()):
+        """Evaluate a range selector OR subquery into window stats.
+        Returns (stats dict, labels, metric, w, range_s) or None when
+        empty."""
+        w, range_s = self._window_steps(sel, p)
         loaded = self._load_any(sel, p, ctx, window=range_s,
                                 extra_channels=extra_channels)
         if loaded is None:
             return None
+        return (self._stats_of(loaded, sel, p, stats, w), loaded.labels,
+                loaded.metric, w, range_s)
+
+    def _stats_of(self, loaded: Loaded, sel, p: EvalParams,
+                  stats: tuple[str, ...], w: int) -> dict:
+        """The window stats of loaded samples, a kernel at a time."""
         grid_ok = not isinstance(sel, Subquery) and _edges_enabled()
         st = None
         if grid_ok and "sum" in stats and set(stats) <= {"sum", "count"}:
@@ -343,7 +376,7 @@ class PromqlEngine:
             st = window_stats(sidx, ts, chans, ~jnp.isnan(chans[:, 0]),
                               p.start, p.step, len(loaded.labels), p.T, w,
                               stats=stats, sorted_input=_sorted_ws())
-        return st, loaded.labels, loaded.metric, w, range_s
+        return st
 
     def _load_any(self, sel, p: EvalParams, ctx, window: float,
                   extra_channels=()):
@@ -813,31 +846,20 @@ class PromqlEngine:
             return self._label_join(call, p, ctx)
         raise PromqlError(f"unsupported function {fn!r}")
 
-    def _eval_range_func(self, call: Call, p: EvalParams, ctx):
+    def _eval_range_func(self, call: Call, p: EvalParams, ctx,
+                         fuse: bool = True):
         fn = call.func
         sel = call.args[0]
         if not isinstance(sel, (VectorSelector, Subquery)):
             raise PromqlError(f"{fn} needs a range selector argument")
 
-        if fn in ("rate", "increase", "delta"):
-            counter = fn in ("rate", "increase")
-            extra = ("adjusted",) if counter else ()
-            r = self._range_stats(sel, p, ctx,
-                                  ("count", "first", "last"), extra)
-            if r is None:
+        if fn in _GRID_FUNCS:
+            plan = self._grid_plan(call, p, ctx, fuse)
+            if plan is None:
                 return SeriesMatrix([], jnp.zeros((0, p.T)))
-            st, labels, metric, w, range_s = r
-            ch = 1 if counter else 0
-            times = h2d(p.times)
-            vals = extrapolated_delta(
-                st["first"][:, :, ch], st["first_ts"],
-                st["last"][:, :, ch], st["last_ts"],
-                st["count"][:, :, 0],
-                times[None, :] - range_s, times[None, :],
-                is_counter=counter, is_rate=(fn == "rate"), range_s=range_s,
-                first_raw=st["first"][:, :, 0] if counter else None,
-            )
-            return SeriesMatrix(labels, vals)
+            if plan.pivot is None:
+                return self._grid_func_stepwise(plan, p)
+            return SeriesMatrix(plan.loaded.labels, _run_on_grid(plan, p))
 
         if fn in ("irate", "idelta"):
             # last two samples in the window (reference functions/
@@ -900,8 +922,7 @@ class PromqlEngine:
 
         # *_over_time family
         stat_map = {
-            "avg_over_time": ("sum", "count"), "sum_over_time": ("sum", "count"),
-            "count_over_time": ("count",), "present_over_time": ("count",),
+            "present_over_time": ("count",),
             "min_over_time": ("min", "count"), "max_over_time": ("max", "count"),
             "last_over_time": ("count", "last"),
             "stddev_over_time": ("sum", "count"), "stdvar_over_time": ("sum", "count"),
@@ -919,13 +940,7 @@ class PromqlEngine:
         st, labels, metric, w, range_s = r
         cnt = st["count"][:, :, 0]
         present = cnt > 0
-        if fn == "sum_over_time":
-            out = jnp.where(present, st["sum"][:, :, 0], jnp.nan)
-        elif fn == "avg_over_time":
-            out = jnp.where(present, st["sum"][:, :, 0] / jnp.maximum(cnt, 1), jnp.nan)
-        elif fn in ("count_over_time",):
-            out = jnp.where(present, cnt.astype(jnp.float64), jnp.nan)
-        elif fn == "present_over_time":
+        if fn == "present_over_time":
             out = jnp.where(present, 1.0, jnp.nan)
         elif fn == "min_over_time":
             out = st["min"][:, :, 0]
@@ -939,6 +954,43 @@ class PromqlEngine:
             var = jnp.maximum(sq / n - (s / n) ** 2, 0.0)  # population, like PromQL
             out = jnp.where(present, jnp.sqrt(var) if fn == "stddev_over_time" else var, jnp.nan)
         return SeriesMatrix(labels, out)
+
+    def _grid_plan(self, call: Call, p: EvalParams, ctx,
+                   fuse: bool = True) -> Optional["_GridPlan"]:
+        """The host half of a `_GRID_FUNCS` call: the window in steps,
+        the selector's samples and, where one program can answer from
+        them — a selector's samples (no subquery's) on one complete
+        grid — their pivot. None without a sample."""
+        fn, sel = call.func, call.args[0]
+        w, range_s = self._window_steps(sel, p)
+        loaded = self._load_any(
+            sel, p, ctx, window=range_s,
+            extra_channels=("adjusted",) if fn in _COUNTER_FUNCS else ())
+        if loaded is None:
+            return None
+        pivot = None
+        if fuse and not isinstance(sel, Subquery) and _edges_enabled():
+            pivot = loaded.pivot()
+        return _GridPlan(fn, sel, loaded, w, range_s, pivot)
+
+    def _grid_func_stepwise(self, plan: "_GridPlan",
+                            p: EvalParams) -> SeriesMatrix:
+        """A `_GRID_FUNCS` call a kernel at a time: what samples of no
+        complete grid, with a NaN tombstone or of a subquery take
+        (window_stats), and what `_run_on_grid` is held to."""
+        fn = plan.fn
+        if fn in _RATE_FUNCS:
+            stats = ("count", "first", "last")
+        else:
+            stats = ("count",) if fn == "count_over_time" \
+                else ("sum", "count")
+        st = self._stats_of(plan.loaded, plan.sel, p, stats, plan.w)
+        if fn in _RATE_FUNCS:
+            vals = rate_of_edges(st, h2d(p.times), plan.range_s,
+                                 fn in _COUNTER_FUNCS, fn == "rate")
+        else:
+            vals = over_time_of_stats(st, fn)
+        return SeriesMatrix(plan.loaded.labels, vals)
 
     def _histogram_quantile(self, call: Call, p: EvalParams, ctx):
         """φ-quantile over `le`-bucketed classic histograms (reference
@@ -1030,54 +1082,57 @@ class PromqlEngine:
 
     # ---- aggregation -------------------------------------------------------
 
-    def _eval_aggregate(self, agg: Aggregate, p: EvalParams, ctx):
-        v = self._eval(agg.expr, p, ctx)
+    @staticmethod
+    def _group_index(labels: list, agg: Aggregate) -> "_GroupIndex":
+        """The group index depends on the input's label sets and the
+        grouping alone: found beside the loaded series they derive
+        from, or built (for a list of no known origin, per request)."""
+        with tracing.stage("assemble", step="group_labels",
+                           series=len(labels)) as attrs:
+            grp, how = derive(labels, "group_index", _build_group_index,
+                              agg.by, agg.without)
+            attrs["index"] = how
+        PROMQL_GROUP_INDEXES.inc(index=how)
+        return grp
+
+    def _eval_aggregate(self, agg: Aggregate, p: EvalParams, ctx,
+                        fuse: bool = True):
+        simple = agg.op in _AGG_STATS
+        expr = agg.expr
+        ranged = isinstance(expr, Call) and expr.func in _RANGE_FUNCS
+        if simple and _grid_call(expr):
+            # `agg by (...) (range_fn(m[w]))`: from the resident pivot
+            # to its [groups, steps] answer in one program
+            plan = self._grid_plan(expr, p, ctx, fuse)
+            if plan is None:
+                return SeriesMatrix([], jnp.zeros((0, p.T)))
+            if plan.pivot is not None:
+                grp = self._group_index(plan.loaded.labels, agg)
+                PROMQL_EVAL_PROGRAMS.inc(path="fused")
+                return SeriesMatrix(
+                    grp.labels, _run_on_grid(plan, p, grp, agg.op))
+            v = self._grid_func_stepwise(plan, p)
+        else:
+            v = self._eval(expr, p, ctx)
         if not isinstance(v, SeriesMatrix):
             raise PromqlError(f"{agg.op} needs an instant vector")
         if v.num_series == 0:
             return SeriesMatrix([], jnp.zeros((0, p.T)))
-
-        # the group index depends on the input's label sets and the
-        # grouping alone: found beside the loaded series they derive
-        # from, or built (for a list of no known origin, per request)
-        with tracing.stage("assemble", step="group_labels",
-                           series=v.num_series) as attrs:
-            grp, how = derive(v.labels, "group_index", _build_group_index,
-                              agg.by, agg.without)
-            attrs["index"] = how
-        PROMQL_GROUP_INDEXES.inc(index=how)
+        grp = self._group_index(v.labels, agg)
         gidx, G, glabels = grp.gidx, grp.G, grp.labels
 
         vals = v.values  # [S, T]
-        if agg.op in ("sum", "avg", "min", "max", "count", "group",
-                      "stddev", "stdvar"):
-            ops = {
-                "sum": ("sum",), "avg": ("sum", "count"),
-                "min": ("min",), "max": ("max",),
-                "count": ("count",), "group": ("count",),
-                "stddev": ("sum", "sumsq", "count"),
-                "stdvar": ("sum", "sumsq", "count"),
-            }[agg.op]
-            need = set(ops) | {"count"}
-            st = segment_agg(vals, grp.d_gidx, grp.mask, G,
-                             ops=tuple(sorted(need)))
-            cnt = st["count"]
-            present = cnt > 0
-            if agg.op == "sum":
-                out = jnp.where(present, st["sum"], jnp.nan)
-            elif agg.op == "avg":
-                out = jnp.where(present, st["sum"] / jnp.maximum(cnt, 1), jnp.nan)
-            elif agg.op in ("min", "max"):
-                out = st[agg.op]
-            elif agg.op in ("count",):
-                out = jnp.where(present, cnt.astype(jnp.float64), jnp.nan)
-            elif agg.op == "group":
-                out = jnp.where(present, 1.0, jnp.nan)
-            else:  # stddev / stdvar (population)
-                n = jnp.maximum(cnt.astype(jnp.float64), 1)
-                var = jnp.maximum(st["sumsq"] / n - (st["sum"] / n) ** 2, 0.0)
-                out = jnp.where(present, var if agg.op == "stdvar" else jnp.sqrt(var), jnp.nan)
-            return SeriesMatrix(glabels, out)
+        if simple:
+            # an operand that is no range function (a binary expression,
+            # a selector): the aggregation is a program of its own; a
+            # range function that took window_stats keeps the kernel at
+            # a time it has always run
+            split = fuse and not ranged
+            PROMQL_EVAL_PROGRAMS.inc(path="split" if split else "stepwise")
+            fold = _agg if split else _fold_groups
+            return SeriesMatrix(glabels, fold(
+                vals, grp.d_gidx, grp.mask, num_groups=G, op=agg.op))
+        PROMQL_EVAL_PROGRAMS.inc(path="stepwise")
 
         if agg.op in ("topk", "bottomk"):
             k = int(_scalar_of(self._eval(agg.param, p, ctx)))
@@ -1454,6 +1509,123 @@ def _build_fold_index(labels: list) -> _FoldIndex:
         valid[g, :n] = True
     return _FoldIndex([dict(s) for s in sigs], h2d(src), h2d(bounds),
                       h2d(valid), skipped)
+
+
+@dataclass
+class _GridPlan:
+    """The host half of a `_GRID_FUNCS` call: what `_load` found and
+    the static facts of the program that answers from it."""
+
+    fn: str
+    sel: object  # the VectorSelector or Subquery
+    loaded: Loaded
+    w: int  # the window in steps
+    range_s: float
+    pivot: Optional[tuple]  # (grid [P], mat [S, P, C]), or None: stepwise
+
+
+def _grid_call(node) -> bool:
+    """Whether `node` is a range function a pivot can answer inside its
+    aggregation's program: no subquery, no `@` (which pins the range's
+    evaluation to one instant and broadcasts it)."""
+    return isinstance(node, Call) and node.func in _GRID_FUNCS \
+        and isinstance(node.args[0], VectorSelector) \
+        and node.args[0].at_s is None
+
+
+def _fold_groups(vals, gidx, mask, num_groups: int, op: str):
+    """An `_AGG_STATS` operator over the series axis, [S, T] -> [G, T]:
+    the segment reduction and its finish, NaN for a group without a
+    sample. Pure: a program of its own (`_agg`), the tail of a fused
+    one, or — called as it is — a kernel and its eager finish."""
+    st = segment_agg(vals, gidx, mask, num_groups,
+                     ops=tuple(sorted(set(_AGG_STATS[op]) | {"count"})))
+    cnt = st["count"]
+    present = cnt > 0
+    if op == "sum":
+        return jnp.where(present, st["sum"], jnp.nan)
+    if op == "avg":
+        return jnp.where(present, st["sum"] / jnp.maximum(cnt, 1), jnp.nan)
+    if op in ("min", "max"):
+        return st[op]
+    if op == "count":
+        return jnp.where(present, cnt.astype(jnp.float64), jnp.nan)
+    if op == "group":
+        return jnp.where(present, 1.0, jnp.nan)
+    # stddev / stdvar (population)
+    n = jnp.maximum(cnt.astype(jnp.float64), 1)
+    # a square is its own maximum with 0: written out, it keeps the
+    # square a rounded product wherever this is traced. Inside one
+    # program the CPU compiler contracts a product that feeds a
+    # subtraction into one fused multiply-add, and the cancelling
+    # difference would read other digits than it does a kernel at a time
+    # (tests/test_promql_fused.py holds the two to the same bits)
+    mean_sq = jnp.maximum((st["sum"] / n) ** 2, 0.0)
+    var = jnp.maximum(st["sumsq"] / n - mean_sq, 0.0)
+    return jnp.where(present, var if op == "stdvar" else jnp.sqrt(var),
+                     jnp.nan)
+
+
+_agg = jax.jit(device_telemetry.kernel_name("promql_agg")(_fold_groups),
+               static_argnames=("num_groups", "op"))
+
+_RATE_STATIC = ("num_steps", "w", "is_counter", "is_rate")
+_OVER_TIME_STATIC = ("n", "num_steps", "w", "fn")
+
+_rate = jax.jit(device_telemetry.kernel_name("promql_rate")(grid_rate),
+                static_argnames=_RATE_STATIC)
+_over_time = jax.jit(
+    device_telemetry.kernel_name("promql_over_time")(grid_over_time),
+    static_argnames=_OVER_TIME_STATIC)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=_RATE_STATIC + ("num_groups", "op"))
+@device_telemetry.kernel_name("promql_rate_agg")
+def _rate_agg(grid, mat, t0, step, range_s, gidx, mask, *, num_steps, w,
+              is_counter, is_rate, num_groups, op):
+    """`agg by (...) (rate | increase | delta (m[w]))` from the pivot to
+    its [G, T] answer."""
+    return _fold_groups(
+        grid_rate(grid, mat, t0, step, range_s, num_steps, w, is_counter,
+                  is_rate), gidx, mask, num_groups, op)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=_OVER_TIME_STATIC + ("num_groups", "op"))
+@device_telemetry.kernel_name("promql_over_time_agg")
+def _over_time_agg(grid, mat, i0, t0, step, gidx, mask, *, n, num_steps, w,
+                   fn, num_groups, op):
+    """`agg by (...) (sum | avg | count _over_time (m[w]))` from the
+    pivot to its [G, T] answer."""
+    return _fold_groups(
+        grid_over_time(grid, mat, i0, t0, step, n, num_steps, w, fn),
+        gidx, mask, num_groups, op)
+
+
+def _run_on_grid(plan: _GridPlan, p: EvalParams,
+                 grp: Optional["_GroupIndex"] = None,
+                 op: Optional[str] = None) -> jax.Array:
+    """The pure half of `plan` as ONE program: its range function over
+    the pivot, [S, T], or with a group index the aggregation `op` of
+    that, [G, T]. The range's place in time (`t0`, `step`, the window's
+    seconds, the own range's first point), the group index and the mask
+    are operands: a range whose end moves runs the same executable."""
+    grid, mat = plan.pivot
+    fold, static = (), {}
+    if grp is not None:
+        fold = (grp.d_gidx, grp.mask)
+        static = {"num_groups": grp.G, "op": op}
+    if plan.fn in _RATE_FUNCS:
+        run = _rate if grp is None else _rate_agg
+        return run(grid, mat, p.start, p.step, plan.range_s, *fold,
+                   num_steps=p.T, w=plan.w,
+                   is_counter=plan.fn in _COUNTER_FUNCS,
+                   is_rate=plan.fn == "rate", **static)
+    i0, n = plan.loaded.cut or (0, int(grid.shape[0]))
+    run = _over_time if grp is None else _over_time_agg
+    return run(grid, mat, i0, p.start, p.step, *fold, n=n, num_steps=p.T,
+               w=plan.w, fn=plan.fn, **static)
 
 
 @jax.jit

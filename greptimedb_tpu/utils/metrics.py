@@ -591,6 +591,15 @@ PROMQL_GROUP_INDEXES = REGISTRY.counter(
     "host and on the device, at their data version; build = built from "
     "the label sets and uploaded for this request: a first touch, or "
     "label sets of no known origin)")
+PROMQL_EVAL_PROGRAMS = REGISTRY.counter(
+    "greptimedb_tpu_promql_eval_programs_total",
+    "PromQL aggregation nodes by how the device evaluated them (fused = "
+    "agg(range_fn(m[w])) over a resident complete-grid pivot, from the "
+    "pivot to the [groups, steps] answer in one program; split = the "
+    "aggregation as a program of its own over an operand that is no "
+    "range function; stepwise = a kernel at a time with eager operations "
+    "between: samples without a complete grid, a subquery, another "
+    "range function or operator)")
 DEVICE_HOT_SET_EVENTS = REGISTRY.counter(
     "greptimedb_tpu_device_hot_set_events_total",
     "HBM-resident columnar hot set events by kind (hit/miss/evict/pin — "

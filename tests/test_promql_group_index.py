@@ -98,6 +98,13 @@ class _Rootless(PromqlEngine):
             v = dataclasses.replace(v, labels=list(v.labels))
         return v
 
+    @staticmethod
+    def _group_index(labels, agg):
+        # an aggregation fused with its range function never passes
+        # through _eval_call: its input's label sets lose their origin
+        # here
+        return PromqlEngine._group_index(list(labels), agg)
+
 
 def _counts() -> dict:
     return {how: PROMQL_GROUP_INDEXES.get(index=how)
