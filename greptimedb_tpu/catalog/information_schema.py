@@ -103,6 +103,36 @@ def _columns(qe, ctx):
     return cols
 
 
+def _partition_exprs(info) -> list:
+    """One expression a region: a range rule's `col >= 'lo' AND col <
+    'hi'` (the first and the last region open on one side; several
+    columns compare as a row), a hash rule's bucket, None for a table
+    of one region."""
+    n = len(info.region_ids)
+    rules = info.partition_rules
+    if not isinstance(rules, dict) or n <= 1:
+        return [None] * n
+    cols = rules.get("columns") or []
+    if rules.get("type") == "hash":
+        return [f"hash({', '.join(cols)}) % {n} = {i}" for i in range(n)]
+    lhs = cols[0] if len(cols) == 1 else "(" + ", ".join(cols) + ")"
+
+    def lit(bound) -> str:
+        vals = [f"'{v}'" if isinstance(v, str) else str(v) for v in bound]
+        return vals[0] if len(vals) == 1 else "(" + ", ".join(vals) + ")"
+
+    bounds = [b for b in rules.get("bounds") or [] if b]
+    exprs = []
+    for i in range(n):
+        parts = []
+        if 0 < i <= len(bounds):
+            parts.append(f"{lhs} >= {lit(bounds[i - 1])}")
+        if i < len(bounds):
+            parts.append(f"{lhs} < {lit(bounds[i])}")
+        exprs.append(" AND ".join(parts) or None)
+    return exprs
+
+
 @_virtual("partitions")
 def _partitions(qe, ctx):
     cols = {k: [] for k in ("table_catalog", "table_schema", "table_name",
@@ -111,13 +141,7 @@ def _partitions(qe, ctx):
     for db in qe.catalog.list_databases():
         for name in qe.catalog.list_tables(db):
             info = qe.catalog.table(db, name)
-            exprs = [None] * len(info.region_ids)
-            if info.partition_rules:
-                rules = info.partition_rules
-                if isinstance(rules, dict):
-                    bounds = rules.get("bounds") or []
-                    exprs = [str(b) for b in bounds] + [None]
-                    exprs = exprs[:len(info.region_ids)] or [None]
+            exprs = _partition_exprs(info)
             for i, rid in enumerate(info.region_ids):
                 cols["table_catalog"].append("greptime")
                 cols["table_schema"].append(db)
@@ -140,8 +164,11 @@ def _region_peers(qe, ctx):
     for db in qe.catalog.list_databases():
         for name in qe.catalog.list_tables(db):
             info = qe.catalog.table(db, name)
-            for rid in info.region_ids:
-                peer = route.get(rid, 0)
+            for i, rid in enumerate(info.region_ids):
+                # one process: region i of a table of several computes
+                # on chip i (query/tier.py region_device), and that
+                # index is its peer whatever this process can see
+                peer = route.get(rid, i if cluster is None else 0)
                 cols["region_id"].append(rid)
                 cols["peer_id"].append(peer)
                 cols["peer_addr"].append(f"datanode-{peer}")

@@ -20,6 +20,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from greptimedb_tpu.storage import index as _index
+
 
 @dataclass
 class PartitionBound:
@@ -61,6 +63,15 @@ class PartitionRule:
         for i, r in enumerate(uniq):
             out[int(r)] = order[bounds[i]:bounds[i + 1]]
         return out
+
+    def match_regions(self, preds: Optional[dict]) -> list[int]:
+        """Read-side pruning: the region indices a conjunction of tag
+        predicates can match, ascending. `preds` is what
+        `storage.index.extract_tag_predicates` reads off a WHERE clause
+        ({column: (InSet | Range | ..., ...)}, ANDed; values are
+        strings). Conservative: a predicate kind or a rule this cannot
+        reason about keeps every region."""
+        return list(range(self.num_regions()))
 
     def to_json(self) -> str:
         raise NotImplementedError
@@ -122,6 +133,39 @@ class RangePartitionRule(PartitionRule):
             region += le.astype(np.int32)
         return region
 
+    def match_regions(self, preds: Optional[dict]) -> list[int]:
+        n = len(self.bounds)
+        if n == 1 or not preds or not self.columns:
+            return list(range(n))
+        # the FIRST partition column decides: a row whose first value is
+        # v lies in a region between #(bounds whose first value < v) and
+        # #(bounds whose first value <= v) — one region for a
+        # single-column rule, where an equal bound is <= the row
+        edges = np.asarray([str(b.values[0]) for b in self.bounds[:-1]])
+        single = len(self.columns) == 1
+
+        def span(lo: Optional[str], hi: Optional[str]) -> tuple[int, int]:
+            first = 0 if lo is None else int(np.searchsorted(
+                edges, lo, side="right" if single else "left"))
+            last = n - 1 if hi is None else int(np.searchsorted(
+                edges, hi, side="right"))
+            return first, last
+
+        keep = np.ones(n, dtype=bool)
+        for p in _as_preds(preds.get(self.columns[0])):
+            hit = np.zeros(n, dtype=bool)
+            if isinstance(p, _index.InSet):
+                for v in p.values:
+                    first, last = span(v, v)
+                    hit[first:last + 1] = True
+            elif isinstance(p, _index.Range):
+                first, last = span(p.lo, p.hi)
+                hit[first:last + 1] = True
+            else:
+                continue  # a kind with no order to read: prunes nothing
+            keep &= hit
+        return np.flatnonzero(keep).tolist()
+
     def to_json(self) -> str:
         return json.dumps(
             {
@@ -137,6 +181,14 @@ class RangePartitionRule(PartitionRule):
         return RangePartitionRule(
             d["columns"], [PartitionBound(tuple(v)) for v in d["bounds"]]
         )
+
+
+def _as_preds(v) -> tuple:
+    """One column's predicates as a tuple (a bare set of values is the
+    historical form of one InSet)."""
+    if v is None:
+        return ()
+    return _index._norm_preds(v)
 
 
 def _hash_column(vals: np.ndarray) -> np.ndarray:
@@ -198,6 +250,30 @@ class HashPartitionRule(PartitionRule):
                 h = h * np.uint64(1000003) ^ _hash_column(c)
         return (h % np.uint64(self._n)).astype(np.int32)
 
+    def match_regions(self, preds: Optional[dict]) -> list[int]:
+        if self._n == 1 or not preds:
+            return list(range(self._n))
+        # every partition column pinned to a few values: hash the
+        # combinations as the write scatter would
+        value_sets = []
+        for c in self.columns:
+            sets = [set(p.values) for p in _as_preds(preds.get(c))
+                    if isinstance(p, _index.InSet)]
+            if not sets:
+                return list(range(self._n))
+            value_sets.append(sorted(set.intersection(*sets)))
+        combos = 1
+        for vs in value_sets:
+            combos *= len(vs)
+        if combos == 0:
+            return []
+        if combos > 4096:
+            return list(range(self._n))
+        grids = np.meshgrid(*[np.asarray(vs, dtype=object)
+                              for vs in value_sets], indexing="ij")
+        regions = self.find_regions([g.reshape(-1) for g in grids])
+        return np.unique(regions).tolist()
+
     def to_json(self) -> str:
         return json.dumps({"type": "hash", "columns": self.columns,
                            "regions": self._n})
@@ -217,6 +293,19 @@ def rule_from_json(obj) -> PartitionRule:
         return HashPartitionRule(d["columns"], d["regions"])
     return RangePartitionRule(
         d["columns"], [PartitionBound(tuple(v)) for v in d["bounds"]])
+
+
+def rule_of(info) -> Optional[PartitionRule]:
+    """The table's partition rule, parsed once and memoized on the
+    TableInfo (hot paths: no JSON round-trip per INSERT or per SELECT);
+    None for a table without one."""
+    rule = getattr(info, "_rule_cache", None)
+    if rule is None and info.partition_rules:
+        rule = info.partition_rules \
+            if isinstance(info.partition_rules, PartitionRule) \
+            else rule_from_json(info.partition_rules)
+        info._rule_cache = rule
+    return rule
 
 
 def single_region_rule() -> RangePartitionRule:

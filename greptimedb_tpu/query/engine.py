@@ -21,6 +21,7 @@ from greptimedb_tpu.datatypes.recordbatch import RecordBatch
 from greptimedb_tpu.datatypes.schema import ColumnSchema, Schema
 from greptimedb_tpu.datatypes.types import DataType, SemanticType, parse_sql_type
 from greptimedb_tpu.datatypes.vector import DictVector
+from greptimedb_tpu.partition.rule import rule_of
 from greptimedb_tpu.query import logical as lp
 from greptimedb_tpu.query.expr import PlanError, eval_host, has_aggregate
 from greptimedb_tpu.query.physical import PhysicalExecutor
@@ -1584,7 +1585,7 @@ class QueryEngine:
         write = self.region_engine.delete if delete else self.region_engine.put
         if len(info.region_ids) == 1 or not info.partition_rules:
             return write(info.region_ids[0], batch)
-        rule = _cached_rule(info)
+        rule = rule_of(info)
         cols = []
         for cname in rule.columns:
             col = batch.columns[cname]
@@ -1981,22 +1982,6 @@ def _subst_session_funcs(sel: ast.Select, ctx: QueryContext) -> ast.Select:
     items = [dataclasses.replace(it, expr=_subst_expr(it.expr, ctx))
              for it in sel.items]
     return dataclasses.replace(sel, items=items)
-
-
-def _cached_rule(info: TableInfo):
-    """Parse the table's partition rule once and memoize it on the
-    TableInfo (hot write path: no JSON round-trip per INSERT)."""
-    from greptimedb_tpu.partition.rule import PartitionRule, rule_from_json
-
-    rule = getattr(info, "_rule_cache", None)
-    if rule is None:
-        rule = (
-            info.partition_rules
-            if isinstance(info.partition_rules, PartitionRule)
-            else rule_from_json(info.partition_rules)
-        )
-        info._rule_cache = rule
-    return rule
 
 
 def _render_type(dt: DataType) -> str:

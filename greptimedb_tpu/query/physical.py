@@ -64,10 +64,12 @@ from greptimedb_tpu.query.expr import (
 from greptimedb_tpu.query.result import QueryResult
 from greptimedb_tpu.query.tier import (
     ACTIVE_TIER,
+    OnShard,
     TierCtx,
     TierRouter,
     incremental_key,
     part_placement,
+    region_device,
     whole_scan_key,
 )
 from greptimedb_tpu.sql import ast
@@ -75,6 +77,7 @@ from greptimedb_tpu.storage.engine import RegionEngine
 from greptimedb_tpu.storage.region import (
     ScanData,
     ScanExpired,
+    _scan_io_add,
     scan_io_counters,
     scan_io_since,
 )
@@ -195,6 +198,79 @@ class DeviceKey:
     step: int = 0  # bucket width in the column's storage unit
     # minimum bucket index (offsets ids to 0)
     base: int = dataclasses.field(default=0, compare=False, repr=False)
+
+
+class _AggBinding(NamedTuple):
+    """One aggregate bound to one scan (`PhysicalExecutor._bind_agg`)."""
+
+    ctx: object
+    bound_where: object
+    keys: list
+    decoders: list
+    extra_cols: dict
+    num_groups: int
+    sparse: bool
+    arg_exprs: list
+    spec_slot: list
+    ops: set
+
+
+class _RegionOut(NamedTuple):
+    """What one region's thread hands the fan-out
+    (`PhysicalExecutor._region_partials`)."""
+
+    partials: list
+    stats: Optional[dict]  # the per-part fold's; None: classic kernels
+    spec_slot: list  # each spec's value-plane column (`_bind_agg`)
+    path: Optional[str]
+    parts_fetched: int
+    scanned: bool  # the region's scan had rows
+
+
+class _RegionFold:
+    """One region's fold on its chip, as `_aggregate` sees it from the
+    folding thread (`_FOLD.current`): `seen` collects the devices the
+    fold's programs ran on (region_partial_total says whether they were
+    the region's own chip), and a program dispatched for the first time
+    is warmed on the table's other chips beside the request — a
+    partitioned table runs ONE program on all its chips, so the chip
+    that meets a shape first pays its compile and the others find it
+    compiled (a one-host panel warms one chip; the next host lives on
+    another)."""
+
+    def __init__(self, siblings, router):
+        self.siblings, self.router = siblings, router
+        self.seen: set = set()
+
+    def warm_siblings(self, sig: tuple, dispatch) -> None:
+        """`dispatch(device)` runs the program that was just new here
+        on `device`, its arguments copied there; once per program and
+        device, on threads the device status counts as warming."""
+        for dev in self.siblings:
+            key = sig[:-1] + (dev,)
+            with _PROGRAMS_LOCK:
+                if key in _PROGRAMS_SEEN:
+                    continue  # that chip met the shape itself
+                _PROGRAMS_SEEN.add(key)
+
+            def warm(dev=dev, key=key):
+                with self.router.compiling(("sibling", key)):
+                    try:
+                        dispatch(dev)
+                    except Exception:  # noqa: BLE001 — best effort
+                        # the chip compiles the shape when it meets it
+                        with _PROGRAMS_LOCK:
+                            _PROGRAMS_SEEN.discard(key)
+
+            # not a daemon: a process that ends while XLA compiles on a
+            # daemon thread aborts; this one is waited for (seconds)
+            threading.Thread(
+                target=tracing.propagate(warm, background=True),
+                name="gtpu-sibling-warm").start()
+
+
+#: the region fold open on this thread (`_region_partials`), if any
+_FOLD = threading.local()
 
 
 class _BlockEntry(NamedTuple):
@@ -1253,8 +1329,28 @@ def _aggregate(step, *args, where, schema, keys=None, **statics):
             _PROGRAMS_SEEN.add(sig)
     AGG_PROGRAM_EVENTS.inc(event=event)
     tracing.note_stage(program=event)
-    return step(*args, where=shape, where_args=operands, schema=schema,
-                **statics)
+    out = step(*args, where=shape, where_args=operands, schema=schema,
+               **statics)
+    fold = getattr(_FOLD, "current", None)
+    if fold is not None:
+        fold.seen.update(d.id for d in
+                         jax.tree_util.tree_leaves(out)[0].devices())
+        if event == "new":
+            def on(device):
+                with OnShard(device):
+                    # as the fold makes them: uncommitted arrays of the
+                    # thread's default device, a Python scalar weakly
+                    # typed (the jit cache tells those apart)
+                    there = jax.tree_util.tree_map(
+                        lambda x: jnp.asarray(
+                            x.item() if getattr(x, "weak_type", False)
+                            else np.asarray(x)), args)
+                    jax.block_until_ready(step(
+                        *there, where=shape, where_args=operands,
+                        schema=schema, **statics))
+
+            fold.warm_siblings(sig, on)
+    return out
 
 
 # ---- execution tiers -------------------------------------------------------
@@ -1596,6 +1692,17 @@ class PhysicalExecutor:
                 if res is not None:
                     return res
 
+            # a table of several regions in one process: each matching
+            # region folds its own scan on its own chip, the partials
+            # combine by key value (what cannot be split gathers below)
+            regions = self._matching_regions(table, tag_preds)
+            if agg is not None and len(table.region_ids) > 1:
+                res = self._try_region_fanout(
+                    regions, table, where, agg, having, project, sort,
+                    limit, offset, ts_range, scan_node, tag_preds)
+                if res is not None:
+                    return res
+
             # beyond-RAM aggregate scans stream: append-mode (no dedup
             # sort), single region, estimated rows over the threshold
             if (agg is not None and table.append_mode
@@ -1641,15 +1748,17 @@ class PhysicalExecutor:
                     scan = self.engine.scan(table.region_ids[0], ts_range,
                                             scan_node.columns, tag_preds)
                 else:
-                    # distributed fan-out: gather every region's scan
-                    # (MergeScan, dist_plan/merge_scan.rs analog)
+                    # what cannot be split into per-region partials
+                    # (raw rows, order statistics) gathers the scans of
+                    # the regions the predicate can match (MergeScan,
+                    # dist_plan/merge_scan.rs analog)
                     from greptimedb_tpu.storage.merge_scan import merge_scans
 
                     scan = merge_scans(
                         [
                             self.engine.scan(rid, ts_range,
                                              scan_node.columns, tag_preds)
-                            for rid in table.region_ids
+                            for _i, rid in regions
                         ]
                     )
                 # rows land on the span (and, through it, the resource
@@ -1696,6 +1805,207 @@ class PhysicalExecutor:
                     return res
             return run(candidates[-1])
         return run(ts_range)
+
+    # ---- a table of several regions, one region a chip ---------------------
+
+    def _matching_regions(self, table, tag_preds) -> list[tuple[int, int]]:
+        """(position in the table, region id) of every region the
+        statement's predicates on the partition columns can match: the
+        table's rule read against the literals (`=`, `IN`, ranges), which
+        stay operands of the device programs. Counts the routed and the
+        pruned on region_route_total."""
+        rids = list(table.region_ids)
+        if len(rids) == 1:
+            return [(0, rids[0])]
+        from greptimedb_tpu.partition.rule import rule_of
+        from greptimedb_tpu.utils.metrics import REGION_ROUTE
+
+        rule = rule_of(table)
+        matched = list(range(len(rids)))
+        if rule is not None and rule.num_regions() == len(rids):
+            matched = rule.match_regions(tag_preds)
+        REGION_ROUTE.inc(float(len(matched)), outcome="scanned")
+        REGION_ROUTE.inc(float(len(rids) - len(matched)), outcome="pruned")
+        return [(i, rids[i]) for i in matched]
+
+    def _try_region_fanout(self, regions, table, where, agg, having,
+                           project, sort, limit, offset, ts_range,
+                           scan_node, tag_preds) -> Optional[QueryResult]:
+        """An aggregate over a table of several regions: every matching
+        region takes its OWN scan — a plan with parts, its region id and
+        data version — through the per-part route (`_scan_partials`) on
+        its own chip, the regions side by side, and all their partials
+        combine by key value as one region's parts do
+        (`combine_partials`: the regions' tag dictionaries differ). A
+        region that fails fails the request: no answer comes from fewer
+        regions than match. None for what per-region partials cannot
+        give (an order statistic needs the rows together): the caller
+        gathers."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        from greptimedb_tpu.query.dist_agg import combine_partials
+        from greptimedb_tpu.utils import deadline as dl
+        from greptimedb_tpu.utils.metrics import (
+            REGION_COMBINE_SECONDS,
+            REGION_FANOUT_SECONDS,
+        )
+
+        if any(_needs_host_agg(spec, table.schema) for spec in agg.aggs):
+            return None
+        lp_tag = self._lastpoint_tag(table, where, agg, ts_range) \
+            if hasattr(self.engine, "scan_last") else None
+
+        def one(region):
+            return self._region_partials(region, table, where, agg,
+                                         ts_range, scan_node, tag_preds,
+                                         lp_tag)
+
+        t0 = time.perf_counter()
+        with tracing.span("region_fanout", table=table.name,
+                          regions_matched=len(regions)) as attrs:
+            if len(regions) > 1:
+                # the request's thread folds the first region itself (a
+                # one-host panel never leaves it); the others run beside
+                # it, so the wall is the slowest region's
+                beside = dl.propagate(tracing.propagate(one))
+                with ThreadPoolExecutor(
+                        max_workers=len(regions) - 1,
+                        thread_name_prefix="gtpu-region") as pool:
+                    futures = [pool.submit(beside, r) for r in regions[1:]]
+                    outs = [one(regions[0])]
+                    outs += [dl.wait_future(f, "region fan-out")
+                             for f in futures]
+            else:
+                outs = [one(r) for r in regions]
+            attrs["regions_scanned"] = sum(1 for o in outs if o.scanned)
+        REGION_FANOUT_SECONDS.observe(time.perf_counter() - t0)
+
+        partials = [p for o in outs for p in o.partials]
+        # what `run` reads off this thread: the parts the regions'
+        # threads fetched, and the fold's statistics summed (None where
+        # a region went through the classic kernels)
+        _scan_io_add(parts=sum(o.parts_fetched for o in outs))
+        all_stats = [o.stats for o in outs if o.scanned]
+        stats = None
+        if all_stats and all(st is not None for st in all_stats):
+            stats = {k: sum(st[k] for st in all_stats)
+                     for k in all_stats[0] if k != "sparse"}
+            stats["sparse"] = any(st["sparse"] for st in all_stats)
+        ops_t = tuple(sorted(self._agg_ops(agg, table.schema)))
+        t0 = time.perf_counter()
+        with tracing.stage("host_agg", step="combine_partials",
+                           regions=len(regions)), \
+                tracing.span("region_combine", partials=len(partials)):
+            combined = combine_partials(partials, len(agg.keys), ops_t)
+        REGION_COMBINE_SECONDS.observe(time.perf_counter() - t0)
+        path = next((o.path for o in outs if o.path), None)
+        self.last_path = "fanout+" + (path or "empty")
+        self.last_tier = "mesh"
+        self.last_partial_stats = stats
+        spec_slot = next((o.spec_slot for o in outs if o.scanned), [])
+        return self._finalize_combined_agg(combined, table, agg, having,
+                                           project, sort, limit, offset,
+                                           spec_slot)
+
+    def _region_partials(self, region, table, where, agg, ts_range,
+                         scan_node, tag_preds, lp_tag) -> "_RegionOut":
+        """One region's scan to its partials, on the region's chip."""
+        from greptimedb_tpu.utils.metrics import REGION_PARTIAL
+
+        index, rid = region
+        device = region_device(index)
+        parts0 = scan_io_counters()[0]
+        others = {region_device(i) for i in range(len(table.region_ids))}
+        _FOLD.current = fold = _RegionFold(
+            sorted(others - {device}, key=lambda d: d.id), self.router)
+        try:
+            with tracing.span("region_partial", region=rid,
+                              device=device.id) as attrs, OnShard(device):
+                scan = None
+                if lp_tag is not None:
+                    # newest-first pruned scan, as a one-region table's
+                    # lastpoint takes (Region.scan_last)
+                    with tracing.stage("scan", table=table.name, regions=1,
+                                       lastpoint=True):
+                        scan = self.engine.scan_last(rid, lp_tag,
+                                                     scan_node.columns)
+                if scan is None:
+                    io0 = scan_io_counters()
+                    with tracing.stage("scan", table=table.name,
+                                       regions=1) as scan_attrs:
+                        scan = self.engine.scan(rid, ts_range,
+                                                scan_node.columns, tag_preds)
+                        scan_attrs["rows"] = 0 if scan is None \
+                            else scan.num_rows
+                        scan_attrs.update(scan_io_since(io0))
+                attrs["rows"] = 0 if scan is None else scan.num_rows
+                if scan is None:
+                    return _RegionOut([], None, [], None, 0, False)
+                try:
+                    with tracing.span("aggregate", rows=scan.num_rows):
+                        partials, stats, spec_slot = self._scan_partials(
+                            scan, table, where, agg, scan_node, device)
+                finally:
+                    scan.close()
+        finally:
+            _FOLD.current = None
+        if fold.seen:
+            REGION_PARTIAL.inc(placement="own_chip"
+                               if fold.seen == {device.id} else "other")
+        return _RegionOut(partials, stats, spec_slot, self.last_path,
+                          scan_io_counters()[0] - parts0, True)
+
+    def _scan_partials(self, scan, table, where, agg, scan_node,
+                       device) -> tuple[list, Optional[dict], list]:
+        """One region's scan to its list of value-keyed partials
+        ({"keys", "planes"}) on `device`: the per-part route — cached
+        partials, the pruned batches, the fold of what is missing — and,
+        where that route refuses the shape (a boundary first/last
+        reduction, tombstones, no immutable part), the classic kernels'
+        planes as ONE partial. Returns (partials, the fold's statistics
+        or None, each spec's value-plane column)."""
+        from greptimedb_tpu.query import partial_cache as pc
+        from greptimedb_tpu.utils.metrics import PARTIAL_AGG_CACHE_EVENTS
+
+        schema = table.schema
+        ts_name = schema.time_index.name
+        b = self._bind_agg(scan, table, where, agg, scan_node)
+        with tracing.stage("host_agg", step="boundary_firstlast"):
+            reduced = self._boundary_firstlast(
+                scan, table, agg, b.bound_where, b.keys, b.extra_cols)
+        if reduced is None and pc.enabled() and not _PARTIAL_DISABLED["flag"]:
+            try:
+                partials, stats, _tier = self._incremental_partials(
+                    scan, table, b.bound_where, b.keys, b.decoders,
+                    b.arg_exprs, b.ops, b.num_groups, ts_name, b.ctx,
+                    b.extra_cols, agg, b.sparse, device=device)
+                self.last_path = "incremental_sparse" if stats["sparse"] \
+                    else "incremental"
+                return partials, stats, b.spec_slot
+            except pc.PartialCacheIneligible:
+                PARTIAL_AGG_CACHE_EVENTS.inc(event="fallback")
+        if reduced is None:
+            self._whole_columns(scan, table)  # the classic kernels' input
+        else:
+            scan = reduced
+        self.last_tier = "device"  # this chip alone, not the mesh's shards
+        acc, sparse_gids = self._stream_agg(
+            scan, table, b.bound_where, tuple(b.keys), tuple(b.arg_exprs),
+            tuple(sorted(b.ops)), b.num_groups, ts_name, b.ctx,
+            b.extra_cols, b.sparse)
+        if reduced is not None:
+            self.last_path = "boundary+" + (self.last_path or "")
+        return [_acc_partial(acc, sparse_gids, b.keys, b.decoders,
+                             bool(agg.keys))], None, b.spec_slot
+
+    @staticmethod
+    def _agg_ops(agg, schema) -> set:
+        """The primitive planes an aggregate's device specs need."""
+        ops: set = {"rows"}
+        for spec in agg.aggs:
+            if not _needs_host_agg(spec, schema):
+                ops.update(_PRIMITIVES[spec.func])
+        return ops
 
     # ---- distributed aggregation pushdown ----------------------------------
 
@@ -1906,14 +2216,12 @@ class PhysicalExecutor:
         return self._post_process(env, agg, having, project, sort,
                                   limit, offset, table, g)
 
-    def _execute_agg(self, scan, table, where, agg, having, project, sort,
-                     limit, offset, scan_node) -> QueryResult:
+    def _bind_agg(self, scan, table, where, agg, scan_node) -> "_AggBinding":
+        """Bind one aggregate to one scan's dictionaries: the predicate,
+        the group keys with their decoders, the value planes' argument
+        expressions and the primitive ops — what every route from a scan
+        to its partial planes starts from."""
         schema = table.schema
-        ts_name = schema.time_index.name
-        self.last_partial_stats = None
-        if scan is None:
-            return self._empty_agg_result(table, agg, having, project, sort, limit, offset)
-
         ctx = BindContext(schema, scan.tag_dicts)
         bound_where = bind_expr(where, ctx) if where is not None else None
 
@@ -1965,11 +2273,22 @@ class PhysicalExecutor:
             if b not in arg_exprs:
                 arg_exprs.append(b)
             spec_slot.append(arg_exprs.index(b))
-        ops: set = {"rows"}
-        for spec in agg.aggs:
-            if not _needs_host_agg(spec, schema):
-                ops.update(_PRIMITIVES[spec.func])
-        need_ts = bool({"first", "last"} & ops)
+        ops = self._agg_ops(agg, schema)
+        return _AggBinding(ctx, bound_where, keys, decoders, extra_cols,
+                           num_groups, sparse, arg_exprs, spec_slot, ops)
+
+    def _execute_agg(self, scan, table, where, agg, having, project, sort,
+                     limit, offset, scan_node) -> QueryResult:
+        schema = table.schema
+        ts_name = schema.time_index.name
+        self.last_partial_stats = None
+        if scan is None:
+            return self._empty_agg_result(table, agg, having, project, sort, limit, offset)
+        b = self._bind_agg(scan, table, where, agg, scan_node)
+        ctx, bound_where, keys, decoders = \
+            b.ctx, b.bound_where, b.keys, b.decoders
+        extra_cols, num_groups, sparse = b.extra_cols, b.num_groups, b.sparse
+        arg_exprs, spec_slot, ops = b.arg_exprs, b.spec_slot, b.ops
 
         with tracing.stage("host_agg", step="boundary_firstlast"):
             reduced = self._boundary_firstlast(scan, table, agg,
@@ -2104,7 +2423,8 @@ class PhysicalExecutor:
 
     def _incremental_partials(self, scan, table, bound_where, keys,
                               decoders, arg_exprs, ops, num_groups, ts_name,
-                              ctx, extra_cols, agg, sparse=False):
+                              ctx, extra_cols, agg, sparse=False,
+                              device=None):
         """Gather cached part partials, compute the uncached parts and
         the memtable delta with the SAME per-block kernel the classic
         dense path runs, and return the part-ordered partial list (the
@@ -2117,7 +2437,11 @@ class PhysicalExecutor:
         OBSERVED groups' value-keyed planes ([U, F], U <= part rows) —
         the 64k-group fallback becomes a different per-part kernel, and
         the value-keyed combine (query/dist_agg.py) is cardinality-
-        oblivious either way."""
+        oblivious either way.
+
+        `device`: the chip of a region of a multi-region table
+        (`_try_region_fanout`) — every part computes there, nothing is
+        hedged or routed."""
         from collections import OrderedDict as _OrderedDict
 
         from greptimedb_tpu import config
@@ -2206,7 +2530,8 @@ class PhysicalExecutor:
             probed.append((key, entry, p))
             if p is None:
                 delta_est += entry.end - entry.start
-        tier = self.tier_for(agg, delta_est)
+        tier = "mesh" if device is not None \
+            else self.tier_for(agg, delta_est)
         # first-touch hedge (the classic paths' 40s-cold-start fix must
         # not regress here): until this shape's per-part kernel has
         # compiled on the accelerator, folds serve host-side and a
@@ -2214,7 +2539,7 @@ class PhysicalExecutor:
         # fold, per block size this request dispatches: a part's program
         # knows its own block, not the request's other parts
         cold: dict[tuple, _BlockEntry] = {}
-        if delta_est > 0 and self.router.hedges(tier):
+        if device is None and delta_est > 0 and self.router.hedges(tier):
             shape = split_operands(bound_where, schema)[0]
             for e in [entry for _k, entry, p in probed if p is None] \
                     + mem_entries:
@@ -2228,7 +2553,8 @@ class PhysicalExecutor:
         if hedge:
             tier = "host"
         self.last_tier = tier
-        place = part_placement(self.mesh, tier, scan)
+        place = part_placement(self.mesh, tier, scan) if device is None \
+            else (lambda fid: OnShard(device))
 
         tag_names = frozenset(ctx.tag_names)
         float_fields = {c.name for c in schema.field_columns
@@ -2265,21 +2591,10 @@ class PhysicalExecutor:
                                  entry_dmask(entry), **kw)
             with _kstage("readback"):
                 planes = {op: _readback(v) for op, v in out.items()}
-            rows = planes["rows"]
-            rows1 = rows[:, 0] if rows.ndim == 2 else rows
             # keyed aggregates keep only observed groups (matching the
-            # per-region Partial step); a global aggregate keeps its one
-            # group even when empty so the combined result has a row
-            present = np.flatnonzero(rows1 > 0) if agg.keys \
-                else np.arange(1)
-            key_cols = []
-            for i, decode in enumerate(decoders):
-                idx = (present // strides[i]) % keys[i].size
-                col, _ = decode(idx)
-                key_cols.append(np.asarray(col))
-            return {"keys": key_cols,
-                    "planes": {op: pl[present]
-                               for op, pl in planes.items()}}
+            # per-region Partial step)
+            return _acc_partial(planes, None, keys, decoders,
+                                bool(agg.keys))
 
         sparse_kw = {k: v for k, v in kw.items() if k != "num_segments"}
 
@@ -3569,13 +3884,14 @@ class PhysicalExecutor:
     def _upload_prefetch_ok(self, scan) -> bool:
         """Whether the dense block loops should double-buffer uploads:
         the knob is on, the scan is cacheable (prefetch parks results in
-        the HBM cache), and the host tier is not active — the tier's
-        jax.default_device context is thread-scoped, so a background
-        build would land on the wrong device."""
+        the HBM cache), and neither the host tier nor one chip of
+        several (OnShard) is active — their jax.default_device context
+        is thread-scoped, so a background build would land on the wrong
+        device."""
         from greptimedb_tpu.query.device_cache import upload_prefetch_enabled
 
         return (upload_prefetch_enabled() and scan.region_id >= 0
-                and ACTIVE_TIER.get() != "host")
+                and ACTIVE_TIER.get() == "device")
 
     def _gather_blocks(self, scan, plan, fetch, dedup_mask):
         """Walk the block plan through `fetch`, double-buffering block
@@ -4114,6 +4430,28 @@ class PhysicalExecutor:
 
 
 # ---- helpers ---------------------------------------------------------------
+
+
+def _acc_partial(acc: dict, sparse_gids, keys, decoders,
+                 keyed: bool) -> dict:
+    """The classic kernels' planes as one value-keyed partial, the form
+    `combine_partials` folds: the observed groups' decoded keys beside
+    their planes (a global aggregate keeps its one group even when
+    empty, so that the combined result has a row)."""
+    rows = acc["rows"][:, 0] if acc["rows"].ndim == 2 else acc["rows"]
+    if sparse_gids is not None:
+        # acc rows [0, U) are the observed groups, ascending global id
+        present = np.arange(len(sparse_gids))
+        gids = sparse_gids
+    else:
+        present = np.flatnonzero(rows > 0) if keyed else np.arange(1)
+        gids = present
+    strides = _strides([k.size for k in keys])
+    key_cols = [np.asarray(decode((gids // strides[i]) % keys[i].size)[0])
+                for i, decode in enumerate(decoders)]
+    return {"keys": key_cols,
+            "planes": {op: np.asarray(pl)[present]
+                       for op, pl in acc.items()}}
 
 
 def _lost_rows_digest(mask: Optional[np.ndarray], entry) -> Optional[str]:

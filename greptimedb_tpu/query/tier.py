@@ -1,5 +1,11 @@
 """Where does this work run: the one owner of the tier decision.
 
+A table of several regions (PARTITION ON COLUMNS) has no decision to
+make: region i computes on chip i (`region_device`), all matching
+regions at once, and their partials combine on the host
+(query/physical.py `_try_region_fanout`). What follows chooses for a
+table of ONE region.
+
 Three outcomes: "device" is the process's default backend (the chip, or
 the CPU where there is none), "mesh" the row-sharded dispatch over a
 device mesh, "host" the CPU backend of an accelerator process. The
@@ -129,6 +135,16 @@ class OnShard:
         self._dd.__exit__(*exc)
         ACTIVE_TIER.reset(self._token)
         return False
+
+
+def region_device(index: int):
+    """The chip a region of a multi-region table computes on: region i
+    (its position in the table) on local device i, wrapping where the
+    process sees fewer devices than the table has regions. A function
+    of the table's layout alone — what `information_schema.region_peers`
+    reports as the region's peer."""
+    devs = jax.local_devices()
+    return devs[index % len(devs)]
 
 
 def part_placement(mesh, tier: str, scan) -> Callable:

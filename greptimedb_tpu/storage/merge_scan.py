@@ -13,9 +13,46 @@ greptimedb_tpu/parallel/mesh.py.)
 
 from __future__ import annotations
 
+import collections
+import threading
+
 import numpy as np
 
 from greptimedb_tpu.storage.region import ScanData
+
+
+#: (tag, each region's dictionary version) -> (union, per-region code
+#: remaps). A region's dictionary is append-only and its array is
+#: rebuilt only when it grows (TagRegistry.dict_array), so (region,
+#: incarnation, length) names its content: the sort of every value of
+#: every region runs once per version, not in every request that gathers
+_UNIONS: "collections.OrderedDict[tuple, tuple]" = collections.OrderedDict()
+_UNIONS_LOCK = threading.Lock()
+_UNIONS_MAX = 256
+
+
+def _union_dict(name: str, parts: list[ScanData]) -> tuple:
+    """One tag's union dictionary over the regions' own, and each
+    region's old-code -> union-code array."""
+    key = None
+    if all(p.region_id >= 0 for p in parts):
+        key = (name, tuple((p.region_id, p.incarnation,
+                            len(p.tag_dicts[name])) for p in parts))
+        with _UNIONS_LOCK:
+            hit = _UNIONS.get(key)
+            if hit is not None:
+                _UNIONS.move_to_end(key)
+                return hit
+    locals_ = [p.tag_dicts[name].astype(str) for p in parts]
+    union = np.unique(np.concatenate(locals_))
+    out = (union, [np.searchsorted(union, local).astype(np.int32)
+                   for local in locals_])
+    if key is not None:
+        with _UNIONS_LOCK:
+            _UNIONS[key] = out
+            while len(_UNIONS) > _UNIONS_MAX:
+                _UNIONS.popitem(last=False)
+    return out
 
 
 def merge_scans(parts: list[ScanData]) -> ScanData | None:
@@ -31,12 +68,10 @@ def merge_scans(parts: list[ScanData]) -> ScanData | None:
     union_dicts: dict[str, np.ndarray] = {}
     remaps: list[dict[str, np.ndarray]] = [dict() for _ in parts]
     for name in tag_names:
-        all_vals = np.concatenate([p.tag_dicts[name] for p in parts])
-        union = np.unique(all_vals.astype(str))
+        union, maps = _union_dict(name, parts)
         union_dicts[name] = union
-        for i, p in enumerate(parts):
-            local = p.tag_dicts[name].astype(str)
-            remaps[i][name] = np.searchsorted(union, local).astype(np.int32)
+        for i, remap in enumerate(maps):
+            remaps[i][name] = remap
 
     columns: dict[str, np.ndarray] = {}
     for cname in parts[0].columns:

@@ -36,6 +36,7 @@ from greptimedb_tpu.utils.metrics import (
     DEVICE_INFO,
     DEVICE_MEMORY,
     DEVICE_TRANSFER_BYTES,
+    DEVICE_TRANSFER_BYTES_BY_DEVICE,
     REGISTRY,
     XLA_CACHE_RETRIEVALS,
     XLA_COMPILE_SECONDS,
@@ -92,15 +93,29 @@ def register_cache(cache) -> None:
     _caches.add(cache)
 
 
+def _device_label() -> str:
+    """platform:id of the device this thread's new arrays land on."""
+    import jax
+
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return f"{jax.default_backend()}:0"
+    return f"{dev.platform}:{dev.id}"
+
+
 def count_h2d(nbytes: int) -> None:
     if nbytes:
         DEVICE_TRANSFER_BYTES.inc(float(nbytes), direction="h2d")
+        DEVICE_TRANSFER_BYTES_BY_DEVICE.inc(
+            float(nbytes), direction="h2d", device=_device_label())
         ledger.add("h2d_bytes", float(nbytes))
 
 
 def count_d2h(nbytes: int) -> None:
     if nbytes:
         DEVICE_TRANSFER_BYTES.inc(float(nbytes), direction="d2h")
+        DEVICE_TRANSFER_BYTES_BY_DEVICE.inc(
+            float(nbytes), direction="d2h", device=_device_label())
         ledger.add("d2h_bytes", float(nbytes))
 
 

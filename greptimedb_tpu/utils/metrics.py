@@ -559,6 +559,12 @@ DEVICE_TRANSFER_BYTES = REGISTRY.counter(
     "greptimedb_tpu_device_transfer_bytes_total",
     "Host<->device bytes moved by the query engine, by direction "
     "(h2d uploads of scan blocks, d2h result readbacks)")
+DEVICE_TRANSFER_BYTES_BY_DEVICE = REGISTRY.counter(
+    "greptimedb_tpu_device_transfer_bytes_by_device_total",
+    "The bytes of device_transfer_bytes_total by direction and by the "
+    "device they moved to or from (platform:id of the thread's default "
+    "device when the copy was counted), so that bytes per chip can be "
+    "read where a table's regions compute on several")
 DEVICE_CACHE_EVENTS = REGISTRY.counter(
     "greptimedb_tpu_device_cache_events_total",
     "HBM block cache events by kind (hit/miss/evict/prefetch_join — a "
@@ -925,6 +931,26 @@ FRAGMENT_PUSHDOWNS = REGISTRY.counter(
     "Distributed plan fragments shipped to region owners, by mode "
     "(agg/topk/rows/rows_agg/window/lastpoint/rollup/vmapped — partial "
     "planes or pruned candidates return, never raw region scans)")
+REGION_ROUTE = REGISTRY.counter(
+    "greptimedb_tpu_region_route_total",
+    "Regions of a multi-region table a statement was routed to, by "
+    "outcome: scanned (the statement's predicates on the partition "
+    "columns can match it) or pruned (the table's partition rule says "
+    "they cannot); a table of one region counts nothing")
+REGION_PARTIAL = REGISTRY.counter(
+    "greptimedb_tpu_region_partial_total",
+    "Regional folds of a multi-region table's aggregate that dispatched "
+    "a device program, by placement: own_chip (every dispatch ran on the "
+    "chip the table's layout gives the region) or other")
+REGION_FANOUT_SECONDS = REGISTRY.histogram(
+    "greptimedb_tpu_region_fanout_seconds",
+    "Wall time of one fan-out over the matching regions of a multi-"
+    "region table: scan, per-part fold and readback of every region "
+    "side by side, i.e. its slowest region")
+REGION_COMBINE_SECONDS = REGISTRY.histogram(
+    "greptimedb_tpu_region_combine_seconds",
+    "Host wall time of combining all regions' value-keyed partials "
+    "into one aggregate (combine_partials over the union of group keys)")
 EXPIRED_SSTS = REGISTRY.counter(
     "greptimedb_tpu_maintenance_expired_ssts_total",
     "SSTs dropped whole by retention (TTL) expiry")

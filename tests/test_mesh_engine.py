@@ -40,9 +40,11 @@ def _off_oracle(qe, sql, monkeypatch):
 
 
 def test_partitioned_regions_dict_remap_on_mesh(mesh_db, monkeypatch):
-    """Two regions whose tag dictionaries grew in DIFFERENT orders: the
-    merged scan remaps codes, then shards over the mesh — group results
-    must match both the numpy oracle and the mesh-off run."""
+    """Two regions whose tag dictionaries grew in DIFFERENT orders: each
+    folds its own scan on its own device of the mesh and the partials
+    combine by key value — group results must match both the numpy
+    oracle and the mesh-off run; an order statistic still gathers the
+    regions into one scan with a union dictionary (merge_scans)."""
     qe = mesh_db
     qe.execute_one(
         "CREATE TABLE cpu (host STRING, v DOUBLE, ts TIMESTAMP(3) NOT "
@@ -66,7 +68,7 @@ def test_partitioned_regions_dict_remap_on_mesh(mesh_db, monkeypatch):
     sql = ("SELECT host, avg(v), count(v), max(v) FROM cpu "
            "GROUP BY host ORDER BY host")
     got = qe.execute_one(sql).rows()
-    assert qe.executor.last_path in ("sharded", "sharded_prepared"), \
+    assert qe.executor.last_path.startswith("fanout+"), \
         qe.executor.last_path
     assert len(got) == 100
     by_host: dict = {}
@@ -81,6 +83,13 @@ def test_partitioned_regions_dict_remap_on_mesh(mesh_db, monkeypatch):
     assert [r[0] for r in off] == [r[0] for r in got]
     np.testing.assert_allclose(
         [r[1] for r in off], [r[1] for r in got], rtol=1e-9)
+    gathered = qe.execute_one(
+        "SELECT host, median(v) FROM cpu GROUP BY host ORDER BY host").rows()
+    assert not qe.executor.last_path.startswith("fanout+")
+    assert [r[0] for r in gathered] == [r[0] for r in got]
+    np.testing.assert_allclose(
+        [r[1] for r in gathered],
+        [np.median(by_host[r[0]]) for r in gathered], rtol=1e-9)
 
 
 def test_sparse_cardinality_with_mesh_present(mesh_db, monkeypatch):

@@ -522,12 +522,41 @@ class TestScanOnDemand:
             qe.execute_one(f"INSERT INTO cpu VALUES {vals}", CTX)
             for rid in rids:
                 eng.flush(rid)
-            sql = AGG_SQL
+            # an order statistic over a partitioned table still gathers
+            # (merge_scans): its scan has no region of its own
+            sql = "SELECT host, median(v) FROM cpu GROUP BY host " \
+                  "ORDER BY host"
         modes0 = _modes()
         first = qe.execute_one(sql, CTX)
         assert qe.executor.last_path != "incremental"
         second = qe.execute_one(sql, CTX)
         assert _mode_delta(modes0) == {"none": 0, "parts": 0, "whole": 2}
+        assert_same(first, second)
+        assert first.num_rows == 5
+
+
+    def test_a_partitioned_table_takes_the_per_part_route(self, db):
+        """Each region of a PARTITION ON COLUMNS table folds its own
+        scan part by part, under its own identity: the second request
+        fetches no part."""
+        eng, qe = db
+        qe.execute_one(
+            "CREATE TABLE cpu (ts TIMESTAMP(3) TIME INDEX, host "
+            "STRING, v DOUBLE, w DOUBLE, PRIMARY KEY(host)) "
+            "PARTITION ON COLUMNS (host) (host < 'h2', host >= 'h2') "
+            "WITH (append_mode='true')", CTX)
+        rids = qe.catalog.table("public", "cpu").region_ids
+        vals = ", ".join(f"({i * 10}, 'h{i % 5}', {i}.5, 1.0)"
+                         for i in range(100))
+        qe.execute_one(f"INSERT INTO cpu VALUES {vals}", CTX)
+        for rid in rids:
+            eng.flush(rid)
+        modes0 = _modes()
+        first = qe.execute_one(AGG_SQL, CTX)
+        assert qe.executor.last_path == "fanout+incremental"
+        second = qe.execute_one(AGG_SQL, CTX)
+        assert _mode_delta(modes0) == {"none": 1, "parts": 1, "whole": 0}
+        assert qe.executor.last_partial_stats["part_hits"] == 2
         assert_same(first, second)
         assert first.num_rows == 5
 
