@@ -123,6 +123,76 @@ def encode_sql_payload(results, elapsed_ms: float) -> bytes:
                 % (", ".join(out), json.dumps(elapsed_ms))).encode()
 
 
+# ---- Prometheus range answers ----------------------------------------------
+# A matrix answer is written from its columns: no Python object is made
+# for a sample. What Python does is per request, per step (the step
+# prefixes) and, where the fragments are not kept, per series; the rest
+# runs in arrow's kernels, which give the interpreter lock up.
+
+
+_MATRIX_HEAD = b'{"status":"success","data":{"resultType":"matrix","result":['
+_MATRIX_TAIL = b"]}}"
+
+
+def metric_fragments(labels: list, metric=None):
+    """One string a series, `{"metric":{…},"values":[`: the head of its
+    entry in a matrix answer. `json.dumps` does the escaping, `__name__`
+    last as the object form has it. Depends on the label sets and the
+    metric name alone (`promql/loaded.py` `derive` keeps it)."""
+    import pyarrow as pa
+
+    name = {"__name__": metric} if metric else {}
+    dumps = json.JSONEncoder(separators=(",", ":")).encode
+    return pa.array(['{"metric":%s,"values":[' % dumps({**lab, **name})
+                     for lab in labels], pa.large_string())
+
+
+def matrix_body(times: np.ndarray, vals: np.ndarray, fragments) -> bytes:
+    """The whole body of a `query_range` matrix answer: `vals`
+    [series, steps] at `times`, `fragments` from `metric_fragments`.
+    NaN samples are left out, and with them a series that has no other;
+    a value is spelled in arrow's shortest form that parses back to the
+    same float64 (`3`, `1e-7`, `-0`; Prometheus writes `3` too), ±Inf
+    as `+Inf` / `-Inf`."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    def lit(s):
+        return pa.scalar(s, pa.large_string())
+
+    def joined(parts, counts):
+        # `parts` joined by commas, `counts` of them at a time
+        offsets = np.zeros(len(counts) + 1, dtype=np.int32)
+        np.cumsum(counts, out=offsets[1:])
+        return pc.binary_join(
+            pa.ListArray.from_arrays(pa.array(offsets), parts), lit(","))
+
+    # widened first, so a float32 answer spells the float64 it equals
+    vals = np.asarray(vals, dtype=np.float64)
+    keep = ~np.isnan(vals)
+    counts = keep.sum(axis=1)
+    series = np.flatnonzero(counts)
+    if len(series) == 0:
+        return _MATRIX_HEAD + _MATRIX_TAIL
+    kept = vals[keep]
+    text = pc.cast(pa.array(kept), pa.large_string())
+    inf = np.isinf(kept)
+    if inf.any():
+        text = pc.if_else(pa.array(inf), pc.if_else(
+            pa.array(kept > 0), lit("+Inf"), lit("-Inf")), text)
+    # a step's `[<time>,"` is spelled once and taken by its kept samples
+    steps = pa.array(['[%r,"' % t for t in np.asarray(times).tolist()],
+                     pa.large_string())
+    samples = pc.binary_join_element_wise(
+        steps.take(pa.array(np.nonzero(keep)[1])), text, lit('"]'), lit(""))
+    entries = pc.binary_join_element_wise(
+        fragments.take(pa.array(series)), joined(samples, counts[series]),
+        lit("]}"), lit(""))
+    result = joined(entries, [len(entries)])[0]
+    return b"".join((_MATRIX_HEAD, memoryview(result.as_buffer()),
+                     _MATRIX_TAIL))
+
+
 # ---- MySQL wire fragments --------------------------------------------------
 # (moved here from servers/mysql.py so the resultset encoding can run on
 # encode-pool workers without importing the engine)

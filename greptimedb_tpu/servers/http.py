@@ -26,7 +26,12 @@ from greptimedb_tpu.fault import FaultError, Unavailable
 from greptimedb_tpu.fault.retry import Cancelled, DeadlineExceeded
 from greptimedb_tpu.query.engine import QueryContext, QueryEngine
 from greptimedb_tpu.query.result import QueryResult
-from greptimedb_tpu.utils.metrics import HTTP_REQUESTS, QUERY_DURATION, REGISTRY
+from greptimedb_tpu.utils.metrics import (
+    HTTP_REQUESTS,
+    PROMQL_ENCODED_RESPONSES,
+    QUERY_DURATION,
+    REGISTRY,
+)
 
 
 class HttpServer:
@@ -395,13 +400,14 @@ class _Handler(BaseHTTPRequestHandler):
             if path == "/v1/sql":
                 return self._handle_sql()
             if path == "/v1/promql":
-                return self._handle_promql_range(v1=True)
+                return self._promql_response(self._handle_promql_range)
             if path.startswith("/v1/prometheus/api/v1/") or path.startswith("/api/v1/"):
                 sub = path.split("/api/v1/", 1)[1]
                 if sub == "query_range":
-                    return self._handle_promql_range()
+                    return self._promql_response(self._handle_promql_range)
                 if sub == "query":
-                    return self._handle_promql_instant()
+                    return self._promql_response(
+                        self._handle_promql_instant)
                 if sub == "labels":
                     return self._handle_labels()
                 if sub.startswith("label/") and sub.endswith("/values"):
@@ -511,7 +517,24 @@ class _Handler(BaseHTTPRequestHandler):
 
     # ---- Prometheus API (reference http.rs:724-744) ------------------------
 
-    def _handle_promql_range(self, v1=False):
+    def _promql_response(self, handle):
+        """A PromQL request, every response to it counted once by how
+        its body was written: an answer by `_send_promql`, an error
+        that `_route` answers as `rows`."""
+        try:
+            handle()
+        except Exception:
+            PROMQL_ENCODED_RESPONSES.inc(path="rows")
+            raise
+
+    def _send_promql(self, code: int, payload):
+        # counted before the bytes go out: a scrape that follows the
+        # response has it. Only `_matrix_body` hands bytes over
+        PROMQL_ENCODED_RESPONSES.inc(
+            path="columnar" if isinstance(payload, bytes) else "rows")
+        self._send(code, payload)
+
+    def _handle_promql_range(self):
         from greptimedb_tpu.promql.engine import (
             PromqlEngine,
             SeriesMatrix,
@@ -521,13 +544,14 @@ class _Handler(BaseHTTPRequestHandler):
         params = self._form_or_query()
         query = params.get("query") or params.get("promql")
         if not query:
-            return self._send(400, _prom_err("missing query"))
+            return self._send_promql(400, _prom_err("missing query"))
         try:
             start = _prom_time(params["start"])
             end = _prom_time(params["end"])
             step = _prom_duration(params.get("step", "60"))
         except (KeyError, ValueError) as e:
-            return self._send(400, _prom_err(f"bad range params: {e}"))
+            return self._send_promql(
+                400, _prom_err(f"bad range params: {e}"))
         ctx = self._ctx(params)
         engine = PromqlEngine(self.query_engine)
         from greptimedb_tpu.utils import slow_query, tracing
@@ -540,17 +564,15 @@ class _Handler(BaseHTTPRequestHandler):
                 times, result = engine.eval_matrix(query, start, end, step,
                                                    ctx)
             if isinstance(result, SeriesMatrix):
-                payload = _matrix_json(times, result)
-            else:
-                with tracing.stage("readback"):
-                    vals = np.broadcast_to(d2h(result, dtype=np.float64),
-                                           times.shape)
-                with tracing.stage("encode"):
-                    payload = {"resultType": "matrix",
-                               "result": [{"metric": {},
-                                           "values": _values_json(times,
-                                                                  vals)}]}
-            self._send(200, {"status": "success", "data": payload})
+                return self._send_promql(200, _matrix_body(times, result))
+            with tracing.stage("readback"):
+                vals = np.broadcast_to(d2h(result, dtype=np.float64),
+                                       times.shape)
+            with tracing.stage("encode"):
+                payload = {"resultType": "matrix",
+                           "result": [{"metric": {},
+                                       "values": _values_json(times, vals)}]}
+            self._send_promql(200, {"status": "success", "data": payload})
 
     def _handle_promql_instant(self):
         from greptimedb_tpu.promql.engine import (
@@ -563,7 +585,7 @@ class _Handler(BaseHTTPRequestHandler):
         params = self._form_or_query()
         query = params.get("query")
         if not query:
-            return self._send(400, _prom_err("missing query"))
+            return self._send_promql(400, _prom_err("missing query"))
         t = _prom_time(params.get("time", str(time.time())))
         ctx = self._ctx(params)
         engine = PromqlEngine(self.query_engine)
@@ -590,7 +612,7 @@ class _Handler(BaseHTTPRequestHandler):
                     v = float(vals.reshape(-1)[-1])
                     payload = {"resultType": "scalar",
                                "value": [t, _fmt_float(v)]}
-            self._send(200, {"status": "success", "data": payload})
+            self._send_promql(200, {"status": "success", "data": payload})
 
     def _handle_labels(self):
         params = self._form_or_query()
@@ -823,23 +845,23 @@ def _records_json(r: QueryResult) -> dict:
     return records_json(r)
 
 
-def _matrix_json(times: np.ndarray, sm) -> dict:
-    from greptimedb_tpu.promql.engine import d2h
+def _matrix_body(times: np.ndarray, sm) -> bytes:
+    """The response to a range query whose answer is a `SeriesMatrix`,
+    written from its columns (`encode.matrix_body`): no Python object
+    is made for a sample."""
+    from greptimedb_tpu.promql.loaded import d2h, derive
+    from greptimedb_tpu.servers.encode import matrix_body, metric_fragments
     from greptimedb_tpu.utils import tracing
 
     # the evaluation's device work ends here: the readback waits for it
     with tracing.stage("readback"):
         vals = d2h(sm.values)
-    out = []
     with tracing.stage("encode", series=len(sm.labels)):
-        for i, lab in enumerate(sm.labels):
-            metric = dict(lab)
-            if sm.metric:
-                metric["__name__"] = sm.metric
-            series_vals = _values_json(times, vals[i])
-            if series_vals:
-                out.append({"metric": metric, "values": series_vals})
-    return {"resultType": "matrix", "result": out}
+        # the series' heads are kept beside the label sets they derive
+        # from, as an aggregation's group index is
+        heads, _ = derive(sm.labels, "metric_json", metric_fragments,
+                          sm.metric)
+        return matrix_body(times, vals, heads)
 
 
 def _values_json(times: np.ndarray, vals: np.ndarray) -> list:

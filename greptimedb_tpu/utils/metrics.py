@@ -606,6 +606,13 @@ PROMQL_EVAL_PROGRAMS = REGISTRY.counter(
     "range function; stepwise = a kernel at a time with eager operations "
     "between: samples without a complete grid, a subquery, another "
     "range function or operator)")
+PROMQL_ENCODED_RESPONSES = REGISTRY.counter(
+    "greptimedb_tpu_promql_encoded_responses_total",
+    "PromQL HTTP responses by how the body was written (columnar = a "
+    "query_range matrix answer, from the read-back [series, steps] array "
+    "to bytes in arrow's kernels with no Python object a sample; rows = "
+    "an instant query, a scalar-valued range answer or an error, a value "
+    "at a time through json.dumps)")
 DEVICE_HOT_SET_EVENTS = REGISTRY.counter(
     "greptimedb_tpu_device_hot_set_events_total",
     "HBM-resident columnar hot set events by kind (hit/miss/evict/pin — "

@@ -7,6 +7,7 @@ reference is `promql_board`'s numpy template, reached through the
 `prom_fleet` family as the benchmark reaches it (ISSUE 27).
 """
 
+import json
 import os
 import sys
 
@@ -23,7 +24,7 @@ from greptimedb_tpu.catalog.kv import FileKv, MemoryKv  # noqa: E402
 from greptimedb_tpu.datatypes import DictVector, RecordBatch  # noqa: E402
 from greptimedb_tpu.promql import engine as promql_engine  # noqa: E402
 from greptimedb_tpu.query.engine import QueryEngine  # noqa: E402
-from greptimedb_tpu.servers.http import _matrix_json  # noqa: E402
+from greptimedb_tpu.servers.http import _matrix_body  # noqa: E402
 from greptimedb_tpu.servers.prom_store import handle_remote_write  # noqa: E402
 from greptimedb_tpu.storage import metric_engine as me  # noqa: E402
 from greptimedb_tpu.storage.engine import EngineConfig, RegionEngine  # noqa: E402
@@ -223,7 +224,7 @@ def ask(qe, template, params, fleet):
     q = {k: v[0] for k, v in parse_qs(urlparse(path).query).items()}
     times, sm = promql_engine.PromqlEngine(qe).eval_matrix(
         q["query"], float(q["start"]), float(q["end"]), float(q["step"]))
-    return _matrix_json(times, sm)["result"]
+    return json.loads(_matrix_body(times, sm))["data"]["result"]
 
 
 @pytest.mark.parametrize("panel", PANELS, ids=lambda p: p["agg"] + "-by-"

@@ -19,7 +19,7 @@ from greptimedb_tpu.promql import engine as promql_engine
 from greptimedb_tpu.promql.engine import PromqlEngine, SeriesMatrix
 from greptimedb_tpu.promql.loaded import LabelSets, derive
 from greptimedb_tpu.query import QueryEngine
-from greptimedb_tpu.servers.http import _matrix_json
+from greptimedb_tpu.servers.http import _matrix_body
 from greptimedb_tpu.storage import RegionEngine
 from greptimedb_tpu.storage.engine import EngineConfig
 from greptimedb_tpu.utils import tracing
@@ -372,7 +372,7 @@ def test_what_is_kept_is_not_altered_downstream(db):
         assert out.num_series > 0, text
         # what the HTTP API and TQL make of it
         times = np.arange(*db.args[:2], STEP, dtype=float)
-        _matrix_json(np.append(times, db.args[1]), out)
+        _matrix_body(np.append(times, db.args[1]), out)
         db.prom.eval_range(text, *db.args)
         again = db.eval(q)
         assert again.labels is first.labels, text
