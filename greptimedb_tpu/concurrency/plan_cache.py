@@ -332,6 +332,8 @@ class PlanCache:
             if isinstance(node, lp.Limit):
                 return lp.Limit(rebuild(node.input), node.limit,
                                 node.offset)
+            if isinstance(node, lp.RangeCombine):
+                return lp.RangeCombine(rebuild(node.input), node.spec)
             raise ValueError(f"uncacheable node {type(node).__name__}")
 
         return rebuild(ent.plan)

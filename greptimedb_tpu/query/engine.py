@@ -894,10 +894,7 @@ class QueryEngine:
                 self, outer, dict(zip(base.names, base.columns)),
                 dict(zip(base.names, base.dtypes)),
                 alias=sel.table_alias or sel.table)
-        if rs.is_range_select(sel):
-            self._note_plan_cache_skip("range_select")
-            rplan = rs.plan_range_select(sel, info)
-            return rs.execute_range_select(self.executor, rplan)
+        is_range = rs.is_range_select(sel)
         # shape-keyed plan cache: repeated dashboard statements re-bind
         # a cached validated plan instead of re-planning; the entry also
         # memoizes a negative rollup-substitution probe (version-stamped
@@ -918,8 +915,8 @@ class QueryEngine:
             # sibling parameter bindings
             sub_note = {"memoizable": True}
             sub_stamp = None
-            if sel.group_by or any(has_aggregate(it.expr)
-                                   for it in sel.items):
+            if is_range or sel.group_by or any(has_aggregate(it.expr)
+                                               for it in sel.items):
                 # rollup substitution: eligible coarse-bucket aggregates
                 # are served from downsampled plane SSTs
                 # (maintenance/rollup.py); None = ineligible/uncovered,
@@ -936,14 +933,17 @@ class QueryEngine:
                     # not lend its fresher version to this negative
                     # outcome
                     sub_stamp = substitution_stamp()
-                    res = try_substitute(self, sel, info, ctx,
-                                         shape_note=sub_note)
+                    # (a RANGE statement is never substituted: roll-up
+                    # planes hold finalized buckets, not the primitives
+                    # a sliding window combines)
+                    res = None if is_range else try_substitute(
+                        self, sel, info, ctx, shape_note=sub_note)
                     if res is not None:
                         return res
                     if entry is not None and sub_note.get("memoizable"):
                         entry.mark_sub_ineligible(sub_stamp)
             if plan is None:
-                plan = plan_select(sel, info)
+                plan = self._plan_table_select(sel, info)
                 entry = self.concurrency.plan_cache.store(binding, sel,
                                                           info, plan)
                 if entry is not None and sub_note.get("memoizable"):
@@ -954,6 +954,17 @@ class QueryEngine:
         self.concurrency.fast_lane.note_plan_execution(sel, info, entry)
         with tracing.enclosing_stage("execute"):
             return self.executor.execute(plan)
+
+    @staticmethod
+    def _plan_table_select(sel: ast.Select, info: TableInfo):
+        """A single-table SELECT's logical plan; a RANGE ... ALIGN
+        statement's is its tumbling aggregate under a RangeCombine root
+        (query/range_select.py), cached, bound and executed as any."""
+        from greptimedb_tpu.query import range_select as rs
+
+        if rs.is_range_select(sel):
+            return rs.plan_range_select(sel, info)
+        return plan_select(sel, info)
 
     def _try_window_pushdown(self, sel: ast.Select, info, ctx):
         """Ship [filter, prune, window] PlanFragments when every window
@@ -1706,8 +1717,8 @@ class QueryEngine:
                         "  (outer select evaluates over the view result)")
             else:
                 info = self._table(stmt.inner.table, ctx)
-                plan = plan_select(stmt.inner, info)
-                text = lp.explain_plan(plan)
+                text = lp.explain_plan(
+                    self._plan_table_select(stmt.inner, info))
         else:
             text = f"{type(stmt.inner).__name__}"
         lines = text.split("\n")

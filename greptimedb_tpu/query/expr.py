@@ -891,7 +891,9 @@ def _eval_host_func(e: ast.FuncCall, ev, schema):
         interval, ts_expr = e.args[0], e.args[1]
         step = _interval_in_col_unit(interval, ts_expr, schema) if schema else _lit_interval(interval)
         ts = np.asarray(ev(ts_expr))
-        return ts // step * step
+        # the device twin's rule: an origin in the column's own unit
+        origin = int(_lit(e.args[2])) if len(e.args) > 2 else 0
+        return (ts - origin) // step * step + origin
     if name == "date_trunc":
         unit_lit, ts_expr = e.args[0], e.args[1]
         unit = str(_lit(unit_lit)).lower()

@@ -73,8 +73,22 @@ class Limit(LogicalPlan):
     offset: int = 0
 
 
+@dataclass
+class RangeCombine(LogicalPlan):
+    """Root of a lowered RANGE ... ALIGN statement: `input` is the
+    tumbling aggregate over (BY keys, ALIGN bucket) that yields the
+    primitives a group, `spec` the query/range_select.py RangePlan whose
+    sliding combine, FILL, projection and ORDER BY / LIMIT run over
+    them."""
+    input: LogicalPlan
+    spec: object
+
+
 def explain_plan(plan: LogicalPlan, indent: int = 0) -> str:
     pad = "  " * indent
+    if isinstance(plan, RangeCombine):
+        return (f"{pad}RangeCombine: {plan.spec.describe()}\n"
+                + explain_plan(plan.input, indent + 1))
     if isinstance(plan, Scan):
         return (f"{pad}Scan: {plan.table.db}.{plan.table.name} "
                 f"columns={plan.columns} ts_range={plan.ts_range}")

@@ -135,13 +135,18 @@ _PRIMITIVES = {
     "last": ("last",),
     "stddev": ("sum", "sumsq", "count"),
     "variance": ("sum", "sumsq", "count"),
+    # a lowered RANGE statement reads the moments apart (range_select.py)
+    "sumsq": ("sumsq",),
 }
 
 
 def _is_time_bucket(kexpr, ts_name: str) -> bool:
-    """date_bin / time_bucket of a constant interval over the time index."""
+    """date_bin / time_bucket of a constant interval over the time index
+    (with an origin it is a generic key: the bucket ids planned from the
+    statement's range count steps from 0)."""
     return (isinstance(kexpr, ast.FuncCall)
             and kexpr.name in ("date_bin", "time_bucket")
+            and len(kexpr.args) == 2
             and isinstance(kexpr.args[0], ast.Interval)
             and isinstance(kexpr.args[1], ast.Column)
             and kexpr.args[1].name == ts_name)
@@ -1560,7 +1565,18 @@ class PhysicalExecutor:
         self._tls.__dict__.pop("last_tier", None)
         self._tls.__dict__.pop("agg_scan_mode", None)
         self.last_partial_stats = None
-        res = self._execute(plan)
+        if isinstance(plan, lp.RangeCombine):
+            # a RANGE statement: its tumbling aggregate by whatever path
+            # an aggregate takes, then the sliding combine over its groups
+            from greptimedb_tpu.query import range_select as rs
+            from greptimedb_tpu.utils.metrics import RANGE_SELECT
+
+            res = rs.range_combine(self, plan.spec,
+                                   self._execute(plan.input))
+            self.last_path = (self.last_path or "empty") + "+range_combine"
+            RANGE_SELECT.inc(path=self.last_path)
+        else:
+            res = self._execute(plan)
         stats = self.last_partial_stats
         QUERY_TIER.inc(tier="cache" if stats and not stats["delta_rows"]
                        else self.last_tier)
@@ -4526,7 +4542,7 @@ def _finalize_agg(func: str, acc: dict, slot: Optional[int], present: np.ndarray
         s, c = get("sum"), get("count")
         with np.errstate(invalid="ignore", divide="ignore"):
             return np.where(c > 0, s / np.maximum(c, 1), np.nan)
-    if func in ("min", "max", "first", "last"):
+    if func in ("min", "max", "first", "last", "sumsq"):
         return get(func)
     if func in ("stddev", "variance"):
         s, ss, c = get("sum"), get("sumsq"), get("count")

@@ -6,34 +6,41 @@ rows with `T <= ts < T + range`, output points step every ALIGN interval,
 series are keyed by the BY columns (default: the table's primary-key
 tags). `RANGE` may exceed `ALIGN` (overlapping sliding windows).
 
-TPU-first design: instead of the reference's per-row hash-map of
-accumulators, each row is replicated across `S = ceil(range/align)`
-static slots — slot j assigns the row to window `T_j = align_slot(ts) -
-j*align` — and ONE masked segment reduction over the [N*S] replicated
-rows produces every window's primitives in a single fused device kernel
-(ops/segment.segment_agg). S, the bucket capacity, and the series
-capacity are rounded to powers of two so XLA compilations cache across
-query shapes.
+Every aggregate the grammar admits is made of primitives that combine
+across adjacent ALIGN buckets (sum, count, min, max, first / last by
+time, sum of squares). So a range statement is planned as
+
+1. the tumbling aggregate `GROUP BY <BY keys>, date_bin(ALIGN, ts)` over
+   the statement's WHERE, one primitive an output column — an ordinary
+   lp.Aggregate, executed by whatever path the executor gives any
+   GROUP BY (plan cache, literal operands, tier router, partial cache,
+   region fan-out, the memtable tail), under
+2. an lp.RangeCombine root: `range_combine` slides over each series'
+   buckets (a window of S = RANGE / ALIGN adjacent ones per distinct
+   RANGE), finalizes, applies FILL and hands the projection, ORDER BY
+   and LIMIT to the executor's post-processing.
+
+`range_combine` is numpy on the host over the observed groups the
+aggregate read back (thousands of cells, never rows); it keeps them
+sparse — sorted (series, bucket) codes, windows found by searchsorted,
+runs reduced with ufunc.reduceat — so a group space of any size
+answers, and only FILL builds the dense grid its semantics name.
 """
 
 from __future__ import annotations
 
-import functools
+import time
 from dataclasses import dataclass, field
 from typing import Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from greptimedb_tpu.catalog.catalog import TableInfo
-from greptimedb_tpu.ops.segment import segment_agg
 from greptimedb_tpu.query import logical as lp
 from greptimedb_tpu.query.expr import (
     PlanError,
     collect_aggregates,
     collect_columns,
-    eval_host,
     extract_ts_bounds,
     _interval_in_col_unit,
 )
@@ -48,12 +55,15 @@ class RangeAgg:
     #                         FuncCall may appear with different RANGEs
     range_steps: int        # window width, in align steps (>= 1)
     fill: Optional[object]  # None | 'null' | 'prev' | 'linear' | float
+    slot: Optional[int] = None  # its argument's place among the planes
 
 
 @dataclass
 class RangePlan:
+    """What `range_combine` runs over the lowered aggregate's result,
+    whose columns are the BY `keys`, the bucket, then one primitive
+    each in `prims` order ((op, argument slot) pairs)."""
     table: TableInfo
-    where: Optional[ast.Expr]
     align_step: int         # in ts-column units
     origin: int             # ALIGN TO, in ts-column units
     by: list[ast.Expr]
@@ -62,6 +72,22 @@ class RangePlan:
     order_keys: list[ast.OrderByItem] = field(default_factory=list)
     limit: Optional[int] = None
     offset: int = 0
+    keys: list[ast.Expr] = field(default_factory=list)  # BY, no constants
+    prims: list[tuple[str, Optional[int]]] = field(default_factory=list)
+
+    @property
+    def shift(self) -> int:
+        """Where window starts sit inside an ALIGN step: T = k * align +
+        shift (0 unless ALIGN TO names an origin off the step's grid)."""
+        return self.origin % self.align_step
+
+    def describe(self) -> str:
+        aggs = ", ".join(
+            f"{a.func} RANGE {a.range_steps}x"
+            + (f" FILL {a.fill}" if a.fill is not None else "")
+            for a in self.aggs)
+        return (f"align={self.align_step} origin={self.origin} "
+                f"by={len(self.keys)} keys aggs=[{aggs}]")
 
 
 _RANGE_FUNCS = {
@@ -78,9 +104,12 @@ def is_range_select(sel: ast.Select) -> bool:
     )
 
 
-def plan_range_select(sel: ast.Select, table: TableInfo) -> RangePlan:
+def plan_range_select(sel: ast.Select,
+                      table: TableInfo) -> lp.RangeCombine:
     """Validate + lower a RANGE select (reference plan_rewrite.rs
-    RangePlanRewriter)."""
+    RangePlanRewriter) to the tumbling aggregate under its RangeCombine
+    root. The Filter holds `sel.where` itself, so the plan cache re-binds
+    its literals as it does any statement's."""
     schema = table.schema
     ts_col = schema.time_index
     ts_expr = ast.Column(ts_col.name)
@@ -167,11 +196,75 @@ def plan_range_select(sel: ast.Select, table: TableInfo) -> RangePlan:
         raise PlanError(
             f"column(s) {sorted(bad)} must appear in the ALIGN BY clause")
 
-    return RangePlan(
-        table=table, where=sel.where, align_step=align_step, origin=origin,
+    rp = RangePlan(
+        table=table, align_step=align_step, origin=origin,
         by=by, aggs=aggs, items=items, order_keys=list(sel.order_by),
         limit=sel.limit, offset=sel.offset or 0,
     )
+    return lp.RangeCombine(_lower(rp, sel.where, sel.align), rp)
+
+
+def _lower(rp: RangePlan, where: Optional[ast.Expr],
+           align: ast.Interval) -> lp.LogicalPlan:
+    """The aggregate a range statement is made of: keys = the BY keys
+    and the ALIGN bucket, one AggSpec a primitive (`rows` always: which
+    windows hold rows). Fills in rp's column layout."""
+    from greptimedb_tpu.query.physical import _PRIMITIVES, _needs_host_agg
+
+    schema = rp.table.schema
+    ts_name = schema.time_index.name
+    # a constant key (`BY ()` parses to one) groups nothing
+    rp.keys = [b for b in rp.by if not isinstance(b, ast.Literal)]
+    keys = [(f"__range_by_{i}", b) for i, b in enumerate(rp.keys)]
+    # the executor plans date_bin(<interval>, <time index>) from the
+    # statement's range alone; an origin off the step's grid makes it a
+    # generic key
+    bucket_args = (align, ast.Column(ts_name)) \
+        + ((ast.Literal(rp.shift),) if rp.shift else ())
+    keys.append(("__range_bucket", ast.FuncCall("date_bin", bucket_args)))
+
+    args: list[ast.Expr] = []
+    specs: list[lp.AggSpec] = []
+
+    def want(op: str, slot: Optional[int]) -> None:
+        if (op, slot) in rp.prims:
+            return
+        arg = None if slot is None else args[slot]
+        call = ast.FuncCall("count", (ast.Star(),)) if arg is None \
+            else ast.FuncCall(f"__range_{op}", (arg,))
+        spec = lp.AggSpec(f"__range_{op}_{slot}", op, arg, call)
+        if _needs_host_agg(spec, schema):
+            raise PlanError(
+                "RANGE aggregates take numeric arguments")
+        rp.prims.append((op, slot))
+        specs.append(spec)
+
+    want("rows", None)
+    for a in rp.aggs:
+        if a.arg is not None:
+            if a.arg not in args:
+                args.append(a.arg)
+            a.slot = args.index(a.arg)
+        for op in _PRIMITIVES[a.func]:
+            want(op, None if op == "rows" else a.slot)
+
+    needed: set[str] = {ts_name}
+    collect_columns(where, needed)
+    for _, k in keys:
+        collect_columns(k, needed)
+    for e in args:
+        collect_columns(e, needed)
+    unknown = needed - set(schema.names)
+    if unknown:
+        raise PlanError(
+            f"unknown column(s) {sorted(unknown)} in table {rp.table.name}")
+    plan: lp.LogicalPlan = lp.Scan(
+        rp.table, [c for c in schema.names if c in needed],
+        extract_ts_bounds(where, ts_name, schema.time_index.dtype))
+    if where is not None:
+        plan = lp.Filter(plan, where)
+    plan = lp.Aggregate(plan, keys, specs)
+    return lp.Project(plan, keys + [(s.name, s.call) for s in specs])
 
 
 def _item_name(e: ast.Expr) -> str:
@@ -213,284 +306,193 @@ def _collect_nonagg_columns(e: ast.Expr, out: set) -> None:
                     _collect_nonagg_columns(x, out)
 
 
-def _pow2(n: int) -> int:
-    return 1 << max(int(n) - 1, 0).bit_length()
+# ---- the sliding combine -----------------------------------------------------
+
+#: how each primitive of adjacent buckets folds into a window's
+_ADD = ("rows", "count", "sum", "sumsq")
 
 
-def execute_range_select(executor, rp: RangePlan):
-    """Run a RangePlan through the executor's storage + device substrate."""
-    from greptimedb_tpu import config
-    from greptimedb_tpu.datatypes.vector import DictVector
-    from greptimedb_tpu.query.physical import (
-        BindContext,
-        _PRIMITIVES,
-        _closed_range,
-        _finalize_agg,
-        bind_expr,
-    )
-    from greptimedb_tpu.storage.index import extract_tag_predicates
-    from greptimedb_tpu.storage.merge_scan import merge_scans
+def range_combine(executor, rp: RangePlan, groups) -> "QueryResult":
+    """From the lowered aggregate's observed groups (a QueryResult:
+    BY keys, bucket start, primitives) to the statement's answer."""
     from greptimedb_tpu.utils import tracing
+    from greptimedb_tpu.utils.metrics import (
+        RANGE_SELECT_SECONDS,
+        RANGE_WINDOWS,
+    )
 
-    table = rp.table
-    schema = table.schema
-    ts_name = schema.time_index.name
-    ts_range = _closed_range(
-        extract_ts_bounds(rp.where, ts_name, schema.time_index.dtype))
-    tag_preds = extract_tag_predicates(rp.where, schema) or None
+    ts_key = ast.Column(rp.table.schema.time_index.name)
+    ranges = sorted({a.range_steps for a in rp.aggs})
+    t0 = time.perf_counter()
+    with tracing.stage("assemble", step="range_combine"), \
+            tracing.span("range_combine", groups=groups.num_rows,
+                         distinct_ranges=len(ranges),
+                         slots=ranges[-1]) as attrs:
+        env, series, window = _combine(rp, groups, ranges, ts_key)
+        observed = len(window)
+        t1 = time.perf_counter()
+        RANGE_SELECT_SECONDS.observe(t1 - t0, phase="combine")
+        env, nrows = _apply_fill(rp, env, series, window, ts_key)
+        RANGE_SELECT_SECONDS.observe(time.perf_counter() - t1, phase="fill")
+        attrs.update(series=int(series.max()) + 1 if observed else 0,
+                     buckets=int(window.max() - window.min()) + 1
+                     if observed else 0,
+                     filled=nrows - observed)
+    RANGE_WINDOWS.inc(float(observed), kind="observed")
+    RANGE_WINDOWS.inc(float(nrows - observed), kind="filled")
+    return executor._post_process(
+        env, None, None, lp.Project(None, rp.items),
+        lp.Sort(None, rp.order_keys) if rp.order_keys else None,
+        rp.limit, rp.offset, rp.table, nrows)
 
-    # projection pruning: only ts, WHERE, BY, and aggregate-arg columns
-    needed: set[str] = {ts_name}
-    collect_columns(rp.where, needed)
-    for b in rp.by:
-        collect_columns(b, needed)
-    for a in rp.aggs:
-        collect_columns(a.arg, needed)
-    proj_cols = [c for c in schema.names if c in needed]
 
-    with tracing.span("scan", table=table.name,
-                      regions=len(table.region_ids)):
-        if len(table.region_ids) == 1:
-            scan = executor.engine.scan(table.region_ids[0], ts_range,
-                                        proj_cols, tag_preds)
-        else:
-            scan = merge_scans([
-                executor.engine.scan(rid, ts_range, proj_cols, tag_preds)
-                for rid in table.region_ids
-            ])
-    project = lp.Project(None, rp.items)
-    sort = lp.Sort(None, rp.order_keys) if rp.order_keys else None
+def _combine(rp: RangePlan, groups, ranges: list, ts_key) -> tuple:
+    """(env of the observed windows, their series index, their window
+    index k: the window starts at k * align + shift). A window exists
+    where ANY aggregate's range holds a row; an aggregate whose own
+    range holds none there is NULL (count: 0)."""
+    from greptimedb_tpu.query.dist_agg import _factorize_with_null
+    from greptimedb_tpu.query.physical import _finalize_agg
 
-    def empty_result():
-        # zero windows: every projected expression still needs a binding
-        env0: dict = {ast.Column(ts_name): np.empty(0, dtype=np.int64)}
-        for b in rp.by:
-            env0[b] = np.empty(0, dtype=object)
-        for a in rp.aggs:
-            env0[a.key] = np.empty(0, dtype=np.float64)
-        return executor._post_process(env0, None, None, project, sort,
-                                      rp.limit, rp.offset, table, 0)
+    n, n_keys = groups.num_rows, len(rp.keys)
+    by_cols, bucket_col = groups.columns[:n_keys], groups.columns[n_keys]
+    if n == 0:
+        env = {ts_key: np.empty(0, dtype=np.int64)}
+        env.update({b: np.empty(0, dtype=object) for b in rp.keys})
+        env.update({a.key: np.empty(0, dtype=np.float64) for a in rp.aggs})
+        empty = np.empty(0, dtype=np.int64)
+        return env, empty, empty
 
-    if scan is None or scan.num_rows == 0:
-        return empty_result()
-
-    ctx = BindContext(schema, scan.tag_dicts)
-    bound_where = bind_expr(rp.where, ctx) if rp.where is not None else None
-    idx = executor._filtered_row_indices(scan, table, ctx, bound_where,
-                                         where_unbound=rp.where)
-    if len(idx) == 0:
-        return empty_result()
-
-    # host gather of surviving rows
-    host: dict[str, np.ndarray] = {}
-    for name, arr in scan.columns.items():
-        taken = arr[idx]
-        if name in scan.tag_dicts:
-            taken = DictVector(taken, scan.tag_dicts[name]).decode()
-        host[name] = taken
-    ts = host[ts_name].astype(np.int64)
-    n = len(ts)
-
-    # BY-key factorization -> one dense series code
-    by_values: list[np.ndarray] = []
-    by_codes = np.zeros(n, dtype=np.int64)
-    n_series = 1
-    for b in rp.by:
-        vals = np.asarray(eval_host(b, host, schema))
-        if vals.ndim == 0:
-            vals = np.broadcast_to(vals, (n,))
-        uniq, codes = np.unique(vals, return_inverse=True)
-        by_values.append(uniq)
-        by_codes = by_codes * len(uniq) + codes
-        n_series *= len(uniq)
-    # compact the combined code (the cross product may have holes)
-    series_uniq, series_code = (np.unique(by_codes, return_inverse=True)
-                                if rp.by else
-                                (np.zeros(1, dtype=np.int64),
-                                 np.zeros(n, dtype=np.int64)))
-
-    align, origin = rp.align_step, rp.origin
-    base_slot = (ts - origin) // align
-    n_slots = max(a.range_steps for a in rp.aggs)
-    # the grid extends n_slots-1 below the earliest data slot: a window
-    # starting before the first row still covers it when range > align
-    # (reference emits those leading partial windows)
-    slot_lo = int(base_slot.min()) - (n_slots - 1)
-    slot_span = int(base_slot.max()) - slot_lo + 1
-    cap_buckets = _pow2(slot_span)
-    cap_series = _pow2(len(series_uniq))
-    num_groups = cap_series * cap_buckets
-    if num_groups > config.dense_groups_max() * 4:
-        raise PlanError(
-            f"RANGE query group space {num_groups} too large; narrow the "
-            "time window or coarsen ALIGN")
-
-    # aggregate value planes
-    arg_exprs: list[Optional[ast.Expr]] = []
-    slots: list[Optional[int]] = []
-    for a in rp.aggs:
-        if a.arg is None:
-            slots.append(None)
-            continue
-        if a.arg not in arg_exprs:
-            arg_exprs.append(a.arg)
-        slots.append(arg_exprs.index(a.arg))
-    if arg_exprs:
-        planes = [
-            np.asarray(eval_host(e, host, schema), dtype=np.float64)
-            for e in arg_exprs
-        ]
-        vals = np.stack([np.broadcast_to(p, (n,)) for p in planes], axis=1)
+    # one dense series code a group, by VALUE of its BY keys
+    series, card = np.zeros(n, dtype=np.int64), 1
+    for col in by_cols:
+        uniq, codes = _factorize_with_null(np.asarray(col))
+        if card * len(uniq) >= 1 << 62:
+            # keep the composite inside int64: compact before mixing in
+            series, card = np.unique(series, return_inverse=True)[1], n
+        series, card = series * len(uniq) + codes, card * len(uniq)
+    _, rep, series = np.unique(series, return_index=True,
+                               return_inverse=True)
+    bucket = (np.asarray(bucket_col, dtype=np.int64)
+              - rp.shift) // rp.align_step
+    b_lo = int(bucket.min())
+    s_max = ranges[-1]
+    # (series, bucket) as one sorted code; the room of s_max - 1 below a
+    # series' first bucket holds its leading partial windows and keeps a
+    # window's reach out of the next series
+    span = int(bucket.max()) - b_lo + s_max
+    code = series * span + (bucket - b_lo + s_max - 1)
+    if np.any(code[1:] <= code[:-1]):
+        order = np.argsort(code, kind="stable")
+        code = code[order]
     else:
-        vals = np.zeros((n, 1), dtype=np.float64)
+        order = None
+    # a window is observed where one of its s_max buckets holds a group:
+    # each group ends a run of up to s_max windows, cut where the group
+    # before it already covers (W windows, no [groups, s_max] grid)
+    lens = np.minimum(np.diff(code, prepend=code[0] - s_max), s_max)
+    run_end = np.cumsum(lens)
+    wcode = np.repeat(code - run_end, lens) + np.arange(1, run_end[-1] + 1)
+    planes = {}
+    for (op, slot), col in zip(rp.prims, groups.columns[n_keys + 1:]):
+        col = np.asarray(col, dtype=np.float64)
+        planes[(op, slot)] = col if order is None else col[order]
 
-    ops: set = {"rows"}
+    accs = {r: _slide(planes, code, wcode, r) for r in ranges}
+    w_series = wcode // span
+    window = wcode % span + (b_lo - s_max + 1)
+    env = {ts_key: window * rp.align_step + rp.shift}
+    for b, col in zip(rp.keys, by_cols):
+        env[b] = np.asarray(col)[rep][w_series]
+    everything = np.arange(len(wcode))
     for a in rp.aggs:
-        ops.update(_PRIMITIVES[a.func])
-    ranges = tuple(sorted({a.range_steps for a in rp.aggs}))
-    need_ts = bool({"first", "last"} & ops)
-
-    with tracing.span("range_agg", rows=n, slots=n_slots,
-                      groups=num_groups):
-        accs = _range_kernel(
-            jnp.asarray(ts), jnp.asarray(series_code.astype(np.int32)),
-            jnp.asarray(vals), jnp.asarray(base_slot - slot_lo),
-            align=align, n_slots=n_slots, cap_buckets=cap_buckets,
-            num_groups=num_groups, ranges=ranges,
-            ops=tuple(sorted(ops)), need_ts=need_ts,
-        )
-    accs = {r: {k: np.asarray(v) for k, v in acc.items()}
-            for r, acc in accs.items()}
-
-    # windows observed by ANY aggregate's range
-    present_mask = np.zeros(num_groups, dtype=bool)
-    for r in ranges:
-        rows_r = accs[r]["rows"]
-        rows_r = rows_r[:, 0] if rows_r.ndim == 2 else rows_r
-        present_mask |= rows_r > 0
-    present = np.flatnonzero(present_mask)
-
-    env: dict = {}
-    series_idx = present // cap_buckets
-    bucket_idx = present % cap_buckets
-    align_ts = (bucket_idx + slot_lo) * align + origin
-    env[ast.Column(ts_name)] = align_ts
-    # decode BY values for the present windows
-    gcodes = series_uniq[series_idx] if rp.by else series_idx
-    for b, uniq in zip(reversed(rp.by), reversed(by_values)):
-        env[b] = uniq[gcodes % len(uniq)]
-        gcodes = gcodes // len(uniq)
-    for a, slot in zip(rp.aggs, slots):
-        env[a.key] = _finalize_agg(a.func, accs[a.range_steps], slot,
-                                    present)
-
-    nrows = len(present)
-    env, nrows = _apply_fill(rp, env, series_idx, bucket_idx, align_ts,
-                             slot_lo, align, origin, ts_name, nrows)
-    return executor._post_process(env, None, None, project, sort, rp.limit,
-                                  rp.offset, table, nrows)
+        acc = {op: plane for (op, slot), plane in accs[a.range_steps].items()
+               if slot == a.slot or op == "rows"}
+        env[a.key] = _finalize_agg(a.func, acc, None, everything)
+    return env, w_series, window
 
 
-def _range_kernel(ts, series_code, vals, rel_slot, *, align, n_slots,
-                  cap_buckets, num_groups, ranges, ops, need_ts):
-    """One fused device reduction over slot-replicated rows. Returns
-    {range_steps: {op: [G(,F)]}}."""
-    return _range_kernel_jit(ts, series_code, vals, rel_slot, align,
-                             n_slots, cap_buckets, num_groups, ranges,
-                             ops, need_ts)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnums=(4, 5, 6, 7, 8, 9, 10),
-)
-def _range_kernel_jit(ts, series_code, vals, rel_slot, align, n_slots,
-                      cap_buckets, num_groups, ranges, ops, need_ts):
-    n, f = vals.shape
-    # replicate rows across slots: slot j -> window starting j*align earlier
-    j = jnp.arange(n_slots, dtype=rel_slot.dtype)[:, None]       # [S, 1]
-    cand = rel_slot[None, :] - j                                  # [S, N]
-    in_grid = (cand >= 0) & (cand < cap_buckets)
-    gid = (series_code.astype(jnp.int64)[None, :] * cap_buckets
-           + jnp.clip(cand, 0, cap_buckets - 1))                  # [S, N]
-    gid_flat = gid.reshape(-1).astype(jnp.int32)
-    vals_rep = jnp.broadcast_to(vals[None], (n_slots, n, f)).reshape(-1, f)
-    ts_rep = (jnp.broadcast_to(ts[None], (n_slots, n)).reshape(-1)
-              if need_ts else None)
+def _slide(planes: dict, code: np.ndarray, wcode: np.ndarray,
+           steps: int) -> dict:
+    """Every primitive plane of the windows `wcode`, each the fold of
+    the groups whose code lies in [wcode, wcode + steps): a run of the
+    sorted groups, reduced with ufunc.reduceat."""
+    lo = np.searchsorted(code, wcode, side="left")
+    hi = np.searchsorted(code, wcode + steps, side="left")
+    held = hi > lo
+    # reduceat folds [i0:i1), [i1:i2), ...: (lo, hi) pairs interleaved,
+    # the fold of each pair read at the even places (an empty pair reads
+    # one element there: masked below); one pad row keeps hi in range
+    pairs = np.empty(2 * len(wcode), dtype=np.int64)
+    pairs[0::2], pairs[1::2] = lo, hi
+    first, last = np.minimum(lo, len(code) - 1), np.maximum(hi - 1, 0)
     out = {}
-    for r in ranges:
-        # row in window iff its slot distance j < range_steps
-        valid = (in_grid & (j < r)).reshape(-1)
-        out[r] = segment_agg(vals_rep, gid_flat, valid, num_groups,
-                             ops=ops, ts=ts_rep)
+    for (op, slot), plane in planes.items():
+        if op in _ADD:
+            # a NULL sum (no valid value in the bucket) adds nothing
+            padded = np.append(np.nan_to_num(plane, nan=0.0), 0.0)
+            folded = np.where(held, np.add.reduceat(padded, pairs)[0::2], 0.0)
+        elif op in ("min", "max"):
+            fold = np.fmin if op == "min" else np.fmax
+            padded = np.append(plane, np.nan)
+            folded = np.where(held, fold.reduceat(padded, pairs)[0::2],
+                              np.nan)
+        else:  # first / last: buckets are in time order
+            folded = np.where(held, plane[first if op == "first" else last],
+                              np.nan)
+        out[(op, slot)] = folded
     return out
 
 
-def _apply_fill(rp, env, series_idx, bucket_idx, align_ts, slot_lo, align,
-                origin, ts_name, nrows):
-    """FILL NULL/PREV/LINEAR/<const> densify the per-series time grid
-    between the globally observed first and last windows
-    (reference range_select FILL, plan.rs RangeFn::fill)."""
+def _apply_fill(rp: RangePlan, env: dict, series: np.ndarray,
+                window: np.ndarray, ts_key) -> tuple:
+    """FILL NULL/PREV/LINEAR/<const> densify each series' time grid
+    between the globally first and last observed windows (reference
+    range_select FILL, plan.rs RangeFn::fill). Returns (env, rows)."""
+    nrows = len(window)
     if not any(a.fill is not None for a in rp.aggs) or nrows == 0:
         return env, nrows
-    b_lo, b_hi = int(bucket_idx.min()), int(bucket_idx.max())
-    span = b_hi - b_lo + 1
-    series = np.unique(series_idx)
-    dense_n = len(series) * span
-    # position of each present window in the dense grid
-    s_pos = np.searchsorted(series, series_idx)
-    pos = s_pos * span + (bucket_idx - b_lo)
-    out_env: dict = {}
-    dense_buckets = np.tile(np.arange(b_lo, b_hi + 1), len(series))
-    new_align_ts = (dense_buckets + slot_lo) * align + origin
-    for key, arr in env.items():
-        if arr is align_ts:
-            out_env[key] = new_align_ts
-            continue
-        if key in rp.by:
-            continue  # densified from the series blocks below
-        if np.issubdtype(np.asarray(arr).dtype, np.number):
-            dense = np.full(dense_n, np.nan)
-        else:
-            dense = np.empty(dense_n, dtype=object)
-        dense[pos] = arr
-        out_env[key] = dense
-    # BY columns must be total on the dense grid: each series block gets
-    # its decoded value
-    for b in rp.by:
-        arr = env[b]
-        per_series = {}
-        for sp, v in zip(s_pos, arr):
-            per_series.setdefault(sp, v)
-        col = np.empty(dense_n, dtype=object)
-        for k in range(len(series)):
-            col[k * span:(k + 1) * span] = per_series.get(k)
-        out_env[b] = col
-    # per-aggregate fill policies
+    k_lo = int(window.min())
+    span = int(window.max()) - k_lo + 1
+    n_series = int(series.max()) + 1
+    dense_n = n_series * span
+    pos = series * span + (window - k_lo)
     have = np.zeros(dense_n, dtype=bool)
     have[pos] = True
+    at = np.arange(dense_n)
+    start = at // span * span
+    # the nearest observed window at or before / at or after each place,
+    # inside its own series
+    prev = np.maximum.accumulate(np.where(have, at, -1))
+    has_prev = prev >= start
+    nxt = np.minimum.accumulate(np.where(have, at, dense_n)[::-1])[::-1]
+    has_next = nxt < start + span
+    prev, nxt = np.maximum(prev, 0), np.minimum(nxt, dense_n - 1)
+
+    out: dict = {ts_key: (at % span + k_lo) * rp.align_step + rp.shift}
+    for b in rp.keys:
+        per_series = np.empty(n_series, dtype=env[b].dtype)
+        per_series[series] = env[b]
+        out[b] = np.repeat(per_series, span)
     for a in rp.aggs:
-        arr = out_env[a.key]
-        if a.fill in (None, "null"):
-            continue
+        arr = np.full(dense_n, np.nan)
+        arr[pos] = env[a.key]
         if isinstance(a.fill, float):
             arr = np.where(have, arr, a.fill)
         elif a.fill == "prev":
-            arr = arr.copy()
-            for k in range(len(series)):
-                seg = arr[k * span:(k + 1) * span]
-                for i in range(1, span):
-                    if not have[k * span + i]:
-                        seg[i] = seg[i - 1]
+            arr = np.where(have | ~has_prev, arr, arr[prev])
         elif a.fill == "linear":
-            arr = arr.copy()
-            for k in range(len(series)):
-                seg = arr[k * span:(k + 1) * span]
-                hs = have[k * span:(k + 1) * span]
-                xs = np.flatnonzero(hs)
-                if len(xs) >= 2:
-                    miss = np.flatnonzero(~hs)
-                    seg[miss] = np.interp(miss, xs,
-                                          seg[xs].astype(np.float64))
-        out_env[a.key] = arr
-    return out_env, dense_n
+            # between two observed windows: the line through them;
+            # outside them the nearest one's value; a series with one
+            # observed window has no line
+            both = has_prev & has_next
+            with np.errstate(invalid="ignore", divide="ignore"):
+                line = arr[prev] + (arr[nxt] - arr[prev]) \
+                    * ((at - prev) / np.maximum(nxt - prev, 1))
+            two = np.repeat(have.reshape(n_series, span).sum(axis=1) >= 2,
+                            span)
+            filled = np.where(both, line,
+                              np.where(has_prev, arr[prev], arr[nxt]))
+            arr = np.where(have | ~two, arr, filled)
+        out[a.key] = arr
+    return out, dense_n

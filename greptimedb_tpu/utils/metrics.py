@@ -772,7 +772,7 @@ PLAN_CACHE_EVENTS = REGISTRY.sharded_counter(
     "invalidate — invalidations come from DDL, schema drift, and "
     "rollup-substitution state changes; skip events carry a reason "
     "label naming why a statement never reached the cache: join/cte/"
-    "subquery/range_select/window)")
+    "subquery/window)")
 ADMISSION_EVENTS = REGISTRY.sharded_counter(
     "greptimedb_tpu_admission_events_total",
     "Admission control decisions by kind (admit/queue/reject_full/"
@@ -958,6 +958,20 @@ REGION_COMBINE_SECONDS = REGISTRY.histogram(
     "greptimedb_tpu_region_combine_seconds",
     "Host wall time of combining all regions' value-keyed partials "
     "into one aggregate (combine_partials over the union of group keys)")
+RANGE_SELECT = REGISTRY.counter(
+    "greptimedb_tpu_range_select_total",
+    "RANGE ... ALIGN statements answered, by the execution path of the "
+    "lowered aggregate plus +range_combine (query/range_select.py)")
+RANGE_SELECT_SECONDS = REGISTRY.histogram(
+    "greptimedb_tpu_range_select_seconds",
+    "Host wall time of a RANGE statement's steps after its lowered "
+    "aggregate, by phase: combine (series and bucket indices, the "
+    "sliding combine of adjacent ALIGN buckets per RANGE, finalize) and "
+    "fill (the dense grid and the FILL policies)")
+RANGE_WINDOWS = REGISTRY.counter(
+    "greptimedb_tpu_range_windows_total",
+    "Output points of RANGE statements, by kind: observed (the window "
+    "held rows) or filled (FILL produced it)")
 EXPIRED_SSTS = REGISTRY.counter(
     "greptimedb_tpu_maintenance_expired_ssts_total",
     "SSTs dropped whole by retention (TTL) expiry")

@@ -89,7 +89,7 @@ class SlowQuery:
     execution_path: Optional[str]
     started_at: float    # epoch seconds
     #: why the statement never reached the plan cache (join/cte/
-    #: subquery/range_select/window) — uncacheable dashboard queries
+    #: subquery/window) — uncacheable dashboard queries
     #: show up here instead of just being slow
     plan_cache_skip: Optional[str] = None
     #: how the deadline plane ended this statement, if it did
