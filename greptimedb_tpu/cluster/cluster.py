@@ -388,10 +388,11 @@ class RegionRouter:
                     "and route refresh", e2) from e2
 
     def scan(self, region_id: int, ts_range=None, projection=None,
-             tag_predicates=None, seq_min=None):
+             tag_predicates=None, seq_min=None, full_key=True):
         def op(eng):
             call = lambda e: e.scan(region_id, ts_range, projection,  # noqa: E731
-                                    tag_predicates, seq_min=seq_min)
+                                    tag_predicates, seq_min=seq_min,
+                                    full_key=full_key)
             if hasattr(eng, "execute_fragment") and _HedgePlane.enabled():
                 # wire-mode region read: the same hedge plane as
                 # fragment pushdown — a straggling scan races a backup
@@ -400,7 +401,7 @@ class RegionRouter:
         return self._with_failover(region_id, op)
 
     def scan_stream(self, region_id: int, ts_range=None, projection=None,
-                    tag_predicates=None):
+                    tag_predicates=None, full_key=True):
         # degradation covers stream CONSTRUCTION only: chunks read
         # lazily after return cannot be replayed on a refreshed route
         # without duplicating data (they lean on the objectstore seam's
@@ -408,7 +409,7 @@ class RegionRouter:
         return self._with_failover(
             region_id,
             lambda eng: eng.scan_stream(region_id, ts_range, projection,
-                                        tag_predicates))
+                                        tag_predicates, full_key=full_key))
 
     def _local_executor_for(self, eng):
         """Per-engine pushdown executor cache (holds device caches; the

@@ -145,7 +145,8 @@ def _lastpoint_prescan(executor, region_id: int, tag: str, shim,
         collect_columns(a, needed)
     proj = [c for c in schema.names if c in needed]
     try:
-        return eng.scan_last(region_id, tag, proj)
+        return eng.scan_last(region_id, tag, proj,
+                             full_key=not shim.append_mode)
     except Exception:  # noqa: BLE001 — pruning is an optimization only
         return None
 
@@ -280,11 +281,13 @@ def _region_host_columns_inner(executor, region_id, where, ts_range, needed,
         # (same dedup/filter tail below — scan_last's contract is that
         # the subset contains every LWW winner)
         scan = prescan
-    elif seq_min is not None:
-        scan = executor.engine.scan(region_id, ts_range, proj, tag_preds,
-                                    seq_min=seq_min)
     else:
-        scan = executor.engine.scan(region_id, ts_range, proj, tag_preds)
+        # the whole primary key rides along only where the rows will be
+        # merged by it (Region.scan): the fragment carries the table's
+        # append_mode, as the dedup below reads it
+        scan = executor.engine.scan(region_id, ts_range, proj, tag_preds,
+                                    seq_min=seq_min,
+                                    full_key=not append_mode)
     if stats_out is not None:
         stats_out["rows"] = 0 if scan is None else int(scan.num_rows)
         if scan is None or scan.num_rows == 0:

@@ -1651,6 +1651,9 @@ class PhysicalExecutor:
         from greptimedb_tpu.storage.index import extract_tag_predicates
 
         tag_preds = extract_tag_predicates(where, table.schema) or None
+        # the whole primary key rides along with a scan only where its
+        # rows are merged by it (Region.scan): what `_dedups` reads
+        full_key = not table.append_mode
 
         def run(ts_range):
             # one scan = one snapshot; a snapshot whose files died
@@ -1684,10 +1687,13 @@ class PhysicalExecutor:
             lp_tag = self._lastpoint_tag(table, where, agg, ts_range)
             if (lp_tag is not None and len(table.region_ids) == 1
                     and hasattr(self.engine, "scan_last")):
+                io0 = scan_io_counters()
                 with tracing.stage("scan", table=table.name, regions=1,
-                                   lastpoint=True):
+                                   lastpoint=True) as scan_attrs:
                     pruned = self.engine.scan_last(
-                        table.region_ids[0], lp_tag, scan_node.columns)
+                        table.region_ids[0], lp_tag, scan_node.columns,
+                        full_key=full_key)
+                    scan_attrs.update(scan_io_since(io0))
                 if pruned is not None:
                     with tracing.span("aggregate", rows=pruned.num_rows):
                         res = self._execute_agg(
@@ -1715,7 +1721,8 @@ class PhysicalExecutor:
             if agg is not None and len(table.region_ids) > 1:
                 res = self._try_region_fanout(
                     regions, table, where, agg, having, project, sort,
-                    limit, offset, ts_range, scan_node, tag_preds)
+                    limit, offset, ts_range, scan_node, tag_preds,
+                    full_key)
                 if res is not None:
                     return res
 
@@ -1736,7 +1743,7 @@ class PhysicalExecutor:
                         >= config.stream_threshold_rows():
                     stream = self.engine.scan_stream(
                         table.region_ids[0], ts_range, scan_node.columns,
-                        tag_preds)
+                        tag_preds, full_key=full_key)
                 if stream is not None:
                     if stream.est_rows >= config.stream_threshold_rows():
                         tier = self.tier_for(agg, stream.est_rows,
@@ -1762,7 +1769,8 @@ class PhysicalExecutor:
                                regions=len(table.region_ids)) as scan_attrs:
                 if len(table.region_ids) == 1:
                     scan = self.engine.scan(table.region_ids[0], ts_range,
-                                            scan_node.columns, tag_preds)
+                                            scan_node.columns, tag_preds,
+                                            full_key=full_key)
                 else:
                     # what cannot be split into per-region partials
                     # (raw rows, order statistics) gathers the scans of
@@ -1773,7 +1781,8 @@ class PhysicalExecutor:
                     scan = merge_scans(
                         [
                             self.engine.scan(rid, ts_range,
-                                             scan_node.columns, tag_preds)
+                                             scan_node.columns, tag_preds,
+                                             full_key=full_key)
                             for _i, rid in regions
                         ]
                     )
@@ -1846,7 +1855,8 @@ class PhysicalExecutor:
 
     def _try_region_fanout(self, regions, table, where, agg, having,
                            project, sort, limit, offset, ts_range,
-                           scan_node, tag_preds) -> Optional[QueryResult]:
+                           scan_node, tag_preds,
+                           full_key) -> Optional[QueryResult]:
         """An aggregate over a table of several regions: every matching
         region takes its OWN scan — a plan with parts, its region id and
         data version — through the per-part route (`_scan_partials`) on
@@ -1874,7 +1884,7 @@ class PhysicalExecutor:
         def one(region):
             return self._region_partials(region, table, where, agg,
                                          ts_range, scan_node, tag_preds,
-                                         lp_tag)
+                                         lp_tag, full_key)
 
         t0 = time.perf_counter()
         with tracing.span("region_fanout", table=table.name,
@@ -1924,7 +1934,8 @@ class PhysicalExecutor:
                                            spec_slot)
 
     def _region_partials(self, region, table, where, agg, ts_range,
-                         scan_node, tag_preds, lp_tag) -> "_RegionOut":
+                         scan_node, tag_preds, lp_tag,
+                         full_key) -> "_RegionOut":
         """One region's scan to its partials, on the region's chip."""
         from greptimedb_tpu.utils.metrics import REGION_PARTIAL
 
@@ -1941,16 +1952,20 @@ class PhysicalExecutor:
                 if lp_tag is not None:
                     # newest-first pruned scan, as a one-region table's
                     # lastpoint takes (Region.scan_last)
+                    io0 = scan_io_counters()
                     with tracing.stage("scan", table=table.name, regions=1,
-                                       lastpoint=True):
+                                       lastpoint=True) as scan_attrs:
                         scan = self.engine.scan_last(rid, lp_tag,
-                                                     scan_node.columns)
+                                                     scan_node.columns,
+                                                     full_key=full_key)
+                        scan_attrs.update(scan_io_since(io0))
                 if scan is None:
                     io0 = scan_io_counters()
                     with tracing.stage("scan", table=table.name,
                                        regions=1) as scan_attrs:
                         scan = self.engine.scan(rid, ts_range,
-                                                scan_node.columns, tag_preds)
+                                                scan_node.columns, tag_preds,
+                                                full_key=full_key)
                         scan_attrs["rows"] = 0 if scan is None \
                             else scan.num_rows
                         scan_attrs.update(scan_io_since(io0))
@@ -2802,7 +2817,16 @@ class PhysicalExecutor:
         (tags, ts) sub-run. Memtable rows are unsorted and are included
         wholesale. DELETE tombstones void the argument (the newest row
         may be a tombstone, making an interior row the answer) — any
-        tombstone in the scan disables the path."""
+        tombstone in the scan disables the path.
+
+        An append-mode table's scan holds the tags the statement names,
+        not the whole key (Region.scan `full_key`): the group keys are
+        among them. Runs are then cut where a held tag changes or the
+        time index falls: several series of one group may share a run,
+        but inside it time never falls, so its first and last rows still
+        hold the run's first and last instants, and a group's are among
+        its runs'. (Rows of one group that tie on the instant have no
+        defined winner without last-write-wins, on any path.)"""
         offsets = scan.sorted_part_offsets
         if len(offsets) < 2 or offsets[-1] == 0:
             return None
@@ -2815,6 +2839,11 @@ class PhysicalExecutor:
             return None
         if not all(k.kind == "tag" for k in keys):
             return None
+        held = [c.name for c in table.schema.tag_columns
+                if c.name in scan.tag_dicts]
+        whole_key = len(held) == len(table.schema.tag_columns)
+        if not whole_key and self._dedups(scan, table):
+            return None  # a last-write-wins merge reads the whole key
         cached = getattr(scan, "_boundary_fl_cache", None)
         if cached is not None:
             return cached if cached is not False else None
@@ -2829,12 +2858,14 @@ class PhysicalExecutor:
         # row i-1, or i is a segment seam (sortedness restarts there)
         new_run = np.zeros(send, dtype=bool)
         new_run[0] = True
-        for c in table.schema.tag_columns:
-            col = scan.columns[c.name]
+        for name in held:
+            col = scan.columns[name]
             new_run[1:] |= col[1:send] != col[: send - 1]
         seams = np.asarray(offsets[1:-1], dtype=np.int64)
         new_run[seams[seams < send]] = True
         ts = scan.columns[table.schema.time_index.name]
+        if not whole_key:
+            new_run[1:] |= ts[1:send] < ts[: send - 1]
         new_sub = new_run.copy()
         new_sub[1:] |= ts[1:send] != ts[: send - 1]
         run_start = np.flatnonzero(new_run)

@@ -312,7 +312,8 @@ def run_vmapped(executor, sel: ast.Select, info, pspecs,
     # a superset of every member's decoded parts.
     scan = executor.engine.scan(table.region_ids[0],
                                 ph._closed_range(union_range),
-                                scan_node.columns, None)
+                                scan_node.columns, None,
+                                full_key=not table.append_mode)
     if scan is None or scan.num_rows == 0:
         raise VmapIneligible("empty scan: serial path settles it")
     if table.append_mode and \
@@ -685,7 +686,8 @@ def _region_partial_inner(executor, region_id, vm, schema, append_mode,
     # the fragment's ts_range is the UNION of member windows: index-
     # pruned like the serial per-member pushdown scan; rows outside a
     # member's own window are masked by its ts parameters below
-    scan = eng.scan(region_id, ph._closed_range(ts_range), proj, None)
+    scan = eng.scan(region_id, ph._closed_range(ts_range), proj, None,
+                    full_key=not append_mode)
     if scan is None or scan.num_rows == 0:
         return {"members": [None] * m}
     n = scan.num_rows

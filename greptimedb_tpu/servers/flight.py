@@ -412,7 +412,8 @@ class FlightServer(fl.FlightServerBase):
                             node=local_node(), op="region_scan")
                 scan = self.engine.scan(
                     region_id, ts_range=ts_range, projection=projection,
-                    tag_predicates=preds, seq_min=req.get("seq_min"))
+                    tag_predicates=preds, seq_min=req.get("seq_min"),
+                    full_key=bool(req.get("full_key", True)))
                 # scan stats ride the span: rows served, SST pruning,
                 # host scan-cache reuse (reference RecordBatchMetrics
                 # carries the same per-stage counters)
@@ -877,10 +878,15 @@ class RemoteRegionEngine:
     # -- read ----------------------------------------------------------------
 
     def scan(self, region_id: int, ts_range=None, projection=None,
-             tag_predicates=None, seq_min=None) -> Optional[ScanData]:
+             tag_predicates=None, seq_min=None,
+             full_key=True) -> Optional[ScanData]:
         from greptimedb_tpu.utils import tracing
 
         spec = {"region_id": region_id}
+        if not full_key:
+            # the caller's table is append-mode (Region.scan); a peer
+            # that predates the key sends every tag, as before
+            spec["full_key"] = False
         if seq_min is not None:
             spec["seq_min"] = int(seq_min)
         if ts_range is not None:
@@ -973,7 +979,7 @@ class RemoteRegionEngine:
         return json.loads(res[0].body.to_pybytes().decode())
 
     def scan_stream(self, region_id: int, ts_range=None, projection=None,
-                    tag_predicates=None):
+                    tag_predicates=None, full_key=True):
         # remote streaming scan not implemented yet: fall back to the
         # materialized wire scan (executor handles None)
         return None

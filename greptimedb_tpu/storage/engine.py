@@ -381,19 +381,27 @@ class RegionEngine:
         projection: Optional[Sequence[str]] = None,
         tag_predicates: Optional[dict[str, set]] = None,
         seq_min: Optional[int] = None,
+        full_key: bool = True,
     ) -> Optional[ScanData]:
+        """`full_key`: whether every tag column rides along with the
+        projection (Region.scan). False only from a caller that holds
+        the table and read `append_mode` off it: nothing will merge
+        these rows by their primary key."""
         return self.region(region_id).scan(ts_range, projection,
-                                           tag_predicates, seq_min=seq_min)
+                                           tag_predicates, seq_min=seq_min,
+                                           full_key=full_key)
 
     def scan_last(self, region_id: int, group_tag: str,
                   projection: Optional[Sequence[str]] = None,
+                  full_key: bool = True,
                   ) -> Optional[ScanData]:
         """Lastpoint-pruned newest-first scan (see Region.scan_last);
         None when the region type or data shape cannot serve it — the
         caller falls back to the full scan."""
         region = self.region(region_id)
         fn = getattr(region, "scan_last", None)
-        return None if fn is None else fn(group_tag, projection)
+        return None if fn is None else fn(group_tag, projection,
+                                          full_key=full_key)
 
     def estimate_rows(self, region_id: int, ts_range=None) -> int:
         """Upper bound on a scan's rows from metadata only."""
@@ -426,10 +434,12 @@ class RegionEngine:
         ts_range: Optional[tuple[int, int]] = None,
         projection: Optional[Sequence[str]] = None,
         tag_predicates: Optional[dict[str, set]] = None,
+        full_key: bool = True,
     ):
         """Lazy bounded-memory scan (see region.ScanStream)."""
         return self.region(region_id).scan_stream(ts_range, projection,
-                                                  tag_predicates)
+                                                  tag_predicates,
+                                                  full_key=full_key)
 
     def close(self) -> None:
         if self.workers is not None:

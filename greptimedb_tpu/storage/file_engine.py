@@ -50,7 +50,10 @@ class FileRegion:
             return 0
 
     def scan(self, ts_range=None, projection: Optional[Sequence[str]] = None,
-             tag_predicates=None, seq_min=None) -> Optional[ScanData]:
+             tag_predicates=None, seq_min=None,
+             full_key=True) -> Optional[ScanData]:
+        # `full_key` (Region.scan) asks nothing here: a file's rows are
+        # never merged by key, so only the named columns are returned
         if seq_min is not None:
             raise NotImplementedError(
                 "seq_min scans are not supported on external tables")

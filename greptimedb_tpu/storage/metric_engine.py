@@ -346,6 +346,7 @@ class LogicalRegion:
         projection: Optional[Sequence[str]] = None,
         tag_predicates: Optional[dict[str, set]] = None,
         seq_min: Optional[int] = None,
+        full_key: bool = True,
     ) -> Optional[ScanData]:
         if seq_min is not None:
             # logical regions share a physical region: a sequence
@@ -416,8 +417,11 @@ class LogicalRegion:
         columns: dict[str, np.ndarray] = {}
         tag_dicts: dict[str, np.ndarray] = {}
         names = projection or self.schema.names
-        # all tags always materialize (dedup needs the full primary key,
-        # Region._scan_columns invariant); each is one numpy gather
+        # all tags always materialize, whatever `full_key` says: the
+        # shared physical region is last-write-wins and is read whole
+        # (projection None, so Region.scan's `full_key` default holds:
+        # dedup needs the full primary key), and a virtual tag is one
+        # numpy gather, not a decode
         for t in self.meta.tag_names:
             codes, tag_dicts[t] = catalog.column(t)
             columns[t] = codes[label_codes]

@@ -137,9 +137,12 @@ class _ReadCount(NamedTuple):
 #: difference around a statement to say what the scan really cost it
 #: (the `scan` stage's rows_decoded / rows_read / rows_kept / batches,
 #: greptimedb_tpu_agg_scan_total's mode); pool workers decode on other
-#: threads, so the request thread counts for them in _cached_parts
+#: threads, so the request thread counts for them in _cached_parts.
+#: Then the tag columns outside a scan's projection: those it carried
+#: for the primary key's sake and those it left unread (_scan_columns)
 _SCAN_IO = threading.local()
-_SCAN_IO_FIELDS = ("parts", "rows", "read", "kept", "batches")
+_SCAN_IO_FIELDS = ("parts", "rows", "read", "kept", "batches",
+                   "key_decoded", "key_skipped")
 
 
 def scan_io_counters() -> tuple[int, ...]:
@@ -150,15 +153,30 @@ def scan_io_since(before: tuple[int, ...]) -> dict:
     """What this thread's scans cost since `before` (an earlier
     `scan_io_counters()`), under the names a `scan` stage span carries:
     rows_decoded, and of the pruned reads among them rows_read,
-    rows_kept and batches."""
+    rows_kept and batches; where a scan named fewer tags than the
+    table has, key_columns_decoded and key_columns_skipped beside them."""
     d = [a - b for a, b in zip(scan_io_counters(), before)]
-    return {"rows_decoded": d[1], "rows_read": d[2], "rows_kept": d[3],
-            "batches": d[4]}
+    out = {"rows_decoded": d[1], "rows_read": d[2], "rows_kept": d[3],
+           "batches": d[4]}
+    if d[5] or d[6]:
+        out.update(key_columns_decoded=d[5], key_columns_skipped=d[6])
+    return out
 
 
 def _scan_io_add(**by: int) -> None:
     for f, n in by.items():
         setattr(_SCAN_IO, f, getattr(_SCAN_IO, f, 0) + n)
+
+
+def _note_key_columns(n: int, decoded: bool) -> None:
+    """A scan's `n` tag columns outside its projection: carried for the
+    primary key's sake (`decoded`), or left unread because the caller
+    makes no last-write-wins mask."""
+    from greptimedb_tpu.utils.metrics import SCAN_KEY_COLUMNS
+
+    kind = "decoded" if decoded else "skipped"
+    SCAN_KEY_COLUMNS.inc(float(n), kind=kind)
+    _scan_io_add(**{"key_" + kind: n})
 
 
 class _PlanPins:
@@ -1703,6 +1721,7 @@ class Region:
         projection: Optional[Sequence[str]] = None,
         tag_predicates: Optional[dict[str, set]] = None,
         seq_min: Optional[int] = None,
+        full_key: bool = True,
     ) -> Optional[ScanData]:
         """Collect memtable + pruned SSTs into concatenated host columns.
         `tag_predicates` (tag -> allowed values) drives inverted-index
@@ -1710,11 +1729,21 @@ class Region:
         predicate rejects — the device filter still runs, pruning is purely
         an IO reduction (never affects correctness).
 
+        `full_key`: whether the table's whole primary key rides along
+        with `projection` (every tag column; the time index always
+        does). A last-write-wins merge (query/lww.py) and a cut into
+        series runs need it, so that is the default. The CALLER decides,
+        from the table's declared `append_mode` — the catalog holds it,
+        a region's manifest does not: an append-mode table's statements
+        make no mask, and with `full_key=False` their scans decode the
+        columns they name and no other (`__seq` / `__op_type` stay in
+        every part).
+
         `seq_min`: return only rows written AFTER that sequence — the
         incremental-consumer scan (flow ticks fold each row exactly
         once). Prunes whole SSTs by FileMeta.max_seq, so the IO cost is
         O(new data + files that straddle the boundary), not O(table)."""
-        names = self._scan_columns(projection)
+        names = self._scan_columns(projection, full_key)
         from greptimedb_tpu.storage.index import predicates_cache_key
         pred_key = predicates_cache_key(tag_predicates)
         if seq_min is not None:
@@ -1863,6 +1892,7 @@ class Region:
 
     def scan_last(self, group_tag: str,
                   projection: Optional[Sequence[str]] = None,
+                  full_key: bool = True,
                   ) -> Optional[ScanData]:
         """Lastpoint-pruned scan: visit SSTs NEWEST-FIRST (FileMeta
         ts_max order) and stop once every series grouped by `group_tag`
@@ -1889,8 +1919,9 @@ class Region:
         Returns None when the path cannot serve the query exactly —
         any DELETE tombstone in the visited rows or memtable (the
         newest row may be a tombstone, making an interior row the
-        answer) — and the caller falls back to the full scan."""
-        names = self._scan_columns(projection)
+        answer) — and the caller falls back to the full scan.
+        `full_key` as in `scan`."""
+        names = self._scan_columns(projection, full_key)
         tag_names = [c.name for c in self.schema.tag_columns]
         if group_tag not in tag_names or group_tag not in names:
             return None
@@ -2139,10 +2170,11 @@ class Region:
         ts_range: Optional[tuple[int, int]] = None,
         projection: Optional[Sequence[str]] = None,
         tag_predicates: Optional[dict[str, set]] = None,
+        full_key: bool = True,
     ) -> Optional["ScanStream"]:
         """Lazy bounded-memory scan (see ScanStream). Returns None when the
-        time range prunes everything."""
-        names = self._scan_columns(projection)
+        time range prunes everything. `full_key` as in `scan`."""
+        names = self._scan_columns(projection, full_key)
         with self._lock:
             snapshot_files = list(self.files.values())
             self._pin_files(snapshot_files)
@@ -2323,18 +2355,26 @@ class Region:
                         break
             pool.shutdown(wait=False)
 
-    def _scan_columns(self, projection: Optional[Sequence[str]]) -> list[str]:
-        ts_name = self.schema.time_index.name
+    def _scan_columns(self, projection: Optional[Sequence[str]],
+                      full_key: bool = True) -> list[str]:
+        """The columns a scan decodes, in schema order: the projection
+        and the time index, and with `full_key` every tag column beside
+        them — a last-write-wins mask merges by the whole primary key,
+        whatever the statement names. The caller asks for the key (see
+        `scan`); without it the tags outside the projection are never
+        read. Counts those tags, either way, on
+        greptimedb_tpu_scan_key_columns_total."""
         if projection is None:
             return self.schema.names
-        names = list(dict.fromkeys(projection))
-        if ts_name not in names:
-            names.append(ts_name)
-        # dedup correctness needs the full primary key
-        for c in self.schema.tag_columns:
-            if c.name not in names:
-                names.append(c.name)
-        return [n for n in self.schema.names if n in names]
+        named = set(projection)
+        named.add(self.schema.time_index.name)
+        key_only = sum(1 for c in self.schema.tag_columns
+                       if c.name not in named)
+        if key_only:
+            _note_key_columns(key_only, decoded=full_key)
+            if full_key:
+                named.update(c.name for c in self.schema.tag_columns)
+        return [n for n in self.schema.names if n in named]
 
     def _decode_sst(self, table: pa.Table, names: list[str]) -> dict[str, np.ndarray]:
         cols: dict[str, np.ndarray] = {}

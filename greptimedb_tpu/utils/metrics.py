@@ -727,6 +727,13 @@ SCAN_PART_CACHE_EVENTS = REGISTRY.counter(
 SCAN_DECODE_BYTES = REGISTRY.counter(
     "greptimedb_tpu_scan_decode_bytes_total",
     "Host bytes materialized by SST scan decode (part-cache misses)")
+SCAN_KEY_COLUMNS = REGISTRY.counter(
+    "greptimedb_tpu_scan_key_columns_total",
+    "Tag columns outside a region scan's projection, summed over scans, "
+    "by kind: decoded (carried with the scan for the primary key's sake: "
+    "a last-write-wins table's mask merges by it) and skipped (left "
+    "unread: the caller declared the table append-mode, so no mask is "
+    "made and the scan decodes the columns the statement names)")
 SCAN_ROWS = REGISTRY.counter(
     "greptimedb_tpu_scan_rows_total",
     "Rows through pruned SST reads (a window or tag predicates; "

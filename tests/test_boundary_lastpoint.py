@@ -202,6 +202,49 @@ def test_append_mode_large_random(db):
     assert fast == slow
 
 
+@pytest.mark.parametrize("group", ["host", "dc", ""])
+def test_append_mode_runs_are_cut_by_the_tags_the_scan_holds(db, group):
+    """An append-mode table's scan holds the tags the statement names,
+    not the whole key: series of one group then share a run wherever
+    time does not fall between them, and the reduction still finds each
+    group's first and last instants — grouped by the leading tag, by
+    the second one (several series a group, interleaved in time), and
+    by nothing. 3,000 rows, 24 series, two files and a memtable tail,
+    no two rows on one instant."""
+    _mk(db, append_mode=True, two_tags=True)
+    rng = np.random.default_rng(7)
+    rid = db.catalog.table("public", "t").region_ids[0]
+    from greptimedb_tpu.datatypes import DictVector, RecordBatch
+
+    hosts = np.asarray([f"h{i}" for i in range(6)], dtype=object)
+    dcs = np.asarray([f"d{i}" for i in range(4)], dtype=object)
+    schema = db.catalog.table("public", "t").schema
+    for part in range(3):
+        n = 1000
+        ts = rng.permutation(3 * n)[:n].astype(np.int64) * 3 + part
+        db.region_engine.put(rid, RecordBatch(schema, {
+            "host": DictVector(rng.integers(0, 6, n).astype(np.int32),
+                               hosts),
+            "dc": DictVector(rng.integers(0, 4, n).astype(np.int32), dcs),
+            "v": rng.uniform(0, 100, n), "w": rng.uniform(0, 100, n),
+            "ts": ts,
+        }))
+        if part < 2:
+            db.region_engine.flush(rid)
+    by = f" GROUP BY {group} ORDER BY {group}" if group else ""
+    sql = (f"SELECT {group + ', ' if group else ''}"
+           "last_value(v ORDER BY ts) AS lv, "
+           f"first_value(w ORDER BY ts) AS fw FROM t{by}")
+    from greptimedb_tpu.utils.metrics import SCAN_KEY_COLUMNS
+
+    decoded = SCAN_KEY_COLUMNS.get(kind="decoded")
+    fast, slow, used = _run_both(db, sql)
+    assert used
+    assert fast == slow
+    assert len(fast) == {"host": 6, "dc": 4, "": 1}[group]
+    assert SCAN_KEY_COLUMNS.get(kind="decoded") == decoded
+
+
 def test_global_first_last_no_group(db):
     _mk(db)
     _ins(db, [("a", 1.0, 10.0, 1000), ("b", 2.0, 20.0, 9000),

@@ -106,6 +106,34 @@ class TestParallelDecode:
             b = engine.scan(1, **kwargs)
             assert scans_equal(a, b)
 
+    @pytest.mark.parametrize("kwargs", [
+        {}, {"ts_range": (1_000_000, 2_000_500)},
+        {"tag_predicates": {"host": {"h1", "h4"}}, "projection": ["host"]},
+    ])
+    def test_a_scan_without_the_key_decodes_alike_serial_and_parallel(
+            self, engine, monkeypatch, kwargs):
+        """`full_key=False` (an append-mode table's scan): the parts
+        hold the named columns only, the same bytes from one decode
+        thread and from four, and they are the full-key scan's columns
+        of those names."""
+        engine.create_region(1, schema3())
+        fill_files(engine, 1)
+        region = engine.region(1)
+        kwargs = {"projection": ["v"], **kwargs}
+        got = []
+        for threads in ("1", "4"):
+            monkeypatch.setenv("GREPTIMEDB_TPU_SCAN_DECODE_THREADS", threads)
+            clear_scan_caches(region)
+            got.append(engine.scan(1, full_key=False,
+                                   **kwargs).materialize())
+        assert "host" not in got[0].columns or "host" in kwargs["projection"]
+        assert scans_equal(got[0], got[1])
+        full = engine.scan(1, **kwargs).materialize()
+        assert set(full.columns) == set(got[0].columns) | {"host"}
+        assert all(np.array_equal(col, full.columns[name])
+                   for name, col in got[0].columns.items())
+        assert np.array_equal(got[0].seq, full.seq)
+
     def test_decode_pool_actually_exercised(self, engine, monkeypatch):
         """Tier-1 speed guard: a multi-SST region's cold scan must run
         on >1 pool worker — a refactor silently re-serializing the
@@ -529,6 +557,36 @@ class TestPartCacheMutation:
         assert scan.stats["part_hits"] == 3
         # and the incremental assembly is correct
         assert scan.num_rows == 3 * 300 + 1
+
+    @pytest.mark.parametrize("ts_range", [None, (0, 1_500_000)])
+    def test_parts_without_the_key_are_entries_of_their_own(self, engine,
+                                                            ts_range):
+        """A `full_key=False` scan's parts are keyed by the columns
+        they hold: the same projection with the key decodes its own
+        parts and takes none of these, and each is hit again by its
+        own scan."""
+        engine.create_region(1, schema3())
+        fill_files(engine, 1, n_files=3)
+        region = engine.region(1)
+        files = 3  # every file is asked; the window leaves one no rows
+        narrow = engine.scan(1, ts_range, ["v"], full_key=False)
+        narrow.materialize()
+        assert narrow.stats["files_decoded"] == files
+        assert {k[2] for k in region._part_cache} == {("ts", "v")}
+        wide = engine.scan(1, ts_range, ["v"]).materialize()
+        assert wide.stats["files_decoded"] == files
+        assert wide.stats["part_hits"] == 0
+        assert {k[2] for k in region._part_cache} == {
+            ("ts", "v"), ("host", "ts", "v")}
+        with region._lock:
+            region._scan_cache.clear()
+            region._scan_cache_sizes.clear()
+            region._scan_cache_bytes = 0
+        again = engine.scan(1, ts_range, ["v"], full_key=False)
+        again.materialize()
+        assert again.stats["part_hits"] == files
+        assert again.stats["files_decoded"] == 0
+        assert set(again.columns) == {"ts", "v"}
 
     def test_compaction_invalidates_input_parts(self, engine):
         engine.create_region(1, schema3())

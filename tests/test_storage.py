@@ -236,6 +236,31 @@ class TestRegionEngine:
         scan = engine.scan(1, projection=["usage_user"])
         assert set(scan.columns) == {"hostname", "ts", "usage_user"}
 
+    @pytest.mark.parametrize("projection,full_key,want", [
+        (["usage_user"], False, ["ts", "usage_user"]),
+        (["usage_user"], True, ["hostname", "ts", "usage_user"]),
+        (["hostname"], False, ["hostname", "ts"]),
+        (["usage_user", "ts", "usage_user"], False, ["ts", "usage_user"]),
+        ([], False, ["ts"]),
+        (None, False, ["hostname", "ts", "usage_user"]),
+    ])
+    def test_projection_without_the_key_is_the_named_columns(
+            self, engine, projection, full_key, want):
+        """A caller whose table is append-mode asks for no key
+        (`full_key=False`): the named columns and the time index, in
+        schema order, from the memtable and from an SST alike."""
+        s = cpu_schema()
+        engine.create_region(1, s)
+        engine.put(1, make_batch(s, ["a", "b"], [10, 20], [1.0, 2.0]))
+        for _ in range(2):
+            scan = engine.scan(1, projection=projection,
+                               full_key=full_key).materialize()
+            assert list(scan.columns) == want
+            assert set(scan.tag_dicts) == {"hostname"} & set(want)
+            assert scan.num_rows == 2
+            assert sorted(scan.columns["ts"].tolist()) == [10, 20]
+            engine.flush(1)
+
 
 class TestSeqMinScan:
     """Incremental-consumer scans (`scan(seq_min=...)`): only rows
