@@ -10,10 +10,14 @@ threads still executing queries. The pool bounds that contention:
   execution slot;
 - at most `workers` serializations run at once — the other request
   threads park on a future (releasing the GIL) instead of thrashing it;
-- the encoders themselves are columnar (servers/encode.py): numpy
-  C-loop casts and a single C `json.dumps`, no per-value Python
-  sanitization, and batched results share one materialization through
-  their group `encode_memo`;
+- the encoders themselves are columnar (servers/encode.py): a
+  `/v1/sql` answer's rows go from the result's arrays to bytes in
+  arrow's kernels, no Python object a value, and those kernels give the
+  interpreter lock up while they run, so a pool THREAD writing a large
+  answer holds nobody else up (a result with a column of another class
+  still goes a value at a time through `json_rows` + one C
+  `json.dumps`, holding the lock); batched results share what was
+  written through their group `encode_memo`;
 - process mode moves the serialization into spawn-mode worker processes
   for a true GIL escape. It is selected PER RESULT by measured size
   (`process_mode="auto"`, the default): results at or above

@@ -1,21 +1,29 @@
 """Columnar result serialization for the protocol servers.
 
-Deliberately light on imports (json/math/numpy only): the encode pool's
-process mode (spawn) imports this module in its workers, and pulling
-the engine or JAX into an encode worker would cost seconds of startup
-for a serialization job.
+Deliberately light on imports (json/math/numpy at the top; pyarrow
+inside the functions that write with it): the encode pool's process
+mode (spawn) imports this module in its workers, and pulling the engine
+or JAX into an encode worker would cost seconds of startup for a
+serialization job.
 
 Two properties the tier-1 parity tests pin down:
 
-- **byte identity**: the columnar fast path produces exactly the bytes
-  the per-value path produced (same null mapping: NaN/Inf -> null, same
-  C `json.dumps` on native Python objects), so responses are identical
-  whether encoding runs inline, on a pool thread, or in a worker
+- **the same values and types, one spelling on every serving mode**: a
+  `/v1/sql` answer's `"rows"` are written from the result's columns in
+  arrow's kernels (`columnar_rows`), no Python object a value, and
+  `json.loads` of the body gives what the per-value writer's gave — a
+  float the identical float64 (and still a float: `3.0`, never `3`), an
+  integer, a boolean and a string the identical value, NULL / NaN /
+  +-Inf `null`. A result holding a column the writer has no class for
+  goes through `json_rows` + `json.dumps` whole. Which of the two wrote
+  a result depends on its columns' dtypes alone, so the bytes are the
+  same whether encoding runs inline, on a pool thread, or in a worker
   process;
 - **one materialization per batch group**: results that came out of the
   cross-query batcher share an `encode_memo` dict — the first encoder
-  to run stores the materialized row list, the other members of the
-  coalesced group reuse it instead of re-walking the columns.
+  to run stores what it wrote (the `"rows"` bytes; the row list for
+  `json_rows` / `memo_rows`), the other members of the coalesced group
+  reuse it instead of re-walking the columns.
 """
 
 from __future__ import annotations
@@ -26,7 +34,7 @@ import struct
 
 import numpy as np
 
-from greptimedb_tpu.utils.metrics import ENCODE_SECONDS
+from greptimedb_tpu.utils.metrics import ENCODE_SECONDS, SQL_ENCODED_ROWS
 
 
 def _json_safe(v):
@@ -102,25 +110,132 @@ def schema_header_json(names, dtypes) -> str:
     return cached
 
 
+def columnar_rows(columns) -> tuple | None:
+    """A result set's `"rows"` written from its columns: one text array
+    a column, one `binary_join_element_wise` for the rows, one
+    `binary_join` for the body. Python works per column and per DISTINCT
+    string; the rest runs in arrow's kernels, which give the interpreter
+    lock up. Returned as the pieces to join (arrow's buffer is not copied
+    here). None where a column is of neither class below (an object
+    column holding a list, bytes, a Decimal, a number; a datetime; an
+    array of arrays):
+
+    - numbers: columns of one dtype are cast to text together. A float
+      is widened to float64 first (a float32 answer spells the float64
+      it equals) and spelled in arrow's shortest form that parses back
+      to the same float64, `.0` added where that form is a bare integer
+      (`3`, `-0`); NaN and +-Inf are null;
+    - strings (`str` / `None`): the distinct values are escaped by
+      `json.dumps` and taken by their codes."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    large = pa.large_string()
+
+    def lit(s):
+        return pa.scalar(s, large)
+
+    def numbers(a):
+        if a.dtype.kind != "f":
+            return pc.cast(pa.array(a), large)
+        bad = ~np.isfinite(a)
+        text = pc.cast(pa.array(a, mask=bad if bad.any() else None), large)
+        with np.errstate(invalid="ignore"):  # a signalling NaN
+            whole = a == np.trunc(a)
+        if whole.any():
+            # a whole number is spelled with an `e` or bare, never a `.`
+            bare = pc.and_not(pa.array(whole),
+                              pc.match_substring(text, "e"))
+            text = pc.if_else(bare, pc.binary_join_element_wise(
+                text, lit(".0"), lit("")), text)
+        return text
+
+    def strings(a):
+        try:
+            values = pa.array(a)
+        except (pa.ArrowException, UnicodeError):
+            return None  # mixed classes, a lone surrogate
+        if pa.types.is_null(values.type):
+            return pa.nulls(len(a), large)
+        if not pa.types.is_string(values.type):
+            return None
+        coded = values.dictionary_encode()
+        return pa.array([json.dumps(s) for s in coded.dictionary.to_pylist()],
+                        large).take(coded.indices)
+
+    n = len(columns[0]) if columns else 0
+    if n == 0:
+        return (b"[]",)
+    texts: list = [None] * len(columns)
+    by_dtype: dict = {}
+    for i, col in enumerate(columns):
+        a = np.asarray(col)
+        if a.ndim != 1:
+            return None
+        if a.dtype.kind == "f":
+            by_dtype.setdefault(np.dtype(np.float64), []).append((i, a))
+        elif a.dtype.kind in "iub":
+            by_dtype.setdefault(a.dtype, []).append((i, a))
+        elif a.dtype.kind in "OU":
+            texts[i] = strings(a)
+            if texts[i] is None:
+                return None
+        else:
+            return None
+    for dtype, members in by_dtype.items():
+        flat = np.empty(n * len(members), dtype)
+        for j, (_, a) in enumerate(members):
+            flat[j * n:(j + 1) * n] = a
+        text = numbers(flat)
+        for j, (i, _) in enumerate(members):
+            texts[i] = text.slice(j * n, n)
+    rows = pc.binary_join_element_wise(
+        *texts, lit(", "), null_handling="replace", null_replacement="null")
+    body = pc.binary_join(pa.ListArray.from_arrays(
+        pa.array([0, n], pa.int32()), rows), lit("], ["))[0]
+    return b"[[", body.as_buffer(), b"]]"
+
+
+def rows_json(r) -> tuple:
+    """The `"rows"` of one query result as the response carries them
+    (pieces to join), counted by which writer wrote them. Which one is
+    decided by the columns' dtypes alone (`columnar_rows`). Kept in the
+    result's batch-group `encode_memo` when present."""
+    memo = getattr(r, "encode_memo", None)
+    written = memo.get("rows_json") if memo is not None else None
+    if written is None:
+        pieces = columnar_rows(r.columns)
+        written = ("columnar", pieces) if pieces is not None else (
+            "values", (json.dumps(json_rows(r)).encode(),))
+        if memo is not None:
+            # benign race: concurrent encoders write identical bytes
+            memo["rows_json"] = written
+    path, pieces = written
+    SQL_ENCODED_ROWS.inc(r.num_rows, path=path)
+    return pieces
+
+
 def encode_sql_payload(results, elapsed_ms: float) -> bytes:
-    """The full /v1/sql response body — built and dumped in one place
-    so the pool can run it off the request thread. Assembled from the
-    memoized schema-header fragment + one C `json.dumps` of the rows;
-    byte-identical to dumping the whole document (json.dumps emits
-    `", "`/`": "` separators — pinned by the tier-1 parity test)."""
+    """The full /v1/sql response body — built in one place so the pool
+    can run it off the request thread. Assembled from the memoized
+    schema-header fragment + each result's `rows_json`, spelled as
+    `json.dumps` of the whole document spells it (`", "`/`": "`
+    separators — pinned by the tier-1 parity test)."""
     with ENCODE_SECONDS.time(protocol="http"):
-        out = []
-        for r in results:
+        out = [b'{"code": 0, "output": [']
+        for i, r in enumerate(results):
+            if i:
+                out.append(b", ")
             if not r.is_query:
-                out.append('{"affectedrows": %d}' % r.affected_rows)
-            else:
-                out.append(
-                    '{"records": {"schema": %s, "rows": %s, '
-                    '"total_rows": %d}}'
-                    % (schema_header_json(r.names, r.dtypes),
-                       json.dumps(json_rows(r)), r.num_rows))
-        return ('{"code": 0, "output": [%s], "execution_time_ms": %s}'
-                % (", ".join(out), json.dumps(elapsed_ms))).encode()
+                out.append(b'{"affectedrows": %d}' % r.affected_rows)
+                continue
+            out.append(b'{"records": {"schema": %s, "rows": '
+                       % schema_header_json(r.names, r.dtypes).encode())
+            out.extend(rows_json(r))
+            out.append(b', "total_rows": %d}}' % r.num_rows)
+        out.append(b'], "execution_time_ms": %s}'
+                   % json.dumps(elapsed_ms).encode())
+        return b"".join(out)
 
 
 # ---- Prometheus range answers ----------------------------------------------

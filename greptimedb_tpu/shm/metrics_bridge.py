@@ -28,7 +28,8 @@ from greptimedb_tpu.shm.fabric import FabricError
 #: names resolve against the parent registry at fold time
 _BRIDGED_HISTOGRAMS = ("greptimedb_tpu_encode_seconds",)
 _BRIDGED_COUNTERS = ("greptimedb_tpu_shm_fabric_events_total",
-                     "greptimedb_tpu_encode_pool_events_total")
+                     "greptimedb_tpu_encode_pool_events_total",
+                     "greptimedb_tpu_sql_encoded_rows_total")
 
 _installed = {"done": False}
 _install_lock = threading.Lock()

@@ -613,6 +613,14 @@ PROMQL_ENCODED_RESPONSES = REGISTRY.counter(
     "to bytes in arrow's kernels with no Python object a sample; rows = "
     "an instant query, a scalar-valued range answer or an error, a value "
     "at a time through json.dumps)")
+SQL_ENCODED_ROWS = REGISTRY.counter(
+    "greptimedb_tpu_sql_encoded_rows_total",
+    "Rows of /v1/sql query results by how their \"rows\" were written "
+    "(columnar = from the result's columns to bytes in arrow's kernels "
+    "with no Python object a value; values = the whole result set a value "
+    "at a time through json_rows + json.dumps, because a column is neither "
+    "numeric nor str / None). Rows written in an encode-pool worker "
+    "process reach this registry through the shm fabric's metrics bridge")
 DEVICE_HOT_SET_EVENTS = REGISTRY.counter(
     "greptimedb_tpu_device_hot_set_events_total",
     "HBM-resident columnar hot set events by kind (hit/miss/evict/pin — "
