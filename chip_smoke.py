@@ -154,7 +154,7 @@ class Server:
 
     def stop(self) -> None:
         """SIGTERM the server's process group, then SIGKILL whatever is
-        left of it (encode workers included)."""
+        left of it."""
         for sig in (signal.SIGTERM, signal.SIGKILL):
             try:
                 os.killpg(self.proc.pid, sig)

@@ -185,9 +185,8 @@ def cmd_standalone(args):
 
 def stop_standalone(engine, qe, servers=(), tasks=()) -> None:
     """Stop what `cmd_standalone` started, in its order: background
-    tasks, servers, the encode workers, the engine, and the
-    interpreter-lock probe `build_standalone` started with the
-    observability plane."""
+    tasks, servers, the engine, and the interpreter-lock probe
+    `build_standalone` started with the observability plane."""
     from greptimedb_tpu.utils import lock_probe
 
     for t in tasks:
@@ -197,9 +196,6 @@ def stop_standalone(engine, qe, servers=(), tasks=()) -> None:
             s.stop()
         except AttributeError:
             s.shutdown()
-    # reclaim encode workers deterministically (spawn-mode worker
-    # PROCESSES especially must not outlive a clean shutdown)
-    qe.concurrency.shutdown()
     engine.close()
     lock_probe.shutdown()
 

@@ -1,21 +1,24 @@
 """Frontend concurrency plane (the ISSUE-6 subsystem).
 
-A genuinely new layer between the protocol servers (L6/L5) and the
-query engine (L3) that makes fleet-scale concurrent traffic cheap:
+The layer between the protocol servers (L6/L5) and the query engine
+(L3) that makes fleet-scale concurrent traffic cheap, three boxes in
+one direction — admission -> plan cache -> fast lane -> executor:
 
+- `admission`    — bounded admission queue + per-tenant weighted fair
+                   scheduling with typed `Overloaded` rejection; the
+                   slot is released at execute-done, so an answer's
+                   serialization never holds one;
 - `plan_cache`   — shape-keyed parameterized logical-plan cache (one
                    plan + one XLA executable shared by thousands of
                    near-identical dashboard queries), invalidated on
                    DDL/schema/rollup-state change;
-- `admission`    — bounded admission queue + per-tenant weighted fair
-                   scheduling with typed `Overloaded` rejection;
-- `batcher`      — a short collection window that coalesces identical
-                   statements and executes parameter-sibling aggregates
-                   (multi-tag selectors, differing time windows) as one
-                   vmap'd stacked dispatch, bit-for-bit with serial;
-- `encode_pool`  — a bounded pool that serializes query results off the
-                   request threads (admission slots are released at
-                   execute-done, serialization never holds one).
+- `fast_lane`    — text-keyed templates in front of the plan cache: a
+                   repeat shape binds and executes without parsing, and
+                   concurrent identical requests share one execution
+                   (single flight), bit-for-bit with serial.
+
+An answer is encoded on the thread that owns the request, by
+`servers/encode.py`, inside `tracing.stage("encode")`.
 
 `QueryEngine` routes every statement through the plane; configuration
 comes from the `[concurrency]` options section via `configure()` (env
@@ -26,7 +29,6 @@ from __future__ import annotations
 
 import os
 import threading
-from contextlib import contextmanager
 from dataclasses import dataclass
 
 from greptimedb_tpu.concurrency.admission import (  # noqa: F401
@@ -34,8 +36,6 @@ from greptimedb_tpu.concurrency.admission import (  # noqa: F401
     Overloaded,
     parse_weights,
 )
-from greptimedb_tpu.concurrency.batcher import QueryBatcher
-from greptimedb_tpu.concurrency.encode_pool import EncodePool
 from greptimedb_tpu.concurrency.fast_lane import FastLane
 from greptimedb_tpu.concurrency.plan_cache import PlanCache
 
@@ -57,36 +57,6 @@ class ConcurrencyConfig:
     fast_lane: bool = True
     #: fast-lane template capacity; 0 disables
     fast_lane_entries: int = 512
-    batching: bool = True
-    batch_window_ms: float = 2.0
-    batch_max_queries: int = 64
-    #: stacked dispatch only below this estimated row count (single
-    #: kernel dispatch keeps float parity provable); 0 = no bound
-    batch_max_rows: int = 4 << 20
-    #: vmap'd multi-query kernel for parameter-sibling batch members
-    #: (off -> IN-list stacking / serial fallback only)
-    batch_vmap: bool = True
-    #: bounded result-encode pool (off -> serialize on request threads)
-    encode_offload: bool = True
-    #: encode workers; 0 = auto (max(2, min(8, cpu/2)))
-    encode_workers: int = 0
-    #: serializations in flight before inline fallback
-    encode_queue: int = 64
-    #: results smaller than this many rows encode inline (a thread
-    #: handoff costs more than serializing a dashboard-sized result)
-    encode_min_rows: int = 256
-    #: spawn-mode worker processes instead of threads (full GIL escape;
-    #: pays pickling) — legacy pin: True forces every offload to the
-    #: process pool (same as encode_process_mode="on")
-    encode_process_pool: bool = False
-    #: process-pool routing: "auto" escapes to spawn workers only for
-    #: results at/above encode_process_min_rows (measured size picks the
-    #: executor), "on" pins process mode, "off" disables it (A/B knob,
-    #: GTPU_ENCODE_PROCESS_MODE)
-    encode_process_mode: str = "auto"
-    #: auto-mode threshold: results at/above this many rows serialize in
-    #: a worker process; dashboard-sized rows keep the thread pool
-    encode_process_min_rows: int = 100_000
 
 
 _config = ConcurrencyConfig()
@@ -125,21 +95,6 @@ def current_config() -> ConcurrencyConfig:
                              int) != 0
     cfg.fast_lane_entries = _env_num("GTPU_FAST_LANE_ENTRIES",
                                      cfg.fast_lane_entries, int)
-    cfg.batching = _env_num("GTPU_QUERY_BATCHING", int(cfg.batching),
-                            int) != 0
-    cfg.batch_window_ms = _env_num("GTPU_BATCH_WINDOW_MS",
-                                   cfg.batch_window_ms, float)
-    cfg.batch_vmap = _env_num("GTPU_BATCH_VMAP", int(cfg.batch_vmap),
-                              int) != 0
-    cfg.encode_offload = _env_num("GTPU_ENCODE_OFFLOAD",
-                                  int(cfg.encode_offload), int) != 0
-    cfg.encode_workers = _env_num("GTPU_ENCODE_WORKERS",
-                                  cfg.encode_workers, int)
-    mode = os.environ.get("GTPU_ENCODE_PROCESS_MODE", "").lower()
-    if mode in ("auto", "on", "off"):
-        cfg.encode_process_mode = mode
-    cfg.encode_process_min_rows = _env_num("GTPU_ENCODE_PROCESS_MIN_ROWS",
-                                           cfg.encode_process_min_rows, int)
     return cfg
 
 
@@ -162,58 +117,15 @@ class ConcurrencyPlane:
             cfg.fast_lane_entries,
             enabled=(cfg.enabled and cfg.fast_lane
                      and self.plan_cache.enabled))
-        self.batcher = QueryBatcher(
-            window_s=cfg.batch_window_ms / 1000.0,
-            max_queries=cfg.batch_max_queries,
-            max_rows=cfg.batch_max_rows,
-            enabled=cfg.enabled and cfg.batching,
-            vmap=cfg.batch_vmap)
-        self.encode = EncodePool(
-            workers=cfg.encode_workers,
-            queue_size=cfg.encode_queue,
-            process=cfg.encode_process_pool,
-            enabled=cfg.enabled and cfg.encode_offload,
-            min_rows=cfg.encode_min_rows,
-            process_mode=("on" if cfg.encode_process_pool
-                          else cfg.encode_process_mode),
-            process_min_rows=cfg.encode_process_min_rows)
-        self._tls = threading.local()
         # the serving fabric (shm/): attach once per process and
-        # register the scrape-time collectors (fabric gauges +
-        # worker-metrics fold) — no-ops when GTPU_SHM_FABRIC is off.
-        # Compiled executables are shared through JAX's persistent
+        # register the scrape-time collector of its gauges — a no-op
+        # when GTPU_SHM_FABRIC is off. Compiled executables are shared through JAX's persistent
         # compilation cache, which every process of a checkout places by
         # the one rule in greptimedb_tpu/__init__.py
         from greptimedb_tpu import shm
 
         if cfg.enabled and shm.get_fabric() is not None:
-            from greptimedb_tpu.shm import metrics_bridge
-
-            metrics_bridge.install_collector()
             shm.install_stats_collector()
-
-    # ---- batching gate -----------------------------------------------------
-
-    @contextmanager
-    def suppress_batching(self):
-        """EXPLAIN/TQL ANALYZE must observe ITS execution's spans —
-        riding another leader's run would report an empty trace."""
-        prev = getattr(self._tls, "no_batch", False)
-        self._tls.no_batch = True
-        try:
-            yield
-        finally:
-            self._tls.no_batch = prev
-
-    def execute_select(self, qe, sel, info, ctx):
-        """Route one table SELECT: batch when this is a top-level
-        statement on a busy server, else straight through."""
-        if (not self.batcher.enabled
-                or self.admission.depth() != 1
-                or getattr(self._tls, "no_batch", False)):
-            return qe._select_table(sel, info, ctx)
-        return self.batcher.execute(qe, sel, info, ctx,
-                                    busy=self.admission.active > 1)
 
     # ---- tenancy -----------------------------------------------------------
 
@@ -229,9 +141,10 @@ class ConcurrencyPlane:
     # ---- lifecycle ---------------------------------------------------------
 
     def shutdown(self) -> None:
-        """Deterministic teardown of pool resources (encode workers —
-        the GC finalizer is only the backstop for discarded planes)."""
-        self.encode.shutdown()
+        """Nothing to release: the plane owns no thread or process. Kept
+        because the benchmark's loaders (`benchmark/loaders/`,
+        `benchmark/harness/bulk_load.py`) call it before they close
+        their engine, and those files are the yardstick."""
 
     # ---- invalidation ------------------------------------------------------
 

@@ -46,8 +46,7 @@ scanner:
   changes, hits fall back until the slow lane re-probes.
 
 Concurrent identical requests single-flight: followers ride the
-leader's in-flight execution (the cross-query batcher's coalescing
-semantics, without the collection window).
+leader's in-flight execution.
 
 With the serving fabric on (`[shm] fabric`), a template another process
 on the box already validated is ADOPTED instead of re-proved: the
@@ -350,13 +349,8 @@ class FastLane:
         ticket = _Ticket()
         self._tls.ticket = ticket
         try:
-            # batching suppressed: a build run must stamp ITS OWN
-            # statement's plan, not a batch leader's combined rewrite
-            # (serial execution is the batcher's own fallback, so the
-            # semantics are unchanged)
-            with qe.concurrency.suppress_batching():
-                results = qe._execute_sql_slow(sql, ctx,
-                                               _intercepted=intercepted)
+            results = qe._execute_sql_slow(sql, ctx,
+                                           _intercepted=intercepted)
         finally:
             self._tls.ticket = None
         try:
@@ -373,7 +367,7 @@ class FastLane:
         uncacheable — the slow lane stays authoritative."""
         if ticket.stamps != 1 or ticket.entry is None:
             # the statement did not execute exactly one plan-cache plan
-            # (DDL, rollup substitution, batched leader, view, CTE, ...)
+            # (DDL, rollup substitution, view, CTE, ...)
             self._mark_uncacheable(key)
             return
         stmts = qe._parse_cached(sql)
@@ -656,9 +650,8 @@ class FastLane:
         ticket = _Ticket()
         self._tls.ticket = ticket
         try:
-            with qe.concurrency.suppress_batching():
-                results = qe._execute_sql_slow(sql, ctx,
-                                               _intercepted=intercepted)
+            results = qe._execute_sql_slow(sql, ctx,
+                                           _intercepted=intercepted)
         finally:
             self._tls.ticket = None
         try:
@@ -736,12 +729,10 @@ class FastLane:
 
     def _execute_shared(self, qe, entry, params, tz):
         """Single-flight: concurrent identical (entry, params) requests
-        share one bind+execute (the batcher's coalescing semantics for
-        the fast lane — identical statements were the dominant batch
-        shape, and the collection window is pure latency here). The
-        session timezone is part of the key: naive string timestamp
-        literals bind under it, so same-text requests from differently
-        zoned sessions must not share an execution.
+        share one bind+execute. The session timezone is part of the
+        key: naive string timestamp literals bind under it, so same-text
+        requests from differently zoned sessions must not share an
+        execution.
 
         So is the data the request saw when it arrived: the table's
         regions' `data_identity`, read here, before the flight is looked
@@ -829,8 +820,8 @@ class FastLane:
                 raise _BindFailed(str(e)) from e
         with tracing.enclosing_stage("fast_execute"):
             result = qe.executor.execute(plan)
-        # batch-group style memo: coalesced followers and the encoder
-        # share one row materialization / schema header
+        # the flight's memo: coalesced followers' encoders share one
+        # row materialization / written rows
         result.encode_memo = {}
         return result
 

@@ -178,7 +178,7 @@ def install() -> None:
     threading.RLock = make_rlock
     _installed = True
     if os.environ.get("GTPU_LOCKDEP_DIR"):
-        # cross-process mode (ProcessCluster children, encode workers):
+        # cross-process mode (ProcessCluster children):
         # leave this process's edge set behind for the parent's merge
         import atexit
 

@@ -53,10 +53,9 @@ SCOPE_FILES = (
     "greptimedb_tpu/storage/wal.py",
     "greptimedb_tpu/storage/group_commit.py",
     "greptimedb_tpu/query/device_cache.py",
-    # serving path: the vmapped batch leader and the result-encode
-    # seam run under the batch-window/encode-pool locks (the
+    # serving path: the result-encode seam runs on every request
+    # thread, single-flight followers sharing one memo (the
     # concurrency/ package itself is scope-prefixed)
-    "greptimedb_tpu/query/vmapped.py",
     "greptimedb_tpu/servers/encode.py",
 )
 

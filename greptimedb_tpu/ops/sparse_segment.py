@@ -4,8 +4,8 @@ execution flavor routes through past its dense cardinality envelope.
 The dense paths hold [G, F] accumulator planes indexed by the full group
 key PRODUCT — which caps cardinality everywhere it is used: the fused
 Pallas kernel refuses >4096 segments, the partial cache falls back past
-64k groups, and the mesh/vmapped flavors require the dense plane to fit
-the device. The defining time-series workload (millions of small
+64k groups, and the mesh flavors require the dense plane to fit the
+device. The defining time-series workload (millions of small
 series, the reference's metric-engine scenario) blows every one of
 those budgets while OBSERVING only a bounded number of groups per scan:
 U <= N rows, regardless of how large the key product is.

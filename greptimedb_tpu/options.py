@@ -211,7 +211,7 @@ class IngestOptions:
 class ConcurrencyOptions:
     """Frontend concurrency plane (concurrency/ package): shape-keyed
     plan cache, bounded admission with per-tenant weighted fair
-    scheduling, and cross-query device batching."""
+    scheduling, and the parse-free fast lane with its single flight."""
 
     enabled: bool = True
     # concurrent statements in execution; 0 = auto (max(32, 4*cpu))
@@ -228,25 +228,6 @@ class ConcurrencyOptions:
     fast_lane: bool = True
     # fast-lane template capacity; 0 disables
     fast_lane_entries: int = 512
-    # cross-query batching: coalesce identical statements and stack
-    # shape-compatible small aggregates into one device dispatch
-    batching: bool = True
-    batch_window_ms: float = 2.0
-    batch_max_queries: int = 64
-    # stacked dispatch only below this estimated row count; 0 = no bound
-    batch_max_rows: int = 4 << 20
-    # vmap'd multi-query kernel for parameter-sibling batch members
-    batch_vmap: bool = True
-    # bounded result-encode pool: serialize results off request threads
-    encode_offload: bool = True
-    encode_workers: int = 0
-    encode_queue: int = 64
-    encode_min_rows: int = 256
-    encode_process_pool: bool = False
-    # process-pool routing: auto = escape to spawn workers only for
-    # results at/above encode_process_min_rows; on/off pin it (A/B)
-    encode_process_mode: str = "auto"
-    encode_process_min_rows: int = 100000
 
 
 @dataclass
@@ -631,26 +612,14 @@ def apply_concurrency(opts: "StandaloneOptions") -> None:
         plan_cache_entries=c.plan_cache_entries,
         fast_lane=c.fast_lane,
         fast_lane_entries=c.fast_lane_entries,
-        batching=c.batching,
-        batch_window_ms=c.batch_window_ms,
-        batch_max_queries=c.batch_max_queries,
-        batch_max_rows=c.batch_max_rows,
-        batch_vmap=c.batch_vmap,
-        encode_offload=c.encode_offload,
-        encode_workers=c.encode_workers,
-        encode_queue=c.encode_queue,
-        encode_min_rows=c.encode_min_rows,
-        encode_process_pool=c.encode_process_pool,
-        encode_process_mode=c.encode_process_mode,
-        encode_process_min_rows=c.encode_process_min_rows,
     ))
 
 
 def apply_shm(opts: "StandaloneOptions") -> None:
     """Feed [shm] into the GTPU_SHM_* env twins the fabric reads
-    (shm.config_from_env) — env-mediated so spawn-mode encode workers
-    and ProcessCluster children attach the same fabric. Operator-set
-    env always wins (the documented layering)."""
+    (shm.config_from_env) — env-mediated so ProcessCluster children
+    attach the same fabric. Operator-set env always wins (the
+    documented layering)."""
     s, ds = opts.shm, ShmOptions()
     m = {
         "GTPU_SHM_FABRIC": ("1" if s.fabric else "", ""),
@@ -870,42 +839,6 @@ _DOC = {
         "fall back to the full path). A/B knob: GTPU_FAST_LANE.",
     "concurrency.fast_lane_entries":
         "Fast-lane template cache capacity; 0 disables the lane.",
-    "concurrency.batching":
-        "Coalesce identical statements / stack compatible aggregates.",
-    "concurrency.batch_window_ms":
-        "Collection window while the server is busy; idle queries skip it.",
-    "concurrency.batch_max_queries": "Queries per batch group.",
-    "concurrency.batch_vmap":
-        "Execute parameter-sibling batch members (multi-tag selectors, "
-        "differing time windows) as one vmap'd stacked kernel dispatch; "
-        "off falls back to IN-list stacking / serial execution.",
-    "concurrency.encode_offload":
-        "Serialize query results on a bounded encode pool instead of "
-        "the request threads (admission slots are released at "
-        "execute-done either way).",
-    "concurrency.encode_workers":
-        "Encode pool workers; 0 = auto (max(2, min(8, cpu/2))).",
-    "concurrency.encode_queue":
-        "Serializations in flight before requests encode inline.",
-    "concurrency.encode_min_rows":
-        "Results smaller than this many rows encode inline — a thread "
-        "handoff costs more than serializing a dashboard-sized "
-        "result.",
-    "concurrency.encode_process_pool":
-        "Use spawn-mode worker processes for result serialization "
-        "(full GIL escape; pays pickling — for very large result "
-        "sets). Per-group encode-memo sharing and in-encoder metrics "
-        "are thread-mode only; process encodes are timed as "
-        "encode_seconds{protocol=\"process\"}.",
-    "concurrency.batch_max_rows":
-        "Stack only below this estimated scan row count; 0 = no bound.",
-    "concurrency.encode_process_mode":
-        "Process-pool routing: auto = escape to spawn workers only for "
-        "results at/above encode_process_min_rows (measured size picks "
-        "the executor); on/off pin it (A/B knob).",
-    "concurrency.encode_process_min_rows":
-        "Auto-mode threshold: results at/above this many rows "
-        "serialize in a worker process; smaller keep the thread pool.",
     "query.compute_dtype": "Kernel float dtype; empty = auto per backend.",
     "query.mesh": "Device mesh: off | auto | NxM.",
     "query.dense_groups_max":
@@ -973,10 +906,9 @@ _DOC = {
     "telemetry.interval_s": "Seconds between telemetry reports.",
     "shm.fabric":
         "Cross-process serving fabric: shared plan/template/executable "
-        "plane + zero-copy result handoff (GTPU_SHM_FABRIC).",
+        "plane (GTPU_SHM_FABRIC).",
     "shm.fabric_bytes":
-        "Bytes per shared segment, artifact fabric and result arena "
-        "each (GTPU_SHM_FABRIC_BYTES).",
+        "Bytes of the shared segment (GTPU_SHM_FABRIC_BYTES).",
     "shm.fabric_dir":
         "Fabric rendezvous directory (lockfiles + shared XLA cache); "
         "empty = per-user tempdir (GTPU_SHM_FABRIC_DIR).",

@@ -53,8 +53,7 @@ def execute_region_fragment(executor, region_id: int, frag: PlanFragment,
     region's entries through the invalidation seam."""
     from greptimedb_tpu.query import partial_cache as pc
 
-    if frag.stage("partial_agg") is not None \
-            and frag.stage("vmapped_agg") is None and pc.enabled():
+    if frag.stage("partial_agg") is not None and pc.enabled():
         reg = version = None
         try:
             reg = executor.engine.region(region_id)
@@ -87,12 +86,6 @@ def _execute_region_fragment_uncached(executor, region_id: int,
     agg = frag.stage("partial_agg")
     common = dict(where=where, ts_range=frag.ts_range,
                   append_mode=frag.append_mode, tz=frag.tz)
-    vm = frag.stage("vmapped_agg")
-    if vm is not None:
-        from greptimedb_tpu.query.vmapped import run_vmapped_region_partial
-
-        return run_vmapped_region_partial(executor, region_id, vm,
-                                          schema=schema, **common)
     if agg is not None:
         shim = SimpleNamespace(keys=agg["keys"], args=agg["args"],
                                ops=agg["ops"], **common)

@@ -51,8 +51,8 @@ class QueryEngine:
         self.plugins = plugins if plugins is not None else default_plugins()
         self.executor = PhysicalExecutor(region_engine)
         # frontend concurrency plane (concurrency/ package): admission
-        # control + plan cache + cross-query batching; every statement
-        # routes through it (pass concurrency= to inject a tuned one)
+        # control + plan cache + fast lane; every statement routes
+        # through it (pass concurrency= to inject a tuned one)
         self.concurrency = concurrency if concurrency is not None \
             else ConcurrencyPlane()
         # per-thread statement-scope flags (plan-cache skip noted once
@@ -86,9 +86,9 @@ class QueryEngine:
         from greptimedb_tpu.utils import deadline as dl
 
         if dl.current() is not None:
-            # nested statement (view expansion, TQL-in-SQL, a batch
-            # member re-entering) rides the outer statement's token —
-            # a fresh one would let inner work outlive the outer kill
+            # nested statement (view expansion, TQL-in-SQL) rides the
+            # outer statement's token — a fresh one would let inner
+            # work outlive the outer kill
             if ctx.cancel_token is None:
                 ctx.cancel_token = dl.current()
             return self._dispatch_lane(sql, ctx)
@@ -827,17 +827,13 @@ class QueryEngine:
             return QueryResult(names, dtypes, cols)
         info = self._table(sel.table, ctx)
         sel = _subst_session_funcs(sel, ctx)
-        # concurrency plane: a top-level SELECT on a busy server may
-        # coalesce/stack with shape-compatible concurrent queries; the
-        # plane always lands back in _select_table below
-        return self.concurrency.execute_select(self, sel, info, ctx)
+        return self._select_table(sel, info, ctx)
 
     def _select_table(self, sel: ast.Select, info: TableInfo,
                       ctx: QueryContext) -> QueryResult:
-        """The single-table SELECT pipeline below the concurrency plane
-        (window pushdown, RANGE..ALIGN, rollup substitution, the plan
-        cache, device execution). Batch leaders re-enter here with the
-        combined statement."""
+        """The single-table SELECT pipeline (window pushdown,
+        RANGE..ALIGN, rollup substitution, the plan cache, device
+        execution)."""
         from greptimedb_tpu.query.join import execute_select_over
         from greptimedb_tpu.query import range_select as rs
         from greptimedb_tpu.query.window import select_has_window
@@ -1755,10 +1751,7 @@ class QueryEngine:
             # statement's resources, not the whole request's
             with ledger.attach_fresh() as led:
                 t0 = _time.perf_counter()
-                # ANALYZE must run ITS OWN execution: riding a batch
-                # leader's run would report someone else's (empty) trace
-                with self.concurrency.suppress_batching():
-                    result = run()
+                result = run()
                 total_ms = (_time.perf_counter() - t0) * 1000.0
             spans = tracing.spans_for(tid)
         lines = ["", f"ANALYZE trace={tid} total={total_ms:.2f} ms "

@@ -1117,8 +1117,8 @@ def extract_ts_bounds(
 
 def split_conjuncts(where) -> list:
     """The AND-conjunction atoms of a WHERE clause (None -> []) — the
-    one splitter shared by join pushdown, rollup eligibility, and the
-    cross-query batcher, so their notion of 'a conjunct' can't drift."""
+    one splitter shared by join pushdown and rollup eligibility, so
+    their notion of 'a conjunct' can't drift."""
     if where is None:
         return []
     if isinstance(where, ast.BinaryOp) and where.op == "and":

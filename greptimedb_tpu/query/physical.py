@@ -1190,7 +1190,7 @@ def _agg_scan_sparse(
     Sorting is XLA-native and shapes stay static: all arrays are [N] or
     [cap, F]; only the group *count* is dynamic (returned as a scalar).
     The sort-compact core lives in ops/sparse_segment.py, shared with the
-    fused/sharded/incremental/vmapped sparse flavors.
+    fused/sharded/incremental sparse flavors.
     """
     mask = _where_mask(base_mask, where, where_args, cols, tag_names,
                        schema)
@@ -2230,8 +2230,8 @@ class PhysicalExecutor:
     def _finalize_combined_agg(self, combined, table, agg, having, project,
                                sort, limit, offset,
                                spec_slot) -> QueryResult:
-        """Final step over combined [G, F] partial planes — shared by
-        the fragment pushdown and the vmapped-fragments member loop."""
+        """Final step over combined [G, F] partial planes (the
+        fragment pushdown)."""
         if combined is None:
             return self._empty_agg_result(table, agg, having, project,
                                           sort, limit, offset)
@@ -2517,10 +2517,10 @@ class PhysicalExecutor:
             raise pc.PartialCacheIneligible("no immutable parts")
         for pk, es in parts.items():
             if len(es) != 1:
-                # one-device-block-per-part gate (the vmapped parity
-                # precedent): the cached partial must BE the part's
-                # left-fold contribution for combine order to reproduce
-                # the classic block-sequential association bit-for-bit
+                # one-device-block-per-part gate: the cached partial
+                # must BE the part's left-fold contribution for combine
+                # order to reproduce the classic block-sequential
+                # association bit-for-bit
                 raise pc.PartialCacheIneligible("multi-block part")
         # LWW dedup is whole-scan: a newer duplicate in part Q can kill
         # a row in part P. Duplicates share an exact (series, ts)

@@ -95,17 +95,6 @@ def _stage_to_json(st: dict) -> dict:
         # the partial from its newest-first lastpoint scan
         # (Region.scan_last) instead of decoding the full region
         out["tag"] = st["tag"]
-    elif op == "vmapped_agg":
-        # a BATCH of parameter-sibling partial aggregates: member
-        # parameter values stack into one region-side vmapped dispatch
-        # (query/vmapped.run_vmapped_region_partial); per-member
-        # {keys, planes} partials return — terminal
-        out["keys"] = [[n, expr_to_json(e)] for n, e in st["keys"]]
-        out["args"] = [expr_to_json(a) for a in st["args"]]
-        out["ops"] = list(st["ops"])
-        out["shared_where"] = expr_to_json(st.get("shared_where"))
-        out["params"] = [[c, o] for c, o in st["params"]]
-        out["values"] = [list(v) for v in st["values"]]
     else:
         raise ValueError(f"unknown fragment stage {op!r}")
     return out
@@ -132,16 +121,6 @@ def _stage_from_json(d: dict) -> dict:
                 "calls": [(n, expr_from_json(e)) for n, e in d["calls"]]}
     if op == "lastpoint":
         return {"op": op, "tag": d["tag"]}
-    if op == "vmapped_agg":
-        sw = d.get("shared_where")
-        return {"op": op,
-                "keys": [(n, expr_from_json(e)) for n, e in d["keys"]],
-                "args": [expr_from_json(a) for a in d["args"]],
-                "ops": list(d["ops"]),
-                "shared_where": expr_from_json(sw) if sw is not None
-                else None,
-                "params": [(c, o) for c, o in d["params"]],
-                "values": [list(v) for v in d["values"]]}
     raise ValueError(f"unknown fragment stage {op!r}")
 
 

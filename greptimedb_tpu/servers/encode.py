@@ -1,10 +1,9 @@
 """Columnar result serialization for the protocol servers.
 
-Deliberately light on imports (json/math/numpy at the top; pyarrow
-inside the functions that write with it): the encode pool's process
-mode (spawn) imports this module in its workers, and pulling the engine
-or JAX into an encode worker would cost seconds of startup for a
-serialization job.
+Called on the thread that owns the request, inside
+`tracing.stage("encode")`. Light on imports (json/math/numpy at the top;
+pyarrow inside the functions that write with it): nothing here needs the
+engine or JAX.
 
 Two properties the tier-1 parity tests pin down:
 
@@ -17,13 +16,13 @@ Two properties the tier-1 parity tests pin down:
   +-Inf `null`. A result holding a column the writer has no class for
   goes through `json_rows` + `json.dumps` whole. Which of the two wrote
   a result depends on its columns' dtypes alone, so the bytes are the
-  same whether encoding runs inline, on a pool thread, or in a worker
-  process;
-- **one materialization per batch group**: results that came out of the
-  cross-query batcher share an `encode_memo` dict — the first encoder
-  to run stores what it wrote (the `"rows"` bytes; the row list for
-  `json_rows` / `memo_rows`), the other members of the coalesced group
-  reuse it instead of re-walking the columns.
+  same on every request thread;
+- **one materialization per single flight**: the fast lane's single
+  flight gives its result an `encode_memo` dict that the followers'
+  encoders share — the first encoder to run stores what it wrote (the
+  `"rows"` bytes; the row list for `json_rows` / `memo_rows`), the
+  other requests of the flight reuse it instead of re-walking the
+  columns.
 """
 
 from __future__ import annotations
@@ -53,7 +52,7 @@ def json_rows(r) -> list:
     native Python scalars) + a vectorized non-finite -> None mask,
     instead of a Python-level `_json_safe` call per value. Object/
     string columns keep the per-value loop (they may hold anything).
-    Memoized in the result's batch-group `encode_memo` when present."""
+    Memoized in the result's single-flight `encode_memo` when present."""
     memo = getattr(r, "encode_memo", None)
     if memo is not None:
         rows = memo.get("json_rows")
@@ -200,7 +199,7 @@ def rows_json(r) -> tuple:
     """The `"rows"` of one query result as the response carries them
     (pieces to join), counted by which writer wrote them. Which one is
     decided by the columns' dtypes alone (`columnar_rows`). Kept in the
-    result's batch-group `encode_memo` when present."""
+    result's single-flight `encode_memo` when present."""
     memo = getattr(r, "encode_memo", None)
     written = memo.get("rows_json") if memo is not None else None
     if written is None:
@@ -216,8 +215,7 @@ def rows_json(r) -> tuple:
 
 
 def encode_sql_payload(results, elapsed_ms: float) -> bytes:
-    """The full /v1/sql response body — built in one place so the pool
-    can run it off the request thread. Assembled from the memoized
+    """The full /v1/sql response body. Assembled from the memoized
     schema-header fragment + each result's `rows_json`, spelled as
     `json.dumps` of the whole document spells it (`", "`/`": "`
     separators — pinned by the tier-1 parity test)."""
@@ -309,8 +307,7 @@ def matrix_body(times: np.ndarray, vals: np.ndarray, fragments) -> bytes:
 
 
 # ---- MySQL wire fragments --------------------------------------------------
-# (moved here from servers/mysql.py so the resultset encoding can run on
-# encode-pool workers without importing the engine)
+# (the resultset encoding needs nothing of the engine)
 
 MYSQL_TYPE_VAR_STRING = 253
 
@@ -360,8 +357,8 @@ def _fmt(v) -> str:
 
 
 def memo_rows(result) -> list:
-    """`QueryResult.rows()` through the batch-group memo: coalesced
-    members materialize the Python row objects once."""
+    """`QueryResult.rows()` through the single flight's memo: coalesced
+    requests materialize the Python row objects once."""
     memo = getattr(result, "encode_memo", None)
     if memo is not None:
         rows = memo.get("rows")
@@ -374,10 +371,8 @@ def memo_rows(result) -> list:
 
 
 def encode_mysql_result(result, binary: bool = False) -> list[bytes]:
-    """Resultset packets straight from a QueryResult: the row
-    materialization (`memo_rows` — the GIL-heaviest half of MySQL
-    serialization) runs HERE, so offloading this function moves it off
-    the session thread along with the packet assembly."""
+    """Resultset packets straight from a QueryResult, its rows
+    materialized once a single flight (`memo_rows`)."""
     return encode_mysql_rows(list(result.names), memo_rows(result),
                              binary)
 

@@ -147,29 +147,6 @@ def table_to_partial(t: pa.Table) -> dict:
     return {"keys": keys, "planes": planes}
 
 
-def vmapped_part_to_wire(part: dict) -> dict:
-    """JSON-safe form of a vmapped_agg fragment result ({"members":
-    [...]} or {"vmap_ineligible": reason}). float64 round-trips exactly
-    through Python json (shortest-repr), int64 stays int, NULL keys stay
-    null — the frontend re-materializes numpy arrays."""
-    if "members" not in part:
-        return {"vmap_ineligible": str(part.get("vmap_ineligible", ""))}
-    out = []
-    for p in part["members"]:
-        if p is None:
-            out.append(None)
-            continue
-        keys = []
-        for k in p["keys"]:
-            vals = np.asarray(k, dtype=object).tolist()
-            keys.append([None if (isinstance(x, float) and x != x) else x
-                         for x in vals])
-        planes = {op: np.asarray(v).tolist()
-                  for op, v in p["planes"].items()}
-        out.append({"keys": keys, "planes": planes})
-    return {"members": out}
-
-
 def table_to_scan(t: pa.Table) -> ScanData:
     meta = t.schema.metadata or {}
     schema = Schema.from_dict(json.loads(meta[b"schema"].decode()))
@@ -463,14 +440,6 @@ class FlightServer(fl.FlightServerBase):
             if part is None:
                 table = pa.Table.from_arrays(
                     [], schema=pa.schema([], metadata={b"empty": b"1"}))
-            elif "members" in part or "vmap_ineligible" in part:
-                # vmapped_agg terminal: per-member partials (or the
-                # typed ineligibility marker) ride schema metadata
-                table = pa.Table.from_arrays([], schema=pa.schema(
-                    [], metadata={
-                        b"kind": b"vmapped",
-                        b"payload": json.dumps(
-                            vmapped_part_to_wire(part)).encode()}))
             elif "planes" in part:
                 table = partial_to_table(part)
             else:
@@ -956,9 +925,12 @@ class RemoteRegionEngine:
         md = t.schema.metadata or {}
         if md.get(b"empty") == b"1":
             return None
-        if md.get(b"kind") == b"vmapped":
-            return json.loads(md[b"payload"].decode())
-        if md.get(b"kind") == b"rows":
+        kind = md.get(b"kind")
+        if kind not in (None, b"rows"):
+            # a peer's answer, input from outside this process
+            raise ValueError(f"unknown fragment reply kind "
+                             f"{kind.decode(errors='replace')!r}")
+        if kind == b"rows":
             t = t.combine_chunks()
             cols = {}
             for i, name in enumerate(t.column_names):

@@ -119,40 +119,29 @@ class _Handler(BaseHTTPRequestHandler):
         return params
 
     def _send(self, code: int, payload, content_type="application/json"):
-        # a ShmPayload (serving fabric's zero-copy handoff) is written
-        # straight from its shared-memory view — duck-typed so this
-        # module never imports shm
         from greptimedb_tpu.utils import tracing
 
         # stages belong to a request with a root (/health, /metrics and
         # a refused login have none and stay out of the stage histogram)
         stage = tracing.stage if getattr(self, "_traceparent", None) \
             else (lambda name, **kw: contextlib.nullcontext())
-        shm_payload = None
-        if getattr(payload, "is_shm_payload", False):
-            shm_payload = payload
-            data = payload.view
-        elif isinstance(payload, bytes):
+        if isinstance(payload, bytes):
             data = payload
         else:
             with stage("encode"):
                 data = json.dumps(payload).encode()
-        try:
-            with stage("send", bytes=len(data)):
-                self.send_response(code)
-                self.send_header("Content-Type", content_type)
-                self.send_header("Content-Length", str(len(data)))
-                # W3C egress: echo the request's trace context so the
-                # caller can join its spans to ours (set per traced
-                # request in _route)
-                tp = getattr(self, "_traceparent", None)
-                if tp:
-                    self.send_header("traceparent", tp)
-                self.end_headers()
-                self.wfile.write(data)
-        finally:
-            if shm_payload is not None:
-                shm_payload.release()
+        with stage("send", bytes=len(data)):
+            self.send_response(code)
+            self.send_header("Content-Type", content_type)
+            self.send_header("Content-Length", str(len(data)))
+            # W3C egress: echo the request's trace context so the
+            # caller can join its spans to ours (set per traced
+            # request in _route)
+            tp = getattr(self, "_traceparent", None)
+            if tp:
+                self.send_header("traceparent", tp)
+            self.end_headers()
+            self.wfile.write(data)
         route = urllib.parse.urlparse(self.path).path
         HTTP_REQUESTS.inc(path=route, status=str(code))
 
@@ -477,7 +466,7 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _handle_sql(self):
         from greptimedb_tpu.servers.encode import encode_sql_payload
-        from greptimedb_tpu.utils import deadline
+        from greptimedb_tpu.utils import deadline, tracing
 
         params = self._form_or_query()
         sql = params.get("sql")
@@ -498,21 +487,10 @@ class _Handler(BaseHTTPRequestHandler):
             stop_watch()
         # the admission slot was released inside execute_sql (at
         # execute-done): serialization below never occupies an
-        # execution slot, and runs on the bounded encode pool rather
-        # than this request thread (byte-identical either way)
+        # execution slot
         elapsed = round((time.perf_counter() - t0) * 1000, 3)
-        pool = getattr(self.query_engine.concurrency, "encode", None)
-        from greptimedb_tpu.utils import tracing
-
-        # as this request thread sees it: on the pool it also waits for
-        # a worker
         with tracing.stage("encode"):
-            if pool is not None:
-                rows = sum(r.num_rows for r in results if r.is_query)
-                data = pool.run(encode_sql_payload, results, elapsed,
-                                cost_rows=rows, shm_result=True)
-            else:
-                data = encode_sql_payload(results, elapsed)
+            data = encode_sql_payload(results, elapsed)
         self._send(200, data)
 
     # ---- Prometheus API (reference http.rs:724-744) ------------------------
@@ -839,7 +817,7 @@ class _Handler(BaseHTTPRequestHandler):
 
 def _records_json(r: QueryResult) -> dict:
     # columnar encoding (timestamps stay epoch ints, like greptime's
-    # HTTP default) — shared with the encode-pool workers
+    # HTTP default)
     from greptimedb_tpu.servers.encode import records_json
 
     return records_json(r)

@@ -77,7 +77,7 @@ MYSQL_TYPE_DECIMAL = 0
 MYSQL_TYPE_NEWDECIMAL = 246
 
 
-# wire fragments shared with the encode-pool workers (servers/encode.py)
+# wire fragments and row writers live in servers/encode.py
 from greptimedb_tpu.servers.encode import (  # noqa: E402
     _coldef,
     _eof,
@@ -279,8 +279,7 @@ class _Session(socketserver.BaseRequestHandler):
                 except Exception as e:  # noqa: BLE001 — wire must stay up
                     io.send_packet(_err(1064, "42000", str(e)[:400]))
                     continue
-                _send_result(io, result, binary=True,
-                             pool=_encode_pool(server))
+                _send_result(io, result, binary=True)
                 continue
             if cmd == COM_STMT_CLOSE:
                 stmts.pop(struct.unpack("<I", body[:4])[0], None)
@@ -313,7 +312,7 @@ class _Session(socketserver.BaseRequestHandler):
             except Exception as e:  # noqa: BLE001 — wire must stay up
                 io.send_packet(_err(1064, "42000", str(e)[:400]))
                 continue
-            _send_result(io, result, pool=_encode_pool(server))
+            _send_result(io, result)
 
 
 def _dispatch(engine: QueryEngine, sql: str, ctx: QueryContext,
@@ -374,9 +373,9 @@ def _dispatch(engine: QueryEngine, sql: str, ctx: QueryContext,
             ctx.cancel_token = None
         if not res.is_query:
             return ("affected", res.affected_rows)
-        # the QueryResult itself, NOT materialized rows: row building is
-        # the GIL-heaviest half of serialization and belongs on the
-        # encode pool (encode_mysql_result), not the session thread
+        # the QueryResult itself, NOT materialized rows: the encoder
+        # builds them (encode_mysql_result), through the single
+        # flight's memo where the result carries one
         return ("result", res)
 
 
@@ -617,22 +616,11 @@ def _err(code: int, state: str, msg: str) -> bytes:
     return b"\xff" + struct.pack("<H", code) + b"#" + state.encode() + msg.encode()
 
 
-def _encode_pool(server):
-    """The engine's concurrency-plane encode pool, or None for engines
-    constructed without one (encoding then runs inline, pre-pool
-    behavior)."""
-    conc = getattr(server.query_engine, "concurrency", None)
-    return getattr(conc, "encode", None)
-
-
-def _send_result(io: _PacketIO, result, binary: bool = False,
-                 pool=None) -> None:
+def _send_result(io: _PacketIO, result, binary: bool = False) -> None:
     """Text resultset for COM_QUERY; binary-protocol rows for
     COM_STMT_EXECUTE (all columns declared VAR_STRING, so binary values
     are length-encoded strings — connectors convert from the metadata).
-    Row serialization runs on the bounded encode pool when one is
-    wired (the session thread parks on the future instead of holding
-    the GIL); the session loop only stamps sequence ids and writes."""
+    The session loop only stamps sequence ids and writes."""
     if result is None:
         io.send_packet(_ok())
         return
@@ -640,19 +628,10 @@ def _send_result(io: _PacketIO, result, binary: bool = False,
         io.send_packet(_ok(result[1]))
         return
     if result[0] == "result":
-        res = result[1]
-        if pool is not None:
-            packets = pool.run(encode_mysql_result, res, binary,
-                               cost_rows=res.num_rows)
-        else:
-            packets = encode_mysql_result(res, binary)
+        packets = encode_mysql_result(result[1], binary)
     else:
         _, names, rows = result
-        if pool is not None:
-            packets = pool.run(encode_mysql_rows, names, rows, binary,
-                               cost_rows=len(rows))
-        else:
-            packets = encode_mysql_rows(names, rows, binary)
+        packets = encode_mysql_rows(names, rows, binary)
     for p in packets:
         io.send_packet(p)
 

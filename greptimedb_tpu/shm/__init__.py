@@ -17,12 +17,6 @@ What rides the fabric:
   persistent compilation cache to one namespace under the fabric
   directory (`<fabric_dir>/xla-cache`), so process 2's first query hits
   a compiled executable instead of paying XLA compile.
-- **zero-copy result handoff** (`shm/results.py`): process-mode encode
-  workers write encoded payloads into a shared-memory arena and return
-  an offset; the socket writer sends straight from the mapping.
-- **worker metrics** (`shm/metrics_bridge.py`): encode workers publish
-  their cumulative counters through the fabric so the parent's
-  /metrics is exact, not a parent-side approximation.
 
 Configuration: `[shm]` options (`fabric`, `fabric_bytes`,
 `fabric_dir`) with `GTPU_SHM_FABRIC` / `GTPU_SHM_FABRIC_BYTES` /
@@ -57,7 +51,7 @@ _TRUE = ("1", "true", "on", "yes")
 class ShmConfig:
     #: master switch for the whole fabric plane (opt-in)
     fabric: bool = False
-    #: bytes per shared segment (artifact fabric and result arena each)
+    #: bytes of the shared segment
     fabric_bytes: int = 64 << 20
     #: directory holding the lockfiles + the shared XLA cache namespace;
     #: every process pointing at the same directory shares one fabric
@@ -73,7 +67,7 @@ def default_fabric_dir() -> str:
 
 def config_from_env() -> ShmConfig:
     """The env-twin layer (options.apply_shm writes these so spawned
-    children — encode workers, ProcessCluster datanodes — inherit)."""
+    children — ProcessCluster datanodes — inherit)."""
     cfg = ShmConfig()
     cfg.fabric = os.environ.get("GTPU_SHM_FABRIC", "").lower() in _TRUE
     raw = os.environ.get("GTPU_SHM_FABRIC_BYTES", "")
@@ -149,9 +143,6 @@ def shutdown_fabric():
             f.close()
         except OSError:
             pass
-    from greptimedb_tpu.shm import results
-
-    results.shutdown_arena()
 
 
 _stats_installed = {"done": False}

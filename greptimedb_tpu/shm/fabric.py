@@ -368,37 +368,6 @@ class Fabric:
         buf[_HDR.size:data_off] = bytes(data_off - _HDR.size)
         struct.pack_into("<Q", buf, _CURSOR_OFF, 0)
 
-    # ---- enumeration (metrics bridge) --------------------------------------
-
-    def scan(self, kind: str) -> list:
-        """Every (key, value) currently published under `kind` —
-        seqlock-consistent per slot, not across slots (cache reads)."""
-        prefix = kind.encode() + b"\x00"
-        out = []
-        with self._lock:
-            if self._closed:
-                return out
-            buf = self._shm.buf
-            (_, _, slots, data_off, data_size, _,
-             epoch0) = self._header()
-            for idx in range(slots):
-                soff = _HDR.size + idx * _SLOT.size
-                gen1, khash, klen, vlen, koff = _SLOT.unpack_from(buf,
-                                                                  soff)
-                if gen1 == 0 or gen1 % 2 == 1:
-                    continue
-                if klen > _MAX_KEY or koff + klen + vlen > data_size:
-                    continue
-                start = data_off + koff
-                blob = bytes(buf[start:start + klen + vlen])
-                gen2 = struct.unpack_from("<Q", buf, soff)[0]
-                epoch2 = struct.unpack_from("<Q", buf, _EPOCH_OFF)[0]
-                if gen2 != gen1 or epoch2 != epoch0:
-                    continue
-                if blob[:len(prefix)] == prefix:
-                    out.append((blob[len(prefix):klen], blob[klen:]))
-        return out
-
     # ---- introspection -----------------------------------------------------
 
     def stats(self) -> dict:

@@ -277,8 +277,8 @@ def wait_future(fut, where: str = ""):
 
 def wait_event(event: threading.Event, timeout_s: float,
                where: str = "") -> bool:
-    """Wait on a foreign event (admission grant, batch-leader done,
-    single-flight result) while honoring the active token: returns
+    """Wait on a foreign event (admission grant, single-flight
+    result) while honoring the active token: returns
     event.is_set() within `timeout_s`, raises typed on cancel/expiry.
     The foreign event's owner doesn't know about the token, so the wait
     re-checks every POLL_S."""

@@ -15,26 +15,7 @@ class Counter:
         self.name = name
         self.help = help_
         self._values: dict[tuple, float] = defaultdict(float)
-        # cumulative snapshots published by OTHER processes (encode-pool
-        # workers via the shm fabric), folded into every read — the
-        # cross-process twin of the per-thread shards
-        self._external: dict[str, dict] = {}
         self._lock = threading.Lock()
-
-    def set_external(self, source: str, snapshot: dict) -> None:
-        """Install a cumulative series snapshot from another process
-        (keyed by a stable source id, e.g. the worker pid); replaces
-        that source's previous snapshot — snapshots are cumulative, so
-        folding the latest one per source never double-counts."""
-        with self._lock:
-            self._external[source] = dict(snapshot)
-
-    def _fold_external_locked(self, out: dict) -> dict:
-        """Caller holds self._lock."""
-        for snap in self._external.values():
-            for k, v in snap.items():
-                out[k] = out.get(k, 0.0) + v
-        return out
 
     def inc(self, value: float = 1.0, **labels):
         key = tuple(sorted(labels.items()))
@@ -68,7 +49,7 @@ class Counter:
         """Point-in-time copy of every series (Registry sampling uses
         this so sharded subclasses can fold their shards in)."""
         with self._lock:
-            return self._fold_external_locked(dict(self._values))
+            return dict(self._values)
 
     def render(self, exemplars: bool = False) -> list[str]:
         # OpenMetrics family naming: the metric FAMILY drops the _total
@@ -148,8 +129,7 @@ class ShardedCounter(Counter):
             # a shard another thread may be appending to
             for k, v in list(cell.items()):
                 out[k] = out.get(k, 0.0) + v
-        with self._lock:
-            return self._fold_external_locked(out)
+        return out
 
 
 class Gauge(Counter):
@@ -175,7 +155,7 @@ class Histogram:
         self.name = name
         self.help = help_
         if buckets is not None:
-            # per-instance bounds for non-latency shapes (batch sizes,
+            # per-instance bounds for non-latency shapes (batch rows,
             # byte counts) — the default decade grid is seconds-tuned
             self.BUCKETS = tuple(sorted(buckets))
         self._buckets: dict[tuple, list[int]] = {}
@@ -186,44 +166,7 @@ class Histogram:
         # gtpu_query_stage_seconds bucket links to a trace to pull)
         self._exemplars_on = exemplars
         self._exemplar: dict[tuple, tuple] = {}
-        # cumulative (buckets, sum, count) snapshots published by other
-        # processes (encode-pool workers via the shm fabric); folded
-        # into every read so worker-side observations are exact in the
-        # parent's /metrics instead of parent-side approximations
-        self._external: dict[str, dict] = {}
         self._lock = threading.Lock()
-
-    def set_external(self, source: str, state: dict) -> None:
-        """Install another process's cumulative series state (the shape
-        `export_state` returns). Replaces that source's previous
-        snapshot, so cumulative republishing never double-counts."""
-        with self._lock:
-            self._external[source] = state
-
-    def export_state(self) -> dict:
-        """This process's cumulative series, keyed for set_external:
-        {label-key: ([bucket counts], sum, count)}."""
-        with self._lock:
-            return {key: (list(b), self._sum[key], self._count[key])
-                    for key, b in self._buckets.items()}
-
-    def _merged_locked(self):
-        """Local series with every external snapshot folded in —
-        caller holds self._lock."""
-        buckets = {key: list(b) for key, b in self._buckets.items()}
-        sums = dict(self._sum)
-        counts = dict(self._count)
-        for state in self._external.values():
-            for key, (b, s, c) in state.items():
-                if len(b) != len(self.BUCKETS) + 1:
-                    continue  # bucket-grid drift across versions: skip
-                if key in buckets:
-                    buckets[key] = [x + y for x, y in zip(buckets[key], b)]
-                else:
-                    buckets[key] = list(b)
-                sums[key] = sums.get(key, 0.0) + s
-                counts[key] = counts.get(key, 0) + c
-        return buckets, sums, counts
 
     def observe(self, value: float, **labels):
         tid = None
@@ -257,36 +200,33 @@ class Histogram:
         """Total of observed values for one label set (benches read the
         execute/encode wall-time split from here)."""
         with self._lock:
-            _, sums, _ = self._merged_locked()
-        return sums.get(tuple(sorted(labels.items())), 0.0)
+            return self._sum.get(tuple(sorted(labels.items())), 0.0)
 
     def count(self, **labels) -> int:
         with self._lock:
-            _, _, counts = self._merged_locked()
-        return counts.get(tuple(sorted(labels.items())), 0)
+            return self._count.get(tuple(sorted(labels.items())), 0)
 
     def total_count(self, **labels) -> int:
         """Observation count summed over every series whose labels are
         a superset of the given ones (Counter.total's analog)."""
         want = set(labels.items())
         with self._lock:
-            _, _, counts = self._merged_locked()
-        return sum(c for key, c in counts.items() if want <= set(key))
+            return sum(c for key, c in self._count.items()
+                       if want <= set(key))
 
     def total_sum(self, **labels) -> float:
         """Observed-value total over matching series (see total_count)."""
         want = set(labels.items())
         with self._lock:
-            _, sums, _ = self._merged_locked()
-        return sum(s for key, s in sums.items() if want <= set(key))
+            return sum(s for key, s in self._sum.items()
+                       if want <= set(key))
 
     def render(self, exemplars: bool = False) -> list[str]:
         out = [f"# HELP {self.name} {self.help}", f"# TYPE {self.name} histogram"]
         with self._lock:
-            buckets, sums, counts = self._merged_locked()
             snapshot = sorted(
-                (key, b, sums[key], counts[key])
-                for key, b in buckets.items()
+                (key, list(b), self._sum[key], self._count[key])
+                for key, b in self._buckets.items()
             )
             ex = dict(self._exemplar) if exemplars else {}
         for key, b, _sum, _count in snapshot:
@@ -415,8 +355,8 @@ class Registry:
         for m in metrics:
             if isinstance(m, Histogram):
                 with m._lock:
-                    _, sums, counts = m._merged_locked()
-                items = [(key, sums[key], counts[key]) for key in counts]
+                    items = [(key, m._sum[key], c)
+                             for key, c in m._count.items()]
                 for key, s, c in items:
                     yield m.name + "_sum", s, key
                     yield m.name + "_count", c, key
@@ -619,8 +559,7 @@ SQL_ENCODED_ROWS = REGISTRY.counter(
     "(columnar = from the result's columns to bytes in arrow's kernels "
     "with no Python object a value; values = the whole result set a value "
     "at a time through json_rows + json.dumps, because a column is neither "
-    "numeric nor str / None). Rows written in an encode-pool worker "
-    "process reach this registry through the shm fabric's metrics bridge")
+    "numeric nor str / None)")
 DEVICE_HOT_SET_EVENTS = REGISTRY.counter(
     "greptimedb_tpu_device_hot_set_events_total",
     "HBM-resident columnar hot set events by kind (hit/miss/evict/pin — "
@@ -649,8 +588,7 @@ SPARSE_DISPATCHES = REGISTRY.counter(
     "Sparse sort-compact aggregation dispatches by path (classic = "
     "whole-scan XLA segment reduce, fused = tiled Pallas windows, "
     "sharded = per-shard compaction + gid-space combine, incremental = "
-    "per-part value-space partials, vmapped = shared compaction across "
-    "stacked batch members)")
+    "per-part value-space partials)")
 SPARSE_COMPACTION_RATIO = REGISTRY.gauge(
     "greptimedb_tpu_sparse_compaction_ratio",
     "Observed groups per scanned row in the last sparse aggregation "
@@ -778,7 +716,7 @@ WRITE_STALL_TIMEOUTS = REGISTRY.counter(
     "Stalls that hit stall_timeout_s and fell back to an inline flush "
     "(the maintenance plane is wedged or saturated)")
 # frontend concurrency plane (concurrency/ package): the shape-keyed
-# plan cache, admission control, and cross-query batching that carry
+# plan cache, admission control, and the fast lane that carry
 # fleet-scale dashboard traffic (ISSUE 6) — hit rates and rejection
 # behavior are asserted from these series, not eyeballed
 PLAN_CACHE_EVENTS = REGISTRY.sharded_counter(
@@ -799,43 +737,16 @@ ADMISSION_WAIT_SECONDS = REGISTRY.histogram(
     "greptimedb_tpu_admission_wait_seconds",
     "Time queued statements waited for an execution slot",
     exemplars=True)
-QUERY_BATCH_EVENTS = REGISTRY.sharded_counter(
-    "greptimedb_tpu_query_batch_events_total",
-    "Cross-query batching events by kind (join/coalesced/vmapped/"
-    "stacked/serial_fallback — coalesced, vmapped, and stacked members "
-    "skipped their own device dispatch; vmapped_failed marks the "
-    "runtime latch that degrades to the fallbacks)")
-QUERY_BATCH_SIZE = REGISTRY.histogram(
-    "greptimedb_tpu_query_batch_size",
-    "Queries served per batch group (leader + members)", exemplars=True)
-VMAP_BATCH_WIDTH = REGISTRY.histogram(
-    "greptimedb_tpu_query_vmap_batch_width",
-    "Distinct parameter-sibling queries executed per vmapped multi-"
-    "query dispatch (the stacked member axis M)",
-    buckets=(2, 4, 8, 16, 32, 64, 128), exemplars=True)
-ENCODE_POOL_EVENTS = REGISTRY.sharded_counter(
-    "greptimedb_tpu_encode_pool_events_total",
-    "Result-encode pool decisions by kind (offload = serialized on a "
-    "pool worker, inline = pool saturated, small_inline = result "
-    "under encode_min_rows; inline encodes run on the request thread)")
-ENCODE_POOL_QUEUE_DEPTH = REGISTRY.gauge(
-    "greptimedb_tpu_encode_pool_queue_depth",
-    "Result serializations queued or running in the bounded encode "
-    "pool")
 ENCODE_SECONDS = REGISTRY.histogram(
     "greptimedb_tpu_encode_seconds",
     "Wall time serializing one query result to its wire format "
     "(HTTP JSON / MySQL packets), by protocol — compare against "
-    "query_duration_seconds for the execute-vs-encode split; "
-    "protocol=process series are measured inside the spawn-mode encode "
-    "workers and folded in through the shm fabric metrics bridge, so "
-    "they are exact worker wall time, not a parent-side round trip",
+    "query_duration_seconds for the execute-vs-encode split",
     exemplars=True)
 
 # cross-process serving fabric (greptimedb_tpu/shm/): the shared-memory
 # artifact plane N frontend processes on one box attach to — fast-lane
-# templates, plan-cache entries, warm XLA shape keys, zero-copy result
-# handoff, and the worker->parent metrics bridge all ride it
+# templates, plan-cache entries and warm XLA shape keys ride it
 SHM_FABRIC_EVENTS = REGISTRY.sharded_counter(
     "greptimedb_tpu_shm_fabric_events_total",
     "Serving-fabric events by kind (hit = an artifact adopted from a "
@@ -844,13 +755,12 @@ SHM_FABRIC_EVENTS = REGISTRY.sharded_counter(
     "bump or wipe fanned out to peers, corrupt = a slot failed its "
     "generation/bounds check, detach = this process fell back to the "
     "private in-process lane; the kind label names the artifact plane: "
-    "template/plan/result/metrics/fabric)")
+    "template/plan/fabric)")
 SHM_FABRIC_BYTES = REGISTRY.gauge(
     "greptimedb_tpu_shm_fabric_bytes",
-    "Bytes of the attached shared-memory fabric by segment "
-    "(fabric = the artifact plane, arena = the zero-copy result "
-    "arena) and dimension (size = mapped capacity, used = heap bytes "
-    "behind the current write cursor)")
+    "Bytes of the attached shared-memory fabric (segment = fabric, "
+    "the artifact plane) by dimension (size = mapped capacity, used = "
+    "heap bytes behind the current write cursor)")
 
 # parse-free serving fast lane (concurrency/fast_lane.py, ISSUE 14): a
 # text-keyed template cache in front of the plan cache — a repeat-shape
@@ -894,7 +804,7 @@ STAGE_CPU_SECONDS = REGISTRY.sharded_counter(
     "compile) counts as CPU. One more label, background: the CPU of "
     "threads that work for a request beside its own (everything run "
     "under tracing.propagate, bg:<stage> spans included: scan pool, "
-    "part workers, the hedge's warm-up, encode-pool threads): they take "
+    "part workers, the hedge's warm-up): they take "
     "the same lock; their wall time counts nowhere")
 INGEST_REQUEST_CPU_SECONDS = REGISTRY.histogram(
     "greptimedb_tpu_ingest_request_cpu_seconds",
@@ -951,7 +861,7 @@ MESH_SHARD_SKEW = REGISTRY.gauge(
 FRAGMENT_PUSHDOWNS = REGISTRY.counter(
     "greptimedb_tpu_fragment_pushdown_total",
     "Distributed plan fragments shipped to region owners, by mode "
-    "(agg/topk/rows/rows_agg/window/lastpoint/rollup/vmapped — partial "
+    "(agg/topk/rows/rows_agg/window/lastpoint/rollup — partial "
     "planes or pruned candidates return, never raw region scans)")
 REGION_ROUTE = REGISTRY.counter(
     "greptimedb_tpu_region_route_total",

@@ -1,5 +1,5 @@
-"""Cluster-mode rollup substitution, lastpoint pruning, vmapped member
-batches, and partition scatter (ISSUE 12): the distributed frontend must
+"""Cluster-mode rollup substitution, lastpoint pruning, and partition
+scatter (ISSUE 12): the distributed frontend must
 ship partial-aggregate planes — never raw rows — and return bit-for-bit
 what the raw path returns."""
 
@@ -299,110 +299,4 @@ class TestPartitionScatter:
         qe = QueryEngine(Catalog(MemoryKv()), engine)
         qe.execute_one(CREATE)
         assert len(qe.catalog.table("public", "cpu").region_ids) == 1
-        engine.close()
-
-
-class TestVmappedFragments:
-    # the selector tag must stay OUT of the projection/group keys (the
-    # batcher's shape contract); members differ in host + window
-    DASH = ("SELECT date_bin(INTERVAL '1 minute', ts) AS minute, max(v), "
-            "sum(v), count(v) FROM cpu WHERE host = '{h}' AND "
-            "ts >= {lo} AND ts < {hi} GROUP BY minute")
-
-    def _group(self, qe, sqls):
-        from greptimedb_tpu.concurrency import batcher as batcher_mod
-        from greptimedb_tpu.query.engine import QueryContext
-        from greptimedb_tpu.sql.parser import parse_sql
-
-        ctx = QueryContext()
-        info = qe._table("cpu", ctx)
-        shapes = []
-        for sql in sqls:
-            sel = parse_sql(sql)[0]
-            sh = batcher_mod.analyze(sel, info)
-            assert sh is not None, sql
-            shapes.append((sel, sh))
-        assert len({sh.masked for _, sh in shapes}) == 1
-        order = []
-        for _, sh in shapes:
-            if sh.values not in order:
-                order.append(sh.values)
-        return info, shapes[0][0], shapes[0][1], order
-
-    def test_multi_region_members_ride_fragments(self, tmp_path):
-        """Cluster frontends used to decline vmapped batches (IN-list/
-        serial fallback); members must now execute as one vmapped_agg
-        fragment per region, bit-for-bit with serial."""
-        from greptimedb_tpu.query.vmapped import run_vmapped
-
-        c = make_cluster(tmp_path)
-        c.create_partitioned_table(CREATE, host_rule("host2", "host4"))
-        seed_minutes(c)
-        qe = c.frontend
-        sqls = [self.DASH.format(h=f"host{i % 6}",
-                                 lo=(i % 2) * 30_000,
-                                 hi=90_000 + (i % 2) * 30_000)
-                for i in range(8)]
-        info, leader, shape, order = self._group(qe, sqls)
-        results = run_vmapped(qe.executor, leader, info, shape.params,
-                              order)
-        assert qe.executor.last_path == "vmapped_fragments"
-        for sql in sqls:
-            vals = self._values_of(qe, sql)
-            got = results[order.index(vals)]
-            # serial oracle through the same cluster frontend
-            with qe.concurrency.suppress_batching():
-                want = qe.execute_one(sql)
-            assert got.names == want.names
-            assert got.rows() == want.rows(), sql
-        c.close()
-
-    def _values_of(self, qe, sql):
-        from greptimedb_tpu.concurrency import batcher as batcher_mod
-        from greptimedb_tpu.query.engine import QueryContext
-        from greptimedb_tpu.sql.parser import parse_sql
-
-        info = qe._table("cpu", QueryContext())
-        return batcher_mod.analyze(parse_sql(sql)[0], info).values
-
-    def test_vmapped_first_last_members(self, tmp_path):
-        """Satellite: first/last ride the stacked axis (single region,
-        ts-paired combine) — lastpoint-class dashboards batch too."""
-        from greptimedb_tpu.catalog import Catalog, MemoryKv
-        from greptimedb_tpu.query import QueryEngine
-        from greptimedb_tpu.query.vmapped import run_vmapped
-        from greptimedb_tpu.storage import RegionEngine
-        from greptimedb_tpu.storage.engine import EngineConfig
-
-        engine = RegionEngine(EngineConfig(data_dir=str(tmp_path / "d"),
-                                           maintenance_workers=0))
-        qe = QueryEngine(Catalog(MemoryKv()), engine)
-        qe.execute_one(CREATE)
-        rng = np.random.default_rng(9)
-        for gen in range(2):  # two SSTs + memtable tail
-            rows = [f"('host{h}', {int(rng.integers(0, 100))}, "
-                    f"{(gen * 50 + i) * 1000})"
-                    for h in range(4) for i in range(50)]
-            qe.execute_one("INSERT INTO cpu (host, v, ts) VALUES "
-                           + ",".join(rows))
-            qe.execute_one("ADMIN flush_table('cpu')")
-        qe.execute_one("INSERT INTO cpu (host, v, ts) VALUES "
-                       "('host0', 777, 200000)")
-        sql = ("SELECT date_bin(INTERVAL '30 seconds', ts) AS b, "
-               "first(v), last(v) FROM cpu "
-               "WHERE host = '{h}' AND ts >= {lo} AND ts < {hi} "
-               "GROUP BY b")
-        sqls = [sql.format(h=f"host{i % 4}", lo=(i % 2) * 20_000,
-                           hi=80_000 + (i % 2) * 60_000 + 70_000)
-                for i in range(6)]
-        info, leader, shape, order = self._group(qe, sqls)
-        results = run_vmapped(qe.executor, leader, info, shape.params,
-                              order)
-        assert qe.executor.last_path == "dense_vmapped"
-        for sql_i, vals in zip(sqls, [self._values_of(qe, s)
-                                      for s in sqls]):
-            got = results[order.index(vals)]
-            with qe.concurrency.suppress_batching():
-                want = qe.execute_one(sql_i)
-            assert got.rows() == want.rows(), sql_i
         engine.close()

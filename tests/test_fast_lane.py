@@ -479,10 +479,9 @@ class TestByteIdentity:
         sqls = [DASH.format(host=f"h{h}", lo=lo, hi=lo + 60_000)
                 for h in range(4) for lo in (0, 10_000, 20_000)]
         oracle = {}
-        with qe.concurrency.suppress_batching():
-            for s in sqls:
-                r = qe._execute_sql_slow(s, QueryContext())[-1]
-                oracle[s] = (list(r.names), r.rows())
+        for s in sqls:
+            r = qe._execute_sql_slow(s, QueryContext())[-1]
+            oracle[s] = (list(r.names), r.rows())
         srv = HttpServer(qe, host="127.0.0.1", port=0)
         errors = []
         try:

@@ -2,13 +2,10 @@
 shape-keyed parameterized plan cache and its invalidation under DDL and
 rollup-state changes, bounded admission with per-tenant weighted fair
 scheduling (a flooding tenant cannot starve a light one), typed
-Overloaded rejection through the HTTP/MySQL error mapping, and the
-cross-query batcher's bit-for-bit parity with serial execution — the
-tier-1 concurrency smoke drives threaded clients through the full
-frontend path (HTTP server -> admission -> plan cache -> batcher ->
-device execution)."""
+Overloaded rejection through the HTTP/MySQL error mapping. (Threaded
+clients through the full frontend path, bit-for-bit with serial:
+tests/test_serving_path.py.)"""
 
-import json
 import threading
 import time
 import urllib.error
@@ -30,12 +27,10 @@ from greptimedb_tpu.concurrency.admission import (
     parse_weights,
 )
 from greptimedb_tpu.query.engine import QueryEngine
-from greptimedb_tpu.session import QueryContext
 from greptimedb_tpu.storage.engine import EngineConfig, RegionEngine
 from greptimedb_tpu.utils.metrics import (
     ADMISSION_EVENTS,
     PLAN_CACHE_EVENTS,
-    QUERY_BATCH_EVENTS,
 )
 
 
@@ -135,8 +130,7 @@ class TestPlanCache:
         engine.close()
 
     def test_capacity_eviction(self, tmp_path):
-        plane = ConcurrencyPlane(ConcurrencyConfig(plan_cache_entries=2,
-                                                   batching=False))
+        plane = ConcurrencyPlane(ConcurrencyConfig(plan_cache_entries=2))
         engine, qe = make_qe(tmp_path, plane=plane)
         create_cpu(qe)
         ingest(qe, hosts=2, points=10)
@@ -271,25 +265,55 @@ class TestAdmission:
             run_threads([blocked])
 
     def test_queue_timeout_rejects_typed(self):
-        ac = AdmissionController(1, queue_size=4, queue_timeout_s=0.05)
-        release = threading.Event()
+        """The queued request is rejected Overloaded after its timeout
+        and before the holder releases. Asserted by the order of
+        events: the holder holds until the rejection has been seen,
+        however long a loaded machine takes to get there; the one
+        clock read is a lower bound, which load cannot break."""
+        timeout_s = 0.05
+        ac = AdmissionController(1, queue_size=4,
+                                 queue_timeout_s=timeout_s)
+        held, release = threading.Event(), threading.Event()
+        events = []
 
         def holder():
             with ac.slot("t"):
-                release.wait(10)
+                events.append("held")
+                held.set()
+                release.wait()
+            events.append("released")
 
         t = threading.Thread(target=holder)
         t.start()
-        while ac.active == 0:
-            time.sleep(0.001)
-        rej0 = ADMISSION_EVENTS.get(event="reject_timeout", tenant="t")
-        with pytest.raises(Overloaded):
-            with ac.slot("t"):
-                pass
-        assert ADMISSION_EVENTS.get(event="reject_timeout", tenant="t") \
-            > rej0
-        release.set()
-        t.join(10)
+        try:
+            assert held.wait(120)
+            before = {e: ADMISSION_EVENTS.get(event=e, tenant="t")
+                      for e in ("queue", "reject_timeout")}
+            t0 = time.perf_counter()
+            with pytest.raises(Overloaded):
+                with ac.slot("t"):
+                    events.append("admitted")
+            waited = time.perf_counter() - t0
+            events.append("rejected")
+            # rejected while the holder still held: the slot is taken,
+            # the queue has let the waiter go
+            assert ac.active == 1 and ac.queued == 0
+            assert not release.is_set()
+        finally:
+            release.set()
+            t.join(120)
+        assert events == ["held", "rejected", "released"]
+        assert waited >= timeout_s
+        for e in ("queue", "reject_timeout"):
+            assert ADMISSION_EVENTS.get(event=e, tenant="t") \
+                == before[e] + 1, e
+
+    def test_env_kill_switch_disables_the_plane(self, monkeypatch):
+        monkeypatch.setenv("GTPU_CONCURRENCY", "0")
+        plane = ConcurrencyPlane()
+        assert not plane.admission.enabled
+        assert not plane.plan_cache.enabled
+        assert not plane.fast_lane.enabled
 
     def test_nested_statements_ride_the_outer_slot(self):
         ac = AdmissionController(1, queue_size=0)
@@ -350,7 +374,7 @@ class TestAdmission:
 
     def test_engine_overload_raises_typed(self, tmp_path):
         plane = ConcurrencyPlane(ConcurrencyConfig(
-            max_concurrency=1, queue_size=0, batching=False))
+            max_concurrency=1, queue_size=0))
         engine, qe = make_qe(tmp_path, plane=plane)
         create_cpu(qe)
         ingest(qe, hosts=2, points=10)
@@ -379,7 +403,7 @@ class TestAdmission:
         from greptimedb_tpu.servers.http import HttpServer
 
         plane = ConcurrencyPlane(ConcurrencyConfig(
-            max_concurrency=1, queue_size=0, batching=False))
+            max_concurrency=1, queue_size=0))
         engine, qe = make_qe(tmp_path, plane=plane)
         create_cpu(qe)
         ingest(qe, hosts=2, points=10)
@@ -412,176 +436,3 @@ class TestAdmission:
         finally:
             srv.stop()
         engine.close()
-
-
-# ---- cross-query batching ---------------------------------------------------
-
-
-class BatchPlane(ConcurrencyPlane):
-    """A plane whose batcher treats every caller as busy and uses a wide
-    window, so a threaded test reliably forms groups without depending
-    on scheduler timing."""
-
-    def __init__(self, window_ms=60.0, **kw):
-        # the batcher is the layer under test: the parse-free fast lane
-        # (which would otherwise serve these repeats before batching)
-        # has its own suite in test_fast_lane.py
-        kw.setdefault("fast_lane", False)
-        super().__init__(ConcurrencyConfig(batch_window_ms=window_ms, **kw))
-
-    def execute_select(self, qe, sel, info, ctx):
-        if (not self.batcher.enabled or self.admission.depth() != 1
-                or getattr(self._tls, "no_batch", False)):
-            return qe._select_table(sel, info, ctx)
-        return self.batcher.execute(qe, sel, info, ctx, busy=True)
-
-
-class TestCrossQueryBatching:
-    def _oracle(self, tmp_path, sqls, plane=None):
-        """Serial ground truth + a batching engine over the same data."""
-        engine, qe = make_qe(tmp_path, plane=plane or BatchPlane())
-        create_cpu(qe)
-        ingest(qe)
-        serial = {}
-        with qe.concurrency.suppress_batching():
-            for sql in set(sqls):
-                r = qe.execute_one(sql)
-                serial[sql] = (r.names, r.rows())
-        return engine, qe, serial
-
-    def assert_parity(self, qe, sqls, serial, min_group=2):
-        joined0 = QUERY_BATCH_EVENTS.get(event="join")
-        got = run_threads(
-            [lambda s=s: qe.execute_one(s) for s in sqls])
-        for sql, res in zip(sqls, got):
-            names, rows = serial[sql]
-            assert res.names == names, sql
-            assert res.rows() == rows, sql
-        return QUERY_BATCH_EVENTS.get(event="join") - joined0
-
-    def test_identical_statements_coalesce_bit_for_bit(self, tmp_path):
-        sql = DASH_SQL.format(host="h1", lo=0, hi=120_000)
-        sqls = [sql] * 12
-        engine, qe, serial = self._oracle(tmp_path, sqls)
-        co0 = QUERY_BATCH_EVENTS.get(event="coalesced")
-        self.assert_parity(qe, sqls, serial)
-        assert QUERY_BATCH_EVENTS.get(event="coalesced") > co0
-        engine.close()
-
-    def test_stacked_dispatch_bit_for_bit(self, tmp_path):
-        """Members differing only in the selector tag value execute as
-        ONE batched dispatch — the vmap'd stacked-parameter kernel, or
-        the IN-list rewrite when it declines; each member's slice must
-        equal its serial run exactly (values AND row order)."""
-        sqls = [DASH_SQL.format(host=f"h{i % 4}", lo=0, hi=120_000)
-                for i in range(16)]
-        engine, qe, serial = self._oracle(tmp_path, sqls)
-        st0 = (QUERY_BATCH_EVENTS.get(event="stacked")
-               + QUERY_BATCH_EVENTS.get(event="vmapped"))
-        self.assert_parity(qe, sqls, serial)
-        assert (QUERY_BATCH_EVENTS.get(event="stacked")
-                + QUERY_BATCH_EVENTS.get(event="vmapped")) > st0
-        engine.close()
-
-    def test_mixed_shapes_do_not_cross_batch(self, tmp_path):
-        """Different shapes (different agg set / bucket / table-less)
-        form separate groups — and every result is still exact."""
-        sqls = ([DASH_SQL.format(host="h0", lo=0, hi=120_000)] * 3
-                + [DASH_SQL.format(host="h2", lo=0, hi=120_000)] * 3
-                + ["SELECT host, min(v) FROM cpu WHERE ts >= 0 AND "
-                   "ts < 120000 GROUP BY host ORDER BY host"] * 3
-                + ["SELECT count(*) FROM cpu WHERE ts >= 60000"] * 3)
-        engine, qe, serial = self._oracle(tmp_path, sqls)
-        self.assert_parity(qe, sqls, serial)
-        engine.close()
-
-    def test_leader_error_propagates_to_members(self, tmp_path):
-        sql = "SELECT max(v) FROM cpu WHERE host = 'h0' GROUP BY host"
-        engine, qe, _ = self._oracle(tmp_path, [sql])
-
-        orig = qe._select_table
-        calls = []
-
-        def boom(sel, info, ctx):
-            calls.append(1)
-            raise RuntimeError("device fell over")
-
-        qe._select_table = boom
-        errors = []
-
-        def one():
-            try:
-                qe.execute_one(sql)
-            except RuntimeError as e:
-                errors.append(e)
-
-        ts = [threading.Thread(target=one) for _ in range(6)]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(30)
-        qe._select_table = orig
-        assert len(errors) == 6
-        # at least one member rode the leader's (failed) execution
-        assert len(calls) < 6
-        engine.close()
-
-    def test_http_threaded_smoke_bit_for_bit(self, tmp_path):
-        """The tier-1 concurrency smoke: threaded keep-alive HTTP
-        clients through the FULL frontend path; every response's result
-        payload must be bit-for-bit identical to the idle-server
-        response for the same SQL (only the timing field may differ)."""
-        import http.client
-
-        from greptimedb_tpu.servers.http import HttpServer
-
-        engine, qe = make_qe(tmp_path, plane=BatchPlane(window_ms=20.0))
-        create_cpu(qe)
-        ingest(qe)
-        srv = HttpServer(qe, port=0)
-        try:
-            port = srv.start()
-
-            def fetch(sql, tenant):
-                conn = http.client.HTTPConnection("127.0.0.1", port,
-                                                  timeout=60)
-                try:
-                    body = urllib.parse.urlencode({"sql": sql}).encode()
-                    conn.request(
-                        "POST", "/v1/sql", body=body,
-                        headers={"Content-Type":
-                                 "application/x-www-form-urlencoded",
-                                 "X-Greptime-Tenant": tenant})
-                    resp = conn.getresponse()
-                    data = resp.read()
-                    assert resp.status == 200, data[:200]
-                    payload = json.loads(data)
-                    payload.pop("execution_time_ms", None)
-                    return json.dumps(payload, sort_keys=True)
-                finally:
-                    conn.close()
-
-            sqls = [DASH_SQL.format(host=f"h{i % 4}", lo=0, hi=120_000)
-                    for i in range(8)]
-            sqls += [sqls[0], sqls[1]] * 2  # identical duplicates too
-            serial = {sql: fetch(sql, "warm") for sql in set(sqls)}
-            for body in serial.values():
-                assert json.loads(body)["output"]  # real rows came back
-            got = run_threads(
-                [lambda s=s, i=i: fetch(s, f"tenant{i % 3}")
-                 for i, s in enumerate(sqls)])
-            for sql, body in zip(sqls, got):
-                assert body == serial[sql], sql
-        finally:
-            srv.stop()
-        engine.close()
-
-    def test_env_kill_switch_disables_batching(self, tmp_path,
-                                               monkeypatch):
-        monkeypatch.setenv("GTPU_QUERY_BATCHING", "0")
-        plane = ConcurrencyPlane()
-        assert not plane.batcher.enabled
-        monkeypatch.setenv("GTPU_CONCURRENCY", "0")
-        plane = ConcurrencyPlane()
-        assert not plane.admission.enabled
-        assert not plane.plan_cache.enabled

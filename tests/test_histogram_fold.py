@@ -200,8 +200,7 @@ def _spans():
 
 def _observations() -> dict:
     """Observations of the fold's histogram so far, by phase."""
-    state = PROMQL_HISTOGRAM_FOLD_SECONDS.export_state()
-    return {phase: state.get((("phase", phase),), (None, 0.0, 0))[2]
+    return {phase: PROMQL_HISTOGRAM_FOLD_SECONDS.count(phase=phase)
             for phase in ("index", "dispatch")}
 
 

@@ -347,7 +347,6 @@ def test_remote_write_creates_logical_tables_that_survive_a_reopen(tmp_path):
             [({"__name__": "node_load1", "instance": "n0"},
               [(9.0, 0)])])) == 1
     finally:
-        db.concurrency.shutdown()
         engine.close()
     engine, db = open_db()
     try:
@@ -364,5 +363,4 @@ def test_remote_write_creates_logical_tables_that_survive_a_reopen(tmp_path):
             "ORDER BY instance").rows() \
             == [["n0", 3.0], ["n1", 13.0], ["n2", 23.0]]
     finally:
-        db.concurrency.shutdown()
         engine.close()

@@ -107,7 +107,6 @@ def db(tmp_path_factory):
     _put(qe, "m_4r", points, series)
     _flush(qe, engine, "m_4r")
     yield qe
-    qe.concurrency.shutdown()
     engine.close()
 
 

@@ -86,7 +86,6 @@ def db(tmp_path_factory):
     engine.flush(rid1)
     bulk.wait_flushed(engine)
     yield qe, ds, one, region_of
-    qe.concurrency.shutdown()
     engine.close()
 
 

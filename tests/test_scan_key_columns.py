@@ -87,7 +87,6 @@ def db(tmp_path_factory):
         create(qe, name, append, parts)
         fill(qe, name)
     yield qe
-    qe.concurrency.shutdown()
     engine.close()
 
 
@@ -358,7 +357,6 @@ def lww_db(tmp_path_factory):
     insert(qe, "t", [("h00", "dc0", "r0", 500.0, 0.0, 5000)])
     qe.execute_one("DELETE FROM t WHERE host = 'h01' AND ts = 7000")
     yield qe
-    qe.concurrency.shutdown()
     engine.close()
 
 

@@ -1,9 +1,8 @@
 """Cross-process serving fabric (greptimedb_tpu/shm/, ISSUE 19): the
-shared-memory artifact plane, the result arena, peer adoption through
-the fast lane and plan cache, peer-DDL invalidation, SIGKILL-mid-publish
-crash safety, attach refusal, the worker-metrics bridge, the merged
-cross-process lock graph, and the byte-identity contract with the
-fabric on vs off."""
+shared-memory artifact plane, peer adoption through the fast lane and
+plan cache, peer-DDL invalidation, SIGKILL-mid-publish crash safety,
+attach refusal, the merged cross-process lock graph, and the
+byte-identity contract with the fabric on vs off."""
 
 import glob
 import json
@@ -60,7 +59,7 @@ def fabric_dir(tmp_path):
     """A private fabric directory whose segments provably do not
     outlive the test (the tier-1 leak check)."""
     d = str(tmp_path / "fabric")
-    names = [segment_name(d), segment_name(os.path.join(d, "arena"))]
+    names = [segment_name(d)]
     yield d
     from greptimedb_tpu import shm
 
@@ -458,100 +457,6 @@ class TestByteIdentityFabric:
             ms.shutdown()
             ps.shutdown()
             ef.close()
-
-
-# ---- result arena ----------------------------------------------------------
-
-
-class TestResultArena:
-    def test_publish_claim_roundtrip_and_free(self, fabric_dir):
-        from greptimedb_tpu.shm.results import ResultArena
-
-        arena = ResultArena(fabric_dir, size=2 << 20)
-        try:
-            data = b"HTTP payload bytes" * 100
-            handle = arena.publish(data)
-            assert handle is not None
-            payload = arena.claim(handle)
-            assert payload is not None
-            assert bytes(payload) == data
-            assert len(payload) == len(data)
-            payload.release()
-            # the freed block is reusable
-            assert arena.publish(b"second") is not None
-        finally:
-            arena.close()
-
-    def test_claim_failure_falls_back_to_reencode(self, fabric_dir,
-                                                  fabric_env):
-        from greptimedb_tpu.shm import results
-
-        arena = results.get_arena()
-        assert arena is not None
-        handle = arena.publish(b"the-bytes")
-        assert handle is not None
-        # wreck the handle's pid so the claim dies (publisher "gone",
-        # block reaped): resolve must re-encode inline, byte-identical
-        mark, idx, off, ln, _pid = handle
-        dead = (mark, idx, off, ln, 2 ** 22 + 12345)
-        out = results.resolve(dead, lambda: b"the-bytes", ())
-        assert bytes(out) == b"the-bytes" if not isinstance(out, bytes) \
-            else out == b"the-bytes"
-
-    def test_shm_encode_times_worker_exactly(self, fabric_env):
-        from greptimedb_tpu.shm import results
-        from greptimedb_tpu.utils.metrics import ENCODE_SECONDS
-
-        c0 = ENCODE_SECONDS.total_count(protocol="process")
-        out = results.shm_encode(lambda: b"abc" * 10, )
-        assert ENCODE_SECONDS.total_count(protocol="process") == c0 + 1
-        resolved = results.resolve(out, lambda: b"abc" * 10, ())
-        assert bytes(resolved) == b"abc" * 10
-        if hasattr(resolved, "release"):
-            resolved.release()
-
-    def test_non_bytes_results_pass_through(self, fabric_env):
-        from greptimedb_tpu.shm import results
-
-        # MySQL encoders return packet LISTS: those never ride the
-        # arena, they fall through to the pickle path untouched
-        out = results.shm_encode(lambda: [b"pkt1", b"pkt2"])
-        assert out == [b"pkt1", b"pkt2"]
-
-
-# ---- worker metrics bridge -------------------------------------------------
-
-
-class TestMetricsBridge:
-    def test_worker_snapshot_folds_into_parent_scrape(self, fabric_env):
-        from greptimedb_tpu import shm
-        from greptimedb_tpu.shm import metrics_bridge
-        from greptimedb_tpu.utils.metrics import ENCODE_SECONDS
-
-        fabric = shm.get_fabric()
-        assert fabric is not None
-        # forge a snapshot under a dead peer pid (collect skips our own)
-        state = {
-            "hist": {"greptimedb_tpu_encode_seconds": {
-                "series": [[[["protocol", "process"]],
-                            {"count": 7, "sum": 1.25,
-                             "buckets": {}}]]}},
-            "counter": {},
-        }
-        hist_state = ENCODE_SECONDS.export_state()
-        # use the real exporter's shape for one series instead of a
-        # hand-rolled guess, scaled to a recognizable count
-        fabric.put("met", b"999999", pickle.dumps(
-            {"hist": {"greptimedb_tpu_encode_seconds": hist_state},
-             "counter": {}}))
-        before = ENCODE_SECONDS.total_count(protocol="process")
-        ENCODE_SECONDS.observe(0.001, protocol="process")
-        metrics_bridge.collect_worker_metrics()
-        after = ENCODE_SECONDS.total_count(protocol="process")
-        # the forged worker snapshot folds in as an external source:
-        # the merged count grows by at least our own +1
-        assert after >= before + 1
-        assert state  # silence the unused strict-shape example
 
 
 # ---- merged cross-process lock graph ---------------------------------------

@@ -1,12 +1,10 @@
 """Cardinality-envelope boundary matrix (ISSUE 20): the 4096-segment
 (fused kernel) and 64k-group (partial cache) envelopes crossed at
 N-1/N/N+1 on every tier flavor — classic sparse, tiled sparse-fused,
-mesh sharded-sparse, vmapped stacked, and the incremental partial
-cache — each bit-for-bit against the classic sort-compact oracle (the
+mesh sharded-sparse, and the incremental partial cache — each bit-for-bit against the classic sort-compact oracle (the
 single-device XLA scatter path). Integer-valued doubles keep f64 sums
 associativity-free, so "equal" means EQUAL, not allclose. The typed
-fallbacks (MeshIneligible demotion, VmapIneligible budget refusal,
-PlanError cap overflow) and the hot-set tier-admission probe ride
+fallbacks (MeshIneligible demotion, PlanError cap overflow) and the hot-set tier-admission probe ride
 along."""
 
 import numpy as np
@@ -203,82 +201,6 @@ class TestGroupEnvelope:
         assert db.executor.last_path == "sparse"
         assert SPARSE_DISPATCHES.get(path="classic") == before + 1
         assert got == dense
-
-
-class TestVmappedEnvelope:
-    """The stacked member axis over the sparse compaction: boundary
-    group domains, every member bit-for-bit with its serial run."""
-
-    DASH = ("SELECT date_bin(INTERVAL '1 second', ts) AS sec, sum(v), "
-            "count(v), min(v), max(v) FROM cpu WHERE host = '{h}' AND "
-            "ts >= {lo} AND ts < {hi} GROUP BY sec")
-
-    def _mk(self, qe, seconds):
-        qe.execute_one(
-            "CREATE TABLE cpu (host STRING, v DOUBLE, ts TIMESTAMP(3) "
-            "TIME INDEX, PRIMARY KEY(host))")
-        rows = []
-        for h in range(2):
-            for i in range(seconds):
-                rows.append(f"('h{h}', {float((i * 11 + h) % 97)!r}, "
-                            f"{i * 1000})")
-        qe.execute_one("INSERT INTO cpu (host, v, ts) VALUES "
-                       + ",".join(rows))
-
-    def _group(self, qe, sqls):
-        from greptimedb_tpu.concurrency import batcher as batcher_mod
-        from greptimedb_tpu.session import QueryContext
-        from greptimedb_tpu.sql.parser import parse_sql
-
-        info = qe._table("cpu", QueryContext())
-        shapes = []
-        for sql in sqls:
-            sel = parse_sql(sql)[0]
-            sh = batcher_mod.analyze(sel, info)
-            assert sh is not None, sql
-            shapes.append((sel, sh))
-        order = []
-        for _, sh in shapes:
-            if sh.values not in order:
-                order.append(sh.values)
-        return info, shapes[0][0], shapes[0][1], order, \
-            [sh.values for _, sh in shapes]
-
-    @pytest.mark.parametrize("seconds", [4095, 4097])
-    def test_sparse_vmapped_parity(self, db, monkeypatch, seconds):
-        from greptimedb_tpu.query.vmapped import run_vmapped
-
-        monkeypatch.setenv("GREPTIMEDB_TPU_SPARSE_GROUPS_MIN", "1")
-        self._mk(db, seconds)
-        hi = seconds * 1000
-        sqls = [self.DASH.format(h=f"h{i % 2}", lo=(i % 3) * 1000, hi=hi)
-                for i in range(4)]
-        info, leader, shape, order, per_sql = self._group(db, sqls)
-        results = run_vmapped(db.executor, leader, info, shape.params,
-                              order)
-        assert db.executor.last_path == "sparse_vmapped"
-        for sql, vals in zip(sqls, per_sql):
-            got = results[order.index(vals)]
-            with db.concurrency.suppress_batching():
-                want = db.execute_one(sql)
-            assert db.executor.last_path == "sparse"
-            assert got.names == want.names, sql
-            assert got.rows() == want.rows(), sql
-
-    def test_budget_refusal_is_typed(self, db, monkeypatch):
-        from greptimedb_tpu.query.vmapped import (
-            VmapIneligible,
-            run_vmapped,
-        )
-
-        monkeypatch.setenv("GREPTIMEDB_TPU_SPARSE_GROUPS_MIN", "1")
-        monkeypatch.setenv("GREPTIMEDB_TPU_SPARSE_GROUPS_MAX", "16")
-        self._mk(db, 600)
-        sqls = [self.DASH.format(h=f"h{i % 2}", lo=0, hi=600_000)
-                for i in range(4)]
-        info, leader, shape, order, _ = self._group(db, sqls)
-        with pytest.raises(VmapIneligible, match="budget"):
-            run_vmapped(db.executor, leader, info, shape.params, order)
 
 
 class TestTypedFallbacks:

@@ -23,7 +23,6 @@ import pytest
 
 from greptimedb_tpu import options
 from greptimedb_tpu.cli import build_standalone, stop_standalone
-from greptimedb_tpu.concurrency.encode_pool import EncodePool
 from greptimedb_tpu.servers.http import HttpServer
 from greptimedb_tpu.utils import (device_telemetry, lock_probe, profiling,
                                   tracing)
@@ -226,23 +225,33 @@ def test_tracing_off_moves_no_counter_and_starts_no_probe(monkeypatch):
     assert not _probe_threads()
 
 
-def test_encode_pool_thread_workers_run_beside_the_request():
-    pool = EncodePool(workers=1, min_rows=0, process_mode="off")
+def test_the_encoders_cpu_is_the_requests():
+    """An answer is encoded on the thread that owns the request: the
+    `encode` span holds the encoder's CPU (within its wall time), the
+    stage's counter takes it, nothing lands under `background`, and no
+    `bg:encode` span exists."""
+    import numpy as np
+
+    from greptimedb_tpu.query.result import QueryResult
+    from greptimedb_tpu.servers.encode import encode_sql_payload
+
+    result = QueryResult(["v"], [None], [np.arange(4.0)])
     c0 = _cpu()
-    try:
-        with tracing.request_span("test:encode_pool"):
-            tid = tracing.current_trace_id()
-            with tracing.stage("encode"):
-                out = pool.run(lambda: (_spin(), b"x")[1], cost_rows=10)
-    finally:
-        pool.shutdown()
-    assert out == b"x"
+    with tracing.request_span("test:encode"):
+        tid = tracing.current_trace_id()
+        with tracing.stage("parse"):
+            pass
+        with tracing.stage("encode"):
+            _spin()
+            out = encode_sql_payload([result], 1.0)
+    assert json.loads(out)["output"][0]["records"]["total_rows"] == 4
     d = _moved(c0, _cpu())
-    assert d["background"] >= SPIN_S
-    # parked on the future, the request thread is off the CPU
-    assert d["encode"] < d["background"] / 2
-    names = {s.name for s in tracing.spans_for(tid)}
-    assert {"encode", "bg:encode"} <= names
+    assert d["encode"] >= SPIN_S
+    assert d["background"] == 0.0
+    spans = tracing.spans_for(tid)
+    assert "bg:encode" not in {s.name for s in spans}
+    (enc,) = [s for s in spans if s.name == "encode"]
+    assert SPIN_S * 1000 <= enc.cpu_ms <= enc.duration_ms
 
 
 # ---- through the server ------------------------------------------------------

@@ -1,22 +1,18 @@
-"""Vectorized multi-query serving (ISSUE 11): the vmap'd stacked
-multi-query kernel (bit-for-bit vs serial execution, including
-window-union and multi-tag members), the wider batching shapes, the
-zero-GIL result-encode path (byte-identical responses under the encode
-pool, admission slot released at execute-done), typed-Overloaded
-bounds under burst with batching on, plan-cache skip-reason
-visibility, and runtime lockdep over the new encode-pool/batcher
-locks."""
+"""The serving path's encode seam and its guards: the columnar
+result-encode path (byte-identical responses from concurrent clients,
+the single flight's shared memo, admission slot released at
+execute-done), typed-Overloaded bounds under burst, plan-cache
+skip-reason visibility, and runtime lockdep over admission, the fast
+lane and the single flight's lock."""
 
 import json
 import os
 import subprocess
 import sys
 import threading
-import time
 import urllib.parse
 
 import numpy as np
-import pytest
 
 from greptimedb_tpu.catalog.catalog import Catalog
 from greptimedb_tpu.catalog.kv import MemoryKv
@@ -24,17 +20,12 @@ from greptimedb_tpu.concurrency import (
     ConcurrencyConfig,
     ConcurrencyPlane,
 )
-from greptimedb_tpu.concurrency import batcher as batcher_mod
-from greptimedb_tpu.concurrency.encode_pool import EncodePool
 from greptimedb_tpu.query.engine import QueryEngine
 from greptimedb_tpu.query.result import QueryResult
-from greptimedb_tpu.session import QueryContext
 from greptimedb_tpu.storage.engine import EngineConfig, RegionEngine
 from greptimedb_tpu.utils.metrics import (
-    ENCODE_POOL_EVENTS,
+    FAST_LANE_EVENTS,
     PLAN_CACHE_EVENTS,
-    QUERY_BATCH_EVENTS,
-    VMAP_BATCH_WIDTH,
 )
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -74,14 +65,6 @@ def ingest(qe, hosts=4, dcs=0, points=120, step_ms=1000, seed=7):
     qe.execute_one(f"INSERT INTO cpu {cols} VALUES " + ",".join(rows))
 
 
-def batch_plane(window_ms=25.0, **kw):
-    # batcher-layer tests: the parse-free fast lane would serve these
-    # repeat shapes before they could form batch groups
-    kw.setdefault("fast_lane", False)
-    return ConcurrencyPlane(ConcurrencyConfig(batch_window_ms=window_ms,
-                                              **kw))
-
-
 def run_threads(fns, timeout=120):
     out = [None] * len(fns)
     errors = []
@@ -104,355 +87,7 @@ def run_threads(fns, timeout=120):
     return out
 
 
-DASH2 = ("SELECT date_bin(INTERVAL '1 minute', ts) AS minute, max(v), "
-         "sum(v), avg(v) FROM cpu WHERE host = '{h}' AND dc = '{d}' AND "
-         "ts >= {lo} AND ts < {hi} GROUP BY minute")
-
-
-# ---- the vmap'd multi-query kernel ------------------------------------------
-
-
-class TestVmappedKernel:
-    def _analyze_group(self, qe, sqls):
-        """Parse + analyze a set of statements; they must share one
-        masked shape. Returns (leader sel, shape, member order,
-        per-sql member values)."""
-        from greptimedb_tpu.sql.parser import parse_sql
-
-        ctx = QueryContext()
-        info = qe._table("cpu", ctx)
-        shapes = []
-        for sql in sqls:
-            sel = parse_sql(sql)[0]
-            sh = batcher_mod.analyze(sel, info)
-            assert sh is not None, sql
-            shapes.append((sel, sh))
-        assert len({sh.masked for _, sh in shapes}) == 1
-        order = []
-        for _, sh in shapes:
-            if sh.values not in order:
-                order.append(sh.values)
-        return info, shapes[0][0], shapes[0][1], order, \
-            [sh.values for _, sh in shapes]
-
-    def test_vmapped_bit_for_bit_multi_tag_and_window_union(self, tmp_path):
-        """The acceptance differential: one vmapped dispatch over
-        members that differ in BOTH tag selectors and in their time
-        window (plus one member naming an absent tag value) must equal
-        each member's serial execution exactly — values, dtypes, and
-        row order."""
-        from greptimedb_tpu.query.vmapped import run_vmapped
-
-        engine, qe = make_qe(tmp_path)
-        create_cpu(qe, two_tags=True)
-        ingest(qe, hosts=4, dcs=2, points=120)
-        sqls = [DASH2.format(h=f"h{i % 4}", d=f"dc{i % 2}",
-                             lo=(i % 3) * 20_000,
-                             hi=60_000 + (i % 3) * 20_000)
-                for i in range(8)]
-        sqls.append(DASH2.format(h="absent", d="dc0", lo=0, hi=60_000))
-        info, leader, shape, order, per_sql = self._analyze_group(qe, sqls)
-        assert len(order) == 9
-        # window-union and multi-tag parameters both made it in
-        kinds = {p.kind for p in shape.params}
-        assert kinds == {"tag", "ts"}
-        assert sum(p.kind == "tag" for p in shape.params) == 2
-        results = run_vmapped(qe.executor, leader, info, shape.params,
-                              order)
-        assert qe.executor.last_path == "dense_vmapped"
-        for sql, vals in zip(sqls, per_sql):
-            got = results[order.index(vals)]
-            with qe.concurrency.suppress_batching():
-                want = qe.execute_one(sql)
-            assert got.names == want.names, sql
-            assert got.rows() == want.rows(), sql
-        engine.close()
-
-    def test_vmapped_parity_across_parts_and_dedup(self, tmp_path):
-        """Multi-part scans are where the fold-association argument
-        bites: two flushed SSTs plus a memtable tail, windows straddling
-        the part seams, and duplicate (host, ts) rows engaging the LWW
-        dedup mask — vmapped members must still equal serial exactly."""
-        from greptimedb_tpu.query.vmapped import run_vmapped
-
-        engine, qe = make_qe(tmp_path, maintenance_workers=1)
-        create_cpu(qe)
-        rng = np.random.default_rng(11)
-        for gen in range(3):
-            rows = []
-            for h in range(3):
-                for i in range(80):
-                    ts = (gen * 60 + i) * 1000
-                    rows.append(f"('h{h}',{rng.uniform(0, 50)!r},{ts})")
-            # overlap: re-write some of the previous generation's keys
-            # (same (host, ts), new value) so dedup has survivors to pick
-            if gen:
-                for h in range(3):
-                    for i in range(0, 40, 5):
-                        ts = ((gen - 1) * 60 + i) * 1000
-                        rows.append(
-                            f"('h{h}',{rng.uniform(50, 99)!r},{ts})")
-            qe.execute_one("INSERT INTO cpu (host, v, ts) VALUES "
-                           + ",".join(rows))
-            if gen < 2:
-                maint = qe.region_engine.maintenance
-                for r in qe.execute_one("ADMIN flush_table('cpu')").rows():
-                    maint.wait(int(r[0]), timeout=30)
-        sql = ("SELECT date_bin(INTERVAL '30 seconds', ts) AS b, sum(v), "
-               "min(v), count(*) FROM cpu WHERE host = 'h{h}' AND "
-               "ts >= {lo} AND ts < {hi} GROUP BY b")
-        sqls = [sql.format(h=i % 3, lo=(i % 4) * 30_000,
-                           hi=90_000 + (i % 4) * 25_000)
-                for i in range(10)]
-        info, leader, shape, order, per_sql = self._analyze_group(qe, sqls)
-        results = run_vmapped(qe.executor, leader, info, shape.params,
-                              order)
-        for sql, vals in zip(sqls, per_sql):
-            got = results[order.index(vals)]
-            with qe.concurrency.suppress_batching():
-                want = qe.execute_one(sql)
-            assert got.rows() == want.rows(), sql
-        engine.close()
-
-    def test_vmapped_threaded_through_batcher(self, tmp_path):
-        """Concurrent parameter-sibling dashboards land in ONE group
-        and ride the vmapped dispatch; every response equals its serial
-        oracle."""
-        engine, qe = make_qe(tmp_path, plane=batch_plane())
-        create_cpu(qe)
-        ingest(qe)
-        sql = ("SELECT date_bin(INTERVAL '1 minute', ts) AS minute, "
-               "max(v), sum(v) FROM cpu WHERE host = 'h{h}' AND "
-               "ts >= {lo} AND ts < {hi} GROUP BY minute")
-        sqls = [sql.format(h=i % 4, lo=(i % 2) * 30_000,
-                           hi=90_000 + (i % 2) * 30_000)
-                for i in range(12)]
-        serial = {}
-        with qe.concurrency.suppress_batching():
-            for s in set(sqls):
-                r = qe.execute_one(s)
-                serial[s] = (r.names, r.rows())
-        v0 = QUERY_BATCH_EVENTS.get(event="vmapped")
-        w0 = VMAP_BATCH_WIDTH.count()
-        got = run_threads([lambda s=s: qe.execute_one(s) for s in sqls])
-        for s, r in zip(sqls, got):
-            names, rows = serial[s]
-            assert r.names == names and r.rows() == rows, s
-        assert QUERY_BATCH_EVENTS.get(event="vmapped") > v0
-        assert VMAP_BATCH_WIDTH.count() > w0
-        engine.close()
-
-    def test_ineligible_single_tag_falls_back_to_stacked(self, tmp_path,
-                                                         monkeypatch):
-        """When the vmapped path declines, a single-tag group still
-        stacks via the legacy IN-list rewrite — parity preserved."""
-        from greptimedb_tpu.query import vmapped as vm
-
-        def refuse(*a, **k):
-            raise vm.VmapIneligible("test forces fallback")
-
-        monkeypatch.setattr(vm, "run_vmapped", refuse)
-        engine, qe = make_qe(tmp_path, plane=batch_plane())
-        create_cpu(qe)
-        ingest(qe)
-        sql = ("SELECT date_bin(INTERVAL '1 minute', ts) AS minute, "
-               "max(v) FROM cpu WHERE host = 'h{h}' AND ts >= 0 AND "
-               "ts < 90000 GROUP BY minute")
-        sqls = [sql.format(h=i % 4) for i in range(12)]
-        serial = {}
-        with qe.concurrency.suppress_batching():
-            for s in set(sqls):
-                r = qe.execute_one(s)
-                serial[s] = r.rows()
-        st0 = QUERY_BATCH_EVENTS.get(event="stacked")
-        got = run_threads([lambda s=s: qe.execute_one(s) for s in sqls])
-        for s, r in zip(sqls, got):
-            assert r.rows() == serial[s], s
-        assert QUERY_BATCH_EVENTS.get(event="stacked") > st0
-        engine.close()
-
-    def test_unexpected_vmapped_failure_latches_and_degrades(
-            self, tmp_path, monkeypatch):
-        """A runtime dispatch failure (compile error, device OOM) must
-        not poison the members — the batcher latches the vmapped path
-        off and serves the group via the fallbacks, still exactly."""
-        from greptimedb_tpu.query import vmapped as vm
-
-        def boom(*a, **k):
-            raise RuntimeError("XLA fell over")
-
-        monkeypatch.setattr(vm, "run_vmapped", boom)
-        engine, qe = make_qe(tmp_path, plane=batch_plane())
-        create_cpu(qe)
-        ingest(qe)
-        sql = ("SELECT date_bin(INTERVAL '1 minute', ts) AS minute, "
-               "sum(v) FROM cpu WHERE host = 'h{h}' AND ts >= {lo} AND "
-               "ts < {hi} GROUP BY minute")
-        sqls = [sql.format(h=i % 4, lo=(i % 2) * 30_000,
-                           hi=90_000 + (i % 2) * 30_000)
-                for i in range(10)]
-        serial = {}
-        with qe.concurrency.suppress_batching():
-            for s in set(sqls):
-                serial[s] = qe.execute_one(s).rows()
-        got = run_threads([lambda s=s: qe.execute_one(s) for s in sqls])
-        for s, r in zip(sqls, got):
-            assert r.rows() == serial[s], s
-        assert qe.concurrency.batcher._vmap_failed
-        # latched: later groups never try the vmapped path again
-        got = run_threads([lambda s=s: qe.execute_one(s) for s in sqls])
-        for s, r in zip(sqls, got):
-            assert r.rows() == serial[s], s
-        engine.close()
-
-    def test_typed_transient_failure_does_not_latch(self, tmp_path,
-                                                    monkeypatch):
-        """Unavailable/FaultError during a vmapped dispatch (a chaos
-        seam, a region mid-failover) falls back for THIS group but must
-        not disable the path for the process lifetime."""
-        from greptimedb_tpu.fault import Unavailable
-        from greptimedb_tpu.query import vmapped as vm
-
-        def flaky(*a, **k):
-            raise Unavailable("region mid-failover")
-
-        monkeypatch.setattr(vm, "run_vmapped", flaky)
-        engine, qe = make_qe(tmp_path, plane=batch_plane())
-        create_cpu(qe)
-        ingest(qe)
-        sql = ("SELECT date_bin(INTERVAL '1 minute', ts) AS minute, "
-               "sum(v) FROM cpu WHERE host = 'h{h}' AND ts >= 0 AND "
-               "ts < 90000 GROUP BY minute")
-        sqls = [sql.format(h=i % 4) for i in range(8)]
-        serial = {}
-        with qe.concurrency.suppress_batching():
-            for s in set(sqls):
-                serial[s] = qe.execute_one(s).rows()
-        got = run_threads([lambda s=s: qe.execute_one(s) for s in sqls])
-        for s, r in zip(sqls, got):
-            assert r.rows() == serial[s], s
-        assert not qe.concurrency.batcher._vmap_failed
-        engine.close()
-
-    def test_serial_fallback_coalesces_duplicate_values(self, tmp_path,
-                                                        monkeypatch):
-        """When the group self-executes (vmapped off, not IN-list
-        stackable), duplicates of one parameter tuple ride ONE relay
-        execution instead of each re-running the query."""
-        engine, qe = make_qe(tmp_path,
-                             plane=batch_plane(batch_vmap=False))
-        create_cpu(qe)
-        ingest(qe)
-        calls = []
-        orig = qe._select_table
-
-        def counted(sel, info, ctx):
-            calls.append(repr(sel))
-            return orig(sel, info, ctx)
-
-        monkeypatch.setattr(qe, "_select_table", counted)
-        sql = ("SELECT date_bin(INTERVAL '1 minute', ts) AS minute, "
-               "sum(v) FROM cpu WHERE host = 'h{h}' AND ts >= {lo} AND "
-               "ts < {hi} GROUP BY minute")
-        # 3 distinct (host, window) tuples x 4 duplicates each
-        sqls = [sql.format(h=i % 3, lo=(i % 3) * 30_000,
-                           hi=90_000 + (i % 3) * 30_000)
-                for i in range(3)] * 4
-        serial = {}
-        with qe.concurrency.suppress_batching():
-            for s in set(sqls):
-                serial[s] = qe.execute_one(s).rows()
-        calls.clear()
-        sf0 = QUERY_BATCH_EVENTS.get(event="serial_fallback")
-        got = run_threads([lambda s=s: qe.execute_one(s) for s in sqls])
-        for s, r in zip(sqls, got):
-            assert r.rows() == serial[s], s
-        if QUERY_BATCH_EVENTS.get(event="serial_fallback") > sf0:
-            # a fallback group really formed: duplicates must not have
-            # multiplied the executions (one per distinct tuple, plus
-            # any members that raced into their own groups)
-            assert len(calls) < len(sqls)
-        engine.close()
-
-    def test_ineligible_window_union_falls_back_to_serial(self, tmp_path,
-                                                          monkeypatch):
-        """Window-union members with the vmapped kernel disabled can't
-        use the IN-list rewrite (no single selector) — they execute
-        serially inside the group, still bit-for-bit."""
-        engine, qe = make_qe(tmp_path,
-                             plane=batch_plane(batch_vmap=False))
-        create_cpu(qe)
-        ingest(qe)
-        sql = ("SELECT date_bin(INTERVAL '1 minute', ts) AS minute, "
-               "sum(v) FROM cpu WHERE host = 'h1' AND ts >= {lo} AND "
-               "ts < {hi} GROUP BY minute")
-        sqls = [sql.format(lo=(i % 3) * 20_000,
-                           hi=60_000 + (i % 3) * 20_000)
-                for i in range(9)]
-        serial = {}
-        with qe.concurrency.suppress_batching():
-            for s in set(sqls):
-                serial[s] = qe.execute_one(s).rows()
-        sf0 = QUERY_BATCH_EVENTS.get(event="serial_fallback")
-        got = run_threads([lambda s=s: qe.execute_one(s) for s in sqls])
-        for s, r in zip(sqls, got):
-            assert r.rows() == serial[s], s
-        assert QUERY_BATCH_EVENTS.get(event="serial_fallback") > sf0
-        engine.close()
-
-    def test_multi_block_part_gate_refuses(self, tmp_path, monkeypatch):
-        """A scan part spanning several device blocks breaks the
-        fold-association parity argument — the vmapped path must refuse
-        (and the batcher then serves the group another way)."""
-        from greptimedb_tpu.query import physical as ph
-        from greptimedb_tpu.query import vmapped as vm
-        from greptimedb_tpu.sql.parser import parse_sql
-
-        engine, qe = make_qe(tmp_path)
-        create_cpu(qe)
-        ingest(qe, hosts=2, points=200)
-        sql = ("SELECT date_bin(INTERVAL '1 minute', ts) AS minute, "
-               "sum(v) FROM cpu WHERE host = 'h{h}' AND ts >= 0 AND "
-               "ts < 90000 GROUP BY minute")
-        ctx = QueryContext()
-        info = qe._table("cpu", ctx)
-        sels = [parse_sql(sql.format(h=h))[0] for h in (0, 1)]
-        shape = batcher_mod.analyze(sels[0], info)
-        order = [batcher_mod.analyze(s, info).values for s in sels]
-        monkeypatch.setattr(ph, "DEFAULT_BLOCK_ROWS", 64)
-        with pytest.raises(vm.VmapIneligible):
-            vm.run_vmapped(qe.executor, sels[0], info, shape.params,
-                           order)
-        engine.close()
-
-    def test_analyze_widened_shapes(self, tmp_path):
-        """analyze() now parameterizes multi-tag conjunctions and
-        time-window comparisons; selectors feeding the projection still
-        refuse."""
-        from greptimedb_tpu.sql.parser import parse_sql
-
-        engine, qe = make_qe(tmp_path)
-        create_cpu(qe, two_tags=True)
-        ingest(qe, hosts=2, dcs=2, points=5)
-        ctx = QueryContext()
-        info = qe._table("cpu", ctx)
-
-        sh = batcher_mod.analyze(parse_sql(
-            "SELECT dc, max(v) FROM cpu WHERE host = 'h0' AND "
-            "dc = 'dc1' AND ts >= 0 AND ts < 5000 GROUP BY dc")[0], info)
-        assert sh is not None
-        # dc feeds the output relation -> not a parameter; host + both
-        # window bounds are
-        assert [(p.col, p.kind, p.op) for p in sh.params] == [
-            ("host", "tag", "="), ("ts", "ts", ">="), ("ts", "ts", "<")]
-        assert sh.values == ("h0", 0, 5000)
-        # no parameters at all -> coalesce-only (shape None)
-        assert batcher_mod.analyze(parse_sql(
-            "SELECT dc, max(v) FROM cpu GROUP BY dc")[0], info) is None
-        engine.close()
-
-
-# ---- zero-GIL result-encode path --------------------------------------------
+# ---- the result-encode path ------------------------------------------------
 
 
 def _legacy_json_rows(r: QueryResult) -> list:
@@ -491,134 +126,43 @@ class TestEncodePath:
         # and the JSON bytes agree too (the wire contract)
         assert json.dumps(fast) == json.dumps(_legacy_json_rows(r))
 
-    def test_encode_memo_shares_materialization(self):
-        from greptimedb_tpu.servers.encode import json_rows, memo_rows
+    def test_encode_memo_shares_materialization(self, tmp_path):
+        """The memo as the single flight makes it: a fast-lane hit's
+        result carries one, and what the first encoder wrote is what
+        every later encoder of that result gets."""
+        from greptimedb_tpu.servers.encode import (
+            json_rows,
+            memo_rows,
+            rows_json,
+        )
 
-        r = QueryResult(["x"], [None], [np.asarray([1.0, 2.0])])
-        r.encode_memo = {}
+        engine, qe = make_qe(tmp_path)
+        create_cpu(qe)
+        ingest(qe, hosts=2, points=10)
+        sql = "SELECT host, max(v) FROM cpu WHERE ts >= {} GROUP BY host"
+        hits = FAST_LANE_EVENTS.get(event="hit")
+        for lo in (0, 1000, 2000):
+            r = qe.execute_one(sql.format(lo))
+        assert FAST_LANE_EVENTS.get(event="hit") > hits
+        assert r.encode_memo == {}
         first = json_rows(r)
         assert json_rows(r) is first  # memoized, not rebuilt
         rows = memo_rows(r)
         assert memo_rows(r) is rows
-
-    def test_pool_offloads_and_inline_fallback(self):
-        pool = EncodePool(workers=2, queue_size=1)
-        off0 = ENCODE_POOL_EVENTS.get(event="offload")
-        in0 = ENCODE_POOL_EVENTS.get(event="inline")
-        assert pool.run(lambda: b"x") == b"x"
-        assert ENCODE_POOL_EVENTS.get(event="offload") == off0 + 1
-
-        gate = threading.Event()
-        results = []
-
-        def slow():
-            gate.wait(10)
-            return b"slow"
-
-        t = threading.Thread(target=lambda: results.append(
-            pool.run(slow)))
-        t.start()
-        for _ in range(100):  # wait until the slow job holds the queue
-            if pool._inflight >= 1:
-                break
-            time.sleep(0.01)
-        assert pool.run(lambda: b"y") == b"y"  # inline: queue is full
-        assert ENCODE_POOL_EVENTS.get(event="inline") > in0
-        gate.set()
-        t.join(10)
-        assert results == [b"slow"]
-        assert pool._inflight == 0
-        pool.shutdown()
-
-    def test_auto_mode_routes_by_measured_result_size(self):
-        """ISSUE-13 satellite: process_mode="auto" escapes to the spawn
-        pool only for results at/above the threshold — dashboard-sized
-        rows keep the thread pool, and the on/off knobs pin it."""
-        pool = EncodePool(workers=1, min_rows=0,
-                          process_min_rows=1000)
-        assert pool.process_mode == "auto"
-        assert not pool._want_process(10)       # dashboard-sized
-        assert not pool._want_process(999)
-        assert pool._want_process(1000)         # measured size escapes
-        assert not pool._want_process(None)     # unknown: stay thread
-        off = EncodePool(workers=1, process_mode="off",
-                         process_min_rows=0)
-        assert not off._want_process(1 << 30)
-        pinned = EncodePool(workers=1, process=True)
-        assert pinned.process_mode == "on"
-        assert pinned._want_process(1)
-
-    def test_auto_mode_process_escape_round_trip(self):
-        """A result over the auto threshold actually rides the spawn
-        pool and returns byte-identical output; a small one offloads to
-        the thread pool in the same EncodePool instance."""
-        from greptimedb_tpu.servers.encode import encode_sql_payload
-
-        r = QueryResult(["a"], [None], [np.arange(8, dtype=float)])
-        want = encode_sql_payload([r], 1.0)
-        pool = EncodePool(workers=1, min_rows=0, process_min_rows=4)
-        po0 = ENCODE_POOL_EVENTS.get(event="offload_process")
-        o0 = ENCODE_POOL_EVENTS.get(event="offload")
-        try:
-            got = pool.run(encode_sql_payload, [r], 1.0, cost_rows=8)
-            assert got == want
-            assert ENCODE_POOL_EVENTS.get(event="offload_process") \
-                == po0 + 1
-            small = pool.run(encode_sql_payload, [r], 1.0, cost_rows=2)
-            assert small == want
-            assert ENCODE_POOL_EVENTS.get(event="offload") == o0 + 1
-        finally:
-            pool.shutdown()
-
-    def test_encode_process_mode_env_knob(self, monkeypatch):
-        """GTPU_ENCODE_PROCESS_MODE / GTPU_ENCODE_PROCESS_MIN_ROWS A/B
-        the routing without an options object."""
-        from greptimedb_tpu import concurrency as conc
-
-        monkeypatch.setenv("GTPU_ENCODE_PROCESS_MODE", "off")
-        assert conc.current_config().encode_process_mode == "off"
-        monkeypatch.setenv("GTPU_ENCODE_PROCESS_MODE", "on")
-        monkeypatch.setenv("GTPU_ENCODE_PROCESS_MIN_ROWS", "7")
-        cfg = conc.current_config()
-        assert cfg.encode_process_mode == "on"
-        assert cfg.encode_process_min_rows == 7
-
-    def test_process_pool_round_trip(self, monkeypatch):
-        """Spawn-mode process encoding returns the same bytes as
-        inline (full GIL escape behind [concurrency]
-        encode_process_pool). The worker inherits the server's
-        environment — which on a chip names the accelerator — and must
-        pin itself to the CPU: the chip belongs to the server."""
-        from greptimedb_tpu.concurrency.encode_pool import (
-            worker_jax_platforms,
-        )
-        from greptimedb_tpu.servers.encode import encode_sql_payload
-
-        r = QueryResult(["a", "b"], [None, None],
-                        [np.asarray([1.0, float("nan")]),
-                         np.asarray(["x", "y"], dtype=object)])
-        want = encode_sql_payload([r], 1.25)
-        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
-        pool = EncodePool(workers=1, process=True)
-        try:
-            got = pool.run(encode_sql_payload, [r], 1.25)
-            platforms = pool.run(worker_jax_platforms)
-        finally:
-            pool.shutdown()
-        assert got == want
-        assert platforms == "cpu"
+        written = rows_json(r)
+        assert rows_json(r) is written
+        assert set(r.encode_memo) == {"json_rows", "rows", "rows_json"}
+        engine.close()
 
     def test_http_50_clients_byte_identical_to_idle_serial(self, tmp_path):
-        """The satellite acceptance: threaded keep-alive clients under
-        the encode pool get responses byte-identical to the idle-server
-        serial path (only execution_time_ms may differ)."""
+        """Threaded clients, each response encoded on its own request
+        thread, get responses byte-identical to the idle-server serial
+        path (only execution_time_ms may differ)."""
         import http.client
 
         from greptimedb_tpu.servers.http import HttpServer
 
-        engine, qe = make_qe(
-            tmp_path,
-            plane=batch_plane(window_ms=10.0, encode_min_rows=0))
+        engine, qe = make_qe(tmp_path)
         create_cpu(qe)
         ingest(qe)
         srv = HttpServer(qe, port=0)
@@ -649,18 +193,16 @@ class TestEncodePath:
             sqls = [sql.format(h=i % 4, lo=(i % 2) * 30_000,
                                hi=90_000 + (i % 2) * 30_000)
                     for i in range(50)]
-            off0 = ENCODE_POOL_EVENTS.get(event="offload")
             serial = {s: fetch(s) for s in set(sqls)}
             got = run_threads([lambda s=s: fetch(s) for s in sqls])
             for s, body in zip(sqls, got):
                 assert body == serial[s], s
-            assert ENCODE_POOL_EVENTS.get(event="offload") > off0
         finally:
             srv.stop()
         engine.close()
 
-    def test_burst_overloaded_rates_bounded_with_batching_on(self, tmp_path):
-        """Burst past the admission bound with batching ON: every
+    def test_burst_overloaded_rates_bounded(self, tmp_path):
+        """Burst past the admission bound: every
         failure is the typed 503 (code 5003), never a stack trace, and
         the server keeps serving at least its configured concurrency —
         no starvation regression vs the PR 6 contract."""
@@ -669,8 +211,7 @@ class TestEncodePath:
         from greptimedb_tpu.servers.http import HttpServer
 
         plane = ConcurrencyPlane(ConcurrencyConfig(
-            max_concurrency=2, queue_size=2, queue_timeout_s=0.5,
-            batch_window_ms=5.0))
+            max_concurrency=2, queue_size=2, queue_timeout_s=0.5))
         engine, qe = make_qe(tmp_path, plane=plane)
         create_cpu(qe)
         ingest(qe, hosts=2, points=60)
@@ -713,17 +254,13 @@ class TestEncodePath:
             srv.stop()
         engine.close()
 
-    def test_mysql_rows_encode_parity_and_pool(self):
+    def test_mysql_rows_encode_parity(self):
         from greptimedb_tpu.servers.encode import encode_mysql_rows
 
         rows = [[1, "a", None], [2.5, "b", float("nan")]]
         inline = encode_mysql_rows(["x", "y", "z"], rows)
-        pool = EncodePool(workers=1)
-        try:
-            pooled = pool.run(encode_mysql_rows, ["x", "y", "z"], rows)
-        finally:
-            pool.shutdown()
-        assert pooled == inline
+        # text rows: a length-prefixed string a value, 0xfb a NULL / NaN
+        assert inline[-3:-1] == [b"\x011\x01a\xfb", b"\x032.5\x01b\xfb"]
         binary = encode_mysql_rows(["x", "y", "z"], rows, True)
         assert binary != inline  # binary protocol really is distinct
         assert binary[0] == inline[0]  # same column count header
@@ -794,7 +331,7 @@ class TestPlanCacheSkipReasons:
         engine.close()
 
 
-# ---- runtime lockdep over the new locks -------------------------------------
+# ---- runtime lockdep over the serving locks -------------------------------------
 
 
 _LOCKDEP_SCRIPT = """
@@ -812,7 +349,7 @@ from greptimedb_tpu.storage.engine import EngineConfig, RegionEngine
 
 with tempfile.TemporaryDirectory() as d:
     eng = RegionEngine(EngineConfig(data_dir=d, maintenance_workers=0))
-    plane = ConcurrencyPlane(ConcurrencyConfig(batch_window_ms=10.0))
+    plane = ConcurrencyPlane(ConcurrencyConfig(max_concurrency=2))
     qe = QueryEngine(Catalog(MemoryKv()), eng, concurrency=plane)
     ctx = QueryContext(db="public")
     qe.execute_sql("CREATE TABLE t (host STRING, ts TIMESTAMP TIME INDEX,"
@@ -828,7 +365,7 @@ with tempfile.TemporaryDirectory() as d:
                     "SELECT host, count(*), sum(v) FROM t WHERE "
                     f"host = 'h{(k + j) % 4}' AND ts >= 1700000000000 "
                     "GROUP BY host", ctx)
-                plane.encode.run(encode_sql_payload, r, 0.0)
+                encode_sql_payload(r, 0.0)
         except Exception as e:
             errs.append(e)
     threads = [threading.Thread(target=worker, args=(k,))
@@ -845,11 +382,13 @@ print(f"LOCKDEP_EDGES={len(repo_edges)}")
 """
 
 
-def test_runtime_lockdep_covers_batcher_and_encode_pool():
-    """GTPU_LOCKDEP=1 over the new serving path: threaded batched
-    queries whose results are then serialized through the encode pool;
-    the observed lock nesting (batch-window lock, encode-pool
-    bookkeeping, admission, metrics) must stay acyclic."""
+def test_runtime_lockdep_covers_admission_fast_lane_and_single_flight():
+    """GTPU_LOCKDEP=1 over the serving path: threaded repeat-shape
+    queries queueing for two admission slots, served by the fast lane
+    (identical ones sharing a flight), their results serialized on the
+    thread that ran them; the observed lock nesting (admission, the
+    lane's template and flight locks, plan cache, metrics) must stay
+    acyclic."""
     res = subprocess.run(
         [sys.executable, "-c", _LOCKDEP_SCRIPT],
         capture_output=True, text=True, timeout=480, cwd=REPO_ROOT,
@@ -860,12 +399,11 @@ def test_runtime_lockdep_covers_batcher_and_encode_pool():
 
 
 def test_lint_scope_covers_serving_modules():
-    """The static lockdep/blocking checkers must include the vmapped
-    leader and the encode seam (concurrency/ itself is scope-prefixed,
-    which covers batcher.py and encode_pool.py)."""
+    """The static lockdep/blocking checkers must include the encode
+    seam (concurrency/ itself is scope-prefixed, which covers
+    admission.py, plan_cache.py and fast_lane.py)."""
     from greptimedb_tpu.lint.lockgraph import SCOPE_FILES, _in_scope
 
-    assert "greptimedb_tpu/query/vmapped.py" in SCOPE_FILES
     assert "greptimedb_tpu/servers/encode.py" in SCOPE_FILES
-    assert _in_scope("greptimedb_tpu/concurrency/encode_pool.py")
-    assert _in_scope("greptimedb_tpu/concurrency/batcher.py")
+    for mod in ("admission", "plan_cache", "fast_lane"):
+        assert _in_scope(f"greptimedb_tpu/concurrency/{mod}.py")
